@@ -43,8 +43,9 @@ def parse_args(argv=None):
 
 
 def main(argv=None):
-    from k8s1m_tpu.envboot import tune_gc
+    from k8s1m_tpu.envboot import place_compile_cache, tune_gc
 
+    place_compile_cache()
     tune_gc()
     args = parse_args(argv)
     spec = ClusterSpec(

@@ -107,7 +107,7 @@ class UndonatedDeviceUpdate(Rule):
                 elif isinstance(node.value, ast.Name):
                     got = {node.value.id}      # alias: fn = impl
                 elif isinstance(node.value, ast.Call):
-                    # Wrapper aliasing: fn = shard_map_compat(impl, ...)
+                    # Wrapper aliasing: fn = jax.shard_map(impl, ...)
                     # / step = jax.jit(impl, ...) — the bound name
                     # reaches the wrapped callable, so a later jit of
                     # the wrapper is still covered.  Only the callable

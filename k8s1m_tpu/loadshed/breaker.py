@@ -19,8 +19,7 @@ faultline ``stall`` kind, driver rejections) and successes at *retire*
 (the wave's results came back).  A runtime that accepts the async
 dispatch and then never completes blocks the caller inside the
 device fetch, where no portable timeout exists — that class needs an
-external watchdog (``tools/with_deadline.py`` process-level deadlines),
-not this breaker.  Under a deep pipeline an open can lag dispatch
+external process-level deadline, not this breaker.  Under a deep pipeline an open can lag dispatch
 failures by up to ``depth`` retires (old waves retiring successfully
 reset the consecutive count) — by design: a device draining real work
 is not yet dead.

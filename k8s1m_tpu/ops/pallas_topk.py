@@ -24,9 +24,15 @@ become one-hot matmuls on the MXU, like the taint trick: the [Q, C]
 query-key resolution is packed as a [Q, 5C] plane (found, value-id hi/lo,
 numeric hi/lo) and each expression slot selects its row with a
 [TB, Q] x [Q, 5C] dot.  Every id travels the f32 dot as two 16-bit
-halves (f32-exact) and is recombined in int32, so In/NotIn equality and
-Gt/Lt compares are bit-exact even for ids beyond f32's 2^24 integer
-range (one-hot rows make the dot a pure selection — no summation error).
+halves and is recombined in int32, so In/NotIn equality and Gt/Lt
+compares are bit-exact even for ids beyond f32's 2^24 integer range
+(one-hot rows make the dot a pure selection — no summation error).  That
+only holds if the dot itself keeps f32 precision: Mosaic's default
+contraction rounds f32 operands to bf16 (8 significant bits — measured
+on v5e: every score above 255 came back off by a few units), so every
+dot that carries a wide value asks for ``_EXACT`` (fp32 contraction);
+the taint dots carry only 0/1 and counts <= taint_slots and stay on the
+fast default.
 Constraint plugins (PodTopologySpread, InterPodAffinity) stay on the XLA
 path — their count-table state doesn't fit the stateless-kernel mold;
 the engine picks the backend per batch (engine/cycle.py schedule_batch).
@@ -58,13 +64,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Version skew: newer jax renamed TPUCompilerParams -> CompilerParams;
-# accept either so the kernel builds on current jax AND this
-# environment's 0.4.x (the virtual CPU mesh runs it interpreted).
-_COMPILER_PARAMS = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 from k8s1m_tpu.config import (
     EFFECT_NO_EXECUTE,
     EFFECT_NO_SCHEDULE,
@@ -91,6 +90,11 @@ from k8s1m_tpu.ops.priority import (
 from k8s1m_tpu.plugins.registry import Profile
 from k8s1m_tpu.snapshot.node_table import NodeTable
 from k8s1m_tpu.snapshot.pod_encoding import PodBatch
+
+# Contraction precision for the one-hot selection dots whose operands
+# carry integers wider than 8 bits (16-bit id/score halves, domain
+# counts) — see the module doc.
+_EXACT = lax.Precision.HIGHEST
 
 
 def supports(profile: Profile) -> bool:
@@ -290,9 +294,10 @@ def _kernel(
         q = qkey.shape[0]
         kq = qkey[:]                                  # [Q, 1]
         found = jnp.zeros((q, c), jnp.float32)
-        # Every id travels the f32 dot as two 16-bit halves (f32-exact)
-        # and is recombined in int32 — value ids as well as numerics, so
-        # vocab ids beyond f32's 2^24 integer range can never alias.
+        # Every id travels the f32 dot as two 16-bit halves (exact under
+        # the _EXACT contraction) and is recombined in int32 — value ids
+        # as well as numerics, so vocab ids beyond f32's 2^24 integer
+        # range can never alias.
         vhi = jnp.zeros((q, c), jnp.float32)
         vlo = jnp.zeros((q, c), jnp.float32)
         nhi = jnp.zeros((q, c), jnp.float32)
@@ -324,7 +329,10 @@ def _kernel(
             i32, numeric i32 — both recombined exactly from 16-bit
             halves)."""
             onehot = (qidx_c == iota_q).astype(jnp.float32)       # [TB, Q]
-            g = jnp.dot(onehot, planes, preferred_element_type=jnp.float32)
+            g = jnp.dot(
+                onehot, planes, precision=_EXACT,
+                preferred_element_type=jnp.float32,
+            )
             fi = (g[:, :c] > 0.5).astype(jnp.int32)
             v = (
                 g[:, c : 2 * c].astype(jnp.int32) * 65536
@@ -440,11 +448,11 @@ def _kernel(
     # _counts_for) become: per chunk, project the [SLOTS, Z] zone/region
     # tables onto the chunk's nodes with a domain one-hot ([SLOTS, Z] x
     # [Z, C] on the MXU), then select each pod ref's slot with a one-hot
-    # [TB, SLOTS] dot.  Counts are integers < 2^24, f32-exact through
-    # the dots.  Batch-global statistics (min/max per domain, target
-    # totals, preferred-score bounds) are [TB, *] inputs precomputed by
-    # the caller from topology.prologue — global reductions don't belong
-    # in a chunk-local kernel.
+    # [TB, SLOTS] dot.  Counts are integers < 2^24, exact through the
+    # dots under the _EXACT contraction.  Batch-global statistics
+    # (min/max per domain, target totals, preferred-score bounds) are
+    # [TB, *] inputs precomputed by the caller from topology.prologue —
+    # global reductions don't belong in a chunk-local kernel.
     if with_cons:
         zdim = sz.shape[1]
         rdim = sr.shape[1]
@@ -463,8 +471,10 @@ def _kernel(
             return (
                 node_cols[:].astype(jnp.float32),
                 jnp.dot(ztab[:].astype(jnp.float32), onehot_z,
+                        precision=_EXACT,
                         preferred_element_type=jnp.float32),
                 jnp.dot(rtab[:].astype(jnp.float32), onehot_r,
+                        precision=_EXACT,
                         preferred_element_type=jnp.float32),
             )
 
@@ -476,9 +486,12 @@ def _kernel(
             sel = (
                 lax.broadcasted_iota(jnp.int32, (tb, slots), 1) == slot_col
             ).astype(jnp.float32)                             # [TB, SLOTS]
-            cn = jnp.dot(sel, nf, preferred_element_type=jnp.float32)
-            cz = jnp.dot(sel, zf, preferred_element_type=jnp.float32)
-            cr = jnp.dot(sel, rf, preferred_element_type=jnp.float32)
+            cn = jnp.dot(sel, nf, precision=_EXACT,
+                         preferred_element_type=jnp.float32)
+            cz = jnp.dot(sel, zf, precision=_EXACT,
+                         preferred_element_type=jnp.float32)
+            cr = jnp.dot(sel, rf, precision=_EXACT,
+                         preferred_element_type=jnp.float32)
             is_h = topo_col == TOPO_HOSTNAME
             is_z = topo_col == TOPO_ZONE
             cnt = jnp.where(is_h, cn, jnp.where(is_z, cz, cr))
@@ -802,7 +815,7 @@ def _call(
             pltpu.VMEM((tb, 128), jnp.int32),
             pltpu.VMEM((tb, 128), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
@@ -1057,7 +1070,7 @@ def _delta_kernel(
 
     The slot gather is a one-hot [TB, S] x [S, 3C] dot on the MXU (the
     taint/label trick): scores travel the f32 dot as two 16-bit halves
-    (f32-exact, recombined in int32 — exact for negatives too since
+    (exact under _EXACT, recombined in int32 — exact for negatives too since
     x == (x >> 16) * 65536 + (x & 0xFFFF) under the arithmetic shift).
     Slot ids clip to S-1 like jnp.take's clip mode, so padding pods read
     the same garbage row plane_topk's take reads — bit-identical
@@ -1086,7 +1099,9 @@ def _delta_kernel(
         ],
         axis=1,
     )                                                             # [S, 3C]
-    g = jnp.dot(onehot, planes, preferred_element_type=jnp.float32)
+    g = jnp.dot(
+        onehot, planes, precision=_EXACT, preferred_element_type=jnp.float32
+    )
     mask = g[:, :c] > 0.5
     score = (
         g[:, c : 2 * c].astype(jnp.int32) * 65536
@@ -1154,7 +1169,7 @@ def _delta_call(
             pltpu.VMEM((tb, 128), jnp.int32),
             pltpu.VMEM((tb, 128), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,

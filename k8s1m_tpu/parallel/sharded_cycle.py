@@ -11,7 +11,9 @@ Dataflow per cycle (replacing reference SURVEY.md §3.2's process hops):
    device the full batch's candidate lists — a few KB;
 4. the greedy conflict-resolution scan runs *replicated* on every device
    (identical inputs -> identical result, no coordination), replacing the
-   reference's optimistic bind-and-rollback;
+   reference's optimistic bind-and-rollback — replicated by
+   construction, not by inference, which is why every shard_map here
+   passes ``check_vma=False``;
 5. each sp shard commits the binds that landed in its row range to its
    slice of the table and of the hostname-domain count tables; zone /
    region count tables are replicated and take the full (identical)
@@ -72,30 +74,6 @@ from k8s1m_tpu.plugins.registry import Profile
 from k8s1m_tpu.snapshot.constraints import ConstraintState
 from k8s1m_tpu.snapshot.node_table import NodeTable, scatter_rows
 from k8s1m_tpu.snapshot.pod_encoding import PodBatch
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across the installed-version API skew.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``.  Both
-    flags gate the same replication/varying-manual-axes check, which the
-    scheduling step disables (the epilogue's replicated conflict scan is
-    replicated by construction, not by inference).  Routing through this
-    shim is what lets the same mesh code drive a TPU pod on current jax
-    AND the 8-device virtual CPU mesh this environment's jax hosts.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
 
 
 def make_sharded_scatter(table_sharding):
@@ -208,11 +186,12 @@ def make_sharded_step(mesh, profile: Profile, *, chunk: int, k: int):
     def step(table, batch, key, constraints=None):
         asg_specs = Assignment(P(), P(), P(), P(), P())
         cons_specs = constraint_specs(constraints) if constraints is not None else None
-        return shard_map_compat(
+        return jax.shard_map(
             _local_step,
             mesh=mesh,
             in_specs=(table_specs(table), batch_specs(batch), P(), cons_specs),
             out_specs=(table_specs(table), cons_specs, asg_specs),
+            check_vma=False,
         )(table, batch, key, constraints)
 
     # Replay/dev surface (tests, dryruns, multihost smokes re-run one
@@ -369,21 +348,23 @@ def make_sharded_packed_step(
     def _step_cons(table, ints, bools, key, offset, constraints):
         asg_specs = Assignment(P(), P(), P(), P(), P())
         cons_specs = constraint_specs(constraints)
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             _local_step,
             mesh=mesh,
             in_specs=(table_specs(table), P(), P(), P(), P(), cons_specs),
             out_specs=(table_specs(table), cons_specs, asg_specs, P()),
+            check_vma=False,
         )
         return fn(table, ints, bools, key, offset, constraints)
 
     def _step_plain(table, ints, bools, key, offset):
         asg_specs = Assignment(P(), P(), P(), P(), P())
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             lambda t, i, bl, kk, off: _local_step(t, i, bl, kk, off, None),
             mesh=mesh,
             in_specs=(table_specs(table), P(), P(), P(), P()),
             out_specs=(table_specs(table), None, asg_specs, P()),
+            check_vma=False,
         )
         return fn(table, ints, bools, key, offset)
 
@@ -523,7 +504,7 @@ def make_sharded_delta_step(
     def _step(table, ints, bools, key, slot_ids, pmask, pscore, dirty,
               *inflight):
         asg_specs = Assignment(P(), P(), P(), P(), P())
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             _local_step,
             mesh=mesh,
             in_specs=(
@@ -534,6 +515,7 @@ def make_sharded_delta_step(
                 table_specs(table), asg_specs, P(),
                 PLANE_SPEC, PLANE_SPEC,
             ),
+            check_vma=False,
         )
         return fn(table, ints, bools, key, slot_ids, pmask, pscore,
                   dirty, *inflight)
@@ -571,13 +553,14 @@ def make_sharded_plane_fill(
         )
 
     def _fill(table, ints, bools, fill_slots, pmask, pscore):
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             _local_fill,
             mesh=mesh,
             in_specs=(
                 table_specs(table), P(), P(), P(), PLANE_SPEC, PLANE_SPEC
             ),
             out_specs=(PLANE_SPEC, PLANE_SPEC),
+            check_vma=False,
         )
         return fn(table, ints, bools, fill_slots, pmask, pscore)
 

@@ -24,7 +24,7 @@ import time
 
 from k8s1m_tpu.config import PodSpec, TableSpec
 from k8s1m_tpu.control.coordinator import Coordinator
-from k8s1m_tpu.envboot import tune_gc
+from k8s1m_tpu.envboot import place_compile_cache, tune_gc
 from k8s1m_tpu.control.objects import encode_node, encode_pod, node_key, pod_key
 from k8s1m_tpu.plugins.registry import Profile
 from k8s1m_tpu.snapshot.pod_encoding import PodInfo
@@ -153,7 +153,7 @@ def parse_args(argv=None):
     ap.add_argument(
         "--depth", type=int, default=2,
         help="scheduling pipeline depth (in-flight waves; >2 helps when "
-        "the device round trip dominates the wave, e.g. a remote relay)",
+        "the device round trip dominates the wave)",
     )
     ap.add_argument(
         "--churn", action="store_true",
@@ -785,6 +785,7 @@ def _start_watch_stress(target: str, watchers: int, write_concurrency: int):
 
 
 def main(argv=None):
+    place_compile_cache()
     from k8s1m_tpu.obs.profiler import install_signal_dump
 
     # Always-on on-demand stack dump (SIGUSR2 -> /tmp/stacks-<pid>.txt),

@@ -318,7 +318,7 @@ async def amain(args) -> dict:
     certs_dir = tempfile.mkdtemp(prefix="soak-certs-")
     certs = provision(certs_dir)
     token = "soak-bearer-token"
-    env = {**os.environ, "PYTHONPATH": "", "JAX_PLATFORMS": "cpu"}
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
 
     plan = None
     fault_env = env

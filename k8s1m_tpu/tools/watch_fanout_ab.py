@@ -491,7 +491,7 @@ class _ReplicaDrill:
         if resume_floor:
             cmd += ["--resume-floor", str(resume_floor)]
         self.proc = subprocess.Popen(
-            cmd, env={**os.environ, "PYTHONPATH": "", "JAX_PLATFORMS": "cpu"},
+            cmd, env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
         deadline = time.monotonic() + 180
         while True:

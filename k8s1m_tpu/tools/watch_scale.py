@@ -235,7 +235,7 @@ async def amain(args) -> dict:
         tier_flags += ["--lag-budget", str(args.lag_budget)]
     if args.pumps:
         tier_flags += ["--pumps", str(args.pumps)]
-    _env = {**os.environ, "PYTHONPATH": "", "JAX_PLATFORMS": "cpu"}
+    _env = {**os.environ, "JAX_PLATFORMS": "cpu"}
 
     def _tier_cmd(port: int, extra=()) -> list:
         return [

@@ -154,8 +154,8 @@ def test_topk_by_argmax_matches_lax_top_k():
     Tie semantics caveat: the earlier-index-wins tie-break this test
     asserts is only verified on CPU (both forms here run on the CPU
     backend); on silicon the same equivalence — including index order
-    under ties — is covered by the on-chip parity suite
-    (tests/test_pallas_topk.py via the recovery-daemon batch).
+    under ties — is covered by phase B of chip_smoke.py (XLA scan vs
+    the fused kernel, bit for bit, on the chip).
     """
     import jax.numpy as jnp
     from jax import lax

@@ -451,7 +451,7 @@ def test_make_nodes_bulk_batched_puts():
 
 
 def _run_drill(extra, timeout):
-    env = {**os.environ, "PYTHONPATH": "", "JAX_PLATFORMS": "cpu"}
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     proc = subprocess.run(
         [sys.executable, "-m", "k8s1m_tpu.tools.megarow_drill", *extra],
         cwd=REPO, env=env, timeout=timeout,

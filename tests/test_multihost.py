@@ -21,35 +21,9 @@ import subprocess
 import sys
 
 import numpy as np
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHILD = os.path.join(REPO, "tests", "multihost_child.py")
-
-
-def _cpu_multiprocess_unsupported() -> str | None:
-    """Why 2-process jax.distributed cannot run HERE, or None.
-
-    Keyed on the actual condition, not a blanket skip: the child
-    processes ALWAYS run on the CPU backend (``cleaned_cpu_env`` pins
-    them there regardless of the parent's accelerators), and jax < 0.5
-    raises "Multiprocess computations aren't implemented on the CPU
-    backend" at the first collective.  A jax new enough to route CPU
-    collectives through gloo runs the test for real.
-    """
-    import jax
-
-    try:
-        major, minor = (int(x) for x in jax.__version__.split(".")[:2])
-    except ValueError:
-        return None                      # unparseable: let the test run
-    if (major, minor) >= (0, 5):
-        return None
-    return (
-        f"jax {jax.__version__}: multiprocess computations not "
-        f"implemented on the CPU backend the children are pinned to "
-        f"(needs jax>=0.5)"
-    )
 
 
 def _free_port() -> int:
@@ -98,9 +72,6 @@ def _reference_digest():
 
 
 def test_two_process_distributed_step_matches_single_process():
-    reason = _cpu_multiprocess_unsupported()
-    if reason is not None:
-        pytest.skip(reason)
     from k8s1m_tpu.envboot import cleaned_cpu_env
 
     ref_digest, ref_bound = _reference_digest()
@@ -108,8 +79,10 @@ def test_two_process_distributed_step_matches_single_process():
 
     coord = f"127.0.0.1:{_free_port()}"
     env = cleaned_cpu_env(os.environ, 4)   # 4 local devices per process
-    env["PYTHONPATH"] = REPO + (
-        ":" + env["PYTHONPATH"] if env["PYTHONPATH"] else ""
+    # The child is a script under tests/: the package root is not on
+    # its sys.path unless PYTHONPATH carries it.
+    env["PYTHONPATH"] = os.pathsep.join(
+        [REPO, *filter(None, [env.get("PYTHONPATH")])]
     )
     procs = [
         subprocess.Popen(

@@ -494,27 +494,3 @@ def test_sched_bench_backend_auto_packed_smoke(tmp_path):
     assert kp["ms_per_batch"]["full"] > 0
     assert kp["stages"]["filter_topk_floor"] > 0
     assert json.loads(out.read_text())["detail"]["device_state"]["layout"] == "packed"
-
-
-def test_bench_cpu_lane_packed_smoke():
-    """bench.py --packing packed on the CPU lane: same metric name as
-    the committed baseline (layout-invariant comparisons), packed-layout
-    bytes evidence, donation honored."""
-    import subprocess
-    import sys
-
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--cpu-lane", "--nodes", "4096",
-         "--batch", "256", "--steps", "2", "--warmup", "1",
-         "--packing", "packed"],
-        capture_output=True, text=True, timeout=600,
-        cwd=__import__("os").path.dirname(__import__("os").path.dirname(
-            __import__("os").path.abspath(__file__)
-        )),
-    )
-    assert proc.returncode == 0, proc.stderr
-    rep = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rep["layout"] == "packed"
-    assert rep["cold_bytes_reduction"] >= 2.0
-    assert rep["donation_inplace"] is True
-    assert rep["value"] > 0

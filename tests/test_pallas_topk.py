@@ -1,9 +1,11 @@
 """Fused Pallas kernel vs the XLA path and a pure-numpy oracle.
 
 Runs the kernel in interpreter mode on the CPU mesh (the wrapper
-auto-selects); the identical code path compiles on TPU, where bench.py
-exercises it.  The hash jitter makes interpret and compiled runs
-bit-identical, so these assertions carry over to hardware.
+auto-selects).  The interpreter is exact where Mosaic need not be (an
+f32 dot contracts in bf16 on the chip unless asked otherwise), so these
+assertions do NOT carry over to hardware by themselves: phase B of
+chip_smoke.py repeats the pallas-vs-XLA comparison compiled, at 1M
+rows, on the chip.
 """
 
 import jax

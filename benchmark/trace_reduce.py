@@ -1,0 +1,201 @@
+"""From a profiler trace to numbers: one reduction, patterns as data.
+
+``load`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote into plain
+tuples; everything below it works on those tuples alone, so the tests
+drive it with a handful of synthetic intervals.
+
+An event is ``(plane, line, name, start_s, dur_s)``.
+"""
+
+from __future__ import annotations
+
+import glob
+import os
+import re
+
+DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+
+
+def _xspace_class():
+    """The few fields of the profiler's XSpace that the reduction reads,
+    as a protobuf message of its own (jax's ``ProfileData`` takes minutes
+    over the millions of events a while loop leaves; this takes seconds).
+    Field numbers are tsl/profiler/protobuf/xplane.proto's."""
+    from google.protobuf import descriptor_pb2, descriptor_pool, message_factory
+
+    T = descriptor_pb2.FieldDescriptorProto
+    fd = descriptor_pb2.FileDescriptorProto(
+        name="benchmark_xplane.proto", package="benchmark_xplane", syntax="proto3"
+    )
+
+    def msg(name, *fields):
+        m = fd.message_type.add(name=name)
+        for fname, num, ftype, rep, tname in fields:
+            m.field.add(
+                name=fname, number=num, type=ftype, type_name=tname,
+                label=T.LABEL_REPEATED if rep else T.LABEL_OPTIONAL,
+            )
+
+    P = ".benchmark_xplane."
+    msg("XEvent", ("metadata_id", 1, T.TYPE_INT64, 0, None),
+        ("offset_ps", 2, T.TYPE_INT64, 0, None),
+        ("duration_ps", 3, T.TYPE_INT64, 0, None))
+    msg("XLine", ("name", 2, T.TYPE_STRING, 0, None),
+        ("timestamp_ns", 3, T.TYPE_INT64, 0, None),
+        ("events", 4, T.TYPE_MESSAGE, 1, P + "XEvent"))
+    msg("XEventMetadata", ("id", 1, T.TYPE_INT64, 0, None),
+        ("name", 2, T.TYPE_STRING, 0, None))
+    msg("MetadataEntry", ("key", 1, T.TYPE_INT64, 0, None),
+        ("value", 2, T.TYPE_MESSAGE, 0, P + "XEventMetadata"))
+    msg("XPlane", ("name", 2, T.TYPE_STRING, 0, None),
+        ("lines", 3, T.TYPE_MESSAGE, 1, P + "XLine"),
+        ("event_metadata", 4, T.TYPE_MESSAGE, 1, P + "MetadataEntry"))
+    msg("XSpace", ("planes", 1, T.TYPE_MESSAGE, 1, P + "XPlane"))
+    pool = descriptor_pool.DescriptorPool()
+    pool.Add(fd)
+    return message_factory.GetMessageClass(
+        pool.FindMessageTypeByName("benchmark_xplane.XSpace")
+    )
+
+
+def trace_file(trace_dir: str) -> str:
+    paths = sorted(glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+    ))
+    if not paths:
+        raise RuntimeError(f"no .xplane.pb under {trace_dir}")
+    return paths[-1]
+
+
+def load(trace_dir: str) -> list[tuple[str, str, str, float, float]]:
+    with open(trace_file(trace_dir), "rb") as f:
+        space = _xspace_class().FromString(f.read())
+    events = []
+    for plane in space.planes:
+        names = {e.key: e.value.name for e in plane.event_metadata}
+        pname = plane.name
+        for line in plane.lines:
+            lname, base = line.name, line.timestamp_ns * 1e-9
+            for ev in line.events:
+                events.append((
+                    pname, lname, names.get(ev.metadata_id, ""),
+                    base + ev.offset_ps * 1e-12, ev.duration_ps * 1e-12,
+                ))
+    return events
+
+
+def short_name(name: str) -> str:
+    """An HLO op's name without its text: ``%while.7``; a Mosaic kernel
+    keeps its mark (``%_call.1 tpu_custom_call``)."""
+    head = name.split(" = ", 1)[0]
+    if 'custom_call_target="tpu_custom_call"' in name:
+        head += " tpu_custom_call"
+    return head[:120]
+
+
+def device_planes(events) -> list[str]:
+    return sorted({e[0] for e in events if DEVICE_PLANE.match(e[0])})
+
+
+def select(events, plane: str, line: str, pattern: str | None = None):
+    rx = re.compile(pattern) if pattern else None
+    return [
+        e for e in events
+        if e[0] == plane and e[1] == line and (rx is None or rx.search(e[2]))
+    ]
+
+
+def union_seconds(intervals) -> float:
+    """Total length covered by ``(start, dur)`` intervals."""
+    total, end = 0.0, None
+    for s, d in sorted(intervals):
+        e = s + d
+        if end is None or s > end:
+            total += d
+            end = e
+        elif e > end:
+            total += e - end
+            end = e
+    return total
+
+
+def busy_window(events, t0: float, t1: float) -> dict:
+    """Seconds in which an operation ran on the device inside
+    ``[t0, t1]``, averaged over the device planes, and the window's
+    length.  Idle share = 1 - busy_s / window_s."""
+    planes = device_planes(events)
+    busy = []
+    for p in planes:
+        clipped = []
+        for _p, _l, _n, s, d in select(events, p, OPS_LINE):
+            lo, hi = max(s, t0), min(s + d, t1)
+            if hi > lo:
+                clipped.append((lo, hi - lo))
+        busy.append(union_seconds(clipped))
+    if not busy:
+        raise RuntimeError("trace holds no device plane")
+    return {"busy_s": sum(busy) / len(busy), "window_s": t1 - t0,
+            "planes": len(planes)}
+
+
+def per_event(events, plane: str, line: str, pattern: str) -> tuple[float, int]:
+    """Summed duration and count of the events on ``line`` that match."""
+    sel = select(events, plane, line, pattern)
+    return sum(e[4] for e in sel), len(sel)
+
+
+def sums_by_name(events, plane: str, line: str, top: int = 10):
+    sums: dict[str, float] = {}
+    for _p, _l, name, _s, d in select(events, plane, line):
+        sums[name] = sums.get(name, 0.0) + d
+    return sorted(sums.items(), key=lambda kv: -kv[1])[:top]
+
+
+def idle_gaps(events, plane: str, host_spans, t0: float, t1: float,
+              top: int = 10):
+    """Summed idle time of the device by what the host was doing at the
+    middle of each gap: ``host_spans`` are ``(name, start_s, dur_s)`` of
+    the benchmark's own annotations; a gap that no span covers is
+    ``unattributed``."""
+    ops = sorted(
+        (max(s, t0), min(s + d, t1))
+        for _p, _l, _n, s, d in select(events, plane, OPS_LINE)
+        if s + d > t0 and s < t1
+    )
+    gaps, end = [], t0
+    for s, e in ops:
+        if s > end:
+            gaps.append((end, s))
+        end = max(end, e)
+    if t1 > end:
+        gaps.append((end, t1))
+    spans = sorted((s, s + d, n) for n, s, d in host_spans)
+    out: dict[str, float] = {}
+    for s, e in gaps:
+        mid = (s + e) / 2
+        name = "unattributed"
+        for hs, he, hn in spans:
+            if hs <= mid < he:
+                name = hn
+                break
+        out[name] = out.get(name, 0.0) + (e - s)
+    return sorted(out.items(), key=lambda kv: -kv[1])[:top]
+
+
+def overview(events, top: int = 25) -> str:
+    """What a trace holds — planes, lines, busiest names — for the first
+    look by hand."""
+    lines: dict[tuple[str, str], dict[str, list]] = {}
+    for p, l, n, _s, d in events:
+        rec = lines.setdefault((p, l), {}).setdefault(n, [0, 0.0])
+        rec[0] += 1
+        rec[1] += d
+    out = []
+    for (p, l), names in sorted(lines.items()):
+        out.append(f"{p} | {l}: {sum(r[0] for r in names.values())} events, "
+                   f"{len(names)} names")
+        for n, (c, d) in sorted(names.items(), key=lambda kv: -kv[1][1])[:top]:
+            out.append(f"    {d:10.6f}s {c:8d}x {n[:160]}")
+    return "\n".join(out)

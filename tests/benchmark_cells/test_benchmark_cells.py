@@ -1,0 +1,541 @@
+"""The benchmark's own tests (CPU, no chip, no TPU library).
+
+The manifest and every data file cross-reference; the window arithmetic,
+the plain reference, the trace reduction, the roofline bytes and the
+templates do what PERF.md says; the harness runs end to end at a tiny
+size, and with the timed path broken underneath ``correct`` comes out
+false — once for each control and once for each fault a cell can have.
+"""
+
+from __future__ import annotations
+
+import copy
+import json
+import os
+import random
+import re
+import shutil
+import subprocess
+import sys
+import types
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import generate, readers, reference, roofline, run, trace_reduce
+from benchmark.faults import FAULTS
+
+MANIFEST = run.read_json("BENCHMARK.json")
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
+PER_LAYER = [m["name"] for m in MANIFEST["per_layer"]]
+E2E = {m["name"]: m for m in MANIFEST["end_to_end"]}
+
+
+# ---- the manifest and its files ---------------------------------------------
+
+
+def test_manifest_shape():
+    assert set(MANIFEST) == {
+        "command", "paths", "run_seconds", "configs", "workloads",
+        "end_to_end", "per_layer",
+    }
+    assert "setup_s" in E2E and E2E["setup_s"]["bound"] <= 0.25
+    assert 1 <= MANIFEST["run_seconds"] <= 51
+    names = [m["name"] for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]]
+    assert len(names) == len(set(names))
+    for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert m["better"] in ("lower", "higher")
+    for m in MANIFEST["end_to_end"]:
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    chips4 = sum(w["chips"] == 4 for w in MANIFEST["workloads"])
+    assert chips4 <= max(1, len(CELLS) // 2)
+
+
+def test_every_file_under_paths_is_named_plainly():
+    ok = re.compile(r"^[A-Za-z0-9_.\-/]+$")
+    for path in MANIFEST["paths"]:
+        for d, dirs, files in os.walk(os.path.join(ROOT, path)):
+            dirs[:] = [x for x in dirs if x != "__pycache__"]
+            for f in files:
+                assert ok.match(os.path.relpath(os.path.join(d, f), ROOT))
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_files_cross_reference(cell):
+    workload, config, pods = run.load_cell(MANIFEST, cell)
+    entry = next(w for w in MANIFEST["workloads"] if w["name"] == cell)
+    cfg = next(c for c in MANIFEST["configs"] if c["name"] == entry["config"])
+    assert NAME.match(cell) and NAME.match(entry["traffic"])
+    assert cell == f"{entry['config']}.{entry['traffic']}"
+    assert workload["config"] == entry["config"] == config["name"]
+    assert cfg["file"].startswith("benchmark/configs/")
+    assert config["source"] == cfg["source"] and len(cfg["source"]) <= 200
+    assert sorted(config["reduced"]) == sorted(cfg["reduced"])
+    assert config["guarantees"] and "coordinator" in config
+    assert workload["arrival"] == "backlog"
+    assert workload["wave"] == config["pod_spec"]["batch"]
+    assert config["nodes"]["cordon_every"] > 1 and config["assumed"]
+    assert pods["shapes"] and pods["source"] and len(entry["why"]) <= 200
+    # every cell reports set-up, another end-to-end metric, a per-layer one
+    e2e = [m["name"] for m in run.metrics_of(MANIFEST, "end_to_end", cell)]
+    assert "setup_s" in e2e and len(e2e) >= 2
+    assert run.metrics_of(MANIFEST, "per_layer", cell)
+
+
+@pytest.mark.parametrize("metric", PER_LAYER)
+def test_per_layer_metric_files(metric):
+    m = next(x for x in MANIFEST["per_layer"] if x["name"] == metric)
+    spec = run.read_json("benchmark", "metrics", f"{metric}.json")
+    assert spec["reader"] in readers.READERS and spec["what"]
+    assert m["moves"] in E2E and m["layer"]
+    assert m["source"] in ("device_trace", "program_span", "program_counter",
+                           "host_clock")
+    cells = [c for c in CELLS
+             if m in run.metrics_of(MANIFEST, "per_layer", c)]
+    assert cells and set(m.get("workloads", cells)) == set(cells)
+    for cell in cells:
+        moved = [x["name"] for x in run.metrics_of(MANIFEST, "end_to_end", cell)]
+        assert m["moves"] in moved
+    if metric.endswith("_roofline") or "_roofline." in metric:
+        assert m["unit"] == "%"
+
+
+def test_peaks_table_names_its_source():
+    peaks = run.read_json("benchmark", "peaks.json")
+    v5e = peaks["TPU v5 lite"]
+    assert v5e["hbm_bytes_per_s"] == 819e9 and v5e["source"]
+    assert "cpu" not in peaks
+
+
+# ---- window arithmetic and the plain reference ----------------------------
+
+
+KEY = b"/registry/pods/b1/bench-pod-"
+
+
+def _put(i, node=None, parsed=True):
+    """One put event: (type, key, flags, aux).  A value the store parsed
+    natively comes with its node name alone; any other comes whole."""
+    name = None if node is None else b"n-%d" % node
+    if parsed:
+        return (0, KEY + b"%d" % i, 1 | (2 if name else 0), name or b"")
+    val = b'{"spec":{"schedulerName":"x"' + (
+        b',"nodeName":"%s"' % name if name else b"") + b"}}"
+    return (0, KEY + b"%d" % i, 0, val)
+
+
+def _delete(i):
+    return (1, KEY + b"%d" % i, 0, b"")
+
+
+def _batch(events):
+    """The store's columnar pod events, as ``Watcher.poll_pods`` gives them."""
+    off = lambda parts: np.cumsum([0] + [len(x) for x in parts]).astype(np.uint32)
+    keys, aux = [e[1] for e in events], [e[3] for e in events]
+    return types.SimpleNamespace(
+        n=len(events), etype=np.array([e[0] for e in events], np.uint8),
+        flags=np.array([e[2] for e in events], np.uint8),
+        koff=off(keys), aoff=off(aux),
+        key_blob=b"".join(keys), aux_blob=b"".join(aux),
+    )
+
+
+def _ledger(batches):
+    led = reference.Ledger(len(KEY))
+    for t, events in batches:
+        led.add(t, _batch(events))
+    return led.arrays({b"n-%d" % i: i for i in range(4)})
+
+
+def _replay(seen, offered, pods_per_node=2, cordoned=()):
+    n = 4
+    shut = np.zeros(n, bool)
+    shut[list(cordoned)] = True
+    return reference.replay(
+        seen, offered=offered, pod_cpu=np.full(offered, 10),
+        pod_mem=np.full(offered, 1024), alloc_cpu=np.full(n, 1000),
+        alloc_mem=np.full(n, 1 << 20), alloc_pods=np.full(n, pods_per_node),
+        cordoned=shut,
+    )
+
+
+def test_binds_are_counted_by_the_time_the_client_saw_them():
+    seen = _ledger([
+        (0.5, [_put(0), _put(1), _put(0, node=0)]),     # before the window
+        (1.5, [_put(1, node=1), _put(2), _put(3)]),
+        (2.5, [_put(2, node=2)]),
+        (3.5, [_put(3, node=3)]),                       # after it closed
+    ])
+    n, rate = reference.window_rate(seen["bind_t"], 1.0, 3.0)
+    assert (n, rate) == (2, 1.0)
+    assert seen["bind_pod"].tolist() == [0, 1, 2, 3]
+
+
+def test_a_stall_lowers_the_rate():
+    steady = np.arange(0.0, 10.0, 0.1)
+    stalled = np.r_[steady[:50], steady[50:] + 5.05]
+    _n, r0 = reference.window_rate(steady, 0.0, 10.0)
+    n1, r1 = reference.window_rate(stalled, 0.0, 10.0)
+    assert n1 == 50 and r1 == pytest.approx(r0 / 2)
+
+
+@pytest.mark.parametrize("parsed", [True, False])
+def test_replay_a_sound_history_reads_nought(parsed):
+    seen = _ledger([(1.0, [_put(0, parsed=parsed), _put(1, parsed=parsed),
+                           _put(0, 0, parsed), _put(1, 0, parsed)]),
+                    (2.0, [_put(2, parsed=parsed), _put(2, 1, parsed)])])
+    out = _replay(seen, 3)
+    assert set(out["numbers"].values()) == {0} and set(out["numbers"]) == {
+        "never_bound", "bound_twice", "unknown_node", "bound_to_cordoned",
+        "overcommitted_nodes", "deleted"}
+    assert out["final_pods"].tolist() == [2, 1, 0, 0]
+    assert out["final_cpu"].tolist() == [20, 10, 0, 0]
+    assert out["node_of_pod"].tolist() == [0, 0, 1]
+
+
+def test_replay_an_unbound_pod_is_a_failure():
+    seen = _ledger([(1.0, [_put(0), _put(1), _put(0, node=0)])])
+    assert _replay(seen, 2)["numbers"]["never_bound"] == 1
+
+
+def test_replay_sees_a_second_bind_and_an_unknown_node():
+    seen = _ledger([(1.0, [_put(0, node=0), _put(0, node=1), _put(1, node=9)])])
+    numbers = _replay(seen, 2)["numbers"]
+    assert numbers["bound_twice"] == 1 and numbers["unknown_node"] == 1
+    assert numbers["never_bound"] == 0
+
+
+@pytest.mark.parametrize("resource,pods_per_node,cpu,over", [
+    ("pods", 2, 10, 1), ("pods", 3, 10, 0), ("cpu", 3, 400, 1)])
+def test_replay_a_node_over_its_allocatable_is_counted(resource, pods_per_node,
+                                                       cpu, over):
+    seen = _ledger([(1.0, [_put(i, node=0) for i in range(3)] + [_put(3, node=1)])])
+    out = reference.replay(
+        seen, offered=4, pod_cpu=np.full(4, cpu), pod_mem=np.full(4, 1024),
+        alloc_cpu=np.full(4, 1000), alloc_mem=np.full(4, 1 << 20),
+        alloc_pods=np.full(4, pods_per_node), cordoned=np.zeros(4, bool))
+    assert out["numbers"]["overcommitted_nodes"] == over
+
+
+def test_replay_a_bind_to_a_cordoned_node_is_counted():
+    seen = _ledger([(1.0, [_put(0, node=0), _put(1, node=3), _put(2, node=3)])])
+    assert _replay(seen, 3)["numbers"]["bound_to_cordoned"] == 0
+    assert _replay(seen, 3, cordoned=[3])["numbers"]["bound_to_cordoned"] == 2
+
+
+def test_replay_counts_a_delete_nobody_asked_for():
+    seen = _ledger([(1.0, [_put(0, node=0), _delete(0)])])
+    assert _replay(seen, 1)["numbers"]["deleted"] == 1
+
+
+def test_rows_wrong_compares_every_row():
+    final = {"final_cpu": np.array([20, 0, 10]), "final_mem": np.array([2, 0, 1]),
+             "final_pods": np.array([2, 0, 1])}
+    row_of = np.array([3, 0, 1])
+    cpu, mem, pods = np.zeros(4), np.zeros(4), np.zeros(4)
+    cpu[[3, 1]], mem[[3, 1]], pods[[3, 1]] = [20, 10], [2, 1], [2, 1]
+    assert reference.rows_wrong(final, row_of, cpu, mem, pods) == 0
+    pods[2] = 1     # a row that holds no node has to read nought
+    cpu[3] = 10
+    assert reference.rows_wrong(final, row_of, cpu, mem, pods) == 2
+
+
+# ---- traffic generation --------------------------------------------------------
+
+
+def test_shape_pattern_same_set_another_order():
+    params = {"shapes": [{"weight": 3, "cpu_milli": 10, "mem_kib": 1},
+                         {"weight": 1, "cpu_milli": 500, "mem_kib": 9}]}
+    a, b = generate.shape_pattern(params, 1), generate.shape_pattern(params, 2)
+    key = lambda s: (s["cpu_milli"], s["mem_kib"])
+    assert sorted(a, key=key) == sorted(b, key=key) and len(a) == 4
+    assert generate.shape_pattern(params, 1) == a
+
+
+def test_templates_equal_the_programs_encoders():
+    rng = random.Random(0)
+    for cell in CELLS:
+        _w, config, pods = run.load_cell(MANIFEST, cell)
+        generate.Nodes({**config["nodes"], "count": 4096}).verify(rng)
+        generate.Pods(pods, seed=(1 << 31) + 7).verify(rng)
+    p = generate.Pods(pods, seed=5)
+    key, val = p.wave(41, 1)[0]
+    assert key == p.key(41) == b"/registry/pods/b5/bench-pod-41"
+    assert json.loads(val)["metadata"] == {
+        "name": "bench-pod-41", "namespace": "b5", "labels": {"app": "bench-pod"}}
+
+
+def test_the_pod_is_the_one_make_pods_makes():
+    from k8s1m_tpu.control.objects import decode_pod
+    from k8s1m_tpu.tools.make_pods import build_pod
+
+    p = generate.Pods(run.read_json("benchmark", "pods", "uniform.json"), seed=5)
+    got = decode_pod(p.wave(7, 1)[0][1])
+    want = build_pod(7, namespace="b5")
+    assert (got.name, got.cpu_milli, got.mem_kib, got.labels) == (
+        want.name, want.cpu_milli, want.mem_kib, want.labels)
+    assert [t.key for t in got.tolerations] == ["kwok.x-k8s.io/node"]
+
+
+def test_the_frame_is_what_the_store_packs():
+    from k8s1m_tpu.store.native import pack_put_frame
+
+    p = generate.Pods(run.read_json("benchmark", "pods", "uniform.json"), seed=9)
+    assert p.frame(95, 12) == pack_put_frame(p.wave(95, 12))
+
+
+def test_one_node_in_every_n_is_cordoned():
+    from k8s1m_tpu.control.objects import decode_node
+
+    _w, config, _p = run.load_cell(MANIFEST, CELLS[0])
+    every = config["nodes"]["cordon_every"]
+    n = generate.Nodes({**config["nodes"], "count": 4 * every})
+    shut = [i for i in range(n.count) if n.cordoned(i)]
+    assert shut == [every - 1, 2 * every - 1, 3 * every - 1, 4 * every - 1]
+    for i, (_key, val) in enumerate(n.items(0, n.count)):
+        assert decode_node(val).unschedulable == (i in shut)
+    assert not any(generate.Nodes(
+        {**config["nodes"], "count": 64, "cordon_every": 0}).cordoned(i)
+        for i in range(64))
+
+
+def test_a_cell_that_fills_to_the_brim_offers_whole_waves_that_fit():
+    workload, config, pods = _tiny("fit-10k.fill")
+    from k8s1m_tpu.store.native import MemStore
+
+    with MemStore() as store:
+        cell = run.Cell(store, config, workload, pods, seed=1)
+        n = cell.nodes
+        slots = sum(not n.cordoned(i) for i in range(n.count)) * n.pods
+        assert cell.most % cell.wave == 0
+        assert 0 <= slots - workload["brim_slack_pods"] - cell.most < cell.wave
+        workload.pop("brim_slack_pods")
+        free = run.Cell(store, config, workload, pods, seed=1)
+        assert free.most > 1 << 40
+
+
+# ---- trace reduction ------------------------------------------------------------
+
+DEV = "/device:TPU:0"
+EVENTS = [
+    (DEV, "XLA Modules", "jit__lambda(1)", 0.0, 1.0),
+    (DEV, "XLA Modules", "jit__lambda(1)", 2.0, 1.0),
+    (DEV, "XLA Modules", "jit_scatter_rows(2)", 3.0, 0.1),
+    (DEV, "XLA Ops", "%while.7 = (s32[]) while(...)", 0.1, 0.8),
+    (DEV, "XLA Ops", "%fusion.1 = fusion(...)", 0.2, 0.1),       # inside the while
+    (DEV, "XLA Ops", '%_call.1 = custom-call(...), custom_call_target="tpu_custom_call"', 0.0, 0.1),
+    (DEV, "XLA Ops", "%while.7 = (s32[]) while(...)", 2.1, 0.8),
+    (DEV, "XLA Ops", '%_call.1 = custom-call(...), custom_call_target="tpu_custom_call"', 2.0, 0.1),
+    ("/host:CPU", "python3", "bench.put", 0.0, 0.9),
+    ("/host:CPU", "python3", "bench.step", 0.9, 1.2),
+    ("/host:CPU", "python3", "bench.watch", 2.1, 0.9),
+]
+
+
+def test_union_counts_overlaps_once():
+    assert trace_reduce.union_seconds([(0, 1), (0.5, 1), (3, 1), (3.2, 0.1)]) \
+        == pytest.approx(2.5)
+    assert trace_reduce.union_seconds([]) == 0
+
+
+def test_busy_window_and_idle_share():
+    d = trace_reduce.busy_window(EVENTS, 0.0, 3.0)
+    assert d["busy_s"] == pytest.approx(1.8) and d["window_s"] == 3.0
+    clipped = trace_reduce.busy_window(EVENTS, 0.5, 2.5)
+    assert clipped["busy_s"] == pytest.approx(0.4 + 0.5)
+    with pytest.raises(RuntimeError):
+        trace_reduce.busy_window([e for e in EVENTS if e[0] != DEV], 0, 1)
+
+
+def test_per_name_sums_and_patterns():
+    total, count = trace_reduce.per_event(EVENTS, DEV, "XLA Ops", "tpu_custom_call")
+    assert (total, count) == (pytest.approx(0.2), 2)
+    assert trace_reduce.per_event(EVENTS, DEV, "XLA Modules", r"^jit__lambda\(") \
+        == (2.0, 2)
+    top = trace_reduce.sums_by_name(EVENTS, DEV, "XLA Ops", top=2)
+    assert trace_reduce.short_name(top[0][0]) == "%while.7"
+    assert trace_reduce.short_name(EVENTS[5][2]) == "%_call.1 tpu_custom_call"
+
+
+def test_idle_gaps_go_to_the_span_that_covers_them():
+    spans = [(n, s, d) for p, _l, n, s, d in EVENTS if p == "/host:CPU"]
+    gaps = dict(trace_reduce.idle_gaps(EVENTS, DEV, spans, 0.0, 3.0))
+    assert gaps["bench.step"] == pytest.approx(1.1)       # 0.9 .. 2.0
+    assert gaps["bench.watch"] == pytest.approx(0.1)      # 2.9 .. 3.0
+    # a gap goes whole to what covers its middle; past the last span, to nobody
+    late = dict(trace_reduce.idle_gaps(EVENTS, DEV, spans, 0.0, 3.2))
+    assert late["unattributed"] == pytest.approx(0.3) and "bench.watch" not in late
+
+
+def test_readers_on_a_synthetic_window():
+    ctx = {
+        "stage_s": {"drain": 1.0, "bind": 3.0, "sync_out": 5.0}, "binds": 1000,
+        "trace": {"events": EVENTS, "plane": DEV},
+        "shapes": {"scan_rows": 53248, "bytes_per_row": 42, "batch": 4096,
+                   "k": 4, "pod_bytes": 16},
+        "peaks": {"hbm_bytes_per_s": 819e9},
+    }
+    spec = lambda m: run.read_json("benchmark", "metrics", f"{m}.json")
+    value = lambda m: readers.READERS[spec(m)["reader"]](spec(m)["args"], ctx)
+    assert value("host_us_per_bind.fill") == pytest.approx(4000.0)
+    assert value("store_bind_us_per_bind.fill") == pytest.approx(3000.0)
+    assert value("engine_step_ms.fill") == pytest.approx(1000.0)
+    assert value("assign_loop_ms.fill") == pytest.approx(800.0)
+    assert value("fused_topk_ms.fill") == pytest.approx(100.0)
+    moved = 53248 * 42 + 4096 * 16 + 4096 * 4 * 8
+    assert value("fused_topk_roofline.fill") == pytest.approx(
+        100 * moved / 819e9 / 0.1)
+    # nothing to read: no value, never a nought
+    assert readers.READERS["trace_roofline_pct"](
+        spec("fused_topk_roofline.fill")["args"], {**ctx, "trace": None}) is None
+    assert readers.READERS["registry_stage_per_bind"](
+        {"stages": ["bind"]}, {**ctx, "binds": 0}) is None
+
+
+def test_xplane_loader_reads_what_jax_reads(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("bench.put"):
+        jnp.ones((64, 64)).sum().block_until_ready()
+    jax.profiler.stop_trace()
+    got = trace_reduce.load(str(tmp_path))
+    want = [
+        (p.name, l.name, e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
+        for p in ProfileData.from_file(trace_reduce.trace_file(str(tmp_path))).planes
+        for l in p.lines for e in l.events
+    ]
+    assert len(got) == len(want) and any(e[2] == "bench.put" for e in got)
+    for a, b in zip(got, want):
+        assert a[:3] == b[:3] and a[3] == pytest.approx(b[3], abs=1e-6)
+        assert a[4] == pytest.approx(b[4], abs=1e-9)
+    assert "bench.put" in trace_reduce.overview(got)
+
+
+# ---- roofline ------------------------------------------------------------------
+
+
+def test_roofline_bytes_for_both_deployments():
+    assert roofline.window_rows(1 << 20, 5, 4096) == 53248
+    assert roofline.window_rows(16384, 100, 4096) == 16384
+    cols = {"cpu_alloc": (4, 1), "mem_alloc": (4, 1), "cpu_req": (4, 1),
+            "mem_req": (4, 1), "pods_req": (4, 1), "pods_alloc": (2, 1),
+            "meta": (4, 1), "taint_id": (2, 8), "label_key": (4, 16)}
+    per_row = roofline.row_bytes(cols)
+    assert per_row == 42
+    kwok = roofline.wave_bytes(scan_rows=53248, bytes_per_row=per_row,
+                               batch=4096, k=4, pod_bytes=16)
+    fit = roofline.wave_bytes(scan_rows=16384, bytes_per_row=per_row,
+                              batch=4096, k=4, pod_bytes=16)
+    assert kwok == 53248 * 42 + 4096 * 16 + 4096 * 32 == 2433024
+    assert fit == 16384 * 42 + 4096 * 48 == 884736
+    assert roofline.hbm_share_pct(819e9 // 100, 0.01, 819e9) == pytest.approx(100.0)
+
+
+# ---- the harness, end to end at a tiny size ---------------------------------
+
+CPU_DEVICE = {"platform": "cpu", "kind": "cpu", "count": 1}
+
+
+def _tiny(cell):
+    """The cell at 1,000 nodes and waves of 128, XLA scan; where it fills
+    to the brim, nodes of 8 pod slots, so that half a second gets there."""
+    workload, config, pods = run.load_cell(MANIFEST, cell)
+    config = copy.deepcopy(config)
+    config["nodes"].update(count=1000, cordon_every=16)
+    config["table_spec"]["max_nodes"] = 1024
+    config["pod_spec"]["batch"] = 128
+    config["coordinator"].update(chunk=128, backend="xla")
+    workload = dict(workload, wave=128)
+    if "brim_slack_pods" in workload:
+        config["nodes"]["pods"] = 8
+        workload["brim_slack_pods"] = 64
+    return workload, config, pods
+
+
+def _rehearse(cell, fault=None, seconds=0.5):
+    return run.run_cell(
+        MANIFEST, cell, _tiny(cell), seed=(1 << 31) + 11, seconds=seconds,
+        trace=False, device=dict(CPU_DEVICE), peaks={}, fault=fault,
+    )
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_rehearsal_prints_a_well_formed_line(cell, capsys):
+    result = _rehearse(cell)
+    line = json.loads(json.dumps(result))
+    assert list(line)[:5] == ["correct", "attempted", "failed", "metrics", "device"]
+    assert list(line)[-1] == "compared"
+    assert line["correct"] is True and line["failed"] == 0 < line["attempted"]
+    assert set(line["metrics"]) == {
+        m["name"] for m in run.metrics_of(MANIFEST, "end_to_end", cell)}
+    for m in line["metrics"].values():
+        assert m["value"] > 0 and UNIT.match(m["unit"])
+    assert all(c["value"] == c["limit"] == 0 for c in line["compared"].values())
+    err = capsys.readouterr().err.strip().splitlines()
+    assert err[-1] == "correct=True failed_pods=0"
+    assert err[-2].startswith("compared ") and " limit=0" in err[-2]
+
+
+# the control first, then each fault a one-chip cell can have
+@pytest.mark.parametrize("fault,cell,caught_by", [
+    ("lazy_bind", "kwok-1m-pct5.fill", "never_bound"),
+    ("lazy_bind", "fit-10k.fill", "never_bound"),
+    ("filter_off", "kwok-1m-pct5.fill", "bound_to_cordoned"),
+    ("filter_off", "fit-10k.fill", "bound_to_cordoned"),
+    ("capacity_off", "fit-10k.fill", "overcommitted_nodes"),
+    ("half_batch", "kwok-1m-pct5.fill", "never_bound"),
+    ("answer_altered", "fit-10k.fill", "device_rows_wrong"),
+    ("state_unchanged", "kwok-1m-pct5.fill", "device_rows_wrong"),
+])
+def test_a_broken_timed_path_is_not_correct(fault, cell, caught_by):
+    assert fault in FAULTS
+    line = _rehearse(cell, fault=fault)
+    assert line["correct"] is False
+    assert line["compared"][caught_by]["value"] > line["compared"][caught_by]["limit"]
+
+
+def test_state_unchanged_is_undone():
+    import k8s1m_tpu.control.coordinator as mod
+    from k8s1m_tpu.engine.cycle import schedule_batch_packed
+
+    assert mod.schedule_batch_packed is schedule_batch_packed
+
+
+def _cli(cwd):
+    env = {k: v for k, v in os.environ.items() if not k.startswith("BENCH_")}
+    env["JAX_PLATFORMS"] = "cpu"
+    return subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload", CELLS[0],
+         "--seed", "3000000000", "--seconds", "1", "--trace", "0"],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=300,
+    )
+
+
+def test_the_command_refuses_a_machine_without_a_chip():
+    out = _cli(ROOT)
+    assert out.returncode == 2
+    assert "refusing to run" in out.stderr
+    assert not [l for l in out.stdout.splitlines() if l.startswith("{")]
+
+
+def test_the_command_fails_where_the_program_is_missing(tmp_path):
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    for path in MANIFEST["paths"]:
+        shutil.copytree(
+            os.path.join(ROOT, path), tmp_path / path,
+            ignore=shutil.ignore_patterns("__pycache__"),
+        )
+    out = _cli(tmp_path)
+    assert out.returncode != 0
+    assert not [l for l in out.stdout.splitlines() if l.startswith("{")]

@@ -1,0 +1,334 @@
+"""Readers of what the program itself names: ``jax.named_scope`` phases of
+the device step, ``coord.*`` host spans, lane counters, set-up stages.
+
+They sit beside ``readers.py`` and are not registered there yet: the harness
+(``run.py``) has to hand them three things it does not collect today, and it
+may be edited only by a ``benchmark`` PR (PERF.md section 7 lists the five
+edits).  Until then ``tools/span_report.py`` drives them around an unedited
+``run.py``.
+
+What the readers want in ``ctx`` beyond what ``readers.py`` documents:
+  trace["op_names"]  {event name: op_name} of the device plane (``load_names``)
+  counters           {"open": snap, "close": snap}, each ``snapshot_counters(...)``
+  setup_stage_s      {stage: seconds} of coordinator_cycle_seconds before the
+                     window's ``reset()``
+"""
+
+from __future__ import annotations
+
+import bisect
+
+from benchmark import trace_reduce
+
+SCOPES = ("candidates", "assign", "commit")
+HOST_SPANS = ("bench.", "coord.", "feed.")
+HOST_PLANE = "/host:CPU"
+# The stat of a device op's XEventMetadata that holds its op_name, the
+# "jit(f)/scope/.../primitive:" path jax gives every HLO instruction.
+OP_NAME_STAT = "tf_op"
+
+
+# ---- the loader beside trace_reduce.load ---------------------------------
+
+
+def _xspace_class():
+    """What ``trace_reduce``'s own message class leaves out and
+    ``load_names`` reads: the event metadata's stats with the stats'
+    names, and each line's id (two threads' lines can share a name).
+    Field numbers are tsl/profiler/protobuf/xplane.proto's."""
+    from google.protobuf import descriptor_pb2, descriptor_pool, message_factory
+
+    T = descriptor_pb2.FieldDescriptorProto
+    fd = descriptor_pb2.FileDescriptorProto(
+        name="benchmark_xplane_stats.proto", package="benchmark_xplane_stats",
+        syntax="proto3",
+    )
+
+    def msg(name, *fields):
+        m = fd.message_type.add(name=name)
+        for fname, num, ftype, rep, tname in fields:
+            m.field.add(
+                name=fname, number=num, type=ftype, type_name=tname,
+                label=T.LABEL_REPEATED if rep else T.LABEL_OPTIONAL,
+            )
+
+    P = ".benchmark_xplane_stats."
+    msg("XEvent", ("metadata_id", 1, T.TYPE_INT64, 0, None),
+        ("offset_ps", 2, T.TYPE_INT64, 0, None),
+        ("duration_ps", 3, T.TYPE_INT64, 0, None))
+    msg("XLine", ("id", 1, T.TYPE_INT64, 0, None),
+        ("timestamp_ns", 3, T.TYPE_INT64, 0, None),
+        ("events", 4, T.TYPE_MESSAGE, 1, P + "XEvent"))
+    msg("XStat", ("metadata_id", 1, T.TYPE_INT64, 0, None),
+        ("str_value", 5, T.TYPE_STRING, 0, None),
+        ("ref_value", 7, T.TYPE_UINT64, 0, None))
+    msg("XEventMetadata", ("id", 1, T.TYPE_INT64, 0, None),
+        ("name", 2, T.TYPE_STRING, 0, None),
+        ("stats", 5, T.TYPE_MESSAGE, 1, P + "XStat"))
+    msg("EventEntry", ("key", 1, T.TYPE_INT64, 0, None),
+        ("value", 2, T.TYPE_MESSAGE, 0, P + "XEventMetadata"))
+    msg("XStatMetadata", ("id", 1, T.TYPE_INT64, 0, None),
+        ("name", 2, T.TYPE_STRING, 0, None))
+    msg("StatEntry", ("key", 1, T.TYPE_INT64, 0, None),
+        ("value", 2, T.TYPE_MESSAGE, 0, P + "XStatMetadata"))
+    msg("XPlane", ("name", 2, T.TYPE_STRING, 0, None),
+        ("lines", 3, T.TYPE_MESSAGE, 1, P + "XLine"),
+        ("event_metadata", 4, T.TYPE_MESSAGE, 1, P + "EventEntry"),
+        ("stat_metadata", 5, T.TYPE_MESSAGE, 1, P + "StatEntry"))
+    msg("XSpace", ("planes", 1, T.TYPE_MESSAGE, 1, P + "XPlane"))
+    pool = descriptor_pool.DescriptorPool()
+    pool.Add(fd)
+    return message_factory.GetMessageClass(
+        pool.FindMessageTypeByName("benchmark_xplane_stats.XSpace")
+    )
+
+
+def load_names(trace_dir: str) -> dict:
+    """Beside ``trace_reduce.load``: ``op_names``, ``{device plane: {event
+    name: op_name}}`` for the events whose metadata carries one, and
+    ``host_spans``, ``(line id, name, start_s, dur_s)`` of the host's
+    ``bench.`` / ``coord.`` / ``feed.`` annotations.  XLA keeps the
+    op_name out of the event's name (that is the HLO instruction's text)
+    and in the ``tf_op`` stat of its XEventMetadata."""
+    with open(trace_reduce.trace_file(trace_dir), "rb") as f:
+        space = _xspace_class().FromString(f.read())
+    op_names: dict[str, dict[str, str]] = {}
+    host_spans = []
+    for p in space.planes:
+        if trace_reduce.DEVICE_PLANE.match(p.name):
+            stat_name = {e.key: e.value.name for e in p.stat_metadata}
+            names = op_names.setdefault(p.name, {})
+            for entry in p.event_metadata:
+                for st in entry.value.stats:
+                    if stat_name.get(st.metadata_id) == OP_NAME_STAT:
+                        names[entry.value.name] = (
+                            st.str_value or stat_name.get(st.ref_value, "")
+                        )
+        elif p.name == HOST_PLANE:
+            wanted = {e.key: e.value.name for e in p.event_metadata
+                      if e.value.name.startswith(HOST_SPANS)}
+            for line in p.lines:
+                base = line.timestamp_ns * 1e-9
+                host_spans += [
+                    (line.id, wanted[ev.metadata_id],
+                     base + ev.offset_ps * 1e-12, ev.duration_ps * 1e-12)
+                    for ev in line.events if ev.metadata_id in wanted
+                ]
+    return {"op_names": op_names, "host_spans": host_spans}
+
+
+# ---- reductions on plain tuples -------------------------------------------
+
+
+def scope_of(op_name: str, scopes=SCOPES) -> str | None:
+    """The first of ``scopes`` among the op_name path's components."""
+    parts = op_name.rstrip(":").split("/")
+    return next((sc for sc in scopes if sc in parts), None)
+
+
+def scoped_ops(events, plane: str, op_names: dict, scopes=SCOPES):
+    """``(start, dur, scope)`` of the device's ``XLA Ops`` events, scope
+    None where there is none.  An event with an op_name takes its scope
+    from it.  One without (XLA's TPU pipeline leaves a ``while`` none)
+    that contains other events takes the scope they all share: a loop's
+    own time belongs with its body's."""
+    ops = sorted(
+        ((s, d, scope_of(op_names[n], scopes) if n in op_names else None,
+          n in op_names)
+         for _p, _l, n, s, d in
+         trace_reduce.select(events, plane, trace_reduce.OPS_LINE)),
+        key=lambda o: (o[0], -o[1]),            # a container before its contents
+    )
+    starts = [o[0] for o in ops]
+    out = []
+    for i, (s, d, scope, named) in enumerate(ops):
+        if not named:
+            inside = ops[i + 1:bisect.bisect_left(starts, s + d, i + 1)]
+            shared = {o[2] for o in inside if o[3]}
+            if len(shared) == 1:
+                scope = shared.pop()
+        out.append((s, d, scope))
+    return out
+
+
+def device_scopes(events, plane: str, op_names: dict, t0: float, t1: float,
+                  scopes=SCOPES) -> list[list]:
+    """Seconds the device was busy inside ``[t0, t1]``, by named scope: the
+    union of each scope's intervals (a loop's event contains its body's, so
+    a sum would count them twice), largest first, and what no scope covers
+    as ``unscoped``.  The rows add up to ``busy_window``'s ``busy_s``."""
+    ops = scoped_ops(events, plane, op_names, scopes)
+
+    def busy(keep) -> float:
+        return trace_reduce.union_seconds(
+            (max(s, t0), min(s + d, t1) - max(s, t0))
+            for s, d, sc in ops if keep(sc) and min(s + d, t1) > max(s, t0)
+        )
+
+    rows = sorted(
+        ([sc, busy(lambda x, sc=sc: x == sc)] for sc in scopes),
+        key=lambda r: -r[1],
+    )
+    rows.append(["unscoped",
+                 busy(lambda x: True) - busy(lambda x: x is not None)])
+    return rows
+
+
+def innermost_segments(spans) -> list[tuple[float, float, str]]:
+    """Nested ``(start, end, name)`` spans of one thread, flattened to
+    ``(start, end, name)`` segments that do not overlap: every instant
+    goes to the innermost span that covers it."""
+    segs, stack, at = [], [], 0.0
+
+    def close_until(t):
+        nonlocal at
+        while stack and stack[-1][0] <= t:
+            end, name = stack.pop()
+            if end > at:
+                segs.append((at, end, name))
+                at = end
+
+    for start, end, name in sorted(spans, key=lambda sp: (sp[0], -sp[1])):
+        close_until(start)
+        if stack and start > at:
+            segs.append((at, start, stack[-1][1]))
+        stack.append((end, name))
+        at = start
+    close_until(float("inf"))
+    return segs
+
+
+def idle_by_span(events, plane: str, host_spans, t0: float, t1: float,
+                 root: str = "bench.step", top: int = 12):
+    """The device's idle seconds inside ``[t0, t1]`` by what the host loop
+    was in meanwhile: each gap between ``XLA Ops`` is split over the
+    **innermost** spans that overlap it, second for second (the pipeline
+    leaves one long gap a wave, and a rule that gives a whole gap to one
+    span names only the longest stage).  ``host_spans`` are ``load_names``'
+    ``(line id, name, start_s, dur_s)``; only those on the line that holds
+    ``root`` count (the loop's own thread: another thread's span, the
+    hotfeed worker's say, overlaps the wave and explains no wait, and the
+    two lines may share a name); idle time no span covers is
+    ``unattributed``."""
+    lines = {lid for lid, n, _s, _d in host_spans if n == root}
+    segs = innermost_segments(
+        (s, s + d, n) for lid, n, s, d in host_spans if lid in lines
+    )
+    ends = [seg[1] for seg in segs]
+    ops = sorted(
+        (max(s, t0), min(s + d, t1))
+        for _p, _l, _n, s, d in
+        trace_reduce.select(events, plane, trace_reduce.OPS_LINE)
+        if s + d > t0 and s < t1
+    )
+    out: dict[str, float] = {}
+    at = t0
+    for s, e in [*ops, (t1, t1)]:
+        if s > at:
+            covered = 0.0
+            for a, b, name in segs[bisect.bisect_right(ends, at):]:
+                if a >= s:
+                    break
+                part = min(b, s) - max(a, at)
+                out[name] = out.get(name, 0.0) + part
+                covered += part
+            if s - at > covered:
+                out["unattributed"] = out.get("unattributed", 0.0) \
+                    + (s - at - covered)
+        at = max(at, e)
+    return sorted(out.items(), key=lambda kv: -kv[1])[:top]
+
+
+def snapshot_counters(names) -> dict:
+    """``{counter: {labels: value}}`` of the program's registry now, labels
+    as a sorted tuple of ``(name, value)``; a counter the program does not
+    have is left out."""
+    from k8s1m_tpu.obs.metrics import REGISTRY
+
+    snap = {}
+    for name in names:
+        c = REGISTRY.get(name)
+        if c is None:
+            continue
+        snap[name] = {
+            tuple(sorted(zip(c.labelnames, key))):
+                c.value(**dict(zip(c.labelnames, key)))
+            for key in c.label_keys()
+        }
+    return snap
+
+
+def stage_sums() -> dict[str, float]:
+    """Every ``stage`` label ``coordinator_cycle_seconds`` holds now."""
+    from k8s1m_tpu.obs.metrics import REGISTRY
+
+    cyc = REGISTRY.get("coordinator_cycle_seconds")
+    return {k[0]: cyc.sum(stage=k[0]) for k in cyc.label_keys()}
+
+
+# ---- readers --------------------------------------------------------------
+
+
+def trace_scope_ms_per_wave(args: dict, ctx: dict):
+    """Device milliseconds under the named scope per wave: the union of the
+    ``XLA Ops`` intervals whose op_name path holds ``args.scope``, over the
+    count of ``args.wave_pattern`` events on ``args.wave_line``."""
+    tr = ctx.get("trace")
+    if tr is None or not tr.get("op_names"):
+        return None
+    covered = [
+        (s, d) for s, d, scope in
+        scoped_ops(tr["events"], tr["plane"], tr["op_names"])
+        if scope == args["scope"]
+    ]
+    _t, waves = trace_reduce.per_event(
+        tr["events"], tr["plane"], args["wave_line"], args["wave_pattern"]
+    )
+    if not covered or not waves:
+        return None
+    return 1e3 * trace_reduce.union_seconds(covered) / waves
+
+
+def counter_share_pct(args: dict, ctx: dict):
+    """The increase of ``args.counter``'s ``args.labels`` label sets over the
+    increase of its ``args.of`` label sets (all of the counter's when
+    ``of`` is missing), in %: over the window (``over: "window"``, open to
+    close) or over set-up (``over: "setup"``, process start to open)."""
+    snaps = ctx.get("counters")
+    if not snaps or args["counter"] not in snaps["open"]:
+        return None
+    at_open = snaps["open"][args["counter"]]
+    at_close = snaps["close"].get(args["counter"], {})
+
+    def grown(label_sets) -> float:
+        total = 0.0
+        for key in label_sets:
+            if args["over"] == "setup":
+                total += at_open.get(key, 0.0)
+            else:
+                total += at_close.get(key, 0.0) - at_open.get(key, 0.0)
+        return total
+
+    as_key = lambda labels: tuple(sorted(labels.items()))
+    of = [as_key(l) for l in args["of"]] if "of" in args \
+        else set(at_open) | set(at_close)
+    whole = grown(of)
+    if not whole:
+        return None
+    return 100.0 * grown([as_key(l) for l in args["labels"]]) / whole
+
+
+def setup_stage_s(args: dict, ctx: dict):
+    """Seconds of the named coordinator stages observed before the window
+    opened (set-up)."""
+    before = ctx.get("setup_stage_s")
+    if not before or not any(s in before for s in args["stages"]):
+        return None
+    return sum(before.get(s, 0.0) for s in args["stages"])
+
+
+READERS = {
+    "trace_scope_ms_per_wave": trace_scope_ms_per_wave,
+    "counter_share_pct": counter_share_pct,
+    "setup_stage_s": setup_stage_s,
+}

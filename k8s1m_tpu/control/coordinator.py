@@ -81,7 +81,6 @@ import dataclasses
 import heapq
 import json
 import logging
-import os
 import random
 import threading
 import time
@@ -98,6 +97,7 @@ from k8s1m_tpu.lint import THREAD_OWNER, guarded_by, racy_read
 from k8s1m_tpu.control.objects import (
     decode_node,
     decode_pod,
+    decode_pod_fast,
     decode_pod_obj,
     node_key,
     pod_key,
@@ -125,7 +125,6 @@ from k8s1m_tpu.loadshed import CLOSED as BREAKER_CLOSED
 from k8s1m_tpu.loadshed.breaker import FALLBACK_BINDS
 from k8s1m_tpu.obs.metrics import Counter, Gauge, Histogram, LevelTimer
 from k8s1m_tpu.obs.podtrace import NULL_TRACER
-from k8s1m_tpu.obs.trace import FlightRecorder
 from k8s1m_tpu.ops.priority import pod_priority_of
 from k8s1m_tpu.oracle import oracle_feasible, oracle_score
 from k8s1m_tpu.plugins.registry import Profile, degraded_profile
@@ -203,6 +202,16 @@ _PODS_SCHEDULED = Counter(
 )
 _DECODE_ERRORS = Counter(
     "coordinator_decode_errors_total", "Objects that failed to decode", ("kind",)
+)
+# Counted where the intake forks (_apply_pod_batch, _on_pod_put), one
+# inc per lane per batch: batch_fast = a whole poll of canonical pending
+# pods taken column-wise; canonical = per event, parsed natively;
+# decode_fast / json = a non-canonical put through decode_pod_fast or
+# json.loads + decode_pod_obj; echo = a non-canonical bind echo known by
+# its key, not decoded; delete.
+_POD_INTAKE = Counter(
+    "coordinator_pod_intake_total",
+    "Pod watch/list events applied, by intake lane", ("lane",),
 )
 _CYCLE_TIME = Histogram(
     "coordinator_cycle_seconds", "Scheduling cycle latency by stage", ("stage",)
@@ -566,11 +575,6 @@ class Coordinator:
         retry_policy: RetryPolicy | None = None,
         scheduler_name: str = DEFAULT_SCHEDULER,
         seed: int = 0,
-        flight_recorder: FlightRecorder | None = None,
-        # Sampling profiler (obs/profiler.py) to dump alongside a slow-
-        # cycle flight dump — the reference's always-answerable "where
-        # did the time go" (parca-agent.tf, scheduler_metrics.go:68-74).
-        profiler=None,
         # Per-pod lifecycle tracing (obs/podtrace.py): a PodTracer
         # head-samples 1-in-N pods (deterministic by pod-key hash) and
         # records their whole journey as a contiguous span chain —
@@ -579,9 +583,7 @@ class Coordinator:
         # bind CAS incl. retries, preemption/eviction, failover
         # requeue.  None (the default) installs the null tracer: every
         # emit site is behind a single ``enabled`` read, so tracing off
-        # is free (enforced by the trace-lazy-emit lint pass).  A pod
-        # whose schedule-to-bind exceeds the flight recorder's
-        # threshold dumps the ring WITH its span chain attached.
+        # is free (enforced by the trace-lazy-emit lint pass).
         tracer=None,
         backend: str = "xla",
         pipeline: bool = False,
@@ -674,8 +676,6 @@ class Coordinator:
         )
         self.max_attempts = max_attempts
         self.scheduler_name = scheduler_name
-        self.flight = flight_recorder
-        self.profiler = profiler
         self._tracer = tracer if tracer is not None else NULL_TRACER
         # Pods that spent their retry budget THIS wave (populated only
         # while tracing): the wave-retire pass closes their chains
@@ -684,7 +684,6 @@ class Coordinator:
         # terminal requeue span (the give-up sites run mid-bind-loop,
         # before the retire pass, and cannot stamp those spans).
         self._trace_gaveup: set[str] = set()
-        self._profile_dumps = 0
         self.backend = backend
         from k8s1m_tpu.ops.priority import JITTER_BITS
 
@@ -1053,6 +1052,9 @@ class Coordinator:
         # Seconds of nested out-of-band work to subtract from the
         # enclosing _stage observation (see _stage).
         self._stage_excluded = 0.0
+        # _on_pod_put's lanes since the last _flush_lanes (plain adds
+        # per pod; the Counter is touched once a batch).
+        self._put_lanes = dict.fromkeys(("echo", "decode_fast", "json"), 0)
         self._nodes_watch: Watcher | None = None
         self._pods_watch: Watcher | None = None
         # True when the store's bind_batch can suppress our own watch
@@ -1133,9 +1135,11 @@ class Coordinator:
         into ``megarow_cold_build_seconds``.
         """
         t_cold = time.perf_counter()
-        with _CYCLE_TIME.time(stage="bootstrap"):
-            values, rev = self._relist_nodes()
-            self._bulk.ingest(values)
+        with self._stage("bootstrap"):
+            with self._stage("relist", "bootstrap"):
+                values, rev = self._relist_nodes()
+            with self._stage("ingest", "bootstrap"):
+                self._bulk.ingest(values)
             del values
             self._nodes_watch = self.store.watch(
                 NODES_PREFIX, prefix_end(NODES_PREFIX),
@@ -1144,12 +1148,14 @@ class Coordinator:
             pod_kvs, pod_rev = list_prefix(self.store, PODS_PREFIX)
             for kv in pod_kvs:
                 self._on_pod_put(kv.value, kv.mod_revision)
+            self._flush_lanes()
             self._pods_watch = self.store.watch(
                 PODS_PREFIX, prefix_end(PODS_PREFIX),
                 start_revision=pod_rev + 1, queue_cap=self.watch_queue_cap,
             )
             self._bind_excludes = isinstance(self._pods_watch, Watcher)
-            self.table = self._table_to_device()
+            with self._stage("to_device", "bootstrap"):
+                self.table = self._table_to_device()
         _COLD_BUILD.set(time.perf_counter() - t_cold)
 
     # ---- watch delta application --------------------------------------
@@ -1216,9 +1222,16 @@ class Coordinator:
             pod_key_str = key[len(PODS_PREFIX):].decode()
             if pod_key_str in self._bound:
                 self._queued_keys.discard(pod_key_str)
+                self._put_lanes["echo"] += 1
                 return
         try:
-            pod = decode_pod(data, self.tracker)
+            # decode_pod's two lanes, forked here so each is counted.
+            pod = decode_pod_fast(data, self.tracker)
+            if pod is not None:
+                self._put_lanes["decode_fast"] += 1
+            else:
+                self._put_lanes["json"] += 1
+                pod = decode_pod_obj(json.loads(data), self.tracker)
         except Exception:
             # One malformed object must not poison the event stream — the
             # rest of the polled batch would be lost and the snapshot
@@ -1267,6 +1280,17 @@ class Coordinator:
             ),
             pod,
         )
+
+    def _flush_lanes(self, **lanes: int) -> None:
+        """Count one batch's intake into coordinator_pod_intake_total:
+        the caller's own lanes plus whatever _on_pod_put has taken since
+        the last flush."""
+        put = self._put_lanes
+        for lane, n in (*lanes.items(), *put.items()):
+            if n:
+                _POD_INTAKE.inc(n, lane=lane)
+        for lane in put:
+            put[lane] = 0
 
     def _on_pod_delete(self, key: bytes) -> None:
         pod_key_str = key[len(PODS_PREFIX):].decode()
@@ -1359,23 +1383,37 @@ class Coordinator:
         return True
 
     @contextlib.contextmanager
-    def _stage(self, stage: str):
-        """Stage timer that also feeds the overlap split: host-stage
+    def _stage(self, stage: str, parent: str | None = None):
+        """The one place a stage is timed: ``coordinator_cycle_seconds
+        {stage}`` and a ``coord.<stage>`` span on the profiler's clock
+        (jax.profiler.TraceAnnotation — a TraceMe check when no profiler
+        session runs) for the same interval, so a stage cannot be timed
+        without being a span.  A child (``parent`` given) is the label
+        ``<parent>_<stage>`` and the span ``coord.<parent>.<stage>``,
+        once a wave, never once a pod.
+
+        The _OVERLAP_STAGES also feed the overlap split: host-stage
         seconds labeled by whether device waves were in flight when the
         stage ran (inflight=yes time is hidden behind device work).
         Out-of-band work that runs nested inside a stage (the exhaustion
         quiesce's flush mid-drain) adds its duration to _stage_excluded
         so the same seconds are not counted into two stages; the inflight
         label is latched at entry (a rare-path approximation)."""
+        if parent is None:
+            label, span = stage, "coord." + stage
+        else:
+            label, span = f"{parent}_{stage}", f"coord.{parent}.{stage}"
         inflight = "yes" if self._inflights else "no"
-        t0 = time.perf_counter()
-        excl0 = self._stage_excluded
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0 - (self._stage_excluded - excl0)
-            _CYCLE_TIME.observe(dt, stage=stage)
-            _PIPE_OVERLAP.inc(dt, stage=stage, inflight=inflight)
+        with jax.profiler.TraceAnnotation(span):
+            t0 = time.perf_counter()
+            excl0 = self._stage_excluded
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0 - (self._stage_excluded - excl0)
+                _CYCLE_TIME.observe(dt, stage=label)
+                if label in _OVERLAP_STAGES:
+                    _PIPE_OVERLAP.inc(dt, stage=label, inflight=inflight)
 
     def _upsert_node(self, node) -> int:
         """host.upsert with the one structural quiesce left: allocation
@@ -1465,15 +1503,17 @@ class Coordinator:
             batch = min(max_events, 10000)
             with self._stage("drain"):
                 while True:
-                    evb = self._pods_watch.poll_pods(
-                        batch, self._sched_bytes
-                    )
+                    with self._stage("poll", "drain"):
+                        evb = self._pods_watch.poll_pods(
+                            batch, self._sched_bytes
+                        )
                     if evb.n:
-                        self._apply_pod_batch(evb)
+                        with self._stage("apply", "drain"):
+                            self._apply_pod_batch(evb)
                         n += evb.n
                     if evb.n < batch or n >= 20 * max_events:
                         return n
-        n = 0
+        n = deletes = 0
         with self._stage("drain"):
             for etype, key, value, mrev in drain_events_light(
                 self._pods_watch, max_events
@@ -1482,7 +1522,9 @@ class Coordinator:
                 if etype == 0:
                     self._on_pod_put(value, mrev, key)
                 else:
+                    deletes += 1
                     self._on_pod_delete(key)
+            self._flush_lanes(delete=deletes)
         return n
 
     def _apply_pod_batch(self, evb) -> None:
@@ -1530,6 +1572,7 @@ class Coordinator:
                 ))
                 if tr_on:
                     tracer.begin(ks, now, source="intake")
+            self._flush_lanes(batch_fast=evb.n)
             return
         aoff = evb.aoff.tolist()
         ab = evb.aux_blob
@@ -1538,13 +1581,16 @@ class Coordinator:
         mrev_l = evb.mrev.tolist()
         flags_l = flags.tolist()
         etype_l = etype.tolist()
+        deletes = slow = 0
         for i in range(evb.n):
             key = kb[koff[i] : koff[i + 1]]
             if etype_l[i] == 1:
+                deletes += 1
                 self._on_pod_delete(key)
                 continue
             f = flags_l[i]
             if not f & POD_CANONICAL:
+                slow += 1
                 self._on_pod_put(ab[aoff[i] : aoff[i + 1]], mrev_l[i], key)
                 # decode_pod may have interned a new constraint whose
                 # empty selector matches later canonical pods in this
@@ -1605,6 +1651,9 @@ class Coordinator:
             ))
             if tr_on:
                 tracer.begin(ks, now, source="intake")
+        self._flush_lanes(
+            delete=deletes, canonical=evb.n - deletes - slow
+        )
 
     def _node_name_bytes(self) -> list:
         """Encoded node names, index-parallel with vocab.node_names
@@ -1665,7 +1714,7 @@ class Coordinator:
             # The relist rebuilds the row->node mapping wholesale; no
             # row set bounds what a cached plane may now mis-describe.
             self._delta.drop_all("resync")
-        with _CYCLE_TIME.time(stage="resync"):
+        with self._stage("resync"):
             self._nodes_watch.cancel()
             self._pods_watch.cancel()
 
@@ -1694,6 +1743,7 @@ class Coordinator:
             for kv in pod_kvs:
                 seen.add(kv.key[len(PODS_PREFIX):].decode())
                 self._on_pod_put(kv.value, kv.mod_revision)
+            self._flush_lanes()
             for key in list(self._bound):
                 if key not in seen:
                     ns, name = key.split("/", 1)
@@ -3133,7 +3183,7 @@ class Coordinator:
         idx_flag = None
         idx_attempted = False
         idx_touched = (0, 0)
-        with _CYCLE_TIME.time(stage="device"):
+        with self._stage("device"):
             if delta_plan is not None:
                 (
                     self.table, asg, rows_dev,
@@ -3367,7 +3417,7 @@ class Coordinator:
         )
         nbound = 0
         bound_ok = np.zeros(len(take), bool)
-        with _CYCLE_TIME.time(stage="fallback"):
+        with self._stage("fallback"):
             for pi, p in enumerate(take):
                 pod = p.ensure_pod()
                 best_row, best_score, best_name = -1, -1, None
@@ -3552,10 +3602,11 @@ class Coordinator:
                 failed[i] = True
                 self._wave_fail(p)
             if entries:
-                results = self._fenced_bind_batch(
-                    entries,
-                    self._pods_watch.id if self._bind_excludes else None,
-                )
+                with self._stage("cas", "bind"):
+                    results = self._fenced_bind_batch(
+                        entries,
+                        self._pods_watch.id if self._bind_excludes else None,
+                    )
                 now = time.perf_counter()
                 ok_rows: list[int] = []
                 ok_cpu: list[int] = []
@@ -3662,8 +3713,7 @@ class Coordinator:
         if self._tracer.enabled:
             self._trace_retire(inflight, rows, bound_ok, t_sync)
 
-        cycle_s = time.perf_counter() - t_start
-        self._last_cycle_s = cycle_s
+        self._last_cycle_s = time.perf_counter() - t_start
         # This wave retired: rows removed at or before the oldest
         # still-in-flight wave's launch are past their aliasing hazard.
         if self._inflights:
@@ -3681,65 +3731,26 @@ class Coordinator:
             # promptly: while the breaker is not CLOSED, step() quiesces
             # the pipeline, which completes the probe right here.
             self.breaker.record_success()
-        if self.flight is not None:
-            self.flight.record(
-                "cycle",
-                cycle_s,
-                pods=len(batch_pods),
-                bound=nbound,
-                queue=len(self.queue),
-            )
-            if (
-                self.profiler is not None
-                and cycle_s > self.flight.threshold_s
-                # Same cap discipline as the flight recorder: sustained
-                # slow cycles must not fill the disk, and the dump cost
-                # itself lengthens cycles (self-amplifying otherwise).
-                and self._profile_dumps < self.flight.max_dumps
-            ):
-                self._profile_dumps += 1
-                # The flight dump says WHAT was slow; the profile dump
-                # says WHERE the window's time went.
-                self.profiler.dump(
-                    os.path.join(
-                        self.flight.dump_dir,
-                        # graftlint: disable=no-wall-clock (epoch-ms dump name, correlates with flight dumps)
-                        f"profile-slowcycle-{int(time.time() * 1e3)}"
-                        f"-{self._profile_dumps}.json",
-                    )
-                )
         return nbound
 
     def _trace_retire(self, inflight: Wave, rows, bound_ok, t_sync: float) -> None:
         """Wave-retire observability pass (runs only while tracing is
-        enabled — tracing off keeps the flight recorder's historical
-        slow-CYCLE behavior exactly): close every sampled pod's span
-        chain — the device span stamped with the wave's epoch, pipeline
-        depth and delta-vs-full pass, the bind span with the settled
-        outcome — and give any TRACED pod whose schedule-to-bind
-        exceeded the flight threshold the reference's per-slow-pod
-        flight dump with its span chain attached (scheduler.go:556-565).
-        Traced pods only, by design: the dump budget (max_dumps) is
-        shared with the slow-cycle dumps, so an untraced backlog —
-        where every pod's queue wait clears the per-op threshold — must
-        not be able to drain it 1-in-1."""
+        enabled): close every sampled pod's span chain — the device
+        span stamped with the wave's epoch, pipeline depth and
+        delta-vs-full pass, the bind span with the settled outcome."""
         tracer = self._tracer
         if not tracer.enabled:
             return
-        flight = self.flight
         now = time.perf_counter()
         for i, p in enumerate(inflight.batch_pods):
             ok = bool(bound_ok[i])
-            done = None
             tracer.emit(
                 p.key_str, "device", t=t_sync,
                 wave_epoch=inflight.epoch, depth=inflight.depth,
                 path=inflight.path,
             )
             if ok:
-                done = tracer.finish(
-                    p.key_str, "bind", t=now, outcome="bound"
-                )
+                tracer.finish(p.key_str, "bind", t=now, outcome="bound")
             else:
                 tracer.emit(
                     p.key_str, "bind", t=now,
@@ -3753,19 +3764,6 @@ class Coordinator:
                     tracer.finish(
                         p.key_str, "requeue",
                         outcome="unschedulable", attempts=p.attempts,
-                    )
-            if done is not None and flight is not None:
-                lat = now - p.enqueued_at
-                if lat > flight.threshold_s:
-                    flight.dump(
-                        reason=(
-                            f"pod {p.key_str} schedule-to-bind "
-                            f"{lat * 1e3:.1f}ms"
-                        ),
-                        extra={
-                            "pod": p.key_str,
-                            "pod_spans": done.doc()["spans"],
-                        },
                     )
 
     def step(self) -> int:
@@ -3782,7 +3780,14 @@ class Coordinator:
         mirror ahead of the dirty-row re-upload the next launch
         consumes.  Call ``flush()`` (or ``run_until_idle``) to retire
         the tail.
+
+        The whole step is the ``coord.step`` span, the root of the
+        ``coord.<stage>`` spans (_stage) in a profiler trace.
         """
+        with jax.profiler.TraceAnnotation("coord.step"):
+            return self._step()
+
+    def _step(self) -> int:
         if not self.pipeline:
             self._drain_external()
             self.drain_watches()

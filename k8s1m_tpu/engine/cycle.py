@@ -410,11 +410,16 @@ def finalize_batch(
     this shard's node-row tables, while zone/region count tables (replicated
     in the sharded cycle) take the full global update.
 
-    Returns (table, constraints, Assignment)."""
-    node_row, bound, score, chosen_k = greedy_assign(
-        cand.idx, cand.prio, cand.cpu, cand.mem, cand.pods,
-        fields.cpu, fields.mem, fields.valid,
-    )
+    Returns (table, constraints, Assignment).
+
+    The two phases carry ``jax.named_scope`` names (``assign``,
+    ``commit``; the callers put ``candidates`` on theirs), so a device
+    trace can be read by phase whatever the ops under them become."""
+    with jax.named_scope("assign"):
+        node_row, bound, score, chosen_k = greedy_assign(
+            cand.idx, cand.prio, cand.cpu, cand.mem, cand.pods,
+            fields.cpu, fields.mem, fields.valid,
+        )
     take1 = lambda x: jnp.take_along_axis(x, chosen_k[:, None], axis=1)[:, 0]
     asg = Assignment(
         node_row=node_row, bound=bound, score=score,
@@ -427,11 +432,12 @@ def finalize_batch(
     else:
         local = bound & (node_row >= row_offset) & (node_row < row_offset + rows)
         local_row = jnp.where(local, node_row - row_offset, 0)
-    table = commit_binds(table, local_row, fields.cpu, fields.mem, local)
-    if constraints is not None:
-        constraints = commit_constraints_for_batch(
-            constraints, fields, asg, local_row, local, bound
-        )
+    with jax.named_scope("commit"):
+        table = commit_binds(table, local_row, fields.cpu, fields.mem, local)
+        if constraints is not None:
+            constraints = commit_constraints_for_batch(
+                constraints, fields, asg, local_row, local, bound
+            )
     return table, constraints, asg
 
 
@@ -487,30 +493,31 @@ def _schedule_batch_impl(
     # binds always commit into ``table`` — the split that makes ownership
     # masks (mask_rows) work without touching commit state.
     src = table if src is None else src
-    stats = None
-    if constraints is not None:
-        # Domain statistics are GLOBAL by semantics (a spread
-        # constraint's min/max is over the whole cluster): build them
-        # from the commit table, not the candidate view — an ownership
-        # mask (mask_rows) must narrow candidate selection, never the
-        # skew baseline, or shards would disagree on feasibility.  The
-        # sampling path below applies the same rule.
-        stats = _prologue_stats(table, constraints)
-    if backend == "pallas":
-        from k8s1m_tpu.ops.pallas_topk import pallas_candidates
+    with jax.named_scope("candidates"):
+        stats = None
+        if constraints is not None:
+            # Domain statistics are GLOBAL by semantics (a spread
+            # constraint's min/max is over the whole cluster): build them
+            # from the commit table, not the candidate view — an ownership
+            # mask (mask_rows) must narrow candidate selection, never the
+            # skew baseline, or shards would disagree on feasibility.  The
+            # sampling path below applies the same rule.
+            stats = _prologue_stats(table, constraints)
+        if backend == "pallas":
+            from k8s1m_tpu.ops.pallas_topk import pallas_candidates
 
-        cand = pallas_candidates(
-            src, batch, key, profile, chunk=chunk, k=k,
-            with_affinity=with_affinity,
-            constraints=constraints, stats=stats,
-            stratum_bits=stratum_bits,
-        )
-    else:
-        cand = filter_score_topk(
-            src, batch, key, profile,
-            chunk=chunk, k=k, constraints=constraints, stats=stats,
-            stratum_bits=stratum_bits,
-        )
+            cand = pallas_candidates(
+                src, batch, key, profile, chunk=chunk, k=k,
+                with_affinity=with_affinity,
+                constraints=constraints, stats=stats,
+                stratum_bits=stratum_bits,
+            )
+        else:
+            cand = filter_score_topk(
+                src, batch, key, profile,
+                chunk=chunk, k=k, constraints=constraints, stats=stats,
+                stratum_bits=stratum_bits,
+            )
     return finalize_batch(table, constraints, cand, commit_fields_of(batch))
 
 
@@ -650,56 +657,59 @@ def _jitted_schedule_packed(
             # README.adoc:525-531); the bind commit still lands in the
             # full table.  Candidate rows are remapped from window-local
             # to global.
-            view = jax.tree.map(
-                lambda a: lax.dynamic_slice_in_dim(a, offset, sample_rows, 0),
-                src,
-            )
-            if backend == "pallas":
-                from k8s1m_tpu.ops.pallas_topk import pallas_candidates
-
-                p_stats = None
-                view_cons = None
-                if constraints is not None:
-                    # Same composition rule as the XLA branch below:
-                    # global domain statistics, window-local node cols.
-                    from k8s1m_tpu.snapshot.constraints import (
-                        slice_constraints,
-                    )
-
-                    p_stats = _prologue_stats(table, constraints)
-                    view_cons = slice_constraints(
-                        constraints, offset, sample_rows
-                    )
-                cand = pallas_candidates(
-                    view, batch, key, profile, chunk=chunk, k=k,
-                    with_affinity=aff,
-                    constraints=view_cons, stats=p_stats,
-                    stratum_bits=stratum_bits,
+            with jax.named_scope("candidates"):
+                view = jax.tree.map(
+                    lambda a: lax.dynamic_slice_in_dim(
+                        a, offset, sample_rows, 0
+                    ),
+                    src,
                 )
-            else:
-                stats = None
-                view_cons = None
-                if constraints is not None:
-                    # Constraint plugins under sampling: domain statistics
-                    # are GLOBAL reductions over the full count tables
-                    # (the prologue never depended on the scan window);
-                    # only the per-node count columns follow the window.
-                    from k8s1m_tpu.snapshot.constraints import (
-                        slice_constraints,
-                    )
+                if backend == "pallas":
+                    from k8s1m_tpu.ops.pallas_topk import pallas_candidates
 
-                    stats = _prologue_stats(table, constraints)
-                    view_cons = slice_constraints(
-                        constraints, offset, sample_rows
+                    p_stats = None
+                    view_cons = None
+                    if constraints is not None:
+                        # Same composition rule as the XLA branch below:
+                        # global domain statistics, window-local node cols.
+                        from k8s1m_tpu.snapshot.constraints import (
+                            slice_constraints,
+                        )
+
+                        p_stats = _prologue_stats(table, constraints)
+                        view_cons = slice_constraints(
+                            constraints, offset, sample_rows
+                        )
+                    cand = pallas_candidates(
+                        view, batch, key, profile, chunk=chunk, k=k,
+                        with_affinity=aff,
+                        constraints=view_cons, stats=p_stats,
+                        stratum_bits=stratum_bits,
                     )
-                cand = filter_score_topk(
-                    view, batch, key, profile, chunk=chunk, k=k,
-                    constraints=view_cons, stats=stats,
-                    stratum_bits=stratum_bits,
+                else:
+                    stats = None
+                    view_cons = None
+                    if constraints is not None:
+                        # Constraint plugins under sampling: domain statistics
+                        # are GLOBAL reductions over the full count tables
+                        # (the prologue never depended on the scan window);
+                        # only the per-node count columns follow the window.
+                        from k8s1m_tpu.snapshot.constraints import (
+                            slice_constraints,
+                        )
+
+                        stats = _prologue_stats(table, constraints)
+                        view_cons = slice_constraints(
+                            constraints, offset, sample_rows
+                        )
+                    cand = filter_score_topk(
+                        view, batch, key, profile, chunk=chunk, k=k,
+                        constraints=view_cons, stats=stats,
+                        stratum_bits=stratum_bits,
+                    )
+                cand = cand.replace(
+                    idx=jnp.where(cand.idx >= 0, cand.idx + offset, -1)
                 )
-            cand = cand.replace(
-                idx=jnp.where(cand.idx >= 0, cand.idx + offset, -1)
-            )
             table, cons, asg = finalize_batch(
                 table, constraints, cand, commit_fields_of(batch)
             )
@@ -893,71 +903,72 @@ def _jitted_schedule_delta(
             inflight = rest
         batch = unpack_pod_batch(ints, bools, pod_spec, table_spec, groups)
         n = pmask.shape[1]
-        rows = combine_dirty(dirty, inflight, n)
-        pmask, pscore, mask_d, score_d = merge_dirty_planes(
-            table, batch, profile, slot_ids, pmask, pscore, rows
-        )
-        seed = seed_of(key)
+        with jax.named_scope("candidates"):
+            rows = combine_dirty(dirty, inflight, n)
+            pmask, pscore, mask_d, score_d = merge_dirty_planes(
+                table, batch, profile, slot_ids, pmask, pscore, rows
+            )
+            seed = seed_of(key)
 
-        def plane_tail():
-            if backend == "pallas":
-                from k8s1m_tpu.ops.pallas_topk import delta_plane_topk
+            def plane_tail():
+                if backend == "pallas":
+                    from k8s1m_tpu.ops.pallas_topk import delta_plane_topk
 
-                return delta_plane_topk(
+                    return delta_plane_topk(
+                        pmask, pscore, slot_ids, seed, chunk=chunk, k=k,
+                        stratum_bits=stratum_bits,
+                    )
+                return plane_topk(
                     pmask, pscore, slot_ids, seed, chunk=chunk, k=k,
                     stratum_bits=stratum_bits,
                 )
-            return plane_topk(
-                pmask, pscore, slot_ids, seed, chunk=chunk, k=k,
-                stratum_bits=stratum_bits,
-            )
 
-        flag = jnp.int32(0)
-        if index_k and rows.shape[0] <= index_dirty_cap:
-            rows_dd = dedup_rows(rows, n)
-            idx_row, idx_class, idx_floor = update_index(
-                idx_row, idx_class, idx_floor, rep_idx, rows_dd,
-                mask_d, score_d, n, stratum_bits=stratum_bits,
-            )
-            usable = index_usable(idx_class, idx_floor, slot_ids, k)
-
-            def from_index(state):
-                ir, ic, fl = state
-                return (
-                    index_topk(
-                        ir, ic, slot_ids, seed, k=k,
-                        stratum_bits=stratum_bits,
-                    ),
-                    ir, ic, fl,
+            flag = jnp.int32(0)
+            if index_k and rows.shape[0] <= index_dirty_cap:
+                rows_dd = dedup_rows(rows, n)
+                idx_row, idx_class, idx_floor = update_index(
+                    idx_row, idx_class, idx_floor, rep_idx, rows_dd,
+                    mask_d, score_d, n, stratum_bits=stratum_bits,
                 )
+                usable = index_usable(idx_class, idx_floor, slot_ids, k)
 
-            def from_planes(state):
-                ir, ic, fl = state
-                ir, ic, fl = rebuild_index(
-                    pmask, pscore, rebuild_slots, rep_idx, ir, ic, fl,
+                def from_index(state):
+                    ir, ic, fl = state
+                    return (
+                        index_topk(
+                            ir, ic, slot_ids, seed, k=k,
+                            stratum_bits=stratum_bits,
+                        ),
+                        ir, ic, fl,
+                    )
+
+                def from_planes(state):
+                    ir, ic, fl = state
+                    ir, ic, fl = rebuild_index(
+                        pmask, pscore, rebuild_slots, rep_idx, ir, ic, fl,
+                        chunk=chunk, stratum_bits=stratum_bits,
+                        batch_b=slot_ids.shape[0],
+                    )
+                    return plane_tail(), ir, ic, fl
+
+                cand, idx_row, idx_class, idx_floor = lax.cond(
+                    usable, from_index, from_planes,
+                    (idx_row, idx_class, idx_floor),
+                )
+                flag = usable.astype(jnp.int32)
+            elif index_k:
+                # Oversized dirty slice: plane tail, and the used slots'
+                # indexes rebuild from the merged planes (or fail closed).
+                cand = plane_tail()
+                idx_row, idx_class, idx_floor = rebuild_index(
+                    pmask, pscore, rebuild_slots, rep_idx,
+                    idx_row, idx_class, idx_floor,
                     chunk=chunk, stratum_bits=stratum_bits,
                     batch_b=slot_ids.shape[0],
                 )
-                return plane_tail(), ir, ic, fl
-
-            cand, idx_row, idx_class, idx_floor = lax.cond(
-                usable, from_index, from_planes,
-                (idx_row, idx_class, idx_floor),
-            )
-            flag = usable.astype(jnp.int32)
-        elif index_k:
-            # Oversized dirty slice: plane tail, and the used slots'
-            # indexes rebuild from the merged planes (or fail closed).
-            cand = plane_tail()
-            idx_row, idx_class, idx_floor = rebuild_index(
-                pmask, pscore, rebuild_slots, rep_idx,
-                idx_row, idx_class, idx_floor,
-                chunk=chunk, stratum_bits=stratum_bits,
-                batch_b=slot_ids.shape[0],
-            )
-        else:
-            cand = plane_tail()
-        cand = attach_payload(table, cand)
+            else:
+                cand = plane_tail()
+            cand = attach_payload(table, cand)
         table, _cons, asg = finalize_batch(
             table, None, cand, commit_fields_of(batch)
         )

@@ -39,9 +39,8 @@ ROWS = [
     # and the host-stage overlap split (pipeline_* in control/coordinator).
     ("Scheduling cycle", ("pipeline_",)),
     # Per-pod lifecycle tracing (obs/podtrace.py): the schedule-to-bind
-    # latency decomposed by stage, trace-bus accounting, and the
-    # flight recorder's dump-budget outcomes (obs/trace.py).
-    ("Latency attribution", ("pod_stage_", "podtrace_", "flight_")),
+    # latency decomposed by stage and the trace-bus accounting.
+    ("Latency attribution", ("pod_stage_", "podtrace_")),
     # Cached + overlapped pod encoding (snapshot/hotfeed.py): encode
     # seconds by path, template-cache hit/miss, staged-batch use and the
     # stale-discard reasons.
@@ -56,9 +55,10 @@ ROWS = [
     ("Incremental scheduling (deltasched)", ("deltasched_",)),
     # The 1,048,576-row operating shape (ISSUE 14 megarow): cold-build
     # wall seconds (bootstrap relist -> bulk ingest -> device table),
-    # bulk-ingest row rate (snapshot/bulkload + bulk_upsert), and the
-    # host mirror's column-byte budget under the narrow-dtype rule.
-    ("Million-row (megarow)", ("megarow_",)),
+    # bulk-ingest row rate (snapshot/bulkload + bulk_upsert) with its
+    # template / per-node split, and the host mirror's column-byte
+    # budget under the narrow-dtype rule.
+    ("Million-row (megarow)", ("megarow_", "bulkload_")),
     # Packed device snapshot + buffer donation (snapshot/packing.py,
     # ISSUE 10 devicestate): table HBM bytes by layout, per-wave commit
     # donations split by whether the runtime honored them in place, and

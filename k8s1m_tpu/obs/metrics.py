@@ -70,6 +70,10 @@ class Counter(Metric):
     def value(self, **labels) -> float:
         return self._values.get(self._key(labels), 0.0)
 
+    def label_keys(self) -> list[tuple]:
+        with self._lock:
+            return list(self._values)
+
     def render(self) -> list[str]:
         out = [f"# HELP {self.name} {self.help}", f"# TYPE {self.name} counter"]
         with self._lock:

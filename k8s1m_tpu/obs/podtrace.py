@@ -100,18 +100,6 @@ class PodTrace:
     last_t: float = 0.0
     spans: list = dataclasses.field(default_factory=list)
 
-    def doc(self) -> dict:
-        """JSON-ready form (flight-recorder dumps, debugging)."""
-        return {
-            "pod": self.key,
-            "total_s": round(self.last_t - self.t0, 6),
-            **self.attrs,
-            "spans": [
-                {"stage": s, "dur_s": round(t1 - t0, 6), **a}
-                for s, t0, t1, a in self.spans
-            ],
-        }
-
 
 class PodTracer:
     """Lock-sharded, bounded, head-sampled per-pod trace bus.
@@ -207,8 +195,7 @@ class PodTracer:
     def finish(self, key: str, stage: str, t: float | None = None,
                **attrs) -> PodTrace | None:
         """Terminal ``emit``: close the chain and move the trace to the
-        completed ring.  Returns the completed trace (the flight
-        recorder attaches its span chain to slow-pod dumps)."""
+        completed ring.  Returns the completed trace."""
         if not self.emit(key, stage, t, **attrs):
             return None
         i = self._shard(key)
@@ -224,8 +211,8 @@ class PodTracer:
     # ---- reads ---------------------------------------------------------
 
     def spans_of(self, key: str) -> list[dict]:
-        """The live span chain for a pod (flight-recorder dumps); []
-        when the pod is not being traced."""
+        """The live span chain for a pod; [] when the pod is not being
+        traced."""
         i = self._shard(key)
         with self._locks[i]:
             tr = self._shards[i].get(key)

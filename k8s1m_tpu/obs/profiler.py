@@ -14,13 +14,10 @@ Three entry points:
 
 - ``SamplingProfiler`` — start/stop around a window (sched_bench
   --profile wires it); ``report()`` returns the aggregate, ``dump()``
-  writes the artifact next to the flight-recorder dumps.
+  writes the artifact.
 - ``install_signal_dump()`` — the py-spy-dump-on-demand equivalent:
   SIGUSR2 writes every thread's current stack to a file, for attaching
   to a live coordinator that stopped making progress.
-- Coordinator integration: the flight recorder's slow-cycle dump can
-  carry the profiler's report (coordinator.py wires ``profiler=``), so
-  a >threshold cycle leaves both the event ring AND where the time went.
 """
 
 from __future__ import annotations
@@ -150,7 +147,7 @@ class SamplingProfiler:
         }
 
     def dump(self, path: str | None = None, top: int = 25) -> str:
-        """Write the report next to the flight-recorder dumps."""
+        """Write the report as JSON; returns the path."""
         if path is None:
             # graftlint: disable=no-wall-clock (epoch-ms dump name, correlates across restarts)
             path = f"/tmp/profile-{int(time.time() * 1e3)}.json"

@@ -804,6 +804,9 @@ def _call(
     )
     idx, prio = pl.pallas_call(
         kernel,
+        # The kernel's name in HLO and in a device trace; the affinity
+        # variant is a different (and far dearer) program.
+        name="fused_topk_affinity" if with_aff else "fused_topk",
         grid=grid,
         in_specs=in_specs,
         out_specs=(out, out),
@@ -1153,6 +1156,7 @@ def _delta_call(
     )
     idx, prio = pl.pallas_call(
         kernel,
+        name="delta_plane_topk",
         grid=grid,
         in_specs=[
             pl.BlockSpec(

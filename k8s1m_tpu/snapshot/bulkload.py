@@ -47,6 +47,7 @@ from k8s1m_tpu.control.objects import (
     CANONICAL_NODE_RE,
     decode_node,
 )
+from k8s1m_tpu.obs.metrics import Counter
 from k8s1m_tpu.snapshot.interning import numeric_of
 from k8s1m_tpu.snapshot.node_table import (
     _BULK_ROWS,
@@ -61,6 +62,16 @@ from k8s1m_tpu.snapshot.node_table import (
 # intern-order proof simple), and the transient per-chunk Python lists
 # stay bounded at 1M+ rows.
 DEFAULT_CHUNK = 65536
+
+# Values, not chunks, so that the share is of nodes: how many took the
+# template lane and how many rode a chunk that one value dropped.
+_VALUES = Counter(
+    "bulkload_values_total",
+    "Encoded node values ingested, by path (template = the vectorized "
+    "canonical lane; per_node = the chunk fell back to decode_node + "
+    "bulk_upsert)",
+    ("path",),
+)
 
 
 class _Template:
@@ -223,6 +234,7 @@ class BulkNodeLoader:
                 # whole chunk takes the exact decode + bulk_upsert path
                 # (prefix interning above matches the loop's order, so
                 # re-interning below hits the same ids).
+                _VALUES.inc(len(values), path="per_node")
                 return host.bulk_upsert([decode_node(x) for x in values])
             name = m.group(1).decode()
             names.append(name)
@@ -273,6 +285,7 @@ class BulkNodeLoader:
         host.region[rows] = trid[tidx].astype(host.region.dtype)
         host.name_id[rows] = np.asarray(nid, np.int32)
         _BULK_ROWS.inc(b)
+        _VALUES.inc(b, path="template")
         return rows
 
 

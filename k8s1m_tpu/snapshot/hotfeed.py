@@ -66,6 +66,7 @@ import threading
 import time
 import weakref
 
+import jax
 import numpy as np
 
 from k8s1m_tpu.config import NONE_ID
@@ -913,7 +914,10 @@ class HostFeed:
                 # mutate=False: the peeked PendingPods are still owned
                 # by the cycle thread's queue; the worker must not
                 # assign p.pod (the one write ensure_pod would do).
-                packed = encode_batch(self.encoder, pods, mutate=False)
+                # feed.encode: what overlapped the wave, on the
+                # profiler's clock beside the cycle thread's coord.* spans.
+                with jax.profiler.TraceAnnotation("feed.encode"):
+                    packed = encode_batch(self.encoder, pods, mutate=False)
             # Broad on purpose (log.exception satisfies the lint): the
             # worker must stage None so the inline fallback reproduces
             # the error on the cycle thread, where it can propagate.

@@ -1,0 +1,304 @@
+"""The readers of what the program names (benchmark/span_readers.py), on
+synthetic events; the eleven metric specs; the accepted metrics unmoved;
+and tools/span_report.py around the unedited harness at a tiny size.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import readers, run, span_readers, trace_reduce
+from test_benchmark_cells import (
+    CELLS, CPU_DEVICE, DEV, E2E, EVENTS, MANIFEST, NAME, UNIT, _tiny,
+)
+
+SPECS = run.read_json("benchmark", "span_metrics.json")["metrics"]
+WAVES = {"wave_line": "XLA Modules", "wave_pattern": r"^jit__lambda\("}
+# the accepted fixture, with the second while's body in it too; and its
+# device ops by the scope XLA's tf_op stat would give them: the while has
+# none (as on the chip), its body has
+OPS = EVENTS + [(DEV, "XLA Ops", EVENTS[4][2], 2.2, 0.1)]
+OP_NAMES = {
+    EVENTS[4][2]: "jit(<lambda>)/assign/while/body/closed_call/reduce_or:",
+    EVENTS[5][2]: "jit(<lambda>)/candidates/jit(_call)/fused_topk/pallas_call:",
+}
+HOST = [  # (line id, name, start, dur): the loop's thread is line 1
+    (1, "bench.put", 0.0, 0.9), (1, "bench.step", 0.9, 1.2),
+    (1, "coord.step", 0.95, 1.1), (1, "coord.drain", 1.0, 0.6),
+    (1, "coord.drain.apply", 1.1, 0.4), (1, "coord.bind", 1.7, 0.2),
+    (2, "feed.encode", 1.0, 0.9), (1, "bench.watch", 2.1, 0.9),
+]
+
+
+def _ctx(**more):
+    return {"trace": {"events": OPS, "plane": DEV, "op_names": OP_NAMES},
+            **more}
+
+
+# ---- device scopes --------------------------------------------------------
+
+
+def test_scope_time_is_a_union_not_a_sum():
+    """The while (0.8 s, twice) contains its body's fusion (0.1 s): the
+    assign scope covers 1.6 s over two waves, not 1.7."""
+    ms = span_readers.trace_scope_ms_per_wave({"scope": "assign", **WAVES}, _ctx())
+    assert ms == pytest.approx(800.0)
+    assert span_readers.trace_scope_ms_per_wave(
+        {"scope": "candidates", **WAVES}, _ctx()) == pytest.approx(100.0)
+
+
+def test_a_loop_without_an_op_name_takes_its_bodys_scope():
+    whiles = lambda ev: [sc for s, d, sc in
+                         span_readers.scoped_ops(ev, DEV, OP_NAMES) if d == 0.8]
+    assert whiles(OPS) == ["assign", "assign"]
+    # the accepted fixture's second while holds nothing named
+    assert whiles(EVENTS) == ["assign", None]
+    # a container over two scopes takes neither; over one, that one
+    both = [(DEV, "XLA Ops", "%call.9", 0.0, 1.0), *OPS]
+    assert span_readers.scoped_ops(both, DEV, OP_NAMES)[0] == (0.0, 1.0, None)
+    one = {**OP_NAMES, EVENTS[5][2]: "jit(f)/assign/x:"}
+    assert span_readers.scoped_ops(both, DEV, one)[0] == (0.0, 1.0, "assign")
+
+
+def test_scope_is_a_component_of_the_path_not_a_substring():
+    assert span_readers.scope_of("jit(f)/assign/while/body/add:") == "assign"
+    assert span_readers.scope_of("jit(f)/commit:") == "commit"
+    assert span_readers.scope_of("jit(f)/reassign/add:") is None
+    assert span_readers.scope_of("jit(f)/jit(commit_binds)/add:") is None
+
+
+def test_device_scopes_add_up_to_busy_with_the_rest_unscoped():
+    ev = OPS + [(DEV, "XLA Ops", "%copy.3 = copy(...)", 1.0, 0.05)]
+    rows = span_readers.device_scopes(ev, DEV, OP_NAMES, 0.0, 3.0)
+    assert [r[0] for r in rows] == ["assign", "candidates", "commit", "unscoped"]
+    busy = trace_reduce.busy_window(ev, 0.0, 3.0)["busy_s"]
+    assert sum(r[1] for r in rows) == pytest.approx(busy) == pytest.approx(1.85)
+    assert dict(rows) == pytest.approx(
+        {"assign": 1.6, "candidates": 0.2, "commit": 0.0, "unscoped": 0.05})
+    # clipped to the window like busy_s
+    half = dict(span_readers.device_scopes(ev, DEV, OP_NAMES, 0.5, 2.5))
+    assert half["assign"] == pytest.approx(0.4 + 0.4)
+
+
+def test_no_op_names_no_value():
+    bare = {"trace": {"events": OPS, "plane": DEV}}
+    args = {"scope": "assign", **WAVES}
+    assert span_readers.trace_scope_ms_per_wave(args, bare) is None
+    assert span_readers.trace_scope_ms_per_wave(args, {"trace": None}) is None
+    assert span_readers.trace_scope_ms_per_wave(
+        {"scope": "commit", **WAVES}, _ctx()) is None
+
+
+# ---- idle time by host span -----------------------------------------------
+
+
+def test_the_innermost_span_wins():
+    segs = span_readers.innermost_segments(
+        [(0, 10, "a"), (1, 3, "b"), (2, 2.5, "c"), (5, 6, "d"), (12, 13, "e")])
+    assert segs == [(0, 1, "a"), (1, 2, "b"), (2, 2.5, "c"), (2.5, 3, "b"),
+                    (3, 5, "a"), (5, 6, "d"), (6, 10, "a"), (12, 13, "e")]
+
+
+def test_idle_time_is_split_over_the_spans_second_for_second():
+    """The device idles 0.9 .. 2.0 and 2.9 .. 3.0; the first gap is split
+    over what the loop's thread was in, innermost first."""
+    idle = dict(span_readers.idle_by_span(EVENTS, DEV, HOST, 0.0, 3.0))
+    assert idle == pytest.approx({
+        "bench.step": 0.05, "coord.step": 0.05 + 0.1 + 0.1, "coord.drain": 0.1 + 0.1,
+        "coord.drain.apply": 0.4, "coord.bind": 0.2, "bench.watch": 0.1,
+    })
+    assert sum(idle.values()) == pytest.approx(
+        3.0 - trace_reduce.busy_window(EVENTS, 0.0, 3.0)["busy_s"])
+    late = dict(span_readers.idle_by_span(EVENTS, DEV, HOST, 0.0, 3.2))
+    assert late["unattributed"] == pytest.approx(0.2)
+
+
+def test_a_span_on_another_threads_line_never_attributes():
+    idle = dict(span_readers.idle_by_span(EVENTS, DEV, HOST, 0.0, 3.0))
+    assert "feed.encode" not in idle
+    # even where nothing on the loop's own line covers the gap
+    alone = [sp for sp in HOST if sp[1] in ("bench.step", "feed.encode")]
+    idle = dict(span_readers.idle_by_span(EVENTS, DEV, alone, 0.0, 3.0))
+    assert set(idle) == {"bench.step", "unattributed"}
+
+
+# ---- counters and set-up stages -------------------------------------------
+
+
+def _snap(**lanes):
+    return {"c_total": {(("lane", k),): float(v) for k, v in lanes.items()}}
+
+
+def test_counter_share_over_the_window_and_over_setup():
+    ctx = {"counters": {"open": _snap(json=10, batch_fast=30),
+                        "close": _snap(json=110, batch_fast=30, delete=5)}}
+    args = {"counter": "c_total", "labels": [{"lane": "json"}],
+            "of": [{"lane": "json"}, {"lane": "batch_fast"}], "over": "window"}
+    assert span_readers.counter_share_pct(args, ctx) == pytest.approx(100.0)
+    assert span_readers.counter_share_pct({**args, "over": "setup"}, ctx) \
+        == pytest.approx(25.0)
+    every = {k: v for k, v in args.items() if k != "of"}      # all label sets
+    assert span_readers.counter_share_pct(every, ctx) == pytest.approx(100 * 100 / 105)
+
+
+def test_counter_share_of_a_zero_denominator_is_nothing():
+    ctx = {"counters": {"open": _snap(json=10), "close": _snap(json=10)}}
+    args = {"counter": "c_total", "labels": [{"lane": "json"}], "over": "window"}
+    assert span_readers.counter_share_pct(args, ctx) is None
+    assert span_readers.counter_share_pct({**args, "counter": "absent"}, ctx) is None
+    assert span_readers.counter_share_pct(args, {}) is None
+
+
+def test_setup_stages_are_read_from_before_the_window():
+    ctx = {"setup_stage_s": {"bootstrap": 26.0, "bootstrap_ingest": 17.0},
+           "stage_s": {"bootstrap": 0.0}}
+    assert span_readers.setup_stage_s({"stages": ["bootstrap"]}, ctx) == 26.0
+    assert span_readers.setup_stage_s({"stages": ["resync"]}, ctx) is None
+    assert span_readers.setup_stage_s({"stages": ["bootstrap"]}, {}) is None
+
+
+def test_snapshots_read_the_programs_registry():
+    import k8s1m_tpu.control.coordinator  # noqa: F401  (registers the counters)
+
+    snap = span_readers.snapshot_counters(
+        ["coordinator_pod_intake_total", "no_such_counter_total"])
+    assert set(snap) == {"coordinator_pod_intake_total"}
+    assert all(k[0][0] == "lane" for k in snap["coordinator_pod_intake_total"])
+    assert isinstance(span_readers.stage_sums(), dict)
+
+
+# ---- the loader beside trace_reduce.load ----------------------------------
+
+
+def test_loader_reads_op_names_and_line_ids(tmp_path):
+    space = span_readers._xspace_class()()
+    dev = space.planes.add(name=DEV)
+    dev.stat_metadata.add(key=7).value.name = "tf_op"
+    dev.stat_metadata.add(key=8).value.name = "hlo_category"
+    named = dev.event_metadata.add(key=1).value
+    named.name = "%fusion.1 = fusion(...)"
+    named.stats.add(metadata_id=8, str_value="fusion")
+    named.stats.add(metadata_id=7, str_value="jit(f)/assign/while/body/add:")
+    dev.event_metadata.add(key=2).value.name = "%while.7 = while(...)"
+    host = space.planes.add(name="/host:CPU")
+    host.event_metadata.add(key=1).value.name = "coord.drain"
+    host.event_metadata.add(key=2).value.name = "PjRtExecute"
+    for line_id in (11, 12):                # two threads, as two lines do
+        line = host.lines.add(id=line_id, timestamp_ns=2_000_000_000)
+        line.events.add(metadata_id=1, offset_ps=500_000_000_000,
+                        duration_ps=250_000_000_000)
+        line.events.add(metadata_id=2, offset_ps=0, duration_ps=1)
+    out = tmp_path / "plugins" / "profile" / "run"
+    out.mkdir(parents=True)
+    (out / "host.xplane.pb").write_bytes(space.SerializeToString())
+    got = span_readers.load_names(str(tmp_path))
+    assert got["op_names"] == {DEV: {
+        "%fusion.1 = fusion(...)": "jit(f)/assign/while/body/add:"}}
+    assert got["host_spans"] == [
+        (11, "coord.drain", pytest.approx(2.5), pytest.approx(0.25)),
+        (12, "coord.drain", pytest.approx(2.5), pytest.approx(0.25)),
+    ]
+    # trace_reduce's own loader reads the same file
+    assert (DEV, ) not in trace_reduce.load(str(tmp_path))
+
+
+# ---- the eleven specs, and the eight accepted metrics ----------------------
+
+
+@pytest.mark.parametrize("spec", SPECS, ids=[m["name"] for m in SPECS])
+def test_span_metric_spec(spec):
+    assert set(spec) == {"name", "unit", "better", "source", "layer", "moves",
+                         "reader", "args", "what"}
+    assert NAME.match(spec["name"]) and UNIT.match(spec["unit"])
+    assert spec["better"] in ("lower", "higher") and spec["what"]
+    assert spec["source"] in ("device_trace", "program_counter")
+    assert spec["moves"] in E2E
+    assert spec["reader"] in {**readers.READERS, **span_readers.READERS}
+    # not in the manifest until run.py can feed the reader (PERF.md section 7)
+    assert spec["name"] not in {m["name"] for m in MANIFEST["per_layer"]}
+    assert [m["name"] for m in SPECS].count(spec["name"]) == 1
+    layers = {m["layer"] for m in MANIFEST["per_layer"]} | {"snapshot"}
+    assert spec["layer"] in layers
+
+
+def test_span_readers_take_no_accepted_readers_name():
+    assert not set(span_readers.READERS) & set(readers.READERS)
+    assert {m["reader"] for m in SPECS} >= set(span_readers.READERS)
+
+
+def test_the_accepted_metrics_read_what_they_read():
+    """With the program's spans in the trace, op_names beside it and every
+    stage label in ``stage_s``, the eight accepted metrics read the values
+    test_readers_on_a_synthetic_window pins."""
+    events = EVENTS + [
+        ("/host:CPU", "python3", n, s, d) for _l, n, s, d in HOST
+        if n.startswith(("coord.", "feed."))
+    ]
+    ctx = {
+        "stage_s": {"drain": 1.0, "drain_poll": 0.1, "drain_apply": 0.9,
+                    "bind": 3.0, "bind_cas": 1.0, "sync_out": 5.0},
+        "binds": 1000,
+        "trace": {"events": events, "plane": DEV, "op_names": OP_NAMES,
+                  "host_spans": HOST},
+        "shapes": {"scan_rows": 53248, "bytes_per_row": 42, "batch": 4096,
+                   "k": 4, "pod_bytes": 16},
+        "peaks": {"hbm_bytes_per_s": 819e9},
+    }
+    spec = lambda m: run.read_json("benchmark", "metrics", f"{m}.json")
+    value = lambda m: readers.READERS[spec(m)["reader"]](spec(m)["args"], ctx)
+    moved = 53248 * 42 + 4096 * 16 + 4096 * 4 * 8
+    assert {m["name"]: value(m["name"]) for m in MANIFEST["per_layer"]} == \
+        pytest.approx({
+            "engine_step_ms.fill": 1000.0, "assign_loop_ms.fill": 800.0,
+            "fused_topk_ms.fill": 100.0,
+            "fused_topk_roofline.fill": 100 * moved / 819e9 / 0.1,
+            "host_us_per_bind.fill": 4000.0, "store_bind_us_per_bind.fill": 3000.0,
+            "encode_us_per_bind.fill": 0.0, "drain_us_per_bind.fill": 1000.0,
+        })
+
+
+# ---- tools/span_report.py around the unedited harness ----------------------
+
+
+def _span_report():
+    spec = importlib.util.spec_from_file_location(
+        "span_report", os.path.join(ROOT, "tools", "span_report.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_span_report_reads_the_counters_of_a_tiny_run(cell):
+    tool = _span_report()
+    result = tool.report(
+        MANIFEST, cell, _tiny(cell), seed=(1 << 31) + 25, seconds=0.5,
+        trace=False, device=dict(CPU_DEVICE), peaks={},
+    )
+    assert result["correct"] is True
+    assert run.Cell is not tool.SpanCell and run.read_trace is not tool.read_trace
+    got = result["span_metrics"]
+    untraced = {m["name"] for m in SPECS if m["source"] != "device_trace"}
+    assert set(got) == untraced and len(untraced) == 8
+    assert got["intake_slow_lane_pct.fill"]["value"] == 100.0
+    assert got["bulkload_per_node_pct"]["value"] == 100.0     # cordons in its one chunk
+    assert 0 < got["bootstrap_ingest_s"]["value"] < got["bootstrap_s"]["value"]
+    assert got["drain_poll_us_per_bind.fill"]["value"] \
+        + got["drain_apply_us_per_bind.fill"]["value"] > 0
+    # the window's lane counts are the pods it offered, give or take the
+    # wave in flight at each edge
+    ctx = tool.SpanCell.last.ctx
+    lanes = lambda at: sum(
+        ctx["counters"][at]["coordinator_pod_intake_total"].values())
+    wave = _tiny(cell)[0]["wave"]
+    assert abs((lanes("close") - lanes("open")) - result["attempted"]) <= 2 * wave
+    assert set(ctx["stage_s"]) >= {"drain", "drain_poll", "drain_apply",
+                                   "bind", "bind_cas", "encode", "device"}

@@ -12,9 +12,6 @@ Layers:
    churn + tenants + depth-3 pipelining, stage attribution covers
    >= 95% of every traced pod's schedule-to-bind time (sum of stage
    spans vs end-to-end) and the waterfall's shares sum to ~1.
-4. Flight-recorder integration: a pod whose schedule-to-bind exceeds
-   the threshold dumps the ring WITH its span chain attached (the
-   reference's per-slow-pod flight dump, scheduler.go:556-565).
 """
 
 from __future__ import annotations
@@ -32,7 +29,6 @@ from k8s1m_tpu.obs.podtrace import (
     STAGES,
     validate_trace,
 )
-from k8s1m_tpu.obs.trace import FlightRecorder
 from k8s1m_tpu.plugins.registry import Profile
 from k8s1m_tpu.snapshot.node_table import NodeInfo
 from k8s1m_tpu.snapshot.pod_encoding import PodInfo
@@ -101,7 +97,7 @@ def test_null_tracer_is_inert():
 # ---- 2. exporter + validator ------------------------------------------
 
 
-def _traced_run(tmp_path, *, flight=None, sample_n=1, pods=6):
+def _traced_run(tmp_path, *, sample_n=1, pods=6):
     store = MemStore()
     for i in range(32):
         store.put(node_key(f"n-{i}"), encode_node(NodeInfo(
@@ -111,7 +107,6 @@ def _traced_run(tmp_path, *, flight=None, sample_n=1, pods=6):
     coord = Coordinator(
         store, TableSpec(max_nodes=64), PodSpec(batch=8), PROFILE,
         chunk=64, with_constraints=False, tracer=tracer,
-        flight_recorder=flight,
     )
     try:
         coord.bootstrap()
@@ -291,30 +286,3 @@ def test_podtrace_composed_4096_coverage_gate():
         for s, _, _, a in t.spans if s == "device"
     }
     assert max(d for d in depths if d is not None) > 1
-
-
-# ---- 4. flight-recorder integration -----------------------------------
-
-
-def test_slow_pod_flight_dump_attaches_span_chain(tmp_path):
-    """A pod whose schedule-to-bind exceeds the flight threshold dumps
-    the ring with its full span chain attached."""
-    flight = FlightRecorder(threshold_s=0.0, dump_dir=str(tmp_path))
-    _traced_run(tmp_path, flight=flight, pods=3)
-    dumps = sorted(
-        f for f in os.listdir(tmp_path) if f.startswith("flight-")
-    )
-    assert dumps
-    slow = None
-    for fn in dumps:
-        with open(tmp_path / fn) as f:
-            doc = json.load(f)
-        if "pod" in doc:
-            slow = doc
-            break
-    assert slow is not None, dumps
-    assert slow["pod"].startswith("default/p")
-    stages = [s["stage"] for s in slow["pod_spans"]]
-    assert "bind" in stages and "device" in stages
-    assert all("dur_s" in s for s in slow["pod_spans"])
-    assert slow["reason"].startswith(f"pod {slow['pod']}")

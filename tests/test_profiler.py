@@ -1,13 +1,10 @@
 """Sampling profiler (obs/profiler.py) — the Parca/pprof role.
 
 Pins that the sampler attributes wall time to the function that burns
-it, that artifacts are well-formed collapsed stacks, and that the
-coordinator's slow-cycle hook leaves a profile artifact next to the
-flight dump.
+it, and that artifacts are well-formed collapsed stacks.
 """
 
 import json
-import os
 import threading
 import time
 
@@ -123,47 +120,3 @@ def test_profiler_excludes_late_started_profiler_thread():
     assert not any("_late_decoy_spin" in s for s in prof.stacks), (
         [s for s in prof.stacks if "_late_decoy_spin" in s][:3]
     )
-
-
-def test_slow_cycle_dumps_profile_artifact(tmp_path):
-    """Coordinator wiring: a cycle over the flight threshold writes a
-    profile-slowcycle-*.json next to the flight dump."""
-    from k8s1m_tpu.config import PodSpec, TableSpec
-    from k8s1m_tpu.control.coordinator import Coordinator
-    from k8s1m_tpu.control.objects import encode_node, encode_pod, node_key, pod_key
-    from k8s1m_tpu.obs.trace import FlightRecorder
-    from k8s1m_tpu.plugins.registry import Profile
-    from k8s1m_tpu.snapshot.pod_encoding import PodInfo
-    from k8s1m_tpu.store.native import MemStore
-    from k8s1m_tpu.tools.make_nodes import build_node
-
-    store = MemStore()
-    for i in range(32):
-        store.put(node_key(f"n-{i}"), encode_node(build_node(i)))
-    prof = SamplingProfiler(hz=250).start()
-    coord = Coordinator(
-        store, TableSpec(max_nodes=64), PodSpec(batch=8),
-        Profile(topology_spread=0, interpod_affinity=0),
-        chunk=64, with_constraints=False,
-        # Any real cycle exceeds a 0-second threshold.
-        flight_recorder=FlightRecorder(
-            threshold_s=0.0, dump_dir=str(tmp_path)
-        ),
-        profiler=prof,
-    )
-    try:
-        coord.bootstrap()
-        store.put(
-            pod_key("default", "p0"),
-            encode_pod(PodInfo("p0", cpu_milli=10, mem_kib=1024)),
-        )
-        assert coord.run_until_idle() == 1
-    finally:
-        prof.stop()
-        coord.close()
-        store.close()
-    dumps = [f for f in os.listdir(tmp_path) if f.startswith("profile-slowcycle-")]
-    assert dumps
-    with open(tmp_path / dumps[0]) as f:
-        art = json.load(f)
-    assert "top_self" in art and "collapsed" in art

@@ -99,6 +99,7 @@ from k8s1m_tpu.control.objects import (
     decode_pod,
     decode_pod_fast,
     decode_pod_obj,
+    decode_pod_shape,
     node_key,
     pod_key,
     pod_key_str_of_obj,
@@ -137,6 +138,7 @@ from k8s1m_tpu.snapshot.hotfeed import (
     ShardedHostFeed,
     cache_counts,
     encode_batch,
+    fingerprint,
     shape_key,
 )
 from k8s1m_tpu.snapshot.node_table import (
@@ -164,6 +166,7 @@ from k8s1m_tpu.tenancy.policy import (
     tenant_of_key,
     tenant_of_obj,
     tenant_of_pod,
+    tenant_override,
 )
 from k8s1m_tpu.tenancy.preempt import (
     Victim,
@@ -205,14 +208,27 @@ _DECODE_ERRORS = Counter(
 )
 # Counted where the intake forks (_apply_pod_batch, _on_pod_put), one
 # inc per lane per batch: batch_fast = a whole poll of canonical pending
-# pods taken column-wise; canonical = per event, parsed natively;
-# decode_fast / json = a non-canonical put through decode_pod_fast or
-# json.loads + decode_pod_obj; echo = a non-canonical bind echo known by
-# its key, not decoded; delete.
+# pods taken column-wise, labels and tolerations by their interned shape;
+# canonical = per event, parsed natively (shaped or not); decode_fast /
+# json = a put the native parser did not take (or a watcher without
+# poll_pods) through decode_pod_fast or json.loads + decode_pod_obj;
+# echo = a non-canonical bind echo known by its key, not decoded; delete.
 _POD_INTAKE = Counter(
     "coordinator_pod_intake_total",
     "Pod watch/list events applied, by intake lane", ("lane",),
 )
+# Once per frame, never per pod: pods taken natively over `interned` is
+# the shape table's hit share.
+_POD_SHAPES = Counter(
+    "coordinator_pod_shapes_total",
+    "Distinct (label map, toleration list) byte spans of natively parsed "
+    "pods decoded into the intake's shape table (interned), and resets of "
+    "the full table (evicted)", ("event",),
+)
+# Bound of the shape table, like _gang_oversize's: a stream of unique
+# label sets degrades to one decode a pod (the JSON lane's cost), and
+# clearing only re-decodes a live template once more.
+POD_SHAPES_MAX = 4096
 _CYCLE_TIME = Histogram(
     "coordinator_cycle_seconds", "Scheduling cycle latency by stage", ("stage",)
 )
@@ -375,11 +391,45 @@ _BIND_LATENCY = Histogram(
 )
 
 
+class PodShape:
+    """What the pods of one template share, decoded once per distinct
+    (label map, toleration list) byte span of the native parser's frame
+    and not once per pod: exactly what the JSON lane's PodInfo would hold
+    of it.  Immutable after construction (the hotfeed worker reads it)."""
+
+    __slots__ = ("labels", "tolerations", "scheduler_name", "fp", "gang",
+                 "tenant")
+
+    def __init__(self, labels: dict, tolerations: list,
+                 scheduler_name: str) -> None:
+        self.labels = labels
+        self.tolerations = tolerations
+        self.scheduler_name = scheduler_name
+        # hotfeed.fingerprint of every pod of the shape that carries no
+        # constraint increments (labels are not structural; PLAIN for a
+        # shape of labels alone).
+        self.fp = fingerprint(self.pod("/", 0, 0))
+        # Whether the labels name a gang (tenancy/policy.gang_of_labels).
+        self.gang = gang_of_labels(labels, "") is not None
+        # The tenant label's override, None = the namespace is the tenant.
+        self.tenant = tenant_override(labels)
+
+    def pod(self, key_str: str, cpu_milli: int, mem_kib: int,
+            node_name: str | None = None) -> PodInfo:
+        ns, name = key_str.split("/", 1)
+        return PodInfo(
+            name=name, namespace=ns, cpu_milli=cpu_milli, mem_kib=mem_kib,
+            scheduler_name=self.scheduler_name, node_name=node_name,
+            tolerations=list(self.tolerations), labels=dict(self.labels),
+        )
+
+
 @dataclasses.dataclass(slots=True)
 class PendingPod:
-    # None = native-intake fast lane: the pod is canonical and label-less
-    # (store/native.py poll_pods parsed it in C), so the full PodInfo is
-    # materialized only if a slow path actually needs it (ensure_pod).
+    # None = native-intake fast lane: the pod is canonical
+    # (store/native.py poll_pods parsed it in C) and what it holds beyond
+    # its scalars is its ``shape``, so the full PodInfo is materialized
+    # only if a slow path actually needs it (ensure_pod).
     pod: PodInfo | None
     # None = webhook intake: the object wasn't persisted at admission
     # time, so the bind path resolves the live revision instead.
@@ -401,13 +451,17 @@ class PendingPod:
     # retry (RetryPolicy backoff; 0 = immediately eligible).
     not_before: float = 0.0
     # spec.priority — admission/preemption only (never encoded).  0 for
-    # native fast-lane pods: the canonical label-less shape cannot carry
-    # a priority, so the hot path needs no decode to know it.
+    # native fast-lane pods: the canonical shape cannot carry a
+    # priority, so the hot path needs no decode to know it.
     priority: int = 0
     # Gang membership (tenancy/gang.py): namespace-qualified gang id and
     # declared size; "" / 0 = not a gang pod.
     gang_id: str = ""
     gang_size: int = 0
+    # Labels and tolerations of a native fast-lane pod, interned per
+    # template; None = it has neither.  Read only while ``pod`` is None:
+    # a materialized or re-decoded PodInfo supersedes it.
+    shape: PodShape | None = None
 
     def peek_pod(self) -> PodInfo:
         """The PodInfo WITHOUT caching it on the record — the hotfeed
@@ -416,6 +470,8 @@ class PendingPod:
         write on shared state)."""
         if self.pod is not None:
             return self.pod
+        if self.shape is not None:
+            return self.shape.pod(self.key_str, self.cpu_milli, self.mem_kib)
         ns, name = self.key_str.split("/", 1)
         return PodInfo(
             name=name, namespace=ns,
@@ -1005,6 +1061,9 @@ class Coordinator:
         # Label-less pods can still match constraints whose selector is
         # empty; the fast lane must not lose those.
         self._empty_incs_cache: dict[tuple[int, int, str], tuple] = {}
+        # (label span, toleration span) of natively parsed pods -> their
+        # PodShape (_frame_shapes); at most POD_SHAPES_MAX entries.
+        self._pod_shapes: dict[tuple[bytes, bytes], PodShape] = {}
         # Webhook-intake staging: appended from server threads, drained
         # into the queue at the top of each cycle (deque+set aren't
         # thread-safe to mutate from the handler directly).
@@ -1527,11 +1586,44 @@ class Coordinator:
             self._flush_lanes(delete=deletes)
         return n
 
+    def _frame_shapes(self, evb) -> list:
+        """The PodShape of every entry of one frame's shape table, at the
+        index the frame's events name it by (0 = None: no labels, no
+        tolerations).  A span pair not seen before is decoded by the JSON
+        lane's own code (objects.decode_pod_shape); one that cannot be
+        decoded is False, and its pods count as decode errors just as
+        _on_pod_put would have counted them."""
+        shapes: list = [None]
+        table = self._pod_shapes
+        interned = 0
+        for spans in evb.shapes:
+            sh = table.get(spans)
+            if sh is None:
+                try:
+                    sh = PodShape(
+                        *decode_pod_shape(*spans), self.scheduler_name
+                    )
+                except Exception:
+                    log.exception("undecodable pod labels/tolerations")
+                    sh = False
+                else:
+                    if len(table) >= POD_SHAPES_MAX:
+                        table.clear()
+                        _POD_SHAPES.inc(event="evicted")
+                    table[spans] = sh
+                    interned += 1
+            shapes.append(sh)
+        if interned:
+            _POD_SHAPES.inc(interned, event="interned")
+        return shapes
+
     def _apply_pod_batch(self, evb) -> None:
         """Apply one columnar poll_pods drain (store/native.py
         PodEventBatch).  Flag semantics decided natively: CANONICAL means
-        the C parser accepted the exact encode_pod shape (label-less);
-        everything else falls back to _on_pod_put's full decode."""
+        the C parser accepted the exact encode_pod shape (scalars, plus a
+        label map and a toleration list that arrive as the index of an
+        interned PodShape); everything else falls back to _on_pod_put's
+        full decode."""
         plen = len(PODS_PREFIX)
         koff = evb.koff.tolist()
         kb = evb.key_blob
@@ -1547,7 +1639,20 @@ class Coordinator:
         tr_on = tracer.enabled
         tr = self.tracker
         has_constraints = bool(tr._spread or tr._affinity)
-        if fastmask.all() and not has_constraints:
+        tn = self.tenancy
+        gangs_on = tn is not None and tn.policy.gang_enabled
+        if evb.shapes:
+            shapes = self._frame_shapes(evb)
+            shape_l = [shapes[s] for s in evb.shape.tolist()]
+            # False with a shape that must be looked at pod by pod:
+            # undecodable, or gang labels while gangs are staged.
+            shapes_columnar = all(
+                sh and not (gangs_on and sh.gang) for sh in shapes[1:]
+            )
+        else:
+            shape_l = [None] * evb.n
+            shapes_columnar = True
+        if fastmask.all() and not has_constraints and shapes_columnar:
             # Pure create wave (the make_pods steady state): one batched
             # tolist per column, no per-event branching.
             cpu_l = evb.cpu.tolist()
@@ -1557,18 +1662,23 @@ class Coordinator:
             bound = self._bound
             q = self.queue
             filt = self.intake_filter
+            # Keys are ASCII but for the odd name: decoded in one piece,
+            # byte offsets are then string offsets too.
+            ka = kb.decode() if kb.isascii() else None
             for i in range(evb.n):
-                key = kb[koff[i] : koff[i + 1]]
-                ks = key[plen:].decode()
+                lo, hi = koff[i], koff[i + 1]
+                ks = (
+                    ka[lo + plen : hi] if ka is not None
+                    else kb[lo + plen : hi].decode()
+                )
                 if ks in queued or ks in bound:
                     continue
                 if filt is not None and not filt(ks):
                     continue
                 queued.add(ks)
                 q.append(PendingPod(
-                    None, mrev_l[i], now,
-                    cpu_milli=cpu_l[i], mem_kib=mem_l[i],
-                    key_str=ks, key_bytes=key,
+                    None, mrev_l[i], now, cpu_l[i], mem_l[i], ks,
+                    key_bytes=kb[lo:hi], shape=shape_l[i],
                 ))
                 if tr_on:
                     tracer.begin(ks, now, source="intake")
@@ -1598,6 +1708,10 @@ class Coordinator:
                 has_constraints = bool(tr._spread or tr._affinity)
                 continue
             ks = key[plen:].decode()
+            sh = shape_l[i]
+            if sh is False:
+                _DECODE_ERRORS.inc(kind="pod")
+                continue
             if f & POD_HAS_NODE:
                 # A bind: ours echoing back (suppressed at the store for
                 # native binds, but the slow _bind path still echoes), or
@@ -1606,16 +1720,9 @@ class Coordinator:
                     self._queued_keys.discard(ks)
                     continue
                 node_name = ab[aoff[i] : aoff[i + 1]].decode()
-                ns, name = ks.split("/", 1)
-                pod = PodInfo(
-                    name=name, namespace=ns,
-                    cpu_milli=cpu_l[i], mem_kib=mem_l[i],
-                    node_name=node_name,
+                pod = self._native_pod(
+                    sh, ks, cpu_l[i], mem_l[i], has_constraints, node_name
                 )
-                if has_constraints:
-                    si, ii = self._empty_incs(ns)
-                    pod.spread_incs = list(si)
-                    pod.ipa_incs = list(ii)
                 if node_name in self.host._row_of:
                     self._orphan_bound.pop(ks, None)
                     self.host.add_pod(node_name, pod.cpu_milli, pod.mem_kib)
@@ -1631,29 +1738,59 @@ class Coordinator:
                 continue
             if self.intake_filter is not None and not self.intake_filter(ks):
                 continue
+            # The record carries a PodInfo only where something reads
+            # more of the pod than its shape: constraint increments
+            # (decode_pod_fast's tracker matches) or gang staging.
             pod = None
-            if has_constraints:
-                ns, name = ks.split("/", 1)
-                si, ii = self._empty_incs(ns)
-                if si or ii:
-                    # Matches an empty-selector constraint: not plain.
-                    pod = PodInfo(
-                        name=name, namespace=ns,
-                        cpu_milli=cpu_l[i], mem_kib=mem_l[i],
-                    )
-                    pod.spread_incs = list(si)
-                    pod.ipa_incs = list(ii)
+            gang = gangs_on and sh is not None and sh.gang
+            if gang or (has_constraints and (
+                sh is not None or any(self._empty_incs(ks.split("/", 1)[0]))
+            )):
+                pod = self._native_pod(
+                    sh, ks, cpu_l[i], mem_l[i], has_constraints
+                )
+                if not (gang or pod.spread_incs or pod.ipa_incs):
+                    pod = None
             self._queued_keys.add(ks)
-            self.queue.append(PendingPod(
+            rec = PendingPod(
                 pod, mrev_l[i], now,
                 cpu_milli=cpu_l[i], mem_kib=mem_l[i],
-                key_str=ks, key_bytes=key,
-            ))
+                key_str=ks, key_bytes=key, shape=sh,
+            )
+            if gang:
+                self._stage_or_queue(rec, pod)
+                continue
+            self.queue.append(rec)
             if tr_on:
                 tracer.begin(ks, now, source="intake")
         self._flush_lanes(
             delete=deletes, canonical=evb.n - deletes - slow
         )
+
+    def _native_pod(
+        self, shape: PodShape | None, key_str: str, cpu_milli: int,
+        mem_kib: int, has_constraints: bool, node_name: str | None = None,
+    ) -> PodInfo:
+        """The PodInfo of one natively parsed pod, with the tracker's
+        matches of its labels as decode_pod_fast would have set them."""
+        if shape is not None:
+            pod = shape.pod(key_str, cpu_milli, mem_kib, node_name)
+        else:
+            ns, name = key_str.split("/", 1)
+            pod = PodInfo(
+                name=name, namespace=ns, cpu_milli=cpu_milli,
+                mem_kib=mem_kib, node_name=node_name,
+            )
+        if has_constraints:
+            ns = pod.namespace
+            if shape is not None:
+                pod.spread_incs = self.tracker.spread_matches(ns, pod.labels)
+                pod.ipa_incs = self.tracker.affinity_matches(ns, pod.labels)
+            else:
+                si, ii = self._empty_incs(ns)
+                pod.spread_incs = list(si)
+                pod.ipa_incs = list(ii)
+        return pod
 
     def _node_name_bytes(self) -> list:
         """Encoded node names, index-parallel with vocab.node_names
@@ -3015,10 +3152,13 @@ class Coordinator:
     def _delta_key(p: PendingPod):
         """The pod's plane-cache shape key (snapshot/hotfeed.shape_key),
         or None for uncacheable shapes.  Native fast-lane pods
-        (pod=None) are canonical label-less plain pods by construction
-        — their key needs no PodInfo materialization at all."""
+        (pod=None) carry no constraint increment and no nodeName by
+        construction, and their interned shape holds their fingerprint
+        (PLAIN without one) — their key needs no PodInfo
+        materialization at all."""
         if p.pod is None:
-            return (PLAIN, p.cpu_milli, p.mem_kib)
+            fp = PLAIN if p.shape is None else p.shape.fp
+            return (fp, p.cpu_milli, p.mem_kib)
         return shape_key(p.pod)
 
     def _plan_delta(self, batch_pods, batch):
@@ -3623,9 +3763,10 @@ class Coordinator:
                         ok_cpu.append(p.cpu_milli)
                         ok_mem.append(p.mem_kib)
                         lats.append(now - p.enqueued_at)
+                        pod = p.pod
                         keep = (
-                            p.pod
-                            if p.pod is not None and self._constraintful(p.pod)
+                            pod
+                            if pod is not None and self._constraintful(pod)
                             else None
                         )
                         node_name = nv[ids_l[j]]
@@ -3636,13 +3777,16 @@ class Coordinator:
                         self._bind_seq += 1
                         # bind_batch takes ANY pod with an observed
                         # revision, decoded or not: a decoded PodInfo
-                        # supplies the label-aware tenant; the true
-                        # fast-lane (pod=None) is label-less canonical,
-                        # so its key namespace IS the tenant.
-                        tenant = (
-                            tenant_of_pod(p.pod) if p.pod is not None
-                            else tenant_of_key(p.key_str)
-                        )
+                        # supplies the label-aware tenant, a fast-lane
+                        # pod's shape knows whether its labels name one;
+                        # failing both the key's namespace IS the tenant.
+                        if pod is not None:
+                            tenant = tenant_of_pod(pod)
+                        else:
+                            sh = p.shape
+                            tenant = (
+                                sh.tenant if sh is not None else None
+                            ) or tenant_of_key(p.key_str)
                         self._bind_meta[p.key_str] = (
                             p.priority, self._bind_seq, tenant, p.gang_id,
                         )

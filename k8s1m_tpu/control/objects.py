@@ -596,15 +596,18 @@ def decode_pod(data: bytes, tracker: ConstraintTracker | None = None) -> PodInfo
 
 
 # Byte landmarks of the canonical encode_pod shape.  The fast parser
-# accepts EXACTLY the objects this module's encode_pod emits for pods with
-# no selectors/tolerations/affinity/spread (plus the nodeName-spliced bind
-# form) — anything else, including any backslash escape anywhere, falls
-# back to the full JSON path.  This is the restricted-parser analogue of
-# the reference's empirically-restricted Txn support (one shape, fast;
-# everything else rejected — kv_service.rs:126-337).
+# accepts EXACTLY the objects this module's encode_pod emits for pods
+# whose only free parts are a flat label map and a toleration list (no
+# selectors, affinity, spread or priority), in either nodeName form —
+# anything else, including any backslash escape anywhere, falls back to
+# the full JSON path.  The native parser (native/memstore parse_pod) is
+# its twin and accepts the same inputs.  This is the restricted-parser
+# analogue of the reference's empirically-restricted Txn support (one
+# shape, fast; everything else rejected — kv_service.rs:126-337).
 _FP_HEAD = b'{"apiVersion":"v1","kind":"Pod","metadata":{"name":"'
 _FP_NS = b'","namespace":"'
 _FP_LABELS = b'","labels":{'
+_FP_SPEC = b'},"spec":{'
 _FP_NODE = b'"nodeName":"'
 _FP_SCHED = b'"schedulerName":"'
 _FP_CONTAINERS = (
@@ -612,11 +615,71 @@ _FP_CONTAINERS = (
     b'"resources":{"requests":{"cpu":"'
 )
 _FP_MEM = b'","memory":"'
-_FP_TAIL = b'"}}}]},"status":{"phase":"Pending"}}'
+_FP_CTR_END = b'"}}}]'
 # encode_pod appends nodeName after containers (dict insertion order);
 # the bind splice inserts it before schedulerName.  Accept both.
-_FP_NODE_TAIL = b'"}}}],"nodeName":"'
-_FP_STATUS = b'"},"status":{"phase":"Pending"}}'
+_FP_NODE_APP = b',"nodeName":"'
+_FP_TOLS = b',"tolerations":['
+_FP_END = b'},"status":{"phase":"Pending"}}'
+_FP_TOL_EFFECTS = tuple(
+    (name.encode() + b'"', effect) for name, effect in _EFFECTS.items() if name
+)
+
+
+def _scan_tolerations(data: bytes, i: int):
+    """Parse one or more toleration objects exactly as encode_pod writes
+    them (optional key, operator Exists|Equal, optional value, optional
+    effect, in that order) starting at ``i`` (just past the opening
+    bracket).  Returns (tolerations, index past the closing bracket) or
+    None for any other shape."""
+    tols: list[Toleration] = []
+    while True:
+        if data[i : i + 1] != b"{":
+            return None
+        i += 1
+        key = value = b""
+        if data.startswith(b'"key":"', i):
+            j = data.find(b'"', i + 7)
+            if data[j : j + 2] != b'",':
+                return None
+            key = data[i + 7 : j]
+            i = j + 2
+        if not data.startswith(b'"operator":"', i):
+            return None
+        i += 12
+        if data.startswith(b'Exists"', i):
+            op = TOL_OP_EXISTS
+            i += 7
+        elif data.startswith(b'Equal"', i):
+            op = TOL_OP_EQUAL
+            i += 6
+        else:
+            return None
+        if data.startswith(b',"value":"', i):
+            j = data.find(b'"', i + 10)
+            if j < 0:
+                return None
+            value = data[i + 10 : j]
+            i = j + 1
+        effect = EFFECT_NONE
+        if data.startswith(b',"effect":"', i):
+            i += 11
+            for name, effect in _FP_TOL_EFFECTS:
+                if data.startswith(name, i):
+                    i += len(name)
+                    break
+            else:
+                return None
+        if data[i : i + 1] != b"}":
+            return None
+        nxt = data[i + 1 : i + 2]
+        i += 2
+        tols.append(Toleration(key.decode(), op, value.decode(), effect))
+        if nxt == b",":
+            continue
+        if nxt == b"]":
+            return tols, i
+        return None
 
 
 def decode_pod_fast(
@@ -642,10 +705,12 @@ def decode_pod_fast(
     scanned = _scan_labels(data, j + len(_FP_LABELS))
     if scanned is None:
         return None
+    # _scan_labels consumed the map's own brace; _FP_SPEC opens with
+    # metadata's.
     labels, i = scanned
-    if data[i : i + 10] != b'},"spec":{':
+    if not data.startswith(_FP_SPEC, i):
         return None
-    i += 10
+    i += len(_FP_SPEC)
     node_name = None
     if data.startswith(_FP_NODE, i):
         i += len(_FP_NODE)
@@ -669,16 +734,28 @@ def decode_pod_fast(
     i = j + len(_FP_MEM)
     j = data.find(b'"', i)
     mem_b = data[i:j]
-    # The tail must be the EXACT remainder: proves there is no
-    # nodeSelector/tolerations/affinity/topologySpreadConstraints.
-    if data[j:] != _FP_TAIL:
-        if node_name is not None or not data.startswith(_FP_NODE_TAIL, j):
+    if not data.startswith(_FP_CTR_END, j):
+        return None
+    i = j + len(_FP_CTR_END)
+    if data.startswith(_FP_NODE_APP, i):
+        if node_name is not None:
             return None
-        i = j + len(_FP_NODE_TAIL)
+        i += len(_FP_NODE_APP)
         j = data.find(b'"', i)
-        node_name = data[i:j].decode()
-        if data[j:] != _FP_STATUS:
+        if j < 0:
             return None
+        node_name = data[i:j].decode()
+        i = j + 1
+    tolerations: list[Toleration] = []
+    if data.startswith(_FP_TOLS, i):
+        scanned = _scan_tolerations(data, i + len(_FP_TOLS))
+        if scanned is None:
+            return None
+        tolerations, i = scanned
+    # The tail must be the EXACT remainder: proves there is no
+    # nodeSelector/affinity/topologySpreadConstraints/priority.
+    if data[i:] != _FP_END:
+        return None
     if not cpu_b.endswith(b"m") or not mem_b.endswith(b"Ki"):
         return None
     try:
@@ -695,12 +772,39 @@ def decode_pod_fast(
         mem_kib=mem,
         scheduler_name=scheduler_name.decode(),
         node_name=node_name,
+        tolerations=tolerations,
     )
     if tracker is not None:
         ns = pod.namespace
         pod.spread_incs = tracker.spread_matches(ns, labels)
         pod.ipa_incs = tracker.affinity_matches(ns, labels)
     return pod
+
+
+def decode_pod_shape(labels: bytes, tolerations: bytes):
+    """(labels, tolerations) of a natively parsed pod, from the two byte
+    spans the native parser found (between the braces of metadata.labels,
+    between the brackets of spec.tolerations): json.loads and then
+    decode_pod_obj's own handling, so that a shape never means anything
+    else than the JSON lane would have made of the same pod.  Control
+    bytes inside strings are let through, as decode_pod_fast lets them."""
+    obj = json.loads(
+        b'{"labels":{%s},"tolerations":[%s]}' % (labels, tolerations),
+        strict=False,
+    )
+    return dict(obj["labels"]), _decode_tolerations(obj["tolerations"])
+
+
+def _decode_tolerations(items: list) -> list[Toleration]:
+    return [
+        Toleration(
+            key=t.get("key", ""),
+            op=TOL_OP_EXISTS if t.get("operator", "Equal") == "Exists" else TOL_OP_EQUAL,
+            value=t.get("value", ""),
+            effect=_EFFECTS[t.get("effect", "")],
+        )
+        for t in items
+    ]
 
 
 def decode_pod_obj(obj: dict, tracker: ConstraintTracker | None = None) -> PodInfo:
@@ -732,15 +836,7 @@ def decode_pod_obj(obj: dict, tracker: ConstraintTracker | None = None) -> PodIn
         # with a garbage priority schedules at 0, it is not rejected.
         priority=pod_priority_of(obj),
         node_selector=dict(spec.get("nodeSelector", {})),
-        tolerations=[
-            Toleration(
-                key=t.get("key", ""),
-                op=TOL_OP_EXISTS if t.get("operator", "Equal") == "Exists" else TOL_OP_EQUAL,
-                value=t.get("value", ""),
-                effect=_EFFECTS[t.get("effect", "")],
-            )
-            for t in spec.get("tolerations", [])
-        ],
+        tolerations=_decode_tolerations(spec.get("tolerations", [])),
     )
 
     aff = spec.get("affinity", {})

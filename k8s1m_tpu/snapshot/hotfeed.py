@@ -458,7 +458,13 @@ class HotPodBatchHost(PodBatchHost):
 
     # ---- cached fill ---------------------------------------------------
 
-    def _fill(self, out: dict, pods: list[PodInfo]) -> None:
+    def _fill(self, out: dict, pods: list, fps: list | None = None) -> None:
+        """``fps[i]``, where given and not None, is pod i's fingerprint
+        as intake interned it (one object per pod template), and
+        ``pods[i]`` is then the pending record itself: its scalars are
+        read here, a PodInfo is materialized (``peek_pod``) only for the
+        one representative a template is built from, or for the members
+        of a group too small for a template.  Such a pod has no nodeName."""
         s = self.spec
         b = s.batch
         if len(pods) > b:
@@ -473,6 +479,8 @@ class HotPodBatchHost(PodBatchHost):
         self._last_gen = v.feed_generation()
         gen = v.generation()
         n = len(pods)
+        if fps is None:
+            fps = [None] * n
         out["valid"][:n] = True
         out["cpu"][:n] = np.fromiter((p.cpu_milli for p in pods), np.int32, n)  # graftlint: disable=hotfeed-no-per-pod-python (scalar column)
         out["mem"][:n] = np.fromiter((p.mem_kib for p in pods), np.int32, n)  # graftlint: disable=hotfeed-no-per-pod-python (scalar column)
@@ -498,26 +506,35 @@ class HotPodBatchHost(PodBatchHost):
                 out["qkey"][i] = v.label_keys.lookup(key)
             return i
 
+        def pod_at(i: int) -> PodInfo:
+            return pods[i] if fps[i] is None else pods[i].peek_pod()
+
         cache = self.cache
         groups: dict = {}
         taints = None
         # Phase 1 — per-pod: scalar nodeName + fingerprint + grouping,
-        # O(shape) dict/tuple work per pod; every field write happens in
-        # phase 2, per shape.
+        # O(shape) dict/tuple work per pod (an identity check for a pod
+        # whose fingerprint intake interned: a run of one template never
+        # hashes it); every field write happens in phase 2, per shape.
+        last_fp = members = None
         # graftlint: disable=hotfeed-no-per-pod-python (fingerprinting is the irreducible per-pod work; field writes are per-shape in phase 2)
-        for i, pod in enumerate(pods):
-            if pod.node_name is not None:
-                nid = v.node_names.lookup(pod.node_name)
-                out["node_name_id"][i] = nid if nid != NONE_ID else -1
-                dirty.add("node_name_id")
-            fp = fingerprint(pod)
+        for i, (pod, fp) in enumerate(zip(pods, fps)):
+            if fp is None:
+                if pod.node_name is not None:
+                    nid = v.node_names.lookup(pod.node_name)
+                    out["node_name_id"][i] = nid if nid != NONE_ID else -1
+                    dirty.add("node_name_id")
+                fp = fingerprint(pod)
+            elif fp is last_fp:
+                members.append(i)
+                continue
             if fp is PLAIN:
                 continue
             members = groups.get(fp)
             if members is None:
-                groups[fp] = [(i, pod)]
-            else:
-                members.append((i, pod))
+                members = groups[fp] = []
+            members.append(i)
+            last_fp = fp
 
         # Phase 2 — per shape, in first-encounter (insertion) order.
         # qkey byte-identity holds because a key's first reference in
@@ -534,14 +551,15 @@ class HotPodBatchHost(PodBatchHost):
             if len(members) < TEMPLATE_MIN:
                 if taints is None:
                     taints = list(v.taints.items())
-                for i, pod in members:
+                for i in members:
+                    pod = pod_at(i)
                     self._fill_pod(out, i, pod, qidx, taints)
                     for attr, names in _FIELDS_BY_ATTR:
                         if getattr(pod, attr):
                             dirty.update(names)
                 continue
             tpl, was_cached = cache.get_or_build(
-                self, members[0][1], fp, gen
+                self, pod_at(members[0]), fp, gen
             )
             if was_cached:
                 hits += len(members)
@@ -550,7 +568,7 @@ class HotPodBatchHost(PodBatchHost):
                 hits += len(members) - 1
             dirty.update(tpl.direct)
             dirty.update(tpl.qidx)
-            idx = np.asarray([i for i, _ in members], np.intp)
+            idx = np.asarray(members, np.intp)
             for name, row in tpl.direct.items():
                 out[name][idx] = row
             if tpl.key_seq or tpl.qidx:
@@ -589,12 +607,16 @@ class HotPodBatchHost(PodBatchHost):
             self._zeros[name] = z
         return z
 
-    def encode_packed(self, pods: list[PodInfo]) -> PackedPodBatch:
+    def encode_packed(
+        self, pods: list, fps: list | None = None
+    ) -> PackedPodBatch:
+        """``fps``: fingerprints intake interned, parallel to ``pods``
+        (see ``_fill``); None = every entry is a PodInfo."""
         t0 = time.perf_counter()
         specs = batch_field_specs(self.spec, self.table_spec)
         out = self._arena_take(specs)
         try:
-            self._fill(out, pods)
+            self._fill(out, pods, fps)
         except BaseException:
             # A mid-fill error (oversized pod) leaves unknown regions
             # written with the dirty bookkeeping lost; drop the arena so
@@ -766,7 +788,7 @@ def merge_packed(parts: list[PackedPodBatch]) -> PackedPodBatch | None:
     )
 
 
-def encode_batch(enc: PodBatchHost, batch_pods, *, mutate: bool = True):
+def encode_batch(enc: HotPodBatchHost, batch_pods, *, mutate: bool = True):
     """Encode popped/peeked PendingPods with ``enc`` — the ONE encode
     body both the inline path (Coordinator._take_batch) and the feed
     worker run, so staged and inline encodes of the same pods can never
@@ -774,7 +796,7 @@ def encode_batch(enc: PodBatchHost, batch_pods, *, mutate: bool = True):
     without assigning ``p.pod`` — the peeked objects still belong to
     the cycle thread's queue."""
     # graftlint: disable=hotfeed-no-per-pod-python (O(pods) scalar extraction feeding the vectorized plain lane / cached fill)
-    if all(p.pod is None for p in batch_pods):
+    if all(p.pod is None and p.shape is None for p in batch_pods):
         # Native-intake fast lane: a wave of plain pods encodes from
         # two int columns, no per-pod Python (vocab-independent, so the
         # stamp stays None and claim() skips the generation check).
@@ -782,11 +804,21 @@ def encode_batch(enc: PodBatchHost, batch_pods, *, mutate: bool = True):
             [p.cpu_milli for p in batch_pods],  # graftlint: disable=hotfeed-no-per-pod-python (scalar column)
             [p.mem_kib for p in batch_pods],  # graftlint: disable=hotfeed-no-per-pod-python (scalar column)
         )
-    if mutate:
-        # graftlint: disable=hotfeed-no-per-pod-python (materializing PodInfo refs for the cached fill; field writes are vectorized inside)
-        return enc.encode_packed([p.ensure_pod() for p in batch_pods])
-    # graftlint: disable=hotfeed-no-per-pod-python (read-only PodInfo materialization for the worker)
-    return enc.encode_packed([p.peek_pod() for p in batch_pods])
+    # A fast-lane record with an interned shape stands in for its own
+    # PodInfo: the fill reads its scalars and its shape's fingerprint,
+    # and builds what intake did not (a PodInfo, a fingerprint) once per
+    # template.  Everything else is materialized as before.
+    pods: list = []
+    fps: list = []
+    # graftlint: disable=hotfeed-no-per-pod-python (O(pods) reference gathering for the cached fill; field writes are vectorized inside)
+    for p in batch_pods:
+        if p.pod is None and p.shape is not None:
+            pods.append(p)
+            fps.append(p.shape.fp)
+        else:
+            pods.append(p.ensure_pod() if mutate else p.peek_pod())
+            fps.append(None)
+    return enc.encode_packed(pods, fps)
 
 
 @guarded_by(_req="_lock", _staged="_lock", _closed="_lock")

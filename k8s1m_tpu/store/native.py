@@ -110,6 +110,12 @@ class PodEventBatch:
     aoff: "object"      # u32[n+1] offsets into aux_blob
     key_blob: bytes
     aux_blob: bytes
+    # u32[n]: 0 = no labels and no tolerations, s > 0 = shapes[s - 1].
+    shape: "object" = None
+    # Each distinct (label span, toleration span) of the frame, once:
+    # the bytes between the braces of metadata.labels and between the
+    # brackets of spec.tolerations.
+    shapes: tuple = ()
 
     @staticmethod
     def empty() -> "PodEventBatch":
@@ -119,7 +125,7 @@ class PodEventBatch:
         o = np.zeros(1, np.uint32)
         return PodEventBatch(
             0, False, z, z, np.zeros(0, np.int64), np.zeros(0, np.int32),
-            np.zeros(0, np.int32), o, o, b"", b"",
+            np.zeros(0, np.int32), o, o, b"", b"", np.zeros(0, np.uint32),
         )
 
     @staticmethod
@@ -135,14 +141,24 @@ class PodEventBatch:
         mrev = np.frombuffer(data, np.int64, n, off); off += 8 * n
         cpu = np.frombuffer(data, np.int32, n, off); off += 4 * n
         mem = np.frombuffer(data, np.int32, n, off); off += 4 * n
+        shape = np.frombuffer(data, np.uint32, n, off); off += 4 * n
         koff = np.frombuffer(data, np.uint32, n + 1, off); off += 4 * (n + 1)
         aoff = np.frombuffer(data, np.uint32, n + 1, off); off += 4 * (n + 1)
+        (ns,) = _U32.unpack_from(data, off); off += 4
+        soff = np.frombuffer(data, np.uint32, 2 * ns + 1, off).tolist()
+        off += 4 * (2 * ns + 1)
         klen = int(koff[-1])
         key_blob = data[off : off + klen]; off += klen
-        aux_blob = data[off : off + int(aoff[-1])]
+        alen = int(aoff[-1])
+        aux_blob = data[off : off + alen]; off += alen
+        shapes = tuple(
+            (data[off + soff[2 * s] : off + soff[2 * s + 1]],
+             data[off + soff[2 * s + 1] : off + soff[2 * s + 2]])
+            for s in range(ns)
+        )
         return PodEventBatch(
             int(n), canceled, etype, flags, mrev, cpu, mem, koff, aoff,
-            key_blob, aux_blob,
+            key_blob, aux_blob, shape, shapes,
         )
 
 

@@ -7,8 +7,10 @@ wave/epoch machinery instead of adding a second scheduler:
 
 - A pod declares its gang with labels ``k8s1m.io/gang=<name>`` and
   ``k8s1m.io/gang-size=<N>`` (namespace-qualified id, so tenants never
-  collide).  Gang pods carry labels, so they always take the full
-  decode path — the label-less native fast lane is untouched.
+  collide).  The native intake lane knows from a pod's interned
+  shape whether its labels name a gang, and hands such a pod to
+  staging with a decoded PodInfo; every other pod stays on the fast
+  lane.
 - Members **stage** until all N are present, then enter the queue
   contiguously; ``_take_batch`` never splits a gang across a batch
   boundary, so the whole gang rides ONE device wave (N must fit the

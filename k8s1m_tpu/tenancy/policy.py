@@ -10,9 +10,10 @@ answer:
 
 - **tenant identity** — a pod's tenant is its namespace, unless the
   ``k8s1m.io/tenant`` label overrides it (the multi-namespace-tenant
-  shape real multi-tenancy layers use).  Identity is derivable from the
-  pod key alone for label-less fast-lane pods, so the hot intake path
-  never decodes an object to find its tenant.
+  shape real multi-tenancy layers use).  For a native fast-lane pod the
+  override is part of its interned shape (decoded once per pod
+  template) and without one the pod key alone gives the tenant, so the
+  hot intake path never decodes an object to find its tenant.
 - **weights** — ``TenancyPolicy.weights`` maps tenant -> integer weight;
   unknown tenants get ``default_weight``.  A tenant's *fair share* of
   any contended capacity is ``weight / sum(weights of active tenants)``
@@ -35,23 +36,24 @@ import dataclasses
 import json
 from typing import Mapping
 
-# Label keys (pod metadata.labels).  A pod carrying any of these falls
-# off the native label-less fast lane into the full decode path — which
-# is exactly where gang/priority handling lives, so the fast lane stays
-# fast for the plain-pod firehose.
+# Label keys (pod metadata.labels).  The native fast lane reads them
+# from a pod's interned shape (Coordinator PodShape.tenant / .gang); a
+# gang pod is then decoded for staging, where gang handling lives.
 TENANT_LABEL = "k8s1m.io/tenant"
 GANG_LABEL = "k8s1m.io/gang"
 GANG_SIZE_LABEL = "k8s1m.io/gang-size"
 
 
+def tenant_override(labels: Mapping[str, str] | None) -> str | None:
+    """The tenant the ``k8s1m.io/tenant`` label names, or None where the
+    namespace decides (no labels, no such label, an empty value)."""
+    return (labels.get(TENANT_LABEL) or None) if labels else None
+
+
 def tenant_of_namespace(namespace: str, labels: Mapping[str, str] | None = None) -> str:
     """Tenant identity: the ``k8s1m.io/tenant`` label when present, else
     the namespace (the common one-namespace-per-tenant shape)."""
-    if labels:
-        t = labels.get(TENANT_LABEL)
-        if t:
-            return t
-    return namespace or "default"
+    return tenant_override(labels) or namespace or "default"
 
 
 def tenant_of_obj(obj: dict) -> str:
@@ -67,8 +69,8 @@ def tenant_of_pod(pod) -> str:
 
 
 def tenant_of_key(key_str: str) -> str:
-    """Tenant of a ``<ns>/<name>`` pod key — the fast-lane form (label-
-    less by construction, so the namespace IS the tenant)."""
+    """Tenant of a ``<ns>/<name>`` pod key — the fast-lane form for a
+    pod whose labels name no tenant (the namespace IS the tenant)."""
     ns, _, _ = key_str.partition("/")
     return ns or "default"
 

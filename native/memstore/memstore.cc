@@ -1208,24 +1208,36 @@ int ms_watch_poll(ms_store* s, int64_t watcher_id, int max_events,
 namespace {
 
 // ---- canonical pod fast parser -------------------------------------------
-// The exact byte landmarks of this framework's encode_pod for label-less
-// pods (k8s1m_tpu/control/objects.py decode_pod_fast is the Python twin;
-// the two parsers accept the same inputs so the fast lane and the fallback
-// path can never disagree).  Anything else — labels, selectors, escapes —
-// is left for the caller's full JSON parser.
+// The exact byte landmarks of this framework's encode_pod for pods whose
+// only free parts are a flat label map and a toleration list
+// (k8s1m_tpu/control/objects.py decode_pod_fast is the Python twin; the two
+// parsers accept the same inputs so the fast lane and the fallback path can
+// never disagree).  Labels and tolerations are not interpreted here: the
+// parser proves their grammar and hands back their byte spans, which the
+// frame carries once per distinct pair (a "shape").  Anything else —
+// selectors, affinity, priority, escapes — is left for the caller's full
+// JSON parser.
 constexpr char kPodHead[] =
     "{\"apiVersion\":\"v1\",\"kind\":\"Pod\",\"metadata\":{\"name\":\"";
 constexpr char kPodNs[] = "\",\"namespace\":\"";
-constexpr char kPodLabels[] = "\",\"labels\":{}},\"spec\":{";
+constexpr char kPodLabels[] = "\",\"labels\":{";
+constexpr char kPodSpec[] = "},\"spec\":{";
 constexpr char kPodNode[] = "\"nodeName\":\"";
 constexpr char kPodSched[] = "\"schedulerName\":\"";
 constexpr char kPodContainers[] =
     "\",\"containers\":[{\"name\":\"app\",\"image\":\"img\","
     "\"resources\":{\"requests\":{\"cpu\":\"";
 constexpr char kPodMem[] = "\",\"memory\":\"";
-constexpr char kPodTail[] = "\"}}}]},\"status\":{\"phase\":\"Pending\"}}";
-constexpr char kPodNodeTail[] = "\"}}}],\"nodeName\":\"";
-constexpr char kPodStatus[] = "\"},\"status\":{\"phase\":\"Pending\"}}";
+constexpr char kPodCtrEnd[] = "\"}}}]";
+// encode_pod appends nodeName after containers (dict insertion order); the
+// bind splice inserts it before schedulerName.  Both are accepted.
+constexpr char kPodNodeApp[] = ",\"nodeName\":\"";
+constexpr char kPodTols[] = ",\"tolerations\":[";
+constexpr char kPodEnd[] = "},\"status\":{\"phase\":\"Pending\"}}";
+constexpr char kTolKey[] = "\"key\":\"";
+constexpr char kTolOp[] = "\"operator\":\"";
+constexpr char kTolValue[] = ",\"value\":\"";
+constexpr char kTolEffect[] = ",\"effect\":\"";
 
 struct PodParse {
   bool has_node = false;
@@ -1233,6 +1245,12 @@ struct PodParse {
   int32_t cpu = 0, mem = 0;
   const char* node = nullptr;
   size_t node_len = 0;
+  // Contents of the label map's braces and of the toleration list's
+  // brackets (both empty for the bare pod).
+  const char* labels = "";
+  size_t labels_len = 0;
+  const char* tols = "";
+  size_t tols_len = 0;
 };
 
 inline bool lit_at(std::string_view v, size_t pos, const char* lit,
@@ -1256,9 +1274,73 @@ bool parse_qty(const char* p, size_t n, const char* suffix, size_t suffix_len,
   return true;
 }
 
+#define LIT(name) name, sizeof(name) - 1
+
+// A flat {"k":"v",...} map of plain strings, from just past its opening
+// brace; *i ends just past the closing brace (objects.py _scan_labels).
+// The value holds no backslash, so a string ends at the next quote.
+bool scan_labels(std::string_view v, size_t* i) {
+  size_t p = *i;
+  if (p < v.size() && v[p] == '}') {
+    *i = p + 1;
+    return true;
+  }
+  for (;;) {
+    if (p >= v.size() || v[p] != '"') return false;
+    size_t j = v.find('"', p + 1);
+    if (j == std::string::npos || !lit_at(v, j, "\":\"", 3)) return false;
+    j = v.find('"', j + 3);
+    if (j == std::string::npos || j + 1 >= v.size()) return false;
+    p = j + 2;
+    if (v[j + 1] == ',') continue;
+    if (v[j + 1] != '}') return false;
+    *i = p;
+    return true;
+  }
+}
+
+// One or more toleration objects exactly as encode_pod writes them
+// (optional key, operator Exists|Equal, optional value, optional effect,
+// in that order), from just past the opening bracket; *i ends just past
+// the closing one.
+bool scan_tolerations(std::string_view v, size_t* i) {
+  size_t p = *i;
+  for (;;) {
+    if (p >= v.size() || v[p] != '{') return false;
+    p++;
+    if (lit_at(v, p, LIT(kTolKey))) {
+      size_t j = v.find('"', p + sizeof(kTolKey) - 1);
+      if (j == std::string::npos || !lit_at(v, j, "\",", 2)) return false;
+      p = j + 2;
+    }
+    if (!lit_at(v, p, LIT(kTolOp))) return false;
+    p += sizeof(kTolOp) - 1;
+    if (lit_at(v, p, "Exists\"", 7)) p += 7;
+    else if (lit_at(v, p, "Equal\"", 6)) p += 6;
+    else return false;
+    if (lit_at(v, p, LIT(kTolValue))) {
+      size_t j = v.find('"', p + sizeof(kTolValue) - 1);
+      if (j == std::string::npos) return false;
+      p = j + 1;
+    }
+    if (lit_at(v, p, LIT(kTolEffect))) {
+      p += sizeof(kTolEffect) - 1;
+      if (lit_at(v, p, "NoSchedule\"", 11)) p += 11;
+      else if (lit_at(v, p, "PreferNoSchedule\"", 17)) p += 17;
+      else if (lit_at(v, p, "NoExecute\"", 10)) p += 10;
+      else return false;
+    }
+    if (p + 1 >= v.size() || v[p] != '}') return false;
+    p += 2;
+    if (v[p - 1] == ',') continue;
+    if (v[p - 1] != ']') return false;
+    *i = p;
+    return true;
+  }
+}
+
 bool parse_pod(std::string_view v, const uint8_t* sched, size_t sched_len,
                PodParse* out) {
-#define LIT(name) name, sizeof(name) - 1
   if (!lit_at(v, 0, LIT(kPodHead))) return false;
   if (memchr(v.data(), '\\', v.size()) != nullptr) return false;
   size_t i = sizeof(kPodHead) - 1;
@@ -1268,6 +1350,13 @@ bool parse_pod(std::string_view v, const uint8_t* sched, size_t sched_len,
   j = v.find('"', i);
   if (j == std::string::npos || !lit_at(v, j, LIT(kPodLabels))) return false;
   i = j + sizeof(kPodLabels) - 1;
+  out->labels = v.data() + i;
+  if (!scan_labels(v, &i)) return false;
+  out->labels_len = static_cast<size_t>(v.data() + i - 1 - out->labels);
+  // scan_labels consumed the map's own brace; kPodSpec opens with
+  // metadata's.
+  if (!lit_at(v, i, LIT(kPodSpec))) return false;
+  i += sizeof(kPodSpec) - 1;
   if (lit_at(v, i, LIT(kPodNode))) {
     i += sizeof(kPodNode) - 1;
     j = v.find('"', i);
@@ -1293,21 +1382,30 @@ bool parse_pod(std::string_view v, const uint8_t* sched, size_t sched_len,
   j = v.find('"', i);
   if (j == std::string::npos || !parse_qty(v.data() + i, j - i, "Ki", 2, &out->mem))
     return false;
-  if (v.size() - j == sizeof(kPodTail) - 1 && lit_at(v, j, LIT(kPodTail)))
-    return true;
-  // Bind-spliced form appends nodeName after containers instead.
-  if (out->has_node || !lit_at(v, j, LIT(kPodNodeTail))) return false;
-  i = j + sizeof(kPodNodeTail) - 1;
-  j = v.find('"', i);
-  if (j == std::string::npos) return false;
-  if (v.size() - j != sizeof(kPodStatus) - 1 || !lit_at(v, j, LIT(kPodStatus)))
-    return false;
-  out->has_node = true;
-  out->node = v.data() + i;
-  out->node_len = j - i;
-  return true;
-#undef LIT
+  if (!lit_at(v, j, LIT(kPodCtrEnd))) return false;
+  i = j + sizeof(kPodCtrEnd) - 1;
+  if (lit_at(v, i, LIT(kPodNodeApp))) {
+    if (out->has_node) return false;
+    i += sizeof(kPodNodeApp) - 1;
+    j = v.find('"', i);
+    if (j == std::string::npos) return false;
+    out->has_node = true;
+    out->node = v.data() + i;
+    out->node_len = j - i;
+    i = j + 1;
+  }
+  if (lit_at(v, i, LIT(kPodTols))) {
+    i += sizeof(kPodTols) - 1;
+    out->tols = v.data() + i;
+    if (!scan_tolerations(v, &i)) return false;
+    out->tols_len = static_cast<size_t>(v.data() + i - 1 - out->tols);
+  }
+  // The exact remainder: proves there is no nodeSelector, affinity,
+  // topologySpreadConstraints or priority.
+  return v.size() - i == sizeof(kPodEnd) - 1 && lit_at(v, i, LIT(kPodEnd));
 }
+
+#undef LIT
 
 // One event's raw view for the columnar pod-frame emitter (val == null
 // or vlen == 0 with etype DELETE means no value).
@@ -1329,8 +1427,15 @@ uint8_t* emit_pod_frame(size_t n, bool canceled, const uint8_t* sched,
   std::vector<uint8_t> etype(n), flags(n);
   std::vector<int64_t> mrev(n);
   std::vector<int32_t> cpu(n, 0), mem(n, 0);
-  std::vector<uint32_t> koff(n + 1, 0), aoff(n + 1, 0);
+  std::vector<uint32_t> shape(n, 0), koff(n + 1, 0), aoff(n + 1, 0);
   std::string keys, aux;
+  // The frame's shape table: each distinct (label span, toleration span)
+  // pair once.  A wave of one template hits `last` every time; the map
+  // is only consulted when the shape changes.
+  std::string shapes;
+  std::vector<uint32_t> soff(1, 0);
+  std::unordered_map<std::string, uint32_t> shape_of;
+  uint32_t last = 0;
   for (size_t i = 0; i < n; i++) {
     PodEventView ev = get(i);
     etype[i] = ev.etype;
@@ -1350,6 +1455,28 @@ uint8_t* emit_pod_frame(size_t n, bool canceled, const uint8_t* sched,
         }
         cpu[i] = p.cpu;
         mem[i] = p.mem;
+        if (p.labels_len || p.tols_len) {
+          const uint32_t* lo = last ? &soff[2 * (last - 1)] : nullptr;
+          if (lo == nullptr || lo[1] - lo[0] != p.labels_len ||
+              lo[2] - lo[1] != p.tols_len ||
+              memcmp(shapes.data() + lo[0], p.labels, p.labels_len) != 0 ||
+              memcmp(shapes.data() + lo[1], p.tols, p.tols_len) != 0) {
+            uint32_t llen = static_cast<uint32_t>(p.labels_len);
+            std::string k(reinterpret_cast<const char*>(&llen), 4);
+            k.append(p.labels, p.labels_len);
+            k.append(p.tols, p.tols_len);
+            auto ins = shape_of.emplace(
+                std::move(k), static_cast<uint32_t>(soff.size() / 2 + 1));
+            if (ins.second) {
+              shapes.append(p.labels, p.labels_len);
+              soff.push_back(static_cast<uint32_t>(shapes.size()));
+              shapes.append(p.tols, p.tols_len);
+              soff.push_back(static_cast<uint32_t>(shapes.size()));
+            }
+            last = ins.first->second;
+          }
+          shape[i] = last;
+        }
       } else {
         aux.append(value);
       }
@@ -1359,7 +1486,8 @@ uint8_t* emit_pod_frame(size_t n, bool canceled, const uint8_t* sched,
   }
 
   std::string b;
-  b.reserve(8 + 2 * n + 8 + 16 * n + 8 * (n + 1) + keys.size() + aux.size());
+  b.reserve(8 + 2 * n + 8 + 20 * n + 8 * (n + 1) + 4 + 4 * soff.size() +
+            keys.size() + aux.size() + shapes.size());
   put_u32(b, static_cast<uint32_t>(n));
   put_u8(b, canceled ? 1 : 0);
   b.append(3, '\0');
@@ -1369,10 +1497,14 @@ uint8_t* emit_pod_frame(size_t n, bool canceled, const uint8_t* sched,
   b.append(reinterpret_cast<const char*>(mrev.data()), 8 * n);
   b.append(reinterpret_cast<const char*>(cpu.data()), 4 * n);
   b.append(reinterpret_cast<const char*>(mem.data()), 4 * n);
+  b.append(reinterpret_cast<const char*>(shape.data()), 4 * n);
   b.append(reinterpret_cast<const char*>(koff.data()), 4 * (n + 1));
   b.append(reinterpret_cast<const char*>(aoff.data()), 4 * (n + 1));
+  put_u32(b, static_cast<uint32_t>(soff.size() / 2));
+  b.append(reinterpret_cast<const char*>(soff.data()), 4 * soff.size());
   b.append(keys);
   b.append(aux);
+  b.append(shapes);
   return to_malloc(b, out_len);
 }
 

@@ -188,16 +188,19 @@ def test_a_canonical_wave_takes_the_batch_fast_lane(traced):
         {"batch_fast": WAVE}
 
 
-def test_a_make_pods_wave_takes_the_json_lane(traced):
+def test_a_make_pods_wave_takes_the_batch_fast_lane(traced):
+    """``make_pods``' pod (a label, a toleration) is parsed natively: no
+    pod of it reaches json.loads."""
     lanes = traced["lanes"]
     assert _grown(lanes["canonical_wave"], lanes["make_pods_waves"]) == \
-        {"json": 2 * WAVE}
+        {"batch_fast": 2 * WAVE}
 
 
 def test_mixed_polls_count_every_event_once():
     """A poll that holds a delete is applied event by event: the canonical
-    put, the delete and the label-bearing put each count in their lane;
-    a pod listed at bootstrap counts as the watch would have."""
+    puts (one with a label), the delete and the put with a priority each
+    count in their lane; a pod listed at bootstrap counts as the watch
+    would have."""
     with MemStore() as store:
         for i in range(8):
             store.put(node_key(f"kwok-node-{i}"), encode_node(build_node(i)))
@@ -212,10 +215,12 @@ def test_mixed_polls_count_every_event_once():
             store.delete(pod_key("default", "a"))
             _put(store, [PodInfo("b", cpu_milli=10, mem_kib=1024),
                          PodInfo("c", cpu_milli=10, mem_kib=1024,
-                                 labels={"app": "x"})])
+                                 labels={"app": "x"}),
+                         PodInfo("d", cpu_milli=10, mem_kib=1024,
+                                 priority=5)])
             coord.drain_watches()
             assert _grown(before, _lanes()) == \
-                {"canonical": 2, "delete": 1, "decode_fast": 1}
+                {"canonical": 3, "delete": 1, "json": 1}
         finally:
             coord.close()
 

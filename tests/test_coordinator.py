@@ -323,9 +323,9 @@ def test_pipelined_matches_unpipelined_accounting(store):
 
 
 def test_fast_lane_pending_pods_have_no_podinfo(store):
-    """Canonical label-less pods ride the native intake: the coordinator
-    queues them without materializing PodInfo, and scheduling still binds
-    them correctly."""
+    """Canonical pods ride the native intake: the coordinator queues them
+    without materializing PodInfo, and scheduling still binds them
+    correctly."""
     for i in range(4):
         put_node(store, f"n{i}")
     c = make_coord(store)

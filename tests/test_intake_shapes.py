@@ -1,0 +1,547 @@
+"""Interned pod shapes: the native intake lane against the JSON lane.
+
+``make_pods``' pod carries a label and a toleration.  The native parser
+(native/memstore parse_pod) proves their grammar and hands back their
+bytes once per distinct pair; the coordinator decodes each pair once
+(``PodShape``) and queues ``PendingPod(None, ..., shape=...)`` records.
+The lane must be invisible: the same pods through a coordinator whose
+watcher has no ``poll_pods`` (every event through ``_on_pod_put``, the
+lane the shaped one is held to) give the same queue, the same packed
+batches byte for byte, the same binds and the same accounting.
+"""
+
+import dataclasses
+import importlib.util
+import os
+import sys
+
+import numpy as np
+import pytest
+
+from k8s1m_tpu.config import EFFECT_NO_SCHEDULE, TOPO_ZONE, PodSpec, TableSpec
+from k8s1m_tpu.control import coordinator as coordinator_mod
+from k8s1m_tpu.control.coordinator import Coordinator
+from k8s1m_tpu.control.objects import (
+    encode_node,
+    encode_pod,
+    node_key,
+    pod_key,
+)
+from k8s1m_tpu.obs.metrics import REGISTRY
+from k8s1m_tpu.plugins.registry import Profile
+from k8s1m_tpu.snapshot.node_table import Taint
+from k8s1m_tpu.snapshot.pod_encoding import PodInfo, Toleration
+from k8s1m_tpu.store.native import MemStore
+from k8s1m_tpu.tools.make_nodes import build_node
+from k8s1m_tpu.tools.make_pods import build_pod
+
+NODES = 1000
+WAVE = 128
+SPEC = TableSpec(max_nodes=1024)
+PODS = PodSpec(batch=WAVE)
+PLAIN_PROFILE = Profile(
+    node_affinity=0, topology_spread=0, interpod_affinity=0
+)
+KWOK_TAINT = Taint("kwok.x-k8s.io/node", "", EFFECT_NO_SCHEDULE)
+
+
+class _EventWatch:
+    """The coordinator's own pod watcher with ``poll_pods`` taken away:
+    a third-party watcher's shape, so every event goes through
+    ``_on_pod_put``."""
+
+    def __init__(self, watcher) -> None:
+        self._w = watcher
+        self.id = watcher.id
+
+    def poll_light(self, batch):
+        return self._w.poll_light(batch)
+
+    @property
+    def dropped(self):
+        return self._w.dropped
+
+    @property
+    def canceled(self):
+        return self._w.canceled
+
+    def cancel(self) -> None:
+        self._w.cancel()
+
+
+class _Lane:
+    """One store, one coordinator, and a record of what it launched."""
+
+    def __init__(self, native: bool, *, taint=None, nodes=NODES, **kw) -> None:
+        self.store = MemStore()
+        for i in range(nodes):
+            node = build_node(i)
+            if taint is not None:
+                node.taints = [taint]
+            self.store.put(node_key(node.name), encode_node(node))
+        kw.setdefault("with_constraints", False)
+        kw.setdefault("profile", PLAIN_PROFILE)
+        profile = kw.pop("profile")
+        self.coord = Coordinator(
+            self.store, SPEC, PODS, profile, chunk=256, pipeline=True,
+            depth=2, packing="packed", score_pct=50, seed=11, **kw,
+        )
+        self.waves: list = []
+        launch = self.coord._launch
+
+        def spy(batch_pods, batch):
+            self.waves.append((
+                [p.key_str for p in batch_pods],
+                [Coordinator._delta_key(p) for p in batch_pods],
+                batch.ints.copy(), batch.bools.copy(), batch.groups,
+            ))
+            return launch(batch_pods, batch)
+
+        self.coord._launch = spy
+        self.native = native
+
+    def bootstrap(self) -> None:
+        self.coord.bootstrap()
+        if not self.native:
+            self.coord._pods_watch = _EventWatch(self.coord._pods_watch)
+
+    def put(self, pods) -> None:
+        self.store.put_batch(
+            [(pod_key(p.namespace, p.name), encode_pod(p)) for p in pods]
+        )
+
+    def close(self) -> None:
+        self.coord.close()
+        self.store.close()
+
+
+@pytest.fixture()
+def lanes(request):
+    made: list[_Lane] = []
+
+    def make(**kw):
+        pair = (_Lane(True, **kw), _Lane(False, **kw))
+        made.extend(pair)
+        return pair
+
+    yield make
+    for lane in made:
+        lane.close()
+
+
+def _waves(seed: int, n_waves: int) -> list[list[PodInfo]]:
+    """Seeded waves of ``build_pod`` pods in three request sizes, with a
+    bare label-less pod every 16th (the empty shape in a shaped frame)."""
+    rng = np.random.default_rng(seed)
+    sizes = [(100, 200 << 10), (250, 512 << 10), (50, 64 << 10)]
+    out, i = [], 0
+    for _ in range(n_waves):
+        wave = []
+        for _ in range(WAVE):
+            cpu, mem = sizes[int(rng.integers(len(sizes)))]
+            if i % 16 == 15:
+                wave.append(PodInfo(f"bare-{i}", namespace="bench",
+                                    cpu_milli=cpu, mem_kib=mem))
+            else:
+                wave.append(build_pod(i, namespace="bench", cpu_milli=cpu,
+                                      mem_kib=mem))
+            i += 1
+        out.append(wave)
+    return out
+
+
+def _queue_view(coord) -> list:
+    return [
+        (p.key_str, p.cpu_milli, p.mem_kib, p.mod_revision, p.priority,
+         p.gang_id, p.gang_size, dataclasses.asdict(p.peek_pod()))
+        for p in coord.queue
+    ]
+
+
+def _assert_waves_equal(a: _Lane, b: _Lane, first: int | None = None) -> None:
+    """Every launched wave (or the ``first`` ones: retries leave their
+    backoff by the clock, whatever the lane) equal in pods, delta keys,
+    groups and packed bytes."""
+    assert a.waves and b.waves
+    if first is None:
+        assert len(a.waves) == len(b.waves)
+    pairs = list(zip(a.waves, b.waves))[:first]
+    for (ka, da, ia, ba, ga), (kb, db, ib, bb, gb) in pairs:
+        assert ka == kb
+        assert da == db
+        assert ga == gb
+        assert ia.tobytes() == ib.tobytes()
+        assert ba.tobytes() == bb.tobytes()
+
+
+def _assert_accounting_equal(a: Coordinator, b: Coordinator) -> None:
+    assert a._bound == b._bound
+    assert a._bind_meta == b._bind_meta
+    assert set(a.unschedulable) == set(b.unschedulable)
+    np.testing.assert_array_equal(a.host.cpu_req, b.host.cpu_req)
+    np.testing.assert_array_equal(a.host.mem_req, b.host.mem_req)
+    np.testing.assert_array_equal(a.host.pods_req, b.host.pods_req)
+
+
+def test_shaped_lane_equals_json_lane(lanes):
+    """Same seeded waves, both lanes: equal queue order, equal
+    ``peek_pod`` of every record, byte-equal packed batches, equal
+    ``_delta_key``, equal binds, equal ``_bound`` and ``_bind_meta``."""
+    shaped, legacy = lanes()
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+    bound = [0, 0]
+    for wave in _waves(5, 4):
+        for lane in (shaped, legacy):
+            lane.put(wave)
+            lane.coord.drain_watches()
+        assert _queue_view(shaped.coord) == _queue_view(legacy.coord)
+        # The shaped lane built no PodInfo; the JSON lane one per pod.
+        assert all(p.pod is None for p in shaped.coord.queue)
+        assert all(p.pod is not None for p in legacy.coord.queue)
+        assert sum(p.shape is not None for p in shaped.coord.queue) == \
+            WAVE - WAVE // 16
+        for k, lane in enumerate((shaped, legacy)):
+            bound[k] += lane.coord.run_until_idle()
+    assert bound == [4 * WAVE, 4 * WAVE]
+    _assert_waves_equal(shaped, legacy)
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    # One template, decoded once, whatever the number of pods.
+    assert len(shaped.coord._pod_shapes) == 1
+    # The tenant of a bound pod is its namespace on both lanes.
+    assert {m[2] for m in shaped.coord._bind_meta.values()} == {"bench"}
+
+
+def test_tenant_label_reaches_bind_meta_on_both_lanes(lanes):
+    shaped, legacy = lanes(nodes=64)
+    pods = [
+        PodInfo(f"t-{i}", namespace="ns", cpu_milli=10, mem_kib=1024,
+                labels={"k8s1m.io/tenant": f"team-{i % 2}", "app": "x"})
+        for i in range(8)
+    ]
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+        lane.put(pods)
+        assert lane.coord.run_until_idle() == 8
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    assert {m[2] for m in shaped.coord._bind_meta.values()} == \
+        {"team-0", "team-1"}
+
+
+@pytest.mark.parametrize("tolerate", [True, False])
+def test_parsed_toleration_decides_a_bind_on_tainted_nodes(lanes, tolerate):
+    """Every node carries kwok.x-k8s.io/node:NoSchedule, so the taint
+    filter decides: the shaped pod binds, the same pod without its
+    toleration is unschedulable, on both lanes alike."""
+    shaped, legacy = lanes(taint=KWOK_TAINT, nodes=64, max_attempts=2)
+    pods = [
+        build_pod(i, cpu_milli=10, mem_kib=1024, tolerate_kwok=tolerate)
+        for i in range(16)
+    ]
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+        lane.put(pods)
+        lane.coord.drain_watches()
+    assert _queue_view(shaped.coord) == _queue_view(legacy.coord)
+    for lane in (shaped, legacy):
+        assert lane.coord.run_until_idle() == (16 if tolerate else 0)
+        assert len(lane.coord.unschedulable) == (0 if tolerate else 16)
+        assert ("tol" in lane.waves[0][4]) == tolerate
+    _assert_waves_equal(shaped, legacy, first=None if tolerate else 1)
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+
+
+def test_spread_constraint_matches_shaped_labels_on_both_lanes(lanes):
+    """With a spread constraint interned whose selector matches
+    app=bench-pod, shaped pods take the per-event branch and get the
+    tracker's matches per pod, as decode_pod_fast sets them."""
+    shaped, legacy = lanes(
+        with_constraints=True, profile=Profile(interpod_affinity=0),
+        nodes=64,
+    )
+    pods = [build_pod(i, cpu_milli=10, mem_kib=1024) for i in range(12)]
+    pods += [PodInfo(f"other-{i}", cpu_milli=10, mem_kib=1024,
+                     labels={"app": "other"}) for i in range(4)]
+    slots = []
+    for lane in (shaped, legacy):
+        slots.append(lane.coord.tracker.spread_slot(
+            "default", {"app": "bench-pod"}, TOPO_ZONE))
+        lane.bootstrap()
+        lane.put(pods)
+        lane.coord.drain_watches()
+    assert slots[0] == slots[1]
+    assert _queue_view(shaped.coord) == _queue_view(legacy.coord)
+    incs = [p.peek_pod().spread_incs for p in shaped.coord.queue]
+    assert incs == [[(slots[0], TOPO_ZONE)]] * 12 + [[]] * 4
+    # A record holds a PodInfo only where the tracker matched its labels.
+    assert [p.pod is not None for p in shaped.coord.queue] == \
+        [True] * 12 + [False] * 4
+    for lane in (shaped, legacy):
+        assert lane.coord.run_until_idle() == 16
+    _assert_waves_equal(shaped, legacy)
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    assert sum(b[5] is not None for b in shaped.coord._bound.values()) == 12
+
+
+def test_gang_labels_are_staged_not_queued_on_both_lanes(lanes):
+    from k8s1m_tpu.loadshed import LoadshedConfig
+    from k8s1m_tpu.tenancy import TenancyController, TenancyPolicy
+
+    def tenancy(name):
+        return TenancyController(
+            TenancyPolicy(weights={"default": 1}),
+            loadshed_config=LoadshedConfig(queue_cap=1 << 16), name=name,
+        )
+
+    shaped = _Lane(True, nodes=64, tenancy=tenancy("shapes-gang-native"))
+    legacy = _Lane(False, nodes=64, tenancy=tenancy("shapes-gang-events"))
+    try:
+        gang = [
+            PodInfo(f"g-{m}", cpu_milli=10, mem_kib=1024,
+                    labels={"k8s1m.io/gang": "g", "k8s1m.io/gang-size": "4"},
+                    tolerations=[Toleration(key="kwok.x-k8s.io/node")])
+            for m in range(4)
+        ]
+        loner = build_pod(0, cpu_milli=10, mem_kib=1024)
+        for lane in (shaped, legacy):
+            lane.bootstrap()
+            lane.put(gang[:3] + [loner])
+            lane.coord.drain_watches()
+            # Three of four members wait in staging; only the loner queues.
+            assert [p.key_str for p in lane.coord.queue] == \
+                ["default/bench-pod-0"]
+            assert set(lane.coord._gang_staging["default/g"][1]) == \
+                {f"default/g-{m}" for m in range(3)}
+            lane.put(gang[3:])
+            lane.coord.drain_watches()
+            assert not lane.coord._gang_staging
+        assert _queue_view(shaped.coord) == _queue_view(legacy.coord)
+        assert [(p.gang_id, p.gang_size) for p in shaped.coord.queue] == \
+            [("", 0)] + [("default/g", 4)] * 4
+        for lane in (shaped, legacy):
+            assert lane.coord.run_until_idle() == 5
+        _assert_waves_equal(shaped, legacy)
+        _assert_accounting_equal(shaped.coord, legacy.coord)
+    finally:
+        shaped.close()
+        legacy.close()
+
+
+def test_external_bind_of_a_shaped_pod_is_accounted_with_its_labels(lanes):
+    """An external writer's bind (POD_HAS_NODE) of a labelled pod is
+    accounted with its shape's labels: tenant and gang as the JSON lane
+    reads them."""
+    shaped, legacy = lanes(nodes=8)
+    pod = PodInfo("ext", cpu_milli=70, mem_kib=512, node_name="kwok-node-3",
+                  labels={"k8s1m.io/tenant": "team-x"},
+                  tolerations=[Toleration(key="kwok.x-k8s.io/node")])
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+        lane.put([pod])
+        lane.coord.drain_watches()
+        assert not lane.coord.queue
+        assert lane.coord._bound["default/ext"][0] == "kwok-node-3"
+        assert lane.coord._bind_meta["default/ext"][2] == "team-x"
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+
+
+# ---- the counters that say the lane engaged --------------------------
+
+
+def _counts() -> dict:
+    lanes_c = REGISTRY.get("coordinator_pod_intake_total")
+    shapes_c = REGISTRY.get("coordinator_pod_shapes_total")
+    out = {k[0]: lanes_c.value(lane=k[0]) for k in lanes_c.label_keys()}
+    out.update(
+        {k[0]: shapes_c.value(event=k[0]) for k in shapes_c.label_keys()}
+    )
+    return out
+
+
+def _grown(before: dict, after: dict) -> dict:
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)}
+
+
+def test_a_build_pod_wave_counts_batch_fast_and_one_interned():
+    lane = _Lane(True, nodes=8)
+    try:
+        lane.bootstrap()
+        before = _counts()
+        for lo in (0, WAVE):
+            lane.put([build_pod(i) for i in range(lo, lo + WAVE)])
+            lane.coord.drain_watches()
+        assert _grown(before, _counts()) == \
+            {"batch_fast": 2 * WAVE, "interned": 1}
+    finally:
+        lane.close()
+
+
+def test_a_key_that_is_not_ascii_is_still_keyed_right():
+    """The pure-create loop decodes a frame's keys in one piece when they
+    are ASCII and one by one when not; either way a record's key is its
+    store key."""
+    lane = _Lane(True, nodes=8)
+    try:
+        lane.bootstrap()
+        odd = pod_key("default", "p\u00f8d")
+        lane.store.put_batch([
+            (pod_key("default", "before"), encode_pod(build_pod(1))),
+            (odd, encode_pod(build_pod(2))),
+            (pod_key("default", "after"), encode_pod(PodInfo("after"))),
+        ])
+        lane.coord.drain_watches()
+        assert [(p.key_str, p.key_bytes) for p in lane.coord.queue] == [
+            ("default/before", pod_key("default", "before")),
+            ("default/p\u00f8d", odd),
+            ("default/after", pod_key("default", "after")),
+        ]
+        assert lane.coord.run_until_idle() == 3
+    finally:
+        lane.close()
+
+
+def test_a_mixed_frame_counts_every_event_in_its_lane():
+    """Three shapes, one bare pod, one pod with a priority and one
+    delete in one frame."""
+    lane = _Lane(True, nodes=8)
+    try:
+        lane.bootstrap()
+        lane.put([PodInfo("gone")])
+        lane.coord.drain_watches()
+        before = _counts()
+        lane.store.put_batch([
+            (pod_key("default", "a"),
+             encode_pod(build_pod(1, prefix="a")).replace(b"a-1", b"a")),
+            (pod_key("default", "b"),
+             encode_pod(PodInfo("b", labels={"app": "web"}))),
+            (pod_key("default", "c"),
+             encode_pod(PodInfo("c", tolerations=[Toleration(key="k")]))),
+            (pod_key("default", "d"), encode_pod(PodInfo("d"))),
+            (pod_key("default", "e"), encode_pod(PodInfo("e", priority=9))),
+            (pod_key("default", "gone"), None),
+        ])
+        lane.coord.drain_watches()
+        assert _grown(before, _counts()) == \
+            {"canonical": 4, "json": 1, "delete": 1, "interned": 3}
+        # (the deleted pod's record stays in the deque, its key does not)
+        assert lane.coord._queued_keys == {f"default/{n}" for n in "abcde"}
+        queued = {p.key_str: p for p in lane.coord.queue}
+        assert queued["default/e"].priority == 9
+        assert queued["default/d"].shape is None
+        assert queued["default/b"].peek_pod().labels == {"app": "web"}
+        assert queued["default/c"].peek_pod().tolerations == \
+            [Toleration(key="k")]
+    finally:
+        lane.close()
+
+
+def test_more_shapes_than_the_table_holds_evicts_and_still_decodes(
+    monkeypatch,
+):
+    monkeypatch.setattr(coordinator_mod, "POD_SHAPES_MAX", 4)
+    lane = _Lane(True, nodes=8)
+    try:
+        lane.bootstrap()
+        before = _counts()
+        pods = [
+            PodInfo(f"u-{i}", cpu_milli=10, mem_kib=1024,
+                    labels={"app": f"app-{i}"})
+            for i in range(11)
+        ]
+        lane.put(pods)
+        lane.coord.drain_watches()
+        grown = _grown(before, _counts())
+        assert grown == {"batch_fast": 11, "interned": 11, "evicted": 2}
+        assert len(lane.coord._pod_shapes) == 3
+        assert [p.peek_pod().labels for p in lane.coord.queue] == \
+            [p.labels for p in pods]
+        # A second sight of a live template is a hit, of an evicted one a
+        # fresh decode: never a wrong one.
+        lane.put([PodInfo("again-10", labels={"app": "app-10"}),
+                  PodInfo("again-0", labels={"app": "app-0"})])
+        lane.coord.drain_watches()
+        assert _grown(before, _counts())["interned"] == 12
+        assert lane.coord.queue[-1].peek_pod().labels == {"app": "app-0"}
+        assert lane.coord.run_until_idle() == 13
+    finally:
+        lane.close()
+
+
+def test_undecodable_shape_counts_a_decode_error_and_spares_the_rest():
+    """Bytes that are no UTF-8 in a label: the JSON lane counts a decode
+    error and skips the pod; so does the shaped lane, pod by pod."""
+    errors = REGISTRY.get("coordinator_decode_errors_total")
+    bad = encode_pod(PodInfo("bad", labels={"app": "X"})).replace(
+        b'"app":"X"', b'"app":"\xff"')
+    for native in (True, False):
+        lane = _Lane(native, nodes=8)
+        try:
+            lane.bootstrap()
+            before = errors.value(kind="pod")
+            lane.store.put_batch([
+                (pod_key("default", "bad"), bad),
+                (pod_key("default", "good"),
+                 encode_pod(PodInfo("good", labels={"app": "X"}))),
+            ])
+            lane.coord.drain_watches()
+            assert errors.value(kind="pod") - before == 1
+            assert [p.key_str for p in lane.coord.queue] == ["default/good"]
+        finally:
+            lane.close()
+
+
+# ---- the benchmark's own reader of the lanes, at a tiny size ----------
+# (tests/benchmark_cells/test_span_metrics.py holds the same run to the
+# reading of before this lane existed; see tests/conftest.py)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCHMARK_CELLS = ("kwok-1m-pct5.fill", "fit-10k.fill")
+
+
+def _load(name: str, *path: str):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(ROOT, *path))
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("cell", BENCHMARK_CELLS)
+def test_span_report_reads_no_slow_lane_in_a_tiny_run(cell):
+    """The cell's own traffic (``benchmark/generate.py``: every pod is
+    ``encode_pod(build_pod(i))``) through the unedited harness at 1,000
+    nodes: lane ``json`` takes none of the window's pods, the whole
+    window rides ``batch_fast`` on one interned shape."""
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    cells = sys.modules.get("test_benchmark_cells") or _load(
+        "test_benchmark_cells", "tests", "benchmark_cells",
+        "test_benchmark_cells.py")
+    tool = _load("span_report", "tools", "span_report.py")
+    # The harness compares a snapshot of the process's stage sums around a
+    # window that resets them: start from nought, whatever ran before.
+    REGISTRY.get("coordinator_cycle_seconds").reset()
+    shapes = REGISTRY.get("coordinator_pod_shapes_total")
+    interned = shapes.value(event="interned")
+    result = tool.report(
+        cells.MANIFEST, cell, cells._tiny(cell), seed=(1 << 31) + 26,
+        seconds=0.5, trace=False, device=dict(cells.CPU_DEVICE), peaks={},
+    )
+    assert result["correct"] is True
+    got = result["span_metrics"]
+    assert got["intake_slow_lane_pct.fill"]["value"] == 0.0
+    assert got["drain_poll_us_per_bind.fill"]["value"] \
+        + got["drain_apply_us_per_bind.fill"]["value"] > 0
+    counters = tool.SpanCell.last.ctx["counters"]
+    grown = {       # snapshot keys are label tuples: (("lane", <lane>),)
+        key[0][1]:
+            n - counters["open"]["coordinator_pod_intake_total"].get(key, 0)
+        for key, n in
+        counters["close"]["coordinator_pod_intake_total"].items()
+    }
+    assert {lane for lane, n in grown.items() if n} == {"batch_fast"}
+    wave = cells._tiny(cell)[0]["wave"]
+    assert abs(grown["batch_fast"] - result["attempted"]) <= 2 * wave
+    assert shapes.value(event="interned") - interned == 1

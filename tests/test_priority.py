@@ -143,7 +143,7 @@ class TestAdaptiveFloorEdges:
 
 def test_decode_paths_parse_priority():
     """spec.priority round-trips through the JSON codec, and the
-    canonical fast parser stays label-less/priority-less by design."""
+    canonical fast parser stays priority-less by design."""
     from k8s1m_tpu.control.objects import decode_pod, decode_pod_fast, encode_pod
     from k8s1m_tpu.snapshot.pod_encoding import PodInfo
 

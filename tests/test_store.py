@@ -529,7 +529,7 @@ def test_poll_pods_columnar_frame(store):
     w = _pods_watch(store)
     v1 = encode_pod(PodInfo("p1", cpu_milli=250, mem_kib=2048))
     store.put(pod_key("default", "p1"), v1)
-    v2 = encode_pod(PodInfo("p2", labels={"a": "b"}))       # non-canonical
+    v2 = encode_pod(PodInfo("p2", priority=7))               # non-canonical
     store.put(pod_key("default", "p2"), v2)
     v3 = encode_pod(PodInfo("p3", scheduler_name="default-scheduler"))
     store.put(pod_key("default", "p3"), v3)
@@ -553,67 +553,232 @@ def test_poll_pods_columnar_frame(store):
     aux = [evb.aux_blob[evb.aoff[i]: evb.aoff[i + 1]] for i in range(evb.n)]
     assert aux == [b"", v2, b"", b""]
     assert evb.mrev.tolist() == [2, 3, 4, 5]
+    # Neither labels nor tolerations anywhere: no shape table.
+    assert evb.shape.tolist() == [0, 0, 0, 0] and evb.shapes == ()
     assert w.poll_pods(100, b"dist-scheduler").n == 0
 
 
-def test_poll_pods_parses_exactly_what_decode_pod_fast_does(store):
-    """C parser parity: every value decode_pod_fast accepts as a plain
-    label-less pod must be CANONICAL with the same cpu/mem/node, and the
-    shapes it rejects must come back whole for the Python fallback."""
-    from k8s1m_tpu.control.coordinator import splice_node_name
-    from k8s1m_tpu.control.objects import (
-        decode_pod_fast,
-        encode_pod,
-        pod_key,
+def test_poll_pods_shape_table(store):
+    """Labels and tolerations come back as byte spans, each distinct
+    pair once a frame, and every event names its pair by index."""
+    from k8s1m_tpu.control.objects import encode_pod, pod_key
+    from k8s1m_tpu.snapshot.pod_encoding import PodInfo, Toleration
+    from k8s1m_tpu.store.native import POD_CANONICAL, POD_SCHED_MATCH
+    from k8s1m_tpu.tools.make_pods import build_pod
+
+    w = _pods_watch(store)
+    pods = [
+        build_pod(0), PodInfo("bare"), build_pod(1),
+        PodInfo("l", labels={"a": "b", "c": "d"}),
+        PodInfo("t", tolerations=[Toleration(key="k")]),
+        build_pod(2), PodInfo("l2", labels={"a": "b", "c": "d"}),
+    ]
+    for p in pods:
+        store.put(pod_key("default", p.name), encode_pod(p))
+    evb = w.poll_pods(100, b"dist-scheduler")
+    assert evb.flags.tolist() == [POD_CANONICAL | POD_SCHED_MATCH] * 7
+    assert evb.shape.tolist() == [1, 0, 1, 2, 3, 1, 2]
+    assert evb.shapes == (
+        (b'"app":"bench-pod"',
+         b'{"key":"kwok.x-k8s.io/node","operator":"Exists"}'),
+        (b'"a":"b","c":"d"', b""),
+        (b"", b'{"key":"k","operator":"Exists"}'),
     )
+    assert evb.aoff.tolist() == [0] * 8
+
+
+def _pod_grammar_corpus():
+    """(id, value, accepted) for the canonical pod grammar: what both
+    parsers must take and the near-misses both must leave to JSON."""
+    from k8s1m_tpu.config import (
+        EFFECT_NO_EXECUTE,
+        EFFECT_NO_SCHEDULE,
+        EFFECT_PREFER_NO_SCHEDULE,
+        SEL_OP_IN,
+        TOL_OP_EQUAL,
+    )
+    from k8s1m_tpu.control.coordinator import splice_node_name
+    from k8s1m_tpu.control.objects import encode_pod
     from k8s1m_tpu.snapshot.pod_encoding import (
+        NodeSelectorTerm,
         PodInfo,
         SelectorRequirement,
-        NodeSelectorTerm,
         Toleration,
     )
-    from k8s1m_tpu.config import SEL_OP_IN
-    from k8s1m_tpu.store.native import POD_CANONICAL, POD_HAS_NODE
+    from k8s1m_tpu.tools.make_pods import build_pod
 
-    cases = [
-        encode_pod(PodInfo("a", cpu_milli=1, mem_kib=1)),
-        encode_pod(PodInfo("b", namespace="kube-system", cpu_milli=999999,
-                           mem_kib=123456789)),
-        encode_pod(PodInfo("c", node_name="n-1")),
-        splice_node_name(encode_pod(PodInfo("d")), "n-2"),
-        encode_pod(PodInfo("e", labels={"x": "y"})),
-        encode_pod(PodInfo("f", node_selector={"k": "v"})),
-        encode_pod(PodInfo("g", tolerations=[Toleration(key="k")])),
-        encode_pod(PodInfo(
-            "h",
-            required_terms=[NodeSelectorTerm([
-                SelectorRequirement("k", SEL_OP_IN, ["v"])
-            ])],
-        )),
-        encode_pod(PodInfo('esc"aped', cpu_milli=5)),   # escapes -> fallback
-    ]
+    T = Toleration
+    kwok = T(key="kwok.x-k8s.io/node")
+    equal = T("k", TOL_OP_EQUAL, "v")
+    full = T("k", TOL_OP_EQUAL, "v", EFFECT_NO_SCHEDULE)
+    three = {"app": "web", "tier": "front", "k8s1m.io/tenant": "t-1"}
+    accepted = {
+        "bare": encode_pod(PodInfo("a", cpu_milli=1, mem_kib=1)),
+        "bare-wide": encode_pod(PodInfo(
+            "b", namespace="kube-system", cpu_milli=999999,
+            mem_kib=123456789)),
+        "label-1": encode_pod(PodInfo("c", labels={"x": "y"})),
+        "label-3": encode_pod(PodInfo("d", labels=three)),
+        "label-empty-value": encode_pod(PodInfo("d", labels={"x": ""})),
+        "make-pods": encode_pod(build_pod(7)),
+        "tol-exists-key": encode_pod(PodInfo("e", tolerations=[kwok])),
+        "tol-equal-value": encode_pod(PodInfo("f", tolerations=[equal])),
+        "tol-effect": encode_pod(PodInfo("g", tolerations=[full])),
+        "tol-exists-effect": encode_pod(PodInfo(
+            "g", tolerations=[T("k", effect=EFFECT_PREFER_NO_SCHEDULE)])),
+        "tol-keyless-exists": encode_pod(PodInfo("h", tolerations=[T()])),
+        "tol-two": encode_pod(PodInfo(
+            "i", tolerations=[kwok, T("z", effect=EFFECT_NO_EXECUTE)])),
+        "tol-three-labels": encode_pod(PodInfo(
+            "j", labels=three, tolerations=[T(), full, equal])),
+        "node-appended": encode_pod(PodInfo("k", node_name="n-1")),
+        "node-spliced": splice_node_name(encode_pod(PodInfo("l")), "n-2"),
+        "node-appended-labels": encode_pod(PodInfo(
+            "m", node_name="n-1", labels=three)),
+        "node-appended-tols": encode_pod(PodInfo(
+            "n", node_name="n-1", tolerations=[kwok, full])),
+        "node-appended-both": encode_pod(PodInfo(
+            "o", node_name="n-1", labels={"x": "y"}, tolerations=[kwok])),
+        "node-spliced-labels": splice_node_name(
+            encode_pod(PodInfo("p", labels=three)), "n-2"),
+        "node-spliced-tols": splice_node_name(
+            encode_pod(PodInfo("q", tolerations=[equal])), "n-2"),
+        "node-spliced-both": splice_node_name(encode_pod(build_pod(9)), "n-2"),
+        "other-scheduler": encode_pod(PodInfo(
+            "r", scheduler_name="default-scheduler", labels={"x": "y"})),
+    }
+    mp = accepted["make-pods"]
+    tol = b'"tolerations":[{"key":"kwok.x-k8s.io/node","operator":"Exists"}]'
+    assert tol in mp
+    rejected = {
+        "backslash-name": encode_pod(PodInfo('esc"aped', cpu_milli=5)),
+        "backslash-label": encode_pod(PodInfo("a", labels={"x": 'q"r'})),
+        "backslash-toleration": encode_pod(PodInfo(
+            "a", tolerations=[T(key="a\\b")])),
+        "priority": encode_pod(PodInfo("a", priority=3)),
+        "priority-shaped": encode_pod(PodInfo(
+            "a", priority=3, labels={"x": "y"}, tolerations=[kwok])),
+        "node-selector": encode_pod(PodInfo("a", node_selector={"k": "v"})),
+        "node-selector-tols": encode_pod(PodInfo(
+            "a", node_selector={"k": "v"}, tolerations=[kwok])),
+        "affinity": encode_pod(PodInfo(
+            "a", required_terms=[NodeSelectorTerm([
+                SelectorRequirement("k", SEL_OP_IN, ["v"])])])),
+        "spread": encode_pod(PodInfo("a", labels={"x": "y"}), raw_spread=[{
+            "topologyKey": "topology.kubernetes.io/zone", "maxSkew": 1,
+            "whenUnsatisfiable": "DoNotSchedule",
+            "labelSelector": {"matchLabels": {}}}]),
+        "tol-unknown-key": mp.replace(
+            b'"operator":"Exists"}',
+            b'"operator":"Exists","tolerationSeconds":5}'),
+        "tol-unknown-operator": mp.replace(b'"Exists"', b'"Exist"'),
+        "tol-unknown-effect": mp.replace(
+            b'"operator":"Exists"}', b'"operator":"Exists","effect":"No"}'),
+        "tol-reordered": mp.replace(
+            b'{"key":"kwok.x-k8s.io/node","operator":"Exists"}',
+            b'{"operator":"Exists","key":"kwok.x-k8s.io/node"}'),
+        "tol-effect-before-value": accepted["tol-effect"].replace(
+            b'"value":"v","effect":"NoSchedule"',
+            b'"effect":"NoSchedule","value":"v"'),
+        "tol-empty-list": mp.replace(tol, b'"tolerations":[]'),
+        "tol-empty-object": mp.replace(tol, b'"tolerations":[{}]'),
+        "tol-trailing-comma": mp.replace(b'"Exists"}]', b'"Exists"},]'),
+        "tol-before-containers": mp.replace(b"," + tol, b"").replace(
+            b'"containers":', tol + b',"containers":'),
+        "tols-before-appended-node": accepted["node-appended-tols"].replace(
+            b',"nodeName":"n-1"', b"").replace(
+            b'},"status"', b',"nodeName":"n-1"},"status"'),
+        "node-twice": splice_node_name(
+            accepted["bare"], "n-1").replace(
+            b'}}}]}', b'}}}],"nodeName":"n-2"}'),
+        "label-number": mp.replace(b'"app":"bench-pod"', b'"app":1'),
+        "label-nested": mp.replace(b'"app":"bench-pod"', b'"app":{"a":"b"}'),
+        "label-trailing-comma": mp.replace(
+            b'"app":"bench-pod"', b'"app":"bench-pod",'),
+        "no-labels-key": mp.replace(b',"labels":{"app":"bench-pod"}', b""),
+        "reordered-metadata": mp.replace(
+            b'"name":"bench-pod-7","namespace":"default"',
+            b'"namespace":"default","name":"bench-pod-7"'),
+        "annotations": mp.replace(
+            b'},"spec":', b',"annotations":{"a":"b"}},"spec":'),
+        "status-running": mp.replace(b'"Pending"', b'"Running"'),
+        "trailing-byte": mp + b" ",
+        "cpu-cores": mp.replace(b'"cpu":"100m"', b'"cpu":"1"'),
+    }
+    cases = [(k, v, True) for k, v in accepted.items()]
+    cases += [(k, v, False) for k, v in rejected.items()]
+    # A value cut at (and just inside) every landmark of the grammar.
+    marks = (
+        b'"metadata"', b'"namespace"', b'"labels"', b'"app"', b'bench-pod"}',
+        b'"spec"', b'"schedulerName"', b'"containers"', b'"cpu"', b'"memory"',
+        b'"tolerations"', b'"key"', b'kwok', b'"operator"', b'Exists',
+        b'}]}', b'"status"', b'"phase"', b'Pending',
+    )
+    for m in marks:
+        at = mp.index(m)
+        for cut in (at, at + 1, at + len(m)):
+            cases.append((f"cut-{m.decode()}-{cut - at}", mp[:cut], False))
+    cases.append(("cut-last-byte", mp[:-1], False))
+    return cases
+
+
+_POD_GRAMMAR = _pod_grammar_corpus()
+
+
+@pytest.mark.parametrize(
+    "value,accepted", [c[1:] for c in _POD_GRAMMAR],
+    ids=[c[0] for c in _POD_GRAMMAR],
+)
+def test_poll_pods_parses_exactly_what_decode_pod_fast_does(
+    store, value, accepted
+):
+    """Parser parity: the C parser flags a value CANONICAL exactly when
+    decode_pod_fast takes it, and then the frame's scalars, node name
+    and shape spans say what json.loads + decode_pod_obj say of the same
+    value; what they reject comes back whole for the Python fallback."""
+    import json
+
+    from k8s1m_tpu.control.objects import (
+        decode_pod_fast,
+        decode_pod_obj,
+        decode_pod_shape,
+        pod_key,
+    )
+    from k8s1m_tpu.store.native import (
+        POD_CANONICAL,
+        POD_HAS_NODE,
+        POD_SCHED_MATCH,
+        parse_pod_events,
+    )
+
     w = _pods_watch(store)
-    for i, v in enumerate(cases):
-        store.put(pod_key("t", f"case-{i}"), v)
+    store.put(pod_key("t", "case"), value)
     evb = w.poll_pods(100, b"dist-scheduler")
-    assert evb.n == len(cases)
-    for i, v in enumerate(cases):
-        py = decode_pod_fast(v, None)
-        # decode_pod_fast parses labeled pods too; the C lane only takes
-        # the label-less subset (labels need the Python tracker anyway).
-        py_plain = py is not None and not py.labels
-        c_canon = bool(evb.flags[i] & POD_CANONICAL)
-        assert c_canon == py_plain, f"case {i}"
-        if not c_canon:
-            assert evb.aux_blob[evb.aoff[i]: evb.aoff[i + 1]] == v
-            continue
-        assert evb.cpu[i] == py.cpu_milli and evb.mem[i] == py.mem_kib
-        if py.node_name:
-            assert evb.flags[i] & POD_HAS_NODE
-            assert (
-                evb.aux_blob[evb.aoff[i]: evb.aoff[i + 1]].decode()
-                == py.node_name
-            )
+    assert evb.n == 1
+    py = decode_pod_fast(value, None)
+    flags = int(evb.flags[0])
+    assert bool(flags & POD_CANONICAL) == (py is not None) == accepted
+    aux = evb.aux_blob[evb.aoff[0]: evb.aoff[1]]
+    wire = parse_pod_events([(0, b"k", value, 1)], b"dist-scheduler")
+    assert wire.flags.tolist() == [flags] and wire.shapes == evb.shapes
+    if not accepted:
+        assert aux == value and evb.shape.tolist() == [0]
+        return
+    ref = decode_pod_obj(json.loads(value))
+    assert py == ref
+    assert (evb.cpu[0], evb.mem[0]) == (ref.cpu_milli, ref.mem_kib)
+    assert bool(flags & POD_SCHED_MATCH) == (
+        ref.scheduler_name == "dist-scheduler"
+    )
+    assert bool(flags & POD_HAS_NODE) == (ref.node_name is not None)
+    assert aux.decode() == (ref.node_name or "")
+    if ref.labels or ref.tolerations:
+        assert evb.shape.tolist() == [1] and len(evb.shapes) == 1
+        assert decode_pod_shape(*evb.shapes[0]) == (
+            ref.labels, ref.tolerations
+        )
+    else:
+        assert evb.shape.tolist() == [0] and evb.shapes == ()
 
 
 def test_bind_batch_echo_suppression(store):

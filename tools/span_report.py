@@ -29,8 +29,12 @@ from benchmark import run, span_readers, trace_reduce
 from benchmark.readers import READERS
 
 SPECS = run.read_json("benchmark", "span_metrics.json")["metrics"]
+# What the metric specs read, and the intake's shape table, which no
+# metric reads: its one decode falls in the warm-up, so it is logged at
+# the window's close as well as over the window.
 COUNTERS = sorted(
     {m["args"]["counter"] for m in SPECS if "counter" in m["args"]}
+    | {"coordinator_pod_shapes_total"}
 )
 
 
@@ -121,12 +125,14 @@ def report(manifest: dict, name: str, cell_files, **kw) -> dict:
         f"{k}={v:.3f}" for k, v in ctx["setup_stage_s"].items()))
     for name in COUNTERS:
         at_open = ctx["counters"]["open"].get(name, {})
-        grown = {
-            ",".join(v for _k, v in key): n - at_open.get(key, 0.0)
+        at_close = {
+            ",".join(v for _k, v in key): (n, n - at_open.get(key, 0.0))
             for key, n in ctx["counters"]["close"].get(name, {}).items()
         }
         run.log(f"{name} in the window: " + " ".join(
-            f"{k}={v:.0f}" for k, v in sorted(grown.items())))
+            f"{k}={grown:.0f}" for k, (_n, grown) in sorted(at_close.items())
+        ) + "; since the process began: " + " ".join(
+            f"{k}={n:.0f}" for k, (n, _grown) in sorted(at_close.items())))
     return result
 
 

@@ -13,6 +13,7 @@ the program's encoders on a sample.
 
 from __future__ import annotations
 
+import inspect
 import math
 import random
 import struct
@@ -22,27 +23,46 @@ NODES_PREFIX = b"/registry/minions/"
 _PLACEHOLDER = 987654321987654321     # a pod index no run reaches
 
 
+def builder_keywords(builder, given: dict, what: str) -> dict:
+    """The keyword defaults of the program's ``builder`` (``build_pod``,
+    ``build_node``), after holding ``given`` to them: a key the builder
+    does not take fails here, by name, and not as a pod or node quietly
+    built without it."""
+    takes = {
+        name: p.default
+        for name, p in inspect.signature(builder).parameters.items()
+        if p.kind is p.KEYWORD_ONLY
+    }
+    for key in given:
+        if key not in takes:
+            raise ValueError(
+                f"{what}: key {key!r} is no keyword of "
+                f"{builder.__module__}.{builder.__name__} "
+                f"(it takes {sorted(takes)})"
+            )
+    return takes
+
+
 class Nodes:
     """``count`` KWOK nodes of one shape; labels cycle with the index.
     With ``cordon_every`` = n, the last node of every n is cordoned
-    (``spec.unschedulable``): no pod may be bound to it."""
+    (``spec.unschedulable``): no pod may be bound to it.  Every other key
+    of the ``nodes`` object is a keyword of ``make_nodes.build_node``."""
 
     def __init__(self, shape: dict) -> None:
         from k8s1m_tpu.control.objects import encode_node
         from k8s1m_tpu.tools.make_nodes import KWOK_GROUPS, build_node
 
         self.count = int(shape["count"])
-        self.prefix = shape.get("prefix", "kwok-node")
-        self.cpu_milli = int(shape["cpu_milli"])
-        self.mem_kib = int(shape["mem_kib"])
-        self.pods = int(shape["pods"])
         self.cordon_every = int(shape.get("cordon_every", 0))
-        self._kw = dict(
-            prefix=self.prefix, zones=int(shape["zones"]),
-            regions=int(shape["regions"]), cpu_milli=self.cpu_milli,
-            mem_kib=self.mem_kib, pods=self.pods,
-        )
-        period = math.lcm(KWOK_GROUPS, self._kw["zones"], self._kw["regions"],
+        self._kw = {k: v for k, v in shape.items()
+                    if k not in ("count", "cordon_every")}
+        kw = {**builder_keywords(build_node, self._kw, "nodes"), **self._kw}
+        self.prefix = kw["prefix"]
+        self.cpu_milli = int(kw["cpu_milli"])
+        self.mem_kib = int(kw["mem_kib"])
+        self.pods = int(kw["pods"])
+        period = math.lcm(KWOK_GROUPS, int(kw["zones"]), int(kw["regions"]),
                           self.cordon_every or 1)
         self._tmpl = []
         for j in range(period):
@@ -95,9 +115,11 @@ def load_nodes(store, nodes: Nodes) -> None:
 def shape_pattern(params: dict, seed: int) -> list[dict]:
     """The cell's pod shapes, each repeated by its weight, in an order
     drawn from the seed: every seed offers the same set, in another
-    order.  Pod ``i`` has shape ``pattern[i % len(pattern)]``."""
+    order.  Pod ``i`` has shape ``pattern[i % len(pattern)]``.  A shape
+    is every key of its entry but ``weight``: keywords of
+    ``make_pods.build_pod``."""
     pattern = [
-        {"cpu_milli": int(s["cpu_milli"]), "mem_kib": int(s["mem_kib"])}
+        {k: v for k, v in s.items() if k != "weight"}
         for s in params["shapes"] for _ in range(int(s.get("weight", 1)))
     ]
     random.Random(seed).shuffle(pattern)
@@ -111,10 +133,16 @@ class Pods:
 
     def __init__(self, params: dict, seed: int) -> None:
         from k8s1m_tpu.control.objects import encode_pod
+        from k8s1m_tpu.tools.make_pods import build_pod
 
         self.namespace = f"b{seed}"
-        self.prefix = "bench-pod"       # make_pods' own
         self.pattern = shape_pattern(params, seed)
+        given = {k: None for shape in self.pattern for k in shape}
+        self._defaults = builder_keywords(build_pod, given, "pods shape")
+        for fixed in ("prefix", "namespace"):      # the keys are made of them
+            if fixed in given:
+                raise ValueError(f"pods shape: key {fixed!r} is the generator's")
+        self.prefix = self._defaults["prefix"]      # make_pods' own
         self._rec = struct.Struct("<II").pack     # key length, value length
         stem = f"{self.prefix}-".encode()
         self.key_prefix = PODS_PREFIX + f"{self.namespace}/".encode() + stem
@@ -134,6 +162,11 @@ class Pods:
 
     def key(self, i: int) -> bytes:
         return self.key_prefix + str(i).encode()
+
+    def requests(self, key: str) -> list[int]:
+        """``cpu_milli`` or ``mem_kib`` of each shape of the pattern, with
+        ``build_pod``'s default where a shape leaves it out."""
+        return [int(s.get(key, self._defaults[key])) for s in self.pattern]
 
     def wave(self, lo: int, n: int) -> list[tuple[bytes, bytes]]:
         tmpl, period, kp = self._tmpl, len(self._tmpl), self.key_prefix

@@ -1,27 +1,46 @@
 """Per-layer metric readers.  A metric is a file
-``benchmark/metrics/<name>.json`` that names one of these and its
-arguments; a reader that finds nothing to read returns None and the
-metric is left out of the line.
+``benchmark/metrics/<name>.json`` that names a reader and its arguments:
+a bare name is one of ``READERS`` below, ``<module>.<function>`` is that
+function of the module ``benchmark/<module>.py`` (``resolve``), so a
+reader a later PR brings is a file of its own and needs no registration.
+A reader is ``f(args, ctx)``; one that finds nothing to read returns None
+and the metric is left out of the line.
 
 The context ``ctx`` a reader gets:
-  stage_s   {stage: seconds} summed from coordinator_cycle_seconds over the window
-  binds     binds the client saw in the window
-  trace     None, or {"events", "plane"} of the traced part of the window
-  shapes    what roofline.py needs, read from the live table's shapes
-  peaks     this device's entry of peaks.json
+  stage_s        {stage: seconds}: every label coordinator_cycle_seconds
+                 holds at window close, summed over the window
+  setup_stage_s  the same sums just before the window resets them (set-up)
+  counters       {"open": snap, "close": snap} around the window, each
+                 {counter: {labels: value}} of every counter in the
+                 program's registry, labels as a sorted tuple of
+                 (name, value) pairs
+  binds          binds the client saw in the window
+  trace          None, or the traced part of the window: {"events",
+                 "plane", "op_names" ({event name: op_name} of the device
+                 plane), "host_spans" ((line id, name, start_s, dur_s))}
+  shapes         what roofline.py needs, read from the live table's shapes:
+                 scan_rows, columns {name: (itemsize, elements per row)},
+                 batch, k, pod_bytes
+  peaks          this device's entry of peaks.json
 """
 
 from __future__ import annotations
 
+import importlib
+import re
+
 from benchmark import roofline, trace_reduce
+
+_DOTTED = re.compile(r"^([A-Za-z_][A-Za-z0-9_]*)\.([A-Za-z_][A-Za-z0-9_]*)$")
 
 
 def registry_stage_per_bind(args: dict, ctx: dict):
-    """Microseconds of the named coordinator stages per bind."""
-    if not ctx["binds"]:
+    """Microseconds of the named coordinator stages per bind; nothing
+    when the program observed none of them in the window."""
+    stage_s = ctx["stage_s"]
+    if not ctx["binds"] or not any(s in stage_s for s in args["stages"]):
         return None
-    total = sum(ctx["stage_s"].get(s, 0.0) for s in args["stages"])
-    return 1e6 * total / ctx["binds"]
+    return 1e6 * sum(stage_s.get(s, 0.0) for s in args["stages"]) / ctx["binds"]
 
 
 def trace_ms_per_wave(args: dict, ctx: dict):
@@ -46,14 +65,18 @@ def trace_ms_per_wave(args: dict, ctx: dict):
 
 
 def trace_roofline_pct(args: dict, ctx: dict):
-    """HBM roofline share of the candidates kernel: least seconds for the
-    bytes one wave must move over the kernel's measured seconds."""
+    """HBM roofline share of a candidates kernel: least seconds for the
+    bytes one wave must move over the kernel's measured seconds.  The
+    table columns counted are ``args.columns`` (the plugins the kernel's
+    deployment runs decide them; default ``roofline.BASE_COLUMNS``)."""
     ms = trace_ms_per_wave(args, ctx)
     if ms is None:
         return None
     s = ctx["shapes"]
     moved = roofline.wave_bytes(
-        scan_rows=s["scan_rows"], bytes_per_row=s["bytes_per_row"],
+        scan_rows=s["scan_rows"],
+        bytes_per_row=roofline.row_bytes(
+            s["columns"], args.get("columns", roofline.BASE_COLUMNS)),
         batch=s["batch"], k=s["k"], pod_bytes=s["pod_bytes"],
     )
     return roofline.hbm_share_pct(
@@ -67,3 +90,21 @@ READERS = {
     "trace_kernel_ms_per_wave": trace_ms_per_wave,
     "trace_roofline_pct": trace_roofline_pct,
 }
+
+
+def resolve(name: str):
+    """The reader a metric file names."""
+    if name in READERS:
+        return READERS[name]
+    dotted = _DOTTED.match(name)
+    if not dotted:
+        raise LookupError(f"reader {name!r}: not one of {sorted(READERS)} "
+                          "and not <module>.<function>")
+    module, function = dotted.groups()
+    try:
+        reader = getattr(importlib.import_module(f"benchmark.{module}"), function)
+    except (ImportError, AttributeError) as e:
+        raise LookupError(f"reader {name!r}: {e}") from None
+    if not callable(reader):
+        raise LookupError(f"reader {name!r} is not a function")
+    return reader

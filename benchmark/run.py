@@ -19,6 +19,7 @@ T_START = time.perf_counter()       # set-up counts from here
 
 import argparse
 import contextlib
+import importlib.util
 import json
 import os
 import random
@@ -29,11 +30,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import faults, generate, reference, roofline, trace_reduce
-from benchmark.readers import READERS
+from benchmark import (
+    faults, generate, readers, reference, roofline, span_readers, trace_reduce,
+)
 
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
-STAGES = ("drain", "sync", "encode", "device", "sync_out", "bind")
+REFERENCES_DIR = os.path.join(ROOT, "benchmark", "references")
 STORE_SAMPLE = 512
 POLL = 16384
 WARM_UP_WAVES = 3
@@ -59,6 +61,38 @@ def load_cell(manifest: dict, name: str) -> tuple[dict, dict, dict]:
     config = read_json(cfg["file"])
     pods = read_json("benchmark", "pods", f"{workload['pods']}.json")
     return workload, config, pods
+
+
+def load_reference(name: str):
+    """The ``numbers`` function of ``benchmark/references/<name>.py``: a
+    configuration's own guarantees (benchmark/README.md), loaded by path
+    since the file imports nothing, not the program and not the harness."""
+    path = os.path.join(REFERENCES_DIR, f"{name}.py")
+    if not os.path.exists(path):
+        raise SystemExit(f"configuration names reference {name!r}: no {path}")
+    spec = importlib.util.spec_from_file_location(f"benchmark_reference_{name}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.numbers
+
+
+def trace_due(*, now: float, t0: float, deadline: float, trace_seconds: float,
+              first: int, offered: int, most: int, wave: int) -> bool:
+    """Whether the traced part of the window starts now, at the end of an
+    iteration: when ``trace_seconds`` are left on the clock, or, in a cell
+    that fills to the brim (``most`` pods in all), when the waves left
+    before the brim are what the loop, at the pace it has kept since the
+    window opened, offers in ``trace_seconds`` -- whichever comes first,
+    so that the traced part is ``trace_seconds`` long, give or take a
+    wave, at every rate the cell can reach."""
+    if not trace_seconds:
+        return False
+    if now >= deadline - trace_seconds:
+        return True
+    if most >= 1 << 62 or offered <= first:
+        return False
+    pace = (now - t0) * wave / (offered - first)        # seconds a wave
+    return (most - offered) // wave * pace <= trace_seconds
 
 
 def metrics_of(manifest: dict, kind: str, cell: str) -> list[dict]:
@@ -285,15 +319,18 @@ class Cell:
         if self.workload["arrival"] != "backlog":
             raise SystemExit(f"arrival {self.workload['arrival']!r}")
         cyc = REGISTRY.get("coordinator_cycle_seconds")
-        w, step = self.wave, self.coord.step
+        w, step, most = self.wave, self.coord.step, self.most
         tracing = False
         built0 = self.compiles.built
         first = self.offered
+        # what the program counted up to here is set-up's; the snapshots
+        # are taken outside [t0, t1]
+        setup_stage_s = span_readers.stage_sums()
+        counters = {"open": span_readers.snapshot_counters()}
         cyc.reset()
         gc_clock = GcClock()
         t0 = time.perf_counter()
         deadline = t0 + seconds
-        trace_at = deadline - trace_seconds if trace_seconds else float("inf")
         clock = time.perf_counter
         t_put = t_step = t_watch = 0.0
         now = t0
@@ -308,9 +345,12 @@ class Cell:
             now = clock()
             t_step += b - a
             t_watch += now - b
-            if now >= deadline or self.offered + w > self.most:
+            if now >= deadline or self.offered + w > most:
                 break
-            if now >= trace_at and not tracing:
+            if not tracing and trace_due(
+                now=now, t0=t0, deadline=deadline, trace_seconds=trace_seconds,
+                first=first, offered=self.offered, most=most, wave=w,
+            ):
                 tracing = True
                 shutil.rmtree(TRACE_DIR, ignore_errors=True)
                 opts = jax.profiler.ProfileOptions()
@@ -318,21 +358,27 @@ class Cell:
                 opts.host_tracer_level = 2
                 jax.profiler.start_trace(TRACE_DIR, profiler_options=opts)
                 self.span = jax.profiler.TraceAnnotation
+                t_trace = clock()
         t1 = now
         gc_line = gc_clock.close()
-        stage_s = {s: cyc.sum(stage=s) for s in STAGES}
+        stage_s = span_readers.stage_sums()
+        counters["close"] = span_readers.snapshot_counters()
         built = self.compiles.built - built0
         if tracing:
             jax.block_until_ready(self.coord.table)
             jax.profiler.stop_trace()
             self.span = lambda _name: contextlib.nullcontext()
-            log(f"trace of the window's last {trace_seconds}s written in "
+            log(f"trace of the window's last {t1 - t_trace:.3f}s (from "
+                f"{t_trace - t0:.3f}s to its close at {t1 - t0:.3f}s; "
+                f"trace_seconds {trace_seconds}) written in "
                 f"{time.perf_counter() - t1:.1f}s")
         return {"t0": t0, "t1": t1, "first": first, "last": self.offered,
                 "gc": gc_line,
                 "loop_s": {"put": t_put, "step": t_step, "watch": t_watch},
-                "stage_s": stage_s, "compiled_in_window": built,
-                "traced": tracing}
+                "stage_s": stage_s, "setup_stage_s": setup_stage_s,
+                "counters": counters, "compiled_in_window": built,
+                "traced": tracing,
+                "traced_s": t1 - t_trace if tracing else 0.0}
 
     def brim(self) -> int:
         """After the window has closed, the same loop goes on, untimed,
@@ -359,10 +405,9 @@ class Cell:
         n = self.nodes
         node_index = {n.name(i): i for i in range(n.count)}
         seen = self.ledger.arrays(node_index)
-        pattern = self.pods.pattern
-        reps = -(-self.offered // len(pattern))
-        pod_cpu = np.tile([s["cpu_milli"] for s in pattern], reps)[:self.offered]
-        pod_mem = np.tile([s["mem_kib"] for s in pattern], reps)[:self.offered]
+        reps = -(-self.offered // len(self.pods.pattern))
+        pod_cpu = np.tile(self.pods.requests("cpu_milli"), reps)[:self.offered]
+        pod_mem = np.tile(self.pods.requests("mem_kib"), reps)[:self.offered]
         rep = reference.replay(
             seen, offered=self.offered, pod_cpu=pod_cpu, pod_mem=pod_mem,
             alloc_cpu=np.full(n.count, n.cpu_milli, np.int64),
@@ -372,7 +417,6 @@ class Cell:
                 (n.cordoned(i) for i in range(n.count)), bool, n.count),
         )
         numbers = dict(rep["numbers"])
-
         # the store, read back: a sample drawn from the seed, the newest
         # wave in it
         rng = random.Random(self.seed)
@@ -407,6 +451,19 @@ class Cell:
             and not is_packed(table)
         )
         numbers["watch_dropped"] = int(self._watch.dropped)
+        # the configuration's own guarantees, where it names a reference
+        # for them: more numbers, each with the limit 0 like the rest
+        if "reference" in self.config:
+            own = load_reference(self.config["reference"])(
+                seen, rep, nodes=self.config["nodes"],
+                pattern=self.pods.pattern, offered=self.offered,
+            )
+            taken = sorted(set(own) & set(numbers))
+            if taken:
+                raise RuntimeError(
+                    f"reference {self.config['reference']!r} returns "
+                    f"{taken}: the base comparison's own numbers")
+            numbers.update(own)
 
         in_window, rate = reference.window_rate(seen["bind_t"], win["t0"], win["t1"])
         per_pod = np.bincount(
@@ -432,7 +489,7 @@ class Cell:
             "scan_rows": roofline.window_rows(
                 t.num_rows, int(c.get("score_pct", 100)), int(c["chunk"])
             ),
-            "bytes_per_row": roofline.row_bytes(cols),
+            "columns": cols,
             "batch": self.config["pod_spec"]["batch"],
             "k": self.coord.k,
             "pod_bytes": roofline.POD_BYTES,
@@ -453,18 +510,20 @@ def read_trace(cell: Cell) -> dict | None:
     benchmark's first span in the trace to the end of its last."""
     t = time.perf_counter()
     size = os.path.getsize(trace_reduce.trace_file(TRACE_DIR))
-    events = trace_reduce.load(TRACE_DIR)
+    loaded = trace_reduce.load_trace(TRACE_DIR)
     shutil.rmtree(TRACE_DIR, ignore_errors=True)
+    events, host_spans = loaded["events"], loaded["host_spans"]
     log(f"trace: {size} bytes, {len(events)} events read in "
         f"{time.perf_counter() - t:.1f}s")
     planes = trace_reduce.device_planes(events)
     if not planes:
         raise RuntimeError("trace holds no TPU device plane")
-    spans = [(n, s, d) for _p, _l, n, s, d in events if n.startswith("bench.")]
+    spans = [(n, s, d) for _l, n, s, d in host_spans if n.startswith("bench.")]
     t0 = min(s for _n, s, _d in spans)
     t1 = max(s + d for _n, s, d in spans)
     device = trace_reduce.busy_window(events, t0, t1)
     plane = planes[0]
+    op_names = loaded["op_names"].get(plane, {})
     breakdown = {
         "device_ops": [
             [trace_reduce.short_name(n), s] for n, s in trace_reduce.sums_by_name(
@@ -473,16 +532,47 @@ def read_trace(cell: Cell) -> dict | None:
         "idle_gaps": [
             [n, s] for n, s in trace_reduce.idle_gaps(events, plane, spans, t0, t1)
         ],
+        "idle_by_span": [
+            [n, s] for n, s in span_readers.idle_by_span(
+                events, plane, host_spans, t0, t1, top=10)
+        ],
+        "device_scopes": span_readers.device_scopes(
+            events, plane, op_names, t0, t1),
     }
-    return {"events": events, "plane": plane, "device": device,
-            "breakdown": breakdown}
+    return {"events": events, "plane": plane, "op_names": op_names,
+            "host_spans": host_spans, "device": device, "breakdown": breakdown}
+
+
+def per_layer_values(manifest: dict, name: str, ctx: dict) -> dict:
+    """The cell's per-layer metrics, each by the reader its file names;
+    one whose reader finds nothing to read has no value."""
+    values = {}
+    for m in metrics_of(manifest, "per_layer", name):
+        spec = read_json("benchmark", "metrics", f"{m['name']}.json")
+        values[m["name"]] = readers.resolve(spec["reader"])(spec.get("args", {}), ctx)
+    return values
+
+
+def log_counters(counters: dict) -> None:
+    """Every counter that moved in the window or holds anything at its
+    close, by label set: what it grew by, and what it holds."""
+    for name, at_close in sorted(counters["close"].items()):
+        at_open = counters["open"].get(name, {})
+        rows = [
+            (",".join(v for _k, v in key) or "-", n - at_open.get(key, 0.0), n)
+            for key, n in sorted(at_close.items())
+        ]
+        if any(grown or n for _k, grown, n in rows):
+            log(f"{name}: " + " ".join(
+                f"{k}=+{grown:.0f}({n:.0f})" for k, grown, n in rows))
 
 
 def run_cell(manifest: dict, name: str, cell_files: tuple[dict, dict, dict],
              *, seed: int, seconds: float, trace: bool, device: dict,
              peaks: dict, fault: str | None = None,
-             dump_trace: str | None = None) -> dict:
-    """Everything after the device check; returns the result object."""
+             dump_trace: str | None = None, keep: dict | None = None) -> dict:
+    """Everything after the device check; returns the result object.
+    ``keep``, where given, is filled with the readers' ``ctx``."""
     import jax
     import numpy as np
 
@@ -538,19 +628,24 @@ def run_cell(manifest: dict, name: str, cell_files: tuple[dict, dict, dict],
         f"{sched.value(outcome='unschedulable'):.0f}")
     log("stage seconds: " + " ".join(
         f"{k}={v:.3f}" for k, v in win["stage_s"].items()))
+    log("set-up stage seconds: " + " ".join(
+        f"{k}={v:.3f}" for k, v in win["setup_stage_s"].items()))
+    log("counters, +grown in the window(held at its close):")
+    log_counters(win["counters"])
     log("client loop seconds: " + " ".join(
         f"{k}={v:.3f}" for k, v in win["loop_s"].items())
         + f"; collector in window: {win['gc']}")
 
     values = {"binds_per_s": out["binds_per_s"], "setup_s": setup_s}
+    ctx = {
+        "stage_s": win["stage_s"], "setup_stage_s": win["setup_stage_s"],
+        "counters": win["counters"], "binds": out["binds"], "trace": traced,
+        "shapes": shapes, "peaks": peaks,
+    }
+    if keep is not None:
+        keep.update(ctx)
     if trace:
-        ctx = {
-            "stage_s": win["stage_s"], "binds": out["binds"], "trace": traced,
-            "shapes": shapes, "peaks": peaks,
-        }
-        for m in metrics_of(manifest, "per_layer", name):
-            spec = read_json("benchmark", "metrics", f"{m['name']}.json")
-            values[m["name"]] = READERS[spec["reader"]](spec.get("args", {}), ctx)
+        values.update(per_layer_values(manifest, name, ctx))
     metrics = {}
     for m in metrics_of(manifest, "per_layer" if trace else "end_to_end", name):
         v = values.get(m["name"])

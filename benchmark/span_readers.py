@@ -1,17 +1,11 @@
 """Readers of what the program itself names: ``jax.named_scope`` phases of
 the device step, ``coord.*`` host spans, lane counters, set-up stages.
 
-They sit beside ``readers.py`` and are not registered there yet: the harness
-(``run.py``) has to hand them three things it does not collect today, and it
-may be edited only by a ``benchmark`` PR (PERF.md section 7 lists the five
-edits).  Until then ``tools/span_report.py`` drives them around an unedited
-``run.py``.
-
-What the readers want in ``ctx`` beyond what ``readers.py`` documents:
-  trace["op_names"]  {event name: op_name} of the device plane (``load_names``)
-  counters           {"open": snap, "close": snap}, each ``snapshot_counters(...)``
-  setup_stage_s      {stage: seconds} of coordinator_cycle_seconds before the
-                     window's ``reset()``
+A metric file names one of them as ``span_readers.<function>``
+(``readers.resolve``); the harness hands them, in ``ctx`` (the table in
+``readers.py``), ``trace["op_names"]``, ``counters`` and
+``setup_stage_s``.  The reductions below the readers work on plain
+tuples, so the tests drive them with a handful of synthetic intervals.
 """
 
 from __future__ import annotations
@@ -21,100 +15,12 @@ import bisect
 from benchmark import trace_reduce
 
 SCOPES = ("candidates", "assign", "commit")
-HOST_SPANS = ("bench.", "coord.", "feed.")
-HOST_PLANE = "/host:CPU"
-# The stat of a device op's XEventMetadata that holds its op_name, the
-# "jit(f)/scope/.../primitive:" path jax gives every HLO instruction.
-OP_NAME_STAT = "tf_op"
-
-
-# ---- the loader beside trace_reduce.load ---------------------------------
-
-
-def _xspace_class():
-    """What ``trace_reduce``'s own message class leaves out and
-    ``load_names`` reads: the event metadata's stats with the stats'
-    names, and each line's id (two threads' lines can share a name).
-    Field numbers are tsl/profiler/protobuf/xplane.proto's."""
-    from google.protobuf import descriptor_pb2, descriptor_pool, message_factory
-
-    T = descriptor_pb2.FieldDescriptorProto
-    fd = descriptor_pb2.FileDescriptorProto(
-        name="benchmark_xplane_stats.proto", package="benchmark_xplane_stats",
-        syntax="proto3",
-    )
-
-    def msg(name, *fields):
-        m = fd.message_type.add(name=name)
-        for fname, num, ftype, rep, tname in fields:
-            m.field.add(
-                name=fname, number=num, type=ftype, type_name=tname,
-                label=T.LABEL_REPEATED if rep else T.LABEL_OPTIONAL,
-            )
-
-    P = ".benchmark_xplane_stats."
-    msg("XEvent", ("metadata_id", 1, T.TYPE_INT64, 0, None),
-        ("offset_ps", 2, T.TYPE_INT64, 0, None),
-        ("duration_ps", 3, T.TYPE_INT64, 0, None))
-    msg("XLine", ("id", 1, T.TYPE_INT64, 0, None),
-        ("timestamp_ns", 3, T.TYPE_INT64, 0, None),
-        ("events", 4, T.TYPE_MESSAGE, 1, P + "XEvent"))
-    msg("XStat", ("metadata_id", 1, T.TYPE_INT64, 0, None),
-        ("str_value", 5, T.TYPE_STRING, 0, None),
-        ("ref_value", 7, T.TYPE_UINT64, 0, None))
-    msg("XEventMetadata", ("id", 1, T.TYPE_INT64, 0, None),
-        ("name", 2, T.TYPE_STRING, 0, None),
-        ("stats", 5, T.TYPE_MESSAGE, 1, P + "XStat"))
-    msg("EventEntry", ("key", 1, T.TYPE_INT64, 0, None),
-        ("value", 2, T.TYPE_MESSAGE, 0, P + "XEventMetadata"))
-    msg("XStatMetadata", ("id", 1, T.TYPE_INT64, 0, None),
-        ("name", 2, T.TYPE_STRING, 0, None))
-    msg("StatEntry", ("key", 1, T.TYPE_INT64, 0, None),
-        ("value", 2, T.TYPE_MESSAGE, 0, P + "XStatMetadata"))
-    msg("XPlane", ("name", 2, T.TYPE_STRING, 0, None),
-        ("lines", 3, T.TYPE_MESSAGE, 1, P + "XLine"),
-        ("event_metadata", 4, T.TYPE_MESSAGE, 1, P + "EventEntry"),
-        ("stat_metadata", 5, T.TYPE_MESSAGE, 1, P + "StatEntry"))
-    msg("XSpace", ("planes", 1, T.TYPE_MESSAGE, 1, P + "XPlane"))
-    pool = descriptor_pool.DescriptorPool()
-    pool.Add(fd)
-    return message_factory.GetMessageClass(
-        pool.FindMessageTypeByName("benchmark_xplane_stats.XSpace")
-    )
 
 
 def load_names(trace_dir: str) -> dict:
-    """Beside ``trace_reduce.load``: ``op_names``, ``{device plane: {event
-    name: op_name}}`` for the events whose metadata carries one, and
-    ``host_spans``, ``(line id, name, start_s, dur_s)`` of the host's
-    ``bench.`` / ``coord.`` / ``feed.`` annotations.  XLA keeps the
-    op_name out of the event's name (that is the HLO instruction's text)
-    and in the ``tf_op`` stat of its XEventMetadata."""
-    with open(trace_reduce.trace_file(trace_dir), "rb") as f:
-        space = _xspace_class().FromString(f.read())
-    op_names: dict[str, dict[str, str]] = {}
-    host_spans = []
-    for p in space.planes:
-        if trace_reduce.DEVICE_PLANE.match(p.name):
-            stat_name = {e.key: e.value.name for e in p.stat_metadata}
-            names = op_names.setdefault(p.name, {})
-            for entry in p.event_metadata:
-                for st in entry.value.stats:
-                    if stat_name.get(st.metadata_id) == OP_NAME_STAT:
-                        names[entry.value.name] = (
-                            st.str_value or stat_name.get(st.ref_value, "")
-                        )
-        elif p.name == HOST_PLANE:
-            wanted = {e.key: e.value.name for e in p.event_metadata
-                      if e.value.name.startswith(HOST_SPANS)}
-            for line in p.lines:
-                base = line.timestamp_ns * 1e-9
-                host_spans += [
-                    (line.id, wanted[ev.metadata_id],
-                     base + ev.offset_ps * 1e-12, ev.duration_ps * 1e-12)
-                    for ev in line.events if ev.metadata_id in wanted
-                ]
-    return {"op_names": op_names, "host_spans": host_spans}
+    """``op_names`` and ``host_spans`` of ``trace_reduce.load_trace``."""
+    loaded = trace_reduce.load_trace(trace_dir)
+    return {"op_names": loaded["op_names"], "host_spans": loaded["host_spans"]}
 
 
 # ---- reductions on plain tuples -------------------------------------------
@@ -239,23 +145,22 @@ def idle_by_span(events, plane: str, host_spans, t0: float, t1: float,
     return sorted(out.items(), key=lambda kv: -kv[1])[:top]
 
 
-def snapshot_counters(names) -> dict:
+def snapshot_counters(names=None) -> dict:
     """``{counter: {labels: value}}`` of the program's registry now, labels
-    as a sorted tuple of ``(name, value)``; a counter the program does not
-    have is left out."""
-    from k8s1m_tpu.obs.metrics import REGISTRY
+    as a sorted tuple of ``(name, value)``: every counter the registry
+    holds, or the ``names`` of them that it holds."""
+    from k8s1m_tpu.obs.metrics import REGISTRY, Counter
 
-    snap = {}
-    for name in names:
-        c = REGISTRY.get(name)
-        if c is None:
-            continue
-        snap[name] = {
+    metrics = REGISTRY.metrics() if names is None else map(REGISTRY.get, names)
+    counters = [m for m in metrics if isinstance(m, Counter)]
+    return {
+        c.name: {
             tuple(sorted(zip(c.labelnames, key))):
                 c.value(**dict(zip(c.labelnames, key)))
             for key in c.label_keys()
         }
-    return snap
+        for c in counters
+    }
 
 
 def stage_sums() -> dict[str, float]:
@@ -327,6 +232,8 @@ def setup_stage_s(args: dict, ctx: dict):
     return sum(before.get(s, 0.0) for s in args["stages"])
 
 
+# Read by tools/span_report.py, which a ``benchmark`` PR may not delete;
+# metric files name these readers as ``span_readers.<function>``.
 READERS = {
     "trace_scope_ms_per_wave": trace_scope_ms_per_wave,
     "counter_share_pct": counter_share_pct,

@@ -1,8 +1,8 @@
 """From a profiler trace to numbers: one reduction, patterns as data.
 
-``load`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote into plain
-tuples; everything below it works on those tuples alone, so the tests
-drive it with a handful of synthetic intervals.
+``load_trace`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote into
+plain tuples, once; everything below it works on those tuples alone, so
+the tests drive it with a handful of synthetic intervals.
 
 An event is ``(plane, line, name, start_s, dur_s)``.
 """
@@ -16,13 +16,21 @@ import re
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+HOST_PLANE = "/host:CPU"
+HOST_SPANS = ("bench.", "coord.", "feed.")
+# The stat of a device op's XEventMetadata that holds its op_name, the
+# "jit(f)/scope/.../primitive:" path jax gives every HLO instruction.
+OP_NAME_STAT = "tf_op"
 
 
 def _xspace_class():
     """The few fields of the profiler's XSpace that the reduction reads,
     as a protobuf message of its own (jax's ``ProfileData`` takes minutes
-    over the millions of events a while loop leaves; this takes seconds).
-    Field numbers are tsl/profiler/protobuf/xplane.proto's."""
+    over the millions of events a while loop leaves; this takes seconds):
+    events, each line's name and id (two threads' lines can share a
+    name), and the event metadata's stats with the stats' names (XLA
+    keeps a device op's op_name there).  Field numbers are
+    tsl/profiler/protobuf/xplane.proto's."""
     from google.protobuf import descriptor_pb2, descriptor_pool, message_factory
 
     T = descriptor_pb2.FieldDescriptorProto
@@ -42,16 +50,26 @@ def _xspace_class():
     msg("XEvent", ("metadata_id", 1, T.TYPE_INT64, 0, None),
         ("offset_ps", 2, T.TYPE_INT64, 0, None),
         ("duration_ps", 3, T.TYPE_INT64, 0, None))
-    msg("XLine", ("name", 2, T.TYPE_STRING, 0, None),
+    msg("XLine", ("id", 1, T.TYPE_INT64, 0, None),
+        ("name", 2, T.TYPE_STRING, 0, None),
         ("timestamp_ns", 3, T.TYPE_INT64, 0, None),
         ("events", 4, T.TYPE_MESSAGE, 1, P + "XEvent"))
+    msg("XStat", ("metadata_id", 1, T.TYPE_INT64, 0, None),
+        ("str_value", 5, T.TYPE_STRING, 0, None),
+        ("ref_value", 7, T.TYPE_UINT64, 0, None))
     msg("XEventMetadata", ("id", 1, T.TYPE_INT64, 0, None),
-        ("name", 2, T.TYPE_STRING, 0, None))
-    msg("MetadataEntry", ("key", 1, T.TYPE_INT64, 0, None),
+        ("name", 2, T.TYPE_STRING, 0, None),
+        ("stats", 5, T.TYPE_MESSAGE, 1, P + "XStat"))
+    msg("EventEntry", ("key", 1, T.TYPE_INT64, 0, None),
         ("value", 2, T.TYPE_MESSAGE, 0, P + "XEventMetadata"))
+    msg("XStatMetadata", ("id", 1, T.TYPE_INT64, 0, None),
+        ("name", 2, T.TYPE_STRING, 0, None))
+    msg("StatEntry", ("key", 1, T.TYPE_INT64, 0, None),
+        ("value", 2, T.TYPE_MESSAGE, 0, P + "XStatMetadata"))
     msg("XPlane", ("name", 2, T.TYPE_STRING, 0, None),
         ("lines", 3, T.TYPE_MESSAGE, 1, P + "XLine"),
-        ("event_metadata", 4, T.TYPE_MESSAGE, 1, P + "MetadataEntry"))
+        ("event_metadata", 4, T.TYPE_MESSAGE, 1, P + "EventEntry"),
+        ("stat_metadata", 5, T.TYPE_MESSAGE, 1, P + "StatEntry"))
     msg("XSpace", ("planes", 1, T.TYPE_MESSAGE, 1, P + "XPlane"))
     pool = descriptor_pool.DescriptorPool()
     pool.Add(fd)
@@ -69,21 +87,44 @@ def trace_file(trace_dir: str) -> str:
     return paths[-1]
 
 
-def load(trace_dir: str) -> list[tuple[str, str, str, float, float]]:
+def load_trace(trace_dir: str) -> dict:
+    """The one loader.  ``events``: every event as ``(plane, line, name,
+    start_s, dur_s)``.  ``op_names``: ``{device plane: {event name:
+    op_name}}`` for the device events whose metadata carries one (XLA
+    keeps the ``jit(f)/scope/.../primitive:`` path out of the event's
+    name, which is the HLO instruction's text, and in the ``tf_op`` stat
+    of its XEventMetadata).  ``host_spans``: ``(line id, name, start_s,
+    dur_s)`` of the host's ``bench.`` / ``coord.`` / ``feed.``
+    annotations."""
     with open(trace_file(trace_dir), "rb") as f:
         space = _xspace_class().FromString(f.read())
-    events = []
+    events, host_spans = [], []
+    op_names: dict[str, dict[str, str]] = {}
     for plane in space.planes:
         names = {e.key: e.value.name for e in plane.event_metadata}
         pname = plane.name
+        if DEVICE_PLANE.match(pname):
+            stat_name = {e.key: e.value.name for e in plane.stat_metadata}
+            ops = op_names.setdefault(pname, {})
+            for entry in plane.event_metadata:
+                for st in entry.value.stats:
+                    if stat_name.get(st.metadata_id) == OP_NAME_STAT:
+                        ops[entry.value.name] = (
+                            st.str_value or stat_name.get(st.ref_value, "")
+                        )
         for line in plane.lines:
             lname, base = line.name, line.timestamp_ns * 1e-9
             for ev in line.events:
-                events.append((
-                    pname, lname, names.get(ev.metadata_id, ""),
-                    base + ev.offset_ps * 1e-12, ev.duration_ps * 1e-12,
-                ))
-    return events
+                name = names.get(ev.metadata_id, "")
+                start, dur = base + ev.offset_ps * 1e-12, ev.duration_ps * 1e-12
+                events.append((pname, lname, name, start, dur))
+                if pname == HOST_PLANE and name.startswith(HOST_SPANS):
+                    host_spans.append((line.id, name, start, dur))
+    return {"events": events, "op_names": op_names, "host_spans": host_spans}
+
+
+def load(trace_dir: str) -> list[tuple[str, str, str, float, float]]:
+    return load_trace(trace_dir)["events"]
 
 
 def short_name(name: str) -> str:

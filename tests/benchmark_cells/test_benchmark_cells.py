@@ -17,6 +17,7 @@ import re
 import shutil
 import subprocess
 import sys
+import time
 import types
 
 import numpy as np
@@ -26,7 +27,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import generate, readers, reference, roofline, run, trace_reduce
+import benchmark
+from benchmark import (
+    generate, readers, reference, roofline, run, span_readers, trace_reduce,
+)
 from benchmark.faults import FAULTS
 
 MANIFEST = run.read_json("BENCHMARK.json")
@@ -90,22 +94,68 @@ def test_cell_files_cross_reference(cell):
     assert run.metrics_of(MANIFEST, "per_layer", cell)
 
 
-@pytest.mark.parametrize("metric", PER_LAYER)
-def test_per_layer_metric_files(metric):
-    m = next(x for x in MANIFEST["per_layer"] if x["name"] == metric)
-    spec = run.read_json("benchmark", "metrics", f"{metric}.json")
-    assert spec["reader"] in readers.READERS and spec["what"]
+def _hold_metric_file(m, spec):
+    """What a ``per_layer`` entry and its metric file have to satisfy; the
+    reader is resolved as the harness resolves it."""
+    assert set(spec) <= {"reader", "args", "what"} and spec["what"]
+    assert callable(readers.resolve(spec["reader"]))
     assert m["moves"] in E2E and m["layer"]
     assert m["source"] in ("device_trace", "program_span", "program_counter",
                            "host_clock")
+    # every entry says which cells report it: a cell a later PR adds opts
+    # into a metric by name
     cells = [c for c in CELLS
              if m in run.metrics_of(MANIFEST, "per_layer", c)]
-    assert cells and set(m.get("workloads", cells)) == set(cells)
+    assert cells and set(m["workloads"]) == set(cells)
     for cell in cells:
         moved = [x["name"] for x in run.metrics_of(MANIFEST, "end_to_end", cell)]
         assert m["moves"] in moved
-    if metric.endswith("_roofline") or "_roofline." in metric:
+    if m["name"].endswith("_roofline") or "_roofline." in m["name"]:
         assert m["unit"] == "%"
+
+
+@pytest.mark.parametrize("metric", PER_LAYER)
+def test_per_layer_metric_files(metric):
+    m = next(x for x in MANIFEST["per_layer"] if x["name"] == metric)
+    _hold_metric_file(m, run.read_json("benchmark", "metrics", f"{metric}.json"))
+
+
+def test_every_metric_file_is_in_the_manifest():
+    files = {f[:-len(".json")]
+             for f in os.listdir(os.path.join(ROOT, "benchmark", "metrics"))}
+    assert files == set(PER_LAYER)
+    assert "assign_loop_ms.fill" not in files       # retired for assign_ms.fill
+
+
+@pytest.mark.parametrize("reader", [
+    "no_such_reader", "span_readers.no_such_function", "no_such_module.reader",
+    "span_readers.SCOPES", "benchmark.span_readers.setup_stage_s", "os.getcwd",
+    "../tools/x.f", ""])
+def test_a_metric_file_with_an_unknown_reader_fails(reader):
+    m = next(x for x in MANIFEST["per_layer"] if x["name"] == PER_LAYER[0])
+    spec = {"reader": reader, "args": {}, "what": "nothing"}
+    with pytest.raises(LookupError, match="reader"):
+        _hold_metric_file(m, spec)
+
+
+def test_a_reader_is_a_bare_name_or_a_function_of_a_module_of_its_own(
+        tmp_path, monkeypatch):
+    """The door for readers: a module beside the harness's, brought as a
+    new file, is named ``<module>.<function>`` and needs no registration.
+    (The file is written to a temporary directory that the ``benchmark``
+    package is told to look in, so the test adds nothing under it.)"""
+    assert readers.resolve("registry_stage_per_bind") is readers.registry_stage_per_bind
+    assert readers.resolve("span_readers.counter_share_pct") \
+        is span_readers.counter_share_pct
+    (tmp_path / "toy_readers.py").write_text(
+        "def binds_twice(args, ctx):\n"
+        "    return args['times'] * ctx['binds'] or None\n")
+    monkeypatch.setattr(benchmark, "__path__", [*benchmark.__path__, str(tmp_path)])
+    monkeypatch.delitem(sys.modules, "benchmark.toy_readers", raising=False)
+    reader = readers.resolve("toy_readers.binds_twice")
+    assert reader({"times": 2}, {"binds": 21}) == 42
+    assert reader({"times": 2}, {"binds": 0}) is None
+    sys.modules.pop("benchmark.toy_readers", None)
 
 
 def test_peaks_table_names_its_source():
@@ -322,6 +372,56 @@ def test_a_cell_that_fills_to_the_brim_offers_whole_waves_that_fit():
         assert free.most > 1 << 40
 
 
+# ---- the door for traffic: a shape's keys are the builder's keywords ------------
+
+
+def test_a_pods_file_key_reaches_the_pod():
+    """``tolerate_kwok`` is a keyword ``build_pod`` has and no pods file
+    used: a new pods file is all it takes, and a shape that leaves out
+    its requests gets ``build_pod``'s."""
+    from k8s1m_tpu.control.objects import decode_pod
+
+    params = {"shapes": [{"weight": 2, "tolerate_kwok": False},
+                         {"weight": 1, "cpu_milli": 250, "mem_kib": 1024}],
+              "source": "a test"}
+    p = generate.Pods(params, seed=(1 << 31) + 3)
+    p.verify(random.Random(0))
+    assert sorted(map(sorted, p.pattern)) == [
+        ["cpu_milli", "mem_kib"], ["tolerate_kwok"], ["tolerate_kwok"]]
+    assert sorted(p.requests("cpu_milli")) == [100, 100, 250]
+    assert sorted(p.requests("mem_kib")) == [1024, 200 << 10, 200 << 10]
+    for i, shape in enumerate(p.pattern):
+        pod = decode_pod(p.wave(i, 1)[0][1])
+        assert bool(pod.tolerations) == shape.get("tolerate_kwok", True)
+        assert pod.cpu_milli == shape.get("cpu_milli", 100)
+
+
+@pytest.mark.parametrize("shape,named", [
+    ({"cpu_milli": 100, "gpus": 1}, "'gpus'"),
+    ({"topology_spread": [{"max_skew": 1}]}, "'topology_spread'"),
+    ({"namespace": "mine"}, "'namespace'"), ({"prefix": "p"}, "'prefix'")])
+def test_a_pods_file_key_the_builder_does_not_take_fails_by_name(shape, named):
+    with pytest.raises(ValueError, match=f"pods shape: key {named}"):
+        generate.Pods({"shapes": [{"cpu_milli": 1, "mem_kib": 1}, shape]}, seed=1)
+
+
+def test_a_nodes_key_reaches_the_node_or_fails_by_name():
+    from k8s1m_tpu.control.objects import decode_node
+
+    _w, config, _p = run.load_cell(MANIFEST, CELLS[0])
+    small = {"count": 64, "cordon_every": 4, "zones": 3, "prefix": "edge"}
+    n = generate.Nodes(small)
+    n.verify(random.Random(0))
+    node = decode_node(n.items(5, 6)[0][1])
+    assert node.name == "edge-5" and n.name(5) == b"edge-5"
+    assert node.labels["topology.kubernetes.io/zone"] == "zone-2"
+    # what the comparison reads is build_node's default where the object has none
+    assert (n.cpu_milli, n.mem_kib, n.pods) == (32000, 64 << 20, 110)
+    assert (node.cpu_milli, node.pods) == (32000, 110)
+    with pytest.raises(ValueError, match="nodes: key 'taints'"):
+        generate.Nodes({**config["nodes"], "count": 64, "taints": ["x"]})
+
+
 # ---- trace reduction ------------------------------------------------------------
 
 DEV = "/device:TPU:0"
@@ -331,9 +431,9 @@ EVENTS = [
     (DEV, "XLA Modules", "jit_scatter_rows(2)", 3.0, 0.1),
     (DEV, "XLA Ops", "%while.7 = (s32[]) while(...)", 0.1, 0.8),
     (DEV, "XLA Ops", "%fusion.1 = fusion(...)", 0.2, 0.1),       # inside the while
-    (DEV, "XLA Ops", '%_call.1 = custom-call(...), custom_call_target="tpu_custom_call"', 0.0, 0.1),
+    (DEV, "XLA Ops", '%fused_topk.1 = custom-call(...), custom_call_target="tpu_custom_call"', 0.0, 0.1),
     (DEV, "XLA Ops", "%while.7 = (s32[]) while(...)", 2.1, 0.8),
-    (DEV, "XLA Ops", '%_call.1 = custom-call(...), custom_call_target="tpu_custom_call"', 2.0, 0.1),
+    (DEV, "XLA Ops", '%fused_topk.1 = custom-call(...), custom_call_target="tpu_custom_call"', 2.0, 0.1),
     ("/host:CPU", "python3", "bench.put", 0.0, 0.9),
     ("/host:CPU", "python3", "bench.step", 0.9, 1.2),
     ("/host:CPU", "python3", "bench.watch", 2.1, 0.9),
@@ -362,7 +462,7 @@ def test_per_name_sums_and_patterns():
         == (2.0, 2)
     top = trace_reduce.sums_by_name(EVENTS, DEV, "XLA Ops", top=2)
     assert trace_reduce.short_name(top[0][0]) == "%while.7"
-    assert trace_reduce.short_name(EVENTS[5][2]) == "%_call.1 tpu_custom_call"
+    assert trace_reduce.short_name(EVENTS[5][2]) == "%fused_topk.1 tpu_custom_call"
 
 
 def test_idle_gaps_go_to_the_span_that_covers_them():
@@ -375,29 +475,81 @@ def test_idle_gaps_go_to_the_span_that_covers_them():
     assert late["unattributed"] == pytest.approx(0.3) and "bench.watch" not in late
 
 
-def test_readers_on_a_synthetic_window():
-    ctx = {
+COLUMNS = {"cpu_alloc": (4, 1), "mem_alloc": (4, 1), "cpu_req": (4, 1),
+           "mem_req": (4, 1), "pods_req": (4, 1), "pods_alloc": (2, 1),
+           "meta": (4, 1), "taint_id": (2, 8), "label_key": (4, 16)}
+
+
+def _synthetic_ctx(**more):
+    return {
         "stage_s": {"drain": 1.0, "bind": 3.0, "sync_out": 5.0}, "binds": 1000,
         "trace": {"events": EVENTS, "plane": DEV},
-        "shapes": {"scan_rows": 53248, "bytes_per_row": 42, "batch": 4096,
+        "shapes": {"scan_rows": 53248, "columns": COLUMNS, "batch": 4096,
                    "k": 4, "pod_bytes": 16},
-        "peaks": {"hbm_bytes_per_s": 819e9},
+        "peaks": {"hbm_bytes_per_s": 819e9}, **more,
     }
-    spec = lambda m: run.read_json("benchmark", "metrics", f"{m}.json")
-    value = lambda m: readers.READERS[spec(m)["reader"]](spec(m)["args"], ctx)
-    assert value("host_us_per_bind.fill") == pytest.approx(4000.0)
-    assert value("store_bind_us_per_bind.fill") == pytest.approx(3000.0)
-    assert value("engine_step_ms.fill") == pytest.approx(1000.0)
-    assert value("assign_loop_ms.fill") == pytest.approx(800.0)
-    assert value("fused_topk_ms.fill") == pytest.approx(100.0)
+
+
+def _value(metric, ctx):
+    spec = run.read_json("benchmark", "metrics", f"{metric}.json")
+    return readers.resolve(spec["reader"])(spec["args"], ctx)
+
+
+def test_readers_on_a_synthetic_window():
+    ctx = _synthetic_ctx()
+    assert _value("host_us_per_bind.fill", ctx) == pytest.approx(4000.0)
+    assert _value("store_bind_us_per_bind.fill", ctx) == pytest.approx(3000.0)
+    assert _value("engine_step_ms.fill", ctx) == pytest.approx(1000.0)
+    assert _value("fused_topk_ms.fill", ctx) == pytest.approx(100.0)
     moved = 53248 * 42 + 4096 * 16 + 4096 * 4 * 8
-    assert value("fused_topk_roofline.fill") == pytest.approx(
+    assert _value("fused_topk_roofline.fill", ctx) == pytest.approx(
         100 * moved / 819e9 / 0.1)
     # nothing to read: no value, never a nought
-    assert readers.READERS["trace_roofline_pct"](
-        spec("fused_topk_roofline.fill")["args"], {**ctx, "trace": None}) is None
-    assert readers.READERS["registry_stage_per_bind"](
+    roof = run.read_json("benchmark", "metrics", "fused_topk_roofline.fill.json")
+    assert readers.trace_roofline_pct(roof["args"], {**ctx, "trace": None}) is None
+    assert readers.registry_stage_per_bind(
         {"stages": ["bind"]}, {**ctx, "binds": 0}) is None
+
+
+def test_a_stage_the_program_lacks_gives_no_value():
+    ctx = _synthetic_ctx()
+    assert readers.registry_stage_per_bind({"stages": ["encode"]}, ctx) is None
+    assert readers.registry_stage_per_bind(
+        {"stages": ["resync", "fallback"]}, ctx) is None
+    # some of them observed: those are summed
+    assert readers.registry_stage_per_bind(
+        {"stages": ["encode", "drain"]}, ctx) == pytest.approx(1000.0)
+    assert _value("encode_us_per_bind.fill", ctx) is None
+
+
+def test_the_kernel_is_matched_by_its_name_not_as_any_custom_call():
+    """A cell that runs a second Mosaic kernel does not sum it in."""
+    call = 'custom-call(...), custom_call_target="tpu_custom_call"'
+    more = EVENTS + [
+        (DEV, "XLA Ops", f"%delta_plane_topk.2 = {call}", 0.3, 0.4),
+        (DEV, "XLA Ops", f"%fused_topk_affinity.3 = {call}", 0.4, 0.4),
+    ]
+    ctx = _synthetic_ctx(trace={"events": more, "plane": DEV})
+    assert _value("fused_topk_ms.fill", ctx) == pytest.approx(100.0)
+    assert _value("fused_topk_roofline.fill", ctx) == \
+        _value("fused_topk_roofline.fill", _synthetic_ctx())
+    # without the kernel in the trace: nothing, not 0
+    bare = [e for e in EVENTS if "fused_topk" not in e[2]]
+    ctx = _synthetic_ctx(trace={"events": bare, "plane": DEV})
+    assert _value("fused_topk_ms.fill", ctx) is None
+    assert _value("fused_topk_roofline.fill", ctx) is None
+
+
+def test_the_roofline_counts_the_columns_its_metric_file_names():
+    roof = run.read_json("benchmark", "metrics", "fused_topk_roofline.fill.json")
+    ctx = _synthetic_ctx()
+    base = readers.trace_roofline_pct(roof["args"], ctx)
+    wider = readers.trace_roofline_pct(
+        {**roof["args"], "columns": [*roofline.BASE_COLUMNS, "label_key"]}, ctx)
+    rows, rest = 53248, 4096 * 16 + 4096 * 4 * 8
+    assert wider / base == pytest.approx((rows * (42 + 64) + rest) / (rows * 42 + rest))
+    with pytest.raises(KeyError, match="zone"):
+        readers.trace_roofline_pct({**roof["args"], "columns": ["zone"]}, ctx)
 
 
 def test_xplane_loader_reads_what_jax_reads(tmp_path):
@@ -428,10 +580,7 @@ def test_xplane_loader_reads_what_jax_reads(tmp_path):
 def test_roofline_bytes_for_both_deployments():
     assert roofline.window_rows(1 << 20, 5, 4096) == 53248
     assert roofline.window_rows(16384, 100, 4096) == 16384
-    cols = {"cpu_alloc": (4, 1), "mem_alloc": (4, 1), "cpu_req": (4, 1),
-            "mem_req": (4, 1), "pods_req": (4, 1), "pods_alloc": (2, 1),
-            "meta": (4, 1), "taint_id": (2, 8), "label_key": (4, 16)}
-    per_row = roofline.row_bytes(cols)
+    per_row = roofline.row_bytes(COLUMNS)
     assert per_row == 42
     kwok = roofline.wave_bytes(scan_rows=53248, bytes_per_row=per_row,
                                batch=4096, k=4, pod_bytes=16)
@@ -482,9 +631,171 @@ def test_rehearsal_prints_a_well_formed_line(cell, capsys):
     for m in line["metrics"].values():
         assert m["value"] > 0 and UNIT.match(m["unit"])
     assert all(c["value"] == c["limit"] == 0 for c in line["compared"].values())
+    assert set(line["compared"]) == TWELVE      # no reference key: today's twelve
     err = capsys.readouterr().err.strip().splitlines()
     assert err[-1] == "correct=True failed_pods=0"
     assert err[-2].startswith("compared ") and " limit=0" in err[-2]
+
+
+TWELVE = {"never_bound", "bound_twice", "unknown_node", "bound_to_cordoned",
+          "overcommitted_nodes", "deleted", "store_disagrees",
+          "mirror_rows_wrong", "device_rows_wrong", "compiled_in_window",
+          "fell_back", "watch_dropped"}
+
+
+def test_a_pods_file_with_a_new_key_runs_a_cell_to_correct():
+    workload, config, _pods = _tiny(CELLS[0])
+    pods = {"shapes": [{"weight": 3, "tolerate_kwok": False},
+                       {"weight": 1, "cpu_milli": 250, "mem_kib": 4096}],
+            "source": "a test"}
+    line = run.run_cell(
+        MANIFEST, CELLS[0], (workload, config, pods), seed=(1 << 31) + 12,
+        seconds=0.3, trace=False, device=dict(CPU_DEVICE), peaks={},
+    )
+    assert line["correct"] is True and line["attempted"] > 0
+    assert set(line["compared"]) == TWELVE
+
+
+# ---- the door for guarantees: a configuration's own reference -----------------
+
+TOY_REFERENCES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                              "references")
+
+
+def _rehearse_with_reference(monkeypatch, name, cell="fit-10k.fill"):
+    monkeypatch.setattr(run, "REFERENCES_DIR", TOY_REFERENCES)
+    workload, config, pods = _tiny(cell)
+    config["reference"] = name
+    return run.run_cell(
+        MANIFEST, cell, (workload, config, pods), seed=(1 << 31) + 13,
+        seconds=0.3, trace=False, device=dict(CPU_DEVICE), peaks={},
+    )
+
+
+def test_a_configurations_own_reference_is_merged_with_limit_nought(monkeypatch):
+    """A guarantee no sound run keeps (no pod on an even-numbered node)
+    turns a sound tiny run not correct; the twelve are compared all the same."""
+    line = _rehearse_with_reference(monkeypatch, "even_nodes")
+    assert line["correct"] is False and line["failed"] == 0
+    assert set(line["compared"]) == TWELVE | {"bound_to_even_node"}
+    assert line["compared"]["bound_to_even_node"]["limit"] == 0
+    assert line["compared"]["bound_to_even_node"]["value"] > 100
+    assert all(line["compared"][k]["value"] == 0 for k in TWELVE)
+    assert list(line)[-1] == "compared"
+
+
+def test_a_reference_whose_guarantee_holds_leaves_the_run_correct(monkeypatch):
+    line = _rehearse_with_reference(monkeypatch, "quiet", cell=CELLS[0])
+    assert line["correct"] is True
+    assert line["compared"]["bound_past_the_last_node"] == {"value": 0, "limit": 0}
+
+
+def test_a_reference_may_not_take_a_base_numbers_name(monkeypatch):
+    with pytest.raises(RuntimeError, match="device_rows_wrong"):
+        _rehearse_with_reference(monkeypatch, "collides")
+
+
+def test_a_reference_that_is_not_there_is_an_error(monkeypatch):
+    with pytest.raises(SystemExit, match="no_such_reference"):
+        _rehearse_with_reference(monkeypatch, "no_such_reference")
+
+
+def test_a_reference_imports_nothing():
+    """Neither the program nor the harness: what it is given is all it has."""
+    dirs = [TOY_REFERENCES, run.REFERENCES_DIR]
+    files = [os.path.join(d, f) for d in dirs if os.path.isdir(d)
+             for f in os.listdir(d) if f.endswith(".py")]
+    assert len(files) >= 3
+    for path in files:
+        with open(path) as f:
+            src = f.read()
+        assert not re.search(r"^\s*(from|import)\s+(k8s1m_tpu|benchmark)\b", src, re.M)
+        assert "def numbers(seen, replayed, *, nodes, pattern, offered)" in src
+    assert not any("reference" in run.read_json(c["file"]) for c in MANIFEST["configs"])
+
+
+# ---- the traced part of a window that closes at the brim ----------------------
+
+
+@pytest.mark.parametrize("case,want", [
+    # no trace asked for
+    (dict(now=19.5, trace_seconds=0.0), False),
+    # the clock: one second before the deadline, brim or none
+    (dict(now=18.9), False), (dict(now=19.0), True),
+    (dict(now=19.0, most=1 << 62), True), (dict(now=18.9, most=1 << 62), False),
+    # the pods: 100 waves in 10 s is 0.1 s a wave; 10 waves left are 1.0 s
+    (dict(now=10.0, offered=103 * 128, most=114 * 128), False),
+    (dict(now=10.0, offered=103 * 128, most=113 * 128), True),
+    (dict(now=10.0, offered=103 * 128, most=113 * 128 + 127), True),
+    # at twice the pace the same ten waves are half a second: five more waves to go
+    (dict(now=5.0, offered=103 * 128, most=113 * 128, trace_seconds=0.5), True),
+    (dict(now=5.0, offered=103 * 128, most=124 * 128), False),
+    (dict(now=5.0, offered=103 * 128, most=123 * 128), True),
+    # a cell with no brim never starts by its pods; nothing offered yet, no pace
+    (dict(now=10.0, offered=103 * 128, most=1 << 62), False),
+    (dict(now=0.0, offered=3 * 128, most=4 * 128), False),
+])
+def test_the_trace_starts_by_the_clock_or_by_the_pods_left(case, want):
+    base = dict(t0=0.0, deadline=20.0, trace_seconds=1.0, first=3 * 128,
+                offered=103 * 128, most=1000 * 128, wave=128)
+    assert run.trace_due(**{**base, **case}) is want
+
+
+def test_a_cell_that_reaches_its_brim_early_asks_for_its_trace_before_it(
+        monkeypatch):
+    """A tiny cell that fills to the brim, with a deadline far beyond it:
+    the window closes at the brim, and the trace is asked for
+    ``trace_seconds`` of waves before that (no profiler on the CPU: the
+    start and the stop are counted, not made)."""
+    import jax
+    from k8s1m_tpu.store.native import MemStore
+
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda *a, **kw: calls.append(("start", time.perf_counter())))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append(("stop", time.perf_counter())))
+    workload, config, pods = _tiny("fit-10k.fill")
+    trace_seconds = 0.04        # some fifteen of its fifty-five waves here
+    with MemStore() as store:
+        cell = run.Cell(store, config, workload, pods, seed=(1 << 31) + 14)
+        try:
+            cell.setup()
+            win = cell.window(600.0, trace_seconds)
+            assert cell.brim() == 0             # the window got there itself
+            cell.idle()
+            out = cell.check(win)
+        finally:
+            cell.close()
+    assert [c[0] for c in calls] == ["start", "stop"]
+    assert win["last"] == cell.most and win["t1"] - win["t0"] < 300
+    assert win["traced"] and set(out["numbers"].values()) == {0}
+    waves = (win["last"] - win["first"]) // cell.wave
+    pace = (win["t1"] - win["t0"]) / waves
+    # trace_seconds, give or take a wave and what this host's pace wanders
+    assert 0 < win["traced_s"] < trace_seconds + 3 * pace + 0.5
+    assert win["t0"] < calls[0][1] < win["t1"] < calls[1][1]
+
+
+def test_a_cell_without_a_brim_starts_its_trace_by_the_clock_alone(monkeypatch):
+    import jax
+    from k8s1m_tpu.store.native import MemStore
+
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda *a, **kw: calls.append("start"))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: calls.append("stop"))
+    workload, config, pods = _tiny(CELLS[0])
+    with MemStore() as store:
+        cell = run.Cell(store, config, workload, pods, seed=(1 << 31) + 15)
+        try:
+            cell.setup()
+            win = cell.window(0.6, 0.3)
+            cell.idle()
+        finally:
+            cell.close()
+    assert calls == ["start", "stop"] and cell.most > 1 << 40
+    assert 0.6 <= win["t1"] - win["t0"] and 0 < win["traced_s"] < 0.3 + 0.5
 
 
 # the control first, then each fault a one-chip cell can have

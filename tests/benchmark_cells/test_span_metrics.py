@@ -1,11 +1,11 @@
 """The readers of what the program names (benchmark/span_readers.py), on
-synthetic events; the eleven metric specs; the accepted metrics unmoved;
-and tools/span_report.py around the unedited harness at a tiny size.
+synthetic events; the eleven metrics that read them, in the manifest since
+PR 27; the kept metrics unmoved; and the harness itself at a tiny size, with
+the context it hands the readers kept.
 """
 
 from __future__ import annotations
 
-import importlib.util
 import os
 import sys
 
@@ -17,10 +17,22 @@ if ROOT not in sys.path:
 
 from benchmark import readers, run, span_readers, trace_reduce
 from test_benchmark_cells import (
-    CELLS, CPU_DEVICE, DEV, E2E, EVENTS, MANIFEST, NAME, UNIT, _tiny,
+    CELLS, COLUMNS, CPU_DEVICE, DEV, E2E, EVENTS, MANIFEST, NAME, UNIT, _tiny,
 )
 
-SPECS = run.read_json("benchmark", "span_metrics.json")["metrics"]
+# the eleven that read spans, scopes and counters (PR 25), as PR 27 entered them
+SPAN_METRICS = [
+    "candidates_ms.fill", "assign_ms.fill", "commit_ms.fill",
+    "drain_poll_us_per_bind.fill", "drain_apply_us_per_bind.fill",
+    "bind_cas_us_per_bind.fill", "intake_fast_lane_pct.fill",
+    "requeued_pct.fill", "bootstrap_s", "bootstrap_ingest_s",
+    "bulkload_per_node_pct",
+]
+KEPT_METRICS = [
+    "engine_step_ms.fill", "fused_topk_ms.fill", "fused_topk_roofline.fill",
+    "host_us_per_bind.fill", "store_bind_us_per_bind.fill",
+    "encode_us_per_bind.fill", "drain_us_per_bind.fill",
+]
 WAVES = {"wave_line": "XLA Modules", "wave_pattern": r"^jit__lambda\("}
 # the accepted fixture, with the second while's body in it too; and its
 # device ops by the scope XLA's tf_op stat would give them: the while has
@@ -168,18 +180,28 @@ def test_setup_stages_are_read_from_before_the_window():
 def test_snapshots_read_the_programs_registry():
     import k8s1m_tpu.control.coordinator  # noqa: F401  (registers the counters)
 
+    from k8s1m_tpu.obs.metrics import REGISTRY, Counter
+
     snap = span_readers.snapshot_counters(
-        ["coordinator_pod_intake_total", "no_such_counter_total"])
+        ["coordinator_pod_intake_total", "no_such_counter_total",
+         "coordinator_cycle_seconds"])          # a histogram is no counter
     assert set(snap) == {"coordinator_pod_intake_total"}
     assert all(k[0][0] == "lane" for k in snap["coordinator_pod_intake_total"])
     assert isinstance(span_readers.stage_sums(), dict)
+    # with no names: every counter the registry holds, one a later PR adds too
+    every = span_readers.snapshot_counters()
+    assert set(every) == {m.name for m in REGISTRY.metrics()
+                          if isinstance(m, Counter)}
+    assert {"coordinator_pod_intake_total", "coordinator_pod_shapes_total",
+            "coordinator_pods_scheduled_total", "bulkload_values_total"} <= set(every)
+    assert every["coordinator_pod_intake_total"] == snap["coordinator_pod_intake_total"]
 
 
-# ---- the loader beside trace_reduce.load ----------------------------------
+# ---- the one loader ---------------------------------------------------------
 
 
 def test_loader_reads_op_names_and_line_ids(tmp_path):
-    space = span_readers._xspace_class()()
+    space = trace_reduce._xspace_class()()
     dev = space.planes.add(name=DEV)
     dev.stat_metadata.add(key=7).value.name = "tf_op"
     dev.stat_metadata.add(key=8).value.name = "hlo_category"
@@ -192,113 +214,198 @@ def test_loader_reads_op_names_and_line_ids(tmp_path):
     host.event_metadata.add(key=1).value.name = "coord.drain"
     host.event_metadata.add(key=2).value.name = "PjRtExecute"
     for line_id in (11, 12):                # two threads, as two lines do
-        line = host.lines.add(id=line_id, timestamp_ns=2_000_000_000)
+        line = host.lines.add(id=line_id, name="python3",
+                              timestamp_ns=2_000_000_000)
         line.events.add(metadata_id=1, offset_ps=500_000_000_000,
                         duration_ps=250_000_000_000)
         line.events.add(metadata_id=2, offset_ps=0, duration_ps=1)
     out = tmp_path / "plugins" / "profile" / "run"
     out.mkdir(parents=True)
     (out / "host.xplane.pb").write_bytes(space.SerializeToString())
-    got = span_readers.load_names(str(tmp_path))
+    got = trace_reduce.load_trace(str(tmp_path))
     assert got["op_names"] == {DEV: {
         "%fusion.1 = fusion(...)": "jit(f)/assign/while/body/add:"}}
     assert got["host_spans"] == [
         (11, "coord.drain", pytest.approx(2.5), pytest.approx(0.25)),
         (12, "coord.drain", pytest.approx(2.5), pytest.approx(0.25)),
     ]
-    # trace_reduce's own loader reads the same file
-    assert (DEV, ) not in trace_reduce.load(str(tmp_path))
+    # one parse gives the events too, every one of them; the two older
+    # entry points are views of it
+    assert sorted(e[:3] for e in got["events"]) == sorted(
+        [("/host:CPU", "python3", "coord.drain"),
+         ("/host:CPU", "python3", "PjRtExecute")] * 2)
+    assert trace_reduce.load(str(tmp_path)) == got["events"]
+    assert span_readers.load_names(str(tmp_path)) == {
+        "op_names": got["op_names"], "host_spans": got["host_spans"]}
+    assert not hasattr(span_readers, "_xspace_class")
 
 
-# ---- the eleven specs, and the eight accepted metrics ----------------------
+# ---- the eleven, and the seven kept metrics ----------------------------------
 
 
-@pytest.mark.parametrize("spec", SPECS, ids=[m["name"] for m in SPECS])
-def test_span_metric_spec(spec):
-    assert set(spec) == {"name", "unit", "better", "source", "layer", "moves",
-                         "reader", "args", "what"}
-    assert NAME.match(spec["name"]) and UNIT.match(spec["unit"])
-    assert spec["better"] in ("lower", "higher") and spec["what"]
-    assert spec["source"] in ("device_trace", "program_counter")
-    assert spec["moves"] in E2E
-    assert spec["reader"] in {**readers.READERS, **span_readers.READERS}
-    # not in the manifest until run.py can feed the reader (PERF.md section 7)
-    assert spec["name"] not in {m["name"] for m in MANIFEST["per_layer"]}
-    assert [m["name"] for m in SPECS].count(spec["name"]) == 1
-    layers = {m["layer"] for m in MANIFEST["per_layer"]} | {"snapshot"}
-    assert spec["layer"] in layers
+def test_the_manifest_holds_the_eleven_and_the_seven():
+    assert [m["name"] for m in MANIFEST["per_layer"]] == KEPT_METRICS + SPAN_METRICS
+    by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
+    # the two restated: a sound reading of exactly 0 has no ratio to keep
+    assert "intake_slow_lane_pct.fill" not in by_name
+    assert by_name["intake_fast_lane_pct.fill"]["better"] == "higher"
+    assert by_name["requeued_pct.fill"]["workloads"] == ["fit-10k.fill"]
+    assert all(by_name[m]["workloads"] == CELLS
+               for m in by_name if m != "requeued_pct.fill")
+    assert {by_name[m]["moves"] for m in SPAN_METRICS[-3:]} == {"setup_s"}
+    assert {by_name[m]["layer"] for m in SPAN_METRICS[-3:]} == {"snapshot"}
+
+
+@pytest.mark.parametrize("metric", SPAN_METRICS)
+def test_span_metric_spec(metric):
+    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == metric)
+    spec = run.read_json("benchmark", "metrics", f"{metric}.json")
+    assert set(entry) == {"name", "unit", "better", "source", "layer", "moves",
+                          "workloads"}
+    assert set(spec) == {"reader", "args", "what"} and spec["what"]
+    assert NAME.match(metric) and UNIT.match(entry["unit"])
+    assert entry["source"] in ("device_trace", "program_counter")
+    assert entry["moves"] in E2E
+    reader = readers.resolve(spec["reader"])
+    if "." in spec["reader"]:
+        assert reader is getattr(span_readers, spec["reader"].split(".")[1])
+    else:
+        assert reader is readers.registry_stage_per_bind
 
 
 def test_span_readers_take_no_accepted_readers_name():
     assert not set(span_readers.READERS) & set(readers.READERS)
-    assert {m["reader"] for m in SPECS} >= set(span_readers.READERS)
+    named = {run.read_json("benchmark", "metrics", f"{m}.json")["reader"]
+             for m in SPAN_METRICS}
+    assert named >= {f"span_readers.{r}" for r in span_readers.READERS}
 
 
-def test_the_accepted_metrics_read_what_they_read():
-    """With the program's spans in the trace, op_names beside it and every
-    stage label in ``stage_s``, the eight accepted metrics read the values
-    test_readers_on_a_synthetic_window pins."""
-    events = EVENTS + [
+def _full_ctx():
+    events = OPS + [
         ("/host:CPU", "python3", n, s, d) for _l, n, s, d in HOST
         if n.startswith(("coord.", "feed."))
     ]
-    ctx = {
+    return {
         "stage_s": {"drain": 1.0, "drain_poll": 0.1, "drain_apply": 0.9,
                     "bind": 3.0, "bind_cas": 1.0, "sync_out": 5.0},
+        "setup_stage_s": {"bootstrap": 26.0, "bootstrap_ingest": 24.0},
+        "counters": {
+            "open": {"coordinator_pod_intake_total": {
+                         (("lane", "batch_fast"),): 384.0, (("lane", "json"),): 7.0},
+                     "coordinator_pods_scheduled_total": {
+                         (("outcome", "bound"),): 384.0},
+                     "bulkload_values_total": {
+                         (("path", "per_node"),): 750.0, (("path", "template"),): 250.0}},
+            "close": {"coordinator_pod_intake_total": {
+                          (("lane", "batch_fast"),): 1284.0, (("lane", "json"),): 107.0,
+                          (("lane", "delete"),): 5.0},
+                      "coordinator_pods_scheduled_total": {
+                          (("outcome", "bound"),): 1334.0, (("outcome", "retry"),): 50.0},
+                      "bulkload_values_total": {
+                          (("path", "per_node"),): 750.0, (("path", "template"),): 250.0}},
+        },
         "binds": 1000,
         "trace": {"events": events, "plane": DEV, "op_names": OP_NAMES,
                   "host_spans": HOST},
-        "shapes": {"scan_rows": 53248, "bytes_per_row": 42, "batch": 4096,
+        "shapes": {"scan_rows": 53248, "columns": COLUMNS, "batch": 4096,
                    "k": 4, "pod_bytes": 16},
         "peaks": {"hbm_bytes_per_s": 819e9},
     }
-    spec = lambda m: run.read_json("benchmark", "metrics", f"{m}.json")
-    value = lambda m: readers.READERS[spec(m)["reader"]](spec(m)["args"], ctx)
+
+
+def test_every_manifest_metric_reads_a_full_context():
+    """With the program's spans in the trace, op_names beside it, every
+    stage label and both counter snapshots, ``run.per_layer_values`` reads
+    all eighteen: the seven kept ones what test_readers_on_a_synthetic_window
+    pins (``encode`` is no stage of this window: no value, not 0)."""
     moved = 53248 * 42 + 4096 * 16 + 4096 * 4 * 8
-    assert {m["name"]: value(m["name"]) for m in MANIFEST["per_layer"]} == \
-        pytest.approx({
-            "engine_step_ms.fill": 1000.0, "assign_loop_ms.fill": 800.0,
-            "fused_topk_ms.fill": 100.0,
+    for cell in CELLS:
+        got = run.per_layer_values(MANIFEST, cell, _full_ctx())
+        assert got.pop("encode_us_per_bind.fill") is None
+        assert got.pop("commit_ms.fill") is None    # nothing under commit here
+        if cell == "fit-10k.fill":
+            assert got.pop("requeued_pct.fill") == pytest.approx(5.0)
+        assert got == pytest.approx({
+            "engine_step_ms.fill": 1000.0, "fused_topk_ms.fill": 100.0,
             "fused_topk_roofline.fill": 100 * moved / 819e9 / 0.1,
             "host_us_per_bind.fill": 4000.0, "store_bind_us_per_bind.fill": 3000.0,
-            "encode_us_per_bind.fill": 0.0, "drain_us_per_bind.fill": 1000.0,
+            "drain_us_per_bind.fill": 1000.0,
+            "candidates_ms.fill": 100.0, "assign_ms.fill": 800.0,
+            "drain_poll_us_per_bind.fill": 100.0,
+            "drain_apply_us_per_bind.fill": 900.0,
+            "bind_cas_us_per_bind.fill": 1000.0,
+            "intake_fast_lane_pct.fill": 90.0,      # 900 of 1000; deletes apart
+            "bootstrap_s": 26.0, "bootstrap_ingest_s": 24.0,
+            "bulkload_per_node_pct": 75.0,
         })
 
 
-# ---- tools/span_report.py around the unedited harness ----------------------
+def test_assign_ms_reads_what_the_retired_loop_metric_read():
+    """``assign_loop_ms.fill`` matched ``^%while``; ``assign_ms.fill`` reads
+    the assign scope, whatever is under it: the same 800 ms on the fixture,
+    and still 800 when the loop is rebuilt without a ``while``."""
+    ctx = _full_ctx()
+    assert run.per_layer_values(MANIFEST, CELLS[0], ctx)["assign_ms.fill"] \
+        == pytest.approx(800.0)
+    loop = trace_reduce.per_event(OPS, DEV, "XLA Ops", r"^%while[.\d]* = ")
+    assert 1e3 * loop[0] / 2 == pytest.approx(800.0)
+    renamed = [(p, l, n.replace("%while.7", "%scan_fusion.7"), s, d)
+               for p, l, n, s, d in ctx["trace"]["events"]]
+    ctx["trace"] = {**ctx["trace"], "events": renamed}
+    assert trace_reduce.per_event(renamed, DEV, "XLA Ops", r"^%while")[1] == 0
+    assert run.per_layer_values(MANIFEST, CELLS[0], ctx)["assign_ms.fill"] \
+        == pytest.approx(800.0)
 
 
-def _span_report():
-    spec = importlib.util.spec_from_file_location(
-        "span_report", os.path.join(ROOT, "tools", "span_report.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+# ---- the harness itself at a tiny size, its readers' context kept ------------
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_span_report_reads_the_counters_of_a_tiny_run(cell):
-    tool = _span_report()
-    result = tool.report(
+def test_the_harness_reads_the_counters_of_a_tiny_run(cell):
+    from k8s1m_tpu.obs.metrics import REGISTRY
+
+    shapes = REGISTRY.get("coordinator_pod_shapes_total")
+    interned = shapes.value(event="interned")
+    before = span_readers.snapshot_counters(["bulkload_values_total"])
+    ctx = {}
+    result = run.run_cell(
         MANIFEST, cell, _tiny(cell), seed=(1 << 31) + 25, seconds=0.5,
-        trace=False, device=dict(CPU_DEVICE), peaks={},
+        trace=False, device=dict(CPU_DEVICE), peaks={}, keep=ctx,
     )
     assert result["correct"] is True
-    assert run.Cell is not tool.SpanCell and run.read_trace is not tool.read_trace
-    got = result["span_metrics"]
-    untraced = {m["name"] for m in SPECS if m["source"] != "device_trace"}
-    assert set(got) == untraced and len(untraced) == 8
-    assert got["intake_slow_lane_pct.fill"]["value"] == 100.0
-    assert got["bulkload_per_node_pct"]["value"] == 100.0     # cordons in its one chunk
-    assert 0 < got["bootstrap_ingest_s"]["value"] < got["bootstrap_s"]["value"]
-    assert got["drain_poll_us_per_bind.fill"]["value"] \
-        + got["drain_apply_us_per_bind.fill"]["value"] > 0
-    # the window's lane counts are the pods it offered, give or take the
-    # wave in flight at each edge
-    ctx = tool.SpanCell.last.ctx
-    lanes = lambda at: sum(
-        ctx["counters"][at]["coordinator_pod_intake_total"].values())
+    assert set(result["metrics"]) == {"binds_per_s", "setup_s"}
+    assert set(ctx) == {"stage_s", "setup_stage_s", "counters", "binds", "trace",
+                        "shapes", "peaks"}
+    got = {k: v for k, v in run.per_layer_values(MANIFEST, cell, ctx).items()
+           if v is not None}
+    manifest = {m["name"]: m for m in run.metrics_of(MANIFEST, "per_layer", cell)}
+    untraced = {m for m in manifest if manifest[m]["source"] != "device_trace"}
+    assert set(got) == untraced
+    assert len(untraced) == (12 if cell == "fit-10k.fill" else 11)
+    assert got["intake_fast_lane_pct.fill"] == 100.0
+    # a cordon in its one chunk: set-up ingested every node value one by one
+    # (the reader counts from the process's start, which is this run's on
+    # the chip and the test session's here: take this run's part)
+    ctx["counters"]["open"]["bulkload_values_total"] = {
+        key: n - before.get("bulkload_values_total", {}).get(key, 0.0)
+        for key, n in ctx["counters"]["open"]["bulkload_values_total"].items()}
+    assert run.per_layer_values(MANIFEST, cell, ctx)["bulkload_per_node_pct"] == 100.0
+    assert 0 < got["bootstrap_ingest_s"] < got["bootstrap_s"]
+    assert got["drain_poll_us_per_bind.fill"] + got["drain_apply_us_per_bind.fill"] > 0
+    assert got["bind_cas_us_per_bind.fill"] < got["store_bind_us_per_bind.fill"]
+    # the window rides batch_fast alone, on one interned shape; its lane
+    # counts are the pods it offered, give or take the wave in flight at
+    # each edge
+    lanes = lambda at: ctx["counters"][at]["coordinator_pod_intake_total"]
+    grown = {key[0][1]: n - lanes("open").get(key, 0)
+             for key, n in lanes("close").items()}
+    assert {lane for lane, n in grown.items() if n} == {"batch_fast"}
     wave = _tiny(cell)[0]["wave"]
-    assert abs((lanes("close") - lanes("open")) - result["attempted"]) <= 2 * wave
+    assert abs(grown["batch_fast"] - result["attempted"]) <= 2 * wave
+    assert shapes.value(event="interned") - interned == 1
     assert set(ctx["stage_s"]) >= {"drain", "drain_poll", "drain_apply",
                                    "bind", "bind_cas", "encode", "device"}
+    assert set(ctx["setup_stage_s"]) >= {"bootstrap", "bootstrap_ingest"}
+    # every counter of the registry, not a list of names
+    assert "coordinator_pod_shapes_total" in ctx["counters"]["close"]
+    assert len(ctx["counters"]["close"]) > 10

@@ -1,7 +1,10 @@
 """Ways to break the timed path underneath a run, from the benchmark's
 side (nothing in the program is edited; a fault that patches a module
 returns the call that undoes it).  Each is planted before the
-coordinator's bootstrap.
+coordinator's bootstrap.  ``--fault <name>`` is one of ``FAULTS`` below
+or, failing that, ``benchmark/controls/<name>.py`` (``load``): a
+configuration's own guarantee needs a control of its own, and that one
+arrives as a file.
 
 Controls of "How correct is decided", each breaking one guarantee the
 configuration states: ``lazy_bind`` (an acknowledged bind is read back
@@ -16,6 +19,11 @@ out false.
 """
 
 from __future__ import annotations
+
+import importlib.util
+import os
+
+CONTROLS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "controls")
 
 
 def _wrap_bind_batch(store, fn):
@@ -108,3 +116,25 @@ FAULTS = {f.__name__: f for f in (
     lazy_bind, filter_off, capacity_off, half_batch, answer_altered,
     state_unchanged,
 )}
+
+
+def names() -> list[str]:
+    """What ``--fault`` takes: the built-in ones and every control file."""
+    files = os.listdir(CONTROLS_DIR) if os.path.isdir(CONTROLS_DIR) else []
+    return sorted(set(FAULTS) | {f[:-3] for f in files if f.endswith(".py")})
+
+
+def load(name: str):
+    """``FAULTS[name]`` or, failing that, the ``plant`` function of
+    ``benchmark/controls/<name>.py``, loaded by path as a reference is:
+    ``plant(store, coord)`` is called where the built-in ones are and
+    returns the call that undoes it, or None."""
+    if name in FAULTS:
+        return FAULTS[name]
+    path = os.path.join(CONTROLS_DIR, f"{name}.py")
+    if not os.path.exists(path):
+        raise SystemExit(f"--fault {name!r}: not one of {sorted(FAULTS)} and no {path}")
+    spec = importlib.util.spec_from_file_location(f"benchmark_control_{name}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.plant

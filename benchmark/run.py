@@ -256,7 +256,7 @@ class Cell:
                 self.store, self.config, self.workload, self.seed
             )
             if fault:
-                self._undo = faults.FAULTS[fault](self.store, self.coord)
+                self._undo = faults.load(fault)(self.store, self.coord)
             self.coord.bootstrap()
         self._watch = self.store.watch(
             PODS_PREFIX, prefix_end(PODS_PREFIX), queue_cap=1 << 21
@@ -478,13 +478,23 @@ class Cell:
         }
 
     def shapes(self) -> dict:
-        """What roofline.py needs, from the live arrays' shapes alone."""
+        """What roofline.py needs, from the live arrays' shapes alone:
+        the node table's columns and, where the deployment keeps them, the
+        constraint planes that have the node axis (``[slots, N]``), each
+        as bytes per node row."""
         t, c = self.coord.table, self.config["coordinator"]
         cols = {
             name: (leaf.dtype.itemsize,
                    int(leaf.size // leaf.shape[0]) if leaf.shape[0] else 0)
             for name, leaf in vars(t).items() if hasattr(leaf, "dtype")
         }
+        cons = self.coord.constraints
+        if cons is not None:
+            cols.update({
+                name: (leaf.dtype.itemsize, int(leaf.shape[0]))
+                for name, leaf in vars(cons).items()
+                if getattr(leaf, "ndim", 0) == 2 and leaf.shape[1] == t.num_rows
+            })
         return {
             "scan_rows": roofline.window_rows(
                 t.num_rows, int(c.get("score_pct", 100)), int(c["chunk"])
@@ -675,7 +685,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    ap.add_argument("--fault", choices=sorted(faults.FAULTS), default=None,
+    ap.add_argument("--fault", choices=faults.names(), default=None,
                     help="break the timed path (controls; not a measurement)")
     ap.add_argument("--dump-trace", default=None,
                     help="write an overview of the trace to this file")

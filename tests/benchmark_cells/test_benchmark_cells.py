@@ -5,10 +5,18 @@ the plain reference, the trace reduction, the roofline bytes and the
 templates do what PERF.md says; the harness runs end to end at a tiny
 size, and with the timed path broken underneath ``correct`` comes out
 false — once for each control and once for each fault a cell can have.
+
+The tests of the manifest and its files hold rules, not a list of today's
+cells: each is a function of a ``Tree`` (a root with a manifest and the
+benchmark's files under it), registered with ``@rule`` and run here on
+the repo's own tree.  test_third_cell.py runs every one of them again on
+a second tree, the repo's files plus a third deployment brought as new
+files alone.
 """
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import json
 import os
@@ -29,51 +37,121 @@ if ROOT not in sys.path:
 
 import benchmark
 from benchmark import (
-    generate, readers, reference, roofline, run, span_readers, trace_reduce,
+    faults, generate, readers, reference, roofline, run, span_readers,
+    trace_reduce,
 )
 from benchmark.faults import FAULTS
 
-MANIFEST = run.read_json("BENCHMARK.json")
+HERE = os.path.dirname(os.path.abspath(__file__))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-CELLS = [w["name"] for w in MANIFEST["workloads"]]
-PER_LAYER = [m["name"] for m in MANIFEST["per_layer"]]
-E2E = {m["name"]: m for m in MANIFEST["end_to_end"]}
+
+
+class Tree:
+    """A checkout as the harness reads it: ``BENCHMARK.json`` at its root
+    and the benchmark's data files under ``benchmark/``; beside the
+    tests, ``cells/<cell>.json`` with what is particular to a cell."""
+
+    def __init__(self, root: str) -> None:
+        self.root = root
+        with open(os.path.join(root, "BENCHMARK.json")) as f:
+            self.manifest = json.load(f)
+        self.cells = [w["name"] for w in self.manifest["workloads"]]
+        self.per_layer = [m["name"] for m in self.manifest["per_layer"]]
+        self.e2e = {m["name"]: m for m in self.manifest["end_to_end"]}
+
+    @contextlib.contextmanager
+    def pointed(self):
+        """The harness reads this tree: every path it resolves, it
+        resolves from ``run.ROOT`` or from a directory named here."""
+        under = os.path.join(self.root, "benchmark")
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(run, "ROOT", self.root)
+            patch.setattr(run, "REFERENCES_DIR", os.path.join(under, "references"))
+            patch.setattr(faults, "CONTROLS_DIR", os.path.join(under, "controls"))
+            yield self
+
+    def cell_data(self, cell: str) -> dict:
+        """``tests/benchmark_cells/cells/<cell>.json``: what a tiny run of
+        this cell reads that no rule gives.  A cell without the file is
+        held to the rules alone."""
+        path = os.path.join(self.root, "tests", "benchmark_cells", "cells",
+                            f"{cell}.json")
+        if not os.path.exists(path):
+            return {}
+        with open(path) as f:
+            return json.load(f)
+
+
+REPO = Tree(ROOT)
+MANIFEST, CELLS, PER_LAYER, E2E = REPO.manifest, REPO.cells, REPO.per_layer, REPO.e2e
+# the cells the benchmark had when its tests were restated as rules (PR 28):
+# what is pinned, is pinned for these by name
+KWOK, FIT = "kwok-1m-pct5.fill", "fit-10k.fill"
+
+RULES = []      # (rule, what of the tree it is run over: None, "cells", "per_layer")
+
+
+def rule(over=None):
+    """Registers a rule of the manifest and its files: ``f(tree)``, or
+    ``f(tree, name)`` for every cell or every per-layer metric."""
+    def register(f):
+        RULES.append((f, over))
+        return f
+    return register
 
 
 # ---- the manifest and its files ---------------------------------------------
 
 
-def test_manifest_shape():
-    assert set(MANIFEST) == {
+@rule()
+def manifest_shape(tree):
+    manifest = tree.manifest
+    assert set(manifest) == {
         "command", "paths", "run_seconds", "configs", "workloads",
         "end_to_end", "per_layer",
     }
-    assert "setup_s" in E2E and E2E["setup_s"]["bound"] <= 0.25
-    assert 1 <= MANIFEST["run_seconds"] <= 51
-    names = [m["name"] for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]]
+    assert "setup_s" in tree.e2e and tree.e2e["setup_s"]["bound"] <= 0.25
+    assert 1 <= manifest["run_seconds"] <= 51
+    names = [m["name"] for m in manifest["end_to_end"] + manifest["per_layer"]]
     assert len(names) == len(set(names))
-    for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
+    assert len(tree.cells) == len(set(tree.cells))
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
         assert m["better"] in ("lower", "higher")
-    for m in MANIFEST["end_to_end"]:
+        # a list of cells names cells of this manifest, each once
+        listed = m.get("workloads", [])
+        assert set(listed) <= set(tree.cells) and len(listed) == len(set(listed))
+    for m in manifest["end_to_end"]:
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.25
-    chips4 = sum(w["chips"] == 4 for w in MANIFEST["workloads"])
-    assert chips4 <= max(1, len(CELLS) // 2)
+    chips4 = sum(w["chips"] == 4 for w in manifest["workloads"])
+    assert chips4 <= max(1, len(tree.cells) // 2)
+    used = {w["config"] for w in manifest["workloads"]}
+    assert used == {c["name"] for c in manifest["configs"]}
+
+
+def test_manifest_shape():
+    manifest_shape(REPO)
+
+
+@rule()
+def every_file_under_paths_is_named_plainly(tree):
+    ok = re.compile(r"^[A-Za-z0-9_.\-/]+$")
+    for path in tree.manifest["paths"]:
+        for d, dirs, files in os.walk(os.path.join(tree.root, path)):
+            dirs[:] = [x for x in dirs if x != "__pycache__"]
+            for f in files:
+                assert ok.match(os.path.relpath(os.path.join(d, f), tree.root))
 
 
 def test_every_file_under_paths_is_named_plainly():
-    ok = re.compile(r"^[A-Za-z0-9_.\-/]+$")
-    for path in MANIFEST["paths"]:
-        for d, dirs, files in os.walk(os.path.join(ROOT, path)):
-            dirs[:] = [x for x in dirs if x != "__pycache__"]
-            for f in files:
-                assert ok.match(os.path.relpath(os.path.join(d, f), ROOT))
+    every_file_under_paths_is_named_plainly(REPO)
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_cell_files_cross_reference(cell):
+@rule("cells")
+def cell_files_cross_reference(tree, cell):
+    MANIFEST = tree.manifest
     workload, config, pods = run.load_cell(MANIFEST, cell)
     entry = next(w for w in MANIFEST["workloads"] if w["name"] == cell)
     cfg = next(c for c in MANIFEST["configs"] if c["name"] == entry["config"])
@@ -92,11 +170,20 @@ def test_cell_files_cross_reference(cell):
     e2e = [m["name"] for m in run.metrics_of(MANIFEST, "end_to_end", cell)]
     assert "setup_s" in e2e and len(e2e) >= 2
     assert run.metrics_of(MANIFEST, "per_layer", cell)
+    # what the tests keep of a cell beside them, where they keep anything
+    assert set(tree.cell_data(cell)) <= {
+        "untraced_metrics", "window_lanes", "shapes_interned", "values"}
 
 
-def _hold_metric_file(m, spec):
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_files_cross_reference(cell):
+    cell_files_cross_reference(REPO, cell)
+
+
+def _hold_metric_file(m, spec, tree=REPO):
     """What a ``per_layer`` entry and its metric file have to satisfy; the
     reader is resolved as the harness resolves it."""
+    MANIFEST, CELLS, E2E = tree.manifest, tree.cells, tree.e2e
     assert set(spec) <= {"reader", "args", "what"} and spec["what"]
     assert callable(readers.resolve(spec["reader"]))
     assert m["moves"] in E2E and m["layer"]
@@ -114,17 +201,31 @@ def _hold_metric_file(m, spec):
         assert m["unit"] == "%"
 
 
+@rule("per_layer")
+def per_layer_metric_files(tree, metric):
+    m = next(x for x in tree.manifest["per_layer"] if x["name"] == metric)
+    _hold_metric_file(
+        m, run.read_json("benchmark", "metrics", f"{metric}.json"), tree)
+
+
 @pytest.mark.parametrize("metric", PER_LAYER)
 def test_per_layer_metric_files(metric):
-    m = next(x for x in MANIFEST["per_layer"] if x["name"] == metric)
-    _hold_metric_file(m, run.read_json("benchmark", "metrics", f"{metric}.json"))
+    per_layer_metric_files(REPO, metric)
+
+
+@rule()
+def every_metric_file_is_in_the_manifest(tree):
+    files = {f[:-len(".json")]
+             for f in os.listdir(os.path.join(tree.root, "benchmark", "metrics"))}
+    assert files == set(tree.per_layer)
+    assert "assign_loop_ms.fill" not in files       # retired for assign_ms.fill
+    # and every cell's file beside the tests is a cell's
+    cells_dir = os.path.join(tree.root, "tests", "benchmark_cells", "cells")
+    assert {f[:-len(".json")] for f in os.listdir(cells_dir)} <= set(tree.cells)
 
 
 def test_every_metric_file_is_in_the_manifest():
-    files = {f[:-len(".json")]
-             for f in os.listdir(os.path.join(ROOT, "benchmark", "metrics"))}
-    assert files == set(PER_LAYER)
-    assert "assign_loop_ms.fill" not in files       # retired for assign_ms.fill
+    every_metric_file_is_in_the_manifest(REPO)
 
 
 @pytest.mark.parametrize("reader", [
@@ -310,17 +411,24 @@ def test_shape_pattern_same_set_another_order():
     assert generate.shape_pattern(params, 1) == a
 
 
-def test_templates_equal_the_programs_encoders():
+@rule()
+def templates_equal_the_programs_encoders(tree):
     rng = random.Random(0)
-    for cell in CELLS:
-        _w, config, pods = run.load_cell(MANIFEST, cell)
+    for cell in tree.cells:
+        _w, config, pods = run.load_cell(tree.manifest, cell)
         generate.Nodes({**config["nodes"], "count": 4096}).verify(rng)
         generate.Pods(pods, seed=(1 << 31) + 7).verify(rng)
-    p = generate.Pods(pods, seed=5)
+    # the pod make_pods makes: pinned for the reference's own cell by name,
+    # whatever pods a later cell sends
+    p = generate.Pods(run.load_cell(tree.manifest, KWOK)[2], seed=5)
     key, val = p.wave(41, 1)[0]
     assert key == p.key(41) == b"/registry/pods/b5/bench-pod-41"
     assert json.loads(val)["metadata"] == {
         "name": "bench-pod-41", "namespace": "b5", "labels": {"app": "bench-pod"}}
+
+
+def test_templates_equal_the_programs_encoders():
+    templates_equal_the_programs_encoders(REPO)
 
 
 def test_the_pod_is_the_one_make_pods_makes():
@@ -596,10 +704,10 @@ def test_roofline_bytes_for_both_deployments():
 CPU_DEVICE = {"platform": "cpu", "kind": "cpu", "count": 1}
 
 
-def _tiny(cell):
+def _tiny(cell, tree=REPO):
     """The cell at 1,000 nodes and waves of 128, XLA scan; where it fills
     to the brim, nodes of 8 pod slots, so that half a second gets there."""
-    workload, config, pods = run.load_cell(MANIFEST, cell)
+    workload, config, pods = run.load_cell(tree.manifest, cell)
     config = copy.deepcopy(config)
     config["nodes"].update(count=1000, cordon_every=16)
     config["table_spec"]["max_nodes"] = 1024
@@ -612,29 +720,61 @@ def _tiny(cell):
     return workload, config, pods
 
 
-def _rehearse(cell, fault=None, seconds=0.5):
+def _rehearse(cell, fault=None, seconds=0.5, tree=REPO):
     return run.run_cell(
-        MANIFEST, cell, _tiny(cell), seed=(1 << 31) + 11, seconds=seconds,
-        trace=False, device=dict(CPU_DEVICE), peaks={}, fault=fault,
+        tree.manifest, cell, _tiny(cell, tree), seed=(1 << 31) + 11,
+        seconds=seconds, trace=False, device=dict(CPU_DEVICE), peaks={},
+        fault=fault,
     )
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_rehearsal_prints_a_well_formed_line(cell, capsys):
-    result = _rehearse(cell)
+def own_numbers(monkeypatch) -> dict:
+    """Filled, in the runs that follow, with what a configuration's own
+    reference returns (nothing where it names none)."""
+    returned = {}
+    real = run.load_reference
+
+    def load(name):
+        numbers = real(name)
+
+        def recording(*args, **kw):
+            out = numbers(*args, **kw)
+            returned.update(out)
+            return out
+
+        return recording
+
+    monkeypatch.setattr(run, "load_reference", load)
+    return returned
+
+
+def rehearsal_prints_a_well_formed_line(tree, cell, capsys, monkeypatch):
+    own = own_numbers(monkeypatch)
+    result = _rehearse(cell, tree=tree)
     line = json.loads(json.dumps(result))
     assert list(line)[:5] == ["correct", "attempted", "failed", "metrics", "device"]
     assert list(line)[-1] == "compared"
     assert line["correct"] is True and line["failed"] == 0 < line["attempted"]
     assert set(line["metrics"]) == {
-        m["name"] for m in run.metrics_of(MANIFEST, "end_to_end", cell)}
+        m["name"] for m in run.metrics_of(tree.manifest, "end_to_end", cell)}
     for m in line["metrics"].values():
         assert m["value"] > 0 and UNIT.match(m["unit"])
     assert all(c["value"] == c["limit"] == 0 for c in line["compared"].values())
-    assert set(line["compared"]) == TWELVE      # no reference key: today's twelve
+    # the twelve and, where the configuration names a reference, exactly
+    # what that reference returned; without the key, exactly the twelve
+    config = run.load_cell(tree.manifest, cell)[1]
+    assert bool(own) == ("reference" in config) and not set(own) & TWELVE
+    assert set(line["compared"]) == TWELVE | set(own)
     err = capsys.readouterr().err.strip().splitlines()
     assert err[-1] == "correct=True failed_pods=0"
     assert err[-2].startswith("compared ") and " limit=0" in err[-2]
+    compared = [l.split()[1].split("=")[0] for l in err if l.startswith("compared ")]
+    assert compared == list(line["compared"])
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_rehearsal_prints_a_well_formed_line(cell, capsys, monkeypatch):
+    rehearsal_prints_a_well_formed_line(REPO, cell, capsys, monkeypatch)
 
 
 TWELVE = {"never_bound", "bound_twice", "unknown_node", "bound_to_cordoned",
@@ -700,8 +840,12 @@ def test_a_reference_that_is_not_there_is_an_error(monkeypatch):
         _rehearse_with_reference(monkeypatch, "no_such_reference")
 
 
-def test_a_reference_imports_nothing():
-    """Neither the program nor the harness: what it is given is all it has."""
+@rule()
+def a_reference_imports_nothing(tree):
+    """Neither the program nor the harness: what it is given is all it
+    has.  A reference a configuration names is a file under
+    ``benchmark/references/``, and every file there is some
+    configuration's."""
     dirs = [TOY_REFERENCES, run.REFERENCES_DIR]
     files = [os.path.join(d, f) for d in dirs if os.path.isdir(d)
              for f in os.listdir(d) if f.endswith(".py")]
@@ -711,7 +855,18 @@ def test_a_reference_imports_nothing():
             src = f.read()
         assert not re.search(r"^\s*(from|import)\s+(k8s1m_tpu|benchmark)\b", src, re.M)
         assert "def numbers(seen, replayed, *, nodes, pattern, offered)" in src
-    assert not any("reference" in run.read_json(c["file"]) for c in MANIFEST["configs"])
+    assert run.REFERENCES_DIR == os.path.join(tree.root, "benchmark", "references")
+    named = {run.read_json(c["file"]).get("reference")
+             for c in tree.manifest["configs"]} - {None}
+    there = {os.path.basename(f)[:-len(".py")] for f in files
+             if os.path.dirname(f) == run.REFERENCES_DIR}
+    assert named == there
+    for name in named:
+        assert NAME.match(name) and callable(run.load_reference(name))
+
+
+def test_a_reference_imports_nothing():
+    a_reference_imports_nothing(REPO)
 
 
 # ---- the traced part of a window that closes at the brim ----------------------
@@ -821,6 +976,91 @@ def test_state_unchanged_is_undone():
     from k8s1m_tpu.engine.cycle import schedule_batch_packed
 
     assert mod.schedule_batch_packed is schedule_batch_packed
+
+
+# ---- the door for controls: a fault that arrives as a file ---------------------
+
+TOY_CONTROLS = os.path.join(HERE, "controls")
+
+
+def test_a_control_that_is_not_there_is_an_error_by_name(monkeypatch):
+    monkeypatch.setattr(faults, "CONTROLS_DIR", TOY_CONTROLS)
+    assert faults.names() == sorted([*FAULTS, "wave_on_one_node"])
+    assert faults.load("lazy_bind") is FAULTS["lazy_bind"]
+    with pytest.raises(SystemExit, match="no_such_control"):
+        _rehearse(CELLS[0], fault="no_such_control")
+    # where the directory itself is not there (the repo today): the six
+    monkeypatch.setattr(faults, "CONTROLS_DIR", os.path.join(TOY_CONTROLS, "none"))
+    assert faults.names() == sorted(FAULTS)
+
+
+def test_a_control_file_breaks_a_sound_run_and_is_undone(monkeypatch):
+    """``--fault <name>`` with no built-in of that name is
+    ``controls/<name>.py``'s ``plant(store, coord)``, planted where the
+    built-in ones are; what it returns undoes it when the cell closes."""
+    from k8s1m_tpu.store.native import MemStore
+
+    real = MemStore.bind_batch
+    monkeypatch.setattr(faults, "CONTROLS_DIR", TOY_CONTROLS)
+    line = _rehearse(KWOK, fault="wave_on_one_node")
+    assert line["correct"] is False and line["failed"] == 0
+    assert line["compared"]["overcommitted_nodes"]["value"] > 0
+    assert MemStore.bind_batch is real
+    assert _rehearse(KWOK)["correct"] is True
+
+
+def test_a_built_in_fault_keeps_its_name_from_a_control_file(tmp_path, monkeypatch):
+    (tmp_path / "lazy_bind.py").write_text("def plant(store, coord):\n    1 / 0\n")
+    monkeypatch.setattr(faults, "CONTROLS_DIR", str(tmp_path))
+    assert faults.load("lazy_bind") is FAULTS["lazy_bind"]
+    assert faults.names() == sorted(FAULTS)
+
+
+# ---- the constraint planes among the roofline's columns ------------------------
+
+TABLE_COLUMNS = {
+    "cpu_alloc": (4, 1), "mem_alloc": (4, 1), "cpu_req": (4, 1), "mem_req": (4, 1),
+    "pods_req": (4, 1), "name_id": (4, 1), "label_num": (4, 16), "meta": (4, 1),
+    "label_key": (4, 16), "label_val": (4, 0), "taint_id": (2, 8), "zone": (2, 1),
+    "region": (1, 1), "pods_alloc": (2, 1)}
+
+
+def _shapes(cell, tree=REPO, **changes):
+    """``Cell.shapes()`` of the tiny cell once it is set up."""
+    from k8s1m_tpu.store.native import MemStore
+
+    workload, config, pods = _tiny(cell, tree)
+    config["coordinator"].update(changes.pop("coordinator", {}))
+    config["table_spec"].update(changes.pop("table_spec", {}))
+    with MemStore() as store:
+        made = run.Cell(store, config, workload, pods, seed=(1 << 31) + 16)
+        try:
+            made.setup()
+            return made.shapes()
+        finally:
+            made.close()
+
+
+@pytest.mark.parametrize("cell", [KWOK, FIT])
+def test_the_columns_of_both_cells_are_the_node_tables_alone(cell):
+    """No constraint planes in either deployment: ``columns`` holds what
+    it held, and the roofline's 42 bytes a row with it."""
+    shapes = _shapes(cell)
+    assert shapes["columns"] == TABLE_COLUMNS
+    assert roofline.row_bytes(shapes["columns"]) == 42
+    assert set(shapes) == {"scan_rows", "columns", "batch", "k", "pod_bytes"}
+
+
+def test_the_constraint_planes_are_columns_of_a_deployment_that_keeps_them():
+    """``[slots, N]`` planes as bytes per node row, like every other
+    column; the planes over zones and regions have no node axis."""
+    shapes = _shapes(KWOK, coordinator={"with_constraints": True},
+                     table_spec={"spread_slots": 4, "affinity_slots": 2})
+    planes = {"spread_node": (4, 4), "tgt_node": (4, 2), "own_node": (4, 2)}
+    assert shapes["columns"] == {**TABLE_COLUMNS, **planes}
+    assert roofline.row_bytes(shapes["columns"]) == 42
+    assert roofline.row_bytes(
+        shapes["columns"], [*roofline.BASE_COLUMNS, "spread_node"]) == 42 + 16
 
 
 def _cli(cwd):

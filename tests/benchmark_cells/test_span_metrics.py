@@ -17,7 +17,8 @@ if ROOT not in sys.path:
 
 from benchmark import readers, run, span_readers, trace_reduce
 from test_benchmark_cells import (
-    CELLS, COLUMNS, CPU_DEVICE, DEV, E2E, EVENTS, MANIFEST, NAME, UNIT, _tiny,
+    CELLS, COLUMNS, CPU_DEVICE, DEV, EVENTS, FIT, KWOK, MANIFEST, NAME, REPO, UNIT,
+    _tiny, rule,
 )
 
 # the eleven that read spans, scopes and counters (PR 25), as PR 27 entered them
@@ -243,41 +244,73 @@ def test_loader_reads_op_names_and_line_ids(tmp_path):
 # ---- the eleven, and the seven kept metrics ----------------------------------
 
 
-def test_the_manifest_holds_the_eleven_and_the_seven():
-    assert [m["name"] for m in MANIFEST["per_layer"]] == KEPT_METRICS + SPAN_METRICS
-    by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
+EIGHTEEN = KEPT_METRICS + SPAN_METRICS
+
+
+@rule()
+def the_manifest_holds_the_eleven_and_the_seven(tree):
+    """The eighteen first and in their order, each with the cells it had
+    (PR 27); what a later PR appends, a metric or a cell's name in a
+    list, comes after them."""
+    per_layer = tree.manifest["per_layer"]
+    assert [m["name"] for m in per_layer][:len(EIGHTEEN)] == EIGHTEEN
+    by_name = {m["name"]: m for m in per_layer}
     # the two restated: a sound reading of exactly 0 has no ratio to keep
     assert "intake_slow_lane_pct.fill" not in by_name
     assert by_name["intake_fast_lane_pct.fill"]["better"] == "higher"
-    assert by_name["requeued_pct.fill"]["workloads"] == ["fit-10k.fill"]
-    assert all(by_name[m]["workloads"] == CELLS
-               for m in by_name if m != "requeued_pct.fill")
+    assert by_name["requeued_pct.fill"]["workloads"][0] == FIT
+    assert KWOK not in by_name["requeued_pct.fill"]["workloads"]
+    assert all(by_name[m]["workloads"][:2] == [KWOK, FIT]
+               for m in EIGHTEEN if m != "requeued_pct.fill")
+    assert tree.cells[:2] == [KWOK, FIT]
+    for m in tree.manifest["end_to_end"] + per_layer:
+        assert set(m.get("workloads", [])) <= set(tree.cells)
     assert {by_name[m]["moves"] for m in SPAN_METRICS[-3:]} == {"setup_s"}
     assert {by_name[m]["layer"] for m in SPAN_METRICS[-3:]} == {"snapshot"}
 
 
-@pytest.mark.parametrize("metric", SPAN_METRICS)
-def test_span_metric_spec(metric):
-    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == metric)
+def test_the_manifest_holds_the_eleven_and_the_seven():
+    the_manifest_holds_the_eleven_and_the_seven(REPO)
+
+
+@rule("per_layer")
+def span_metric_spec(tree, metric):
+    """An entry and its file, whatever reader the file names; for the
+    eleven, the reader it named when it entered the manifest."""
+    entry = next(m for m in tree.manifest["per_layer"] if m["name"] == metric)
     spec = run.read_json("benchmark", "metrics", f"{metric}.json")
     assert set(entry) == {"name", "unit", "better", "source", "layer", "moves",
                           "workloads"}
     assert set(spec) == {"reader", "args", "what"} and spec["what"]
     assert NAME.match(metric) and UNIT.match(entry["unit"])
-    assert entry["source"] in ("device_trace", "program_counter")
-    assert entry["moves"] in E2E
+    assert entry["source"] in ("device_trace", "program_span", "program_counter",
+                               "host_clock")
+    assert entry["moves"] in tree.e2e
     reader = readers.resolve(spec["reader"])
+    if metric not in SPAN_METRICS:
+        return
+    assert entry["source"] in ("device_trace", "program_counter")
     if "." in spec["reader"]:
         assert reader is getattr(span_readers, spec["reader"].split(".")[1])
     else:
         assert reader is readers.registry_stage_per_bind
 
 
-def test_span_readers_take_no_accepted_readers_name():
+@pytest.mark.parametrize("metric", SPAN_METRICS)
+def test_span_metric_spec(metric):
+    span_metric_spec(REPO, metric)
+
+
+@rule()
+def span_readers_take_no_accepted_readers_name(tree):
     assert not set(span_readers.READERS) & set(readers.READERS)
     named = {run.read_json("benchmark", "metrics", f"{m}.json")["reader"]
              for m in SPAN_METRICS}
     assert named >= {f"span_readers.{r}" for r in span_readers.READERS}
+
+
+def test_span_readers_take_no_accepted_readers_name():
+    span_readers_take_no_accepted_readers_name(REPO)
 
 
 def _full_ctx():
@@ -313,31 +346,53 @@ def _full_ctx():
     }
 
 
-def test_every_manifest_metric_reads_a_full_context():
+@rule()
+def every_manifest_metric_reads_a_full_context(tree):
     """With the program's spans in the trace, op_names beside it, every
     stage label and both counter snapshots, ``run.per_layer_values`` reads
     all eighteen: the seven kept ones what test_readers_on_a_synthetic_window
-    pins (``encode`` is no stage of this window: no value, not 0)."""
+    pins (``encode`` is no stage of this window: no value, not 0).  Held
+    for the two cells of PR 27 by name; any other cell reads, of the
+    eighteen it opts into, the same values.  A metric this fixture knows
+    nothing of has a file whose reader resolves; the fixture's shapes are
+    not its deployment's, so it is not read here."""
     moved = 53248 * 42 + 4096 * 16 + 4096 * 4 * 8
-    for cell in CELLS:
-        got = run.per_layer_values(MANIFEST, cell, _full_ctx())
-        assert got.pop("encode_us_per_bind.fill") is None
-        assert got.pop("commit_ms.fill") is None    # nothing under commit here
-        if cell == "fit-10k.fill":
-            assert got.pop("requeued_pct.fill") == pytest.approx(5.0)
-        assert got == pytest.approx({
-            "engine_step_ms.fill": 1000.0, "fused_topk_ms.fill": 100.0,
-            "fused_topk_roofline.fill": 100 * moved / 819e9 / 0.1,
-            "host_us_per_bind.fill": 4000.0, "store_bind_us_per_bind.fill": 3000.0,
-            "drain_us_per_bind.fill": 1000.0,
-            "candidates_ms.fill": 100.0, "assign_ms.fill": 800.0,
-            "drain_poll_us_per_bind.fill": 100.0,
-            "drain_apply_us_per_bind.fill": 900.0,
-            "bind_cas_us_per_bind.fill": 1000.0,
-            "intake_fast_lane_pct.fill": 90.0,      # 900 of 1000; deletes apart
-            "bootstrap_s": 26.0, "bootstrap_ingest_s": 24.0,
-            "bulkload_per_node_pct": 75.0,
-        })
+    pinned = {
+        "encode_us_per_bind.fill": None,
+        "commit_ms.fill": None,                     # nothing under commit here
+        "requeued_pct.fill": 5.0,
+        "engine_step_ms.fill": 1000.0, "fused_topk_ms.fill": 100.0,
+        "fused_topk_roofline.fill": 100 * moved / 819e9 / 0.1,
+        "host_us_per_bind.fill": 4000.0, "store_bind_us_per_bind.fill": 3000.0,
+        "drain_us_per_bind.fill": 1000.0,
+        "candidates_ms.fill": 100.0, "assign_ms.fill": 800.0,
+        "drain_poll_us_per_bind.fill": 100.0,
+        "drain_apply_us_per_bind.fill": 900.0,
+        "bind_cas_us_per_bind.fill": 1000.0,
+        "intake_fast_lane_pct.fill": 90.0,      # 900 of 1000; deletes apart
+        "bootstrap_s": 26.0, "bootstrap_ingest_s": 24.0,
+        "bulkload_per_node_pct": 75.0,
+    }
+    assert sorted(pinned) == sorted(EIGHTEEN)
+    known = {**tree.manifest, "per_layer": [
+        m for m in tree.manifest["per_layer"] if m["name"] in pinned]}
+    for cell in tree.cells:
+        got = run.per_layer_values(known, cell, _full_ctx())
+        opted = {m["name"] for m in run.metrics_of(known, "per_layer", cell)}
+        if cell in (KWOK, FIT):
+            assert opted == set(pinned) - ({"requeued_pct.fill"} if cell == KWOK else set())
+        assert set(got) == opted
+        for name in opted:
+            assert got[name] == (pinned[name] if pinned[name] is None
+                                 else pytest.approx(pinned[name])), name
+    for m in tree.manifest["per_layer"]:
+        if m["name"] not in pinned:
+            spec = run.read_json("benchmark", "metrics", f"{m['name']}.json")
+            assert callable(readers.resolve(spec["reader"]))
+
+
+def test_every_manifest_metric_reads_a_full_context():
+    every_manifest_metric_reads_a_full_context(REPO)
 
 
 def test_assign_ms_reads_what_the_retired_loop_metric_read():
@@ -360,52 +415,77 @@ def test_assign_ms_reads_what_the_retired_loop_metric_read():
 # ---- the harness itself at a tiny size, its readers' context kept ------------
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_the_harness_reads_the_counters_of_a_tiny_run(cell):
+def the_harness_reads_the_counters_of_a_tiny_run(tree, cell):
+    """The rules every cell is held to; what is particular to one (the
+    lanes its window rides, the shapes it interns, how many of its metrics
+    need no trace, values a tiny run pins) is in
+    ``tests/benchmark_cells/cells/<cell>.json`` (``Tree.cell_data``)."""
     from k8s1m_tpu.obs.metrics import REGISTRY
 
+    manifest, data = tree.manifest, tree.cell_data(cell)
     shapes = REGISTRY.get("coordinator_pod_shapes_total")
     interned = shapes.value(event="interned")
     before = span_readers.snapshot_counters(["bulkload_values_total"])
     ctx = {}
     result = run.run_cell(
-        MANIFEST, cell, _tiny(cell), seed=(1 << 31) + 25, seconds=0.5,
+        manifest, cell, _tiny(cell, tree), seed=(1 << 31) + 25, seconds=0.5,
         trace=False, device=dict(CPU_DEVICE), peaks={}, keep=ctx,
     )
     assert result["correct"] is True
-    assert set(result["metrics"]) == {"binds_per_s", "setup_s"}
+    assert set(result["metrics"]) == {
+        m["name"] for m in run.metrics_of(manifest, "end_to_end", cell)}
     assert set(ctx) == {"stage_s", "setup_stage_s", "counters", "binds", "trace",
                         "shapes", "peaks"}
-    got = {k: v for k, v in run.per_layer_values(MANIFEST, cell, ctx).items()
-           if v is not None}
-    manifest = {m["name"]: m for m in run.metrics_of(MANIFEST, "per_layer", cell)}
-    untraced = {m for m in manifest if manifest[m]["source"] != "device_trace"}
-    assert set(got) == untraced
-    assert len(untraced) == (12 if cell == "fit-10k.fill" else 11)
-    assert got["intake_fast_lane_pct.fill"] == 100.0
     # a cordon in its one chunk: set-up ingested every node value one by one
     # (the reader counts from the process's start, which is this run's on
     # the chip and the test session's here: take this run's part)
     ctx["counters"]["open"]["bulkload_values_total"] = {
         key: n - before.get("bulkload_values_total", {}).get(key, 0.0)
         for key, n in ctx["counters"]["open"]["bulkload_values_total"].items()}
-    assert run.per_layer_values(MANIFEST, cell, ctx)["bulkload_per_node_pct"] == 100.0
-    assert 0 < got["bootstrap_ingest_s"] < got["bootstrap_s"]
-    assert got["drain_poll_us_per_bind.fill"] + got["drain_apply_us_per_bind.fill"] > 0
-    assert got["bind_cas_us_per_bind.fill"] < got["store_bind_us_per_bind.fill"]
-    # the window rides batch_fast alone, on one interned shape; its lane
-    # counts are the pods it offered, give or take the wave in flight at
-    # each edge
-    lanes = lambda at: ctx["counters"][at]["coordinator_pod_intake_total"]
-    grown = {key[0][1]: n - lanes("open").get(key, 0)
-             for key, n in lanes("close").items()}
-    assert {lane for lane, n in grown.items() if n} == {"batch_fast"}
-    wave = _tiny(cell)[0]["wave"]
-    assert abs(grown["batch_fast"] - result["attempted"]) <= 2 * wave
-    assert shapes.value(event="interned") - interned == 1
+    got = {k: v for k, v in run.per_layer_values(manifest, cell, ctx).items()
+           if v is not None}
+    reports = {m["name"]: m for m in run.metrics_of(manifest, "per_layer", cell)}
+    untraced = {m for m in reports if reports[m]["source"] != "device_trace"}
+    assert set(got) == untraced         # each has a value; no trace, no other
+    for a, b in (("bootstrap_ingest_s", "bootstrap_s"),
+                 ("bind_cas_us_per_bind.fill", "store_bind_us_per_bind.fill"),
+                 ("drain_poll_us_per_bind.fill", "drain_us_per_bind.fill"),
+                 ("drain_apply_us_per_bind.fill", "drain_us_per_bind.fill")):
+        if a in got and b in got:
+            assert 0 < got[a] < got[b]
     assert set(ctx["stage_s"]) >= {"drain", "drain_poll", "drain_apply",
                                    "bind", "bind_cas", "encode", "device"}
     assert set(ctx["setup_stage_s"]) >= {"bootstrap", "bootstrap_ingest"}
     # every counter of the registry, not a list of names
     assert "coordinator_pod_shapes_total" in ctx["counters"]["close"]
     assert len(ctx["counters"]["close"]) > 10
+    # the cell's own: how many metrics read without a trace, what they pin
+    if "untraced_metrics" in data:
+        assert len(untraced) == data["untraced_metrics"]
+    for name, value in data.get("values", {}).items():
+        assert got[name] == value, name
+    # the lanes its window rides, and no other; their counts are the pods
+    # it offered, give or take the wave in flight at each edge
+    lanes = lambda at: ctx["counters"][at]["coordinator_pod_intake_total"]
+    grown = {key[0][1]: n - lanes("open").get(key, 0)
+             for key, n in lanes("close").items()}
+    if "window_lanes" in data:
+        assert {lane for lane, n in grown.items() if n} == set(data["window_lanes"])
+        wave = _tiny(cell, tree)[0]["wave"]
+        rode = sum(grown[lane] for lane in data["window_lanes"])
+        assert abs(rode - result["attempted"]) <= 2 * wave
+    if "shapes_interned" in data:
+        assert shapes.value(event="interned") - interned == data["shapes_interned"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_harness_reads_the_counters_of_a_tiny_run(cell):
+    the_harness_reads_the_counters_of_a_tiny_run(REPO, cell)
+    # both cells of PR 27 bring their file, with that PR's values
+    if cell in (KWOK, FIT):
+        assert REPO.cell_data(cell) == {
+            "untraced_metrics": 12 if cell == FIT else 11,
+            "window_lanes": ["batch_fast"], "shapes_interned": 1,
+            "values": {"intake_fast_lane_pct.fill": 100.0,
+                       "bulkload_per_node_pct": 100.0},
+        }

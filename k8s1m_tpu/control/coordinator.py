@@ -79,8 +79,10 @@ import collections
 import contextlib
 import dataclasses
 import heapq
+import itertools
 import json
 import logging
+import operator
 import random
 import threading
 import time
@@ -216,6 +218,15 @@ _DECODE_ERRORS = Counter(
 _POD_INTAKE = Counter(
     "coordinator_pod_intake_total",
     "Pod watch/list events applied, by intake lane", ("lane",),
+)
+# Once per wave (_bind_wave): columnar = pods bound wholly in columns
+# (a fast-lane record whose batch CAS won), per_pod = every other pod
+# the device gave a row (decoded or webhook pods, CAS losses, rows
+# tombstoned in flight, any pod under a fault plan).
+_BIND_RETIRE = Counter(
+    "coordinator_bind_retire_total",
+    "Pods that reached a wave's bind stage, by the lane that retired them",
+    ("lane",),
 )
 # Once per frame, never per pod: pods taken natively over `interned` is
 # the shape table's hit share.
@@ -484,6 +495,60 @@ class PendingPod:
         return self.pod
 
 
+# A wave's records as columns (_bind_columns): one pass in C a column,
+# which is cheaper than one pass over all ten and a transpose.
+_WAVE_COLS = tuple(map(operator.attrgetter, (
+    "key_bytes", "mod_revision", "cpu_milli", "mem_kib", "key_str",
+    "enqueued_at", "priority", "gang_id", "pod", "shape",
+)))
+
+
+def _is_none(col) -> np.ndarray:
+    """bool[len(col)]: which entries of a column are None."""
+    return np.fromiter(
+        map(operator.is_, col, itertools.repeat(None)), bool, len(col)
+    )
+
+
+def _picker(mask: np.ndarray):
+    """``pick(col)``: the column's entries under the mask, in wave order
+    (the column itself where the mask takes all of it)."""
+    if mask.all():
+        return lambda col: col
+    flags = mask.tolist()
+    return lambda col: list(itertools.compress(col, flags))
+
+
+def _wave_tenants(key_strs, shapes) -> list[str]:
+    """Tenant of each fast-lane record (no PodInfo) of a wave: its
+    shape's where the labels name one, else the namespace of its key —
+    one lookup per distinct namespace of the wave, not one per pod."""
+    n = len(key_strs)
+    first = key_strs[0]
+    cut = first.find("/")
+    if cut >= 0 and all(
+        map(str.startswith, key_strs, itertools.repeat(first[: cut + 1]))
+    ):
+        tenants = [tenant_of_key(first)] * n
+    else:
+        spaces = list(map(
+            operator.itemgetter(0),
+            map(str.partition, key_strs, itertools.repeat("/")),
+        ))
+        of_space = {ns: tenant_of_key(ns) for ns in set(spaces)}
+        tenants = list(map(of_space.__getitem__, spaces))
+    named = {
+        sh: sh.tenant for sh in set(shapes) if sh is not None and sh.tenant
+    }
+    if named:
+        own = list(map(named.get, shapes))
+        tenants = np.where(
+            _is_none(own),
+            np.fromiter(tenants, object, n), np.fromiter(own, object, n),
+        ).tolist()
+    return tenants
+
+
 # Structural splice marker: encode_pod always opens spec with
 # schedulerName, and this byte pattern cannot occur inside any JSON
 # string literal (the quotes would be \"-escaped), so its first
@@ -601,13 +666,12 @@ class _VictimRows:
     _dirty_rows=THREAD_OWNER,
     _dirty_caps=THREAD_OWNER,
     _midflight_rows=THREAD_OWNER,
-    # Tenancy state (gang staging/parking, per-bind priority metadata):
-    # cycle-thread-owned like the queue it feeds.
+    # Tenancy state (gang staging/parking): cycle-thread-owned like the
+    # queue it feeds.
     _gang_staging=THREAD_OWNER,
     _gang_parked=THREAD_OWNER,
-    _bind_meta=THREAD_OWNER,
-    # The incremental preemption-victims index mirrors _bound/_bind_meta
-    # (same insert/delete sites, same cycle-thread confinement).
+    # The incremental preemption-victims index mirrors _bound (same
+    # insert/delete sites, same cycle-thread confinement).
     _victims_by_node=THREAD_OWNER,
     # The incremental fallback NodeInfo index is maintained at the node
     # watch-drain sites (cycle-thread) and read by _fallback_nodes.
@@ -1027,15 +1091,7 @@ class Coordinator:
         # (not_before, seq, members) min-heap, released contiguously.
         self._gang_parked: list[tuple[float, int, list[PendingPod]]] = []
         self._gang_oversize: set[str] = set()
-        # Per-bound-pod preemption metadata:
-        # key -> (priority, bind seq, tenant, gang id).  Parallel to
-        # _bound (same insert/delete sites) so victim selection never
-        # decodes stored objects; the tenant is captured at bind time
-        # (the label override would otherwise be lost for pods whose
-        # PodInfo is not retained), and a nonempty gang id marks the
-        # pod unpreemptable — evicting one member would strand the rest
-        # of its gang bound, the exact state gangs exist to prevent.
-        self._bind_meta: dict[str, tuple[int, int, str, str]] = {}
+        # Binds counted in the order they were entered into _bound.
         self._bind_seq = 0
         # Incremental preemption-victims index: node name -> {pod key ->
         # Victim}, maintained at the same insert/delete sites as _bound
@@ -1069,10 +1125,18 @@ class Coordinator:
         # thread-safe to mutate from the handler directly).
         self._external: list[dict] = []
         self._external_lock = threading.Lock()
-        # Bound-pod record per pod key: (node, cpu, mem, zone, region, pod?).
+        # Bound-pod record per pod key: (node, cpu, mem, zone, region, pod?,
+        # priority, bind seq, tenant, gang id) — ONE record and one dict
+        # insert a bind (the retire enters a wave's with one update).
         # The PodInfo is retained only for constraint-carrying pods — it is
         # needed to decrement count tables on deletion; plain pods stay
-        # compact (the 1M-pod case must not hold 1M PodInfos).
+        # compact (the 1M-pod case must not hold 1M PodInfos).  The last
+        # four are the preemption metadata, kept here so victim selection
+        # never decodes stored objects: the tenant is captured at bind
+        # time (the label override would otherwise be lost for pods whose
+        # PodInfo is not retained), and a nonempty gang id marks the pod
+        # unpreemptable — evicting one member would strand the rest of
+        # its gang bound, the exact state gangs exist to prevent.
         self._bound: dict[str, tuple] = {}
         # Constraint-count corrections awaiting a batched device scatter:
         # (pod, node_name, zone, region, sign).  sign=+1 for externally
@@ -1254,12 +1318,12 @@ class Coordinator:
         row = self.host.row_of(node_name)
         zone, region = int(self.host.zone[row]), int(self.host.region[row])
         keep = pod if self._constraintful(pod) else None
-        self._bound[pod.key] = (node_name, pod.cpu_milli, pod.mem_kib, zone, region, keep)
         self._bind_seq += 1
         gang = gang_of_labels(pod.labels, pod.namespace)
         gang_id = gang[0] if gang is not None else ""
         tenant = tenant_of_pod(pod)
-        self._bind_meta[pod.key] = (
+        self._bound[pod.key] = (
+            node_name, pod.cpu_milli, pod.mem_kib, zone, region, keep,
             pod.priority, self._bind_seq, tenant, gang_id,
         )
         self._victims_note(
@@ -1360,7 +1424,6 @@ class Coordinator:
             tracer.finish(pod_key_str, "requeue", outcome="deleted")
         self._queued_keys.discard(pod_key_str)
         self._orphan_bound.pop(pod_key_str, None)
-        self._bind_meta.pop(pod_key_str, None)
         if self._gang_staging:
             # A deleted member must leave gang staging too: a leaked
             # record would count into the load signal forever and, if
@@ -1372,7 +1435,7 @@ class Coordinator:
                     break
         bound = self._bound.pop(pod_key_str, None)
         if bound is not None:
-            node_name, cpu, mem, zone, region, keep = bound
+            node_name, cpu, mem, zone, region, keep = bound[:6]
             self._victims_drop(pod_key_str, node_name)
             if node_name in self.host._row_of:
                 self.host.remove_pod(node_name, cpu, mem)
@@ -1901,8 +1964,8 @@ class Coordinator:
         """One standby-mirror tick: apply the world's deltas and keep
         every cache warm — NEVER schedule, never write to the store.
 
-        The mirror's derived state (queue, bound-pod ledger,
-        ``_bind_meta``, gang staging, host mirror, device table, encode
+        The mirror's derived state (queue, bound-pod ledger with its
+        preemption metadata, gang staging, host mirror, device table, encode
         templates, compiled step) is thereby a CONTINUOUS reconstruction
         from store facts + intake replay — exactly the state
         ``promote()`` inherits at takeover, which is why takeover is a
@@ -2190,9 +2253,9 @@ class Coordinator:
         if self.tenancy is None or not self.tenancy.policy.gang_enabled:
             return 0
         bound_gangs: dict[str, list[str]] = {}
-        for key, meta in self._bind_meta.items():
-            if meta[3] and key in self._bound:
-                bound_gangs.setdefault(meta[3], []).append(key)
+        for key, rec in self._bound.items():
+            if rec[9]:
+                bound_gangs.setdefault(rec[9], []).append(key)
         if not bound_gangs:
             return 0
         pending_gangs = set(self._gang_staging)
@@ -2704,7 +2767,7 @@ class Coordinator:
         rec = self._bound.get(key_str)
         if rec is None:
             return False, None
-        node_name, cpu, mem, zone, region, keep = rec
+        node_name, cpu, mem, zone, region, keep = rec[:6]
         ns, name = key_str.split("/", 1)
         kb = pod_key(ns, name)
         ok = False
@@ -2732,7 +2795,6 @@ class Coordinator:
         if not ok:
             return False, None
         self._bound.pop(key_str, None)
-        self._bind_meta.pop(key_str, None)
         self._victims_drop(key_str, node_name)
         if node_name in self.host._row_of:
             self.host.remove_pod(node_name, cpu, mem)
@@ -2792,11 +2854,7 @@ class Coordinator:
         victims_by_row: dict[int, list[Victim]] = {}
         row_of = self.host._row_of
         for key, rec in self._bound.items():
-            meta = self._bind_meta.get(key)
-            if meta is None:
-                prio, seq, tenant, gang = 0, 0, tenant_of_key(key), ""
-            else:
-                prio, seq, tenant, gang = meta
+            prio, seq, tenant, gang = rec[6:]
             if gang:
                 continue
             node_name = rec[0]
@@ -3620,7 +3678,15 @@ class Coordinator:
 
     def _complete(self, inflight: Wave) -> int:
         """Bind half: sync the assignment to host, CAS the binds back,
-        roll back conflicts (CAS losses, rows tombstoned mid-flight)."""
+        roll back conflicts (CAS losses, rows tombstoned mid-flight).
+
+        The binds are retired by the wave, in columns (_bind_wave); only
+        the pods that are exceptions run per-pod code.  So in a wave that
+        has exceptions the pods take their ``_bind_seq`` numbers in three
+        runs, each in wave order, instead of interleaved: the pods bound
+        one by one ahead of the batch CAS, then the columnar pods, then
+        the batch's exceptions.  The order among the binds of one wave
+        decides only which of two same-priority victims goes first."""
         batch_pods, batch, asg, rows_dev, t_start = (
             inflight.batch_pods, inflight.batch, inflight.asg,
             inflight.rows_dev, inflight.t_start,
@@ -3640,192 +3706,18 @@ class Coordinator:
                 *inflight.index_touched,
             )
 
-        nbound = 0
         failed = np.zeros(batch.batch, bool)
         # Per-pod settled outcome (True = the bind stuck), consumed by
         # the gang all-or-none settlement after the bind stage.
         bound_ok = np.zeros(batch.batch, bool)
-        bind_batch = getattr(self.store, "bind_batch", None)
-        host = self.host
         with self._stage("bind"):
-            # One native call binds the whole wave: splice + CAS happen
-            # inside the store against the bytes it already holds
-            # (ms_bind_batch), so the per-pod Python cost collapses to
-            # bookkeeping — itself vectorized below (per-pod np scalar
-            # indexing and metric calls were ~12us/pod).  Pods the native
-            # path can't take (webhook intake with no observed revision,
-            # non-canonical objects) fall back to the per-pod path.
-            nb = len(batch_pods)
-            rows = node_row[:nb]
-            bound_idx = np.nonzero(rows >= 0)[0]
-            if self._delta is not None and bound_idx.size:
-                # This wave's device-side assumes are now host-visible:
-                # journal its bound rows so later delta waves recompute
-                # their plane columns.  While the wave was IN flight the
-                # same rows reached delta waves on-stream via rows_dev
-                # (engine/deltacache.combine_dirty) — this retire stamp
-                # closes the window for waves launched from here on.
-                # CAS conflicts and tombstoned rows additionally ride
-                # the ordinary dirty-row re-upload below.
-                self._delta.note_rows(rows[bound_idx])
+            rows = node_row[: len(batch_pods)]
             # No-feasible-row pods are settled AFTER the wave's binds
-            # land in the host mirror (below): preemption's usage
-            # snapshot must include this wave's own placements, or the
-            # preemptor can overcommit a node the wave is about to fill.
+            # land in the host mirror: preemption's usage snapshot must
+            # include this wave's own placements, or the preemptor can
+            # overcommit a node the wave is about to fill.
             nofit = np.nonzero(rows < 0)[0].tolist()
-            brows = rows[bound_idx]
-            # Rows tombstoned while this wave was in flight: the node is
-            # gone (quarantine guarantees no reuse before this retire, so
-            # an invalid row can't alias a new node) — treat like a CAS
-            # conflict: retry the pod, roll back the wave's optimistic
-            # constraint commit.  No dirty-marking: the tombstone scatter
-            # already uploaded the zeroed row.
-            if bound_idx.size:
-                alive = host.valid[brows]
-                if not alive.all():
-                    for i in bound_idx[~alive].tolist():
-                        failed[i] = True
-                        self._wave_fail(batch_pods[i])
-                    bound_idx = bound_idx[alive]
-                    brows = brows[alive]
-            nbytes = self._node_name_bytes()
-            ids_l = host.name_id[brows].tolist()
-            brows_l = brows.tolist()
-            zones = host.zone[brows].tolist()
-            regions = host.region[brows].tolist()
-            bound_l = bound_idx.tolist()
-
-            # Index-parallel wave: wave_j[k] is the position in bound_l
-            # of the k-th native-path record (per-pod tuple building and
-            # name.encode were a measurable slice of the bind stage).
-            wave_j: list[int] = []
-            entries: list[tuple[bytes, int, bytes]] = []
-            native = bind_batch is not None
-            # Hot path stays injection-free unless a plan is installed.
-            inj_active = bool(faultline.active_injector().plan.faults)
-            for j, i in enumerate(bound_l):
-                p = batch_pods[i]
-                if native and p.mod_revision is not None:
-                    # One fault decision per CAS attempt: native-wave
-                    # records are checked here (their CAS runs inside
-                    # bind_batch); slow-path pods are checked inside
-                    # _bind so they never consume two draws per attempt.
-                    if inj_active and self._bind_fault():
-                        # Forced conflict: identical accounting to the
-                        # real CAS-conflict branch below.
-                        name = nbytes[ids_l[j]].decode()
-                        self._dirty_rows.add(host.row_of(name))
-                        failed[i] = True
-                        self._wave_fail(p)
-                        continue
-                    wave_j.append(j)
-                    entries.append((p.key_bytes, p.mod_revision, nbytes[ids_l[j]]))
-                    continue
-                name = nbytes[ids_l[j]].decode()
-                if self._bind(p, name):
-                    nbound += 1
-                    bound_ok[i] = True
-                    _BIND_LATENCY.observe(time.perf_counter() - p.enqueued_at)
-                    if brows_l[j] in self._midflight_rows:
-                        # A mid-flight full scatter erased this wave's
-                        # device-side assume on the row; the host mirror
-                        # just learned the bind — re-upload repairs it.
-                        self._dirty_rows.add(brows_l[j])
-                    continue
-                # CAS conflict: the device table already assumed this
-                # bind (commit_binds), but the host mirror — which is
-                # authoritative — was never incremented.  Marking the
-                # row dirty re-uploads the host values, undoing the
-                # device-side assume; the constraint-count commit is
-                # rolled back below in one signed scatter.
-                self._dirty_rows.add(host.row_of(name))
-                failed[i] = True
-                self._wave_fail(p)
-            if entries:
-                with self._stage("cas", "bind"):
-                    results = self._fenced_bind_batch(
-                        entries,
-                        self._pods_watch.id if self._bind_excludes else None,
-                    )
-                now = time.perf_counter()
-                ok_rows: list[int] = []
-                ok_cpu: list[int] = []
-                ok_mem: list[int] = []
-                lats: list[float] = []
-                bound_dict = self._bound
-                nv = host.vocab.node_names._to_val
-                for j, rev in zip(wave_j, results):
-                    i = bound_l[j]
-                    p = batch_pods[i]
-                    if rev > 0:
-                        bound_ok[i] = True
-                        ok_rows.append(brows_l[j])
-                        ok_cpu.append(p.cpu_milli)
-                        ok_mem.append(p.mem_kib)
-                        lats.append(now - p.enqueued_at)
-                        pod = p.pod
-                        keep = (
-                            pod
-                            if pod is not None and self._constraintful(pod)
-                            else None
-                        )
-                        node_name = nv[ids_l[j]]
-                        bound_dict[p.key_str] = (
-                            node_name, p.cpu_milli, p.mem_kib,
-                            zones[j], regions[j], keep,
-                        )
-                        self._bind_seq += 1
-                        # bind_batch takes ANY pod with an observed
-                        # revision, decoded or not: a decoded PodInfo
-                        # supplies the label-aware tenant, a fast-lane
-                        # pod's shape knows whether its labels name one;
-                        # failing both the key's namespace IS the tenant.
-                        if pod is not None:
-                            tenant = tenant_of_pod(pod)
-                        else:
-                            sh = p.shape
-                            tenant = (
-                                sh.tenant if sh is not None else None
-                            ) or tenant_of_key(p.key_str)
-                        self._bind_meta[p.key_str] = (
-                            p.priority, self._bind_seq, tenant, p.gang_id,
-                        )
-                        self._victims_note(
-                            p.key_str, node_name, p.cpu_milli, p.mem_kib,
-                            p.priority, self._bind_seq, tenant, p.gang_id,
-                        )
-                        continue
-                    name = nbytes[ids_l[j]].decode()
-                    if rev == BIND_INVALID and self._bind(p, name):
-                        nbound += 1
-                        bound_ok[i] = True
-                        _BIND_LATENCY.observe(now - p.enqueued_at)
-                        if brows_l[j] in self._midflight_rows:
-                            self._dirty_rows.add(brows_l[j])
-                        continue
-                    if rev != BIND_INVALID:
-                        _PODS_SCHEDULED.inc(outcome="conflict")
-                    self._dirty_rows.add(host.row_of(name))
-                    failed[i] = True
-                    self._wave_fail(p)
-                if ok_rows:
-                    # Duplicate rows (two pods on one node) accumulate
-                    # correctly under np.add.at.
-                    r = np.asarray(ok_rows, np.int32)
-                    np.add.at(host.cpu_req, r, np.asarray(ok_cpu, host.cpu_req.dtype))
-                    np.add.at(host.mem_req, r, np.asarray(ok_mem, host.mem_req.dtype))
-                    np.add.at(host.pods_req, r, 1)
-                    nbound += len(ok_rows)
-                    _PODS_SCHEDULED.inc(len(ok_rows), outcome="bound")
-                    _BIND_LATENCY.observe_many(lats)
-                    if self._midflight_rows:
-                        # Same repair as the slow path: rows a mid-flight
-                        # full scatter clobbered get the host truth (now
-                        # including this wave's binds) re-uploaded.
-                        self._dirty_rows.update(
-                            rr for rr in ok_rows
-                            if rr in self._midflight_rows
-                        )
+            nbound = self._bind_wave(batch_pods, rows, bound_ok, failed)
             # Preemption pass — after every CAS bind above, so the host
             # mirror (and so the feasibility snapshot) reflects this
             # wave's placements.  The victims index is built lazily, at
@@ -3876,6 +3768,231 @@ class Coordinator:
             # the pipeline, which completes the probe right here.
             self.breaker.record_success()
         return nbound
+
+    def _bind_wave(self, batch_pods, rows, bound_ok, failed) -> int:
+        """CAS every pod the device gave a row into the store and account
+        the binds that stuck; returns their number.  ``bound_ok`` and
+        ``failed`` are filled in place.
+
+        One native call binds the whole wave: splice + CAS happen inside
+        the store against the bytes it already holds (ms_bind_batch).
+        The bookkeeping around it is done by the wave, in columns, for
+        the pods that are plain — read from the wave itself: the store
+        has ``bind_batch``, the record has an observed revision and no
+        PodInfo (a fast-lane record: nothing to keep, the tenant is its
+        shape's or its namespace's), and no fault plan is installed.
+        Every other pod is an exception and runs the per-pod code, over
+        the exception indices only: webhook intake (no revision) and a
+        store without ``bind_batch`` bind one by one through _bind ahead
+        of the batch; decoded pods (constraints, gangs, every retried
+        pod) ride the batch and are entered one by one after it; under a
+        fault plan every pod draws its decision in wave order; CAS
+        losses and BIND_INVALID answers are found by mask."""
+        host = self.host
+        bound_idx = np.nonzero(rows >= 0)[0]
+        reached = bound_idx.size
+        if not reached:
+            return 0
+        brows = rows[bound_idx]
+        if self._delta is not None:
+            # This wave's device-side assumes are now host-visible:
+            # journal its bound rows so later delta waves recompute
+            # their plane columns.  While the wave was IN flight the
+            # same rows reached delta waves on-stream via rows_dev
+            # (engine/deltacache.combine_dirty) — this retire stamp
+            # closes the window for waves launched from here on.
+            # CAS conflicts and tombstoned rows additionally ride
+            # the ordinary dirty-row re-upload below.
+            self._delta.note_rows(brows)
+        # Rows tombstoned while this wave was in flight: the node is
+        # gone (quarantine guarantees no reuse before this retire, so
+        # an invalid row can't alias a new node) — treat like a CAS
+        # conflict: retry the pod, roll back the wave's optimistic
+        # constraint commit.  No dirty-marking: the tombstone scatter
+        # already uploaded the zeroed row.
+        alive = host.valid[brows]
+        if not alive.all():
+            for i in bound_idx[~alive].tolist():
+                failed[i] = True
+                self._wave_fail(batch_pods[i])
+            bound_idx = bound_idx[alive]
+            brows = brows[alive]
+        n = bound_idx.size
+        columnar = nbound = 0
+        if n:
+            columnar, nbound = self._bind_columns(
+                batch_pods, bound_idx, brows, bound_ok, failed
+            )
+        _BIND_RETIRE.inc(columnar, lane="columnar")
+        _BIND_RETIRE.inc(reached - columnar, lane="per_pod")
+        return nbound
+
+    def _bind_columns(
+        self, batch_pods, bound_idx, brows, bound_ok, failed,
+    ) -> tuple[int, int]:
+        """_bind_wave over the pods on live rows (``bound_idx`` into
+        ``batch_pods``, ``brows`` their rows; at least one).  Returns
+        (pods retired wholly in columns, pods bound)."""
+        host = self.host
+        n = bound_idx.size
+        bound_l = bound_idx.tolist()
+        wave = list(map(batch_pods.__getitem__, bound_l))
+        (keys, mods, cpus, mems, key_strs, enqueued, prios, gangs,
+         infos, shapes) = (list(map(get, wave)) for get in _WAVE_COLS)
+        ids_l = host.name_id[brows].tolist()
+        name_bytes = list(map(self._node_name_bytes().__getitem__, ids_l))
+        names = list(map(host.vocab.node_names._to_val.__getitem__, ids_l))
+        zones = host.zone[brows].tolist()
+        regions = host.region[brows].tolist()
+
+        # Hot path stays injection-free unless a plan is installed.
+        inj_active = bool(faultline.active_injector().plan.faults)
+        # sent: rides the batch CAS; plain: and is retired in columns.
+        if getattr(self.store, "bind_batch", None) is not None:
+            sent = ~_is_none(mods)
+        else:
+            sent = np.zeros(n, bool)
+        plain = np.zeros(n, bool) if inj_active else sent & _is_none(infos)
+
+        def bind_singly(j: int, now: float) -> bool:
+            """One pod through _bind, with what follows a bind there."""
+            if not self._bind(wave[j], names[j]):
+                return False
+            bound_ok[bound_l[j]] = True
+            _BIND_LATENCY.observe(now - enqueued[j])
+            if int(brows[j]) in self._midflight_rows:
+                # A mid-flight full scatter erased this wave's
+                # device-side assume on the row; the host mirror
+                # just learned the bind — re-upload repairs it.
+                self._dirty_rows.add(int(brows[j]))
+            return True
+
+        def conflict(j: int) -> None:
+            """CAS conflict: the device table already assumed this bind
+            (commit_binds), but the host mirror — which is authoritative
+            — was never incremented.  Marking the row dirty re-uploads
+            the host values, undoing the device-side assume; the
+            constraint-count commit is rolled back by the caller in one
+            signed scatter."""
+            self._dirty_rows.add(host.row_of(names[j]))
+            failed[bound_l[j]] = True
+            self._wave_fail(wave[j])
+
+        nbound = 0
+        for j in np.nonzero(~plain)[0].tolist():
+            if sent[j]:
+                # One fault decision per CAS attempt: the batch's
+                # records are checked here (their CAS runs inside
+                # bind_batch); slow-path pods are checked inside
+                # _bind so they never consume two draws per attempt.
+                if inj_active and self._bind_fault():
+                    sent[j] = False
+                    conflict(j)
+            elif bind_singly(j, time.perf_counter()):
+                nbound += 1
+            else:
+                conflict(j)
+
+        sent_j = np.nonzero(sent)[0]
+        if not sent_j.size:
+            return 0, nbound
+
+        pick = _picker(sent)
+        entries = list(zip(pick(keys), pick(mods), pick(name_bytes)))
+        with self._stage("cas", "bind"):
+            answer = self._fenced_bind_batch(
+                entries,
+                self._pods_watch.id if self._bind_excludes else None,
+            )
+        now = time.perf_counter()
+        revs = np.zeros(n, np.int64)
+        revs[sent_j] = np.asarray(answer, np.int64)
+        ok = revs > 0
+
+        ok_j = np.nonzero(ok)[0]
+        if ok_j.size:
+            bound_ok[bound_idx[ok_j]] = True
+            # Duplicate rows (two pods on one node) accumulate
+            # correctly under np.add.at.
+            r = brows[ok_j]
+            np.add.at(
+                host.cpu_req, r,
+                np.fromiter(cpus, host.cpu_req.dtype, n)[ok_j],
+            )
+            np.add.at(
+                host.mem_req, r,
+                np.fromiter(mems, host.mem_req.dtype, n)[ok_j],
+            )
+            np.add.at(host.pods_req, r, 1)
+            nbound += ok_j.size
+            _PODS_SCHEDULED.inc(ok_j.size, outcome="bound")
+            _BIND_LATENCY.observe_many(
+                now - np.fromiter(enqueued, float, n)[ok_j]
+            )
+            if self._midflight_rows:
+                # Same repair as the slow path: rows a mid-flight
+                # full scatter clobbered get the host truth (now
+                # including this wave's binds) re-uploaded.
+                self._dirty_rows.update(
+                    self._midflight_rows.intersection(r.tolist())
+                )
+
+        col = plain & ok
+        columnar = int(np.count_nonzero(col))
+        if columnar:
+            pick = _picker(col)
+            k, on, cpu, mem, prio, gang = map(
+                pick, (key_strs, names, cpus, mems, prios, gangs)
+            )
+            seqs = range(self._bind_seq + 1, self._bind_seq + columnar + 1)
+            self._bind_seq += columnar
+            tenants = _wave_tenants(k, pick(shapes))
+            # A fast-lane record keeps no PodInfo: nothing it carries
+            # could be constraintful.
+            self._bound.update(zip(k, zip(
+                on, cpu, mem, pick(zones), pick(regions),
+                itertools.repeat(None), prio, seqs, tenants, gang,
+            )))
+            if self._track_victims:
+                for note in zip(k, on, cpu, mem, prio, seqs, tenants, gang):
+                    self._victims_note(*note)
+
+        for j in np.nonzero(sent & ~col)[0].tolist():
+            if not ok[j]:
+                if revs[j] != BIND_INVALID:
+                    _PODS_SCHEDULED.inc(outcome="conflict")
+                    conflict(j)
+                elif bind_singly(j, now):
+                    nbound += 1
+                else:
+                    conflict(j)
+                continue
+            p = wave[j]
+            pod = p.pod
+            keep = (
+                pod if pod is not None and self._constraintful(pod) else None
+            )
+            self._bind_seq += 1
+            # bind_batch takes ANY pod with an observed revision,
+            # decoded or not: a decoded PodInfo supplies the label-aware
+            # tenant, a fast-lane pod's shape knows whether its labels
+            # name one; failing both the key's namespace IS the tenant.
+            if pod is not None:
+                tenant = tenant_of_pod(pod)
+            else:
+                sh = p.shape
+                tenant = (
+                    sh.tenant if sh is not None else None
+                ) or tenant_of_key(p.key_str)
+            self._bound[p.key_str] = (
+                names[j], p.cpu_milli, p.mem_kib, zones[j], regions[j], keep,
+                p.priority, self._bind_seq, tenant, p.gang_id,
+            )
+            self._victims_note(
+                p.key_str, names[j], p.cpu_milli, p.mem_kib,
+                p.priority, self._bind_seq, tenant, p.gang_id,
+            )
+        return columnar, nbound
 
     def _trace_retire(self, inflight: Wave, rows, bound_ok, t_sync: float) -> None:
         """Wave-retire observability pass (runs only while tracing is

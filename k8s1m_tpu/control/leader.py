@@ -36,7 +36,7 @@ make a scheduler kill boring (ISSUE 9):
   (SIGSTOP past lease expiry, clock-skewed renewals) is exercised by
   the faultline ``pause`` kind on the ``coordinator.lease`` hook.
 - **Crash-consistent recovery**: derived state (queue, bound-pod
-  ledger, ``_bind_meta``, gang staging) is reconstructed from store
+  ledger with its preemption metadata, gang staging) is reconstructed from store
   facts + watch/intake replay; ``Coordinator.recover_gangs`` settles
   gangs the predecessor left partially bound all-or-none.
 

@@ -801,11 +801,12 @@ class MemStore:
     def bind_batch(
         self, binds: list[tuple[bytes, int, bytes]],
         exclude_watcher: int = -1,
-    ) -> list[int]:
+    ) -> np.ndarray:
         """Splice spec.nodeName into stored pods under mod-revision CAS —
         the whole bind wave in one native call.  ``binds`` entries are
-        (key, required_mod, node_name); returns per-entry new revision,
-        or _ERR_CAS / _ERR_INVALID (caller falls back to the slow path).
+        (key, required_mod, node_name); returns per-entry new revision
+        (an int64 array), or _ERR_CAS / _ERR_INVALID (caller falls back
+        to the slow path).
         ``exclude_watcher`` suppresses the bind events on that one watcher
         (the issuing coordinator's own intake — see memstore.h)."""
         rc, results = self.bind_frame(
@@ -817,9 +818,12 @@ class MemStore:
 
     def bind_frame(
         self, frame: bytes, count: int, exclude_watcher: int = -1
-    ) -> tuple[int, list[int]]:
+    ) -> tuple[int, np.ndarray]:
         """bind_batch over a pre-packed frame (see pack_bind_frame).
-        Returns (bound_count_or_negative_error, per_record_revisions)."""
+        Returns (bound_count_or_negative_error, per_record_revisions);
+        the revisions are one int64 array, not a Python int a record."""
+        import numpy as np
+
         lib = _lib()
         out = ctypes.POINTER(ctypes.c_int64)()
         rc = lib.ms_bind_batch(
@@ -827,8 +831,8 @@ class MemStore:
             ctypes.byref(out)
         )
         if rc < 0:
-            return rc, []
-        results = out[:count]
+            return rc, np.empty(0, np.int64)
+        results = np.ctypeslib.as_array(out, (max(count, 1),))[:count].copy()
         lib.ms_free(out)
         return rc, results
 

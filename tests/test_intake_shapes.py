@@ -175,8 +175,15 @@ def _assert_waves_equal(a: _Lane, b: _Lane, first: int | None = None) -> None:
 
 
 def _assert_accounting_equal(a: Coordinator, b: Coordinator) -> None:
-    assert a._bound == b._bound
-    assert a._bind_meta == b._bind_meta
+    """``_bound`` equal but for ``_bind_seq`` (its field 7), which is held
+    up to its order among the pods of one wave: a record that holds a
+    PodInfo takes its number after the wave's fast-lane records
+    (``Coordinator._complete``), and which records hold one is what
+    differs between the two lanes."""
+    strip = lambda bound: {k: r[:7] + r[8:] for k, r in bound.items()}
+    assert strip(a._bound) == strip(b._bound)
+    assert sorted(r[7] for r in a._bound.values()) == \
+        sorted(r[7] for r in b._bound.values())
     assert set(a.unschedulable) == set(b.unschedulable)
     np.testing.assert_array_equal(a.host.cpu_req, b.host.cpu_req)
     np.testing.assert_array_equal(a.host.mem_req, b.host.mem_req)
@@ -186,7 +193,7 @@ def _assert_accounting_equal(a: Coordinator, b: Coordinator) -> None:
 def test_shaped_lane_equals_json_lane(lanes):
     """Same seeded waves, both lanes: equal queue order, equal
     ``peek_pod`` of every record, byte-equal packed batches, equal
-    ``_delta_key``, equal binds, equal ``_bound`` and ``_bind_meta``."""
+    ``_delta_key``, equal binds, equal ``_bound``."""
     shaped, legacy = lanes()
     for lane in (shaped, legacy):
         lane.bootstrap()
@@ -209,7 +216,7 @@ def test_shaped_lane_equals_json_lane(lanes):
     # One template, decoded once, whatever the number of pods.
     assert len(shaped.coord._pod_shapes) == 1
     # The tenant of a bound pod is its namespace on both lanes.
-    assert {m[2] for m in shaped.coord._bind_meta.values()} == {"bench"}
+    assert {r[8] for r in shaped.coord._bound.values()} == {"bench"}
 
 
 def test_tenant_label_reaches_bind_meta_on_both_lanes(lanes):
@@ -224,7 +231,7 @@ def test_tenant_label_reaches_bind_meta_on_both_lanes(lanes):
         lane.put(pods)
         assert lane.coord.run_until_idle() == 8
     _assert_accounting_equal(shaped.coord, legacy.coord)
-    assert {m[2] for m in shaped.coord._bind_meta.values()} == \
+    assert {r[8] for r in shaped.coord._bound.values()} == \
         {"team-0", "team-1"}
 
 
@@ -341,7 +348,7 @@ def test_external_bind_of_a_shaped_pod_is_accounted_with_its_labels(lanes):
         lane.coord.drain_watches()
         assert not lane.coord.queue
         assert lane.coord._bound["default/ext"][0] == "kwok-node-3"
-        assert lane.coord._bind_meta["default/ext"][2] == "team-x"
+        assert lane.coord._bound["default/ext"][8] == "team-x"
     _assert_accounting_equal(shaped.coord, legacy.coord)
 
 

@@ -92,7 +92,7 @@ def main():
         "holds the cold node-table columns bit/byte-packed in HBM and "
         "decodes per chunk on device — byte-identical binds, >=2x less "
         "cold-column HBM (the report's cold_bytes_reduction).  Unset "
-        "defers to K8S1M_PACKING.  Composes with --mesh: the packed "
+        "is 'off'.  Composes with --mesh: the packed "
         "planes shard over sp and decode in the shard-local chunk "
         "slice (the production path since meshpack).",
     )

@@ -134,7 +134,7 @@ def make_coordinator(store, *, nodes, batch, chunk, score_pct, mesh=None):
         Profile(node_affinity=0, topology_spread=0, interpod_affinity=0),
         chunk=chunk, with_constraints=False, backend="pallas",
         pipeline=True, depth=2, score_pct=score_pct,
-        mesh=mesh if mesh is not None else "none",
+        mesh=mesh,
         packing="packed", seed=SEED,
     )
 
@@ -388,15 +388,11 @@ def _equal(name: str, a, b) -> None:
 
 def _window_candidates(packed, profile, *, rows, chunk, k, offset, backend):
     """Jitted ``(table, ints, bools, key, constraints) -> (idx, prio)``:
-    the step's candidate stage over one scan window — the same
-    composition engine/cycle._jitted_schedule_packed runs (global domain
-    statistics, window-local node columns)."""
+    the step's own candidates stage (engine/cycle.candidates) over one
+    scan window, on the backend asked for."""
     import jax
-    from jax import lax
 
-    from k8s1m_tpu.engine.cycle import _prologue_stats, filter_score_topk
-    from k8s1m_tpu.ops.pallas_topk import pallas_candidates
-    from k8s1m_tpu.snapshot.constraints import slice_constraints
+    from k8s1m_tpu.engine.cycle import candidates
     from k8s1m_tpu.snapshot.pod_encoding import unpack_pod_batch
 
     aff = bool(packed.groups & {"sel", "req", "pref"})
@@ -405,23 +401,10 @@ def _window_candidates(packed, profile, *, rows, chunk, k, offset, backend):
         batch = unpack_pod_batch(
             ints, bools, packed.spec, packed.table_spec, packed.groups
         )
-        view = jax.tree.map(
-            lambda a: lax.dynamic_slice_in_dim(a, offset, rows, 0), table
+        cand = candidates(
+            table, batch, key, constraints, profile, chunk=chunk, k=k,
+            backend=backend, with_affinity=aff, window=(offset, rows),
         )
-        stats = view_cons = None
-        if constraints is not None:
-            stats = _prologue_stats(table, constraints)
-            view_cons = slice_constraints(constraints, offset, rows)
-        if backend == "pallas":
-            cand = pallas_candidates(
-                view, batch, key, profile, chunk=chunk, k=k,
-                with_affinity=aff, constraints=view_cons, stats=stats,
-            )
-        else:
-            cand = filter_score_topk(
-                view, batch, key, profile, chunk=chunk, k=k,
-                constraints=view_cons, stats=stats,
-            )
         return cand.idx, cand.prio
 
     return jax.jit(impl)

@@ -111,9 +111,9 @@ class ClusterSpec:
     chunk: int = 1 << 10
     backend: str = "xla"
     # Device-mesh execution (parallel/mesh.py): "DPxSP", "auto", or None
-    # (None defers to K8S1M_MESH; unset = single-device).  The mesh and
-    # the scheduler shard set are different scale-out axes — shard mode
-    # pins its members single-device (compose meshes across processes).
+    # (single device).  The mesh and the scheduler shard set are
+    # different scale-out axes — shard members stay single-device
+    # (compose meshes across processes).
     mesh: str | None = None
 
     def __post_init__(self):
@@ -288,9 +288,6 @@ class Cluster:
                     spec.profile, chunk=spec.chunk, backend=spec.backend,
                     with_constraints=spec.profile.topology_spread > 0
                     or spec.profile.interpod_affinity > 0,
-                    # Shard members scale out by row masks; "none" also
-                    # shuts out a K8S1M_MESH inherited from the rig env.
-                    mesh="none",
                 )
                 self.shard_members.append(
                     ShardMember(store, coord, i, spec.shards)
@@ -319,8 +316,6 @@ class Cluster:
                             backend=spec.backend,
                             with_constraints=spec.profile.topology_spread > 0
                             or spec.profile.interpod_affinity > 0,
-                            # spec.mesh ("DPxSP"/"auto"/None->K8S1M_MESH)
-                            # is the tfvars-level production-mesh switch.
                             mesh=spec.mesh,
                         ),
                     )

@@ -748,23 +748,22 @@ class Coordinator:
         # the cold node-table columns bit/byte-packed in HBM (labels
         # fused, taint effects + validity in one meta word, narrow
         # zone/region/pods planes) and decodes per chunk on device —
-        # byte-identical binds, >=2x less cold-column HBM.  None defers
-        # to the K8S1M_PACKING env var ("off" default).  Fail-closed:
-        # vocab drift past the static bit budget rebuilds under a wider
-        # layout (device_packing_fallback_total) — the widening decision
-        # is made ONCE on the host, so a mesh coordinator never diverges
-        # per-shard.  Composes with ``mesh`` (meshpack): the packed
-        # planes shard over sp like the plain columns and decode inside
-        # the shard-local chunk slice.
+        # byte-identical binds, >=2x less cold-column HBM.  None is
+        # "off".  Fail-closed: vocab drift past the static bit budget
+        # rebuilds under a wider layout (device_packing_fallback_total)
+        # — the widening decision is made ONCE on the host, so a mesh
+        # coordinator never diverges per-shard.  Composes with ``mesh``
+        # (meshpack): the packed planes shard over sp like the plain
+        # columns and decode inside the shard-local chunk slice.
         packing: str | None = None,
         # Incremental scheduling (engine/deltacache.py): cache each pod
         # shape's feasibility/score plane in HBM and run the full
         # filter+score kernel only over dirty rows ∪ in-flight bind
         # rows when every shape in a wave hits — byte-identical binds,
-        # O(batch × dirty) steady-state device work.  None defers to
-        # the K8S1M_DELTASCHED env var ("off" default).  Engages only
-        # for full-scan XLA waves (score_pct 100, no row mask, not
-        # degraded); everything else takes the ordinary full pass.
+        # O(batch × dirty) steady-state device work.  None is "off".
+        # Engages only for full-scan XLA waves (score_pct 100, no row
+        # mask, not degraded); everything else takes the ordinary full
+        # pass.
         deltacache: str | bool | None = None,
         delta_slots: int = 64,
         # Score-stratified candidate index (engine/deltacache.py): keep
@@ -832,9 +831,7 @@ class Coordinator:
         # rotate SHARD-LOCALLY (each device samples its own rows, like
         # each dist-scheduler replica samples the nodes it owns).
         # ``mesh`` accepts a built jax Mesh, a spec string ("2x4",
-        # "auto", "none"), or None — which defers to the K8S1M_MESH env
-        # var (unset = single-device), so deployments flip the
-        # production path on without touching construction sites.
+        # "auto", "none"), or None (single device).
         if mesh is None or isinstance(mesh, str):
             from k8s1m_tpu.parallel.mesh import resolve_mesh
 

@@ -413,8 +413,8 @@ def finalize_batch(
     Returns (table, constraints, Assignment).
 
     The two phases carry ``jax.named_scope`` names (``assign``,
-    ``commit``; the callers put ``candidates`` on theirs), so a device
-    trace can be read by phase whatever the ops under them become."""
+    ``commit``; ``candidates()`` carries the third), so a device trace
+    can be read by phase whatever the ops under them become."""
     with jax.named_scope("assign"):
         node_row, bound, score, chosen_k = greedy_assign(
             cand.idx, cand.prio, cand.cpu, cand.mem, cand.pods,
@@ -476,6 +476,101 @@ adjust_constraints = jax.jit(  # graftlint: disable=undonated-device-update (rep
 )
 
 
+def candidates(
+    table: NodeTable,
+    batch: PodBatch,
+    key: jax.Array,
+    constraints: ConstraintState | None,
+    profile: Profile,
+    *,
+    chunk: int,
+    k: int,
+    backend: str = "xla",
+    with_affinity: bool = True,
+    src: NodeTable | None = None,
+    window=None,
+    row_offset=0,
+    pod_offset=0,
+    axis_name: str | None = None,
+    stratum_bits: int = 0,
+) -> Candidates:
+    """The candidates stage of every full step — which rows are scanned,
+    by which kernel, under which hash coordinates.  Every step shell
+    calls this; nothing else reaches ``filter_score_topk`` or
+    ``pallas_candidates``.
+
+    ``src`` (default: the table) is the candidate-selection view; binds
+    always commit into ``table`` — the split that makes ownership masks
+    (mask_rows) work without touching commit state.  ``window`` is None
+    or ``(offset, rows)``, percentageOfNodesToScore: only rows [offset,
+    offset+rows) of ``src`` are filtered+scored (the reference scores 5%
+    of nodes per pod at 1M scale, README.adoc:525-531); ``offset`` may be
+    traced, and the emitted rows are table rows either way.  Under
+    shard_map ``axis_name`` is the node-shard axis and ``row_offset`` /
+    ``pod_offset`` the shard's global bases (sharded_cycle.mesh_offsets).
+    ``backend="pallas"`` is the fused kernel (ops/pallas_topk.py),
+    constraint plugins included when ``constraints`` is passed;
+    ``with_affinity=False`` compiles its cheaper selector-free form."""
+    src = table if src is None else src
+    with jax.named_scope("candidates"):
+        # Domain statistics are GLOBAL by semantics (a spread constraint's
+        # min/max is over the whole cluster): built from the commit table,
+        # never from the candidate view — an ownership mask or a window
+        # narrows candidate selection, not the skew baseline, or shards
+        # would disagree on feasibility.  Only the per-node count columns
+        # follow the window.
+        stats = (
+            _prologue_stats(table, constraints, axis_name)
+            if constraints is not None else None
+        )
+        view, view_cons, remap = src, constraints, None
+        if window is not None:
+            offset, rows = window
+            view = jax.tree.map(
+                lambda a: lax.dynamic_slice_in_dim(a, offset, rows, 0), src
+            )
+            if constraints is not None:
+                view_cons = slice_constraints(constraints, offset, rows)
+            # Both surfaces keep the hash columns they had: one device
+            # hashes WINDOW-LOCAL columns and remaps the rows afterwards,
+            # the mesh hashes GLOBAL columns (it must, for an unsampled
+            # wave to equal the single-device wave byte for byte, and its
+            # windows inherited that base).  So a sampled wave differs
+            # between the two; making them agree would change every bind
+            # of kwok-1m-pct5 (ROADMAP D12).
+            if axis_name is None:
+                remap = offset
+            else:
+                row_offset = row_offset + offset
+        if backend == "pallas":
+            from k8s1m_tpu.ops import pallas_topk
+
+            if constraints is None and not pallas_topk.supports(profile):
+                raise ValueError(
+                    "profile enables constraint plugins but no constraint "
+                    "state was passed (see ops/pallas_topk.py)"
+                )
+            cand = pallas_topk.pallas_candidates(
+                view, batch, key, profile, chunk=chunk, k=k,
+                row_offset=row_offset, pod_offset=pod_offset,
+                with_affinity=with_affinity,
+                constraints=view_cons, stats=stats,
+                stratum_bits=stratum_bits,
+            )
+        else:
+            cand = filter_score_topk(
+                view, batch, key, profile,
+                chunk=chunk, k=k, constraints=view_cons, stats=stats,
+                row_offset=row_offset, pod_offset=pod_offset,
+                stratum_bits=stratum_bits,
+            )
+        if remap is not None:
+            cand = cand.replace(
+                idx=jnp.where(cand.idx >= 0, cand.idx + remap, -1)
+            )
+    return cand
+
+
 def _schedule_batch_impl(
     table: NodeTable,
     batch: PodBatch,
@@ -488,36 +583,13 @@ def _schedule_batch_impl(
     with_affinity: bool = True,
     src: NodeTable | None = None,
     stratum_bits: int = 0,
+    window=None,
 ):
-    # ``src`` (default: the table itself) is the candidate-selection view;
-    # binds always commit into ``table`` — the split that makes ownership
-    # masks (mask_rows) work without touching commit state.
-    src = table if src is None else src
-    with jax.named_scope("candidates"):
-        stats = None
-        if constraints is not None:
-            # Domain statistics are GLOBAL by semantics (a spread
-            # constraint's min/max is over the whole cluster): build them
-            # from the commit table, not the candidate view — an ownership
-            # mask (mask_rows) must narrow candidate selection, never the
-            # skew baseline, or shards would disagree on feasibility.  The
-            # sampling path below applies the same rule.
-            stats = _prologue_stats(table, constraints)
-        if backend == "pallas":
-            from k8s1m_tpu.ops.pallas_topk import pallas_candidates
-
-            cand = pallas_candidates(
-                src, batch, key, profile, chunk=chunk, k=k,
-                with_affinity=with_affinity,
-                constraints=constraints, stats=stats,
-                stratum_bits=stratum_bits,
-            )
-        else:
-            cand = filter_score_topk(
-                src, batch, key, profile,
-                chunk=chunk, k=k, constraints=constraints, stats=stats,
-                stratum_bits=stratum_bits,
-            )
+    cand = candidates(
+        table, batch, key, constraints, profile, chunk=chunk, k=k,
+        backend=backend, with_affinity=with_affinity, src=src,
+        window=window, stratum_bits=stratum_bits,
+    )
     return finalize_batch(table, constraints, cand, commit_fields_of(batch))
 
 
@@ -575,14 +647,6 @@ def schedule_batch(
     nodeSelector/affinity terms (the packed path derives this per wave
     from the field groups).
     """
-    if backend == "pallas" and constraints is None:
-        from k8s1m_tpu.ops import pallas_topk
-
-        if not pallas_topk.supports(profile):
-            raise ValueError(
-                "profile enables constraint plugins but no constraint "
-                "state was passed (see ops/pallas_topk.py)"
-            )
     step = _jitted_schedule(
         profile, chunk, k, constraints is not None, backend, with_affinity,
         stratum_bits,
@@ -641,78 +705,13 @@ def _jitted_schedule_packed(
 
     def impl(table, ints, bools, key, offset, row_mask, constraints):
         batch = unpack_pod_batch(ints, bools, pod_spec, table_spec, groups)
-        src = table if row_mask is None else mask_rows(table, row_mask)
-        if sample_rows is None:
-            table, cons, asg = _schedule_batch_impl(
-                table, batch, key, constraints, profile, chunk, k, backend,
-                with_affinity=aff,
-                src=None if row_mask is None else src,
-                stratum_bits=stratum_bits,
-            )
-        else:
-            # percentageOfNodesToScore: filter+score only a rotating
-            # window of the node table (the reference's production
-            # config scores 5% of nodes per pod at 1M scale —
-            # terraform/tfvars percentageOfNodesToScore: 5,
-            # README.adoc:525-531); the bind commit still lands in the
-            # full table.  Candidate rows are remapped from window-local
-            # to global.
-            with jax.named_scope("candidates"):
-                view = jax.tree.map(
-                    lambda a: lax.dynamic_slice_in_dim(
-                        a, offset, sample_rows, 0
-                    ),
-                    src,
-                )
-                if backend == "pallas":
-                    from k8s1m_tpu.ops.pallas_topk import pallas_candidates
-
-                    p_stats = None
-                    view_cons = None
-                    if constraints is not None:
-                        # Same composition rule as the XLA branch below:
-                        # global domain statistics, window-local node cols.
-                        from k8s1m_tpu.snapshot.constraints import (
-                            slice_constraints,
-                        )
-
-                        p_stats = _prologue_stats(table, constraints)
-                        view_cons = slice_constraints(
-                            constraints, offset, sample_rows
-                        )
-                    cand = pallas_candidates(
-                        view, batch, key, profile, chunk=chunk, k=k,
-                        with_affinity=aff,
-                        constraints=view_cons, stats=p_stats,
-                        stratum_bits=stratum_bits,
-                    )
-                else:
-                    stats = None
-                    view_cons = None
-                    if constraints is not None:
-                        # Constraint plugins under sampling: domain statistics
-                        # are GLOBAL reductions over the full count tables
-                        # (the prologue never depended on the scan window);
-                        # only the per-node count columns follow the window.
-                        from k8s1m_tpu.snapshot.constraints import (
-                            slice_constraints,
-                        )
-
-                        stats = _prologue_stats(table, constraints)
-                        view_cons = slice_constraints(
-                            constraints, offset, sample_rows
-                        )
-                    cand = filter_score_topk(
-                        view, batch, key, profile, chunk=chunk, k=k,
-                        constraints=view_cons, stats=stats,
-                        stratum_bits=stratum_bits,
-                    )
-                cand = cand.replace(
-                    idx=jnp.where(cand.idx >= 0, cand.idx + offset, -1)
-                )
-            table, cons, asg = finalize_batch(
-                table, constraints, cand, commit_fields_of(batch)
-            )
+        table, cons, asg = _schedule_batch_impl(
+            table, batch, key, constraints, profile, chunk, k, backend,
+            with_affinity=aff,
+            src=None if row_mask is None else mask_rows(table, row_mask),
+            stratum_bits=stratum_bits,
+            window=None if sample_rows is None else (offset, sample_rows),
+        )
         # One fetchable result array: the bound node row per pod, -1 for
         # unbound.  Every device_get is a device->host sync; the
         # coordinator reads this single array per wave.
@@ -810,14 +809,6 @@ def schedule_batch_packed(
 
     Returns (new_table, new_constraints, Assignment, rows).
     """
-    if backend == "pallas" and constraints is None:
-        from k8s1m_tpu.ops import pallas_topk
-
-        if not pallas_topk.supports(profile):
-            raise ValueError(
-                "profile enables constraint plugins but no constraint "
-                "state was passed (see ops/pallas_topk.py)"
-            )
     if mesh is not None:
         if row_mask is not None:
             raise ValueError("mesh and row_mask are mutually exclusive")

@@ -63,7 +63,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import logging
-import os
 import weakref
 
 import jax
@@ -146,17 +145,14 @@ _PLANES_RESIDENT.set_function(
 
 
 def resolve_deltasched(arg: str | bool | None = None) -> str:
-    """Delta-cache mode from an explicit arg or the K8S1M_DELTASCHED env
-    var.  Returns "off" or "on"; unknown values fail loudly (a typo'd
-    env var silently running full recompute would invalidate every
-    steady-state number downstream)."""
+    """Delta-cache mode: "off" (also ``None``/``False``) or "on" (also
+    ``True``).  Unknown values fail loudly (a typo silently running full
+    recompute would invalidate every steady-state number downstream)."""
     if isinstance(arg, bool):
         return "on" if arg else "off"
-    mode = arg if arg is not None else os.environ.get("K8S1M_DELTASCHED", "off")
+    mode = "off" if arg is None else arg
     if mode not in ("off", "on"):
-        raise ValueError(
-            f"K8S1M_DELTASCHED/deltacache must be off|on, got {mode!r}"
-        )
+        raise ValueError(f"deltacache must be off|on, got {mode!r}")
     return mode
 
 

@@ -23,10 +23,10 @@ time:
    local assignments; a tuple-unpack from ``mesh_offsets(...)`` is the
    sanctioned laundering point.
 3. **top-k tie-breaks reference global offsets** — inside ``parallel/``,
-   every ``filter_score_topk``/``pallas_candidates`` call must pass BOTH
-   ``row_offset=`` and ``pod_offset=``; omitting either silently falls
-   back to shard-local coordinates and byte-identity dies at the first
-   cross-shard tie.
+   every ``candidates``/``filter_score_topk``/``pallas_candidates``
+   call must pass BOTH ``row_offset=`` and ``pod_offset=``; omitting
+   either silently falls back to shard-local coordinates and
+   byte-identity dies at the first cross-shard tie.
 4. **no set iteration in encode/merge paths** — in
    ``snapshot/hotfeed*.py`` and ``snapshot/pod_encoding.py`` (the paths
    whose output ``merge_packed`` must rebuild byte-identically),
@@ -56,7 +56,7 @@ MERGE_PATHS = ("k8s1m_tpu/snapshot/pod_encoding.py",)
 
 _TAINT_SOURCES = {"axis_index", "psum"}
 _HASH_SINKS = {"hash_jitter", "pack_hashed", "pack", "seed_of"}
-_TOPK_CALLS = {"filter_score_topk", "pallas_candidates"}
+_TOPK_CALLS = {"candidates", "filter_score_topk", "pallas_candidates"}
 _BLESSED = "mesh_offsets"
 
 

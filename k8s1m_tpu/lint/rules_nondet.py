@@ -23,7 +23,8 @@ intra-repo helper whose RETURN derives from a source — into a
 **placement sink** inside ``engine/ parallel/ ops/ snapshot/
 tenancy/``:
 
-- ``filter_score_topk`` / ``pallas_candidates`` (candidate selection),
+- ``candidates`` / ``filter_score_topk`` / ``pallas_candidates``
+  (candidate selection),
 - ``hash_jitter`` / ``seed_of`` (tie-break hashing),
 - ``commit_binds`` / ``bind_batch`` / ``_fenced_cas`` /
   ``_fenced_bind_batch`` (store-visible placement writes),
@@ -74,7 +75,8 @@ _RNG_EXEMPT_LEAVES = {"Random", "default_rng", "seed"}
 _MISC_SOURCES = {"os.urandom", "uuid.uuid4", "uuid.uuid1"}
 
 _SINK_CALLS = {
-    "filter_score_topk", "pallas_candidates", "hash_jitter", "seed_of",
+    "candidates", "filter_score_topk", "pallas_candidates", "hash_jitter",
+    "seed_of",
     "commit_binds", "bind_batch", "_fenced_cas", "_fenced_bind_batch",
     "select_preemption", "victim_sort_key",
 }

@@ -1,5 +1,4 @@
 from k8s1m_tpu.parallel.mesh import (
-    MESH_ENV,
     auto_mesh_shape,
     batch_specs,
     make_mesh,
@@ -13,7 +12,6 @@ from k8s1m_tpu.parallel.sharded_cycle import (
 )
 
 __all__ = [
-    "MESH_ENV",
     "auto_mesh_shape",
     "make_mesh",
     "parse_mesh",

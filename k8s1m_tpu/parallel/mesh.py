@@ -27,7 +27,6 @@ one production path (meshpack).
 from __future__ import annotations
 
 import logging
-import os
 
 import jax
 import numpy as np
@@ -37,13 +36,6 @@ from k8s1m_tpu.snapshot.node_table import NodeTable
 from k8s1m_tpu.snapshot.pod_encoding import PodBatch
 
 log = logging.getLogger("k8s1m.mesh")
-
-# Production mesh selection (the tfvars-level knob): "DPxSP" (also
-# accepts "DP,SP"), "auto" (largest valid dp x sp over the visible
-# devices), or "none"/"" (single-device).  Read by Coordinator when no
-# explicit mesh is passed, and inherited by every tool that builds one.
-MESH_ENV = "K8S1M_MESH"
-
 
 def make_mesh(dp: int, sp: int, devices=None) -> jax.sharding.Mesh:
     if devices is None:
@@ -97,24 +89,17 @@ def auto_mesh_shape(
     return None
 
 
-def resolve_mesh(
-    mesh, *, batch: int, max_nodes: int, chunk: int, env=None
-):
+def resolve_mesh(mesh, *, batch: int, max_nodes: int, chunk: int):
     """The coordinator's mesh-selection funnel.
 
     ``mesh`` may be an already-built jax Mesh (returned as-is), a spec
-    string ("DPxSP", "auto", "none"), or None — in which case the
-    ``K8S1M_MESH`` env var decides (unset = single-device, so nothing
-    changes for callers that never asked for a mesh).  "auto" picks the
-    largest workload-valid dp x sp over the visible devices and falls
-    back to single-device (with a log line saying why) when none fits —
-    the single-device fallback story documented in README "Sharded
-    execution"."""
+    string — "DPxSP" (also "DP,SP"), "auto", "none" — or None (single
+    device).  "auto" picks the largest workload-valid dp x sp over the
+    visible devices and falls back to single-device (with a log line
+    saying why) when none fits — the single-device fallback story
+    documented in README "Sharded execution"."""
     if mesh is None or isinstance(mesh, str):
-        spec = mesh if isinstance(mesh, str) else (
-            (env if env is not None else os.environ).get(MESH_ENV)
-        )
-        shape = parse_mesh(spec)
+        shape = parse_mesh(mesh)
         if shape is None:
             return None
         if shape == "auto":

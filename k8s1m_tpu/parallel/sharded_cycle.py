@@ -31,7 +31,9 @@ bit-identical to the single-device cycle for the same wave.  This is
 what lets the coordinator promote the mesh to the production execution
 path with a differential gate instead of a statistical one
 (tests/test_mesh_differential.py; sampled windows are the one
-exception — they rotate SHARD-locally by design).
+exception — they rotate SHARD-locally by design, and hash global
+columns where one device hashes window-local ones:
+engine/cycle.candidates).
 
 Pipelined snapshot mutation: the coordinator's dirty-row scatters
 (make_sharded_scatter) consume the *latest* table future, so they are
@@ -65,8 +67,8 @@ import functools
 
 from k8s1m_tpu.engine.cycle import (
     Assignment,
+    candidates,
     commit_fields_of,
-    filter_score_topk,
     finalize_batch,
 )
 from k8s1m_tpu.parallel.mesh import batch_specs, constraint_specs, table_specs
@@ -163,23 +165,14 @@ def make_sharded_step(mesh, profile: Profile, *, chunk: int, k: int):
     -> (table, constraints|None, Assignment); table (and hostname-domain
     count tables) sharded over sp, batch over dp, assignment replicated.
     """
-    from k8s1m_tpu.plugins import topology
-
     def _local_step(table: NodeTable, batch: PodBatch, key: jax.Array,
                     constraints: ConstraintState | None = None):
         pod_offset, row_offset = mesh_offsets(table, batch.batch)
-
-        stats = (
-            topology.prologue(table, constraints, axis_name="sp")
-            if constraints is not None else None
-        )
-
         # Local filter+score+top-k over this device's block — same key
         # on every device, global hash coordinates (see mesh_offsets).
-        cand = filter_score_topk(
-            table, batch, key, profile,
-            chunk=chunk, k=k, constraints=constraints, stats=stats,
-            row_offset=row_offset, pod_offset=pod_offset,
+        cand = candidates(
+            table, batch, key, constraints, profile, chunk=chunk, k=k,
+            row_offset=row_offset, pod_offset=pod_offset, axis_name="sp",
         )
         return gather_and_finalize(table, batch, cand, constraints, k=k)
 
@@ -264,8 +257,6 @@ def make_sharded_packed_step(
     -> (table, constraints|None, Assignment, rows i32[B]); table and
     constraint node tables sharded, everything else replicated.
     """
-    from k8s1m_tpu.engine.cycle import _prologue_stats
-    from k8s1m_tpu.snapshot.constraints import slice_constraints
     from k8s1m_tpu.snapshot.pod_encoding import unpack_pod_batch
 
     dp_size = mesh.shape["dp"]
@@ -298,46 +289,16 @@ def make_sharded_packed_step(
             qkey=full.qkey          # qkey is [Q]; stays whole on every rank
         )
 
-        stats = (
-            # Shared with the single-device path: a packed table decodes
-            # its DomainView once per wave, then the same cross-shard
-            # prologue reductions run (engine/cycle._prologue_stats).
-            _prologue_stats(table, constraints, axis_name="sp")
-            if constraints is not None else None
-        )
-
-        if sample_rows is None:
-            view, view_cons, view_off = table, constraints, row_offset
-        else:
-            view = jax.tree.map(
-                lambda a: lax.dynamic_slice_in_dim(a, offset, sample_rows, 0),
-                table,
-            )
-            view_cons = (
-                slice_constraints(constraints, offset, sample_rows)
-                if constraints is not None else None
-            )
-            view_off = row_offset + offset
-
         # Same key on every device; the tie-break jitter globalizes via
-        # the (pod_offset, view_off) hash bases instead (mesh_offsets) —
+        # the (pod_offset, row_offset) hash bases instead (mesh_offsets) —
         # an unsampled wave is byte-identical to the single-device wave.
-        if backend == "pallas":
-            from k8s1m_tpu.ops.pallas_topk import pallas_candidates
-
-            cand = pallas_candidates(
-                view, batch, key, profile, chunk=chunk, k=k,
-                row_offset=view_off, pod_offset=pod_offset,
-                with_affinity=aff, constraints=view_cons, stats=stats,
-                stratum_bits=stratum_bits,
-            )
-        else:
-            cand = filter_score_topk(
-                view, batch, key, profile, chunk=chunk, k=k,
-                constraints=view_cons, stats=stats,
-                row_offset=view_off, pod_offset=pod_offset,
-                stratum_bits=stratum_bits,
-            )
+        cand = candidates(
+            table, batch, key, constraints, profile, chunk=chunk, k=k,
+            backend=backend, with_affinity=aff,
+            window=None if sample_rows is None else (offset, sample_rows),
+            row_offset=row_offset, pod_offset=pod_offset, axis_name="sp",
+            stratum_bits=stratum_bits,
+        )
 
         table, cons, asg = gather_and_finalize(
             table, batch, cand, constraints, k=k

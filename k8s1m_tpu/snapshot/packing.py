@@ -105,17 +105,12 @@ class PackingSpec:
 
 
 def resolve_packing(arg: str | None = None) -> str:
-    """Packing mode from an explicit arg or the K8S1M_PACKING env var.
-
-    Returns "off" or "packed"; unknown values fail loudly (a typo'd env
-    var silently running unpacked would invalidate every bytes/node
-    number downstream).
-    """
-    import os
-
-    mode = arg if arg is not None else os.environ.get("K8S1M_PACKING", "off")
+    """Packing mode: "off" (also ``None``) or "packed".  Unknown values
+    fail loudly (a typo silently running unpacked would invalidate every
+    bytes/node number downstream)."""
+    mode = "off" if arg is None else arg
     if mode not in ("off", "packed"):
-        raise ValueError(f"K8S1M_PACKING/packing must be off|packed, got {mode!r}")
+        raise ValueError(f"packing must be off|packed, got {mode!r}")
     return mode
 
 

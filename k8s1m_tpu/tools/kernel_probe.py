@@ -176,9 +176,7 @@ def main(argv=None):
         "--packing", choices=("off", "packed"), default=None,
         help="device-snapshot layout (snapshot/packing.py): 'packed' "
         "probes the bit/byte-packed production layout, so the per-chunk "
-        "decode cost shows up in every variant's ms.  Unset defers to "
-        "K8S1M_PACKING — same resolution as bench.py/sched_bench, so "
-        "one env var keeps the whole evidence pipeline on one layout",
+        "decode cost shows up in every variant's ms.  Unset is 'off'",
     )
     args = ap.parse_args(argv)
     from k8s1m_tpu.snapshot.packing import resolve_packing

@@ -99,8 +99,7 @@ def parse_args(argv=None):
         help="incremental scheduling (engine/deltacache.py): cache each "
         "pod shape's feasibility/score plane in HBM and recompute only "
         "dirty rows on a shape hit — byte-identical binds, O(batch x "
-        "dirty) steady-state device work.  Unset defers to "
-        "K8S1M_DELTASCHED ('off' default)",
+        "dirty) steady-state device work.  Unset is 'off'",
     )
     ap.add_argument(
         "--delta-profile", action="store_true",
@@ -192,8 +191,8 @@ def parse_args(argv=None):
         "device mesh (parallel/sharded_cycle.make_sharded_packed_step) — "
         "the reference's multi-replica fan-out as mesh devices.  "
         "Accepts DPxSP or DP,SP (dp*sp <= len(jax.devices())), or "
-        "'auto' (largest workload-valid split).  Unset defers to "
-        "K8S1M_MESH; the sharded run is byte-identical to single-device "
+        "'auto' (largest workload-valid split).  Unset is single "
+        "device; the sharded run is byte-identical to single-device "
         "at score-pct 100, so every churn/overload/encode-profile lane "
         "composes with it.  Mesh evidence (per-shard staged feed depth, "
         "sharded-scatter counts) lands in the report detail.",
@@ -258,7 +257,7 @@ def parse_args(argv=None):
         help="device-snapshot layout (snapshot/packing.py): 'packed' "
         "holds the cold node-table columns bit/byte-packed in HBM "
         "(byte-identical binds, >=2x less cold-column HBM).  Unset "
-        "defers to K8S1M_PACKING.  Layout + donation evidence lands in "
+        "is 'off'.  Layout + donation evidence lands in "
         "the report's device_state detail",
     )
     ap.add_argument(
@@ -837,10 +836,8 @@ def main(argv=None):
     args.chunk = min(args.chunk, cap)
     from k8s1m_tpu.parallel import resolve_mesh
 
-    # One resolve here (explicit --mesh, or K8S1M_MESH when unset) so
-    # the chunk clamp below applies however the mesh was selected, and
-    # an explicit `--mesh none` really opts out even under a rig env
-    # that exports K8S1M_MESH.
+    # Resolved here, not in the Coordinator, so the chunk clamp below
+    # sees the mesh "auto" picked.
     mesh = resolve_mesh(
         args.mesh, batch=args.batch, max_nodes=cap, chunk=args.chunk
     )
@@ -866,9 +863,7 @@ def main(argv=None):
         profile, chunk=args.chunk, with_constraints=False,
         backend=args.backend, pipeline=not args.no_pipeline, depth=args.depth,
         score_pct=args.score_pct, adaptive_batch=bool(args.rate),
-        # Already resolved above (env included): a built Mesh, or
-        # "none" so the Coordinator does NOT re-read K8S1M_MESH.
-        mesh=mesh if mesh is not None else "none",
+        mesh=mesh,
         packing=args.packing,
         deltacache=args.deltacache,
         delta_index_k=args.delta_index_k,

@@ -200,18 +200,9 @@ def parse_args(argv=None):
     if args.trace_out and not args.trace:
         ap.error("--trace-out requires --trace (the pod tracer)")
     if args.packing is None:
-        # Same resolution chain as every other entry point: an explicit
-        # K8S1M_PACKING keeps the whole evidence pipeline on one layout
-        # (resolve_packing also rejects typo'd values loudly).  Only
-        # when the env var is ALSO unset does the mesh drill default to
-        # the composed production path — packed x sharded x donated
-        # gated together (meshpack).
-        if os.environ.get("K8S1M_PACKING") is not None:
-            from k8s1m_tpu.snapshot.packing import resolve_packing
-
-            args.packing = resolve_packing(None)
-        else:
-            args.packing = "packed" if args.mesh else "off"
+        # The mesh drill defaults to the composed production path —
+        # packed x sharded x donated gated together (meshpack).
+        args.packing = "packed" if args.mesh else "off"
     return args
 
 
@@ -323,7 +314,7 @@ def run(args) -> dict:
             PodSpec(batch=b), Profile(topology_spread=0, interpod_affinity=0),
             chunk=args.chunk, k=4, with_constraints=False, seed=args.seed,
             score_pct=50, pipeline=True, depth=args.depth, tenancy=make_tn(),
-            mesh=args.mesh or "none", packing=args.packing, tracer=tracer,
+            mesh=args.mesh, packing=args.packing, tracer=tracer,
         )
 
     alpha = beta = coord = None

@@ -45,27 +45,6 @@ def pytest_configure(config):
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-# PR 26: tests/benchmark_cells/test_span_metrics.py pins, among what it
-# checks of a tiny run, `intake_slow_lane_pct.fill == 100.0`: every pod of
-# make_pods through json.loads, which is the behaviour that PR removed
-# (the reading is 0 now).  Only a `benchmark` PR may edit a file under
-# tests/benchmark_cells, so until one corrects that line the test is
-# expected to fail, strictly (a pass fails it, and this entry goes), and
-# tests/test_intake_shapes.py::test_span_report_reads_no_slow_lane_in_a_tiny_run
-# checks the same run with the reading the program now gives.
-_EXPECTED_TO_FAIL = {
-    "test_span_metrics.py::test_span_report_reads_the_counters_of_a_tiny_run":
-        "pins intake_slow_lane_pct.fill at 100, the pre-PR-26 intake lane; "
-        "a benchmark PR corrects the assertion (PERF.md section 7)",
-}
-
-
-def pytest_collection_modifyitems(items):
-    for item in items:
-        for test, why in _EXPECTED_TO_FAIL.items():
-            if item.nodeid.split("[")[0].endswith(test):
-                item.add_marker(pytest.mark.xfail(reason=why, strict=True))
-
 
 @pytest.fixture
 def rng():

@@ -230,16 +230,14 @@ def test_planes_accessor_is_epoch_checked():
         cache.planes(8)
 
 
-def test_resolve_deltasched_forms(monkeypatch):
+def test_resolve_deltasched_forms():
     assert resolve_deltasched(True) == "on"
     assert resolve_deltasched(False) == "off"
-    monkeypatch.delenv("K8S1M_DELTASCHED", raising=False)
+    assert resolve_deltasched("on") == "on"
+    assert resolve_deltasched("off") == "off"
     assert resolve_deltasched(None) == "off"
-    monkeypatch.setenv("K8S1M_DELTASCHED", "on")
-    assert resolve_deltasched(None) == "on"
-    monkeypatch.setenv("K8S1M_DELTASCHED", "yes")
     with pytest.raises(ValueError):
-        resolve_deltasched(None)
+        resolve_deltasched("yes")
 
 
 # ---- 3. shape_key: what is cacheable ----------------------------------

@@ -2,6 +2,7 @@
 
 import jax
 import numpy as np
+import pytest
 
 from k8s1m_tpu.config import PodSpec, TableSpec
 from k8s1m_tpu.engine import schedule_batch
@@ -140,6 +141,72 @@ def test_sampled_window_with_constraints_matches_full():
     np.testing.assert_array_equal(outs[0][0], outs[1][0])
     np.testing.assert_array_equal(outs[0][1], outs[1][1])
     assert (outs[0][0] >= 0).sum() == 24
+
+
+@pytest.mark.parametrize("offset", [32, 64])
+@pytest.mark.parametrize("with_constraints", [False, True])
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_window_is_the_slice_plus_offset(backend, with_constraints, offset):
+    """What a scan window MEANS on one device: the step over rows
+    [O, O+R) binds exactly what the unsampled step binds on that slice of
+    the table (window-local hash columns), plus O — with constraint
+    plugins too, whose per-node counts follow the window while the
+    domain statistics stay global."""
+    from k8s1m_tpu.cluster.workload import spread_deployment
+    from k8s1m_tpu.engine.cycle import schedule_batch_packed
+    from k8s1m_tpu.snapshot.constraints import (
+        ConstraintTracker,
+        empty_constraints,
+        slice_constraints,
+    )
+
+    rows = 64
+    spec = TableSpec(max_nodes=128, max_zones=8, max_regions=4)
+    host = NodeTableHost(spec)
+    for i in range(128):
+        host.upsert(NodeInfo(
+            name=f"n{i}", cpu_milli=4000, mem_kib=1 << 20, pods=4,
+            labels={"topology.kubernetes.io/zone": f"z{i % 4}"},
+        ))
+    enc = PodBatchHost(PodSpec(batch=32), spec, host.vocab)
+    table, cons = host.to_device(), None
+    kw = dict(chunk=32, k=4, backend=backend)
+    if with_constraints:
+        # Live counts, not zeros: a first unsampled wave of the same
+        # deployment commits into the state both sides then read.
+        profile = Profile()
+        tracker = ConstraintTracker(spec)
+        first = spread_deployment(tracker, "d", 24, topo=1, max_skew=2)
+        pods = spread_deployment(tracker, "d", 24, topo=1, max_skew=2, start=24)
+        table, cons, _, bound = schedule_batch_packed(
+            table, enc.encode_packed(first), jax.random.key(8),
+            profile=profile, constraints=empty_constraints(spec), **kw,
+        )
+        assert (np.asarray(bound) >= 0).sum() == 24
+    else:
+        profile = PROFILE
+        pods = [PodInfo(name=f"p{i}", cpu_milli=100 + i, mem_kib=1 << 15)
+                for i in range(24)]
+    key = jax.random.key(9)
+
+    new_table, _, _, got = schedule_batch_packed(
+        table, enc.encode_packed(pods), key, profile=profile,
+        constraints=cons, sample_rows=rows, sample_offset=offset, **kw,
+    )
+    sliced = jax.tree.map(lambda a: a[offset:offset + rows], table)
+    ref_table, _, asg = schedule_batch(
+        sliced, enc.encode(pods), key, profile=profile,
+        constraints=None if cons is None
+        else slice_constraints(cons, offset, rows), **kw,
+    )
+    want = np.where(np.asarray(asg.bound), np.asarray(asg.node_row) + offset, -1)
+    got = np.asarray(got)
+    np.testing.assert_array_equal(got, want)
+    assert (got >= 0).sum() == 24 and got[24:].max() == -1
+    # The commit lands in the full table, on the window's rows only.
+    want_req = np.asarray(table.pods_req).copy()
+    want_req[offset:offset + rows] = np.asarray(ref_table.pods_req)
+    np.testing.assert_array_equal(np.asarray(new_table.pods_req), want_req)
 
 
 def test_topk_by_argmax_matches_lax_top_k():

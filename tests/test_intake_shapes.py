@@ -11,9 +11,6 @@ batches byte for byte, the same binds and the same accounting.
 """
 
 import dataclasses
-import importlib.util
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -496,59 +493,3 @@ def test_undecodable_shape_counts_a_decode_error_and_spares_the_rest():
             assert [p.key_str for p in lane.coord.queue] == ["default/good"]
         finally:
             lane.close()
-
-
-# ---- the benchmark's own reader of the lanes, at a tiny size ----------
-# (tests/benchmark_cells/test_span_metrics.py holds the same run to the
-# reading of before this lane existed; see tests/conftest.py)
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCHMARK_CELLS = ("kwok-1m-pct5.fill", "fit-10k.fill")
-
-
-def _load(name: str, *path: str):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(ROOT, *path))
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules[name] = mod
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.mark.parametrize("cell", BENCHMARK_CELLS)
-def test_span_report_reads_no_slow_lane_in_a_tiny_run(cell):
-    """The cell's own traffic (``benchmark/generate.py``: every pod is
-    ``encode_pod(build_pod(i))``) through the unedited harness at 1,000
-    nodes: lane ``json`` takes none of the window's pods, the whole
-    window rides ``batch_fast`` on one interned shape."""
-    if ROOT not in sys.path:
-        sys.path.insert(0, ROOT)
-    cells = sys.modules.get("test_benchmark_cells") or _load(
-        "test_benchmark_cells", "tests", "benchmark_cells",
-        "test_benchmark_cells.py")
-    tool = _load("span_report", "tools", "span_report.py")
-    # The harness compares a snapshot of the process's stage sums around a
-    # window that resets them: start from nought, whatever ran before.
-    REGISTRY.get("coordinator_cycle_seconds").reset()
-    shapes = REGISTRY.get("coordinator_pod_shapes_total")
-    interned = shapes.value(event="interned")
-    result = tool.report(
-        cells.MANIFEST, cell, cells._tiny(cell), seed=(1 << 31) + 26,
-        seconds=0.5, trace=False, device=dict(cells.CPU_DEVICE), peaks={},
-    )
-    assert result["correct"] is True
-    got = result["span_metrics"]
-    assert got["intake_slow_lane_pct.fill"]["value"] == 0.0
-    assert got["drain_poll_us_per_bind.fill"]["value"] \
-        + got["drain_apply_us_per_bind.fill"]["value"] > 0
-    counters = tool.SpanCell.last.ctx["counters"]
-    grown = {       # snapshot keys are label tuples: (("lane", <lane>),)
-        key[0][1]:
-            n - counters["open"]["coordinator_pod_intake_total"].get(key, 0)
-        for key, n in
-        counters["close"]["coordinator_pod_intake_total"].items()
-    }
-    assert {lane for lane, n in grown.items() if n} == {"batch_fast"}
-    wave = cells._tiny(cell)[0]["wave"]
-    assert abs(grown["batch_fast"] - result["attempted"]) <= 2 * wave
-    assert shapes.value(event="interned") - interned == 1

@@ -13,7 +13,7 @@ step, not merely statistically equivalent.
 
 Also here: the per-dp-shard host feed (snapshot/hotfeed.ShardedHostFeed)
 — merge byte-identity against the inline full-batch encode, and the
-mesh-selection funnel (parse_mesh/auto_mesh_shape/K8S1M_MESH).
+mesh-selection funnel (parse_mesh/auto_mesh_shape/resolve_mesh).
 """
 
 import json
@@ -423,24 +423,23 @@ def test_auto_mesh_shape_respects_divisibility():
     assert auto_mesh_shape(1, batch=64, max_nodes=4096, chunk=512) is None
 
 
-def test_coordinator_mesh_from_env(monkeypatch):
-    monkeypatch.setenv("K8S1M_MESH", "2x4")
+def test_coordinator_mesh_from_string():
     with MemStore() as store:
         c = Coordinator(
             store, SMALL, SMALL_PODS, PROFILE, chunk=16, k=4,
-            with_constraints=False,
+            with_constraints=False, mesh="2x4",
         )
         assert c.mesh is not None
         assert (c.mesh.shape["dp"], c.mesh.shape["sp"]) == (2, 4)
         c.close()
-    monkeypatch.setenv("K8S1M_MESH", "none")
-    with MemStore() as store:
-        c = Coordinator(
-            store, SMALL, SMALL_PODS, PROFILE, chunk=16, k=4,
-            with_constraints=False,
-        )
-        assert c.mesh is None
-        c.close()
+    for single in ("none", None):
+        with MemStore() as store:
+            c = Coordinator(
+                store, SMALL, SMALL_PODS, PROFILE, chunk=16, k=4,
+                with_constraints=False, mesh=single,
+            )
+            assert c.mesh is None
+            c.close()
 
 
 def test_coordinator_mesh_auto_string():

@@ -200,5 +200,4 @@ def test_soak_smoke_secured_tier():
     assert fo is not None and fo["passed"], fo
     assert fo["lost"] == 0
     assert fo["fencing_rejected"] > 0
-    assert fo["recovery_warm_s"] < fo["recovery_cold_s"]
     # rss_flat is NOT asserted: a 12s window is all startup transient.

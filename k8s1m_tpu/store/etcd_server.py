@@ -142,6 +142,11 @@ CallbackMetric(
     lambda: _watch_samples("enqueued"), kind="counter",
 )
 CallbackMetric(
+    "memstore_watch_enqueue_batches_total",
+    "watcher queue acquisitions by writers (one a watcher a frame)",
+    lambda: _watch_samples("enqueue_batches"), kind="counter",
+)
+CallbackMetric(
     "memstore_watch_dropped_total",
     "events dropped at watcher queue caps (consumer must resync)",
     lambda: _watch_samples("dropped"), kind="counter",

@@ -5,9 +5,11 @@
 //  * One global ordered index instead of per-Kind B-trees: cross-prefix
 //    ranges work (the reference errors on them, store.rs:590-675); the
 //    per-Kind prefix_split survives in the WAL file layout and stats.
-//  * Watch events enqueue inside the write critical section, so revision
-//    order is structural; no notify thread / re-ordering heap
-//    (reference store.rs:444-533 needs both).
+//  * Watch events enqueue inside the write critical section, once a frame
+//    (a batch or a single set), so revision order is structural; no notify
+//    thread / re-ordering heap (reference store.rs:444-533 needs both).
+//  * A key and a write are each one reference-counted allocation that the
+//    indexes, the log, the WAL queue, the watch queues and the polls share.
 //  * Tombstones are garbage-collected at compaction (the reference leaves
 //    this as a TODO, store.rs:832).
 //  * Values live at the compact revision are preserved in a per-key base
@@ -28,11 +30,13 @@
 #include <atomic>
 #include <condition_variable>
 #include <cstdio>
+#include <cstdlib>
 #include <cstring>
 #include <deque>
 #include <map>
 #include <memory>
 #include <mutex>
+#include <new>
 #include <queue>
 #include <shared_mutex>
 #include <string>
@@ -43,34 +47,113 @@
 
 namespace {
 
-using Bytes = std::shared_ptr<const std::string>;
-
 inline int64_t now_ns() {
   return std::chrono::duration_cast<std::chrono::nanoseconds>(
              std::chrono::steady_clock::now().time_since_epoch())
       .count();
 }
 
-Bytes make_bytes(const uint8_t* p, size_t n) {
-  return std::make_shared<const std::string>(reinterpret_cast<const char*>(p),
-                                             n);
-}
+// ---- records --------------------------------------------------------------
+// A key and a write are each ONE allocation, made when they enter the store
+// and shared by reference from then on: the two indexes view the key's
+// bytes, and the item, the MVCC log, the WAL queue, every watcher's queue
+// and the polls hold the same record.  The count lives in the allocation
+// (intrusive), so a reference is one pointer and taking one is one atomic
+// add.  A record is immutable once its write is committed.
+
+template <typename T>
+class Ref {
+ public:
+  Ref() = default;
+  static Ref adopt(T* p) { return Ref(p); }  // takes over the caller's count
+  static Ref share(T* p) {                   // one more reference to *p
+    if (p) p->rc.fetch_add(1, std::memory_order_relaxed);
+    return Ref(p);
+  }
+  Ref(const Ref& o) : Ref(share(o.p_)) {}
+  Ref(Ref&& o) noexcept : p_(o.p_) { o.p_ = nullptr; }
+  Ref& operator=(Ref o) noexcept {
+    std::swap(p_, o.p_);
+    return *this;
+  }
+  ~Ref() {
+    if (p_ && p_->rc.fetch_sub(1, std::memory_order_acq_rel) == 1)
+      T::destroy(p_);
+  }
+  T* get() const { return p_; }
+  T* operator->() const { return p_; }
+  T& operator*() const { return *p_; }
+  explicit operator bool() const { return p_ != nullptr; }
+
+ private:
+  explicit Ref(T* p) : p_(p) {}
+  T* p_ = nullptr;
+};
+
+struct Key {
+  std::atomic<uint32_t> rc{1};
+  uint32_t len = 0;
+  char data[];  // len bytes
+
+  std::string_view view() const { return {data, len}; }
+  static Ref<Key> make(std::string_view k) {
+    Key* p = new (malloc(sizeof(Key) + k.size())) Key();
+    p->len = static_cast<uint32_t>(k.size());
+    memcpy(p->data, k.data(), k.size());
+    return Ref<Key>::adopt(p);
+  }
+  static void destroy(Key* p) { free(p); }
+};
+
+// One write of one key: a value, or a tombstone (del: no value, and
+// create_rev / version / lease 0, as etcd reports a delete).
+struct Rec {
+  std::atomic<uint32_t> rc{1};
+  uint32_t vlen = 0;
+  bool del = false;
+  int64_t mod_rev = 0, create_rev = 0, version = 0, lease = 0;
+  Ref<Key> key;
+  char val[];  // vlen bytes; the writer fills them before it commits
+
+  std::string_view value() const { return {val, vlen}; }
+  static Ref<Rec> make(Ref<Key> key, size_t vlen, bool del = false) {
+    Rec* p = new (malloc(sizeof(Rec) + vlen)) Rec();
+    p->vlen = static_cast<uint32_t>(vlen);
+    p->del = del;
+    p->key = std::move(key);
+    return Ref<Rec>::adopt(p);
+  }
+  static void destroy(Rec* p) {
+    p->~Rec();
+    free(p);
+  }
+};
+
+using RecRef = Ref<Rec>;
 
 // ---- prefix_split ---------------------------------------------------------
 // /registry/<kind>/...          -> /registry/<kind>/
 // /registry/<group.with.dot>/<kind>/... -> /registry/<group>/<kind>/
 // (reference store.rs:836-863: Kubernetes never ranges across Kinds).
-std::string prefix_split(const std::string& key) {
+// *closed says that every key that starts with the result splits to it too,
+// so a run of keys under it need not be split again.
+std::string_view prefix_split(std::string_view key, bool* closed) {
+  *closed = false;
   if (key.empty() || key[0] != '/') return key;
   size_t s1 = key.find('/', 1);
-  if (s1 == std::string::npos) return key;
+  if (s1 == std::string_view::npos) return key;
   size_t s2 = key.find('/', s1 + 1);
-  if (s2 == std::string::npos) return key;
+  if (s2 == std::string_view::npos) return key;
   // second path component (between s1 and s2)
-  if (key.find('.', s1 + 1) < s2) {
+  const bool group = key.find('.', s1 + 1) < s2;
+  if (group) {
     size_t s3 = key.find('/', s2 + 1);
-    if (s3 != std::string::npos) return key.substr(0, s3 + 1);
+    if (s3 != std::string_view::npos) {
+      *closed = true;
+      return key.substr(0, s3 + 1);
+    }
   }
+  *closed = !group;
   return key.substr(0, s2 + 1);
 }
 
@@ -84,22 +167,16 @@ void put_i64(std::string& b, int64_t v) {
   b.append(reinterpret_cast<const char*>(&v), 8);
 }
 
-struct KvMeta {
-  int64_t create_rev = 0, mod_rev = 0, version = 0, lease = 0;
-  Bytes val;  // null for tombstone / keys_only
-};
-
-void put_kv(std::string& b, const std::string& key, const KvMeta& m,
-            bool keys_only = false) {
-  const bool hv = m.val && !keys_only;
-  put_u32(b, static_cast<uint32_t>(key.size()));
-  put_u32(b, hv ? static_cast<uint32_t>(m.val->size()) : 0);
-  put_i64(b, m.create_rev);
-  put_i64(b, m.mod_rev);
-  put_i64(b, m.version);
-  put_i64(b, m.lease);
-  b.append(key);
-  if (hv) b.append(*m.val);
+void put_kv(std::string& b, const Rec& r, bool keys_only = false) {
+  const bool hv = !r.del && !keys_only;
+  put_u32(b, r.key->len);
+  put_u32(b, hv ? r.vlen : 0);
+  put_i64(b, r.create_rev);
+  put_i64(b, r.mod_rev);
+  put_i64(b, r.version);
+  put_i64(b, r.lease);
+  b.append(r.key->view());
+  if (hv) b.append(r.value());
 }
 
 uint8_t* to_malloc(const std::string& b, size_t* len_out) {
@@ -111,29 +188,55 @@ uint8_t* to_malloc(const std::string& b, size_t* len_out) {
 
 // ---- core structures ------------------------------------------------------
 
+// The revisions that touched one key, ascending.  A pod's life is two of
+// them (create, bind), which live in the item itself.
+class RevList {
+ public:
+  RevList() = default;
+  RevList(const RevList&) = delete;
+  RevList& operator=(const RevList&) = delete;
+  ~RevList() {
+    if (p_ != inl_) free(p_);
+  }
+  void push_back(int64_t rev) {
+    if (n_ == cap_) {
+      cap_ *= 2;
+      auto* q = static_cast<int64_t*>(malloc(sizeof(int64_t) * cap_));
+      memcpy(q, p_, sizeof(int64_t) * n_);
+      if (p_ != inl_) free(p_);
+      p_ = q;
+    }
+    p_[n_++] = rev;
+  }
+  const int64_t* begin() const { return p_; }
+  const int64_t* end() const { return p_ + n_; }
+
+ private:
+  int64_t inl_[2];
+  int64_t* p_ = inl_;
+  uint32_t n_ = 0, cap_ = 2;
+};
+
 struct TreeItem {
-  std::string key;
-  std::vector<int64_t> revs;  // every revision that touched this key
-  bool present = false;
-  Bytes latest;
-  int64_t create_rev = 0, mod_rev = 0, version = 0, lease = 0;
+  Ref<Key> key;
+  RevList revs;   // every revision that touched this key
+  RecRef latest;  // the last write, a tombstone included
   // Value live at the compact revision when history below it was dropped.
-  int64_t base_rev = 0;
-  KvMeta base;
+  RecRef base;
+
+  bool present() const { return latest && !latest->del; }
 };
 
 struct RevEntry {  // one revision in the global MVCC log
   TreeItem* item = nullptr;
-  Bytes val;  // null => delete
-  int64_t create_rev = 0, version = 0, lease = 0;
+  RecRef rec;
 };
 
+// What a watcher's queue holds: the write, and for a watcher that asked
+// for prev_kv the write it replaced.
 struct Event {
-  uint8_t type = 0;  // 0 PUT, 1 DELETE
-  std::string key;
-  KvMeta kv;
-  bool has_prev = false;
-  KvMeta prev;
+  RecRef rec;
+  RecRef prev;
 };
 
 constexpr size_t kDefaultWatcherQueueCap = 10000;  // reference store.rs:27
@@ -141,8 +244,9 @@ constexpr size_t kDefaultWatcherQueueCap = 10000;  // reference store.rs:27
 struct Watcher {
   int64_t id = 0;
   size_t queue_cap = kDefaultWatcherQueueCap;
-  std::string start, end;  // end conventions: "" single key, "\0" infinity
+  std::string start, end;  // [start, end), or start alone, or from start on
   bool single = false;
+  bool to_infinity = false;
   bool want_prev = false;
   int64_t min_rev = 0;  // suppress live events below this revision
   std::mutex m;
@@ -151,11 +255,31 @@ struct Watcher {
   int64_t dropped = 0;
   bool canceled = false;
 
-  bool matches(const std::string& key) const {
+  bool matches(std::string_view key) const {
     if (single) return key == start;
     if (key < start) return false;
-    if (end == std::string(1, '\0')) return true;
-    return key < end;
+    return to_infinity || key < end;
+  }
+
+  // Up to max_events from the head of the queue, after waiting up to
+  // timeout_ms for the first.  A poll that takes all there is takes the
+  // queue itself.
+  std::deque<Event> take(int max_events, int timeout_ms, bool* canceled_out) {
+    std::deque<Event> out;
+    std::unique_lock<std::mutex> g(m);
+    if (q.empty() && timeout_ms > 0 && !canceled)
+      cv.wait_for(g, std::chrono::milliseconds(timeout_ms),
+                  [&] { return !q.empty() || canceled; });
+    *canceled_out = canceled;
+    if (max_events > 0 && q.size() <= static_cast<size_t>(max_events)) {
+      out.swap(q);
+    } else {
+      for (int i = 0; i < max_events; i++) {
+        out.push_back(std::move(q.front()));
+        q.pop_front();
+      }
+    }
+    return out;
   }
 };
 
@@ -166,14 +290,12 @@ struct Watcher {
 
 struct WalRec {
   int fd = -1;
-  int64_t rev = 0;
-  std::string key;
-  Bytes val;  // null => delete
+  RecRef rec;
 };
 
 constexpr uint32_t kDeleteMarker = 0xFFFFFFFFu;
 
-std::string hex_encode(const std::string& s) {
+std::string hex_encode(std::string_view s) {
   static const char* d = "0123456789abcdef";
   std::string o;
   o.reserve(s.size() * 2);
@@ -211,7 +333,7 @@ class Wal {
     return fd;
   }
 
-  void Append(int fd, int64_t rev, std::string key, Bytes val) {
+  void Append(int fd, RecRef rec) {
     {
       // Contention-metered (reference metrics.rs:78-94): the queue mutex
       // is shared with the writer thread's drain, the one lock a write
@@ -223,8 +345,8 @@ class Wal {
         append_wait_ns.fetch_add(now_ns() - t0, std::memory_order_relaxed);
       }
       append_count.fetch_add(1, std::memory_order_relaxed);
-      q_.push_back(WalRec{fd, rev, std::move(key), std::move(val)});
-      last_enqueued_ = rev;
+      last_enqueued_ = rec->mod_rev;
+      q_.push_back(WalRec{fd, std::move(rec)});
     }
     qcv_.notify_one();
   }
@@ -275,8 +397,7 @@ class Wal {
         // smaller (reference wal.rs:173-248 batches 16 KiB / 500 us).
         size_t bytes = 0;
         while (!q_.empty() && bytes < (16u << 10)) {
-          bytes += q_.front().key.size() +
-                   (q_.front().val ? q_.front().val->size() : 0) + 16;
+          bytes += q_.front().rec->key->len + q_.front().rec->vlen + 16;
           batch.push_back(std::move(q_.front()));
           q_.pop_front();
         }
@@ -291,15 +412,16 @@ class Wal {
     // Group contiguous records per fd into one buffered write.
     std::unordered_map<int, std::string> bufs;
     int64_t max_rev = 0;
-    for (auto& r : batch) {
-      std::string& b = bufs[r.fd];
-      uint64_t rev = static_cast<uint64_t>(r.rev);
+    for (auto& w : batch) {
+      const Rec& r = *w.rec;
+      std::string& b = bufs[w.fd];
+      uint64_t rev = static_cast<uint64_t>(r.mod_rev);
       b.append(reinterpret_cast<const char*>(&rev), 8);
-      put_u32(b, static_cast<uint32_t>(r.key.size()));
-      put_u32(b, r.val ? static_cast<uint32_t>(r.val->size()) : kDeleteMarker);
-      b.append(r.key);
-      if (r.val) b.append(*r.val);
-      max_rev = std::max(max_rev, r.rev);
+      put_u32(b, r.key->len);
+      put_u32(b, r.del ? kDeleteMarker : r.vlen);
+      b.append(r.key->view());
+      if (!r.del) b.append(r.value());
+      max_rev = std::max(max_rev, r.mod_rev);
     }
     bool err = false;
     for (auto& [fd, buf] : bufs) {
@@ -346,6 +468,16 @@ struct PrefixStats {
   int64_t bytes = 0;
 };
 
+// What the keys of one prefix share, resolved when a key leaves the
+// previous key's prefix and not once a key.
+struct PrefixRun {
+  static constexpr int kUnresolved = -2;
+  std::string prefix;
+  bool closed = false;  // see prefix_split
+  PrefixStats* stats = nullptr;
+  int fd = kUnresolved;  // the prefix's WAL file, from its first durable write
+};
+
 }  // namespace
 
 // ---- the store ------------------------------------------------------------
@@ -353,8 +485,9 @@ struct PrefixStats {
 struct ms_store {
   mutable std::shared_mutex mu;
 
-  std::map<std::string, TreeItem*> sorted;          // full-key ordered index
-  std::unordered_map<std::string, TreeItem*> by_key;  // O(1) point lookup
+  // Both indexes view the bytes of their item's Key.
+  std::map<std::string_view, TreeItem*> sorted;  // live keys, ordered
+  std::unordered_map<std::string_view, TreeItem*> by_key;  // O(1) point lookup
 
   // Global revision log: entry for revision r lives at log[r - log_base].
   std::deque<RevEntry> log;
@@ -364,6 +497,7 @@ struct ms_store {
 
   std::map<int64_t, std::shared_ptr<Watcher>> watchers;
   int64_t next_watcher = 0;
+  int prev_watchers = 0;  // of them, how many asked for prev_kv
 
   std::map<std::string, PrefixStats> prefix_stats;
   std::atomic<int64_t> live_keys{0};
@@ -372,6 +506,15 @@ struct ms_store {
   std::unique_ptr<Wal> wal;
   std::vector<std::string> no_write_prefixes;
   bool replaying = false;
+
+  // ---- the frame: one write critical section (a batch, or one set).
+  // Its writes' events gather here in revision order and go to the
+  // watchers once, at its end (Frame, fan_out).
+  std::vector<Event> frame;
+  // Where the frame's previous insert into `sorted` left off: keys of a
+  // frame mostly arrive in order, and the next one goes right here.
+  std::map<std::string_view, TreeItem*>::iterator sorted_hint = sorted.end();
+  PrefixRun run;
 
   // ---- contention metrics (reference metrics.rs:78-94, store.rs:478-495).
   // Store-mutex acquisitions by (method, read|write), with wait time
@@ -388,81 +531,101 @@ struct ms_store {
   // Watcher-queue pressure.  The reference *blocks* a slow notify and
   // times it (store.rs:478-495); this design drops-at-cap instead (the
   // consumer resyncs), so the analog is enqueue/drop counts and the
-  // high-water queue depth.
+  // high-water queue depth.  enqueue_batches counts the times a writer
+  // took a watcher's queue: enqueued / enqueue_batches is the events
+  // handed over per acquisition.
   std::atomic<int64_t> watch_enqueued{0};
+  std::atomic<int64_t> watch_enqueue_batches{0};
   std::atomic<int64_t> watch_dropped_total{0};
   std::atomic<int64_t> watch_queue_hwm{0};
 
   ~ms_store() {
     wal.reset();  // drain writer before freeing items
+    // by_key's views dangle from here on; nothing reads them again.
     for (auto& [k, item] : by_key) delete item;
   }
 
-  bool wal_skip(const std::string& key) const {
+  bool wal_skip(std::string_view key) const {
     for (const auto& p : no_write_prefixes)
       if (key.compare(0, p.size(), p) == 0) return true;
     return false;
   }
 
-  // Value of `item` as of revision rev (largest touch <= rev).
-  // Returns MS_OK with meta (meta.val null => deleted at that revision,
-  // i.e. key absent), or MS_ERR_COMPACTED when the history is gone.
-  int value_at(const TreeItem* item, int64_t rev, KvMeta* out) const {
-    auto it = std::upper_bound(item->revs.begin(), item->revs.end(), rev);
-    if (it == item->revs.begin()) {
-      out->val = nullptr;  // key did not exist yet at rev
-      return MS_OK;
-    }
-    int64_t r = *(it - 1);
-    if (r == item->mod_rev) {
-      out->create_rev = item->create_rev;
-      out->mod_rev = item->mod_rev;
-      out->version = item->version;
-      out->lease = item->lease;
-      out->val = item->present ? item->latest : nullptr;
-      return MS_OK;
-    }
-    if (r >= log_base) {
-      const RevEntry& e = log[static_cast<size_t>(r - log_base)];
-      out->create_rev = e.create_rev;
-      out->mod_rev = r;
-      out->version = e.version;
-      out->lease = e.lease;
-      out->val = e.val;
-      return MS_OK;
-    }
-    if (r == item->base_rev) {
-      *out = item->base;
-      out->mod_rev = r;
-      return MS_OK;
-    }
-    return MS_ERR_COMPACTED;
+  TreeItem* find(std::string_view key) const {
+    auto it = by_key.find(key);
+    return it == by_key.end() ? nullptr : it->second;
   }
 
-  // Watcher id excluded from dispatch for the current write (set only
-  // inside the exclusive ms_bind_batch critical section; -1 = none).
-  // See ms_bind_batch's exclude_watcher contract in memstore.h.
-  int64_t dispatch_exclude = -1;
+  PrefixRun& run_of(std::string_view key) {
+    if (!run.closed || key.compare(0, run.prefix.size(), run.prefix) != 0) {
+      run.prefix = prefix_split(key, &run.closed);
+      run.stats = &prefix_stats[run.prefix];
+      run.fd = PrefixRun::kUnresolved;
+    }
+    return run;
+  }
 
-  void dispatch(const std::string& key, const Event& ev) {
+  // The write of `item` that revision rev reads (largest touch <= rev):
+  // MS_OK with *out null where the key did not exist, a tombstone where
+  // it was deleted; MS_ERR_COMPACTED for a rev below the compact revision
+  // whose history is gone.
+  int value_at(const TreeItem* item, int64_t rev, const Rec** out) const {
+    auto it = std::upper_bound(item->revs.begin(), item->revs.end(), rev);
+    *out = nullptr;
+    if (it == item->revs.begin()) return MS_OK;
+    int64_t r = *(it - 1);
+    if (r == item->latest->mod_rev) {
+      *out = item->latest.get();
+    } else if (r >= log_base) {
+      *out = log[static_cast<size_t>(r - log_base)].rec.get();
+    } else if (item->base && r == item->base->mod_rev) {
+      *out = item->base.get();
+    } else if (rev < compacted) {
+      return MS_ERR_COMPACTED;
+    }
+    // else: the touch live at the compact revision left no base, so it
+    // was a tombstone (compaction keeps a live value): the key was absent.
+    return MS_OK;
+  }
+
+  // Hand the frame's events to every watcher they match, each watcher's
+  // run under one acquisition of its queue: called at the end of the
+  // write critical section, inside it, so a watcher's queue is in
+  // revision order by construction and holds a write before the call
+  // that made it returns.  `exclude` is ms_bind_batch's exclude_watcher.
+  // The cap falls event by event: a queue takes the first events of its
+  // run that it has room for and counts the rest as dropped.
+  void fan_out(int64_t exclude) {
+    if (frame.empty()) return;
     for (auto& [id, w] : watchers) {
-      if (id == dispatch_exclude) continue;
-      if (!w->matches(key)) continue;
-      if (ev.kv.mod_rev < w->min_rev) continue;
-      std::lock_guard<std::mutex> g(w->m);
-      if (w->canceled) continue;
-      if (w->q.size() >= w->queue_cap) {
-        w->dropped++;
-        watch_dropped_total.fetch_add(1, std::memory_order_relaxed);
-        continue;
+      if (id == exclude) continue;
+      std::unique_lock<std::mutex> g(w->m, std::defer_lock);
+      size_t room = 0;
+      int64_t taken = 0, lost = 0;
+      for (const Event& ev : frame) {
+        if (ev.rec->mod_rev < w->min_rev || !w->matches(ev.rec->key->view()))
+          continue;
+        if (!g.owns_lock()) {
+          g.lock();
+          if (w->canceled) break;
+          room = w->queue_cap > w->q.size() ? w->queue_cap - w->q.size() : 0;
+        }
+        if (room == 0) {
+          lost++;
+          continue;
+        }
+        room--;
+        taken++;
+        w->q.push_back(w->want_prev ? ev : Event{ev.rec, RecRef()});
       }
-      Event e = ev;
-      if (!w->want_prev) {
-        e.has_prev = false;
-        e.prev = KvMeta{};
+      if (!g.owns_lock() || w->canceled) continue;
+      watch_enqueue_batches.fetch_add(1, std::memory_order_relaxed);
+      if (lost) {
+        w->dropped += lost;
+        watch_dropped_total.fetch_add(lost, std::memory_order_relaxed);
       }
-      w->q.push_back(std::move(e));
-      watch_enqueued.fetch_add(1, std::memory_order_relaxed);
+      if (taken == 0) continue;
+      watch_enqueued.fetch_add(taken, std::memory_order_relaxed);
       const int64_t depth = static_cast<int64_t>(w->q.size());
       int64_t hwm = watch_queue_hwm.load(std::memory_order_relaxed);
       while (depth > hwm &&
@@ -471,6 +634,7 @@ struct ms_store {
       }
       w->cv.notify_one();
     }
+    frame.clear();
   }
 };
 
@@ -503,11 +667,24 @@ struct RGuard {
   }
 };
 
+// One frame: the store's write lock and, at the end and still inside it,
+// the fan-out of what the frame wrote.
+struct Frame {
+  ms_store* s;
+  int64_t exclude;
+  WGuard g;
+  Frame(ms_store* s, int m, int64_t exclude_watcher = -1)
+      : s(s), exclude(exclude_watcher), g(s, m) {
+    s->sorted_hint = s->sorted.end();
+  }
+  ~Frame() { s->fan_out(exclude); }
+};
+
 }  // namespace
 
 // ---- open / replay --------------------------------------------------------
 
-static int64_t store_set_locked(ms_store* s, const std::string& key,
+static int64_t store_set_locked(ms_store* s, std::string_view key,
                                 const uint8_t* val, size_t vlen, bool is_del,
                                 int has_req, int req_is_version,
                                 int64_t req_val, int64_t lease,
@@ -537,12 +714,12 @@ ms_store* ms_open(const char* wal_dir, int wal_mode,
   if (!dir.empty()) {
     mkdir(dir.c_str(), 0755);
     // Replay existing files before attaching the writer.
-    struct Rec {
+    struct Replayed {
       int64_t rev;
       std::string key, val;
       bool is_del;
     };
-    std::vector<std::vector<Rec>> files;
+    std::vector<std::vector<Replayed>> files;
     {
       // enumerate prefix_*.wal
       DIR* d = opendir(dir.c_str());
@@ -555,14 +732,14 @@ ms_store* ms_open(const char* wal_dir, int wal_mode,
             continue;
           FILE* f = fopen((dir + "/" + name).c_str(), "rb");
           if (!f) continue;
-          std::vector<Rec> recs;
+          std::vector<Replayed> recs;
           for (;;) {
             uint64_t r;
             uint32_t kl, vl;
             if (fread(&r, 8, 1, f) != 1) break;
             if (fread(&kl, 4, 1, f) != 1) break;
             if (fread(&vl, 4, 1, f) != 1) break;
-            Rec rec;
+            Replayed rec;
             rec.rev = static_cast<int64_t>(r);
             rec.key.resize(kl);
             if (kl && fread(rec.key.data(), 1, kl, f) != kl) break;
@@ -608,27 +785,83 @@ void ms_free(void* p) { free(p); }
 
 // ---- set ------------------------------------------------------------------
 
-static int64_t store_set_locked(ms_store* s, const std::string& key,
+// Commit `rec` as the next write of `item`, under the write lock: the
+// revision, the stats, the ordered index, the MVCC log, the WAL queue and
+// the frame's events.  The caller has decided that the write happens (CAS
+// checked, a delete only of a present key) and has filled the value.
+static int64_t commit_locked(ms_store* s, TreeItem* item, RecRef rec,
+                             bool* fsync_wait_out) {
+  const std::string_view key = item->key->view();
+  const Rec* old = item->latest.get();
+  const bool present = old && !old->del;
+  const int64_t old_bytes =
+      present ? static_cast<int64_t>(key.size() + old->vlen) : 0;
+  const int64_t rev = ++s->current;
+  PrefixRun& run = s->run_of(key);
+
+  rec->mod_rev = rev;
+  int64_t new_bytes = 0;
+  if (rec->del) {
+    run.stats->keys--;
+    s->live_keys.fetch_sub(1, std::memory_order_relaxed);
+    // latest index holds live keys only
+    auto it = s->sorted.find(key);
+    if (it == s->sorted_hint) ++s->sorted_hint;
+    s->sorted.erase(it);
+  } else {
+    if (!present) {
+      rec->create_rev = rev;
+      rec->version = 1;
+      run.stats->keys++;
+      s->live_keys.fetch_add(1, std::memory_order_relaxed);
+      // a new key, or a tombstone resurrected into the index
+      s->sorted_hint =
+          std::next(s->sorted.emplace_hint(s->sorted_hint, key, item));
+    } else {
+      rec->create_rev = old->create_rev;
+      rec->version = old->version + 1;
+    }
+    new_bytes = static_cast<int64_t>(key.size() + rec->vlen);
+  }
+  run.stats->bytes += new_bytes - old_bytes;
+  s->db_bytes.fetch_add(new_bytes - old_bytes, std::memory_order_relaxed);
+
+  RecRef prev;
+  if (present && s->prev_watchers) prev = std::move(item->latest);
+  item->latest = rec;
+  item->revs.push_back(rev);
+  s->log.push_back(RevEntry{item, rec});
+
+  // WAL append (inside the lock: queue order == revision order).
+  if (s->wal && !s->replaying && !s->wal_skip(key)) {
+    if (run.fd == PrefixRun::kUnresolved) run.fd = s->wal->FdFor(run.prefix);
+    s->wal->Append(run.fd, rec);
+    if (fsync_wait_out) *fsync_wait_out = s->wal->fsync_mode();
+  }
+
+  if (!s->watchers.empty())
+    s->frame.push_back(Event{std::move(rec), std::move(prev)});
+  return rev;
+}
+
+static int64_t store_set_locked(ms_store* s, std::string_view key,
                                 const uint8_t* val, size_t vlen, bool is_del,
                                 int has_req, int req_is_version,
                                 int64_t req_val, int64_t lease,
                                 int64_t* latest_rev_out, uint8_t** cur_out,
                                 size_t* cur_len_out, bool* fsync_wait_out) {
-  TreeItem* item = nullptr;
-  auto it = s->by_key.find(key);
-  if (it != s->by_key.end()) item = it->second;
-  const bool present = item && item->present;
+  TreeItem* item = s->find(key);
+  const bool present = item && item->present();
 
   if (has_req) {
-    int64_t have = req_is_version ? (present ? item->version : 0)
-                                  : (present ? item->mod_rev : 0);
+    int64_t have = !present ? 0
+                   : req_is_version ? item->latest->version
+                                    : item->latest->mod_rev;
     if (have != req_val) {
       if (latest_rev_out) *latest_rev_out = s->current;
       if (cur_out && present) {
         std::string b;
-        KvMeta m{item->create_rev, item->mod_rev, item->version, item->lease,
-                 item->latest};
-        put_kv(b, key, m);
+        put_kv(b, *item->latest);
         *cur_out = to_malloc(b, cur_len_out);
       }
       return MS_ERR_CAS;
@@ -639,94 +872,15 @@ static int64_t store_set_locked(ms_store* s, const std::string& key,
 
   if (!item) {
     item = new TreeItem();
-    item->key = key;
-    s->by_key.emplace(key, item);
-    s->sorted.emplace(key, item);
-  } else if (!present && !is_del) {
-    s->sorted.emplace(key, item);  // resurrect tombstone into the index
+    item->key = Key::make(key);
+    s->by_key.emplace(item->key->view(), item);
   }
-
-  // Capture prev for watchers before mutating.
-  KvMeta prev;
-  bool had_prev = present;
-  if (present)
-    prev = KvMeta{item->create_rev, item->mod_rev, item->version, item->lease,
-                  item->latest};
-
-  const int64_t rev = ++s->current;
-  RevEntry e;
-  e.item = item;
-
-  const std::string& prefix = prefix_split(key);
-  auto& ps = s->prefix_stats[prefix];
-
-  if (is_del) {
-    ps.keys--;
-    ps.bytes -= static_cast<int64_t>(key.size() +
-                                     (item->latest ? item->latest->size() : 0));
-    s->live_keys.fetch_sub(1, std::memory_order_relaxed);
-    s->db_bytes.fetch_sub(
-        static_cast<int64_t>(key.size() +
-                             (item->latest ? item->latest->size() : 0)),
-        std::memory_order_relaxed);
-    item->present = false;
-    item->latest = nullptr;
-    item->mod_rev = rev;
-    item->version = 0;
-    item->create_rev = 0;
-    item->lease = 0;
-    s->sorted.erase(key);  // latest index holds live keys only
-  } else {
-    Bytes v = make_bytes(val, vlen);
-    int64_t old_bytes =
-        present ? static_cast<int64_t>(key.size() + item->latest->size()) : 0;
-    if (!present) {
-      item->create_rev = rev;
-      item->version = 1;
-      ps.keys++;
-      s->live_keys.fetch_add(1, std::memory_order_relaxed);
-    } else {
-      item->version++;
-    }
-    item->present = true;
-    item->latest = v;
-    item->mod_rev = rev;
-    item->lease = lease;
-    int64_t new_bytes = static_cast<int64_t>(key.size() + vlen);
-    ps.bytes += new_bytes - old_bytes;
-    s->db_bytes.fetch_add(new_bytes - old_bytes, std::memory_order_relaxed);
-    e.val = v;
-    e.create_rev = item->create_rev;
-    e.version = item->version;
-    e.lease = lease;
+  RecRef rec = Rec::make(item->key, is_del ? 0 : vlen, is_del);
+  if (!is_del) {
+    memcpy(rec->val, val, vlen);
+    rec->lease = lease;
   }
-  item->revs.push_back(rev);
-  s->log.push_back(std::move(e));
-
-  // WAL append (inside the lock: queue order == revision order).
-  if (s->wal && !s->replaying && !s->wal_skip(key)) {
-    int fd = s->wal->FdFor(prefix);
-    s->wal->Append(fd, rev, key, s->log.back().val);
-    if (fsync_wait_out) *fsync_wait_out = s->wal->fsync_mode();
-  }
-
-  // Watch dispatch (inside the lock: revision-ordered by construction).
-  if (!s->watchers.empty()) {
-    Event ev;
-    ev.type = is_del ? 1 : 0;
-    ev.key = key;
-    if (is_del) {
-      ev.kv = KvMeta{0, rev, 0, 0, nullptr};
-    } else {
-      ev.kv = KvMeta{item->create_rev, rev, item->version, item->lease,
-                     item->latest};
-    }
-    ev.has_prev = had_prev;
-    ev.prev = prev;
-    s->dispatch(key, ev);
-  }
-
-  return rev;
+  return commit_locked(s, item, std::move(rec), fsync_wait_out);
 }
 
 static int64_t ms_set_impl(ms_store* s, const uint8_t* key, size_t klen,
@@ -734,11 +888,11 @@ static int64_t ms_set_impl(ms_store* s, const uint8_t* key, size_t klen,
                            int req_is_version, int64_t req_val, int64_t lease,
                            int64_t* latest_rev_out, uint8_t** cur_out,
                            size_t* cur_len_out, bool wait_durable) {
-  std::string k(reinterpret_cast<const char*>(key), klen);
+  std::string_view k(reinterpret_cast<const char*>(key), klen);
   int64_t rev;
   bool fsync_wait = false;
   {
-    WGuard g(s, ms_store::M_SET);
+    Frame f(s, ms_store::M_SET);  // a frame of one
     rev = store_set_locked(s, k, val, vlen, val == nullptr, has_req,
                            req_is_version, req_val, lease, latest_rev_out,
                            cur_out, cur_len_out, &fsync_wait);
@@ -806,7 +960,7 @@ int64_t ms_put_batch(ms_store* s, const uint8_t* buf, size_t len, int n,
   int64_t last = 0;
   bool fsync_wait = false;
   {
-    WGuard g(s, ms_store::M_PUT_BATCH);
+    Frame f(s, ms_store::M_PUT_BATCH);
     size_t off = 0;
     for (int i = 0; i < n; i++) {
       uint32_t klen, vlen;
@@ -815,7 +969,7 @@ int64_t ms_put_batch(ms_store* s, const uint8_t* buf, size_t len, int n,
       off += 8;
       const bool is_del = vlen == kDeleteMarker;
       const size_t vbytes = is_del ? 0 : vlen;
-      std::string key(reinterpret_cast<const char*>(buf + off), klen);
+      std::string_view key(reinterpret_cast<const char*>(buf + off), klen);
       off += klen;
       bool fw = false;
       int64_t rev =
@@ -839,8 +993,20 @@ namespace {
 // opens spec with schedulerName, and this pattern cannot occur inside a
 // JSON string literal (the quotes would be escaped).
 constexpr char kSpecMark[] = "\"spec\":{\"schedulerName\":";
-constexpr size_t kSpecMarkLen = sizeof(kSpecMark) - 1;
 constexpr size_t kSpecCut = 8;  // len("\"spec\":{")
+constexpr char kNodeNameKey[] = "\"nodeName\"";
+constexpr char kNodeNameOpen[] = "\"nodeName\":\"";
+constexpr char kNodeNameClose[] = "\",";
+
+// memmem, not string::find: find looks for the pattern's first byte and
+// compares at every hit, and a pattern that opens with a quote hits at
+// every quote of a JSON value.
+inline const char* find_lit(std::string_view v, const char* lit,
+                            size_t lit_len) {
+  return static_cast<const char*>(memmem(v.data(), v.size(), lit, lit_len));
+}
+// A literal and its length, for the splice here and the pod parser below.
+#define LIT(name) name, sizeof(name) - 1
 
 bool json_plain(const uint8_t* p, size_t n) {
   for (size_t i = 0; i < n; i++)
@@ -872,10 +1038,8 @@ int ms_bind_batch(ms_store* s, const uint8_t* buf, size_t len, int n,
   int64_t last = 0;
   bool fsync_wait = false;
   {
-    WGuard g(s, ms_store::M_BIND_BATCH);
-    s->dispatch_exclude = exclude_watcher;
+    Frame f(s, ms_store::M_BIND_BATCH, exclude_watcher);
     size_t off = 0;
-    std::string spliced;
     for (int i = 0; i < n; i++) {
       int64_t req_mod;
       uint32_t klen, nlen;
@@ -883,47 +1047,51 @@ int ms_bind_batch(ms_store* s, const uint8_t* buf, size_t len, int n,
       memcpy(&klen, buf + off + 8, 4);
       memcpy(&nlen, buf + off + 12, 4);
       off += 16;
-      std::string key(reinterpret_cast<const char*>(buf + off), klen);
+      std::string_view key(reinterpret_cast<const char*>(buf + off), klen);
       off += klen;
       const uint8_t* name = buf + off;
       off += nlen;
 
-      auto it = s->by_key.find(key);
-      if (it == s->by_key.end() || !it->second->present ||
-          it->second->mod_rev != req_mod) {
+      // The one look-up of the record: the item found here is the item
+      // the bind is committed to.
+      TreeItem* item = s->find(key);
+      if (!item || !item->present() || item->latest->mod_rev != req_mod) {
         results[i] = MS_ERR_CAS;
         continue;
       }
-      const std::string& val = *it->second->latest;
-      size_t idx = val.find(kSpecMark);
-      if (idx == std::string::npos ||
-          val.find("\"nodeName\"") != std::string::npos ||
+      const Rec& cur = *item->latest;
+      const std::string_view val = cur.value();
+      const char* mark = find_lit(val, LIT(kSpecMark));
+      if (mark == nullptr || find_lit(val, LIT(kNodeNameKey)) != nullptr ||
           !json_plain(name, nlen)) {
         results[i] = MS_ERR_INVALID;
         continue;
       }
-      const size_t cut = idx + kSpecCut;
-      spliced.clear();
-      spliced.reserve(val.size() + nlen + 14);
-      spliced.append(val, 0, cut);
-      spliced.append("\"nodeName\":\"");
-      spliced.append(reinterpret_cast<const char*>(name), nlen);
-      spliced.append("\",");
-      spliced.append(val, cut, std::string::npos);
+      // The spliced value is written once, into the record that becomes
+      // the key's latest.
+      const size_t cut = static_cast<size_t>(mark - val.data()) + kSpecCut;
+      RecRef rec = Rec::make(item->key, val.size() + nlen +
+                                            sizeof(kNodeNameOpen) - 1 +
+                                            sizeof(kNodeNameClose) - 1);
+      rec->lease = cur.lease;
+      char* p = rec->val;
+      auto put = [&p](const void* src, size_t n) {
+        memcpy(p, src, n);
+        p += n;
+      };
+      put(val.data(), cut);
+      put(LIT(kNodeNameOpen));
+      put(name, nlen);
+      put(LIT(kNodeNameClose));
+      put(val.data() + cut, val.size() - cut);
 
       bool fw = false;
-      int64_t rev = store_set_locked(
-          s, key, reinterpret_cast<const uint8_t*>(spliced.data()),
-          spliced.size(), false, 1, 0, req_mod, it->second->lease, nullptr,
-          nullptr, nullptr, &fw);
+      const int64_t rev = commit_locked(s, item, std::move(rec), &fw);
       results[i] = rev;
-      if (rev > 0) {
-        bound++;
-        last = rev;
-      }
+      bound++;
+      last = rev;
       fsync_wait |= fw;
     }
-    s->dispatch_exclude = -1;
   }
   if (fsync_wait && last > 0) s->wal->WaitPersisted(last);
   *out = results;
@@ -948,11 +1116,12 @@ RangeKind range_kind(const uint8_t* end, size_t end_len) {
 int ms_range(ms_store* s, const uint8_t* start, size_t start_len,
              const uint8_t* end, size_t end_len, int64_t rev, int64_t limit,
              int count_only, int keys_only, uint8_t** out, size_t* out_len) {
-  std::string k(reinterpret_cast<const char*>(start), start_len);
+  const std::string_view k(reinterpret_cast<const char*>(start), start_len);
   RangeKind kind = range_kind(end, end_len);
-  std::string e = kind == RangeKind::kBounded
-                      ? std::string(reinterpret_cast<const char*>(end), end_len)
-                      : std::string();
+  const std::string_view e =
+      kind == RangeKind::kBounded
+          ? std::string_view(reinterpret_cast<const char*>(end), end_len)
+          : std::string_view();
 
   RGuard g(s, ms_store::M_RANGE);
   if (rev > 0) {
@@ -965,68 +1134,57 @@ int ms_range(ms_store* s, const uint8_t* start, size_t start_len,
   int64_t total = 0;
   uint32_t n = 0;
 
-  auto emit = [&](const std::string& key, const KvMeta& m) {
+  auto emit = [&](const Rec& r) {
     total++;
     if (count_only) return;
     if (limit > 0 && n >= limit) return;
-    put_kv(body, key, m, keys_only != 0);
+    put_kv(body, r, keys_only != 0);
     n++;
+  };
+  // The write of `item` the range reads, if it holds a value there.
+  auto emit_at = [&](const TreeItem* item) {
+    const Rec* r = item->latest.get();
+    if (historical) {
+      int rc = s->value_at(item, rev, &r);
+      if (rc != MS_OK) return rc;
+    }
+    if (r && !r->del) emit(*r);
+    return static_cast<int>(MS_OK);
   };
 
   if (kind == RangeKind::kSingle) {
-    auto it = s->by_key.find(k);
-    if (it != s->by_key.end()) {
-      TreeItem* item = it->second;
-      if (historical) {
-        KvMeta m;
-        int rc = s->value_at(item, rev, &m);
-        if (rc != MS_OK) return rc;
-        if (m.val) emit(k, m);
-      } else if (item->present) {
-        emit(k, KvMeta{item->create_rev, item->mod_rev, item->version,
-                       item->lease, item->latest});
-      }
+    // by_key holds tombstoned keys too, which a historical read may need.
+    if (const TreeItem* item = s->find(k)) {
+      int rc = emit_at(item);
+      if (rc != MS_OK) return rc;
     }
-    if (historical) {
-      // A key deleted later than `rev` is absent from `sorted`; by_key
-      // covers it above.  Nothing more to do for single-key reads.
+  } else if (historical) {
+    // Historical ranges must see keys that are tombstoned *now* but were
+    // live at `rev`; those are absent from `sorted`.  Iterate an ordered
+    // snapshot of all item keys in range: item count == live + tombstoned
+    // keys, and tombstones are GC'd at compaction, keeping this bounded.
+    std::vector<const TreeItem*> in_range;
+    for (auto& [key, item] : s->by_key) {
+      if (key < k) continue;
+      if (kind == RangeKind::kBounded && key >= e) continue;
+      in_range.push_back(item);
+    }
+    std::sort(in_range.begin(), in_range.end(), [](auto* a, auto* b) {
+      return a->key->view() < b->key->view();
+    });
+    for (const TreeItem* item : in_range) {
+      int rc = emit_at(item);
+      if (rc != MS_OK) return rc;
     }
   } else {
-    if (historical) {
-      // Historical ranges must see keys that are tombstoned *now* but were
-      // live at `rev`; those are absent from `sorted`.  Walk `by_key`-backed
-      // items via an ordered scan over all items: maintain a merged view by
-      // iterating `sorted` for live keys and checking tombstones from the
-      // revision log is costly; instead iterate an ordered snapshot of all
-      // item keys in range.  Item count == live + tombstoned keys.
-      // (Tombstones are GC'd at compaction, keeping this bounded.)
-      std::vector<std::pair<const std::string*, TreeItem*>> in_range;
-      for (auto& [key, item] : s->by_key) {
-        if (key < k) continue;
-        if (kind == RangeKind::kBounded && key >= e) continue;
-        in_range.emplace_back(&key, item);
-      }
-      std::sort(in_range.begin(), in_range.end(),
-                [](auto& a, auto& b) { return *a.first < *b.first; });
-      for (auto& [key, item] : in_range) {
-        KvMeta m;
-        int rc = s->value_at(item, rev, &m);
-        if (rc != MS_OK) return rc;
-        if (m.val) emit(*key, m);
-      }
-    } else {
-      auto it = s->sorted.lower_bound(k);
-      for (; it != s->sorted.end(); ++it) {
-        if (kind == RangeKind::kBounded && it->first >= e) break;
-        TreeItem* item = it->second;
-        emit(it->first, KvMeta{item->create_rev, item->mod_rev, item->version,
-                               item->lease, item->latest});
-        // Approximate count beyond the limit (the reference allows this,
-        // README.adoc:326-328): one element past the limit proves
-        // more=1, then stop — a paginated list over 1M keys must cost
-        // O(limit), not O(keys).
-        if (limit > 0 && total > limit) break;
-      }
+    for (auto it = s->sorted.lower_bound(k); it != s->sorted.end(); ++it) {
+      if (kind == RangeKind::kBounded && it->first >= e) break;
+      emit(*it->second->latest);
+      // Approximate count beyond the limit (the reference allows this,
+      // README.adoc:326-328): one element past the limit proves
+      // more=1, then stop — a paginated list over 1M keys must cost
+      // O(limit), not O(keys).
+      if (limit > 0 && total > limit) break;
     }
   }
 
@@ -1068,21 +1226,19 @@ int ms_compact(ms_store* s, int64_t rev) {
       // non-superseded versions; see header).
       auto it = std::upper_bound(item->revs.begin(), item->revs.end(), rev);
       int64_t live = (it == item->revs.begin()) ? 0 : *(it - 1);
-      if (r == live && e.val) {
+      if (r == live && !e.rec->del) {
         // Keep it even when r == mod_rev today: a later write would move
         // `latest` on and strand reads in [compact_rev, that write).
-        item->base_rev = r;
-        item->base = KvMeta{e.create_rev, r, e.version, e.lease, e.val};
+        item->base = e.rec;
       }
       // Tombstone GC (the reference's TODO, store.rs:832): a key deleted
       // before the compact revision with no later writes can be dropped
       // entirely.
-      if (!e.val && r == item->mod_rev && !item->present) {
-        s->by_key.erase(item->key);
-        s->sorted.erase(item->key);
+      if (e.rec->del && e.rec.get() == item->latest.get()) {
+        // No log reference remains (this was the item's last touch); the
+        // key's bytes live on for as long as a queued event holds them.
+        s->by_key.erase(item->key->view());
         delete item;
-        // Null out any remaining log references (none: r == mod_rev means
-        // this was the item's last touch).
       }
     }
     s->log.pop_front();
@@ -1111,10 +1267,9 @@ int64_t ms_watch_create(ms_store* s, const uint8_t* start, size_t start_len,
   w->start.assign(reinterpret_cast<const char*>(start), start_len);
   RangeKind kind = range_kind(end, end_len);
   w->single = kind == RangeKind::kSingle;
+  w->to_infinity = kind == RangeKind::kToInfinity;
   if (kind == RangeKind::kBounded)
     w->end.assign(reinterpret_cast<const char*>(end), end_len);
-  else if (kind == RangeKind::kToInfinity)
-    w->end = std::string(1, '\0');
   w->want_prev = want_prev_kv != 0;
   w->min_rev = start_rev;
 
@@ -1124,29 +1279,20 @@ int64_t ms_watch_create(ms_store* s, const uint8_t* start, size_t start_len,
   if (start_rev > 0 && start_rev <= s->current) {
     for (int64_t r = std::max(start_rev, s->log_base); r <= s->current; r++) {
       const RevEntry& e = s->log[static_cast<size_t>(r - s->log_base)];
-      if (!e.item || !w->matches(e.item->key)) continue;
-      Event ev;
-      ev.key = e.item->key;
-      if (e.val) {
-        ev.type = 0;
-        ev.kv = KvMeta{e.create_rev, r, e.version, e.lease, e.val};
-      } else {
-        ev.type = 1;
-        ev.kv = KvMeta{0, r, 0, 0, nullptr};
-      }
+      if (!w->matches(e.rec->key->view())) continue;
+      Event ev{e.rec, RecRef()};
       if (w->want_prev) {
         // prev = value just before r, even across the start revision
         // (reference watch_service_test.rs:372-425 pins this).
-        KvMeta prev;
-        if (s->value_at(e.item, r - 1, &prev) == MS_OK && prev.val) {
-          ev.has_prev = true;
-          ev.prev = prev;
-        }
+        const Rec* prev;
+        if (s->value_at(e.item, r - 1, &prev) == MS_OK && prev && !prev->del)
+          ev.prev = RecRef::share(const_cast<Rec*>(prev));
       }
       w->q.push_back(std::move(ev));
     }
   }
 
+  if (w->want_prev) s->prev_watchers++;
   s->watchers.emplace(w->id, w);
   return w->id;
 }
@@ -1159,6 +1305,7 @@ int ms_watch_cancel(ms_store* s, int64_t watcher_id) {
     if (it == s->watchers.end()) return MS_ERR_NOT_FOUND;
     w = it->second;
     s->watchers.erase(it);
+    if (w->want_prev) s->prev_watchers--;
   }
   {
     std::lock_guard<std::mutex> g(w->m);
@@ -1178,28 +1325,17 @@ int ms_watch_poll(ms_store* s, int64_t watcher_id, int max_events,
   }
   if (!w) return MS_ERR_NOT_FOUND;
 
-  std::vector<Event> events;
   bool canceled;
-  {
-    std::unique_lock<std::mutex> g(w->m);
-    if (w->q.empty() && timeout_ms > 0 && !w->canceled)
-      w->cv.wait_for(g, std::chrono::milliseconds(timeout_ms),
-                     [&] { return !w->q.empty() || w->canceled; });
-    canceled = w->canceled;
-    while (!w->q.empty() && static_cast<int>(events.size()) < max_events) {
-      events.push_back(std::move(w->q.front()));
-      w->q.pop_front();
-    }
-  }
+  const std::deque<Event> events = w->take(max_events, timeout_ms, &canceled);
 
   std::string b;
   put_u32(b, static_cast<uint32_t>(events.size()));
   put_u8(b, canceled ? 1 : 0);
   for (auto& ev : events) {
-    put_u8(b, ev.type);
-    put_u8(b, ev.has_prev ? 1 : 0);
-    put_kv(b, ev.key, ev.kv);
-    if (ev.has_prev) put_kv(b, ev.key, ev.prev);
+    put_u8(b, ev.rec->del ? 1 : 0);
+    put_u8(b, ev.prev ? 1 : 0);
+    put_kv(b, *ev.rec);
+    if (ev.prev) put_kv(b, *ev.prev);
   }
   *out = to_malloc(b, out_len);
   return static_cast<int>(events.size());
@@ -1273,8 +1409,6 @@ bool parse_qty(const char* p, size_t n, const char* suffix, size_t suffix_len,
   *out = acc;
   return true;
 }
-
-#define LIT(name) name, sizeof(name) - 1
 
 // A flat {"k":"v",...} map of plain strings, from just past its opening
 // brace; *i ends just past the closing brace (objects.py _scan_labels).
@@ -1521,25 +1655,17 @@ int ms_watch_poll_pods(ms_store* s, int64_t watcher_id, int max_events,
   }
   if (!w) return MS_ERR_NOT_FOUND;
 
-  std::vector<Event> events;
   bool canceled;
-  {
-    std::unique_lock<std::mutex> g(w->m);
-    canceled = w->canceled;
-    while (!w->q.empty() && static_cast<int>(events.size()) < max_events) {
-      events.push_back(std::move(w->q.front()));
-      w->q.pop_front();
-    }
-  }
+  const std::deque<Event> events = w->take(max_events, 0, &canceled);
 
   *out = emit_pod_frame(
       events.size(), canceled, sched, sched_len,
       [&](size_t i) -> PodEventView {
-        const Event& ev = events[i];
-        return PodEventView{
-            ev.type, ev.kv.mod_rev, ev.key.data(), ev.key.size(),
-            ev.kv.val ? ev.kv.val->data() : nullptr,
-            ev.kv.val ? ev.kv.val->size() : 0};
+        const Rec& r = *events[i].rec;
+        return PodEventView{r.del,         r.mod_rev,
+                            r.key->data,   r.key->len,
+                            r.del ? nullptr : r.val,
+                            r.vlen};
       },
       out_len);
   return static_cast<int>(events.size());
@@ -1646,6 +1772,9 @@ int ms_stats_json(ms_store* s, uint8_t** out, size_t* out_len) {
   }
   j += "],\"watch_pressure\":{\"enqueued\":" +
        std::to_string(s->watch_enqueued.load(std::memory_order_relaxed)) +
+       ",\"enqueue_batches\":" +
+       std::to_string(
+           s->watch_enqueue_batches.load(std::memory_order_relaxed)) +
        ",\"dropped\":" +
        std::to_string(s->watch_dropped_total.load(std::memory_order_relaxed)) +
        ",\"queue_hwm\":" +

@@ -12,7 +12,18 @@
  *     write critical section, so they are revision-ordered by construction
  *     — no re-ordering heap or notify thread needed (the reference needs
  *     one because its revision allocation and notification are decoupled,
- *     store.rs:444-533);
+ *     store.rs:444-533).  The fan-out happens once a frame — a frame is one
+ *     write critical section: a batch, or a single set — at its end and
+ *     before the store's write lock is released: each matching watcher's
+ *     queue is taken once and handed the frame's matching events as one
+ *     run, in revision order, so no event is delivered later than the
+ *     return of the call that wrote it.  A queue's cap falls event by
+ *     event inside a frame: the queue takes the first events of its run
+ *     that it has room for, and the rest are counted as dropped;
+ *   - a key and a write are each one allocation, shared by reference by
+ *     the two indexes, the revision log, the WAL queue, the watchers'
+ *     queues and the polls: a queued event keeps its key and value alive
+ *     after compaction has dropped the item;
  *   - WAL: per-prefix append-only files, none/buffered/fsync modes, a
  *     background writer batching records, boot-time merge-replay by
  *     revision (reference wal.rs:62-299).
@@ -115,7 +126,15 @@ int ms_wal_io_error(ms_store* s);
  * reference gets from gRPC stream batching + per-core WAL writers
  * (reference wal.rs:173-248).  Returns the last allocated revision (or
  * the current revision if the batch allocated none), MS_ERR_INVALID on a
- * malformed buffer.  In fsync mode, returns after the batch is durable. */
+ * malformed buffer (checked whole before anything commits).  In fsync
+ * mode, returns after the batch is durable.
+ *
+ * The batch is one frame: its records commit one by one, exactly as n
+ * ms_set calls in this order would (a key may appear twice, keys may come
+ * in any order — in key order the ordered index takes them fastest), and
+ * its events reach each matching watcher as one run when the last record
+ * has committed, before the call returns (see the design note above for
+ * where a queue's cap falls). */
 int64_t ms_put_batch(ms_store* s, const uint8_t* buf, size_t len, int n,
                      int64_t lease);
 
@@ -135,7 +154,9 @@ int64_t ms_put_batch(ms_store* s, const uint8_t* buf, size_t len, int n,
  * MS_ERR_CAS (revision mismatch / key absent), or MS_ERR_INVALID (value
  * not spliceable or name needs JSON escaping — caller falls back to its
  * slow path).  Returns the number of successful binds, or MS_ERR_INVALID
- * on a malformed buffer.
+ * on a malformed buffer (checked whole before anything commits).  Like
+ * ms_put_batch the wave is one frame: per-record CAS and results, one
+ * fan-out at its end, inside the write critical section.
  *
  * exclude_watcher (-1 = none): watcher id whose queue should NOT receive
  * the bind events from this wave.  A scheduling coordinator passes its
@@ -143,8 +164,10 @@ int64_t ms_put_batch(ms_store* s, const uint8_t* buf, size_t len, int n,
  * and at 20K+ binds/s the echo events are half the watch firehose.  The
  * reference's scheduler cache solves the same problem by assuming the
  * pod before the informer echo arrives (its informer then dedups against
- * the assumed state); suppressing at the dispatch point is the
- * store-native equivalent.  All other watchers observe every event. */
+ * the assumed state); suppressing at the fan-out is the store-native
+ * equivalent: the frame's run is not offered to that watcher at all, so
+ * it neither queues nor counts as dropped there.  All other watchers
+ * observe every event. */
 int ms_bind_batch(ms_store* s, const uint8_t* buf, size_t len, int n,
                   int64_t exclude_watcher, int64_t** out);
 
@@ -283,7 +306,11 @@ int64_t ms_watch_pending(ms_store* s, int64_t watcher_id);
 int64_t ms_num_keys(ms_store* s);
 /* Approximate resident bytes of keys+latest values (db_size analogue). */
 int64_t ms_db_size(ms_store* s);
-/* JSON object: per-prefix {keys, bytes}, revision, watcher count, etc. */
+/* JSON object: per-prefix {keys, bytes}, revision, watcher count, lock
+ * cells, and watch_pressure {enqueued, enqueue_batches, dropped,
+ * queue_hwm}: enqueue_batches counts the times a writer took a watcher's
+ * queue, so enqueued / enqueue_batches is the events handed over per
+ * acquisition (a frame's length on the batch lanes, 1 on ms_set). */
 int ms_stats_json(ms_store* s, uint8_t** out, size_t* out_len);
 
 /* Block until all WAL records at or below the current revision are

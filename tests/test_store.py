@@ -261,6 +261,24 @@ def test_compact_value_superseded_then_modified_later(store):
     del r1, r2
 
 
+def test_compact_past_a_tombstone_of_a_key_that_comes_back(store):
+    # The tombstone is dropped with the history below the compact
+    # revision and the item lives on: reads from the compact revision up
+    # to the re-create see the key absent, not a compacted error.
+    store.put(K, b"v1")
+    store.put(K2, b"keep")
+    r_del, _ = store.delete(K)
+    store.put(K2, b"keep2")
+    r_back = store.put(K, b"v2")
+    store.compact(r_del + 1)
+    for rev in range(r_del + 1, r_back):
+        assert store.get(K, revision=rev) is None
+        assert [kv.key for kv in store.range(K, K2 + b"\0", revision=rev).kvs] == [K2]
+    assert store.get(K, revision=r_back).value == b"v2"
+    with pytest.raises(CompactedError):
+        store.get(K, revision=r_del)
+
+
 def test_compact_errors(store):
     store.put(K, b"v1")
     store.compact(store.current_revision)
@@ -496,10 +514,17 @@ def test_lock_metrics_rendered(tmp_path):
         etcd_server._SERVED_STORES.add(s)
         s.put(b"/registry/pods/ns/a", b"v")
         s.range(b"/registry/pods/ns/a")
+        w = s.watch(b"/registry/pods/", prefix_end(b"/registry/pods/"))
+        s.put_batch([(b"/registry/pods/ns/b%d" % i, b"v") for i in range(4)])
         rendered = REGISTRY.render()
         assert 'memstore_lock_count_total{method="set"' in rendered
         assert "memstore_lock_wait_seconds_total" in rendered
         assert "memstore_watch_dropped_total" in rendered
+        # one frame of four events: four enqueued, one queue acquisition
+        enqueued = rendered.split("\nmemstore_watch_enqueued_total ")[1]
+        batches = rendered.split("\nmemstore_watch_enqueue_batches_total ")[1]
+        assert float(enqueued.split()[0]) >= 4.0 and w.pending == 4
+        assert 1.0 <= float(batches.split()[0]) <= float(enqueued.split()[0]) - 3
         loop.run_until_complete(server.stop(None))
     finally:
         loop.close()
@@ -836,3 +861,428 @@ def test_parse_pod_events_matches_poll_pods(store):
         )
     assert wire.key_blob == native.key_blob
     assert wire.aux_blob == native.aux_blob
+
+
+# ---- frames: fan-out once a frame, records shared by reference ------------
+# What a batch lane does per frame it must do exactly as the one-record
+# lane does per record: the same revisions, stored bytes, history, stats
+# and watch streams, whatever a frame holds and wherever a queue's cap
+# falls inside it.
+
+_ERR_CAS, _ERR_INVALID = -1, -5
+
+
+def _pod(name, **kw):
+    from k8s1m_tpu.control.objects import encode_pod, pod_key
+    from k8s1m_tpu.snapshot.pod_encoding import PodInfo
+
+    return pod_key("default", name), encode_pod(PodInfo(name, **kw))
+
+
+def _raw_frame(w, max_events, pods):
+    """ms_watch_poll's frame or ms_watch_poll_pods', unparsed."""
+    import ctypes
+
+    from k8s1m_tpu.store import native
+
+    lib = native._lib()
+    out, out_len = ctypes.POINTER(ctypes.c_uint8)(), ctypes.c_size_t()
+    outs = ctypes.byref(out), ctypes.byref(out_len)
+    if pods:
+        sched = b"dist-scheduler"
+        rc = lib.ms_watch_poll_pods(w._store._h, w.id, max_events, sched,
+                                    len(sched), *outs)
+    else:
+        rc = lib.ms_watch_poll(w._store._h, w.id, max_events, 0, *outs)
+    assert rc >= 0
+    return native._take_buf(lib, out, out_len)
+
+
+def _raw_poll(w, max_events=1 << 20):
+    return _raw_frame(w, max_events, pods=False)
+
+
+def _raw_poll_pods(w, max_events=1 << 20):
+    return _raw_frame(w, max_events, pods=True)
+
+
+def _readable_history(store):
+    """``range`` over everything at every revision still readable."""
+    lo = max(store.compact_revision, 1)
+    return {
+        rev: store.range(b"\0", b"\0", revision=rev).kvs
+        for rev in range(lo, store.current_revision + 1)
+    }
+
+
+def _bind_one_by_one(store, binds):
+    """ms_bind_batch's contract, record by record through ``cas``."""
+    from k8s1m_tpu.control.coordinator import splice_node_name
+
+    results = []
+    for key, required_mod, name in binds:
+        kv = store.get(key)
+        if kv is None or kv.mod_revision != required_mod:
+            results.append(_ERR_CAS)
+            continue
+        plain = all(c >= 0x20 and c not in b'"\\' for c in name)
+        new = splice_node_name(kv.value, name.decode()) if plain else None
+        if new is None:
+            results.append(_ERR_INVALID)
+            continue
+        ok, rev, _ = store.cas(key, new, required_mod=required_mod,
+                               lease=kv.lease)
+        assert ok
+        results.append(rev)
+    return results
+
+
+@pytest.mark.parametrize("lane", ["put", "bind"])
+def test_frame_fanout_cap_falls_inside_the_frame(store, lane):
+    """A queue smaller than a frame takes the frame's first events, in
+    order, and counts exactly the rest; a queue with room takes all."""
+    pods = [_pod(f"p{i}") for i in range(8)]
+    if lane == "bind":
+        rev = store.put_batch(pods)
+        first = rev - len(pods) + 1
+    small = _pods_watch(store, queue_cap=5)
+    roomy = _pods_watch(store)
+    before = store.stats()["watch_pressure"]
+    if lane == "put":
+        last = store.put_batch(pods)
+    else:
+        res = store.bind_batch(
+            [(k, first + i, b"n-%d" % i) for i, (k, _) in enumerate(pods)]
+        )
+        last = int(res[-1])
+        assert (res > 0).all()
+    revs = list(range(last - 7, last + 1))
+    assert small.dropped == 3 and roomy.dropped == 0
+    wp = store.stats()["watch_pressure"]
+    assert wp["enqueued"] - before["enqueued"] == 5 + 8
+    assert wp["dropped"] - before["dropped"] == 3
+    assert wp["queue_hwm"] >= 8
+    # a full queue takes nothing of the next frame, and counts all of it
+    store.put_batch([_pod("q0"), _pod("q1")])
+    assert small.dropped == 5 and small.pending == 5
+    got_small, got_roomy = small.poll(), roomy.poll()
+    assert [e.kv.mod_revision for e in got_small] == revs[:5]
+    assert [e.kv.key for e in got_small] == [k for k, _ in pods[:5]]
+    assert [e.kv.mod_revision for e in got_roomy] == revs + [last + 1, last + 2]
+    # drained, it takes events again, from the next write on
+    store.put(*_pod("q2"))
+    assert [e.kv.key for e in small.poll()] == [_pod("q2")[0]]
+
+
+def test_events_outlive_their_key(store):
+    """A queued event keeps its key and value after the item is gone:
+    delete, compact past the tombstone (tombstone GC frees the item),
+    churn the allocator, then poll."""
+    plain, pods = _pods_watch(store), _pods_watch(store)
+    k, v = _pod("gone", cpu_milli=123)
+    r1 = store.put(k, v)
+    r2, _ = store.delete(k)
+    store.compact(store.current_revision)
+    assert store.get(k) is None
+    store.put_batch([_pod(f"churn{i}") for i in range(64)])
+    evs = plain.poll(2)
+    assert [(e.type, e.kv.key, e.kv.value, e.kv.mod_revision) for e in evs] == [
+        ("PUT", k, v, r1), ("DELETE", k, b"", r2),
+    ]
+    evb = pods.poll_pods(2)
+    assert evb.key_blob == k + k and evb.mrev.tolist() == [r1, r2]
+    assert evb.cpu.tolist() == [123, 0]
+    # the key can come back: a fresh item under the same bytes
+    r3 = store.put(k, v)
+    kv = store.get(k)
+    assert (kv.create_revision, kv.version) == (r3, 1)
+
+
+def test_put_frame_equals_one_by_one():
+    """Keys out of order, a key twice in one frame, a delete marker for a
+    key the frame wrote, one for a key it did not, one for a key that is
+    absent: the frame leaves what the same operations leave one by one."""
+    a, b = MemStore(), MemStore()
+    try:
+        for s in (a, b):
+            s.put(NODE_PREFIX + b"n0", b"old")
+            s.put(b"/registry/pods/default/z", b"zz")
+        items = [
+            (b"/registry/pods/default/m", b"m1"),
+            (b"/registry/pods/default/c", b"c1"),
+            (NODE_PREFIX + b"n0", b"new"),
+            (b"/registry/pods/default/m", b"m2"),       # twice in the frame
+            (b"/registry/pods/default/c", None),        # written above
+            (b"/registry/pods/default/z", None),        # written before
+            (b"/registry/pods/default/nope", None),     # absent: no revision
+            (b"/registry/apps.k8s.io/deployments/d", b"d1"),
+            (b"/registry/pods/default/c", b"c2"),       # resurrected
+            (b"/registry/pods/default/a", b"a1"),
+        ]
+        watches = {}
+        for name, s in (("a", a), ("b", b)):
+            watches[name] = (
+                s.watch(b"\0", b"\0"), s.watch(b"\0", b"\0", prev_kv=True),
+            )
+        last = a.put_batch(items)
+        for key, value in items:
+            if value is None:
+                b.delete(key)
+            else:
+                b.put(key, value)
+        assert last == a.current_revision == b.current_revision
+        assert _readable_history(a) == _readable_history(b)
+        assert a.get(b"/registry/pods/default/m").version == 2
+        assert a.get(b"/registry/pods/default/c").version == 1
+        sa, sb = a.stats(), b.stats()
+        for f in ("keys", "db_bytes", "prefixes", "revision"):
+            assert sa[f] == sb[f], f
+        for wa, wb in zip(watches["a"], watches["b"]):
+            ra = _raw_poll(wa)
+            assert ra == _raw_poll(wb) and len(ra) > 5
+        # the ordered index took every insert, whatever the hint was worth
+        keys = [kv.key for kv in a.range(b"/registry/", b"/registry0").kvs]
+        assert keys == sorted(keys) and len(keys) == 5
+    finally:
+        a.close()
+        b.close()
+
+
+def test_bind_frame_equals_cas_by_cas():
+    """A mixed bind frame: per-record results, stored bytes, history and
+    events equal the same binds sent ``cas`` by ``cas``."""
+    from k8s1m_tpu.control.coordinator import splice_node_name
+
+    a, b = MemStore(), MemStore()
+    try:
+        seed = [
+            _pod("good0"), _pod("stale"), _pod("good1", labels={"x": "y"}),
+            _pod("appended", node_name="n-old"),      # nodeName after containers
+            _pod("quote"), _pod("good2"),
+            (b"/registry/pods/default/raw", b"not a pod at all"),
+        ]
+        spliced_k, spliced_v = _pod("spliced")
+        spliced_v = splice_node_name(spliced_v, "n-old")  # nodeName in spec's head
+        seed.append((spliced_k, spliced_v))
+        revs = {}
+        for s in (a, b):
+            last = s.put_batch(seed)
+            for i, (k, _) in enumerate(seed):
+                revs[k] = last - len(seed) + 1 + i
+            s.put(*_pod("stale", cpu_milli=9))    # moves its mod_revision on
+        binds = [
+            (_pod("good0")[0], revs[_pod("good0")[0]], b"n-0"),
+            (_pod("stale")[0], revs[_pod("stale")[0]], b"n-1"),
+            (b"/registry/pods/default/missing", 7, b"n-2"),
+            (_pod("good1")[0], revs[_pod("good1")[0]], b"n-3"),
+            (_pod("appended")[0], revs[_pod("appended")[0]], b"n-4"),
+            (spliced_k, revs[spliced_k], b"n-5"),
+            (_pod("quote")[0], revs[_pod("quote")[0]], b'n-"6'),
+            (b"/registry/pods/default/raw",
+             revs[b"/registry/pods/default/raw"], b"n-7"),
+            (_pod("good2")[0], revs[_pod("good2")[0]], b"n-8"),
+            (_pod("good0")[0], revs[_pod("good0")[0]], b"n-9"),  # bound above
+        ]
+        wa = (_pods_watch(a), _pods_watch(a, prev_kv=True))
+        wb = (_pods_watch(b), _pods_watch(b, prev_kv=True))
+        got = a.bind_batch(binds).tolist()
+        want = _bind_one_by_one(b, binds)
+        r = a.current_revision
+        assert got == want == [
+            r - 2, _ERR_CAS, _ERR_CAS, r - 1, _ERR_INVALID, _ERR_INVALID,
+            _ERR_INVALID, _ERR_INVALID, r, _ERR_CAS,
+        ]
+        assert _readable_history(a) == _readable_history(b)
+        kv = a.get(_pod("good1")[0])
+        assert kv.value == splice_node_name(_pod("good1", labels={"x": "y"})[1], "n-3")
+        assert kv.version == 2 and kv.create_revision == revs[kv.key]
+        assert a.stats()["prefixes"] == b.stats()["prefixes"]
+        assert _raw_poll_pods(wa[0]) == _raw_poll_pods(wb[0])
+        ra = _raw_poll(wa[1])
+        assert ra == _raw_poll(wb[1]) and ra[:4] == (3).to_bytes(4, "little")
+    finally:
+        a.close()
+        b.close()
+
+
+@pytest.mark.parametrize("lane", ["put", "put_batch", "bind_batch"])
+def test_prev_kv_only_where_asked(store, lane):
+    """One frame, two watchers on the same keys: prev_kv rides only to
+    the watcher that asked for it."""
+    pods = [_pod(f"p{i}") for i in range(3)]
+    first = store.put_batch(pods) - 2
+    asked, plain = _pods_watch(store, prev_kv=True), _pods_watch(store)
+    if lane == "put":
+        for k, _ in pods:
+            store.put(k, b"v2")
+    elif lane == "put_batch":
+        store.put_batch([(k, b"v2") for k, _ in pods] + [_pod("fresh")])
+    else:
+        store.bind_batch([(k, first + i, b"n") for i, (k, _) in enumerate(pods)])
+    got, bare = asked.poll(), plain.poll()
+    assert [e.prev_kv.value for e in got[:3]] == [v for _, v in pods]
+    assert [e.prev_kv.mod_revision for e in got[:3]] == [first, first + 1, first + 2]
+    assert all(e.prev_kv is None for e in bare)
+    assert [e.kv for e in got] == [e.kv for e in bare]
+    if lane == "put_batch":
+        assert got[3].prev_kv is None       # a create has nothing before it
+    # the last asker gone, the store carries prev_kv to nobody
+    asked.cancel()
+    store.put(pods[0][0], b"v3")
+    assert plain.poll()[0].prev_kv is None
+
+
+def test_exclude_watcher_through_the_frame(store):
+    """exclude_watcher through the batched fan-out: the excluded watcher
+    gets none of the frame's events and loses none of its room; every
+    other watcher gets each of them once."""
+    pods = [_pod(f"p{i}") for i in range(6)]
+    first = store.put_batch(pods) - 5
+    mine = _pods_watch(store, queue_cap=4)
+    others = [_pods_watch(store), _pods_watch(store, prev_kv=True)]
+    unrelated = store.watch(NODE_PREFIX, prefix_end(NODE_PREFIX))
+    res = store.bind_batch(
+        [(k, first + i, b"n-%d" % i) for i, (k, _) in enumerate(pods)],
+        exclude_watcher=mine.id,
+    )
+    assert res.tolist() == list(range(first + 6, first + 12))
+    assert mine.poll() == [] and mine.dropped == 0
+    for w in others:
+        assert [e.kv.mod_revision for e in w.poll()] == res.tolist()
+        assert w.poll() == []
+    assert unrelated.poll() == []
+    # its own puts still reach it, through the same fan-out
+    store.put_batch([_pod("later")])
+    assert len(mine.poll()) == 1
+
+
+def test_enqueue_batches_counts_queue_acquisitions(store):
+    """``watch_pressure.enqueue_batches``: +1 a matching watcher a frame,
+    whatever the frame's length, and +1 a matching watcher a put."""
+    w1, w2 = _pods_watch(store), _pods_watch(store)
+    store.watch(NODE_PREFIX, prefix_end(NODE_PREFIX))     # matches nothing
+
+    def pressure():
+        wp = store.stats()["watch_pressure"]
+        return wp["enqueued"], wp["enqueue_batches"]
+
+    e0, b0 = pressure()
+    pods = [_pod(f"p{i}") for i in range(50)]
+    first = store.put_batch(pods) - 49
+    assert pressure() == (e0 + 100, b0 + 2)
+    store.bind_batch(
+        [(k, first + i, b"n") for i, (k, _) in enumerate(pods)],
+        exclude_watcher=w1.id,
+    )
+    assert pressure() == (e0 + 150, b0 + 3)
+    store.put(*_pod("one"))
+    store.delete(_pod("one")[0])
+    assert pressure() == (e0 + 154, b0 + 7)
+    store.put_batch([])                                 # an empty frame
+    store.delete(b"/registry/pods/default/absent")      # no revision
+    assert pressure() == (e0 + 154, b0 + 7)
+    assert len(w1.poll()) == 52 and len(w2.poll()) == 102
+
+
+def test_batch_lanes_equal_the_one_record_lane_randomised():
+    """A few hundred seeded operations — puts, deletes, CAS, put frames
+    and bind frames of mixed length, polls that take part of a queue, a
+    compaction in the middle — once through the batch lanes and once
+    record by record: the same ``range`` at every revision still
+    readable, the same stats, byte-identical ``poll`` / ``poll_pods``
+    frames."""
+    import random
+
+    rng = random.Random(31)
+    a, b = MemStore(), MemStore()
+    try:
+        names = [f"p{i}" for i in range(40)]
+        node_keys = [NODE_PREFIX + b"n%d" % i for i in range(12)]
+        wa = (_pods_watch(a), a.watch(b"\0", b"\0", prev_kv=True))
+        wb = (_pods_watch(b), b.watch(b"\0", b"\0", prev_kv=True))
+        frames_a, frames_b = [], []
+
+        def poll_some():
+            n = rng.choice([1, 3, 1 << 20])
+            frames_a.append((_raw_poll_pods(wa[0], n), _raw_poll(wa[1], n)))
+            frames_b.append((_raw_poll_pods(wb[0], n), _raw_poll(wb[1], n)))
+
+        def a_put_record():
+            if rng.random() < 0.25:
+                return rng.choice(node_keys), b"node-%d" % rng.randrange(99)
+            name = rng.choice(names)
+            if rng.random() < 0.2:
+                return _pod(name, priority=rng.randrange(1, 5))  # lane json
+            return _pod(name, cpu_milli=rng.randrange(1, 999))
+
+        def a_key():
+            if rng.random() < 0.25:
+                return rng.choice(node_keys)
+            return _pod(rng.choice(names))[0]
+
+        for step in range(320):
+            op = rng.random()
+            if step == 160:
+                poll_some()
+                at = a.current_revision - rng.randrange(0, 20)
+                a.compact(at)
+                b.compact(at)
+            elif op < 0.25:
+                k, v = a_put_record()
+                assert a.put(k, v) == b.put(k, v)
+            elif op < 0.35:
+                k = a_key()
+                assert a.delete(k) == b.delete(k)
+            elif op < 0.45:
+                k, v = a_put_record()
+                cur = a.get(k)
+                req = cur.mod_revision if cur and rng.random() < 0.7 else 3
+                ra = a.cas(k, v, required_mod=req)
+                assert ra == b.cas(k, v, required_mod=req)
+            elif op < 0.75:
+                items = []
+                for _ in range(rng.choice([0, 1, 2, 7, 30])):
+                    if rng.random() < 0.2:
+                        items.append((a_key(), None))
+                    else:
+                        items.append(a_put_record())
+                if rng.random() < 0.5:
+                    items.sort(key=lambda kv: kv[0])    # a client in order
+                a.put_batch(items)
+                for k, v in items:
+                    b.delete(k) if v is None else b.put(k, v)
+            elif op < 0.95:
+                binds = []
+                for _ in range(rng.choice([1, 2, 9, 25])):
+                    k = _pod(rng.choice(names))[0]
+                    cur = a.get(k)
+                    mod = cur.mod_revision if cur and rng.random() < 0.8 else 5
+                    name = rng.choice([b"n-1", b"kwok-node-77", b'bad"name'])
+                    binds.append((k, mod, name))
+                exclude = rng.choice([-1, 0])
+                got = a.bind_batch(
+                    binds, exclude_watcher=wa[0].id if exclude == 0 else -1
+                ).tolist()
+                assert got == _bind_one_by_one(b, binds)
+                if exclude == 0:
+                    # b's plain watcher saw the echoes; drop them there
+                    n = sum(1 for r in got if r > 0)
+                    assert b"nodeName" in _raw_poll(wb[0]) or n == 0
+                    _raw_poll(wa[0])
+            else:
+                poll_some()
+            assert a.current_revision == b.current_revision
+        poll_some()
+        assert frames_a == frames_b
+        assert sum(len(f[1]) for f in frames_a) > 10000
+        assert _readable_history(a) == _readable_history(b)
+        sa, sb = a.stats(), b.stats()
+        for f in ("revision", "compact_revision", "keys", "db_bytes",
+                  "prefixes"):
+            assert sa[f] == sb[f], f
+        assert sa["watch_pressure"]["enqueued"] < sb["watch_pressure"]["enqueued"]
+        assert (sa["watch_pressure"]["enqueue_batches"]
+                < sb["watch_pressure"]["enqueue_batches"])
+    finally:
+        a.close()
+        b.close()

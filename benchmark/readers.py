@@ -45,30 +45,37 @@ def registry_stage_per_bind(args: dict, ctx: dict):
 
 def trace_ms_per_wave(args: dict, ctx: dict):
     """Device milliseconds of the events on ``args.line`` that match
-    ``args.pattern``, per wave: over their own count (a module that runs
-    once a wave), or over the count of ``args.wave_pattern`` events on
-    ``args.wave_line`` (a kernel inside the step)."""
+    ``args.pattern``, per whole wave (``trace_reduce.whole_waves``): the
+    whole ones of them over their own count (a module that runs once a
+    wave), or those of them inside a whole ``args.wave_pattern`` event on
+    ``args.wave_line`` over the count of these (a kernel inside the step).
+    Sum and count are over the same steps: what the trace holds of a step
+    it cut is in neither."""
     tr = ctx.get("trace")
     if tr is None:
         return None
-    total, count = trace_reduce.per_event(
-        tr["events"], tr["plane"], args["line"], args["pattern"]
+    waves = trace_reduce.whole_waves(
+        tr["events"], tr["plane"], args.get("wave_line", args["line"]),
+        args.get("wave_pattern", args["pattern"]),
     )
-    waves = count
     if "wave_pattern" in args:
-        _t, waves = trace_reduce.per_event(
-            tr["events"], tr["plane"], args["wave_line"], args["wave_pattern"]
-        )
-    if not count or not waves:
+        durs = [d for _s, d in trace_reduce.inside(waves, [
+            (s, d) for _p, _l, _n, s, d in trace_reduce.select(
+                tr["events"], tr["plane"], args["line"], args["pattern"])
+        ])]
+    else:
+        durs = [end - start for start, end in waves]
+    if not durs or not waves:
         return None
-    return 1e3 * total / waves
+    return 1e3 * sum(durs) / len(waves)
 
 
 def trace_roofline_pct(args: dict, ctx: dict):
     """HBM roofline share of a candidates kernel: least seconds for the
-    bytes one wave must move over the kernel's measured seconds.  The
-    table columns counted are ``args.columns`` (the plugins the kernel's
-    deployment runs decide them; default ``roofline.BASE_COLUMNS``)."""
+    bytes one wave must move over the kernel's measured seconds a whole
+    wave.  The table columns counted are ``args.columns`` (the plugins the
+    kernel's deployment runs decide them; default
+    ``roofline.BASE_COLUMNS``)."""
     ms = trace_ms_per_wave(args, ctx)
     if ms is None:
         return None

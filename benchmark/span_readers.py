@@ -18,7 +18,9 @@ SCOPES = ("candidates", "assign", "commit")
 
 
 def load_names(trace_dir: str) -> dict:
-    """``op_names`` and ``host_spans`` of ``trace_reduce.load_trace``."""
+    """``op_names`` and ``host_spans`` of ``trace_reduce.load_trace``.
+    The harness calls the loader itself; tests/test_coord_spans.py, which
+    a ``benchmark`` PR may not edit, still reads its spans through this."""
     loaded = trace_reduce.load_trace(trace_dir)
     return {"op_names": loaded["op_names"], "host_spans": loaded["host_spans"]}
 
@@ -110,7 +112,7 @@ def idle_by_span(events, plane: str, host_spans, t0: float, t1: float,
     was in meanwhile: each gap between ``XLA Ops`` is split over the
     **innermost** spans that overlap it, second for second (the pipeline
     leaves one long gap a wave, and a rule that gives a whole gap to one
-    span names only the longest stage).  ``host_spans`` are ``load_names``'
+    span names only the longest stage).  ``host_spans`` are ``load_trace``'s
     ``(line id, name, start_s, dur_s)``; only those on the line that holds
     ``root`` count (the loop's own thread: another thread's span, the
     hotfeed worker's say, overlaps the wave and explains no wait, and the
@@ -175,23 +177,25 @@ def stage_sums() -> dict[str, float]:
 
 
 def trace_scope_ms_per_wave(args: dict, ctx: dict):
-    """Device milliseconds under the named scope per wave: the union of the
-    ``XLA Ops`` intervals whose op_name path holds ``args.scope``, over the
-    count of ``args.wave_pattern`` events on ``args.wave_line``."""
+    """Device milliseconds under the named scope per whole wave: the union
+    of the ``XLA Ops`` intervals whose op_name path holds ``args.scope``
+    and that lie inside a whole ``args.wave_pattern`` event on
+    ``args.wave_line`` (``trace_reduce.whole_waves``), over the count of
+    those events."""
     tr = ctx.get("trace")
     if tr is None or not tr.get("op_names"):
         return None
-    covered = [
+    waves = trace_reduce.whole_waves(
+        tr["events"], tr["plane"], args["wave_line"], args["wave_pattern"]
+    )
+    covered = trace_reduce.inside(waves, [
         (s, d) for s, d, scope in
         scoped_ops(tr["events"], tr["plane"], tr["op_names"])
         if scope == args["scope"]
-    ]
-    _t, waves = trace_reduce.per_event(
-        tr["events"], tr["plane"], args["wave_line"], args["wave_pattern"]
-    )
+    ])
     if not covered or not waves:
         return None
-    return 1e3 * trace_reduce.union_seconds(covered) / waves
+    return 1e3 * trace_reduce.union_seconds(covered) / len(waves)
 
 
 def counter_share_pct(args: dict, ctx: dict):
@@ -230,12 +234,3 @@ def setup_stage_s(args: dict, ctx: dict):
     if not before or not any(s in before for s in args["stages"]):
         return None
     return sum(before.get(s, 0.0) for s in args["stages"])
-
-
-# Read by tools/span_report.py, which a ``benchmark`` PR may not delete;
-# metric files name these readers as ``span_readers.<function>``.
-READERS = {
-    "trace_scope_ms_per_wave": trace_scope_ms_per_wave,
-    "counter_share_pct": counter_share_pct,
-    "setup_stage_s": setup_stage_s,
-}

@@ -9,6 +9,7 @@ An event is ``(plane, line, name, start_s, dur_s)``.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -21,6 +22,9 @@ HOST_SPANS = ("bench.", "coord.", "feed.")
 # The stat of a device op's XEventMetadata that holds its op_name, the
 # "jit(f)/scope/.../primitive:" path jax gives every HLO instruction.
 OP_NAME_STAT = "tf_op"
+# Timestamps are seconds since the epoch as floats, a quarter of a
+# microsecond apart: two events end "together" within this.
+EDGE_S = 1e-6
 
 
 def _xspace_class():
@@ -182,9 +186,47 @@ def busy_window(events, t0: float, t1: float) -> dict:
 
 
 def per_event(events, plane: str, line: str, pattern: str) -> tuple[float, int]:
-    """Summed duration and count of the events on ``line`` that match."""
+    """Summed duration and count of the events on ``line`` that match,
+    cut by the trace or not (the per-wave readers take ``whole_waves``)."""
     sel = select(events, plane, line, pattern)
     return sum(e[4] for e in sel), len(sel)
+
+
+def whole_waves(events, plane: str, line: str, pattern: str
+                ) -> list[tuple[float, float]]:
+    """``(start, end)`` of the events on ``line`` that match ``pattern``
+    and that the trace holds whole, in order.  The profiler starts and
+    stops in the middle of whatever the device is doing, and an event it
+    cut is there all the same, from the trace's first instant or up to its
+    last, with its head or its tail missing; as a wave it would count one
+    for a part of one.  Nothing in an event says that it was cut, so one
+    is whole only where the line holds something before it and something
+    after it: the event that starts at the line's first timestamp and the
+    one that ends at its last are left out, cut or not (a whole wave left
+    out moves no time per wave)."""
+    on_line = select(events, plane, line)
+    if not on_line:
+        return []
+    first = min(e[3] for e in on_line)
+    last = max(e[3] + e[4] for e in on_line)
+    rx = re.compile(pattern)
+    return sorted(
+        (s, s + d) for _p, _l, name, s, d in on_line
+        if rx.search(name) and s > first + EDGE_S and s + d < last - EDGE_S
+    )
+
+
+def inside(waves, intervals) -> list:
+    """Those of ``intervals``, tuples that begin ``(start, dur, ...)``,
+    that lie inside one of ``waves`` (``whole_waves``: in order, none over
+    another): what ran in a step the trace cut belongs to no wave."""
+    starts = [w[0] for w in waves]
+    kept = []
+    for iv in intervals:
+        i = bisect.bisect_right(starts, iv[0] + EDGE_S) - 1
+        if i >= 0 and iv[0] + iv[1] <= waves[i][1] + EDGE_S:
+            kept.append(iv)
+    return kept
 
 
 def sums_by_name(events, plane: str, line: str, top: int = 10):

@@ -110,6 +110,137 @@ def test_no_op_names_no_value():
         {"scope": "commit", **WAVES}, _ctx()) is None
 
 
+# ---- a wave counted is a whole wave -----------------------------------------
+
+KERNEL, LOOP, BODY = EVENTS[5][2], EVENTS[3][2], EVENTS[4][2]
+COMMIT = "%scatter.9 = scatter(...)"
+STEP_NAMES = {**OP_NAMES, COMMIT: "jit(<lambda>)/commit/scatter:"}
+DEVICE_METRICS = ["engine_step_ms.fill", "fused_topk_ms.fill",
+                  "fused_topk_roofline.fill", "candidates_ms.fill",
+                  "assign_ms.fill", "commit_ms.fill"]
+
+
+def _step(t, begins=None, ends=None):
+    """The device's events of one 0.9 s step that starts at ``t``: the
+    kernel (0.1 s, candidates), the loop (0.7 s, no op_name of its own)
+    with two ops of its body (assign), the commit (0.05 s).  Where the
+    trace ``begins`` or ``ends`` inside the step, what the profiler keeps
+    of it: the module's event from there or up to there, and the ops that
+    start and end on the trace's side; an op cut with it is gone."""
+    lo = t if begins is None else begins
+    hi = t + 0.9 if ends is None else ends
+    ops = [(KERNEL, t, 0.1), (LOOP, t + 0.1, 0.7), (BODY, t + 0.2, 0.1),
+           (BODY, t + 0.6, 0.1), (COMMIT, t + 0.85, 0.05)]
+    return [(DEV, "XLA Modules", "jit__lambda(1)", lo, hi - lo)] + [
+        (DEV, "XLA Ops", n, s, d) for n, s, d in ops if s >= lo and s + d <= hi + 1e-9]
+
+
+def OTHER_MODULE(t):
+    """Another module's event on the line, with its one op."""
+    return [(DEV, "XLA Modules", "jit_scatter_rows(2)", t, 0.05),
+            (DEV, "XLA Ops", "%copy.3 = copy(...)", t, 0.05)]
+
+
+TRACES = {
+    # the device was idle between two other modules: every step is whole
+    "uncut": OTHER_MODULE(-0.2) + _step(0.0) + _step(1.0) + _step(2.0)
+             + OTHER_MODULE(3.0),
+    # the trace begins inside the first step: its kernel and the loop's own
+    # event lie before the trace, half of the body and the commit inside
+    "cut_at_its_start": _step(0.0, begins=0.5) + _step(1.0) + _step(2.0)
+                        + OTHER_MODULE(3.0),
+    "cut_at_its_end": OTHER_MODULE(-0.2) + _step(0.0) + _step(1.0)
+                      + _step(2.0, ends=2.45),
+    "cut_at_both_ends": _step(0.0, begins=0.5) + _step(1.0) + _step(2.0)
+                        + _step(3.0, ends=3.45),
+    # nothing before the first step and nothing after the last: both are
+    # whole, and neither can be told from a cut one
+    "nothing_around": _step(0.0) + _step(1.0) + _step(2.0) + _step(3.0),
+}
+CUT_ALONE = _step(0.0, begins=0.5)
+
+
+def _device_values(events):
+    ctx = {**_full_ctx(), "trace": {"events": events, "plane": DEV,
+                                    "op_names": STEP_NAMES, "host_spans": []}}
+    got = run.per_layer_values(MANIFEST, KWOK, ctx)
+    return {m: got[m] for m in DEVICE_METRICS}
+
+
+@pytest.mark.parametrize("trace", TRACES)
+def test_a_trace_reads_its_whole_waves_whatever_its_ends_cut(trace):
+    """A module, a kernel, a scope and the roofline: the per-wave times of
+    the steps the trace holds whole, the same in every trace."""
+    moved = 53248 * 42 + 4096 * 16 + 4096 * 4 * 8
+    assert _device_values(TRACES[trace]) == pytest.approx({
+        "engine_step_ms.fill": 900.0, "fused_topk_ms.fill": 100.0,
+        "fused_topk_roofline.fill": 100 * moved / 819e9 / 0.1,
+        "candidates_ms.fill": 100.0, "assign_ms.fill": 700.0,
+        "commit_ms.fill": 50.0,
+    })
+
+
+def test_a_cut_wave_counted_as_one_read_every_time_low():
+    """What the readers did before (ISSUE 32 (j)): the cut step's event
+    counted as a wave, so three events for two kernels, and 0.4 s of a
+    step for a third one."""
+    cut = TRACES["cut_at_its_start"]
+    kernel = trace_reduce.per_event(cut, DEV, "XLA Ops", r"^%fused_topk[.\d]* = ")
+    steps = trace_reduce.per_event(cut, DEV, "XLA Modules", r"^jit__lambda\(")
+    assert (kernel[1], steps[1]) == (2, 3)
+    assert 1e3 * kernel[0] / steps[1] == pytest.approx(100.0 * 2 / 3)
+    assert 1e3 * steps[0] / steps[1] == pytest.approx((400.0 + 2 * 900.0) / 3)
+    waves = trace_reduce.whole_waves(cut, DEV, "XLA Modules", r"^jit__lambda\(")
+    assert waves == [(1.0, pytest.approx(1.9)), (2.0, pytest.approx(2.9))]
+
+
+def test_a_trace_with_no_cut_event_reads_what_it_read_before():
+    """Sum over count, as ``per_event`` gives them, on the uncut trace and
+    on the accepted fixture."""
+    for events, waves in ((TRACES["uncut"], 3), (OPS, 2)):
+        for metric in ("engine_step_ms.fill", "fused_topk_ms.fill"):
+            args = run.read_json("benchmark", "metrics", f"{metric}.json")["args"]
+            total, count = trace_reduce.per_event(
+                events, DEV, args["line"], args["pattern"])
+            assert count == waves
+            ctx = {"trace": {"events": events, "plane": DEV}}
+            assert readers.trace_ms_per_wave(args, ctx) == pytest.approx(
+                1e3 * total / waves)
+
+
+@pytest.mark.parametrize("metric", DEVICE_METRICS)
+def test_a_trace_with_nothing_but_a_cut_event_reads_nothing(metric):
+    """Never 0, and never the cut step's part as if it were a step."""
+    assert _device_values(CUT_ALONE)[metric] is None
+    # nor does a whole step alone, which no reader can tell from a cut one
+    assert _device_values(_step(0.0))[metric] is None
+
+
+def test_whole_waves_are_those_with_something_before_and_after_on_their_line():
+    step = r"^jit__lambda\("
+    waves = lambda ev: trace_reduce.whole_waves(ev, DEV, "XLA Modules", step)
+    assert waves(TRACES["uncut"]) == [
+        (0.0, pytest.approx(0.9)), (1.0, pytest.approx(1.9)), (2.0, pytest.approx(2.9))]
+    assert [w[0] for w in waves(TRACES["nothing_around"])] == [1.0, 2.0]
+    assert waves(CUT_ALONE) == [] and waves([]) == []
+    # another module's event on the line is something before, an op is not
+    only_ops_before = [(DEV, "XLA Ops", "%copy.3 = copy(...)", -0.2, 0.05),
+                       *_step(0.0), *_step(1.0), *OTHER_MODULE(2.0)]
+    assert [w[0] for w in waves(only_ops_before)] == [1.0]
+    # two timestamps a rounding apart are one
+    near = [*OTHER_MODULE(-0.05 + 4e-7), *_step(0.0), *OTHER_MODULE(1.0)]
+    assert [w[0] for w in waves(near)] == [0.0]
+
+
+def test_inside_keeps_what_lies_in_a_whole_wave():
+    waves = [(1.0, 1.9), (2.0, 2.9)]
+    ops = [(0.95, 0.1, "a"), (1.0, 0.9, "b"), (1.5, 0.1, "c"), (1.85, 0.1, "d"),
+           (1.95, 0.02, "e"), (2.0 - 4e-7, 0.5, "f"), (2.5, 0.4 + 4e-7, "g"),
+           (3.0, 0.1, "h")]
+    assert [o[2] for o in trace_reduce.inside(waves, ops)] == ["b", "c", "f", "g"]
+    assert trace_reduce.inside([], ops) == [] and trace_reduce.inside(waves, []) == []
+
+
 # ---- idle time by host span -----------------------------------------------
 
 
@@ -303,10 +434,16 @@ def test_span_metric_spec(metric):
 
 @rule()
 def span_readers_take_no_accepted_readers_name(tree):
-    assert not set(span_readers.READERS) & set(readers.READERS)
-    named = {run.read_json("benchmark", "metrics", f"{m}.json")["reader"]
-             for m in SPAN_METRICS}
-    assert named >= {f"span_readers.{r}" for r in span_readers.READERS}
+    """A reader of ``span_readers`` is named ``span_readers.<function>`` by
+    the metric files that read it, and none of them has the name of one of
+    ``readers.READERS``, which a metric file gives bare."""
+    dotted = {run.read_json("benchmark", "metrics", f"{m}.json")["reader"]
+              for m in SPAN_METRICS} - set(readers.READERS)
+    assert len(dotted) == 3
+    for name in dotted:
+        module, function = name.split(".")
+        assert module == "span_readers" and function not in readers.READERS
+        assert readers.resolve(name) is getattr(span_readers, function)
 
 
 def test_span_readers_take_no_accepted_readers_name():
@@ -327,6 +464,8 @@ def _full_ctx():
                          (("lane", "batch_fast"),): 384.0, (("lane", "json"),): 7.0},
                      "coordinator_pods_scheduled_total": {
                          (("outcome", "bound"),): 384.0},
+                     "coordinator_bind_retire_total": {
+                         (("lane", "columnar"),): 384.0},
                      "bulkload_values_total": {
                          (("path", "per_node"),): 750.0, (("path", "template"),): 250.0}},
             "close": {"coordinator_pod_intake_total": {
@@ -334,6 +473,8 @@ def _full_ctx():
                           (("lane", "delete"),): 5.0},
                       "coordinator_pods_scheduled_total": {
                           (("outcome", "bound"),): 1334.0, (("outcome", "retry"),): 50.0},
+                      "coordinator_bind_retire_total": {
+                          (("lane", "columnar"),): 1234.0, (("lane", "per_pod"),): 150.0},
                       "bulkload_values_total": {
                           (("path", "per_node"),): 750.0, (("path", "template"),): 250.0}},
         },
@@ -393,6 +534,48 @@ def every_manifest_metric_reads_a_full_context(tree):
 
 def test_every_manifest_metric_reads_a_full_context():
     every_manifest_metric_reads_a_full_context(REPO)
+
+
+NINETEENTH = "bind_columnar_pct.fill"
+
+
+@rule()
+def the_nineteenth_follows_the_eighteen(tree):
+    """PR 32's metric of the retire lanes: right after the eighteen, with
+    both cells of PR 27 first in its list; a cell whose pods retire one by
+    one appends its name and reads its share here."""
+    entry = tree.manifest["per_layer"][len(EIGHTEEN)]
+    assert entry == {
+        "name": NINETEENTH, "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "store", "moves": "binds_per_s",
+        "workloads": entry["workloads"]}
+    assert entry["workloads"][:2] == [KWOK, FIT]
+    spec = run.read_json("benchmark", "metrics", f"{NINETEENTH}.json")
+    assert spec["reader"] == "span_readers.counter_share_pct"
+    assert spec["args"] == {
+        "counter": "coordinator_bind_retire_total", "over": "window",
+        "labels": [{"lane": "columnar"}],
+        "of": [{"lane": "columnar"}, {"lane": "per_pod"}]}
+
+
+def test_the_nineteenth_follows_the_eighteen():
+    the_nineteenth_follows_the_eighteen(REPO)
+
+
+def test_the_nineteenth_reads_the_share_of_the_columnar_lane():
+    """850 of the window's 1,000 retired pods in columns; the counter is
+    registered by the program, so a window in which no pod reached the
+    bind stage reads nothing."""
+    import k8s1m_tpu.control.coordinator  # noqa: F401  (registers the counter)
+
+    ctx = _full_ctx()
+    for cell in (KWOK, FIT):
+        assert run.per_layer_values(MANIFEST, cell, ctx)[NINETEENTH] \
+            == pytest.approx(85.0)
+    assert "coordinator_bind_retire_total" in span_readers.snapshot_counters()
+    still = {**ctx, "counters": {"open": ctx["counters"]["close"],
+                                 "close": ctx["counters"]["close"]}}
+    assert run.per_layer_values(MANIFEST, KWOK, still)[NINETEENTH] is None
 
 
 def test_assign_ms_reads_what_the_retired_loop_metric_read():
@@ -484,7 +667,7 @@ def test_the_harness_reads_the_counters_of_a_tiny_run(cell):
     # both cells of PR 27 bring their file, with that PR's values
     if cell in (KWOK, FIT):
         assert REPO.cell_data(cell) == {
-            "untraced_metrics": 12 if cell == FIT else 11,
+            "untraced_metrics": 13 if cell == FIT else 12,
             "window_lanes": ["batch_fast"], "shapes_interned": 1,
             "values": {"intake_fast_lane_pct.fill": 100.0,
                        "bulkload_per_node_pct": 100.0},

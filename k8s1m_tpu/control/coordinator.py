@@ -106,6 +106,7 @@ from k8s1m_tpu.control.objects import (
     pod_key,
     pod_key_str_of_obj,
 )
+from k8s1m_tpu.engine.assign import UNBOUND_REASONS
 from k8s1m_tpu.engine.cycle import (
     Wave,
     adjust_constraints,
@@ -227,6 +228,16 @@ _BIND_RETIRE = Counter(
     "coordinator_bind_retire_total",
     "Pods that reached a wave's bind stage, by the lane that retired them",
     ("lane",),
+)
+# Once per wave (_complete), from three sums the device step returns with
+# the wave's rows; only a coordinator with in_wave_skew counts them.
+# capacity = every candidate that was legal had lost its room to earlier
+# pods of the wave; skew = it had candidates, and none in a zone its
+# spread constraints allowed at its turn; no_candidate = the candidates
+# stage found no feasible row.  A pod counted here goes on to _retry.
+_WAVE_UNBOUND = Counter(
+    "coordinator_wave_unbound_total",
+    "Valid pods a wave left unbound, by why", ("reason",),
 )
 # Once per frame, never per pod: pods taken natively over `interned` is
 # the shape table's hit share.
@@ -779,13 +790,22 @@ class Coordinator:
         delta_index_k: int = 0,
         stratum_bits: int = 0,
         delta_index_dirty_cap: int | None = None,
+        # PodTopologySpread's hard zone and region constraints counted
+        # inside the wave, pod by pod in wave order (engine/assign.py),
+        # so that they hold at every bind and not only between waves.
+        # A pod then brings one candidate a zone id, so ``k`` is
+        # ``table_spec.max_zones`` whatever was passed — which is why
+        # this is a choice and not the default (the default TableSpec
+        # has 512 zone ids).  One device only.  False = the wave-start
+        # counts alone decide.
+        in_wave_skew: bool = False,
     ):
         self.store = store
         self.table_spec = table_spec
         self.pod_spec = pod_spec
         self.profile = profile
         self.chunk = chunk
-        self.k = k
+        self.k = table_spec.max_zones if in_wave_skew else k
         # One resilience policy for the bind/requeue path; max_attempts
         # stays the constructor-level knob (it predates the policy and
         # every harness passes it), overriding the default's budget.
@@ -840,6 +860,11 @@ class Coordinator:
                 max_nodes=table_spec.max_nodes, chunk=chunk,
             )
         self.mesh = mesh
+        if in_wave_skew and (mesh is not None or not with_constraints):
+            raise ValueError(
+                "in_wave_skew takes with_constraints=True and no mesh"
+            )
+        self.in_wave_skew = in_wave_skew
         if mesh is not None:
             dp_size, sp_size = mesh.shape["dp"], mesh.shape["sp"]
             local_rows = table_spec.max_nodes // sp_size
@@ -2044,7 +2069,7 @@ class Coordinator:
             chunk=self.chunk, k=self.k, backend=self.backend,
             sample_rows=self._sample_rows, sample_offset=0,
             row_mask=self._row_mask_dev, mesh=self.mesh,
-            donate=self._donate,
+            donate=self._donate, in_wave_skew=self.in_wave_skew,
         )
         jax.block_until_ready(rows_dev)
         self._warmed = True
@@ -3397,6 +3422,7 @@ class Coordinator:
                     mesh=self.mesh,
                     donate=self._donate,
                     stratum_bits=self.stratum_bits,
+                    in_wave_skew=self.in_wave_skew,
                 )
         if probe_ptr is not None:
             try:
@@ -3415,6 +3441,8 @@ class Coordinator:
         # on the transfer.
         try:
             rows_dev.copy_to_host_async()
+            if asg.unbound is not None:
+                asg.unbound.copy_to_host_async()
         # Best-effort prefetch: some array types/backends simply lack the
         # async copy; the sync device_get in _complete is the fallback.
         except Exception:  # graftlint: disable=broad-except
@@ -3691,8 +3719,14 @@ class Coordinator:
         with self._stage("sync_out"):
             # ONE device_get per wave: each fetch is a device->host
             # sync, so the bind decision comes back as a single packed
-            # i32[B] (-1 = unbound).
-            node_row = jax.device_get(rows_dev)
+            # i32[B] (-1 = unbound) — and, where the wave counted skew,
+            # three sums with it: why its unbound pods stayed so, with
+            # no look at any one pod.
+            node_row, unbound = jax.device_get((rows_dev, asg.unbound))
+            if unbound is not None:
+                for reason, n in zip(UNBOUND_REASONS, unbound.tolist()):
+                    if n:
+                        _WAVE_UNBOUND.inc(n, reason=reason)
         t_sync = time.perf_counter()
         if inflight.index_flag_dev is not None:
             # The which-tail-ran flag is fetched at retire (the wave's

@@ -510,7 +510,8 @@ def encode_pod(pod: PodInfo, *, scheduler_name: str | None = None,
 
     Slot references (spread_refs/affinity_refs) are a compiled, tracker-
     relative form, so callers that built the pod from raw constraint specs
-    pass them through ``raw_affinity``/``raw_spread`` for re-encoding.
+    pass them through ``raw_affinity``/``raw_spread`` for re-encoding;
+    without ``raw_spread`` the pod's own ``topology_spread`` is written.
     """
     spec: dict = {
         "schedulerName": scheduler_name or pod.scheduler_name,
@@ -560,6 +561,8 @@ def encode_pod(pod: PodInfo, *, scheduler_name: str | None = None,
         affinity["nodeAffinity"] = node_aff
     if affinity:
         spec["affinity"] = affinity
+    if raw_spread is None:
+        raw_spread = pod.topology_spread
     if raw_spread:
         spec["topologySpreadConstraints"] = list(raw_spread)
     if pod.priority:
@@ -848,8 +851,9 @@ def decode_pod_obj(obj: dict, tracker: ConstraintTracker | None = None) -> PodIn
         for p in node_aff.get("preferredDuringSchedulingIgnoredDuringExecution", [])
     ]
 
+    pod.topology_spread = list(spec.get("topologySpreadConstraints", []))
     if tracker is not None:
-        for sc in spec.get("topologySpreadConstraints", []):
+        for sc in pod.topology_spread:
             topo = _TOPO_KEYS.get(sc.get("topologyKey", ""))
             if topo is None:
                 raise ValueError(

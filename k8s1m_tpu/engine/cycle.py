@@ -15,13 +15,25 @@ and topology-domain payload so the greedy conflict scan (engine/assign.py),
 the constraint commit, and the sharded all-gather (parallel/) never have
 to re-gather from the (possibly sharded) table.
 
-In-batch semantics note: the greedy conflict scan re-checks *capacity*
-for pods later in the batch, but not topology constraints — two same-batch
-pods can land in a way that exceeds maxSkew by the batch size in the worst
-case.  The reference has exactly the same window (256 shards bind
-optimistically and only capacity conflicts roll back, reference
-README.adoc:558-560); constraint counts are exact again at the next batch
-boundary.  The pipelined coordinator widens the same window across waves:
+In-batch semantics note: the greedy conflict scan re-checks *capacity* for
+pods later in the batch and, with ``in_wave_skew``, PodTopologySpread's
+hard zone and region constraints as well — it carries the count tables
+through the wave pod by pod, so no bind leaves a constraint's count in the
+bound domain more than maxSkew above its least-populated domain, at every
+bind and in wave order (engine/assign.py).  For that the candidates stage
+drops its own skew filter on those constraints, which could only see the
+counts of the wave's start, and keeps the best row of every zone instead
+of the k best rows (``candidates(in_wave_skew=True)``): 256 pods of one
+Deployment in a wave have to land in all eight zones, whatever stood at
+the minimum when the wave began.  Hostname-keyed hard constraints,
+required (anti-)affinity and every score still read the wave-start
+tables; so does everything when ``in_wave_skew`` is off (the default, and
+the mesh step: its candidate gather is a top-k merge), and two same-batch
+pods can then exceed maxSkew by the batch size in the worst case.  The
+reference has exactly that window (256 shards bind optimistically and
+only capacity conflicts roll back, reference README.adoc:558-560);
+constraint counts are exact again at the next batch boundary.  The
+pipelined coordinator widens the wave-start window across waves:
 capacity-only node deltas (allocatable, labels, taints, zone — same row,
 same name) scatter into the live table while earlier waves are still in
 flight, so a wave may score against capacity a heartbeat just changed.
@@ -43,7 +55,8 @@ import numpy as np
 from flax import struct
 from jax import lax
 
-from k8s1m_tpu.engine.assign import greedy_assign
+from k8s1m_tpu.config import SPREAD_DO_NOT_SCHEDULE, TOPO_HOSTNAME
+from k8s1m_tpu.engine.assign import WaveSkew, greedy_assign, unbound_by_reason
 from k8s1m_tpu.ops.priority import pack_hashed, seed_of
 from k8s1m_tpu.plugins.registry import Profile, score_and_filter
 from k8s1m_tpu.snapshot.constraints import (
@@ -111,6 +124,9 @@ class Assignment:
     score: jax.Array     # i32[B] integer plugin score of the chosen node
     zone: jax.Array      # i32[B] domain of the chosen node
     region: jax.Array    # i32[B]
+    # i32[3], engine.assign.UNBOUND_REASONS: the wave's valid pods left
+    # unbound, by why.  Only a wave with in-wave skew counts them.
+    unbound: jax.Array | None = None
 
 
 @struct.dataclass
@@ -185,17 +201,64 @@ def _slice_table(table: NodeTable, start, chunk: int) -> NodeTable:
     return unpack_chunk(sliced) if is_packed(sliced) else sliced
 
 
-def _prologue_stats(table, constraints, axis_name: str | None = None):
+def prologue_stats(table, constraints, axis_name: str | None = None):
     """topology.prologue over either layout: the prologue needs only the
     full valid/zone/region columns, which a packed table decodes ONCE per
     wave (global domain statistics don't belong in a chunk decode).
     ``axis_name`` is the shard_map node-shard axis ("sp") so the sharded
     cycle shares this decode: domain reductions cross shards while the
-    DomainView decode stays shard-local."""
+    DomainView decode stays shard-local.
+
+    Domain statistics are GLOBAL by semantics (a spread constraint's
+    min/max is over the whole cluster): built from the commit table,
+    never from the candidate view — an ownership mask or a window narrows
+    candidate selection, not the skew baseline, or shards would disagree
+    on feasibility.  Only the per-node count columns follow the window.
+
+    Part of the candidates stage wherever it is called from, under a
+    scope of its own inside that stage's (``candidates/cons_prologue``;
+    the per-pod statistics of ops/pallas_topk.fused_topk share it)."""
     from k8s1m_tpu.plugins import topology
 
-    view = table.domain_view() if is_packed(table) else table
-    return topology.prologue(view, constraints, axis_name=axis_name)
+    with jax.named_scope("candidates"), jax.named_scope("cons_prologue"):
+        view = table.domain_view() if is_packed(table) else table
+        return topology.prologue(view, constraints, axis_name=axis_name)
+
+
+# spread_max_skew of a constraint the wave counts itself: the candidates
+# stage's own test, on the wave-start counts, passes every count
+_NO_SKEW_LIMIT = 1 << 29
+
+
+def _counted_in_wave(batch: PodBatch):
+    """bool[B, S]: the pod's hard zone and region constraints, which
+    ``in_wave_skew`` re-checks in wave order (engine/assign.py)."""
+    return (
+        batch.spread_valid
+        & (batch.spread_mode == SPREAD_DO_NOT_SCHEDULE)
+        & (batch.spread_topo != TOPO_HOSTNAME)
+    )
+
+
+def wave_skew(batch: PodBatch, constraints: ConstraintState, stats) -> WaveSkew:
+    """greedy_assign's ``skew`` for one wave: the wave-start zone and
+    region count tables side by side, the domains that are there, and
+    the pods' constraint references and increments."""
+    return WaveSkew(
+        counts=jnp.concatenate(
+            [constraints.spread_zone, constraints.spread_region], axis=1
+        ),
+        present=jnp.concatenate(
+            [stats.zone_present, stats.region_present]
+        ) > 0,
+        zones=constraints.spread_zone.shape[1],
+        cid=batch.spread_cid, topo=batch.spread_topo,
+        max_skew=batch.spread_max_skew,
+        self_inc=batch.spread_self.astype(jnp.int32),
+        hard=_counted_in_wave(batch),
+        inc_valid=batch.sinc_valid, inc_cid=batch.sinc_cid,
+        inc_topo=batch.sinc_topo,
+    )
 
 
 def topk_by_argmax(prio, k: int):
@@ -284,6 +347,27 @@ def merge_topk(a: Candidates, b: Candidates, k: int) -> Candidates:
     return jax.tree.map(take, a, b).replace(prio=top_prio)
 
 
+def chunk_top_per_zone(prio, zone, k: int):
+    """The best priority of every zone id 0..k-1 over the chunk axis and
+    where it stands (first position wins a tie): ``(i32[B, k], i32[B, k])``,
+    -1 for a zone with no row in the chunk.  ``zone`` is the chunk's
+    ``[C]`` zone ids.  What ops/pallas_topk._merge_running_per_zone does
+    to one chunk."""
+    by_zone = jnp.where(
+        zone.astype(jnp.int32)[None, None, :]
+        == jnp.arange(k, dtype=jnp.int32)[None, :, None],
+        prio[:, None, :], -1,
+    )                                                           # [B, k, C]
+    return by_zone.max(axis=-1), jnp.argmax(by_zone, axis=-1).astype(jnp.int32)
+
+
+def merge_per_zone(a: Candidates, b: Candidates) -> Candidates:
+    """Slot for slot the better of two per-zone candidate sets; ``a``,
+    the earlier rows, wins a tie."""
+    keep = a.prio >= b.prio
+    return jax.tree.map(lambda xa, xb: jnp.where(keep, xa, xb), a, b)
+
+
 def empty_candidates(b: int, k: int) -> Candidates:
     zeros = jnp.zeros((b, k), jnp.int32)
     return Candidates(
@@ -306,8 +390,11 @@ def filter_score_topk(
     row_offset=0,
     pod_offset=0,
     stratum_bits: int = 0,
+    per_zone: bool = False,
 ) -> Candidates:
-    """Stream the node table in chunks, keeping each pod's top-k candidates.
+    """Stream the node table in chunks, keeping each pod's top-k candidates
+    or, with ``per_zone``, its best candidate of every zone id 0..k-1, in
+    zone order (``candidates()`` sorts them).
 
     ``row_offset`` biases emitted node rows — under shard_map each shard
     passes its global row offset so candidate indices stay global.  It
@@ -328,7 +415,7 @@ def filter_score_topk(
         # Single-device convenience: build the batch prologue here.  Under
         # shard_map callers MUST pass stats from topology.prologue(...,
         # axis_name=...) — the auto-built one would be shard-local.
-        stats = _prologue_stats(table, constraints)
+        stats = prologue_stats(table, constraints)
 
     # ONE scalar threefry draw per wave; per-element jitter comes from the
     # separable hash over (pod row, view-local node column) — the same
@@ -352,7 +439,10 @@ def filter_score_topk(
             + start + row_offset
         )
         prio = pack_hashed(score, seed, mask, pod_rows, node_cols, stratum_bits)
-        top_prio, idx = chunk_topk(prio, k)                     # [B, k]
+        if per_zone:
+            top_prio, idx = chunk_top_per_zone(prio, tchunk.zone, k)
+        else:
+            top_prio, idx = chunk_topk(prio, k)                 # [B, k]
         free_cpu, free_mem, free_pods = tchunk.free()
         local = Candidates(
             idx=(idx + start + row_offset).astype(jnp.int32),
@@ -363,7 +453,11 @@ def filter_score_topk(
             zone=jnp.take(tchunk.zone, idx),
             region=jnp.take(tchunk.region, idx),
         )
-        return (merge_topk(carry, local, k), ci + 1), None
+        merged = (
+            merge_per_zone(carry, local) if per_zone
+            else merge_topk(carry, local, k)
+        )
+        return (merged, ci + 1), None
 
     # NB: scan without an xs array — a `jnp.arange(num_chunks)` here gets
     # lifted to an executable constant, which the pjit fast-path cache
@@ -403,12 +497,16 @@ def finalize_batch(
     *,
     row_offset: int | jax.Array = 0,
     rows: int | None = None,
+    skew: WaveSkew | None = None,
 ):
     """Shared epilogue: greedy conflict resolution + capacity/constraint
     commit.  ``rows=None`` means the whole table is local (single device);
     otherwise only binds landing in [row_offset, row_offset+rows) update
     this shard's node-row tables, while zone/region count tables (replicated
-    in the sharded cycle) take the full global update.
+    in the sharded cycle) take the full global update.  ``skew``
+    (``wave_skew``) makes the conflict scan count the hard zone and region
+    spread constraints through the wave, and the Assignment say why pods
+    stayed unbound.
 
     Returns (table, constraints, Assignment).
 
@@ -416,15 +514,20 @@ def finalize_batch(
     ``commit``; ``candidates()`` carries the third), so a device trace
     can be read by phase whatever the ops under them become."""
     with jax.named_scope("assign"):
-        node_row, bound, score, chosen_k = greedy_assign(
+        node_row, bound, score, chosen_k, legal = greedy_assign(
             cand.idx, cand.prio, cand.cpu, cand.mem, cand.pods,
             fields.cpu, fields.mem, fields.valid,
+            skew, cand.zone, cand.region,
+        )
+        unbound = None if skew is None else unbound_by_reason(
+            bound, legal, cand.idx, cand.prio, fields.valid
         )
     take1 = lambda x: jnp.take_along_axis(x, chosen_k[:, None], axis=1)[:, 0]
     asg = Assignment(
         node_row=node_row, bound=bound, score=score,
         zone=jnp.where(bound, take1(cand.zone), 0),
         region=jnp.where(bound, take1(cand.region), 0),
+        unbound=unbound,
     )
     if rows is None:
         local = bound
@@ -493,6 +596,8 @@ def candidates(
     pod_offset=0,
     axis_name: str | None = None,
     stratum_bits: int = 0,
+    stats=None,
+    in_wave_skew: bool = False,
 ) -> Candidates:
     """The candidates stage of every full step — which rows are scanned,
     by which kernel, under which hash coordinates.  Every step shell
@@ -510,19 +615,32 @@ def candidates(
     ``pod_offset`` the shard's global bases (sharded_cycle.mesh_offsets).
     ``backend="pallas"`` is the fused kernel (ops/pallas_topk.py),
     constraint plugins included when ``constraints`` is passed;
-    ``with_affinity=False`` compiles its cheaper selector-free form."""
+    ``with_affinity=False`` compiles its cheaper selector-free form.
+    ``stats`` is ``prologue_stats(table, constraints)`` where the caller
+    has taken it already.
+
+    ``in_wave_skew`` (one device, ``k`` = ``TableSpec.max_zones``) hands
+    the hard zone and region constraints over to the in-wave count
+    (``wave_skew`` + engine/assign.py): here they lose their skew test
+    against the wave-start counts (a node still has to carry the label),
+    and a pod's candidates are the best row of every zone, sorted by
+    priority, so that the zone that is legal at its turn has one."""
     src = table if src is None else src
+    if constraints is not None and stats is None:
+        stats = prologue_stats(table, constraints, axis_name)
     with jax.named_scope("candidates"):
-        # Domain statistics are GLOBAL by semantics (a spread constraint's
-        # min/max is over the whole cluster): built from the commit table,
-        # never from the candidate view — an ownership mask or a window
-        # narrows candidate selection, not the skew baseline, or shards
-        # would disagree on feasibility.  Only the per-node count columns
-        # follow the window.
-        stats = (
-            _prologue_stats(table, constraints, axis_name)
-            if constraints is not None else None
-        )
+        if in_wave_skew:
+            if constraints is None or axis_name is not None or (
+                k != constraints.spread_zone.shape[1]
+            ):
+                raise ValueError(
+                    "in_wave_skew takes constraint state, one device and "
+                    "one candidate a zone id: k = TableSpec.max_zones "
+                    f"(k={k})"
+                )
+            batch = batch.replace(spread_max_skew=jnp.where(
+                _counted_in_wave(batch), _NO_SKEW_LIMIT, batch.spread_max_skew
+            ))
         view, view_cons, remap = src, constraints, None
         if window is not None:
             offset, rows = window
@@ -555,19 +673,26 @@ def candidates(
                 row_offset=row_offset, pod_offset=pod_offset,
                 with_affinity=with_affinity,
                 constraints=view_cons, stats=stats,
-                stratum_bits=stratum_bits,
+                stratum_bits=stratum_bits, per_zone=in_wave_skew,
             )
         else:
             cand = filter_score_topk(
                 view, batch, key, profile,
                 chunk=chunk, k=k, constraints=view_cons, stats=stats,
                 row_offset=row_offset, pod_offset=pod_offset,
-                stratum_bits=stratum_bits,
+                stratum_bits=stratum_bits, per_zone=in_wave_skew,
             )
         if remap is not None:
             cand = cand.replace(
                 idx=jnp.where(cand.idx >= 0, cand.idx + remap, -1)
             )
+        if in_wave_skew:
+            # zone order -> priority order (the lower zone id wins a tie):
+            # greedy_assign takes the first candidate that is ok
+            top_prio, sel = lax.top_k(cand.prio, k)
+            cand = jax.tree.map(
+                lambda x: jnp.take_along_axis(x, sel, axis=-1), cand
+            ).replace(prio=top_prio)
     return cand
 
 
@@ -584,20 +709,30 @@ def _schedule_batch_impl(
     src: NodeTable | None = None,
     stratum_bits: int = 0,
     window=None,
+    in_wave_skew: bool = False,
 ):
+    stats = skew = None
+    if in_wave_skew and constraints is not None:    # candidates() refuses None
+        # the candidates stage's prologue, taken here: the in-wave count
+        # starts from the same tables and the same present domains
+        stats = prologue_stats(table, constraints)
+        skew = wave_skew(batch, constraints, stats)
     cand = candidates(
         table, batch, key, constraints, profile, chunk=chunk, k=k,
         backend=backend, with_affinity=with_affinity, src=src,
-        window=window, stratum_bits=stratum_bits,
+        window=window, stratum_bits=stratum_bits, stats=stats,
+        in_wave_skew=in_wave_skew,
     )
-    return finalize_batch(table, constraints, cand, commit_fields_of(batch))
+    return finalize_batch(
+        table, constraints, cand, commit_fields_of(batch), skew=skew
+    )
 
 
 @functools.lru_cache(maxsize=64)
 def _jitted_schedule(
     profile: Profile, chunk: int, k: int, with_constraints: bool,
     backend: str = "xla", with_affinity: bool = True,
-    stratum_bits: int = 0,
+    stratum_bits: int = 0, in_wave_skew: bool = False,
 ):
     # One jax.jit function object per static configuration.  Routing every
     # configuration through a single jitted function trips a pjit fast-path
@@ -608,6 +743,7 @@ def _jitted_schedule(
         fn = lambda table, batch, key, constraints: _schedule_batch_impl(
             table, batch, key, constraints, profile, chunk, k, backend,
             with_affinity=with_affinity, stratum_bits=stratum_bits,
+            in_wave_skew=in_wave_skew,
         )
     else:
         fn = lambda table, batch, key: _schedule_batch_impl(
@@ -632,6 +768,7 @@ def schedule_batch(
     backend: str = "xla",
     with_affinity: bool = True,
     stratum_bits: int = 0,
+    in_wave_skew: bool = False,
 ):
     """Schedule one pod batch end-to-end on a single device.
 
@@ -645,11 +782,14 @@ def schedule_batch(
     ``with_affinity=False`` compiles the cheaper selector-free kernel;
     pass it only when the caller knows no pod in the batch carries
     nodeSelector/affinity terms (the packed path derives this per wave
-    from the field groups).
+    from the field groups).  ``in_wave_skew`` (with ``constraints``,
+    ``k`` = ``TableSpec.max_zones``): the hard zone and region spread
+    constraints hold at every bind of the wave, in wave order (module
+    doc), and ``Assignment.unbound`` says why pods stayed unbound.
     """
     step = _jitted_schedule(
         profile, chunk, k, constraints is not None, backend, with_affinity,
-        stratum_bits,
+        stratum_bits, in_wave_skew,
     )
     if constraints is None:
         table, cons, asg = step(table, batch, key)
@@ -696,6 +836,7 @@ def _jitted_schedule_packed(
     backend: str, pod_spec, table_spec, groups: frozenset,
     sample_rows: int | None, with_mask: bool = False,
     donate: bool = False, stratum_bits: int = 0,
+    in_wave_skew: bool = False,
 ):
     from k8s1m_tpu.snapshot.pod_encoding import unpack_pod_batch
 
@@ -711,6 +852,7 @@ def _jitted_schedule_packed(
             src=None if row_mask is None else mask_rows(table, row_mask),
             stratum_bits=stratum_bits,
             window=None if sample_rows is None else (offset, sample_rows),
+            in_wave_skew=in_wave_skew,
         )
         # One fetchable result array: the bound node row per pod, -1 for
         # unbound.  Every device_get is a device->host sync; the
@@ -764,6 +906,7 @@ def schedule_batch_packed(
     mesh=None,
     donate: bool = False,
     stratum_bits: int = 0,
+    in_wave_skew: bool = False,
 ):
     """schedule_batch over a PackedPodBatch: the pod features cross the
     host->device boundary as two buffers and the bind decision comes back
@@ -807,11 +950,15 @@ def schedule_batch_packed(
     both backends, and binds are byte-identical to the unpacked layout
     (tests/test_packing.py differential gate).
 
+    ``in_wave_skew``: as ``schedule_batch``'s; one device only.
+
     Returns (new_table, new_constraints, Assignment, rows).
     """
     if mesh is not None:
         if row_mask is not None:
             raise ValueError("mesh and row_mask are mutually exclusive")
+        if in_wave_skew:
+            raise ValueError("in_wave_skew does not compose with mesh sharding")
         from k8s1m_tpu.parallel.sharded_cycle import make_sharded_packed_step
 
         step = make_sharded_packed_step(
@@ -829,7 +976,7 @@ def schedule_batch_packed(
     step = _jitted_schedule_packed(
         profile, chunk, k, constraints is not None, backend,
         packed.spec, packed.table_spec, packed.groups, sample_rows,
-        row_mask is not None, donate, stratum_bits,
+        row_mask is not None, donate, stratum_bits, in_wave_skew,
     )
     offset = np.int32(sample_offset)
     args = (table, packed.ints, packed.bools, key, offset)

@@ -33,9 +33,11 @@ on v5e: every score above 255 came back off by a few units), so every
 dot that carries a wide value asks for ``_EXACT`` (fp32 contraction);
 the taint dots carry only 0/1 and counts <= taint_slots and stay on the
 fast default.
-Constraint plugins (PodTopologySpread, InterPodAffinity) stay on the XLA
-path — their count-table state doesn't fit the stateless-kernel mold;
-the engine picks the backend per batch (engine/cycle.py schedule_batch).
+Constraint plugins (PodTopologySpread, InterPodAffinity) run fused too
+when the caller passes the count tables and their prologue (the
+``with_cons`` stage below, compiled as ``fused_topk_constraints``); with
+``per_zone`` that variant keeps the best row of every zone instead of
+the k best rows, for the in-wave skew count of engine/assign.py.
 
 **Size the PodSpec slot dims to the workload.** The affinity stage
 unrolls one evaluation per selector slot (aff_exprs + aff_terms*aff_exprs
@@ -154,8 +156,12 @@ def _kernel(
     with_cons: bool,
     pack: tuple | None = None,
     stratum_bits: int = 0,
+    per_zone: bool = False,
 ):
-    """Base refs (always):
+    """``per_zone`` (with_cons only): slot z of the K outputs is the best
+    row of zone id z, not the z-th best row (_merge_running_per_zone).
+
+    Base refs (always):
         seed_ref   i32[1, 3] SMEM — (seed, pod hash base, node hash base)
         cpu_alloc, mem_alloc, pods_alloc,
         cpu_req, mem_req, pods_req, name_id   i32[1, C]
@@ -611,9 +617,52 @@ def _kernel(
         -1,
     )
 
-    _merge_running_topk(
-        prio, cols, k, c_i, run_prio, run_idx, out_prio, out_idx
-    )
+    if per_zone:
+        _merge_running_per_zone(
+            prio, c_i * chunk, zone_c[:], k, c_i, run_prio, run_idx,
+            out_prio, out_idx,
+        )
+    else:
+        _merge_running_topk(
+            prio, cols, k, c_i, run_prio, run_idx, out_prio, out_idx
+        )
+
+
+def _merge_running_per_zone(prio, base, zone_row, k, c_i, run_prio,
+                            run_idx, out_prio, out_idx):
+    """Merge one chunk's [TB, C] priorities into the running best row of
+    every zone id 0..K-1 (``zone_row`` i32[1, C], the chunk's zone ids;
+    ``base`` the chunk's first row): one masked max-extract a zone, its
+    result put into lane z of the 128-wide running list by a lane select
+    (no slice, no concat: every shape stays lane-aligned).  A pod whose
+    spread constraint the wave re-checks in order (engine/assign.py)
+    needs a candidate in whatever zone is legal at its turn, which no
+    count taken when the wave began can name; the best row of each zone
+    covers them all.  The running entry wins a tie and within the chunk
+    the first position does, as in _merge_running_topk: the earlier row
+    wins."""
+    tb, c = prio.shape
+    pos_iota = lax.broadcasted_iota(jnp.int32, (tb, c), 1)
+    lane = lax.broadcasted_iota(jnp.int32, (tb, 128), 1)
+    new_p = run_prio[:]                                           # [TB, 128]
+    new_i = run_idx[:]
+    for z in range(k):
+        m = jnp.where(zone_row == z, prio, -1)                    # [TB, C]
+        mx = jnp.max(m, axis=1, keepdims=True)                    # [TB, 1]
+        pos = jnp.min(
+            jnp.where(m == mx, pos_iota, c), axis=1, keepdims=True
+        )
+        better = (lane == z) & (new_p < mx)
+        new_p = jnp.where(better, mx, new_p)
+        new_i = jnp.where(better, base + pos, new_i)
+    run_prio[:] = new_p
+    run_idx[:] = new_i
+    last = pl.num_programs(1) - 1
+
+    @pl.when(c_i == last)
+    def _():
+        out_prio[:] = new_p[:, :k]
+        out_idx[:] = new_i[:, :k]
 
 
 def _merge_running_topk(prio, cols, k, c_i, run_prio, run_idx,
@@ -663,6 +712,7 @@ def _merge_running_topk(prio, cols, k, c_i, run_prio, run_idx,
     static_argnames=(
         "chunk", "k", "w_la", "w_ba", "w_tt", "w_na", "w_ts", "w_ipa",
         "with_aff", "with_cons", "interpret", "pack", "stratum_bits",
+        "per_zone",
     ),
 )
 def _call(
@@ -686,6 +736,7 @@ def _call(
     interpret: bool,
     pack: tuple | None = None,
     stratum_bits: int = 0,
+    per_zone: bool = False,
 ):
     n = cpu_alloc.shape[0]
     b = p_cpu.shape[0]
@@ -800,13 +851,14 @@ def _call(
         _kernel, chunk=chunk, k=k,
         w_la=w_la, w_ba=w_ba, w_tt=w_tt, w_na=w_na, w_ts=w_ts, w_ipa=w_ipa,
         with_aff=with_aff, with_cons=with_cons, pack=pack,
-        stratum_bits=stratum_bits,
+        stratum_bits=stratum_bits, per_zone=per_zone,
     )
     idx, prio = pl.pallas_call(
         kernel,
         # The kernel's name in HLO and in a device trace; the affinity
-        # variant is a different (and far dearer) program.
-        name="fused_topk_affinity" if with_aff else "fused_topk",
+        # and the constraint variants are different (and far dearer)
+        # programs, each read under a name of its own.
+        name="fused_topk" + "_affinity" * with_aff + "_constraints" * with_cons,
         grid=grid,
         in_specs=in_specs,
         out_specs=(out, out),
@@ -841,6 +893,7 @@ def fused_topk(
     row_base=0,
     col_base=0,
     stratum_bits: int = 0,
+    per_zone: bool = False,
 ):
     """(idx i32[B,K], prio i32[B,K]) — global-row candidates, -1 = none.
 
@@ -860,10 +913,19 @@ def fused_topk(
     materializes [max_zones, chunk] one-hot planes in VMEM and unrolls
     one evaluation per ref slot, so worst-case schema dims cost real
     VMEM and compile time (same rule as the affinity slots).
+    ``per_zone`` (constraints only, ``k`` = the zone table's width):
+    candidate slot z is the best row of zone id z, in zone order and not
+    in priority order (_merge_running_per_zone).
     ``interpret=None`` auto-selects interpreter mode off-TPU so the same
     tests run on the CPU mesh.
     """
     with_cons = constraints is not None
+    if per_zone and not (
+        with_cons and k == constraints.spread_zone.shape[1] <= 128
+    ):
+        raise ValueError(
+            "per_zone candidates take constraints and k = max_zones <= 128"
+        )
     if with_cons and stats is None:
         raise ValueError(
             "constraints require stats=topology.prologue(table, constraints)"
@@ -920,50 +982,53 @@ def fused_topk(
     if with_cons:
         from k8s1m_tpu.plugins import topology as topo
 
-        i32 = jnp.int32
-        b = batch.batch
-        sp_min = topo._stat_for(
-            stats.spread_min, batch.spread_cid, batch.spread_topo
-        )
-        sp_max = topo._stat_for(
-            stats.spread_max, batch.spread_cid, batch.spread_topo
-        )
-        sp_hard = (
-            batch.spread_valid & (batch.spread_mode == SPREAD_DO_NOT_SCHEDULE)
-        )
-        total = jnp.take(stats.tgt_total, batch.ipa_tid)
-        boot = (total == 0) & batch.ipa_self
-        reqaff = batch.ipa_valid & batch.ipa_required & ~batch.ipa_anti
-        reqanti = batch.ipa_valid & batch.ipa_required & batch.ipa_anti
-        pref = batch.ipa_valid & ~batch.ipa_required
-        prefsign = jnp.where(
-            pref, jnp.where(batch.ipa_anti, -1, 1) * batch.ipa_weight, 0
-        )
-        bound = (
-            jnp.abs(batch.ipa_weight)
-            * jnp.take(stats.tgt_max, batch.ipa_tid)
-            * pref
-        ).sum(axis=1)
-        cons_pod = [
-            batch.spread_cid, batch.spread_topo, batch.spread_max_skew,
-            sp_hard, batch.spread_valid, batch.spread_self, sp_min, sp_max,
-            batch.ipa_tid, batch.ipa_topo, reqaff, reqanti, boot, prefsign,
-            batch.iinc_tid, batch.iinc_topo, batch.iinc_valid,
-            jnp.maximum(bound, 1).reshape(b, 1),
-            pref.any(axis=1).reshape(b, 1),
-            jnp.maximum(batch.spread_valid.sum(axis=1), 1).reshape(b, 1),
-        ]
-        c = constraints
-        cons_args = (
-            # Packed layout: the constraint stage's one-hot domain planes
-            # need i32 ids (two full-column casts per wave, fused by XLA).
-            table.zone.astype(i32), table.region.astype(i32),
-            c.spread_node.astype(i32), c.tgt_node.astype(i32),
-            c.own_node.astype(i32),
-            c.spread_zone, c.spread_region, c.tgt_zone, c.tgt_region,
-            c.own_zone, c.own_region,
-            cons_pod,
-        )
+        # Per-pod statistics of the batch prologue: read from a trace under
+        # the scope engine.cycle.candidates opens for the prologue itself.
+        with jax.named_scope("cons_prologue"):
+            i32 = jnp.int32
+            b = batch.batch
+            sp_min = topo._stat_for(
+                stats.spread_min, batch.spread_cid, batch.spread_topo
+            )
+            sp_max = topo._stat_for(
+                stats.spread_max, batch.spread_cid, batch.spread_topo
+            )
+            sp_hard = (
+                batch.spread_valid & (batch.spread_mode == SPREAD_DO_NOT_SCHEDULE)
+            )
+            total = jnp.take(stats.tgt_total, batch.ipa_tid)
+            boot = (total == 0) & batch.ipa_self
+            reqaff = batch.ipa_valid & batch.ipa_required & ~batch.ipa_anti
+            reqanti = batch.ipa_valid & batch.ipa_required & batch.ipa_anti
+            pref = batch.ipa_valid & ~batch.ipa_required
+            prefsign = jnp.where(
+                pref, jnp.where(batch.ipa_anti, -1, 1) * batch.ipa_weight, 0
+            )
+            bound = (
+                jnp.abs(batch.ipa_weight)
+                * jnp.take(stats.tgt_max, batch.ipa_tid)
+                * pref
+            ).sum(axis=1)
+            cons_pod = [
+                batch.spread_cid, batch.spread_topo, batch.spread_max_skew,
+                sp_hard, batch.spread_valid, batch.spread_self, sp_min, sp_max,
+                batch.ipa_tid, batch.ipa_topo, reqaff, reqanti, boot, prefsign,
+                batch.iinc_tid, batch.iinc_topo, batch.iinc_valid,
+                jnp.maximum(bound, 1).reshape(b, 1),
+                pref.any(axis=1).reshape(b, 1),
+                jnp.maximum(batch.spread_valid.sum(axis=1), 1).reshape(b, 1),
+            ]
+            c = constraints
+            cons_args = (
+                # Packed layout: the constraint stage's one-hot domain planes
+                # need i32 ids (two full-column casts per wave, fused by XLA).
+                table.zone.astype(i32), table.region.astype(i32),
+                c.spread_node.astype(i32), c.tgt_node.astype(i32),
+                c.own_node.astype(i32),
+                c.spread_zone, c.spread_region, c.tgt_zone, c.tgt_region,
+                c.own_zone, c.own_region,
+                cons_pod,
+            )
     else:
         cons_args = ()
     return _call(
@@ -993,6 +1058,7 @@ def fused_topk(
         interpret=interpret,
         pack=pack,
         stratum_bits=stratum_bits,
+        per_zone=per_zone,
     )
 
 
@@ -1016,6 +1082,7 @@ def pallas_candidates(
     stats=None,
     interpret: bool | None = None,
     stratum_bits: int = 0,
+    per_zone: bool = False,
 ):
     """Drop-in for engine.filter_score_topk.
 
@@ -1033,7 +1100,7 @@ def pallas_candidates(
         chunk=chunk, k=k, with_affinity=with_affinity,
         constraints=constraints, stats=stats, interpret=interpret,
         row_base=pod_offset, col_base=row_offset,
-        stratum_bits=stratum_bits,
+        stratum_bits=stratum_bits, per_zone=per_zone,
     )
     safe = jnp.clip(idx, 0)
     free_cpu, free_mem, free_pods = table.free()

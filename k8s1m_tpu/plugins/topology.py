@@ -58,6 +58,11 @@ class TopoStats:
     spread_max: jax.Array   # i32[3, C]
     tgt_max: jax.Array      # i32[A] max count over the term's domains
     tgt_total: jax.Array    # i32[A] total matching pods cluster-wide
+    # i32[Z] / i32[R]: 1 where a zone / region holds a valid node (id 0,
+    # "label missing", never): the domains spread_min ranges over, kept
+    # for the in-wave skew count (engine/assign.py).
+    zone_present: jax.Array
+    region_present: jax.Array
 
 
 def _domain_presence(table: NodeTable, size: int, ids, axis_name=None):
@@ -127,6 +132,7 @@ def prologue(
     return TopoStats(
         spread_min=spread_min, spread_max=spread_max,
         tgt_max=tgt_max, tgt_total=tgt_total,
+        zone_present=zone_present, region_present=region_present,
     )
 
 

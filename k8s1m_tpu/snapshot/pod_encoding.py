@@ -130,6 +130,11 @@ class PodInfo:
     # non-canonical (the native fast lane is for the plain-pod
     # firehose; priority-bearing pods take the full decode path).
     priority: int = 0
+    # spec.topologySpreadConstraints as written (label selectors and all):
+    # what encode_pod puts on the wire.  spread_refs above is the same
+    # thing compiled against one coordinator's tracker, and only decode
+    # fills it.
+    topology_spread: list[dict] = dataclasses.field(default_factory=list)
 
     @property
     def key(self) -> str:

@@ -28,13 +28,20 @@ def build_pod(
     cpu_milli: int = 100,
     mem_kib: int = 200 << 10,
     tolerate_kwok: bool = True,
+    app: str | None = None,
+    spread_constraints: list | None = None,
 ) -> PodInfo:
+    """``app`` is the value of the pod's ``app`` label (default: the
+    prefix, as upstream's make_pods labels its pods); ``spread_constraints``
+    the pod's raw ``spec.topologySpreadConstraints`` (a Deployment's
+    template carries them, each selecting its own ``app``)."""
     return PodInfo(
         name=f"{prefix}-{i}",
         namespace=namespace,
         cpu_milli=cpu_milli,
         mem_kib=mem_kib,
-        labels={"app": prefix},
+        labels={"app": prefix if app is None else app},
+        topology_spread=[dict(c) for c in spread_constraints or ()],
         # The reference's pods tolerate the kwok taint
         # (make_pods/main.go sets tolerations for kwok.x-k8s.io/node).
         tolerations=(

@@ -97,6 +97,7 @@ from k8s1m_tpu.config import DEFAULT_SCHEDULER, PodSpec, TableSpec
 from k8s1m_tpu.faultline import RetryPolicy, note_give_up, note_retry, policy_for
 from k8s1m_tpu.lint import THREAD_OWNER, guarded_by, racy_read
 from k8s1m_tpu.control.objects import (
+    bind_pod_constraints,
     decode_node,
     decode_pod,
     decode_pod_fast,
@@ -211,7 +212,8 @@ _DECODE_ERRORS = Counter(
 )
 # Counted where the intake forks (_apply_pod_batch, _on_pod_put), one
 # inc per lane per batch: batch_fast = a whole poll of canonical pending
-# pods taken column-wise, labels and tolerations by their interned shape;
+# pods taken column-wise, labels, tolerations and spread constraints by
+# their interned shape;
 # canonical = per event, parsed natively (shaped or not); decode_fast /
 # json = a put the native parser did not take (or a watcher without
 # poll_pods) through decode_pod_fast or json.loads + decode_pod_obj;
@@ -240,12 +242,14 @@ _WAVE_UNBOUND = Counter(
     "Valid pods a wave left unbound, by why", ("reason",),
 )
 # Once per frame, never per pod: pods taken natively over `interned` is
-# the shape table's hit share.
+# the shape table's hit share, over `bound` the binding cache's.
 _POD_SHAPES = Counter(
     "coordinator_pod_shapes_total",
-    "Distinct (label map, toleration list) byte spans of natively parsed "
-    "pods decoded into the intake's shape table (interned), and resets of "
-    "the full table (evicted)", ("event",),
+    "Distinct (label map, toleration list, spread constraints) byte spans "
+    "of natively parsed pods decoded into the intake's shape table "
+    "(interned), resets of the full table (evicted), and shapes bound to "
+    "a namespace and a state of the constraint tracker (bound)",
+    ("event",),
 )
 # Bound of the shape table, like _gang_oversize's: a stream of unique
 # label sets degrades to one decode a pod (the JSON lane's cost), and
@@ -415,22 +419,35 @@ _BIND_LATENCY = Histogram(
 
 class PodShape:
     """What the pods of one template share, decoded once per distinct
-    (label map, toleration list) byte span of the native parser's frame
-    and not once per pod: exactly what the JSON lane's PodInfo would hold
-    of it.  Immutable after construction (the hotfeed worker reads it)."""
+    (label map, toleration list, spread constraints) byte span of the
+    native parser's frame and not once per pod: exactly what the JSON
+    lane's PodInfo would hold of it.  As decoded it is bound to no
+    tracker; ``bind`` gives the shape the pods of one namespace refer to,
+    with the constraint slots and increments the JSON lane would have
+    set on each of them.  Immutable after construction (the hotfeed
+    worker reads it)."""
 
-    __slots__ = ("labels", "tolerations", "scheduler_name", "fp", "gang",
-                 "tenant")
+    __slots__ = ("labels", "tolerations", "topology_spread",
+                 "scheduler_name", "spread_refs", "spread_incs", "ipa_incs",
+                 "fp", "coupled", "keeps", "gang", "tenant")
 
-    def __init__(self, labels: dict, tolerations: list,
-                 scheduler_name: str) -> None:
+    def __init__(self, labels: dict, tolerations: list, topology_spread: list,
+                 scheduler_name: str, spread_refs: tuple = (),
+                 spread_incs: tuple = (), ipa_incs: tuple = ()) -> None:
         self.labels = labels
         self.tolerations = tolerations
+        self.topology_spread = topology_spread
         self.scheduler_name = scheduler_name
-        # hotfeed.fingerprint of every pod of the shape that carries no
-        # constraint increments (labels are not structural; PLAIN for a
-        # shape of labels alone).
+        self.spread_refs = spread_refs
+        self.spread_incs = spread_incs
+        self.ipa_incs = ipa_incs
+        # hotfeed.fingerprint of every pod that refers to this shape
+        # (labels are not structural; PLAIN for a shape of labels alone).
         self.fp = fingerprint(self.pod("/", 0, 0))
+        # Its plane reads the live count tables (hotfeed.shape_key).
+        self.coupled = bool(spread_refs or spread_incs or ipa_incs)
+        # A bound pod's record keeps its PodInfo (_constraintful).
+        self.keeps = bool(spread_incs or ipa_incs)
         # Whether the labels name a gang (tenancy/policy.gang_of_labels).
         self.gang = gang_of_labels(labels, "") is not None
         # The tenant label's override, None = the namespace is the tenant.
@@ -443,6 +460,35 @@ class PodShape:
             name=name, namespace=ns, cpu_milli=cpu_milli, mem_kib=mem_kib,
             scheduler_name=self.scheduler_name, node_name=node_name,
             tolerations=list(self.tolerations), labels=dict(self.labels),
+            topology_spread=list(self.topology_spread),
+            spread_refs=list(self.spread_refs),
+            spread_incs=list(self.spread_incs), ipa_incs=list(self.ipa_incs),
+        )
+
+    def bind(self, namespace: str, tracker: ConstraintTracker) -> "PodShape":
+        """The shape of this template's pods in ``namespace`` as
+        ``tracker`` stands once they have registered their constraints:
+        the JSON lane's own code on one pod of it (this shape itself
+        where that sets nothing).  Raises what decode_pod_obj raises."""
+        pod = PodInfo(
+            name="", namespace=namespace, labels=self.labels,
+            topology_spread=self.topology_spread,
+        )
+        bind_pod_constraints(pod, tracker)
+        if not (pod.spread_refs or pod.spread_incs or pod.ipa_incs):
+            return self
+        return PodShape(
+            self.labels, self.tolerations, self.topology_spread,
+            self.scheduler_name, tuple(pod.spread_refs),
+            tuple(pod.spread_incs), tuple(pod.ipa_incs),
+        )
+
+    def same_binding(self, other) -> bool:
+        """Whether ``other`` is a shape that says of the tracker what
+        this one says (two bindings of one template)."""
+        return isinstance(other, PodShape) and (
+            (self.spread_refs, self.spread_incs, self.ipa_incs)
+            == (other.spread_refs, other.spread_incs, other.ipa_incs)
         )
 
 
@@ -480,9 +526,11 @@ class PendingPod:
     # declared size; "" / 0 = not a gang pod.
     gang_id: str = ""
     gang_size: int = 0
-    # Labels and tolerations of a native fast-lane pod, interned per
-    # template; None = it has neither.  Read only while ``pod`` is None:
-    # a materialized or re-decoded PodInfo supersedes it.
+    # What a native fast-lane pod holds beyond its scalars — labels,
+    # tolerations, spread constraints and the tracker's increments —
+    # interned per template and namespace (Coordinator._bound_shape);
+    # None = it has none of them.  Read only while ``pod`` is None: a
+    # materialized or re-decoded PodInfo supersedes it.
     shape: PodShape | None = None
 
     def peek_pod(self) -> PodInfo:
@@ -1134,14 +1182,16 @@ class Coordinator:
         self._retry_rng = random.Random(seed ^ 0xFA017)
         self._sched_bytes = scheduler_name.encode()
         self._name_bytes: list[bytes] = []
-        # Per-namespace tracker matches for the EMPTY label set, keyed by
-        # the tracker's registration counts (registration only grows).
-        # Label-less pods can still match constraints whose selector is
-        # empty; the fast lane must not lose those.
-        self._empty_incs_cache: dict[tuple[int, int, str], tuple] = {}
-        # (label span, toleration span) of natively parsed pods -> their
-        # PodShape (_frame_shapes); at most POD_SHAPES_MAX entries.
-        self._pod_shapes: dict[tuple[bytes, bytes], PodShape] = {}
+        # (label span, toleration span, spread span) of natively parsed
+        # pods -> their PodShape (_frame_shapes); at most POD_SHAPES_MAX
+        # entries.
+        self._pod_shapes: dict[tuple[bytes, bytes, bytes], PodShape] = {}
+        # (shape, namespace) -> (the tracker's registration counts, the
+        # shape bound at them) (_bound_shape).  The shape None is the
+        # label-less pod's, which can still match a constraint whose
+        # selector is empty: the fast lane must not lose those.
+        self._bare_shape = PodShape({}, [], [], scheduler_name)
+        self._shape_bindings: dict[tuple, tuple] = {}
         # Webhook-intake staging: appended from server threads, drained
         # into the queue at the top of each cycle (deque+set aren't
         # thread-safe to mutate from the handler directly).
@@ -1674,10 +1724,11 @@ class Coordinator:
     def _frame_shapes(self, evb) -> list:
         """The PodShape of every entry of one frame's shape table, at the
         index the frame's events name it by (0 = None: no labels, no
-        tolerations).  A span pair not seen before is decoded by the JSON
-        lane's own code (objects.decode_pod_shape); one that cannot be
-        decoded is False, and its pods count as decode errors just as
-        _on_pod_put would have counted them."""
+        tolerations, no spread constraints).  A span triple not seen
+        before is decoded by the JSON lane's own code
+        (objects.decode_pod_shape); one that cannot be decoded is False,
+        and its pods count as decode errors just as _on_pod_put would
+        have counted them."""
         shapes: list = [None]
         table = self._pod_shapes
         interned = 0
@@ -1689,7 +1740,7 @@ class Coordinator:
                         *decode_pod_shape(*spans), self.scheduler_name
                     )
                 except Exception:
-                    log.exception("undecodable pod labels/tolerations")
+                    log.exception("undecodable pod shape")
                     sh = False
                 else:
                     if len(table) >= POD_SHAPES_MAX:
@@ -1702,13 +1753,52 @@ class Coordinator:
             _POD_SHAPES.inc(interned, event="interned")
         return shapes
 
+    def _bound_shape(self, shape: PodShape | None, namespace: str):
+        """``shape`` (None = the label-less pod's) as the pods of
+        ``namespace`` refer to it: bound to the tracker (PodShape.bind)
+        once per registration state and not once per pod.  An entry
+        holds the tracker's registration counts, which only grow, so a
+        constraint registered since — by this template or any other —
+        reaches the template's next pod, as the JSON lane's per-pod
+        decode would have it.  None = nothing but scalars to carry;
+        False = the JSON lane would have refused the pod (an unsupported
+        topologyKey, a full slot pool).  Cycle thread only: binding
+        registers the template's own constraints."""
+        tr = self.tracker
+        cache = self._shape_bindings
+        key = (shape, namespace)
+        entry = cache.get(key)
+        if entry is not None and entry[0] == (len(tr._spread), len(tr._affinity)):
+            return entry[1]
+        try:
+            bound = (shape or self._bare_shape).bind(namespace, tr)
+        except Exception:
+            log.exception("pod shape refused by the constraint tracker")
+            bound = False
+        else:
+            if bound is self._bare_shape:
+                bound = None
+            elif entry is not None and bound and bound.same_binding(entry[1]):
+                # The registrations since changed nothing for it: its
+                # pods go on referring to one shape, one fingerprint.
+                bound = entry[1]
+        if len(cache) >= 1024:
+            # Bounded like _gang_oversize: namespaces and templates churn
+            # on long soaks.  Clearing just binds a live one once more.
+            cache.clear()
+        # With the counts as binding left them: what the template's next
+        # pod compares.
+        cache[key] = ((len(tr._spread), len(tr._affinity)), bound)
+        _POD_SHAPES.inc(event="bound")
+        return bound
+
     def _apply_pod_batch(self, evb) -> None:
         """Apply one columnar poll_pods drain (store/native.py
         PodEventBatch).  Flag semantics decided natively: CANONICAL means
         the C parser accepted the exact encode_pod shape (scalars, plus a
-        label map and a toleration list that arrive as the index of an
-        interned PodShape); everything else falls back to _on_pod_put's
-        full decode."""
+        label map, a toleration list and spread constraints that arrive
+        as the index of an interned PodShape); everything else falls back
+        to _on_pod_put's full decode."""
         plen = len(PODS_PREFIX)
         koff = evb.koff.tolist()
         kb = evb.key_blob
@@ -1723,7 +1813,9 @@ class Coordinator:
         tracer = self._tracer
         tr_on = tracer.enabled
         tr = self.tracker
-        has_constraints = bool(tr._spread or tr._affinity)
+        # Whether a pod's shape depends on the tracker: a constraint is
+        # registered, or a shape of this frame is about to register one.
+        binding = bool(tr._spread or tr._affinity)
         tn = self.tenancy
         gangs_on = tn is not None and tn.policy.gang_enabled
         if evb.shapes:
@@ -1734,10 +1826,14 @@ class Coordinator:
             shapes_columnar = all(
                 sh and not (gangs_on and sh.gang) for sh in shapes[1:]
             )
+            binding = binding or any(
+                sh and sh.topology_spread for sh in shapes[1:]
+            )
         else:
             shape_l = [None] * evb.n
             shapes_columnar = True
-        if fastmask.all() and not has_constraints and shapes_columnar:
+        bound_shape = self._bound_shape
+        if fastmask.all() and shapes_columnar:
             # Pure create wave (the make_pods steady state): one batched
             # tolist per column, no per-event branching.
             cpu_l = evb.cpu.tolist()
@@ -1749,13 +1845,26 @@ class Coordinator:
             filt = self.intake_filter
             # Keys are ASCII but for the odd name: decoded in one piece,
             # byte offsets are then string offsets too.
-            ka = kb.decode() if kb.isascii() else None
-            for i in range(evb.n):
-                lo, hi = koff[i], koff[i + 1]
-                ks = (
-                    ka[lo + plen : hi] if ka is not None
-                    else kb[lo + plen : hi].decode()
-                )
+            spans = zip(koff, koff[1:])
+            if kb.isascii():
+                ka = kb.decode()
+                keys = [ka[lo + plen : hi] for lo, hi in spans]
+            else:
+                keys = [kb[lo + plen : hi].decode() for lo, hi in spans]
+            todo = range(evb.n)
+            if binding:
+                # In frame order, and ahead of the checks below as the
+                # JSON lane decodes (and registers) ahead of them.
+                shape_l = [
+                    bound_shape(sh, ks.partition("/")[0])
+                    for sh, ks in zip(shape_l, keys)
+                ]
+                refused = sum(sh is False for sh in shape_l)
+                if refused:
+                    _DECODE_ERRORS.inc(refused, kind="pod")
+                    todo = [i for i in todo if shape_l[i] is not False]
+            for i in todo:
+                ks = keys[i]
                 if ks in queued or ks in bound:
                     continue
                 if filt is not None and not filt(ks):
@@ -1763,7 +1872,7 @@ class Coordinator:
                 queued.add(ks)
                 q.append(PendingPod(
                     None, mrev_l[i], now, cpu_l[i], mem_l[i], ks,
-                    key_bytes=kb[lo:hi], shape=shape_l[i],
+                    key_bytes=kb[koff[i] : koff[i + 1]], shape=shape_l[i],
                 ))
                 if tr_on:
                     tracer.begin(ks, now, source="intake")
@@ -1788,12 +1897,14 @@ class Coordinator:
                 slow += 1
                 self._on_pod_put(ab[aoff[i] : aoff[i + 1]], mrev_l[i], key)
                 # decode_pod may have interned a new constraint whose
-                # empty selector matches later canonical pods in this
-                # same batch — refresh the snapshot.
-                has_constraints = bool(tr._spread or tr._affinity)
+                # selector matches later canonical pods in this same
+                # batch.
+                binding = binding or bool(tr._spread or tr._affinity)
                 continue
             ks = key[plen:].decode()
             sh = shape_l[i]
+            if sh is not False and binding:
+                sh = bound_shape(sh, ks.partition("/")[0])
             if sh is False:
                 _DECODE_ERRORS.inc(kind="pod")
                 continue
@@ -1805,8 +1916,8 @@ class Coordinator:
                     self._queued_keys.discard(ks)
                     continue
                 node_name = ab[aoff[i] : aoff[i + 1]].decode()
-                pod = self._native_pod(
-                    sh, ks, cpu_l[i], mem_l[i], has_constraints, node_name
+                pod = (sh or self._bare_shape).pod(
+                    ks, cpu_l[i], mem_l[i], node_name
                 )
                 if node_name in self.host._row_of:
                     self._orphan_bound.pop(ks, None)
@@ -1823,27 +1934,15 @@ class Coordinator:
                 continue
             if self.intake_filter is not None and not self.intake_filter(ks):
                 continue
-            # The record carries a PodInfo only where something reads
-            # more of the pod than its shape: constraint increments
-            # (decode_pod_fast's tracker matches) or gang staging.
-            pod = None
-            gang = gangs_on and sh is not None and sh.gang
-            if gang or (has_constraints and (
-                sh is not None or any(self._empty_incs(ks.split("/", 1)[0]))
-            )):
-                pod = self._native_pod(
-                    sh, ks, cpu_l[i], mem_l[i], has_constraints
-                )
-                if not (gang or pod.spread_incs or pod.ipa_incs):
-                    pod = None
             self._queued_keys.add(ks)
             rec = PendingPod(
-                pod, mrev_l[i], now,
+                None, mrev_l[i], now,
                 cpu_milli=cpu_l[i], mem_kib=mem_l[i],
                 key_str=ks, key_bytes=key, shape=sh,
             )
-            if gang:
-                self._stage_or_queue(rec, pod)
+            if gangs_on and sh is not None and sh.gang:
+                # Gang staging reads the labels off a PodInfo.
+                self._stage_or_queue(rec, rec.ensure_pod())
                 continue
             self.queue.append(rec)
             if tr_on:
@@ -1851,31 +1950,6 @@ class Coordinator:
         self._flush_lanes(
             delete=deletes, canonical=evb.n - deletes - slow
         )
-
-    def _native_pod(
-        self, shape: PodShape | None, key_str: str, cpu_milli: int,
-        mem_kib: int, has_constraints: bool, node_name: str | None = None,
-    ) -> PodInfo:
-        """The PodInfo of one natively parsed pod, with the tracker's
-        matches of its labels as decode_pod_fast would have set them."""
-        if shape is not None:
-            pod = shape.pod(key_str, cpu_milli, mem_kib, node_name)
-        else:
-            ns, name = key_str.split("/", 1)
-            pod = PodInfo(
-                name=name, namespace=ns, cpu_milli=cpu_milli,
-                mem_kib=mem_kib, node_name=node_name,
-            )
-        if has_constraints:
-            ns = pod.namespace
-            if shape is not None:
-                pod.spread_incs = self.tracker.spread_matches(ns, pod.labels)
-                pod.ipa_incs = self.tracker.affinity_matches(ns, pod.labels)
-            else:
-                si, ii = self._empty_incs(ns)
-                pod.spread_incs = list(si)
-                pod.ipa_incs = list(ii)
-        return pod
 
     def _node_name_bytes(self) -> list:
         """Encoded node names, index-parallel with vocab.node_names
@@ -1886,28 +1960,6 @@ class Coordinator:
             v = tv[len(nb)]
             nb.append(v.encode() if isinstance(v, str) else b"")
         return nb
-
-    def _empty_incs(self, namespace: str) -> tuple:
-        """Cached tracker matches for a label-less pod in ``namespace``
-        (cache key includes the registration counts, which only grow)."""
-        tr = self.tracker
-        key = (len(tr._spread), len(tr._affinity), namespace)
-        incs = self._empty_incs_cache.get(key)
-        if incs is None:
-            if len(self._empty_incs_cache) >= 1024:
-                # Bounded like _gang_oversize: namespaces churn on long
-                # soaks, and the registration counts in the key retire
-                # every older entry each time a constraint registers —
-                # unbounded, the dead generations pile up forever.
-                # Clearing just re-derives a live namespace's matches
-                # once more.
-                self._empty_incs_cache.clear()
-            incs = (
-                tuple(tr.spread_matches(namespace, {})),
-                tuple(tr.affinity_matches(namespace, {})),
-            )
-            self._empty_incs_cache[key] = incs
-        return incs
 
     def resync(self) -> int:
         """Full relist after watch overflow: reconcile host state against
@@ -3232,13 +3284,15 @@ class Coordinator:
     def _delta_key(p: PendingPod):
         """The pod's plane-cache shape key (snapshot/hotfeed.shape_key),
         or None for uncacheable shapes.  Native fast-lane pods
-        (pod=None) carry no constraint increment and no nodeName by
-        construction, and their interned shape holds their fingerprint
-        (PLAIN without one) — their key needs no PodInfo
-        materialization at all."""
+        (pod=None) carry no nodeName by construction, and their interned
+        shape holds their fingerprint (PLAIN without one) and whether
+        constraints couple them to the live count tables — their key
+        needs no PodInfo materialization at all."""
         if p.pod is None:
-            fp = PLAIN if p.shape is None else p.shape.fp
-            return (fp, p.cpu_milli, p.mem_kib)
+            sh = p.shape
+            if sh is None:
+                return (PLAIN, p.cpu_milli, p.mem_kib)
+            return None if sh.coupled else (sh.fp, p.cpu_milli, p.mem_kib)
         return shape_key(p.pod)
 
     def _plan_delta(self, batch_pods, batch):
@@ -3809,9 +3863,10 @@ class Coordinator:
         the store against the bytes it already holds (ms_bind_batch).
         The bookkeeping around it is done by the wave, in columns, for
         the pods that are plain — read from the wave itself: the store
-        has ``bind_batch``, the record has an observed revision and no
-        PodInfo (a fast-lane record: nothing to keep, the tenant is its
-        shape's or its namespace's), and no fault plan is installed.
+        has ``bind_batch``, the record has an observed revision, no
+        PodInfo and no shape with constraint increments (a fast-lane
+        record with nothing to keep: the tenant is its shape's or its
+        namespace's), and no fault plan is installed.
         Every other pod is an exception and runs the per-pod code, over
         the exception indices only: webhook intake (no revision) and a
         store without ``bind_batch`` bind one by one through _bind ahead
@@ -3884,6 +3939,18 @@ class Coordinator:
         else:
             sent = np.zeros(n, bool)
         plain = np.zeros(n, bool) if inj_active else sent & _is_none(infos)
+        # A shape with constraint increments (there is none while the
+        # tracker holds no constraint): the bound record keeps a
+        # PodInfo, which the columns below do not make.
+        tr = self.tracker
+        keeping = (
+            {sh for sh in set(shapes) if sh is not None and sh.keeps}
+            if tr._spread or tr._affinity else ()
+        )
+        if keeping:
+            plain &= ~np.fromiter(
+                map(keeping.__contains__, shapes), bool, n
+            )
 
         def bind_singly(j: int, now: float) -> bool:
             """One pod through _bind, with what follows a bind there."""
@@ -3978,8 +4045,8 @@ class Coordinator:
             seqs = range(self._bind_seq + 1, self._bind_seq + columnar + 1)
             self._bind_seq += columnar
             tenants = _wave_tenants(k, pick(shapes))
-            # A fast-lane record keeps no PodInfo: nothing it carries
-            # could be constraintful.
+            # Nothing a record retired here carries is constraintful
+            # (``keeping`` above).
             self._bound.update(zip(k, zip(
                 on, cpu, mem, pick(zones), pick(regions),
                 itertools.repeat(None), prio, seqs, tenants, gang,
@@ -4000,6 +4067,10 @@ class Coordinator:
                 continue
             p = wave[j]
             pod = p.pod
+            if pod is None and shapes[j] in keeping:
+                # What a later delete hands _process_adjusts to take the
+                # increments back.
+                pod = p.ensure_pod()
             keep = (
                 pod if pod is not None and self._constraintful(pod) else None
             )

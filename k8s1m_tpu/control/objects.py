@@ -600,13 +600,14 @@ def decode_pod(data: bytes, tracker: ConstraintTracker | None = None) -> PodInfo
 
 # Byte landmarks of the canonical encode_pod shape.  The fast parser
 # accepts EXACTLY the objects this module's encode_pod emits for pods
-# whose only free parts are a flat label map and a toleration list (no
-# selectors, affinity, spread or priority), in either nodeName form —
-# anything else, including any backslash escape anywhere, falls back to
-# the full JSON path.  The native parser (native/memstore parse_pod) is
-# its twin and accepts the same inputs.  This is the restricted-parser
-# analogue of the reference's empirically-restricted Txn support (one
-# shape, fast; everything else rejected — kv_service.rs:126-337).
+# whose only free parts are a flat label map, a toleration list and a
+# topologySpreadConstraints array (no selectors, affinity or priority),
+# in either nodeName form — anything else, including any backslash
+# escape anywhere, falls back to the full JSON path.  The native parser
+# (native/memstore parse_pod) is its twin and accepts the same inputs.
+# This is the restricted-parser analogue of the reference's
+# empirically-restricted Txn support (one shape, fast; everything else
+# rejected — kv_service.rs:126-337).
 _FP_HEAD = b'{"apiVersion":"v1","kind":"Pod","metadata":{"name":"'
 _FP_NS = b'","namespace":"'
 _FP_LABELS = b'","labels":{'
@@ -623,6 +624,7 @@ _FP_CTR_END = b'"}}}]'
 # the bind splice inserts it before schedulerName.  Accept both.
 _FP_NODE_APP = b',"nodeName":"'
 _FP_TOLS = b',"tolerations":['
+_FP_SPREAD = b',"topologySpreadConstraints":['
 _FP_END = b'},"status":{"phase":"Pending"}}'
 _FP_TOL_EFFECTS = tuple(
     (name.encode() + b'"', effect) for name, effect in _EFFECTS.items() if name
@@ -683,6 +685,32 @@ def _scan_tolerations(data: bytes, i: int):
         if nxt == b"]":
             return tols, i
         return None
+
+
+# A string (the value holds no backslash, so it ends at its next quote),
+# a quote that opens none, or one bracket or brace.
+_ARRAY_TOKEN_RE = re.compile(rb'"[^"]*"|["\[\]{}]')
+
+
+def _scan_array(data: bytes, i: int) -> int | None:
+    """Index just past the bracket that closes the JSON array opened
+    just before ``i``, or None (native/memstore scan_array is the twin).
+    Only the nesting is proven: brackets and braces inside strings do not
+    count, and what lies between is json.loads' to judge."""
+    depth = 1
+    for m in _ARRAY_TOKEN_RE.finditer(data, i):
+        tok = m.group()
+        if len(tok) > 1:
+            continue
+        if tok == b'"':
+            return None
+        if tok in b"[{":
+            depth += 1
+        else:
+            depth -= 1
+            if depth == 0:
+                return m.end() if tok == b"]" else None
+    return None
 
 
 def decode_pod_fast(
@@ -755,8 +783,15 @@ def decode_pod_fast(
         if scanned is None:
             return None
         tolerations, i = scanned
+    spread = b""
+    if data.startswith(_FP_SPREAD, i):
+        j = _scan_array(data, i + len(_FP_SPREAD))
+        if j is None:
+            return None
+        spread = data[i + len(_FP_SPREAD) : j - 1]
+        i = j
     # The tail must be the EXACT remainder: proves there is no
-    # nodeSelector/affinity/topologySpreadConstraints/priority.
+    # nodeSelector/affinity/priority.
     if data[i:] != _FP_END:
         return None
     if not cpu_b.endswith(b"m") or not mem_b.endswith(b"Ki"):
@@ -777,25 +812,82 @@ def decode_pod_fast(
         node_name=node_name,
         tolerations=tolerations,
     )
+    if spread:
+        pod.topology_spread = json.loads(b"[%s]" % spread, strict=False)
     if tracker is not None:
-        ns = pod.namespace
-        pod.spread_incs = tracker.spread_matches(ns, labels)
-        pod.ipa_incs = tracker.affinity_matches(ns, labels)
+        bind_pod_constraints(pod, tracker)
     return pod
 
 
-def decode_pod_shape(labels: bytes, tolerations: bytes):
-    """(labels, tolerations) of a natively parsed pod, from the two byte
-    spans the native parser found (between the braces of metadata.labels,
-    between the brackets of spec.tolerations): json.loads and then
-    decode_pod_obj's own handling, so that a shape never means anything
-    else than the JSON lane would have made of the same pod.  Control
-    bytes inside strings are let through, as decode_pod_fast lets them."""
+def decode_pod_shape(labels: bytes, tolerations: bytes, spread: bytes):
+    """(labels, tolerations, topology_spread) of a natively parsed pod,
+    from the three byte spans the native parser found (between the braces
+    of metadata.labels, between the brackets of spec.tolerations and of
+    spec.topologySpreadConstraints): json.loads and then decode_pod_obj's
+    own handling, so that a shape never means anything else than the JSON
+    lane would have made of the same pod.  Control bytes inside strings
+    are let through, as decode_pod_fast lets them."""
     obj = json.loads(
-        b'{"labels":{%s},"tolerations":[%s]}' % (labels, tolerations),
+        b'{"labels":{%s},"tolerations":[%s],"spread":[%s]}'
+        % (labels, tolerations, spread),
         strict=False,
     )
-    return dict(obj["labels"]), _decode_tolerations(obj["tolerations"])
+    return (
+        dict(obj["labels"]), _decode_tolerations(obj["tolerations"]),
+        obj["spread"],
+    )
+
+
+def bind_pod_constraints(
+    pod: PodInfo, tracker: ConstraintTracker, affinity: dict | None = None
+) -> None:
+    """What a pod's constraints and its labels mean to one tracker:
+    ``topology_spread`` interned into ``spread_refs`` in order (a
+    constraint ahead of an unsupported one keeps its slot), the
+    podAffinity / podAntiAffinity terms of ``affinity`` (spec.affinity;
+    a canonical pod has none) into ``affinity_refs``, then the tracker's
+    matches of the labels.  The one place this is written:
+    decode_pod_obj, decode_pod_fast and the coordinator's pod templates
+    (PodShape.bind) all come here."""
+    namespace, labels = pod.namespace, pod.labels
+    affinity = affinity or {}
+    for sc in pod.topology_spread:
+        topo = _TOPO_KEYS.get(sc.get("topologyKey", ""))
+        if topo is None:
+            raise ValueError(
+                f"pod {pod.key}: unsupported topologyKey {sc.get('topologyKey')!r}"
+            )
+        selector = dict(sc.get("labelSelector", {}).get("matchLabels", {}))
+        cid = tracker.spread_slot(namespace, selector, topo)
+        pod.spread_refs.append(
+            SpreadConstraintRef(
+                cid=cid,
+                topo=topo,
+                max_skew=sc.get("maxSkew", 1),
+                mode=(
+                    SPREAD_SCHEDULE_ANYWAY
+                    if sc.get("whenUnsatisfiable") == "ScheduleAnyway"
+                    else SPREAD_DO_NOT_SCHEDULE
+                ),
+                self_match=ConstraintTracker.selector_matches(selector, labels),
+            )
+        )
+    for kind in ("podAffinity", "podAntiAffinity"):
+        sub = affinity.get(kind, {})
+        anti = kind == "podAntiAffinity"
+        for term in sub.get("requiredDuringSchedulingIgnoredDuringExecution", []):
+            pod.affinity_refs.append(
+                _decode_ipa_term(tracker, namespace, labels, term, True, anti, 1)
+            )
+        for wt in sub.get("preferredDuringSchedulingIgnoredDuringExecution", []):
+            pod.affinity_refs.append(
+                _decode_ipa_term(
+                    tracker, namespace, labels, wt["podAffinityTerm"],
+                    False, anti, wt.get("weight", 1),
+                )
+            )
+    pod.spread_incs = tracker.spread_matches(namespace, labels)
+    pod.ipa_incs = tracker.affinity_matches(namespace, labels)
 
 
 def _decode_tolerations(items: list) -> list[Toleration]:
@@ -853,43 +945,7 @@ def decode_pod_obj(obj: dict, tracker: ConstraintTracker | None = None) -> PodIn
 
     pod.topology_spread = list(spec.get("topologySpreadConstraints", []))
     if tracker is not None:
-        for sc in pod.topology_spread:
-            topo = _TOPO_KEYS.get(sc.get("topologyKey", ""))
-            if topo is None:
-                raise ValueError(
-                    f"pod {pod.key}: unsupported topologyKey {sc.get('topologyKey')!r}"
-                )
-            selector = dict(sc.get("labelSelector", {}).get("matchLabels", {}))
-            cid = tracker.spread_slot(namespace, selector, topo)
-            pod.spread_refs.append(
-                SpreadConstraintRef(
-                    cid=cid,
-                    topo=topo,
-                    max_skew=sc.get("maxSkew", 1),
-                    mode=(
-                        SPREAD_SCHEDULE_ANYWAY
-                        if sc.get("whenUnsatisfiable") == "ScheduleAnyway"
-                        else SPREAD_DO_NOT_SCHEDULE
-                    ),
-                    self_match=ConstraintTracker.selector_matches(selector, labels),
-                )
-            )
-        for kind in ("podAffinity", "podAntiAffinity"):
-            sub = aff.get(kind, {})
-            anti = kind == "podAntiAffinity"
-            for term in sub.get("requiredDuringSchedulingIgnoredDuringExecution", []):
-                pod.affinity_refs.append(
-                    _decode_ipa_term(tracker, namespace, labels, term, True, anti, 1)
-                )
-            for wt in sub.get("preferredDuringSchedulingIgnoredDuringExecution", []):
-                pod.affinity_refs.append(
-                    _decode_ipa_term(
-                        tracker, namespace, labels, wt["podAffinityTerm"],
-                        False, anti, wt.get("weight", 1),
-                    )
-                )
-        pod.spread_incs = tracker.spread_matches(namespace, labels)
-        pod.ipa_incs = tracker.affinity_matches(namespace, labels)
+        bind_pod_constraints(pod, tracker, aff)
     return pod
 
 

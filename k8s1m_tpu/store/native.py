@@ -110,11 +110,13 @@ class PodEventBatch:
     aoff: "object"      # u32[n+1] offsets into aux_blob
     key_blob: bytes
     aux_blob: bytes
-    # u32[n]: 0 = no labels and no tolerations, s > 0 = shapes[s - 1].
+    # u32[n]: 0 = no labels, tolerations or spread constraints, s > 0 =
+    # shapes[s - 1].
     shape: "object" = None
-    # Each distinct (label span, toleration span) of the frame, once:
-    # the bytes between the braces of metadata.labels and between the
-    # brackets of spec.tolerations.
+    # Each distinct (label span, toleration span, spread span) of the
+    # frame, once: the bytes between the braces of metadata.labels and
+    # between the brackets of spec.tolerations and of
+    # spec.topologySpreadConstraints.
     shapes: tuple = ()
 
     @staticmethod
@@ -145,17 +147,16 @@ class PodEventBatch:
         koff = np.frombuffer(data, np.uint32, n + 1, off); off += 4 * (n + 1)
         aoff = np.frombuffer(data, np.uint32, n + 1, off); off += 4 * (n + 1)
         (ns,) = _U32.unpack_from(data, off); off += 4
-        soff = np.frombuffer(data, np.uint32, 2 * ns + 1, off).tolist()
-        off += 4 * (2 * ns + 1)
+        soff = np.frombuffer(data, np.uint32, 3 * ns + 1, off).tolist()
+        off += 4 * (3 * ns + 1)
         klen = int(koff[-1])
         key_blob = data[off : off + klen]; off += klen
         alen = int(aoff[-1])
         aux_blob = data[off : off + alen]; off += alen
-        shapes = tuple(
-            (data[off + soff[2 * s] : off + soff[2 * s + 1]],
-             data[off + soff[2 * s + 1] : off + soff[2 * s + 2]])
-            for s in range(ns)
-        )
+        spans = [
+            data[off + lo : off + hi] for lo, hi in zip(soff, soff[1:])
+        ]
+        shapes = tuple(zip(spans[0::3], spans[1::3], spans[2::3]))
         return PodEventBatch(
             int(n), canceled, etype, flags, mrev, cpu, mem, koff, aoff,
             key_blob, aux_blob, shape, shapes,

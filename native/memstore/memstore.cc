@@ -1345,12 +1345,13 @@ namespace {
 
 // ---- canonical pod fast parser -------------------------------------------
 // The exact byte landmarks of this framework's encode_pod for pods whose
-// only free parts are a flat label map and a toleration list
-// (k8s1m_tpu/control/objects.py decode_pod_fast is the Python twin; the two
-// parsers accept the same inputs so the fast lane and the fallback path can
-// never disagree).  Labels and tolerations are not interpreted here: the
-// parser proves their grammar and hands back their byte spans, which the
-// frame carries once per distinct pair (a "shape").  Anything else —
+// only free parts are a flat label map, a toleration list and a list of
+// topology spread constraints (k8s1m_tpu/control/objects.py decode_pod_fast
+// is the Python twin; the two parsers accept the same inputs so the fast
+// lane and the fallback path can never disagree).  None of the three is
+// interpreted here: the parser proves the grammar of the first two and that
+// the third is a balanced array, and hands back their byte spans, which the
+// frame carries once per distinct triple (a "shape").  Anything else —
 // selectors, affinity, priority, escapes — is left for the caller's full
 // JSON parser.
 constexpr char kPodHead[] =
@@ -1369,6 +1370,7 @@ constexpr char kPodCtrEnd[] = "\"}}}]";
 // bind splice inserts it before schedulerName.  Both are accepted.
 constexpr char kPodNodeApp[] = ",\"nodeName\":\"";
 constexpr char kPodTols[] = ",\"tolerations\":[";
+constexpr char kPodSpread[] = ",\"topologySpreadConstraints\":[";
 constexpr char kPodEnd[] = "},\"status\":{\"phase\":\"Pending\"}}";
 constexpr char kTolKey[] = "\"key\":\"";
 constexpr char kTolOp[] = "\"operator\":\"";
@@ -1381,12 +1383,15 @@ struct PodParse {
   int32_t cpu = 0, mem = 0;
   const char* node = nullptr;
   size_t node_len = 0;
-  // Contents of the label map's braces and of the toleration list's
-  // brackets (both empty for the bare pod).
+  // Contents of the label map's braces and of the brackets of the
+  // toleration list and of topologySpreadConstraints (all empty for the
+  // bare pod).
   const char* labels = "";
   size_t labels_len = 0;
   const char* tols = "";
   size_t tols_len = 0;
+  const char* spread = "";
+  size_t spread_len = 0;
 };
 
 inline bool lit_at(std::string_view v, size_t pos, const char* lit,
@@ -1473,6 +1478,29 @@ bool scan_tolerations(std::string_view v, size_t* i) {
   }
 }
 
+// Any JSON array, from just past its opening bracket; *i ends just past
+// the bracket that closes it (objects.py _scan_array).  Only the nesting
+// is proven — a string ends at its next quote, and brackets and braces
+// inside one do not count; the consumer's JSON parser decides the rest,
+// once per distinct span.
+bool scan_array(std::string_view v, size_t* i) {
+  int depth = 1;
+  for (size_t p = *i; p < v.size(); p++) {
+    char c = v[p];
+    if (c == '"') {
+      p = v.find('"', p + 1);
+      if (p == std::string::npos) return false;
+    } else if (c == '[' || c == '{') {
+      depth++;
+    } else if ((c == ']' || c == '}') && --depth == 0) {
+      if (c != ']') return false;
+      *i = p + 1;
+      return true;
+    }
+  }
+  return false;
+}
+
 bool parse_pod(std::string_view v, const uint8_t* sched, size_t sched_len,
                PodParse* out) {
   if (!lit_at(v, 0, LIT(kPodHead))) return false;
@@ -1534,8 +1562,14 @@ bool parse_pod(std::string_view v, const uint8_t* sched, size_t sched_len,
     if (!scan_tolerations(v, &i)) return false;
     out->tols_len = static_cast<size_t>(v.data() + i - 1 - out->tols);
   }
-  // The exact remainder: proves there is no nodeSelector, affinity,
-  // topologySpreadConstraints or priority.
+  if (lit_at(v, i, LIT(kPodSpread))) {
+    i += sizeof(kPodSpread) - 1;
+    out->spread = v.data() + i;
+    if (!scan_array(v, &i)) return false;
+    out->spread_len = static_cast<size_t>(v.data() + i - 1 - out->spread);
+  }
+  // The exact remainder: proves there is no nodeSelector, affinity or
+  // priority.
   return v.size() - i == sizeof(kPodEnd) - 1 && lit_at(v, i, LIT(kPodEnd));
 }
 
@@ -1563,9 +1597,9 @@ uint8_t* emit_pod_frame(size_t n, bool canceled, const uint8_t* sched,
   std::vector<int32_t> cpu(n, 0), mem(n, 0);
   std::vector<uint32_t> shape(n, 0), koff(n + 1, 0), aoff(n + 1, 0);
   std::string keys, aux;
-  // The frame's shape table: each distinct (label span, toleration span)
-  // pair once.  A wave of one template hits `last` every time; the map
-  // is only consulted when the shape changes.
+  // The frame's shape table: each distinct (label span, toleration span,
+  // spread span) triple once.  A wave of one template hits `last` every
+  // time; the map is only consulted when the shape changes.
   std::string shapes;
   std::vector<uint32_t> soff(1, 0);
   std::unordered_map<std::string, uint32_t> shape_of;
@@ -1589,23 +1623,28 @@ uint8_t* emit_pod_frame(size_t n, bool canceled, const uint8_t* sched,
         }
         cpu[i] = p.cpu;
         mem[i] = p.mem;
-        if (p.labels_len || p.tols_len) {
-          const uint32_t* lo = last ? &soff[2 * (last - 1)] : nullptr;
-          if (lo == nullptr || lo[1] - lo[0] != p.labels_len ||
-              lo[2] - lo[1] != p.tols_len ||
-              memcmp(shapes.data() + lo[0], p.labels, p.labels_len) != 0 ||
-              memcmp(shapes.data() + lo[1], p.tols, p.tols_len) != 0) {
-            uint32_t llen = static_cast<uint32_t>(p.labels_len);
-            std::string k(reinterpret_cast<const char*>(&llen), 4);
-            k.append(p.labels, p.labels_len);
-            k.append(p.tols, p.tols_len);
+        if (p.labels_len || p.tols_len || p.spread_len) {
+          const char* span[3] = {p.labels, p.tols, p.spread};
+          const uint32_t len[3] = {static_cast<uint32_t>(p.labels_len),
+                                   static_cast<uint32_t>(p.tols_len),
+                                   static_cast<uint32_t>(p.spread_len)};
+          const uint32_t* lo = last ? &soff[3 * (last - 1)] : nullptr;
+          if (lo == nullptr || lo[1] - lo[0] != len[0] ||
+              lo[2] - lo[1] != len[1] || lo[3] - lo[2] != len[2] ||
+              memcmp(shapes.data() + lo[0], span[0], len[0]) != 0 ||
+              memcmp(shapes.data() + lo[1], span[1], len[1]) != 0 ||
+              (len[2] &&
+               memcmp(shapes.data() + lo[2], span[2], len[2]) != 0)) {
+            // The two leading lengths make the joined spans unambiguous.
+            std::string k(reinterpret_cast<const char*>(len), 8);
+            for (int j = 0; j < 3; j++) k.append(span[j], len[j]);
             auto ins = shape_of.emplace(
-                std::move(k), static_cast<uint32_t>(soff.size() / 2 + 1));
+                std::move(k), static_cast<uint32_t>(soff.size() / 3 + 1));
             if (ins.second) {
-              shapes.append(p.labels, p.labels_len);
-              soff.push_back(static_cast<uint32_t>(shapes.size()));
-              shapes.append(p.tols, p.tols_len);
-              soff.push_back(static_cast<uint32_t>(shapes.size()));
+              for (int j = 0; j < 3; j++) {
+                shapes.append(span[j], len[j]);
+                soff.push_back(static_cast<uint32_t>(shapes.size()));
+              }
             }
             last = ins.first->second;
           }
@@ -1634,7 +1673,7 @@ uint8_t* emit_pod_frame(size_t n, bool canceled, const uint8_t* sched,
   b.append(reinterpret_cast<const char*>(shape.data()), 4 * n);
   b.append(reinterpret_cast<const char*>(koff.data()), 4 * (n + 1));
   b.append(reinterpret_cast<const char*>(aoff.data()), 4 * (n + 1));
-  put_u32(b, static_cast<uint32_t>(soff.size() / 2));
+  put_u32(b, static_cast<uint32_t>(soff.size() / 3));
   b.append(reinterpret_cast<const char*>(soff.data()), 4 * soff.size());
   b.append(keys);
   b.append(aux);

@@ -126,6 +126,10 @@ def _reference_bind_wave(self, batch_pods, rows, bound_ok, failed) -> int:
                 ok_mem.append(p.mem_kib)
                 lats.append(now - p.enqueued_at)
                 pod = p.pod
+                if pod is None and p.shape is not None and p.shape.keeps:
+                    # A fast-lane record whose shape carries constraint
+                    # increments: the bound record keeps its PodInfo.
+                    pod = p.ensure_pod()
                 keep = (
                     pod
                     if pod is not None and self._constraintful(pod)
@@ -260,6 +264,8 @@ class _Side:
                 if getattr(c.store, "bind_batch", None) is not None
                 and batch_pods[i].mod_revision is not None
                 and batch_pods[i].pod is None
+                and not (batch_pods[i].shape is not None
+                         and batch_pods[i].shape.keeps)
                 and not faultline.active_injector().plan.faults
                 and c.host.valid[rows[i]]
             ]
@@ -716,7 +722,7 @@ def _tenants_by_pod(key_strs, shapes) -> list:
 ], ids=["one_namespace", "namespaces", "a_shape_names_one", "prefix", "no_slash"])
 def test_wave_tenants_equal_the_per_pod_rule(keys, labels):
     shapes = [
-        None if l is None else coordinator_mod.PodShape(l, [], "s")
+        None if l is None else coordinator_mod.PodShape(l, [], [], "s")
         for l in labels
     ]
     assert _wave_tenants(keys, shapes) == _tenants_by_pod(keys, shapes)

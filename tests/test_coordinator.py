@@ -347,8 +347,8 @@ def test_fast_lane_pending_pods_have_no_podinfo(store):
 def test_fast_lane_respects_empty_selector_constraints(store):
     """A topologySpreadConstraint with an empty selector matches label-less
     pods; the fast lane must still record the constraint increments (the
-    invariant: PendingPod.pod is None only for pods with no tracker
-    matches)."""
+    invariant: a record without a PodInfo refers to a shape bound to the
+    tracker, which holds the matches of every pod of it)."""
     from k8s1m_tpu.config import SPREAD_DO_NOT_SCHEDULE, TOPO_ZONE
     from k8s1m_tpu.snapshot.pod_encoding import SpreadConstraintRef
 
@@ -363,11 +363,13 @@ def test_fast_lane_respects_empty_selector_constraints(store):
         put_pod(store, f"sp-{i}")
     c.drain_watches()
     assert len(c.queue) == 6
-    # Empty-selector match forces the slow-lane PodInfo with incs.
+    # The label-less pod's shape, bound once for the six of them.
     for p in c.queue:
-        assert p.pod is not None
-        assert (slot, TOPO_ZONE) in p.pod.spread_incs
+        assert p.pod is None and p.shape is c.queue[0].shape
+        assert p.shape.keeps
+        assert (slot, TOPO_ZONE) in p.peek_pod().spread_incs
     assert c.run_until_idle() == 6
+    assert all(rec[5] is not None for rec in c._bound.values())
 
 
 def test_fast_lane_external_bind_accounting(store):
@@ -403,8 +405,8 @@ def test_mid_batch_constraint_registration_reaches_later_fast_pods(store):
                     chunk=64, k=4, with_constraints=True)
     c.bootstrap()
     # One labeled pod carrying an inline empty-selector spread constraint
-    # (non-canonical -> slow decode interns the slot), then plain pods —
-    # all in ONE batch of watch events.
+    # and a priority (non-canonical -> slow decode interns the slot), then
+    # plain pods — all in ONE batch of watch events.
     spread = [{
         "topologyKey": "topology.kubernetes.io/zone",
         "maxSkew": 1,
@@ -415,12 +417,13 @@ def test_mid_batch_constraint_registration_reaches_later_fast_pods(store):
 
     store.put_batch(
         [(pod_key("default", "carrier"),
-          enc(PodInfo("carrier", labels={"x": "y"}), raw_spread=spread))]
+          enc(PodInfo("carrier", labels={"x": "y"}, priority=1),
+              raw_spread=spread))]
         + [(pod_key("default", f"plain-{i}"),
             enc(PodInfo(f"plain-{i}"))) for i in range(4)]
     )
     c.drain_watches()
     assert len(c.queue) == 5
     plains = [p for p in c.queue if p.key_str.startswith("default/plain")]
-    assert plains and all(p.pod is not None for p in plains)
-    assert all(p.pod.spread_incs for p in plains)
+    assert plains and all(p.pod is None for p in plains)
+    assert all(p.peek_pod().spread_incs for p in plains)

@@ -24,7 +24,7 @@ Layers:
    structural adds + priority preemption + gang scheduling ==
    full-recompute plain single-device, byte for byte.
 
-Also here: the bounded Coordinator._empty_incs_cache (ISSUE 12
+Also here: the bounded Coordinator._shape_bindings (ISSUE 12
 satellite — it grew per (registration-count, namespace) key forever).
 """
 
@@ -976,10 +976,10 @@ def test_deltacache_pallas_byte_identical():
     _assert_identical(snap_p, _drive_steady(False))
 
 
-# ---- satellite: the bounded _empty_incs_cache -------------------------
+# ---- satellite: the bounded binding cache (the label-less shape) ------
 
 
-def test_empty_incs_cache_bounded():
+def test_shape_bindings_bounded():
     with MemStore() as store:
         put_node(store, "n0")
         c = Coordinator(
@@ -989,13 +989,13 @@ def test_empty_incs_cache_bounded():
         c.bootstrap()
         try:
             for i in range(1100):
-                c._empty_incs(f"ns-{i}")
+                c._bound_shape(None, f"ns-{i}")
             # The cap clears the dict rather than let dead generations
             # pile up across long soaks.
-            assert len(c._empty_incs_cache) <= 1024
-            # Still correct after the clear.
-            assert c._empty_incs("ns-0") == (
-                (), ()
-            ) == c._empty_incs("ns-0")
+            assert len(c._shape_bindings) <= 1024
+            # Still correct after the clear: nothing registered, so a
+            # label-less pod carries nothing but its scalars.
+            assert c._bound_shape(None, "ns-0") is None
+            assert c._bound_shape(None, "ns-0") is None
         finally:
             c.close()

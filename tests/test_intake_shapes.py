@@ -1,10 +1,12 @@
 """Interned pod shapes: the native intake lane against the JSON lane.
 
-``make_pods``' pod carries a label and a toleration.  The native parser
-(native/memstore parse_pod) proves their grammar and hands back their
-bytes once per distinct pair; the coordinator decodes each pair once
-(``PodShape``) and queues ``PendingPod(None, ..., shape=...)`` records.
-The lane must be invisible: the same pods through a coordinator whose
+``make_pods``' pod carries a label and a toleration, a Deployment's pod
+its spread constraints besides.  The native parser (native/memstore
+parse_pod) proves their grammar and hands back their bytes once per
+distinct triple; the coordinator decodes each triple once (``PodShape``),
+binds it to the tracker once per namespace and registration state
+(``Coordinator._bound_shape``) and queues ``PendingPod(None, ...,
+shape=...)`` records.  The lane must be invisible: the same pods through a coordinator whose
 watcher has no ``poll_pods`` (every event through ``_on_pod_put``, the
 lane the shaped one is held to) give the same queue, the same packed
 batches byte for byte, the same binds and the same accounting.
@@ -15,7 +17,13 @@ import dataclasses
 import numpy as np
 import pytest
 
-from k8s1m_tpu.config import EFFECT_NO_SCHEDULE, TOPO_ZONE, PodSpec, TableSpec
+from k8s1m_tpu.config import (
+    EFFECT_NO_SCHEDULE,
+    TOPO_HOSTNAME,
+    TOPO_ZONE,
+    PodSpec,
+    TableSpec,
+)
 from k8s1m_tpu.control import coordinator as coordinator_mod
 from k8s1m_tpu.control.coordinator import Coordinator
 from k8s1m_tpu.control.objects import (
@@ -40,6 +48,16 @@ PLAIN_PROFILE = Profile(
     node_affinity=0, topology_spread=0, interpod_affinity=0
 )
 KWOK_TAINT = Taint("kwok.x-k8s.io/node", "", EFFECT_NO_SCHEDULE)
+# spread-1m-pct5's sizes on 1,000 nodes: 8 zones and 4 regions (and id 0),
+# two slots for each of sixteen Deployments; a pod may match four.
+CONS_SPEC = TableSpec(max_nodes=1024, max_zones=9, max_regions=5,
+                      spread_slots=32, affinity_slots=1)
+CONS_PODS = PodSpec(batch=WAVE, spread_refs=2, spread_incs=4,
+                    affinity_refs=1, ipa_incs=1)
+CONS = dict(
+    with_constraints=True, in_wave_skew=True, spec=CONS_SPEC, pods=CONS_PODS,
+    profile=Profile(node_affinity=0, interpod_affinity=0),
+)
 
 
 class _EventWatch:
@@ -69,7 +87,8 @@ class _EventWatch:
 class _Lane:
     """One store, one coordinator, and a record of what it launched."""
 
-    def __init__(self, native: bool, *, taint=None, nodes=NODES, **kw) -> None:
+    def __init__(self, native: bool, *, taint=None, nodes=NODES, spec=SPEC,
+                 pods=PODS, **kw) -> None:
         self.store = MemStore()
         for i in range(nodes):
             node = build_node(i)
@@ -80,7 +99,7 @@ class _Lane:
         kw.setdefault("profile", PLAIN_PROFILE)
         profile = kw.pop("profile")
         self.coord = Coordinator(
-            self.store, SPEC, PODS, profile, chunk=256, pipeline=True,
+            self.store, spec, pods, profile, chunk=256, pipeline=True,
             depth=2, packing="packed", score_pct=50, seed=11, **kw,
         )
         self.waves: list = []
@@ -257,8 +276,8 @@ def test_parsed_toleration_decides_a_bind_on_tainted_nodes(lanes, tolerate):
 
 def test_spread_constraint_matches_shaped_labels_on_both_lanes(lanes):
     """With a spread constraint interned whose selector matches
-    app=bench-pod, shaped pods take the per-event branch and get the
-    tracker's matches per pod, as decode_pod_fast sets them."""
+    app=bench-pod, shaped pods refer to their shape as bound to the
+    tracker, with the matches decode_pod_fast sets pod by pod."""
     shaped, legacy = lanes(
         with_constraints=True, profile=Profile(interpod_affinity=0),
         nodes=64,
@@ -277,9 +296,12 @@ def test_spread_constraint_matches_shaped_labels_on_both_lanes(lanes):
     assert _queue_view(shaped.coord) == _queue_view(legacy.coord)
     incs = [p.peek_pod().spread_incs for p in shaped.coord.queue]
     assert incs == [[(slots[0], TOPO_ZONE)]] * 12 + [[]] * 4
-    # A record holds a PodInfo only where the tracker matched its labels.
-    assert [p.pod is not None for p in shaped.coord.queue] == \
+    # No record holds a PodInfo; the twelve share one bound shape, whose
+    # bind keeps one (what a delete takes the increment back with).
+    assert all(p.pod is None for p in shaped.coord.queue)
+    assert [p.shape.keeps for p in shaped.coord.queue] == \
         [True] * 12 + [False] * 4
+    assert len({id(p.shape) for p in shaped.coord.queue}) == 2
     for lane in (shaped, legacy):
         assert lane.coord.run_until_idle() == 16
     _assert_waves_equal(shaped, legacy)
@@ -347,6 +369,298 @@ def test_external_bind_of_a_shaped_pod_is_accounted_with_its_labels(lanes):
         assert lane.coord._bound["default/ext"][0] == "kwok-node-3"
         assert lane.coord._bound["default/ext"][8] == "team-x"
     _assert_accounting_equal(shaped.coord, legacy.coord)
+
+
+# ---- pods that carry spread constraints ------------------------------
+
+
+def _two(match: dict) -> list:
+    """The Kubernetes documentation's two-constraint example, as
+    benchmark/pods/spread.json writes it."""
+    return [
+        {"maxSkew": 1, "topologyKey": "topology.kubernetes.io/zone",
+         "whenUnsatisfiable": "DoNotSchedule",
+         "labelSelector": {"matchLabels": match}},
+        {"maxSkew": 1, "topologyKey": "kubernetes.io/hostname",
+         "whenUnsatisfiable": "ScheduleAnyway",
+         "labelSelector": {"matchLabels": match}},
+    ]
+
+
+def _web(i: int, d: int, **kw) -> PodInfo:
+    """Pod ``i`` of Deployment ``web-<d>``."""
+    app = f"web-{d}"
+    kw.setdefault("spread_constraints", _two({"app": app}))
+    return build_pod(i, app=app, cpu_milli=10, mem_kib=1024, **kw)
+
+
+def _tables(coord) -> dict:
+    c = coord.constraints
+    return {name: np.asarray(getattr(c, name))
+            for name in ("spread_zone", "spread_region", "spread_node")}
+
+
+def _assert_tables_equal(a: Coordinator, b: Coordinator) -> dict:
+    ta, tb = _tables(a), _tables(b)
+    for name in ta:
+        np.testing.assert_array_equal(ta[name], tb[name], err_msg=name)
+    return ta
+
+
+def _both(shaped: _Lane, legacy: _Lane, pods) -> None:
+    """One frame into both lanes, drained; the queues are then equal."""
+    for lane in (shaped, legacy):
+        lane.put(pods)
+        lane.coord.drain_watches()
+    assert _queue_view(shaped.coord) == _queue_view(legacy.coord)
+    assert shaped.coord.tracker._spread == legacy.coord.tracker._spread
+
+
+def test_sixteen_deployments_in_a_wave_ride_the_shaped_lane(lanes):
+    """The cell's traffic: every pod carries the two constraints of one
+    of sixteen Deployments, eight pods of each a wave.  Equal queue,
+    equal packed batches, equal binds, equal count tables on the device;
+    no pod on lane ``json``, a template decoded once and bound once per
+    state of the tracker it was seen in."""
+    shaped, legacy = lanes(**CONS)
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+    order = np.random.default_rng(7).permutation(16).tolist()
+    waves = [
+        [_web(w * WAVE + i, order[i % 16]) for i in range(WAVE)]
+        for w in range(3)
+    ]
+    before = _counts()
+    shaped.put(waves[0])
+    shaped.coord.drain_watches()
+    # Sixteen first sights, each registering two constraints; then each
+    # template's second pod finds the count moved (but for the last).
+    assert _grown(before, _counts()) == \
+        {"batch_fast": WAVE, "interned": 16, "bound": 31}
+    before = _counts()
+    legacy.put(waves[0])
+    legacy.coord.drain_watches()
+    assert _grown(before, _counts()) == {"decode_fast": WAVE}
+    assert _queue_view(shaped.coord) == _queue_view(legacy.coord)
+    assert all(p.pod is None for p in shaped.coord.queue)
+    # One bound shape a Deployment: one interned fingerprint for encode.
+    assert len({id(p.shape) for p in shaped.coord.queue}) == 16
+    assert len({id(p.shape.fp) for p in shaped.coord.queue}) == 16
+    refs = shaped.coord.queue[0].peek_pod().spread_refs
+    assert [(r.topo, r.mode, r.self_match) for r in refs] == \
+        [(TOPO_ZONE, 0, True), (TOPO_HOSTNAME, 1, True)]
+    bound = [0, 0]
+    for k, lane in enumerate((shaped, legacy)):
+        bound[k] += lane.coord.run_until_idle()
+    before = _counts()
+    for wave in waves[1:]:
+        _both(shaped, legacy, wave)
+        for k, lane in enumerate((shaped, legacy)):
+            bound[k] += lane.coord.run_until_idle()
+        _assert_tables_equal(shaped.coord, legacy.coord)
+    # Steady state: nothing interned, nothing bound anew, nothing on json.
+    assert _grown(before, _counts()) == \
+        {"batch_fast": 2 * WAVE, "decode_fast": 2 * WAVE}
+    assert bound == [3 * WAVE, 3 * WAVE]
+    _assert_waves_equal(shaped, legacy)
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    # Every bound pod keeps what a delete takes its increments back with.
+    assert all(r[5] is not None for r in shaped.coord._bound.values())
+    zone = _tables(shaped.coord)["spread_zone"]
+    assert zone.sum() == 3 * WAVE and (zone.sum(axis=1) > 0).sum() == 16
+
+
+def test_two_namespaces_share_a_template_and_not_its_slots(lanes):
+    shaped, legacy = lanes(nodes=64, **CONS)
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+    pods = [_web(i, 0, namespace=("blue", "green")[i % 2]) for i in range(8)]
+    before = _counts()
+    _both(shaped, legacy, pods)
+    grown = _grown(before, _counts())
+    # One template; bound in blue, in green, and in blue again once
+    # green's two constraints had registered (which changed nothing for
+    # blue: its pods go on referring to the one shape).
+    assert (grown["interned"], grown["bound"]) == (1, 3)
+    cids = [[r.cid for r in p.peek_pod().spread_refs]
+            for p in shaped.coord.queue]
+    assert cids == [[0, 1], [2, 3]] * 4
+    assert len({id(p.shape) for p in shaped.coord.queue}) == 2
+    for lane in (shaped, legacy):
+        assert lane.coord.run_until_idle() == 8
+    _assert_waves_equal(shaped, legacy)
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    zone = _assert_tables_equal(shaped.coord, legacy.coord)["spread_zone"]
+    assert zone.sum(axis=1)[:4].tolist() == [4, 0, 4, 0]
+
+
+def test_a_constraint_registered_mid_frame_reaches_the_next_pod(lanes):
+    """``tier`` registers a constraint that selects ``web-0``'s labels
+    after ``web-0``'s first pod: that pod stays without the increment,
+    ``web-0``'s next pod and a plain pod of the same labels carry it."""
+    shaped, legacy = lanes(nodes=64, **CONS)
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+    pods = [
+        _web(0, 0),
+        _web(1, 1, spread_constraints=_two({"app": "web-0"})[:1]),
+        _web(2, 0),
+        build_pod(3, app="web-0", cpu_milli=10, mem_kib=1024),
+        build_pod(4, app="other", cpu_milli=10, mem_kib=1024),
+    ]
+    _both(shaped, legacy, pods)
+    incs = [p.peek_pod().spread_incs for p in shaped.coord.queue]
+    z, h = TOPO_ZONE, TOPO_HOSTNAME
+    assert incs == [
+        [(0, z), (1, h)],
+        [],                           # selects web-0, is web-1
+        [(0, z), (1, h)],             # (its selector is web-0's own: slot 0)
+        [(0, z), (1, h)],
+        [],
+    ]
+    assert all(p.pod is None for p in shaped.coord.queue)
+    # A selector of its own, then: the frame's later pods see it.
+    later = [
+        _web(5, 0),
+        _web(6, 2, spread_constraints=[dict(
+            _two({})[0], labelSelector={"matchLabels": {}})]),
+        _web(7, 0),
+        PodInfo("bare", cpu_milli=10, mem_kib=1024),
+    ]
+    _both(shaped, legacy, later)
+    incs = [p.peek_pod().spread_incs for p in shaped.coord.queue][5:]
+    assert incs == [
+        [(0, z), (1, h)],
+        [(2, z)],
+        [(0, z), (1, h), (2, z)],
+        [(2, z)],                     # the empty selector takes a bare pod
+    ]
+    for lane in (shaped, legacy):
+        assert lane.coord.run_until_idle() == 9
+    _assert_waves_equal(shaped, legacy)
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    _assert_tables_equal(shaped.coord, legacy.coord)
+    # The plain pod the constraint matched keeps a PodInfo, the other not.
+    kept = {k for k, r in shaped.coord._bound.items() if r[5] is not None}
+    assert kept == {f"default/bench-pod-{i}" for i in (0, 2, 3, 5, 6, 7)} \
+        | {"default/bare"}
+
+
+def test_a_delete_takes_a_shaped_pods_increments_back(lanes):
+    shaped, legacy = lanes(nodes=64, **CONS)
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+    pods = [_web(i, i % 2) for i in range(16)]
+    _both(shaped, legacy, pods)
+    for lane in (shaped, legacy):
+        assert lane.coord.run_until_idle() == 16
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    full = _assert_tables_equal(shaped.coord, legacy.coord)
+    assert full["spread_zone"].sum(axis=1)[:4].tolist() == [8, 0, 8, 0]
+    assert full["spread_node"].sum(axis=1)[:4].tolist() == [0, 8, 0, 8]
+    gone = pods[:6]
+    for lane in (shaped, legacy):
+        lane.store.put_batch(
+            [(pod_key(p.namespace, p.name), None) for p in gone])
+        lane.coord.run_until_idle()
+        lane.coord.step()
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    assert len(shaped.coord._bound) == 10
+    left = _assert_tables_equal(shaped.coord, legacy.coord)
+    assert left["spread_zone"].sum(axis=1)[:4].tolist() == [5, 0, 5, 0]
+    assert left["spread_node"].sum(axis=1)[:4].tolist() == [0, 5, 0, 5]
+    np.testing.assert_array_equal(
+        shaped.coord.host.pods_req, legacy.coord.host.pods_req)
+
+
+def test_an_external_bind_of_a_constrained_pod_counts_on_the_device(lanes):
+    """POD_HAS_NODE with a spread span, both nodeName forms: accounted
+    with the shape's increments, which the next step scatters."""
+    shaped, legacy = lanes(nodes=8, **CONS)
+    ext = [_web(0, 0), _web(1, 0)]
+    ext[0].node_name = "kwok-node-3"
+    values = [
+        encode_pod(ext[0]),
+        coordinator_mod.splice_node_name(encode_pod(ext[1]), "kwok-node-5"),
+    ]
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+        lane.store.put_batch([
+            (pod_key(p.namespace, p.name), v) for p, v in zip(ext, values)
+        ])
+        lane.coord.drain_watches()
+        assert not lane.coord.queue
+        lane.coord.step()
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    tables = _assert_tables_equal(shaped.coord, legacy.coord)
+    assert tables["spread_zone"].sum(axis=1)[:2].tolist() == [2, 0]
+    assert tables["spread_node"][1].sum() == 2
+
+
+def _refused(kind: str) -> tuple[list, int]:
+    """(frame, pods of it the JSON lane refuses)."""
+    if kind == "unsupported-topology-key":
+        rack = _two({"app": "web-1"})
+        rack[1]["topologyKey"] = "example.com/rack"
+        return [_web(0, 0), _web(1, 1, spread_constraints=rack), _web(2, 0),
+                _web(3, 1, spread_constraints=rack), _web(4, 2)], 2
+    if kind == "thirty-third-slot":
+        # Sixteen Deployments take the 32 slots; the seventeenth's first
+        # constraint has none.
+        return [_web(i, i % 18) for i in range(36)], 4
+    assert kind == "constraint-is-no-object"
+    odd = _web(1, 1)
+    odd.topology_spread = [7]
+    return [_web(0, 0), odd, _web(2, 0)], 1
+
+
+@pytest.mark.parametrize("kind", [
+    "unsupported-topology-key", "thirty-third-slot",
+    "constraint-is-no-object",
+])
+def test_a_refused_template_counts_as_the_json_lane_counts_it(lanes, kind):
+    """What decode_pod_obj raises on, the shaped lane refuses pod by pod
+    with the same count, the same slots taken on the way, and the rest of
+    the frame queued."""
+    errors = REGISTRY.get("coordinator_decode_errors_total")
+    shaped, legacy = lanes(nodes=64, **CONS)
+    frame, bad = _refused(kind)
+    counted = []
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+        before = errors.value(kind="pod")
+        lane.put(frame)
+        lane.coord.drain_watches()
+        counted.append(errors.value(kind="pod") - before)
+    assert counted == [bad, bad]
+    assert _queue_view(shaped.coord) == _queue_view(legacy.coord)
+    assert len(shaped.coord.queue) == len(frame) - bad
+    assert shaped.coord.tracker._spread == legacy.coord.tracker._spread
+    for lane in (shaped, legacy):
+        assert lane.coord.run_until_idle() == len(frame) - bad
+    _assert_waves_equal(shaped, legacy)
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    _assert_tables_equal(shaped.coord, legacy.coord)
+
+
+def test_a_span_that_is_balanced_and_no_json_is_a_decode_error_on_both():
+    errors = REGISTRY.get("coordinator_decode_errors_total")
+    bad = encode_pod(_web(0, 0)).replace(b'"maxSkew":1', b'"maxSkew":one')
+    for native in (True, False):
+        lane = _Lane(native, nodes=8, **CONS)
+        try:
+            lane.bootstrap()
+            before = errors.value(kind="pod")
+            lane.store.put_batch([
+                (pod_key("default", "bad"), bad),
+                (pod_key("default", "good"),
+                 encode_pod(dataclasses.replace(_web(1, 0), name="good"))),
+            ])
+            lane.coord.drain_watches()
+            assert errors.value(kind="pod") - before == 1
+            assert [p.key_str for p in lane.coord.queue] == ["default/good"]
+        finally:
+            lane.close()
 
 
 # ---- the counters that say the lane engaged --------------------------

@@ -605,11 +605,48 @@ def test_poll_pods_shape_table(store):
     assert evb.shape.tolist() == [1, 0, 1, 2, 3, 1, 2]
     assert evb.shapes == (
         (b'"app":"bench-pod"',
-         b'{"key":"kwok.x-k8s.io/node","operator":"Exists"}'),
-        (b'"a":"b","c":"d"', b""),
-        (b"", b'{"key":"k","operator":"Exists"}'),
+         b'{"key":"kwok.x-k8s.io/node","operator":"Exists"}', b""),
+        (b'"a":"b","c":"d"', b"", b""),
+        (b"", b'{"key":"k","operator":"Exists"}', b""),
     )
     assert evb.aoff.tolist() == [0] * 8
+
+
+def test_poll_pods_shape_table_holds_the_spread_span(store):
+    """A shape is the triple: two Deployments that differ in their
+    spread constraints alone are two shapes, a pod with constraints and
+    nothing else is one too, and equal triples share an entry."""
+    from k8s1m_tpu.control.objects import encode_pod, pod_key
+    from k8s1m_tpu.snapshot.pod_encoding import PodInfo
+    from k8s1m_tpu.tools.make_pods import build_pod
+
+    def zone(app, skew=1):
+        return {"maxSkew": skew, "topologyKey": "topology.kubernetes.io/zone",
+                "whenUnsatisfiable": "DoNotSchedule",
+                "labelSelector": {"matchLabels": {"app": app}}}
+
+    w = _pods_watch(store)
+    pods = [
+        build_pod(0, app="web", spread_constraints=[zone("web")]),
+        build_pod(1, app="web", spread_constraints=[zone("web", 2)]),
+        build_pod(2, app="web"),
+        PodInfo("only", topology_spread=[zone("db")]),
+        build_pod(3, app="web", spread_constraints=[zone("web")]),
+    ]
+    for p in pods:
+        store.put(pod_key("default", p.name), encode_pod(p))
+    evb = w.poll_pods(100, b"dist-scheduler")
+    assert evb.shape.tolist() == [1, 2, 3, 4, 1]
+    tol = b'{"key":"kwok.x-k8s.io/node","operator":"Exists"}'
+    span = (b'{"maxSkew":%d,"topologyKey":"topology.kubernetes.io/zone",'
+            b'"whenUnsatisfiable":"DoNotSchedule",'
+            b'"labelSelector":{"matchLabels":{"app":"%s"}}}')
+    assert evb.shapes == (
+        (b'"app":"web"', tol, span % (1, b"web")),
+        (b'"app":"web"', tol, span % (2, b"web")),
+        (b'"app":"web"', tol, b""),
+        (b"", b"", span % (1, b"db")),
+    )
 
 
 def _pod_grammar_corpus():
@@ -637,6 +674,15 @@ def _pod_grammar_corpus():
     equal = T("k", TOL_OP_EQUAL, "v")
     full = T("k", TOL_OP_EQUAL, "v", EFFECT_NO_SCHEDULE)
     three = {"app": "web", "tier": "front", "k8s1m.io/tenant": "t-1"}
+
+    def zone(match, key="topology.kubernetes.io/zone"):
+        return {"topologyKey": key, "maxSkew": 1,
+                "whenUnsatisfiable": "DoNotSchedule",
+                "labelSelector": {"matchLabels": match}}
+
+    two = [zone({"app": "web-0"}),
+           dict(zone({"app": "web-0"}, "kubernetes.io/hostname"),
+                whenUnsatisfiable="ScheduleAnyway")]
     accepted = {
         "bare": encode_pod(PodInfo("a", cpu_milli=1, mem_kib=1)),
         "bare-wide": encode_pod(PodInfo(
@@ -671,8 +717,41 @@ def _pod_grammar_corpus():
         "node-spliced-both": splice_node_name(encode_pod(build_pod(9)), "n-2"),
         "other-scheduler": encode_pod(PodInfo(
             "r", scheduler_name="default-scheduler", labels={"x": "y"})),
+        "spread": encode_pod(PodInfo("a", labels={"x": "y"}), raw_spread=[
+            zone({})]),
+        "spread-alone": encode_pod(PodInfo("a", topology_spread=[zone({})])),
+        "spread-cell": encode_pod(build_pod(
+            3, app="web-0", spread_constraints=two)),
+        "spread-other-scheduler": encode_pod(build_pod(
+            3, app="web-0", spread_constraints=two),
+            scheduler_name="default-scheduler"),
+        "spread-node-appended": encode_pod(PodInfo(
+            "s", node_name="n-1", labels=three, tolerations=[kwok, full],
+            topology_spread=two)),
+        "spread-node-spliced": splice_node_name(encode_pod(build_pod(
+            4, app="web-0", spread_constraints=two)), "n-2"),
+        # Brackets, braces and commas inside strings are not structure.
+        "spread-brackets-in-selector": encode_pod(PodInfo(
+            "t", labels={"x": "]}"}, topology_spread=[
+                zone({"x": "]}", "[{": "}],"})])),
+        "label-brackets": encode_pod(PodInfo("u", labels={"a]": "}],{["})),
+        "spread-unknown-keys": encode_pod(PodInfo("v", topology_spread=[
+            dict(zone({"a": "b"}), minDomains=3, matchLabelKeys=["k"],
+                 labelSelector={"matchExpressions": [
+                     {"key": "k", "operator": "In", "values": ["v"]}]})])),
+        "spread-unsupported-key": encode_pod(PodInfo("w", topology_spread=[
+            dict(zone({}), topologyKey="example.com/rack")])),
     }
     mp = accepted["make-pods"]
+    cell = accepted["spread-cell"]
+    spread_key = b',"topologySpreadConstraints":['
+    assert spread_key in cell
+    # An array the encoder never writes and JSON allows: no constraints.
+    accepted["spread-empty-list"] = mp.replace(
+        b'},"status"', spread_key + b']},"status"')
+    accepted["spread-whitespace"] = cell.replace(
+        spread_key + b"{", spread_key + b' {').replace(
+        b'}]},"status"', b'} ]},"status"')
     tol = b'"tolerations":[{"key":"kwok.x-k8s.io/node","operator":"Exists"}]'
     assert tol in mp
     rejected = {
@@ -689,10 +768,31 @@ def _pod_grammar_corpus():
         "affinity": encode_pod(PodInfo(
             "a", required_terms=[NodeSelectorTerm([
                 SelectorRequirement("k", SEL_OP_IN, ["v"])])])),
-        "spread": encode_pod(PodInfo("a", labels={"x": "y"}), raw_spread=[{
-            "topologyKey": "topology.kubernetes.io/zone", "maxSkew": 1,
-            "whenUnsatisfiable": "DoNotSchedule",
-            "labelSelector": {"matchLabels": {}}}]),
+        "spread-priority-after": encode_pod(PodInfo(
+            "a", priority=3, labels={"x": "y"}, topology_spread=two)),
+        "spread-node-selector": encode_pod(PodInfo(
+            "a", node_selector={"k": "v"}, topology_spread=two)),
+        "spread-affinity-before": encode_pod(
+            PodInfo("a", topology_spread=two), raw_affinity={"podAffinity": {
+                "requiredDuringSchedulingIgnoredDuringExecution": [{
+                    "topologyKey": "kubernetes.io/hostname",
+                    "labelSelector": {"matchLabels": {"a": "b"}}}]}}),
+        "spread-backslash": encode_pod(PodInfo("a", topology_spread=[
+            zone({"a": 'q"r'})])),
+        "spread-before-tolerations": cell.replace(b"," + tol, b"").replace(
+            b'},"status"', b"," + tol + b'},"status"'),
+        "spread-twice": cell.replace(
+            b'},"status"', spread_key + b']},"status"'),
+        "spread-unclosed": cell.replace(b'}]},"status"', b'}},"status"'),
+        "spread-closed-early": cell.replace(
+            spread_key + b"{", spread_key + b"]{"),
+        "spread-closed-by-brace": mp.replace(
+            b'},"status"', spread_key + b'}},"status"'),
+        "spread-open-string": cell.replace(
+            b'"maxSkew":1', b'"maxSkew:1', 1),
+        "spread-object": cell.replace(spread_key, spread_key[:-1] + b"{", 1),
+        "spread-trailing-key": cell.replace(
+            b'}]},"status"', b'}],"x":1},"status"'),
         "tol-unknown-key": mp.replace(
             b'"operator":"Exists"}',
             b'"operator":"Exists","tolerationSeconds":5}'),
@@ -744,6 +844,14 @@ def _pod_grammar_corpus():
         for cut in (at, at + 1, at + len(m)):
             cases.append((f"cut-{m.decode()}-{cut - at}", mp[:cut], False))
     cases.append(("cut-last-byte", mp[:-1], False))
+    # ... and the same inside the spread span of the cell's pod.
+    at = cell.index(spread_key)
+    for m in (spread_key, b'"maxSkew"', b'"labelSelector"', b'web-0"}}}',
+              b'"kubernetes.io/hostname"', b'}]},"status"'):
+        lo = cell.index(m, at)
+        for cut in (lo, lo + 1, lo + len(m)):
+            cases.append((f"cut-spread-{m.decode()}-{cut - lo}", cell[:cut],
+                          False))
     return cases
 
 
@@ -797,10 +905,10 @@ def test_poll_pods_parses_exactly_what_decode_pod_fast_does(
     )
     assert bool(flags & POD_HAS_NODE) == (ref.node_name is not None)
     assert aux.decode() == (ref.node_name or "")
-    if ref.labels or ref.tolerations:
+    if ref.labels or ref.tolerations or ref.topology_spread:
         assert evb.shape.tolist() == [1] and len(evb.shapes) == 1
         assert decode_pod_shape(*evb.shapes[0]) == (
-            ref.labels, ref.tolerations
+            ref.labels, ref.tolerations, ref.topology_spread
         )
     else:
         assert evb.shape.tolist() == [0] and evb.shapes == ()

@@ -392,10 +392,10 @@ def _window_candidates(packed, profile, *, rows, chunk, k, offset, backend):
     scan window, on the backend asked for."""
     import jax
 
-    from k8s1m_tpu.engine.cycle import candidates
+    from k8s1m_tpu.engine.cycle import candidates, has_selectors
     from k8s1m_tpu.snapshot.pod_encoding import unpack_pod_batch
 
-    aff = bool(packed.groups & {"sel", "req", "pref"})
+    aff = has_selectors(packed.groups)
 
     def impl(table, ints, bools, key, constraints):
         batch = unpack_pod_batch(

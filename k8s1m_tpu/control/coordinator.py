@@ -112,6 +112,7 @@ from k8s1m_tpu.engine.cycle import (
     Wave,
     adjust_constraints,
     adjust_constraints_impl,
+    candidates_kernel,
     commit_fields_np,
     fill_shape_planes,
     sample_offset_for,
@@ -240,6 +241,16 @@ _BIND_RETIRE = Counter(
 _WAVE_UNBOUND = Counter(
     "coordinator_wave_unbound_total",
     "Valid pods a wave left unbound, by why", ("reason",),
+)
+# Once per dispatched wave (_launch): the candidates kernel its step was
+# built with, as engine/cycle.candidates_kernel names it from the wave's
+# packed field groups -- on the pallas backend the name the kernel has in
+# HLO and in a device trace (fused_topk, fused_topk_affinity,
+# fused_topk_constraints, ...).
+_WAVES = Counter(
+    "coordinator_waves_total",
+    "Waves dispatched to the device, by the candidates kernel of their step",
+    ("kernel",),
 )
 # Once per frame, never per pod: pods taken natively over `interned` is
 # the shape table's hit share, over `bound` the binding cache's.
@@ -708,6 +719,17 @@ class _VictimRows:
         return NotImplemented
 
     __hash__ = None
+
+
+def window_rows_of(used_rows: int, table_rows: int, chunk: int, rows: int) -> int:
+    """The rows a sampled wave's window rotates over: those that have ever
+    held a node (the host table's high-water mark, rounded up to whole
+    chunks), not the table's capacity -- a window over rows no node has
+    had finds no candidate and sends its whole wave back.  At least one
+    window, at most the table.  (Upstream's percentageOfNodesToScore walks
+    the nodes of the snapshot; it has no empty slots to walk.)"""
+    covered = -(-used_rows // chunk) * chunk
+    return min(table_rows, max(rows, covered))
 
 
 @guarded_by(
@@ -3267,7 +3289,10 @@ class Coordinator:
     def _next_window(self, rows: int) -> int:
         i = self._window_i
         self._window_i += 1
-        return sample_offset_for(i, self._window_nodes, rows)
+        nodes = self._window_nodes
+        if self.mesh is None:
+            nodes = window_rows_of(self.host.high_water, nodes, self.chunk, rows)
+        return sample_offset_for(i, nodes, rows)
 
     def _active_knobs(self):
         """(profile, sample_rows) for the next wave: the configured pair
@@ -3489,6 +3514,10 @@ class Coordinator:
             _DONATION.inc(
                 inplace="yes" if self._donation_inplace else "no"
             )
+        _WAVES.inc(kernel=candidates_kernel(
+            self.backend, batch.groups, self.constraints is not None,
+            "delta" if delta_plan is not None else "full",
+        ))
         # Start the device->host copy of the bind decision now: by the
         # time _complete runs (a drain + encode later), the bytes are
         # already on the host and device_get returns without waiting

@@ -182,11 +182,16 @@ def decode_node(data: bytes) -> NodeInfo:
         mem_kib=parse_mem(alloc.get("memory", "0")),
         pods=int(alloc.get("pods", 0)),
         unschedulable=bool(spec.get("unschedulable", False)),
-        taints=[
-            Taint(t["key"], t.get("value", ""), _EFFECTS[t.get("effect", "")])
-            for t in spec.get("taints", [])
-        ],
+        taints=decode_taints(spec.get("taints", [])),
     )
+
+
+def decode_taints(items: list) -> list[Taint]:
+    """Raw ``spec.taints`` -> Taints."""
+    return [
+        Taint(t["key"], t.get("value", ""), _EFFECTS[t.get("effect", "")])
+        for t in items
+    ]
 
 
 # Exact grammar of encode_node's output for a plain schedulable node
@@ -833,7 +838,7 @@ def decode_pod_shape(labels: bytes, tolerations: bytes, spread: bytes):
         strict=False,
     )
     return (
-        dict(obj["labels"]), _decode_tolerations(obj["tolerations"]),
+        dict(obj["labels"]), decode_tolerations(obj["tolerations"]),
         obj["spread"],
     )
 
@@ -890,7 +895,8 @@ def bind_pod_constraints(
     pod.ipa_incs = tracker.affinity_matches(namespace, labels)
 
 
-def _decode_tolerations(items: list) -> list[Toleration]:
+def decode_tolerations(items: list) -> list[Toleration]:
+    """Raw ``spec.tolerations`` -> Tolerations."""
     return [
         Toleration(
             key=t.get("key", ""),
@@ -900,6 +906,24 @@ def _decode_tolerations(items: list) -> list[Toleration]:
         )
         for t in items
     ]
+
+
+def decode_node_affinity(
+    node_aff: dict,
+) -> tuple[list[NodeSelectorTerm], list[PreferredSchedulingTerm]]:
+    """Raw ``affinity.nodeAffinity`` -> (required terms, preferred terms)."""
+    req = node_aff.get("requiredDuringSchedulingIgnoredDuringExecution", {})
+    return (
+        [_decode_term(t) for t in req.get("nodeSelectorTerms", [])],
+        [
+            PreferredSchedulingTerm(
+                weight=p.get("weight", 1), term=_decode_term(p["preference"])
+            )
+            for p in node_aff.get(
+                "preferredDuringSchedulingIgnoredDuringExecution", []
+            )
+        ],
+    )
 
 
 def decode_pod_obj(obj: dict, tracker: ConstraintTracker | None = None) -> PodInfo:
@@ -931,17 +955,13 @@ def decode_pod_obj(obj: dict, tracker: ConstraintTracker | None = None) -> PodIn
         # with a garbage priority schedules at 0, it is not rejected.
         priority=pod_priority_of(obj),
         node_selector=dict(spec.get("nodeSelector", {})),
-        tolerations=_decode_tolerations(spec.get("tolerations", [])),
+        tolerations=decode_tolerations(spec.get("tolerations", [])),
     )
 
     aff = spec.get("affinity", {})
-    node_aff = aff.get("nodeAffinity", {})
-    req = node_aff.get("requiredDuringSchedulingIgnoredDuringExecution", {})
-    pod.required_terms = [_decode_term(t) for t in req.get("nodeSelectorTerms", [])]
-    pod.preferred_terms = [
-        PreferredSchedulingTerm(weight=p.get("weight", 1), term=_decode_term(p["preference"]))
-        for p in node_aff.get("preferredDuringSchedulingIgnoredDuringExecution", [])
-    ]
+    pod.required_terms, pod.preferred_terms = decode_node_affinity(
+        aff.get("nodeAffinity", {})
+    )
 
     pod.topology_spread = list(spec.get("topologySpreadConstraints", []))
     if tracker is not None:

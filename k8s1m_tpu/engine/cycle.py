@@ -66,7 +66,7 @@ from k8s1m_tpu.snapshot.constraints import (
 )
 from k8s1m_tpu.snapshot.node_table import NodeTable, commit_binds
 from k8s1m_tpu.snapshot.packing import is_packed, mask_rows_packed, unpack_chunk
-from k8s1m_tpu.snapshot.pod_encoding import PodBatch
+from k8s1m_tpu.snapshot.pod_encoding import SELECTOR_GROUPS, PodBatch
 
 
 @dataclasses.dataclass
@@ -842,7 +842,7 @@ def _jitted_schedule_packed(
 
     # Waves whose pods carry no selectors skip the affinity stage of the
     # fused kernel entirely; the packed field groups already say so.
-    aff = bool(groups & {"sel", "req", "pref"})
+    aff = has_selectors(groups)
 
     def impl(table, ints, bools, key, offset, row_mask, constraints):
         batch = unpack_pod_batch(ints, bools, pod_spec, table_spec, groups)
@@ -1260,3 +1260,35 @@ def fill_shape_planes(
             profile, chunk, packed.spec, packed.table_spec, packed.groups
         )
     return fill(table, packed.ints, packed.bools, fill_slots, pmask, pscore)
+
+
+# ---- which candidates kernel a wave's step is built with ------------------
+
+
+def has_selectors(groups) -> bool:
+    """Whether a wave's packed field groups carry a nodeSelector or a
+    nodeAffinity term: the step of such a wave is built with the affinity
+    stage, every other without it."""
+    return bool(groups & SELECTOR_GROUPS)
+
+
+def candidates_kernel(
+    backend: str, groups, with_constraints: bool, path: str = "full"
+) -> str:
+    """The name of the candidates kernel the step of a wave with these
+    packed field groups is built with (``coordinator_waves_total``'s
+    label): on the pallas backend the string ``pallas_call(name=)`` gets,
+    on the XLA backend the scan's name with the same suffixes (its filter
+    chain skips an absent group at trace time); a ``path="delta"`` wave's
+    is the plane tail's."""
+    if path == "delta":
+        return "delta_plane_topk" if backend == "pallas" else "plane_topk"
+    aff = has_selectors(groups)
+    if backend == "pallas":
+        from k8s1m_tpu.ops.pallas_topk import kernel_name
+
+        return kernel_name(aff, with_constraints)
+    return (
+        "filter_score_topk" + "_affinity" * aff
+        + "_constraints" * bool(with_constraints)
+    )

@@ -858,7 +858,7 @@ def _call(
         # The kernel's name in HLO and in a device trace; the affinity
         # and the constraint variants are different (and far dearer)
         # programs, each read under a name of its own.
-        name="fused_topk" + "_affinity" * with_aff + "_constraints" * with_cons,
+        name=kernel_name(with_aff, with_cons),
         grid=grid,
         in_specs=in_specs,
         out_specs=(out, out),
@@ -1115,6 +1115,16 @@ def pallas_candidates(
         # the i32 candidate payload (no-op on the plain layout).
         zone=jnp.take(table.zone, safe).astype(jnp.int32),
         region=jnp.take(table.region, safe).astype(jnp.int32),
+    )
+
+
+def kernel_name(with_affinity: bool, with_constraints: bool) -> str:
+    """The fused kernel's name by the stages it was built with: what
+    ``pallas_call(name=)`` gets, and so what HLO and a device trace call
+    it."""
+    return (
+        "fused_topk" + "_affinity" * bool(with_affinity)
+        + "_constraints" * bool(with_constraints)
     )
 
 

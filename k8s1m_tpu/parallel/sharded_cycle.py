@@ -70,6 +70,7 @@ from k8s1m_tpu.engine.cycle import (
     candidates,
     commit_fields_of,
     finalize_batch,
+    has_selectors,
 )
 from k8s1m_tpu.parallel.mesh import batch_specs, constraint_specs, table_specs
 from k8s1m_tpu.plugins.registry import Profile
@@ -264,7 +265,7 @@ def make_sharded_packed_step(
     if b_full % dp_size:
         raise ValueError(f"batch {b_full} not divisible by dp={dp_size}")
     b_local = b_full // dp_size
-    aff = bool(groups & {"sel", "req", "pref"})
+    aff = has_selectors(groups)
 
     def _local_step(table, ints, bools, key, offset, constraints=None):
         pod_offset, row_offset = mesh_offsets(table, b_local)

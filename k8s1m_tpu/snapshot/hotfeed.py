@@ -75,6 +75,7 @@ from k8s1m_tpu.obs.metrics import Counter, Gauge
 from k8s1m_tpu.snapshot.pod_encoding import (
     _GROUP_OF,
     _GROUP_SENTINEL,
+    SELECTOR_GROUPS,
     PackedPodBatch,
     PodBatchHost,
     PodInfo,
@@ -641,7 +642,7 @@ class HotPodBatchHost(PodBatchHost):
             and out["tolerated"][: self._fill_dirty[1]].any()
         ):
             groups.add("tol")
-        if groups & {"sel", "req", "pref"}:
+        if groups & SELECTOR_GROUPS:
             groups.add("qkey")
         groups = frozenset(groups)
         # fields as views into the packed buffers: valid after the arena

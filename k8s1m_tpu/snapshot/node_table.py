@@ -292,6 +292,12 @@ class NodeTableHost:
 
     # ---- row management -------------------------------------------------
 
+    @property
+    def high_water(self) -> int:
+        """Rows that have ever held a node: fresh rows are handed out in
+        order, so every live node's row lies below this mark."""
+        return self._next_row
+
     def row_of(self, name: str) -> int:
         return self._row_of[name]
 

@@ -294,6 +294,10 @@ _GROUP_OF: dict[str, str] = {
     f: g for g, fs in _GROUP_FIELDS.items() for f in fs
 }
 ALL_GROUPS: frozenset = frozenset(_GROUP_FIELDS)
+# The groups of a nodeSelector and of nodeAffinity terms: a wave that
+# carries one of them carries the query-key table, and its step is built
+# with the affinity stage (engine/cycle.has_selectors).
+SELECTOR_GROUPS: frozenset = frozenset({"sel", "req", "pref"})
 
 
 @dataclasses.dataclass
@@ -381,7 +385,7 @@ class PodBatchHost:
         groups = {
             g for g, sentinel in _GROUP_SENTINEL.items() if out[sentinel].any()
         }
-        if groups & {"sel", "req", "pref"}:
+        if groups & SELECTOR_GROUPS:
             groups.add("qkey")
         groups = frozenset(groups)
         int_parts, bool_parts = [], []

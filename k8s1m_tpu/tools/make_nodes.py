@@ -15,7 +15,7 @@ import argparse
 import asyncio
 import json
 
-from k8s1m_tpu.control.objects import encode_node, node_key
+from k8s1m_tpu.control.objects import decode_taints, encode_node, node_key
 from k8s1m_tpu.snapshot.node_table import NodeInfo
 from k8s1m_tpu.tools.common import (
     RateReporter,
@@ -36,7 +36,18 @@ def build_node(
     cpu_milli: int = 32000,
     mem_kib: int = 64 << 20,
     pods: int = 110,
+    node_taints: list | None = None,
+    group_taints: dict | None = None,
+    group_labels: dict | None = None,
 ) -> NodeInfo:
+    """``node_taints`` are raw ``spec.taints`` every node carries (KWOK's own
+    nodes carry ``kwok.x-k8s.io/node=fake:NoSchedule``).  A node pool is
+    a kwok-group: ``group_taints`` / ``group_labels`` map a group (the
+    value of the ``kwok-group`` label, a string) to the raw taints and
+    the labels its nodes carry besides — the documentation's dedicated
+    nodes are ``{"9": [dedicated=batch:NoSchedule]}`` and
+    ``{"9": {"dedicated": "batch"}}``."""
+    group = str(i % KWOK_GROUPS)
     return NodeInfo(
         name=f"{prefix}-{i}",
         cpu_milli=cpu_milli,
@@ -44,10 +55,14 @@ def build_node(
         pods=pods,
         labels={
             "type": "kwok",
-            "kwok-group": str(i % KWOK_GROUPS),
+            "kwok-group": group,
             "topology.kubernetes.io/zone": f"zone-{i % zones}",
             "topology.kubernetes.io/region": f"region-{i % regions}",
+            **(group_labels or {}).get(group, {}),
         },
+        taints=decode_taints(
+            [*(node_taints or ()), *(group_taints or {}).get(group, ())]
+        ),
     )
 
 

@@ -10,7 +10,12 @@ import argparse
 import asyncio
 import json
 
-from k8s1m_tpu.control.objects import encode_pod, pod_key
+from k8s1m_tpu.control.objects import (
+    decode_node_affinity,
+    decode_tolerations,
+    encode_pod,
+    pod_key,
+)
 from k8s1m_tpu.snapshot.pod_encoding import PodInfo, Toleration
 from k8s1m_tpu.tools.common import (
     RateReporter,
@@ -30,11 +35,18 @@ def build_pod(
     tolerate_kwok: bool = True,
     app: str | None = None,
     spread_constraints: list | None = None,
+    node_selector: dict | None = None,
+    node_affinity: dict | None = None,
+    tolerations: list | None = None,
 ) -> PodInfo:
     """``app`` is the value of the pod's ``app`` label (default: the
     prefix, as upstream's make_pods labels its pods); ``spread_constraints``
     the pod's raw ``spec.topologySpreadConstraints`` (a Deployment's
-    template carries them, each selecting its own ``app``)."""
+    template carries them, each selecting its own ``app``);
+    ``node_selector`` its ``spec.nodeSelector``, ``node_affinity`` its raw
+    ``spec.affinity.nodeAffinity`` and ``tolerations`` raw
+    ``spec.tolerations`` it carries after the kwok one."""
+    required, preferred = decode_node_affinity(node_affinity or {})
     return PodInfo(
         name=f"{prefix}-{i}",
         namespace=namespace,
@@ -42,11 +54,14 @@ def build_pod(
         mem_kib=mem_kib,
         labels={"app": prefix if app is None else app},
         topology_spread=[dict(c) for c in spread_constraints or ()],
+        node_selector=dict(node_selector or {}),
+        required_terms=required,
+        preferred_terms=preferred,
         # The reference's pods tolerate the kwok taint
         # (make_pods/main.go sets tolerations for kwok.x-k8s.io/node).
         tolerations=(
             [Toleration(key="kwok.x-k8s.io/node")] if tolerate_kwok else []
-        ),
+        ) + decode_tolerations(tolerations or ()),
     )
 
 

@@ -24,7 +24,10 @@ prints set-up seconds and counts, never a rate.
   packed int16/int8 planes — and the pallas delta tail behind the
   candidate index), each pallas ``(idx, prio)`` compared bit for bit
   with the XLA scan's on the same inputs, plus the XLA scan itself at
-  the window shape and over all 1M rows.
+  the window shape and over all 1M rows; and ``greedy_assign`` (a wave's
+  conflicts in parallel rounds) against the sequential scan it replaced,
+  the four outputs bit for bit, over a wave of this cluster and over the
+  last waves that fill a 10,000-node cluster to its brim.
 - **Mesh phase** (>= 4 chips): phase A once more over an explicit 1x4
   mesh, and a one-chip / 1x4 pair at percentageOfNodesToScore 100 whose
   binds must be byte-identical (sampled windows rotate shard-locally by
@@ -53,6 +56,7 @@ WAVES = 6               # full waves in the checked window (>= 20,480 pods)
 WEBHOOK_PODS = 256
 ORACLE_SAMPLE = 256
 MESH_WAVES = 2
+FIT_NODES = 10_000      # fit-10k's cluster, filled to its brim in phase B
 SEED = 0
 
 
@@ -453,6 +457,150 @@ def _variant(name, table, packed, profile, timer, *, chunk, k, sample_rows,
     return rows
 
 
+def sequential_assign(cand_idx, cand_prio, cand_cpu, cand_mem, cand_pods,
+                       pod_cpu, pod_mem, pod_valid):
+    """The reference: a wave's conflicts as one sequential scan, pod i
+    taking the first of its candidates with room after pods j < i — what
+    ``greedy_assign`` ran as until its rounds, kept to hold the order: here
+    on the chip, and as ``parents_greedy_assign`` in tests/test_spread_waves.py
+    and tests/test_assign_rounds.py on the CPU."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from k8s1m_tpu.ops.priority import unpack_score
+
+    b, _k = cand_idx.shape
+    arange_b = jnp.arange(b)
+
+    def step(carry, _):
+        node_of, bound, i = carry
+        taken = (cand_idx[i][:, None] == node_of[None, :]) & (
+            (arange_b < i) & bound)[None, :]
+        ok = (
+            (cand_prio[i] >= 0) & (cand_idx[i] >= 0)
+            & (pod_cpu[i] <= cand_cpu[i] - (taken * pod_cpu[None, :]).sum(-1))
+            & (pod_mem[i] <= cand_mem[i] - (taken * pod_mem[None, :]).sum(-1))
+            & (cand_pods[i] - taken.sum(-1) >= 1)
+        )
+        any_ok = ok.any() & pod_valid[i]
+        kstar = jnp.argmax(ok)
+        node = jnp.where(any_ok, cand_idx[i, kstar], -1)
+        score = jnp.where(any_ok, unpack_score(cand_prio[i, kstar]), -1)
+        carry = (node_of.at[i].set(node), bound.at[i].set(any_ok), i + 1)
+        return carry, (node, any_ok, score, kstar.astype(jnp.int32))
+
+    init = (jnp.full((b,), -1, jnp.int32), jnp.zeros((b,), bool), jnp.int32(0))
+    return lax.scan(step, init, None, length=b)[1]
+
+
+def _assign_inputs(packed, profile, *, chunk, k, window):
+    """Jitted ``(table, ints, bools, key) -> greedy_assign's eight
+    arguments``: the wave's candidates from the fused kernel with what
+    each holds free, and the pods' requests."""
+    import jax
+
+    from k8s1m_tpu.engine.cycle import candidates, commit_fields_of
+    from k8s1m_tpu.snapshot.pod_encoding import unpack_pod_batch
+
+    def impl(table, ints, bools, key):
+        batch = unpack_pod_batch(
+            ints, bools, packed.spec, packed.table_spec, packed.groups
+        )
+        cand = candidates(
+            table, batch, key, None, profile, chunk=chunk, k=k,
+            backend="pallas", with_affinity=False, window=window,
+        )
+        fields = commit_fields_of(batch)
+        return (cand.idx, cand.prio, cand.cpu, cand.mem, cand.pods,
+                fields.cpu, fields.mem, fields.valid)
+
+    return jax.jit(impl)
+
+
+def _assign_variant(table, base, base_profile, timer, *, chunk, k,
+                    sample_rows, offset, fit_nodes):
+    """``greedy_assign`` against ``sequential_assign`` on the chip, bit
+    for bit: one wave of this cluster (nobody bumped: one round), then
+    ``fit-10k``'s deployment filled by the normal step function, every
+    wave over its last sixteen waves' worth of slots compared before it
+    is committed (pod slots run out: several rounds a wave, unbound pods)."""
+    import jax
+    import numpy as np
+
+    from k8s1m_tpu.cluster.workload import uniform_pods
+    from k8s1m_tpu.config import PodSpec, TableSpec
+    from k8s1m_tpu.engine.assign import greedy_assign
+    from k8s1m_tpu.engine.cycle import schedule_batch_packed
+    from k8s1m_tpu.plugins.registry import Profile
+    from k8s1m_tpu.snapshot import NodeInfo, NodeTableHost, PodBatchHost
+    from k8s1m_tpu.snapshot.packing import pack_table_auto
+
+    def without_legal(*args):
+        *four, _legal, settled = greedy_assign(*args)
+        return *four, settled
+
+    rounds, scan = jax.jit(without_legal), jax.jit(sequential_assign)
+
+    def compare(label, args) -> list:
+        *got, settled = jax.device_get(rounds(*args))
+        for name, a, b in zip(("node_row", "bound", "score", "chosen_k"),
+                              got, jax.device_get(scan(*args))):
+            _equal(f"assign {label} {name}", a, b)
+        return settled.tolist()
+
+    key = jax.random.key(SEED)
+    with timer.measure("B.assign.window_wave"):
+        inputs = _assign_inputs(
+            base, base_profile, chunk=chunk, k=k,
+            window=None if sample_rows is None else (offset, sample_rows),
+        )
+        settled = compare("1m window", inputs(table, base.ints, base.bools, key))
+    log(f"phase B assign: a wave of the window == the sequential scan; "
+        f"settled (rounds, scan, evaluations) {settled}")
+
+    nodes, batch = fit_nodes, base.batch
+    spec = TableSpec(max_nodes=max(chunk, 1 << (nodes - 1).bit_length()))
+    host = NodeTableHost(spec)
+    for i in range(nodes):
+        host.upsert(NodeInfo(
+            f"kwok-node-{i}", cpu_milli=32_000, mem_kib=64 << 20, pods=110,
+            unschedulable=i % 128 == 127,
+        ))
+    small = pack_table_auto(host, spec)
+    fit = Profile(
+        least_allocated=1, balanced_allocation=0, taint_toleration=0,
+        node_affinity=0, topology_spread=0, interpod_affinity=0,
+    )
+    pods = PodBatchHost(PodSpec(batch=batch), spec, host.vocab).encode_packed(
+        uniform_pods(batch)
+    )
+    inputs = _assign_inputs(pods, fit, chunk=chunk, k=k, window=None)
+    open_slots = (nodes - nodes // 128) * 110
+    bound = waves = 0
+    seen = []
+    with timer.measure("B.assign.fit_10k_brim"):
+        while bound < open_slots:
+            key, sub = jax.random.split(key)
+            if open_slots - bound <= 16 * batch:
+                seen.append(compare(
+                    f"brim wave {waves}",
+                    inputs(small, pods.ints, pods.bools, sub),
+                ))
+            small, _c, _a, rows = schedule_batch_packed(
+                small, pods, sub, profile=fit, chunk=chunk, k=k,
+                backend="pallas",
+            )
+            bound += int((np.asarray(rows) >= 0).sum())
+            waves += 1
+            if waves > 4 * open_slots // batch:
+                raise RuntimeError(f"fit-10k fill: {bound}/{open_slots} bound "
+                                   f"after {waves} waves")
+    if not any(e > 1 for *_s, e in seen):
+        raise RuntimeError(f"fit-10k brim: no wave took a second round {seen}")
+    log(f"phase B assign: {len(seen)} waves at fit-10k's brim ({waves} to fill "
+        f"{bound} slots) == the sequential scan; settled {seen}")
+
+
 def _window_node_names(host, offset: int, rows: int, *, want: int) -> list:
     """Names of ``want`` nodes whose table row lies in the scan window
     (rows follow the store's key order, not the node index)."""
@@ -577,7 +725,8 @@ def _delta_variant(table, vocab, table_spec, timer, *, batch, chunk, k):
     )
 
 
-def phase_b(coord, timer, *, batch, chunk, score_pct) -> dict:
+def phase_b(coord, timer, *, batch, chunk, score_pct,
+            fit_nodes=FIT_NODES) -> dict:
     """Runs every variant even after one fails, so a chip run reports
     all refusals at once; returns {variant: error string} (empty = all
     compiled and matched)."""
@@ -743,6 +892,11 @@ def phase_b(coord, timer, *, batch, chunk, score_pct) -> dict:
             log(f"phase B xla_scan {label}: XLA step binds == pallas step binds")
 
     attempt(failures, "xla_scan", xla_scan)
+
+    # (vi) the conflict rounds against the sequential scan.
+    attempt(failures, "assign", lambda: _assign_variant(
+        table, base, base_profile, timer, fit_nodes=fit_nodes, **win
+    ))
     return failures
 
 

@@ -107,7 +107,7 @@ from k8s1m_tpu.control.objects import (
     pod_key,
     pod_key_str_of_obj,
 )
-from k8s1m_tpu.engine.assign import UNBOUND_REASONS
+from k8s1m_tpu.engine.assign import SETTLED_BY, UNBOUND_REASONS
 from k8s1m_tpu.engine.cycle import (
     Wave,
     adjust_constraints,
@@ -241,6 +241,21 @@ _BIND_RETIRE = Counter(
 _WAVE_UNBOUND = Counter(
     "coordinator_wave_unbound_total",
     "Valid pods a wave left unbound, by why", ("reason",),
+)
+# Once per wave (_complete), from the three sums every device step returns
+# with the wave's rows (engine/assign.greedy_assign): the wave's valid
+# pods by what settled their choice -- rounds = the parallel conflict
+# rounds, scan = the sequential step (the tail the rounds left, or the
+# whole of a wave that counts skew) -- and how often the rounds evaluated
+# the whole wave (a wave without contention: once).
+_ASSIGN_PODS = Counter(
+    "coordinator_assign_pods_total",
+    "Valid pods of dispatched waves, by what settled their choice among "
+    "their candidates", ("path",),
+)
+_ASSIGN_ROUNDS = Counter(
+    "coordinator_assign_rounds_total",
+    "Evaluations of a whole wave's conflicts by the parallel rounds", (),
 )
 # Once per dispatched wave (_launch): the candidates kernel its step was
 # built with, as engine/cycle.candidates_kernel names it from the wave's
@@ -3524,6 +3539,7 @@ class Coordinator:
         # on the transfer.
         try:
             rows_dev.copy_to_host_async()
+            asg.settled.copy_to_host_async()
             if asg.unbound is not None:
                 asg.unbound.copy_to_host_async()
         # Best-effort prefetch: some array types/backends simply lack the
@@ -3802,10 +3818,19 @@ class Coordinator:
         with self._stage("sync_out"):
             # ONE device_get per wave: each fetch is a device->host
             # sync, so the bind decision comes back as a single packed
-            # i32[B] (-1 = unbound) — and, where the wave counted skew,
-            # three sums with it: why its unbound pods stayed so, with
-            # no look at any one pod.
-            node_row, unbound = jax.device_get((rows_dev, asg.unbound))
+            # i32[B] (-1 = unbound) — and three sums with it: what
+            # settled the wave's pods; where the wave counted skew,
+            # three more: why its unbound pods stayed so, with no look
+            # at any one pod.
+            node_row, settled, unbound = jax.device_get(
+                (rows_dev, asg.settled, asg.unbound)
+            )
+            *by_path, rounds = settled.tolist()
+            for path, n in zip(SETTLED_BY, by_path):
+                if n:
+                    _ASSIGN_PODS.inc(n, path=path)
+            if rounds:
+                _ASSIGN_ROUNDS.inc(rounds)
             if unbound is not None:
                 for reason, n in zip(UNBOUND_REASONS, unbound.tolist()):
                     if n:

@@ -4,16 +4,29 @@ The reference schedules pods concurrently and lets two pods race for one
 node; the loser's bind fails at the apiserver and rolls back (reference
 README.adoc:558-560, "optimistic concurrency").  Batched on TPU, the same
 problem is solved *before* binding: every pod brings its top-K candidate
-nodes (already sorted by packed priority), and a sequential lax.scan over
-the batch commits pods in order, re-checking candidate capacity against
-what earlier pods in the batch just took.  A pod whose K candidates are all
-exhausted leaves the batch unbound and is retried next cycle — exactly the
-reference's conflict-rollback, but at O(B*K) cost with no apiserver
-round-trip.
+nodes (already sorted by packed priority), and the batch commits pods in
+order, re-checking candidate capacity against what earlier pods in the
+batch just took.  A pod whose K candidates are all exhausted leaves the
+batch unbound and is retried next cycle — exactly the reference's
+conflict-rollback, but at O(B*K) cost with no apiserver round-trip.
 
-With ``skew`` the same scan also keeps PodTopologySpread's hard zone and
-region constraints (whenUnsatisfiable: DoNotSchedule) exact *inside* the
-wave: it carries the matching-pod count of every (constraint slot, domain)
+The order is a sequential scan's: pod i takes the first of its candidates
+that still has room after pods j < i.  Without ``skew`` it is not *run* as
+one.  Write the scan as a function ``F`` of the whole wave's choices
+(pod i's entry of ``F(c)`` is what the scan's step gives pod i when the
+pods before it chose as ``c`` says).  Entry i reads ``c[j]`` for j < i
+alone, so the scan's result is ``F``'s only fixed point, and where two
+successive iterates agree below index m both equal it below m and the
+later one at m as well.  ``F`` for all pods at once is one fused
+``[B, B*K]`` compare-select-reduce; it is iterated from "every pod takes
+its first candidate with room at wave start" until nothing changes (a wave
+without contention: once), and where rounds stop paying (``_go_on``) the
+scan's own step finishes the pods that have not settled, from m to the
+last pod that has a candidate.
+
+With ``skew`` the sequential scan runs as it always has, and it also keeps
+PodTopologySpread's hard zone and region constraints (whenUnsatisfiable:
+DoNotSchedule) exact *inside* the wave: it carries the matching-pod count of every (constraint slot, domain)
 forward from the wave-start tables, pod by pod in wave order, and a
 candidate is legal for pod i only if binding it keeps ``count + self -
 min over the present domains`` within maxSkew on the counts as pods j < i
@@ -24,8 +37,9 @@ is legal at a pod's turn has a candidate.  Hostname-keyed constraints are
 not re-checked here: their domains are nodes, and the kernel's filter on
 the wave-start counts stands for them.
 
-The scan is tiny (B x K integers) and runs replicated on every device in
-the sharded cycle, so no cross-device coordination is needed at commit time.
+All of it is integer work on B x K candidates and runs replicated on every
+device in the sharded cycle (every device settles the same m and runs the
+same trip counts), so no cross-device coordination is needed at commit time.
 """
 
 from __future__ import annotations
@@ -46,6 +60,37 @@ _BIG = 1 << 30
 # earlier pods of the wave; it had candidates and none was legal under
 # the in-wave counts; the candidates stage found it no feasible row.
 UNBOUND_REASONS = ("capacity", "skew", "no_candidate")
+
+# What settled a valid pod's choice (``greedy_assign``'s ``settled`` holds
+# the two sums, and behind them the evaluations of ``F``): the rounds, or
+# the sequential step (the tail the rounds left; a ``WaveSkew`` wave whole).
+SETTLED_BY = ("rounds", "scan")
+
+# The scan's step is launch latency, ~11 us whatever the wave; a round is
+# arithmetic over B x K x B and ~0.2 ms at 4096 x 4, what 18 steps cost
+# (TPU v5e, PR 36's chip runs): two steps' worth of small operations at any
+# size and ``_ROUND_STEPS`` for the pass itself at 4096 x 4.
+_ROUND_STEPS = 16
+_ROUND_FIXED_STEPS = 2
+# Rounds that may pass before the settled prefix has to have paid for them.
+_GRACE_ROUNDS = 8
+
+
+def _round_steps(b: int, k: int) -> int:
+    """A round's cost over a wave of ``b`` x ``k``, in steps of the scan."""
+    return _ROUND_FIXED_STEPS + _ROUND_STEPS * b * b * k // (4096 * 4096 * 4)
+
+
+def _go_on(m, rounds, last, b: int, k: int):
+    """Whether another round is worth more than the scan's step from pod m
+    to pod ``last`` (behind it no pod has a candidate): more pods are left
+    than a round costs in steps, and the prefix the rounds have settled has
+    paid for all of them but ``_GRACE_ROUNDS`` (a wave whose bumps are
+    scattered shows a short prefix after its first round and nothing left
+    after its second; a bump chain as long as the wave settles a pod a
+    round, and the scan has it after the grace)."""
+    steps = _round_steps(b, k)
+    return (last - m > steps) & (m >= (rounds - _GRACE_ROUNDS) * steps)
 
 
 @struct.dataclass
@@ -83,14 +128,17 @@ def greedy_assign(
     """Returns (node_row i32[B] (-1 unbound), bound bool[B], score i32[B],
     chosen_k i32[B] — index of the winning candidate slot, legal — with
     ``skew``, bool[B]: whether a feasible candidate was legal under the
-    in-wave counts at the pod's turn; without, None: each is).
+    in-wave counts at the pod's turn; without, None: each is — and
+    settled i32[3]: the wave's valid pods by ``SETTLED_BY`` and how often
+    ``F`` was evaluated).
 
-    ``skew=None`` traces the capacity scan alone: nothing of the skew
-    path is in the program."""
+    ``skew=None`` traces the rounds and the capacity step alone; with
+    ``skew`` the one sequential scan alone: nothing of the other path is
+    in either program."""
     b, k = cand_idx.shape
     arange_b = jnp.arange(b)
+    feasible = (cand_prio >= 0) & (cand_idx >= 0)             # [B, K]
     if skew is not None:
-        feasible = (cand_prio >= 0) & (cand_idx >= 0)         # [B, K]
         z = skew.zones
         is_zone = skew.topo == TOPO_ZONE                      # [B, S]
         # each (ref, candidate)'s column of the shared domain axis
@@ -155,14 +203,87 @@ def greedy_assign(
             out += (legal,)
         return carry, out
 
-    # xs=None + carried index: see engine/cycle.py on lifted-constant scans.
-    init = (jnp.full((b,), -1, jnp.int32), jnp.zeros((b,), bool), jnp.int32(0))
+    valid_pods = pod_valid.sum().astype(jnp.int32)
     if skew is not None:
-        init += (counts0,)
-    _, (node_row, bound, score, chosen_k, *legal) = lax.scan(
-        step, init, None, length=b
+        # xs=None + carried index: see engine/cycle.py on lifted-constant scans.
+        init = (
+            jnp.full((b,), -1, jnp.int32), jnp.zeros((b,), bool), jnp.int32(0),
+            counts0,
+        )
+        _, (node_row, bound, score, chosen_k, legal) = lax.scan(
+            step, init, None, length=b
+        )
+        settled = jnp.stack([jnp.int32(0), valid_pods, jnp.int32(0)])
+        return node_row, bound, score, chosen_k, legal, settled
+
+    def choose(dcpu, dmem, dpods):
+        """``step``'s ``ok`` / ``any_ok`` / ``argmax`` for every pod, given
+        what earlier pods took from each of its candidates ([B, K])."""
+        ok = (
+            feasible
+            & (pod_cpu[:, None] <= cand_cpu - dcpu)
+            & (pod_mem[:, None] <= cand_mem - dmem)
+            & (cand_pods - dpods >= 1)
+        )
+        any_ok = ok.any(axis=1) & pod_valid
+        kstar = jnp.argmax(ok, axis=1).astype(jnp.int32)
+        node = jnp.take_along_axis(cand_idx, kstar[:, None], axis=1)[:, 0]
+        return jnp.where(any_ok, node, -1), any_ok, kstar
+
+    flat_idx = cand_idx.reshape(-1)                 # [B*K] candidate rows,
+    flat_pod = jnp.repeat(arange_b, k)              # and whose each is
+
+    def evaluate(node_of):
+        """``F``: one fused compare-select-reduce over [B, B*K], earlier
+        pods down the reduced axis — never an array of that shape in
+        memory.  An unbound pod's -1 meets no candidate that ``feasible``
+        lets through."""
+        taken = (node_of[:, None] == flat_idx[None, :]) & (
+            arange_b[:, None] < flat_pod[None, :]
+        )
+        took = lambda what: jnp.where(taken, what[:, None], 0).sum(axis=0)
+        return choose(*(t.reshape(b, k) for t in (
+            took(pod_cpu), took(pod_mem), taken.sum(axis=0, dtype=jnp.int32),
+        )))
+
+    # Behind its last pod with a candidate a wave has nothing to settle
+    # (the padding of a short wave, pods the cluster has no room for): no
+    # choice there depends on an earlier one, and every iterate has it.
+    last = jnp.max(jnp.where(feasible.any(axis=1), arange_b + 1, 0))
+
+    def another_round(state):
+        _, _, _, m, rounds = state
+        return _go_on(m, rounds, last, b, k)
+
+    def round_(state):
+        node_of, _, _, _, rounds = state
+        node, any_ok, kstar = evaluate(node_of)
+        # The first pod the round moved: below it both iterates are the
+        # scan's, and so is the new one's entry at it.
+        same = node == node_of
+        m = jnp.where(same.all(), b, jnp.argmin(same) + 1).astype(jnp.int32)
+        return node, any_ok, kstar, m, rounds + 1
+
+    zeros = jnp.zeros((b, k), jnp.int32)
+    node_of, bound, chosen_k, m, rounds = lax.while_loop(
+        another_round, round_,
+        (*choose(zeros, zeros, zeros), jnp.int32(1), jnp.int32(0)),
     )
-    return node_row, bound, score, chosen_k, legal[0] if legal else None
+
+    def tail(i, carry):
+        node_of, bound, chosen_k = carry
+        (node_of, bound, _), (_, _, _, kstar) = step((node_of, bound, i), None)
+        return node_of, bound, chosen_k.at[i].set(kstar)
+
+    node_row, bound, chosen_k = lax.fori_loop(
+        m, last, tail, (node_of, bound, chosen_k)
+    )
+    prio = jnp.take_along_axis(cand_prio, chosen_k[:, None], axis=1)[:, 0]
+    score = jnp.where(bound, unpack_score(prio), -1)
+    by_scan = (pod_valid & (arange_b >= m) & (arange_b < last)).sum()
+    by_scan = by_scan.astype(jnp.int32)
+    settled = jnp.stack([valid_pods - by_scan, by_scan, rounds])
+    return node_row, bound, score, chosen_k, None, settled
 
 
 def unbound_by_reason(bound, legal, cand_idx, cand_prio, pod_valid):

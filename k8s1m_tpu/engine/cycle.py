@@ -127,6 +127,9 @@ class Assignment:
     # i32[3], engine.assign.UNBOUND_REASONS: the wave's valid pods left
     # unbound, by why.  Only a wave with in-wave skew counts them.
     unbound: jax.Array | None = None
+    # i32[3]: the wave's valid pods by engine.assign.SETTLED_BY, and how
+    # often the conflict rounds evaluated the whole wave.
+    settled: jax.Array | None = None
 
 
 @struct.dataclass
@@ -514,7 +517,7 @@ def finalize_batch(
     ``commit``; ``candidates()`` carries the third), so a device trace
     can be read by phase whatever the ops under them become."""
     with jax.named_scope("assign"):
-        node_row, bound, score, chosen_k, legal = greedy_assign(
+        node_row, bound, score, chosen_k, legal, settled = greedy_assign(
             cand.idx, cand.prio, cand.cpu, cand.mem, cand.pods,
             fields.cpu, fields.mem, fields.valid,
             skew, cand.zone, cand.region,
@@ -527,7 +530,7 @@ def finalize_batch(
         node_row=node_row, bound=bound, score=score,
         zone=jnp.where(bound, take1(cand.zone), 0),
         region=jnp.where(bound, take1(cand.region), 0),
-        unbound=unbound,
+        unbound=unbound, settled=settled,
     )
     if rows is None:
         local = bound

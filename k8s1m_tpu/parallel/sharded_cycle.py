@@ -78,6 +78,10 @@ from k8s1m_tpu.snapshot.constraints import ConstraintState
 from k8s1m_tpu.snapshot.node_table import NodeTable, scatter_rows
 from k8s1m_tpu.snapshot.pod_encoding import PodBatch
 
+# greedy_assign runs replicated: every leaf of its Assignment is whole on
+# every device (no mesh step counts skew in the wave: ``unbound`` is None).
+_ASG_SPECS = Assignment(P(), P(), P(), P(), P(), settled=P())
+
 
 def make_sharded_scatter(table_sharding):
     """Dirty-row scatter pinned to the table's row sharding — the mesh
@@ -178,13 +182,12 @@ def make_sharded_step(mesh, profile: Profile, *, chunk: int, k: int):
         return gather_and_finalize(table, batch, cand, constraints, k=k)
 
     def step(table, batch, key, constraints=None):
-        asg_specs = Assignment(P(), P(), P(), P(), P())
         cons_specs = constraint_specs(constraints) if constraints is not None else None
         return jax.shard_map(
             _local_step,
             mesh=mesh,
             in_specs=(table_specs(table), batch_specs(batch), P(), cons_specs),
-            out_specs=(table_specs(table), cons_specs, asg_specs),
+            out_specs=(table_specs(table), cons_specs, _ASG_SPECS),
             check_vma=False,
         )(table, batch, key, constraints)
 
@@ -308,24 +311,22 @@ def make_sharded_packed_step(
         return table, cons, asg, rows_out
 
     def _step_cons(table, ints, bools, key, offset, constraints):
-        asg_specs = Assignment(P(), P(), P(), P(), P())
         cons_specs = constraint_specs(constraints)
         fn = jax.shard_map(
             _local_step,
             mesh=mesh,
             in_specs=(table_specs(table), P(), P(), P(), P(), cons_specs),
-            out_specs=(table_specs(table), cons_specs, asg_specs, P()),
+            out_specs=(table_specs(table), cons_specs, _ASG_SPECS, P()),
             check_vma=False,
         )
         return fn(table, ints, bools, key, offset, constraints)
 
     def _step_plain(table, ints, bools, key, offset):
-        asg_specs = Assignment(P(), P(), P(), P(), P())
         fn = jax.shard_map(
             lambda t, i, bl, kk, off: _local_step(t, i, bl, kk, off, None),
             mesh=mesh,
             in_specs=(table_specs(table), P(), P(), P(), P()),
-            out_specs=(table_specs(table), None, asg_specs, P()),
+            out_specs=(table_specs(table), None, _ASG_SPECS, P()),
             check_vma=False,
         )
         return fn(table, ints, bools, key, offset)
@@ -465,7 +466,6 @@ def make_sharded_delta_step(
 
     def _step(table, ints, bools, key, slot_ids, pmask, pscore, dirty,
               *inflight):
-        asg_specs = Assignment(P(), P(), P(), P(), P())
         fn = jax.shard_map(
             _local_step,
             mesh=mesh,
@@ -474,7 +474,7 @@ def make_sharded_delta_step(
                 PLANE_SPEC, PLANE_SPEC, P(),
             ) + (P(),) * n_inflight,
             out_specs=(
-                table_specs(table), asg_specs, P(),
+                table_specs(table), _ASG_SPECS, P(),
                 PLANE_SPEC, PLANE_SPEC,
             ),
             check_vma=False,

@@ -152,12 +152,13 @@ def test_smoke_phases_a_and_b_tiny(smoke):
         assert res["compiles_after_warmup"] == 0
         assert all(len(v) == 1 for v in res["binds"].values())
         failures = smoke.phase_b(
-            coord, timer, batch=32, chunk=128, score_pct=5
+            coord, timer, batch=32, chunk=128, score_pct=5, fit_nodes=12
         )
     assert failures == {}
     # Set-up seconds are labelled as such: one entry per executable.
     assert {"A.bootstrap", "B.base.pallas_step", "B.delta.pallas_tail",
-            "B.xla_scan.all_rows.xla_step"} <= set(timer.seconds)
+            "B.xla_scan.all_rows.xla_step", "B.assign.window_wave",
+            "B.assign.fit_10k_brim"} <= set(timer.seconds)
 
 
 def test_smoke_check_catches_a_double_bind(smoke):

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import lax
 
 from k8s1m_tpu.config import PodSpec, TOPO_HOSTNAME, TOPO_ZONE, TableSpec
 from k8s1m_tpu.control.objects import decode_pod, encode_pod, pod_key
@@ -31,12 +30,12 @@ from k8s1m_tpu.engine.assign import (
     UNBOUND_REASONS, greedy_assign, unbound_by_reason,
 )
 from k8s1m_tpu.engine.cycle import candidates, prologue_stats, wave_skew
-from k8s1m_tpu.ops.priority import unpack_score
 from k8s1m_tpu.plugins.registry import Profile
 from k8s1m_tpu.snapshot import NodeInfo, NodeTableHost, PodBatchHost
 from k8s1m_tpu.snapshot.constraints import ConstraintTracker, empty_constraints
 from k8s1m_tpu.snapshot.node_table import REGION_LABEL, ZONE_LABEL
 from k8s1m_tpu.tools.make_pods import build_pod
+from chip_smoke import sequential_assign    # conftest.py: the root is on the path
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ZONES, NODES, WAVE, DEPLOYMENTS = 8, 256, 64, 4
@@ -242,32 +241,10 @@ def test_a_wave_of_one_pod_is_what_it_was(backend):
         assert rows[True] == rows[False] and rows[True][0] >= 0, i
 
 
-def parents_greedy_assign(cand_idx, cand_prio, cand_cpu, cand_mem, cand_pods,
-                          pod_cpu, pod_mem, pod_valid):
-    """``greedy_assign`` as it stood before it learnt to count skew."""
-    b, k = cand_idx.shape
-    arange_b = jnp.arange(b)
-
-    def step(carry, _):
-        node_of, bound, i = carry
-        prev = (arange_b < i) & bound
-        taken = (cand_idx[i][:, None] == node_of[None, :]) & prev[None, :]
-        dcpu = (taken * pod_cpu[None, :]).sum(axis=-1)
-        dmem = (taken * pod_mem[None, :]).sum(axis=-1)
-        dpods = taken.sum(axis=-1)
-        ok = ((cand_prio[i] >= 0) & (cand_idx[i] >= 0)
-              & (pod_cpu[i] <= cand_cpu[i] - dcpu)
-              & (pod_mem[i] <= cand_mem[i] - dmem)
-              & (cand_pods[i] - dpods >= 1))
-        any_ok = ok.any() & pod_valid[i]
-        kstar = jnp.argmax(ok)
-        node = jnp.where(any_ok, cand_idx[i, kstar], -1)
-        score = jnp.where(any_ok, unpack_score(cand_prio[i, kstar]), -1)
-        carry = (node_of.at[i].set(node), bound.at[i].set(any_ok), i + 1)
-        return carry, (node, any_ok, score, kstar.astype(jnp.int32))
-
-    init = (jnp.full((b,), -1, jnp.int32), jnp.zeros((b,), bool), jnp.int32(0))
-    return lax.scan(step, init, None, length=b)[1]
+# ``greedy_assign`` as it stood before it learnt to count skew (and, since
+# PR 36, before its rounds): the one sequential scan, kept where the chip
+# smoke compares with it too.
+parents_greedy_assign = sequential_assign
 
 
 def contended_candidates(seed: int, b: int = 48, k: int = 4):
@@ -282,11 +259,23 @@ def contended_candidates(seed: int, b: int = 48, k: int = 4):
             np.full(b, 1 << 10, np.int32), rng.random(b) < 0.9)
 
 
+def loops_of(jaxpr) -> list[str]:
+    """The loop primitives of a jaxpr, those inside its sub-jaxprs too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("scan", "while"):
+            found.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += loops_of(sub)
+    return found
+
+
 @pytest.mark.parametrize("seed", range(4))
 def test_a_wave_without_constraints_is_the_parents_row_for_row(seed):
-    """Without ``skew`` the scan is the parent's, outputs and program; and
-    with count tables in hand but no pod carrying a constraint, still its
-    rows."""
+    """Without ``skew`` the rows are the parent's scan's (by rounds since
+    PR 36: tests/test_assign_rounds.py); with count tables in hand but no
+    pod carrying a constraint, still its rows, and the program is the one
+    scan: nothing of the rounds is traced with ``skew``."""
     raw = contended_candidates(seed)
     args = tuple(map(jnp.asarray, raw))
     want = parents_greedy_assign(*args)
@@ -295,10 +284,7 @@ def test_a_wave_without_constraints_is_the_parents_row_for_row(seed):
     for a, b in zip(got[:4], want):
         assert (np.asarray(a) == np.asarray(b)).all()
     assert (np.asarray(want[0]) == -1).any()        # some did lose
-    # the same program: nothing of the skew path is traced without it
-    text = lambda f: jax.jit(f).lower(*args).as_text()
-    assert text(lambda *a: tuple(greedy_assign(*a)[:4])) == text(
-        lambda *a: tuple(parents_greedy_assign(*a)))
+    assert int(got[5][:2].sum()) == int(raw[7].sum())
     # count tables, and pods that carry nothing
     host = NodeTableHost(SPEC)
     build_nodes(host)
@@ -314,6 +300,12 @@ def test_a_wave_without_constraints_is_the_parents_row_for_row(seed):
     left = unbound_by_reason(counted[1], counted[4], args[0], args[1], args[7])
     assert int(left[1]) == 0 and int(left.sum()) == int(
         (raw[7] & ~np.asarray(counted[1])).sum())
+    # every pod under ``scan``, and the one scan the only loop traced
+    assert np.asarray(counted[5]).tolist() == [0, int(raw[7].sum()), 0]
+    loops = lambda *extra: loops_of(
+        jax.make_jaxpr(lambda *a: greedy_assign(*a, *extra))(*args).jaxpr)
+    assert loops(skew, zone, zone) == ["scan"]
+    assert loops() == ["while", "while"]             # the rounds, the tail
 
 
 # ---- the served path: store -> Coordinator -> bind_batch -> the watch --------

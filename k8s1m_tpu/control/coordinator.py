@@ -444,37 +444,68 @@ _BIND_LATENCY = Histogram(
 )
 
 
+def _constraintful(pod: "PodInfo | PodShape") -> bool:
+    """Whether a bound pod's record keeps its PodInfo: what a later
+    delete takes its increments back with, and what a required
+    anti-affinity term of its own is read from."""
+    return bool(
+        pod.spread_incs
+        or pod.ipa_incs
+        or any(r.required and r.anti for r in pod.affinity_refs)
+    )
+
+
 class PodShape:
     """What the pods of one template share, decoded once per distinct
-    (label map, toleration list, spread constraints) byte span of the
-    native parser's frame and not once per pod: exactly what the JSON
-    lane's PodInfo would hold of it.  As decoded it is bound to no
-    tracker; ``bind`` gives the shape the pods of one namespace refer to,
-    with the constraint slots and increments the JSON lane would have
-    set on each of them.  Immutable after construction (the hotfeed
-    worker reads it)."""
+    quintuple of byte spans of the native parser's frame (label map,
+    nodeSelector, toleration list, affinity, spread constraints) and not
+    once per pod: exactly what the JSON lane's PodInfo would hold of it.
+    As decoded it is bound to no tracker; ``bind`` gives the shape the
+    pods of one namespace refer to, with the constraint slots and
+    increments the JSON lane would have set on each of them.  Immutable
+    after construction (the hotfeed worker reads it)."""
 
-    __slots__ = ("labels", "tolerations", "topology_spread",
-                 "scheduler_name", "spread_refs", "spread_incs", "ipa_incs",
-                 "fp", "coupled", "keeps", "gang", "tenant")
+    __slots__ = ("labels", "node_selector", "tolerations", "required_terms",
+                 "preferred_terms", "affinity", "topology_spread",
+                 "scheduler_name", "spread_refs", "affinity_refs",
+                 "spread_incs", "ipa_incs", "fp", "coupled", "keeps",
+                 "registers", "gang", "tenant")
 
     def __init__(self, labels: dict, tolerations: list, topology_spread: list,
-                 scheduler_name: str, spread_refs: tuple = (),
-                 spread_incs: tuple = (), ipa_incs: tuple = ()) -> None:
+                 scheduler_name: str, *, node_selector: dict | None = None,
+                 required_terms: list | None = None,
+                 preferred_terms: list | None = None,
+                 affinity: dict | None = None, spread_refs: tuple = (),
+                 affinity_refs: tuple = (), spread_incs: tuple = (),
+                 ipa_incs: tuple = ()) -> None:
         self.labels = labels
+        self.node_selector = node_selector or {}
         self.tolerations = tolerations
+        self.required_terms = required_terms or []
+        self.preferred_terms = preferred_terms or []
+        # Raw spec.affinity: its podAffinity / podAntiAffinity terms are
+        # interned per namespace (bind); nodeAffinity is the two lists
+        # above.
+        self.affinity = affinity or {}
         self.topology_spread = topology_spread
         self.scheduler_name = scheduler_name
         self.spread_refs = spread_refs
+        self.affinity_refs = affinity_refs
         self.spread_incs = spread_incs
         self.ipa_incs = ipa_incs
         # hotfeed.fingerprint of every pod that refers to this shape
         # (labels are not structural; PLAIN for a shape of labels alone).
         self.fp = fingerprint(self.pod("/", 0, 0))
         # Its plane reads the live count tables (hotfeed.shape_key).
-        self.coupled = bool(spread_refs or spread_incs or ipa_incs)
-        # A bound pod's record keeps its PodInfo (_constraintful).
-        self.keeps = bool(spread_incs or ipa_incs)
+        self.coupled = bool(
+            spread_refs or affinity_refs or spread_incs or ipa_incs
+        )
+        self.keeps = _constraintful(self)
+        # Binding it registers constraints of its own with the tracker.
+        self.registers = bool(
+            topology_spread or "podAffinity" in self.affinity
+            or "podAntiAffinity" in self.affinity
+        )
         # Whether the labels name a gang (tenancy/policy.gang_of_labels).
         self.gang = gang_of_labels(labels, "") is not None
         # The tenant label's override, None = the namespace is the tenant.
@@ -486,11 +517,21 @@ class PodShape:
         return PodInfo(
             name=name, namespace=ns, cpu_milli=cpu_milli, mem_kib=mem_kib,
             scheduler_name=self.scheduler_name, node_name=node_name,
-            tolerations=list(self.tolerations), labels=dict(self.labels),
+            node_selector=dict(self.node_selector),
+            tolerations=list(self.tolerations),
+            required_terms=list(self.required_terms),
+            preferred_terms=list(self.preferred_terms),
+            labels=dict(self.labels),
             topology_spread=list(self.topology_spread),
             spread_refs=list(self.spread_refs),
+            affinity_refs=list(self.affinity_refs),
             spread_incs=list(self.spread_incs), ipa_incs=list(self.ipa_incs),
         )
+
+    def _binding(self) -> tuple:
+        """What binding to a tracker sets."""
+        return (self.spread_refs, self.affinity_refs, self.spread_incs,
+                self.ipa_incs)
 
     def bind(self, namespace: str, tracker: ConstraintTracker) -> "PodShape":
         """The shape of this template's pods in ``namespace`` as
@@ -501,21 +542,25 @@ class PodShape:
             name="", namespace=namespace, labels=self.labels,
             topology_spread=self.topology_spread,
         )
-        bind_pod_constraints(pod, tracker)
-        if not (pod.spread_refs or pod.spread_incs or pod.ipa_incs):
+        bind_pod_constraints(pod, tracker, self.affinity)
+        bound = (tuple(pod.spread_refs), tuple(pod.affinity_refs),
+                 tuple(pod.spread_incs), tuple(pod.ipa_incs))
+        if not any(bound):
             return self
         return PodShape(
             self.labels, self.tolerations, self.topology_spread,
-            self.scheduler_name, tuple(pod.spread_refs),
-            tuple(pod.spread_incs), tuple(pod.ipa_incs),
+            self.scheduler_name, node_selector=self.node_selector,
+            required_terms=self.required_terms,
+            preferred_terms=self.preferred_terms, affinity=self.affinity,
+            spread_refs=bound[0], affinity_refs=bound[1],
+            spread_incs=bound[2], ipa_incs=bound[3],
         )
 
     def same_binding(self, other) -> bool:
         """Whether ``other`` is a shape that says of the tracker what
         this one says (two bindings of one template)."""
         return isinstance(other, PodShape) and (
-            (self.spread_refs, self.spread_incs, self.ipa_incs)
-            == (other.spread_refs, other.spread_incs, other.ipa_incs)
+            self._binding() == other._binding()
         )
 
 
@@ -554,8 +599,9 @@ class PendingPod:
     gang_id: str = ""
     gang_size: int = 0
     # What a native fast-lane pod holds beyond its scalars — labels,
-    # tolerations, spread constraints and the tracker's increments —
-    # interned per template and namespace (Coordinator._bound_shape);
+    # selectors, tolerations, affinity, spread constraints and the
+    # tracker's slots and increments — interned per template and
+    # namespace (Coordinator._bound_shape);
     # None = it has none of them.  Read only while ``pod`` is None: a
     # materialized or re-decoded PodInfo supersedes it.
     shape: PodShape | None = None
@@ -1220,10 +1266,10 @@ class Coordinator:
         self._retry_rng = random.Random(seed ^ 0xFA017)
         self._sched_bytes = scheduler_name.encode()
         self._name_bytes: list[bytes] = []
-        # (label span, toleration span, spread span) of natively parsed
-        # pods -> their PodShape (_frame_shapes); at most POD_SHAPES_MAX
-        # entries.
-        self._pod_shapes: dict[tuple[bytes, bytes, bytes], PodShape] = {}
+        # The five byte spans of natively parsed pods (PodEventBatch
+        # .shapes) -> their PodShape (_frame_shapes); at most
+        # POD_SHAPES_MAX entries.
+        self._pod_shapes: dict[tuple[bytes, ...], PodShape] = {}
         # (shape, namespace) -> (the tracker's registration counts, the
         # shape bound at them) (_bound_shape).  The shape None is the
         # label-less pod's, which can still match a constraint whose
@@ -1396,13 +1442,7 @@ class Coordinator:
 
     # ---- watch delta application --------------------------------------
 
-    @staticmethod
-    def _constraintful(pod: PodInfo) -> bool:
-        return bool(
-            pod.spread_incs
-            or pod.ipa_incs
-            or any(r.required and r.anti for r in pod.affinity_refs)
-        )
+    _constraintful = staticmethod(_constraintful)
 
     def _victims_note(
         self, key: str, node_name: str, cpu: int, mem: int,
@@ -1777,9 +1817,9 @@ class Coordinator:
 
     def _frame_shapes(self, evb) -> list:
         """The PodShape of every entry of one frame's shape table, at the
-        index the frame's events name it by (0 = None: no labels, no
-        tolerations, no spread constraints).  A span triple not seen
-        before is decoded by the JSON lane's own code
+        index the frame's events name it by (0 = None: none of the five
+        spans).  A span quintuple not seen before is decoded by the JSON
+        lane's own code
         (objects.decode_pod_shape); one that cannot be decoded is False,
         and its pods count as decode errors just as _on_pod_put would
         have counted them."""
@@ -1791,7 +1831,8 @@ class Coordinator:
             if sh is None:
                 try:
                     sh = PodShape(
-                        *decode_pod_shape(*spans), self.scheduler_name
+                        scheduler_name=self.scheduler_name,
+                        **decode_pod_shape(*spans),
                     )
                 except Exception:
                     log.exception("undecodable pod shape")
@@ -1850,9 +1891,10 @@ class Coordinator:
         """Apply one columnar poll_pods drain (store/native.py
         PodEventBatch).  Flag semantics decided natively: CANONICAL means
         the C parser accepted the exact encode_pod shape (scalars, plus a
-        label map, a toleration list and spread constraints that arrive
-        as the index of an interned PodShape); everything else falls back
-        to _on_pod_put's full decode."""
+        label map, a nodeSelector, a toleration list, an affinity object
+        and spread constraints that arrive as the index of an interned
+        PodShape); everything else falls back to _on_pod_put's full
+        decode."""
         plen = len(PODS_PREFIX)
         koff = evb.koff.tolist()
         kb = evb.key_blob
@@ -1881,7 +1923,7 @@ class Coordinator:
                 sh and not (gangs_on and sh.gang) for sh in shapes[1:]
             )
             binding = binding or any(
-                sh and sh.topology_spread for sh in shapes[1:]
+                sh and sh.registers for sh in shapes[1:]
             )
         else:
             shape_l = [None] * evb.n

@@ -605,11 +605,15 @@ def decode_pod(data: bytes, tracker: ConstraintTracker | None = None) -> PodInfo
 
 # Byte landmarks of the canonical encode_pod shape.  The fast parser
 # accepts EXACTLY the objects this module's encode_pod emits for pods
-# whose only free parts are a flat label map, a toleration list and a
-# topologySpreadConstraints array (no selectors, affinity or priority),
-# in either nodeName form — anything else, including any backslash
-# escape anywhere, falls back to the full JSON path.  The native parser
-# (native/memstore parse_pod) is its twin and accepts the same inputs.
+# whose only free parts are a flat label map, a nodeSelector (a flat map
+# of strings too), a toleration list, an affinity object and a
+# topologySpreadConstraints array, in encode_pod's order and in either
+# nodeName form — anything else (a priority, a member out of order, any
+# backslash escape anywhere) falls back to the full JSON path.  Affinity
+# and the spread constraints are only proven balanced here; json.loads
+# and decode_pod_obj's own helpers then make of them what the JSON path
+# would.  The native parser (native/memstore parse_pod) is its twin and
+# accepts the same inputs.
 # This is the restricted-parser analogue of the reference's
 # empirically-restricted Txn support (one shape, fast; everything else
 # rejected — kv_service.rs:126-337).
@@ -628,7 +632,9 @@ _FP_CTR_END = b'"}}}]'
 # encode_pod appends nodeName after containers (dict insertion order);
 # the bind splice inserts it before schedulerName.  Accept both.
 _FP_NODE_APP = b',"nodeName":"'
+_FP_SELECTOR = b',"nodeSelector":{'
 _FP_TOLS = b',"tolerations":['
+_FP_AFFINITY = b',"affinity":{'
 _FP_SPREAD = b',"topologySpreadConstraints":['
 _FP_END = b'},"status":{"phase":"Pending"}}'
 _FP_TOL_EFFECTS = tuple(
@@ -694,16 +700,17 @@ def _scan_tolerations(data: bytes, i: int):
 
 # A string (the value holds no backslash, so it ends at its next quote),
 # a quote that opens none, or one bracket or brace.
-_ARRAY_TOKEN_RE = re.compile(rb'"[^"]*"|["\[\]{}]')
+_NESTED_TOKEN_RE = re.compile(rb'"[^"]*"|["\[\]{}]')
 
 
-def _scan_array(data: bytes, i: int) -> int | None:
-    """Index just past the bracket that closes the JSON array opened
-    just before ``i``, or None (native/memstore scan_array is the twin).
-    Only the nesting is proven: brackets and braces inside strings do not
-    count, and what lies between is json.loads' to judge."""
+def _scan_nested(data: bytes, i: int, closer: bytes) -> int | None:
+    """Index just past the ``closer`` (``]`` or ``}``) that closes the
+    JSON array or object opened just before ``i``, or None
+    (native/memstore scan_nested is the twin).  Only the nesting is
+    proven: brackets and braces inside strings do not count, and what
+    lies between is json.loads' to judge."""
     depth = 1
-    for m in _ARRAY_TOKEN_RE.finditer(data, i):
+    for m in _NESTED_TOKEN_RE.finditer(data, i):
         tok = m.group()
         if len(tok) > 1:
             continue
@@ -714,7 +721,7 @@ def _scan_array(data: bytes, i: int) -> int | None:
         else:
             depth -= 1
             if depth == 0:
-                return m.end() if tok == b"]" else None
+                return m.end() if tok == closer else None
     return None
 
 
@@ -782,21 +789,33 @@ def decode_pod_fast(
             return None
         node_name = data[i:j].decode()
         i = j + 1
+    node_selector: dict[str, str] = {}
+    if data.startswith(_FP_SELECTOR, i):
+        scanned = _scan_labels(data, i + len(_FP_SELECTOR))
+        if scanned is None:
+            return None
+        node_selector, i = scanned
     tolerations: list[Toleration] = []
     if data.startswith(_FP_TOLS, i):
         scanned = _scan_tolerations(data, i + len(_FP_TOLS))
         if scanned is None:
             return None
         tolerations, i = scanned
-    spread = b""
+    affinity = spread = b""
+    if data.startswith(_FP_AFFINITY, i):
+        j = _scan_nested(data, i + len(_FP_AFFINITY), b"}")
+        if j is None:
+            return None
+        affinity = data[i + len(_FP_AFFINITY) : j - 1]
+        i = j
     if data.startswith(_FP_SPREAD, i):
-        j = _scan_array(data, i + len(_FP_SPREAD))
+        j = _scan_nested(data, i + len(_FP_SPREAD), b"]")
         if j is None:
             return None
         spread = data[i + len(_FP_SPREAD) : j - 1]
         i = j
-    # The tail must be the EXACT remainder: proves there is no
-    # nodeSelector/affinity/priority.
+    # The tail must be the EXACT remainder: proves there is no priority
+    # and no member out of encode_pod's order.
     if data[i:] != _FP_END:
         return None
     if not cpu_b.endswith(b"m") or not mem_b.endswith(b"Ki"):
@@ -815,31 +834,53 @@ def decode_pod_fast(
         mem_kib=mem,
         scheduler_name=scheduler_name.decode(),
         node_name=node_name,
+        node_selector=node_selector,
         tolerations=tolerations,
     )
+    aff: dict = {}
+    if affinity:
+        aff = json.loads(b"{%s}" % affinity, strict=False)
+        pod.required_terms, pod.preferred_terms = decode_node_affinity(
+            aff.get("nodeAffinity", {})
+        )
     if spread:
         pod.topology_spread = json.loads(b"[%s]" % spread, strict=False)
     if tracker is not None:
-        bind_pod_constraints(pod, tracker)
+        bind_pod_constraints(pod, tracker, aff)
     return pod
 
 
-def decode_pod_shape(labels: bytes, tolerations: bytes, spread: bytes):
-    """(labels, tolerations, topology_spread) of a natively parsed pod,
-    from the three byte spans the native parser found (between the braces
-    of metadata.labels, between the brackets of spec.tolerations and of
-    spec.topologySpreadConstraints): json.loads and then decode_pod_obj's
-    own handling, so that a shape never means anything else than the JSON
-    lane would have made of the same pod.  Control bytes inside strings
-    are let through, as decode_pod_fast lets them."""
+def decode_pod_shape(
+    labels: bytes, node_selector: bytes, tolerations: bytes, affinity: bytes,
+    spread: bytes,
+) -> dict:
+    """What a natively parsed pod holds beyond its scalars, as PodShape's
+    keywords, from the five byte spans the native parser found (between
+    the braces of metadata.labels, spec.nodeSelector and spec.affinity,
+    between the brackets of spec.tolerations and of
+    spec.topologySpreadConstraints): one json.loads and then
+    decode_pod_obj's own handling, so that a shape never means anything
+    else than the JSON lane would have made of the same pod.
+    ``affinity`` stays raw beside its decoded nodeAffinity: its
+    podAffinity / podAntiAffinity terms are bind_pod_constraints' to
+    intern, per namespace.  Control bytes inside strings are let through,
+    as decode_pod_fast lets them."""
     obj = json.loads(
-        b'{"labels":{%s},"tolerations":[%s],"spread":[%s]}'
-        % (labels, tolerations, spread),
+        b'{"labels":{%s},"nodeSelector":{%s},"tolerations":[%s],'
+        b'"affinity":{%s},"spread":[%s]}'
+        % (labels, node_selector, tolerations, affinity, spread),
         strict=False,
     )
-    return (
-        dict(obj["labels"]), decode_tolerations(obj["tolerations"]),
-        obj["spread"],
+    aff = obj["affinity"]
+    required, preferred = decode_node_affinity(aff.get("nodeAffinity", {}))
+    return dict(
+        labels=dict(obj["labels"]),
+        node_selector=dict(obj["nodeSelector"]),
+        tolerations=decode_tolerations(obj["tolerations"]),
+        required_terms=required,
+        preferred_terms=preferred,
+        affinity=aff,
+        topology_spread=obj["spread"],
     )
 
 
@@ -849,8 +890,8 @@ def bind_pod_constraints(
     """What a pod's constraints and its labels mean to one tracker:
     ``topology_spread`` interned into ``spread_refs`` in order (a
     constraint ahead of an unsupported one keeps its slot), the
-    podAffinity / podAntiAffinity terms of ``affinity`` (spec.affinity;
-    a canonical pod has none) into ``affinity_refs``, then the tracker's
+    podAffinity / podAntiAffinity terms of ``affinity`` (spec.affinity,
+    raw) into ``affinity_refs``, then the tracker's
     matches of the labels.  The one place this is written:
     decode_pod_obj, decode_pod_fast and the coordinator's pod templates
     (PodShape.bind) all come here."""

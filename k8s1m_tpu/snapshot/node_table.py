@@ -655,9 +655,13 @@ class NodeTableHost:
             cpu_alloc=put(self.cpu_alloc),
             mem_alloc=put(self.mem_alloc),
             pods_alloc=put(self.pods_alloc),
-            cpu_req=put(self.cpu_req),
-            mem_req=put(self.mem_req),
-            pods_req=put(self.pods_req),
+            # Copies: on the CPU backend jnp.asarray takes a 64-byte
+            # aligned numpy buffer zero-copy, and the device table would
+            # then read the retire's in-place np.add.at on these three
+            # until its first donated step, and not by its own commit.
+            cpu_req=put(self.cpu_req.copy()),
+            mem_req=put(self.mem_req.copy()),
+            pods_req=put(self.pods_req.copy()),
             label_key=put(self.label_key),
             label_val=put(self.label_val),
             label_num=put(self.label_num),

@@ -42,7 +42,11 @@ import numpy as np
 from flax import struct
 
 from k8s1m_tpu.config import TableSpec
-from k8s1m_tpu.snapshot.node_table import NodeTable, NodeTableHost
+from k8s1m_tpu.snapshot.node_table import (
+    REQ_COLUMNS,
+    NodeTable,
+    NodeTableHost,
+)
 
 # Columns the packed layout compresses, in NodeTable naming.  The
 # bytes/node evidence in bench.py / sched_bench compares exactly this
@@ -319,6 +323,9 @@ def pack_table_host(
             "taint_id", "taint_effect", "zone", "region", "name_id",
         )
     }
+    for name in REQ_COLUMNS:
+        # Copies the device owns (NodeTableHost.to_device says why).
+        cols[name] = cols[name].copy()
     packed = pack_columns_np(cols, pspec)
 
     def put(x):

@@ -92,6 +92,8 @@ class WatchEvent:
 POD_CANONICAL = 1
 POD_HAS_NODE = 2
 POD_SCHED_MATCH = 4
+# Byte spans a shape of the pod frame holds (memstore.cc kShapeSpans).
+_SHAPE_SPANS = 5
 
 
 @dataclasses.dataclass
@@ -110,12 +112,13 @@ class PodEventBatch:
     aoff: "object"      # u32[n+1] offsets into aux_blob
     key_blob: bytes
     aux_blob: bytes
-    # u32[n]: 0 = no labels, tolerations or spread constraints, s > 0 =
-    # shapes[s - 1].
+    # u32[n]: 0 = none of a shape's five spans, s > 0 = shapes[s - 1].
     shape: "object" = None
-    # Each distinct (label span, toleration span, spread span) of the
-    # frame, once: the bytes between the braces of metadata.labels and
-    # between the brackets of spec.tolerations and of
+    # Each distinct quintuple of byte spans of the frame, once, in
+    # encode_pod's order: (labels, nodeSelector, tolerations, affinity,
+    # spread constraints) — the bytes between the braces of
+    # metadata.labels, spec.nodeSelector and spec.affinity and between
+    # the brackets of spec.tolerations and of
     # spec.topologySpreadConstraints.
     shapes: tuple = ()
 
@@ -147,8 +150,9 @@ class PodEventBatch:
         koff = np.frombuffer(data, np.uint32, n + 1, off); off += 4 * (n + 1)
         aoff = np.frombuffer(data, np.uint32, n + 1, off); off += 4 * (n + 1)
         (ns,) = _U32.unpack_from(data, off); off += 4
-        soff = np.frombuffer(data, np.uint32, 3 * ns + 1, off).tolist()
-        off += 4 * (3 * ns + 1)
+        nso = _SHAPE_SPANS * ns + 1
+        soff = np.frombuffer(data, np.uint32, nso, off).tolist()
+        off += 4 * nso
         klen = int(koff[-1])
         key_blob = data[off : off + klen]; off += klen
         alen = int(aoff[-1])
@@ -156,7 +160,9 @@ class PodEventBatch:
         spans = [
             data[off + lo : off + hi] for lo, hi in zip(soff, soff[1:])
         ]
-        shapes = tuple(zip(spans[0::3], spans[1::3], spans[2::3]))
+        shapes = tuple(
+            zip(*(spans[j::_SHAPE_SPANS] for j in range(_SHAPE_SPANS)))
+        )
         return PodEventBatch(
             int(n), canceled, etype, flags, mrev, cpu, mem, koff, aoff,
             key_blob, aux_blob, shape, shapes,

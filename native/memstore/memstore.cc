@@ -1345,15 +1345,17 @@ namespace {
 
 // ---- canonical pod fast parser -------------------------------------------
 // The exact byte landmarks of this framework's encode_pod for pods whose
-// only free parts are a flat label map, a toleration list and a list of
-// topology spread constraints (k8s1m_tpu/control/objects.py decode_pod_fast
-// is the Python twin; the two parsers accept the same inputs so the fast
-// lane and the fallback path can never disagree).  None of the three is
-// interpreted here: the parser proves the grammar of the first two and that
-// the third is a balanced array, and hands back their byte spans, which the
-// frame carries once per distinct triple (a "shape").  Anything else —
-// selectors, affinity, priority, escapes — is left for the caller's full
-// JSON parser.
+// only free parts are a flat label map, a nodeSelector, a toleration list,
+// an affinity object and a list of topology spread constraints, in
+// encode_pod's order (k8s1m_tpu/control/objects.py decode_pod_fast is the
+// Python twin; the two parsers accept the same inputs so the fast lane and
+// the fallback path can never disagree).  None of the five is interpreted
+// here: the parser proves the grammar of the label map, the nodeSelector
+// (a flat map of strings too) and the tolerations, and that affinity is a
+// balanced object and the spread constraints a balanced array, and hands
+// back their byte spans, which the frame carries once per distinct
+// quintuple (a "shape").  Anything else — priority, escapes, members out
+// of order — is left for the caller's full JSON parser.
 constexpr char kPodHead[] =
     "{\"apiVersion\":\"v1\",\"kind\":\"Pod\",\"metadata\":{\"name\":\"";
 constexpr char kPodNs[] = "\",\"namespace\":\"";
@@ -1369,7 +1371,9 @@ constexpr char kPodCtrEnd[] = "\"}}}]";
 // encode_pod appends nodeName after containers (dict insertion order); the
 // bind splice inserts it before schedulerName.  Both are accepted.
 constexpr char kPodNodeApp[] = ",\"nodeName\":\"";
+constexpr char kPodSelector[] = ",\"nodeSelector\":{";
 constexpr char kPodTols[] = ",\"tolerations\":[";
+constexpr char kPodAffinity[] = ",\"affinity\":{";
 constexpr char kPodSpread[] = ",\"topologySpreadConstraints\":[";
 constexpr char kPodEnd[] = "},\"status\":{\"phase\":\"Pending\"}}";
 constexpr char kTolKey[] = "\"key\":\"";
@@ -1377,21 +1381,21 @@ constexpr char kTolOp[] = "\"operator\":\"";
 constexpr char kTolValue[] = ",\"value\":\"";
 constexpr char kTolEffect[] = ",\"effect\":\"";
 
+constexpr int kShapeSpans = 5;
+enum { kLabels, kSelector, kTols, kAffinity, kSpread };
+
 struct PodParse {
   bool has_node = false;
   bool sched_match = false;
   int32_t cpu = 0, mem = 0;
   const char* node = nullptr;
   size_t node_len = 0;
-  // Contents of the label map's braces and of the brackets of the
-  // toleration list and of topologySpreadConstraints (all empty for the
-  // bare pod).
-  const char* labels = "";
-  size_t labels_len = 0;
-  const char* tols = "";
-  size_t tols_len = 0;
-  const char* spread = "";
-  size_t spread_len = 0;
+  // The shape, in encode_pod's order: the contents of the braces of the
+  // label map and of nodeSelector, of the brackets of the toleration list,
+  // of the braces of affinity and of the brackets of
+  // topologySpreadConstraints (all empty for the bare pod).
+  const char* span[kShapeSpans] = {"", "", "", "", ""};
+  size_t len[kShapeSpans] = {0, 0, 0, 0, 0};
 };
 
 inline bool lit_at(std::string_view v, size_t pos, const char* lit,
@@ -1478,12 +1482,12 @@ bool scan_tolerations(std::string_view v, size_t* i) {
   }
 }
 
-// Any JSON array, from just past its opening bracket; *i ends just past
-// the bracket that closes it (objects.py _scan_array).  Only the nesting
-// is proven — a string ends at its next quote, and brackets and braces
-// inside one do not count; the consumer's JSON parser decides the rest,
-// once per distinct span.
-bool scan_array(std::string_view v, size_t* i) {
+// Any JSON array (closer ']') or object (closer '}'), from just past its
+// opening bracket or brace; *i ends just past the one that closes it
+// (objects.py _scan_nested).  Only the nesting is proven — a string ends at
+// its next quote, and brackets and braces inside one do not count; the
+// consumer's JSON parser decides the rest, once per distinct span.
+bool scan_nested(std::string_view v, size_t* i, char closer) {
   int depth = 1;
   for (size_t p = *i; p < v.size(); p++) {
     char c = v[p];
@@ -1493,7 +1497,7 @@ bool scan_array(std::string_view v, size_t* i) {
     } else if (c == '[' || c == '{') {
       depth++;
     } else if ((c == ']' || c == '}') && --depth == 0) {
-      if (c != ']') return false;
+      if (c != closer) return false;
       *i = p + 1;
       return true;
     }
@@ -1512,9 +1516,15 @@ bool parse_pod(std::string_view v, const uint8_t* sched, size_t sched_len,
   j = v.find('"', i);
   if (j == std::string::npos || !lit_at(v, j, LIT(kPodLabels))) return false;
   i = j + sizeof(kPodLabels) - 1;
-  out->labels = v.data() + i;
-  if (!scan_labels(v, &i)) return false;
-  out->labels_len = static_cast<size_t>(v.data() + i - 1 - out->labels);
+  // One optional member: its span runs from `i` to just short of the
+  // closer the scanner consumed.
+  auto take = [&](int which, auto scan) {
+    out->span[which] = v.data() + i;
+    if (!scan()) return false;
+    out->len[which] = static_cast<size_t>(v.data() + i - 1 - out->span[which]);
+    return true;
+  };
+  if (!take(kLabels, [&] { return scan_labels(v, &i); })) return false;
   // scan_labels consumed the map's own brace; kPodSpec opens with
   // metadata's.
   if (!lit_at(v, i, LIT(kPodSpec))) return false;
@@ -1556,20 +1566,25 @@ bool parse_pod(std::string_view v, const uint8_t* sched, size_t sched_len,
     out->node_len = j - i;
     i = j + 1;
   }
+  if (lit_at(v, i, LIT(kPodSelector))) {
+    i += sizeof(kPodSelector) - 1;
+    if (!take(kSelector, [&] { return scan_labels(v, &i); })) return false;
+  }
   if (lit_at(v, i, LIT(kPodTols))) {
     i += sizeof(kPodTols) - 1;
-    out->tols = v.data() + i;
-    if (!scan_tolerations(v, &i)) return false;
-    out->tols_len = static_cast<size_t>(v.data() + i - 1 - out->tols);
+    if (!take(kTols, [&] { return scan_tolerations(v, &i); })) return false;
+  }
+  if (lit_at(v, i, LIT(kPodAffinity))) {
+    i += sizeof(kPodAffinity) - 1;
+    if (!take(kAffinity, [&] { return scan_nested(v, &i, '}'); }))
+      return false;
   }
   if (lit_at(v, i, LIT(kPodSpread))) {
     i += sizeof(kPodSpread) - 1;
-    out->spread = v.data() + i;
-    if (!scan_array(v, &i)) return false;
-    out->spread_len = static_cast<size_t>(v.data() + i - 1 - out->spread);
+    if (!take(kSpread, [&] { return scan_nested(v, &i, ']'); })) return false;
   }
-  // The exact remainder: proves there is no nodeSelector, affinity or
-  // priority.
+  // The exact remainder: proves there is no priority and no member out of
+  // encode_pod's order.
   return v.size() - i == sizeof(kPodEnd) - 1 && lit_at(v, i, LIT(kPodEnd));
 }
 
@@ -1597,9 +1612,9 @@ uint8_t* emit_pod_frame(size_t n, bool canceled, const uint8_t* sched,
   std::vector<int32_t> cpu(n, 0), mem(n, 0);
   std::vector<uint32_t> shape(n, 0), koff(n + 1, 0), aoff(n + 1, 0);
   std::string keys, aux;
-  // The frame's shape table: each distinct (label span, toleration span,
-  // spread span) triple once.  A wave of one template hits `last` every
-  // time; the map is only consulted when the shape changes.
+  // The frame's shape table: each distinct quintuple of spans (PodParse)
+  // once.  A wave of one template hits `last` every time; the map is only
+  // consulted when the shape changes.
   std::string shapes;
   std::vector<uint32_t> soff(1, 0);
   std::unordered_map<std::string, uint32_t> shape_of;
@@ -1623,26 +1638,30 @@ uint8_t* emit_pod_frame(size_t n, bool canceled, const uint8_t* sched,
         }
         cpu[i] = p.cpu;
         mem[i] = p.mem;
-        if (p.labels_len || p.tols_len || p.spread_len) {
-          const char* span[3] = {p.labels, p.tols, p.spread};
-          const uint32_t len[3] = {static_cast<uint32_t>(p.labels_len),
-                                   static_cast<uint32_t>(p.tols_len),
-                                   static_cast<uint32_t>(p.spread_len)};
-          const uint32_t* lo = last ? &soff[3 * (last - 1)] : nullptr;
-          if (lo == nullptr || lo[1] - lo[0] != len[0] ||
-              lo[2] - lo[1] != len[1] || lo[3] - lo[2] != len[2] ||
-              memcmp(shapes.data() + lo[0], span[0], len[0]) != 0 ||
-              memcmp(shapes.data() + lo[1], span[1], len[1]) != 0 ||
-              (len[2] &&
-               memcmp(shapes.data() + lo[2], span[2], len[2]) != 0)) {
-            // The two leading lengths make the joined spans unambiguous.
-            std::string k(reinterpret_cast<const char*>(len), 8);
-            for (int j = 0; j < 3; j++) k.append(span[j], len[j]);
+        constexpr int S = kShapeSpans;
+        uint32_t len[S];
+        uint32_t any = 0;
+        for (int j = 0; j < S; j++) {
+          len[j] = static_cast<uint32_t>(p.len[j]);
+          any |= len[j];
+        }
+        if (any) {
+          bool same = last != 0;
+          if (same) {
+            const uint32_t* lo = &soff[S * (last - 1)];
+            for (int j = 0; same && j < S; j++)
+              same = lo[j + 1] - lo[j] == len[j] &&
+                     memcmp(shapes.data() + lo[j], p.span[j], len[j]) == 0;
+          }
+          if (!same) {
+            // The leading lengths make the joined spans unambiguous.
+            std::string k(reinterpret_cast<const char*>(len), 4 * (S - 1));
+            for (int j = 0; j < S; j++) k.append(p.span[j], len[j]);
             auto ins = shape_of.emplace(
-                std::move(k), static_cast<uint32_t>(soff.size() / 3 + 1));
+                std::move(k), static_cast<uint32_t>(soff.size() / S + 1));
             if (ins.second) {
-              for (int j = 0; j < 3; j++) {
-                shapes.append(span[j], len[j]);
+              for (int j = 0; j < S; j++) {
+                shapes.append(p.span[j], len[j]);
                 soff.push_back(static_cast<uint32_t>(shapes.size()));
               }
             }
@@ -1673,7 +1692,7 @@ uint8_t* emit_pod_frame(size_t n, bool canceled, const uint8_t* sched,
   b.append(reinterpret_cast<const char*>(shape.data()), 4 * n);
   b.append(reinterpret_cast<const char*>(koff.data()), 4 * (n + 1));
   b.append(reinterpret_cast<const char*>(aoff.data()), 4 * (n + 1));
-  put_u32(b, static_cast<uint32_t>(soff.size() / 3));
+  put_u32(b, static_cast<uint32_t>(soff.size() / kShapeSpans));
   b.append(reinterpret_cast<const char*>(soff.data()), 4 * soff.size());
   b.append(keys);
   b.append(aux);

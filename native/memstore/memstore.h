@@ -234,18 +234,20 @@ int ms_watch_poll(ms_store* s, int64_t watcher_id, int max_events,
  * intake firehose.  Same queue semantics as ms_watch_poll (non-blocking,
  * max_events bound), but each PUT value in the canonical encoded-pod
  * shape (the exact byte shape this framework's encode_pod emits for a
- * pod whose only free parts are a flat label map, a toleration list and
- * a topologySpreadConstraints array, including both nodeName forms —
- * the restricted fast-parser contract,
+ * pod whose only free parts are a flat label map, a nodeSelector, a
+ * toleration list, an affinity object and a topologySpreadConstraints
+ * array, in that order, including both nodeName forms — the restricted
+ * fast-parser contract,
  * mirroring how the reference supports exactly the one Txn shape
  * Kubernetes emits, reference kv_service.rs:126-337) is parsed natively,
  * so the consumer never JSON-decodes its own steady-state traffic.
- * Labels, tolerations and spread constraints are not interpreted (the
- * last are only proven to be a balanced array): the frame carries each
- * distinct (label span, toleration span, spread span) triple once, as a
- * shape, and every event the index of its shape, so the consumer decodes
- * a pod template once and not once per pod.  Non-canonical values are
- * returned whole for the caller's full parser.
+ * None of the five is interpreted (nodeSelector is proven a flat map of
+ * strings, as the labels are; affinity only a balanced object and the
+ * spread constraints only a balanced array): the frame carries each
+ * distinct quintuple of byte spans once, as a shape, and every event the
+ * index of its shape, so the consumer decodes a pod template once and not
+ * once per pod.  Non-canonical values (a priority, an escape, a member out
+ * of order) are returned whole for the caller's full parser.
  *
  * sched/sched_len: expected spec.schedulerName; parsed pods are flagged
  * with MS_POD_SCHED_MATCH when equal.
@@ -258,23 +260,25 @@ int ms_watch_poll(ms_store* s, int64_t watcher_id, int max_events,
  *   i64 mod_revision[n]
  *   i32 cpu_milli[n]        0 unless canonical
  *   i32 mem_kib[n]
- *   u32 shape[n]            0 = no labels, tolerations or spread
- *                           constraints (or not canonical); s > 0 =
- *                           shape table entry s-1
+ *   u32 shape[n]            0 = none of the five spans below (or not
+ *                           canonical); s > 0 = shape table entry s-1
  *   u32 key_off[n+1]        offsets into the key blob
  *   u32 aux_off[n+1]        offsets into the aux blob
  *   u32 n_shapes
- *   u32 shape_off[3*n_shapes+1]  offsets into the shape blob: entry s
- *                           holds its labels at [3s, 3s+1), its
- *                           tolerations at [3s+1, 3s+2) and its spread
- *                           constraints at [3s+2, 3s+3)
+ *   u32 shape_off[5*n_shapes+1]  offsets into the shape blob: entry s
+ *                           holds its labels at [5s, 5s+1), its
+ *                           nodeSelector at [5s+1, 5s+2), its tolerations
+ *                           at [5s+2, 5s+3), its affinity at [5s+3, 5s+4)
+ *                           and its spread constraints at [5s+4, 5s+5)
  *   key blob | aux blob | shape blob
  * aux holds: node name (canonical PUT with nodeName), the whole value
  * (non-canonical PUT), or nothing (canonical PUT without nodeName,
- * DELETE).  A shape's labels are the bytes between the braces of
- * metadata.labels, its tolerations and its spread constraints the bytes
- * between the brackets of spec.tolerations and of
- * spec.topologySpreadConstraints; any may be empty, not all three.
+ * DELETE).  A shape's labels, nodeSelector and affinity are the bytes
+ * between the braces of metadata.labels, spec.nodeSelector and
+ * spec.affinity (the last holds nodeAffinity, podAffinity and
+ * podAntiAffinity as they were written), its tolerations and its spread
+ * constraints the bytes between the brackets of spec.tolerations and of
+ * spec.topologySpreadConstraints; any may be empty, not all five.
  * Returns the event count or MS_ERR_NOT_FOUND. */
 int ms_watch_poll_pods(ms_store* s, int64_t watcher_id, int max_events,
                        const uint8_t* sched, size_t sched_len, uint8_t** out,

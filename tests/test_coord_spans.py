@@ -97,7 +97,7 @@ def traced(tmp_path_factory):
     """Bootstrap, a canonical wave, two ``make_pods`` waves offered at once
     (so the hotfeed worker encodes the second behind the first) and a last
     one, all inside one profiler session.  The last wave holds a pod with
-    a ``nodeSelector`` (lane ``json``: its record holds a PodInfo, so the
+    a ``priority`` (lane ``json``: its record holds a PodInfo, so the
     retire enters it one by one) and a pod no node has room for (the
     device gives it no row; with one attempt allowed it is parked at
     once), and the collector is forced twice between steps."""
@@ -130,7 +130,7 @@ def traced(tmp_path_factory):
         _put(store, [build_pod(i) for i in range(2 * WAVE, 3 * WAVE - 2)])
         _put(store, [
             PodInfo("selective", cpu_milli=10, mem_kib=1024,
-                    node_selector={"kwok-group": "3"}),
+                    node_selector={"kwok-group": "3"}, priority=3),
             PodInfo("too-big", cpu_milli=10 ** 7, mem_kib=1024),
         ])
         for _ in range(FORCED_COLLECTIONS):

@@ -1,9 +1,10 @@
 """Interned pod shapes: the native intake lane against the JSON lane.
 
 ``make_pods``' pod carries a label and a toleration, a Deployment's pod
-its spread constraints besides.  The native parser (native/memstore
-parse_pod) proves their grammar and hands back their bytes once per
-distinct triple; the coordinator decodes each triple once (``PodShape``),
+its spread constraints besides, a pinned pod a ``nodeSelector`` or an
+``affinity``.  The native parser (native/memstore parse_pod) proves their
+grammar and hands back their bytes once per distinct quintuple; the
+coordinator decodes each quintuple once (``PodShape``),
 binds it to the tracker once per namespace and registration state
 (``Coordinator._bound_shape``) and queues ``PendingPod(None, ...,
 shape=...)`` records.  The lane must be invisible: the same pods through a coordinator whose
@@ -13,6 +14,9 @@ batches byte for byte, the same binds and the same accounting.
 """
 
 import dataclasses
+import json
+import os
+import random
 
 import numpy as np
 import pytest
@@ -27,13 +31,19 @@ from k8s1m_tpu.config import (
 from k8s1m_tpu.control import coordinator as coordinator_mod
 from k8s1m_tpu.control.coordinator import Coordinator
 from k8s1m_tpu.control.objects import (
+    decode_pod,
+    decode_pod_fast,
+    decode_pod_obj,
     encode_node,
     encode_pod,
     node_key,
     pod_key,
 )
 from k8s1m_tpu.obs.metrics import REGISTRY
+from k8s1m_tpu.oracle import oracle_feasible
 from k8s1m_tpu.plugins.registry import Profile
+from k8s1m_tpu.snapshot.constraints import ConstraintTracker
+from k8s1m_tpu.snapshot.hotfeed import fingerprint
 from k8s1m_tpu.snapshot.node_table import Taint
 from k8s1m_tpu.snapshot.pod_encoding import PodInfo, Toleration
 from k8s1m_tpu.store.native import MemStore
@@ -88,10 +98,10 @@ class _Lane:
     """One store, one coordinator, and a record of what it launched."""
 
     def __init__(self, native: bool, *, taint=None, nodes=NODES, spec=SPEC,
-                 pods=PODS, **kw) -> None:
+                 pods=PODS, build=build_node, **kw) -> None:
         self.store = MemStore()
         for i in range(nodes):
-            node = build_node(i)
+            node = build(i)
             if taint is not None:
                 node.taints = [taint]
             self.store.put(node_key(node.name), encode_node(node))
@@ -121,10 +131,15 @@ class _Lane:
         if not self.native:
             self.coord._pods_watch = _EventWatch(self.coord._pods_watch)
 
-    def put(self, pods) -> None:
-        self.store.put_batch(
-            [(pod_key(p.namespace, p.name), encode_pod(p)) for p in pods]
-        )
+    def put(self, pods, raw_affinity: dict | None = None) -> None:
+        """``raw_affinity``: pod name -> spec.affinity beside the pod's
+        own nodeAffinity (encode_pod's keyword)."""
+        raw = raw_affinity or {}
+        self.store.put_batch([
+            (pod_key(p.namespace, p.name),
+             encode_pod(p, raw_affinity=raw.get(p.name)))
+            for p in pods
+        ])
 
     def close(self) -> None:
         self.coord.close()
@@ -807,3 +822,390 @@ def test_undecodable_shape_counts_a_decode_error_and_spares_the_rest():
             assert [p.key_str for p in lane.coord.queue] == ["default/good"]
         finally:
             lane.close()
+
+
+# ---- pods that carry a nodeSelector or an affinity -------------------
+
+
+def _json_file(*rel):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, *rel)) as f:
+        return json.load(f)
+
+
+# affinity-100k's cluster and mix at tests/test_affinity_waves.py's size:
+# every node tainted, the nodes of kwok-group 9 a dedicated pool, one
+# node in sixteen cordoned.
+AFF_CONFIG = _json_file("benchmark", "configs", "affinity-100k.json")
+AFF_NODE_KW = {k: v for k, v in AFF_CONFIG["nodes"].items()
+               if k not in ("count", "cordon_every")}
+_AFF_MIX = _json_file("benchmark", "pods", "affinity.json")["shapes"]
+AFF_SHAPES = {s.get("app", "plain"): {k: v for k, v in s.items()
+                                      if k != "weight"}
+              for s in _AFF_MIX}
+AFF_PATTERN = [AFF_SHAPES[s.get("app", "plain")]
+               for s in _AFF_MIX for _ in range(s["weight"])]
+AFF_NODES = 2000
+
+
+def _aff_node(i: int):
+    node = build_node(i, **AFF_NODE_KW)
+    node.unschedulable = i % 16 == 15
+    return node
+
+
+AFF = dict(
+    build=_aff_node, nodes=AFF_NODES,
+    spec=TableSpec(**{**AFF_CONFIG["table_spec"], "max_nodes": 2048}),
+    pods=PodSpec(**{**AFF_CONFIG["pod_spec"], "batch": WAVE}),
+    profile=Profile(**AFF_CONFIG["profile"]),
+)
+# The same cluster with room for inter-pod terms.
+AFF_IPA = dict(
+    AFF, with_constraints=True, profile=Profile(topology_spread=0),
+    spec=TableSpec(**{**AFF_CONFIG["table_spec"], "max_nodes": 2048,
+                      "max_zones": 9, "max_regions": 5, "spread_slots": 2,
+                      "affinity_slots": 4}),
+    pods=PodSpec(**{**AFF_CONFIG["pod_spec"], "batch": WAVE,
+                    "affinity_refs": 2, "ipa_incs": 2}),
+)
+
+_REQUIRED_ALONE = {
+    "requiredDuringSchedulingIgnoredDuringExecution":
+        AFF_SHAPES["zone-pair-1"]["node_affinity"][
+            "requiredDuringSchedulingIgnoredDuringExecution"],
+}
+
+
+def _ipa_term(match: dict, key: str = "kubernetes.io/hostname") -> dict:
+    return {"topologyKey": key, "labelSelector": {"matchLabels": match}}
+
+
+def _anti(match: dict) -> dict:
+    return {"podAntiAffinity": {
+        "requiredDuringSchedulingIgnoredDuringExecution": [_ipa_term(match)]}}
+
+
+# kind -> (build_pod keywords, spec.affinity beside nodeAffinity, lanes')
+SELECTOR_KINDS = {
+    "node-selector": (AFF_SHAPES["group-3"], None, AFF),
+    "required": (dict(app="zone-pair-1", node_affinity=_REQUIRED_ALONE),
+                 None, AFF),
+    "required-preferred": (AFF_SHAPES["zone-pair-0"], None, AFF),
+    "toleration-node-selector": (AFF_SHAPES["dedicated"], None, AFF),
+    "anti-affinity": (dict(app="one-a-node"), _anti({"app": "one-a-node"}),
+                      AFF_IPA),
+    "anti-affinity-and-required": (
+        dict(app="one-a-node", node_affinity=_REQUIRED_ALONE),
+        _anti({"app": "one-a-node"}), AFF_IPA),
+}
+
+
+@pytest.fixture()
+def json_lane(monkeypatch):
+    """_on_pod_put without its byte-scan twin: json.loads and
+    decode_pod_obj for every event, which is what a shape is held to."""
+    monkeypatch.setattr(
+        coordinator_mod, "decode_pod_fast", lambda data, tracker=None: None
+    )
+
+
+def _mixed_wave(kw: dict, n: int = 32) -> list[PodInfo]:
+    """Pods of one selector kind among make_pods' own and label-less
+    ones (every node is tainted: each tolerates that)."""
+    wave = []
+    for i in range(n):
+        if i % 8 == 7:
+            wave.append(PodInfo(
+                f"bare-{i}", cpu_milli=10, mem_kib=1024,
+                tolerations=[Toleration(key="kwok.x-k8s.io/node")]))
+        elif i % 2:
+            wave.append(build_pod(i, cpu_milli=10, mem_kib=1024))
+        else:
+            wave.append(build_pod(i, cpu_milli=10, mem_kib=1024, **kw))
+    return wave
+
+
+def _retired() -> dict:
+    c = REGISTRY.get("coordinator_bind_retire_total")
+    return {k[0]: c.value(lane=k[0]) for k in c.label_keys()}
+
+
+@pytest.mark.parametrize("kind", list(SELECTOR_KINDS))
+def test_a_selector_shape_is_what_the_json_lane_decodes(
+    lanes, json_lane, kind
+):
+    """One kind of pinned pod in a mixed wave, both lanes: no record of
+    the shaped lane holds a PodInfo, each one's PodInfo and fingerprint
+    are decode_pod_obj's, the packed batches are byte-equal and the
+    binds the same."""
+    kw, raw, cluster = SELECTOR_KINDS[kind]
+    shaped, legacy = lanes(**cluster)
+    wave = _mixed_wave(kw)
+    # _mixed_wave pins the even pods.
+    pinned_keys = {p.key for p in wave[::2]}
+    raw_by_name = {p.name: raw for p in wave[::2]} if raw else None
+    before = _counts()
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+        lane.put(wave, raw_by_name)
+        lane.coord.drain_watches()
+    assert _grown(before, _counts()) == {
+        "batch_fast": len(wave), "json": len(wave), "interned": 3,
+        **({"bound": 3} if raw else {}),
+    }
+    assert _queue_view(shaped.coord) == _queue_view(legacy.coord)
+    assert shaped.coord.tracker._affinity == legacy.coord.tracker._affinity
+    for rec, ref in zip(shaped.coord.queue, legacy.coord.queue):
+        assert rec.pod is None and ref.pod is not None
+        made = rec.peek_pod()
+        for f in dataclasses.fields(PodInfo):
+            assert getattr(made, f.name) == getattr(ref.pod, f.name), f.name
+        fp = rec.shape.fp if rec.shape is not None else fingerprint(made)
+        assert fp == fingerprint(ref.pod)
+        assert Coordinator._delta_key(rec) == Coordinator._delta_key(ref)
+    pinned = [p for p in shaped.coord.queue if p.key_str in pinned_keys]
+    assert len(pinned) == len(wave) // 2
+    assert len({id(p.shape) for p in pinned}) == 1
+    retired = _retired()
+    for lane in (shaped, legacy):
+        assert lane.coord.run_until_idle() == len(wave)
+    _assert_waves_equal(shaped, legacy)
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    # A pod that repels its own kind keeps a PodInfo once bound, on both
+    # lanes; every other record of the shaped lane retired in columns.
+    kept = {k for k, r in shaped.coord._bound.items() if r[5] is not None}
+    assert kept == (pinned_keys if raw else set())
+    grown = _grown(retired, _retired())
+    assert grown == {
+        "columnar": len(wave) - len(kept), "per_pod": len(wave) + len(kept)
+    }
+    # ... and every bind honours the pod's selector, terms and taints.
+    for lane in (shaped, legacy):
+        for p in wave:
+            name = lane.coord._bound[p.key][0]
+            assert oracle_feasible(
+                _aff_node(int(name.rsplit("-", 1)[1])), p
+            ), (p.key, name)
+
+
+def test_the_cells_sixteen_unit_pattern_rides_batch_fast_and_retires_in_columns(
+    lanes, json_lane
+):
+    """affinity-100k.fill's traffic: no pod on lane json, seven
+    templates interned once, every record retired on lane columnar; the
+    JSON lane binds the same pods to the same nodes."""
+    shaped, legacy = lanes(**AFF)
+    pattern = list(AFF_PATTERN)
+    random.Random(38).shuffle(pattern)
+    assert len(pattern) == 16
+    waves = [
+        [build_pod(w * WAVE + i, namespace="t", **pattern[i % 16])
+         for i in range(WAVE)]
+        for w in range(3)
+    ]
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+    bound = [0, 0]
+    for k, lane in enumerate((shaped, legacy)):
+        before, retired = _counts(), _retired()
+        for wave in waves:
+            lane.put(wave)
+            lane.coord.drain_watches()
+            if lane is shaped:
+                assert all(p.pod is None and p.shape is not None
+                           for p in lane.coord.queue)
+            bound[k] += lane.coord.run_until_idle()
+        if lane is shaped:
+            assert _grown(before, _counts()) == \
+                {"batch_fast": 3 * WAVE, "interned": 7}
+            assert _grown(retired, _retired()) == {"columnar": 3 * WAVE}
+        else:
+            assert _grown(before, _counts()) == {"json": 3 * WAVE}
+            assert _grown(retired, _retired()) == {"per_pod": 3 * WAVE}
+    assert bound == [3 * WAVE, 3 * WAVE]
+    assert len(shaped.coord._pod_shapes) == 7
+    _assert_waves_equal(shaped, legacy)
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    assert all(r[5] is None for r in shaped.coord._bound.values())
+    for wave in waves:
+        for p in wave:
+            name = shaped.coord._bound[p.key][0]
+            assert oracle_feasible(
+                _aff_node(int(name.rsplit("-", 1)[1])), p), (p.key, name)
+
+
+@pytest.mark.parametrize("affinity, keeps", [
+    (_anti({"app": "x"}), True),
+    ({"podAntiAffinity": {"preferredDuringSchedulingIgnoredDuringExecution": [
+        {"weight": 5, "podAffinityTerm": _ipa_term({"app": "x"})}]}}, False),
+    ({"podAffinity": {"preferredDuringSchedulingIgnoredDuringExecution": [
+        {"weight": 2, "podAffinityTerm": _ipa_term({"app": "x"})}]}}, False),
+    # Its selector takes the pod's own label: the increment keeps it.
+    ({"podAffinity": {"requiredDuringSchedulingIgnoredDuringExecution": [
+        _ipa_term({"app": "bench-pod"}, "topology.kubernetes.io/zone")]}},
+     True),
+    ({"podAffinity": {"preferredDuringSchedulingIgnoredDuringExecution": [
+        {"weight": 2, "podAffinityTerm": _ipa_term({"app": "y"})}]},
+      **_anti({"app": "z"})}, True),
+], ids=["required-anti", "preferred-anti", "preferred-affinity",
+        "required-affinity-to-its-own-kind", "affinity-and-anti"])
+def test_an_inter_pod_term_in_the_span_registers_as_the_json_lane_registers_it(
+    lanes, json_lane, affinity, keeps
+):
+    """With nothing registered, a frame whose shape carries podAffinity /
+    podAntiAffinity still binds its shapes to the tracker: the records
+    carry the term's slot, and a pod that repels (required) keeps its
+    PodInfo once bound, as ``_constraintful`` says of the JSON lane's."""
+    shaped, legacy = lanes(**dict(AFF_IPA, nodes=64))
+    pods = [build_pod(i, cpu_milli=10, mem_kib=1024) for i in range(6)]
+    raw = {p.name: affinity for p in pods[:4]}
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+        assert not lane.coord.tracker._affinity
+        lane.put(pods, raw)
+        lane.coord.drain_watches()
+    assert _queue_view(shaped.coord) == _queue_view(legacy.coord)
+    assert shaped.coord.tracker._affinity == legacy.coord.tracker._affinity
+    assert len(shaped.coord.tracker._affinity) == len(affinity)
+    assert all(p.pod is None for p in shaped.coord.queue)
+    refs = [p.peek_pod().affinity_refs for p in shaped.coord.queue]
+    assert [len(r) for r in refs] == [len(affinity)] * 4 + [0, 0]
+    assert [p.shape.keeps for p in shaped.coord.queue][:4] == [keeps] * 4
+    assert [Coordinator._constraintful(p.pod) for p in legacy.coord.queue][:4] \
+        == [keeps] * 4
+    for lane in (shaped, legacy):
+        assert lane.coord.run_until_idle() == 6
+    _assert_waves_equal(shaped, legacy)
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    kept = sum(r[5] is not None for r in shaped.coord._bound.values())
+    # (the label app=bench-pod of the two plain pods matches the zone
+    # term's selector: they carry its increment and keep a PodInfo too)
+    assert kept == sum(
+        Coordinator._constraintful(p) for p in
+        (r[5] for r in legacy.coord._bound.values()) if p is not None
+    ) >= (4 if keeps else 0)
+
+
+def _undecodable(kind: str) -> bytes:
+    pod = build_pod(0, cpu_milli=10, mem_kib=1024, **AFF_SHAPES["zone-pair-0"])
+    if kind == "no-json":
+        return encode_pod(pod).replace(b'"weight":1', b'"weight":one')
+    if kind == "unknown-operator":
+        return encode_pod(pod).replace(b'"operator":"In"', b'"operator":"Near"')
+    if kind == "node-affinity-is-a-list":
+        return encode_pod(build_pod(0)).replace(
+            b'},"status"', b',"affinity":{"nodeAffinity":[]}},"status"')
+    assert kind == "unsupported-topology-key"
+    return encode_pod(pod, raw_affinity={"podAntiAffinity": {
+        "requiredDuringSchedulingIgnoredDuringExecution": [
+            _ipa_term({"a": "b"}, "example.com/rack")]}})
+
+
+@pytest.mark.parametrize("kind", [
+    "no-json", "unknown-operator", "node-affinity-is-a-list",
+    "unsupported-topology-key",
+])
+def test_an_affinity_span_the_json_lane_refuses_is_a_decode_error_on_both(
+    json_lane, kind
+):
+    """A span that is balanced and no JSON, JSON that decode_pod_obj
+    raises on, and a term the tracker refuses: one decode error a pod on
+    either lane, and the rest of the frame queued."""
+    errors = REGISTRY.get("coordinator_decode_errors_total")
+    bad = _undecodable(kind)
+    for native in (True, False):
+        lane = _Lane(native, **dict(AFF_IPA, nodes=8))
+        try:
+            lane.bootstrap()
+            before = errors.value(kind="pod")
+            lane.store.put_batch([
+                (pod_key("default", "bad-0"), bad),
+                (pod_key("default", "good"), encode_pod(dataclasses.replace(
+                    build_pod(1, **AFF_SHAPES["zone-pair-0"]), name="good"))),
+                (pod_key("default", "bad-1"), bad),
+            ])
+            lane.coord.drain_watches()
+            assert errors.value(kind="pod") - before == 2
+            assert [p.key_str for p in lane.coord.queue] == ["default/good"]
+        finally:
+            lane.close()
+
+
+@pytest.mark.parametrize("kind", list(SELECTOR_KINDS))
+def test_decode_pod_takes_the_byte_scan_and_builds_decode_pod_objs_pod(kind):
+    """``_retry``'s and ``resync``'s decode: the twin accepts the pod, in
+    both nodeName forms, and what it builds against a tracker is what
+    json.loads + decode_pod_obj build against one in the same state."""
+    kw, raw, cluster = SELECTOR_KINDS[kind]
+    pod = build_pod(3, namespace="t", **kw)
+    values = [
+        encode_pod(pod, raw_affinity=raw),
+        encode_pod(dataclasses.replace(pod, node_name="n-1"),
+                   raw_affinity=raw),
+        coordinator_mod.splice_node_name(
+            encode_pod(pod, raw_affinity=raw), "n-2"),
+    ]
+    for value in values:
+        ours, theirs = (ConstraintTracker(cluster["spec"]) for _ in range(2))
+        fast = decode_pod_fast(value, ours)
+        assert fast is not None
+        assert fast == decode_pod_obj(json.loads(value), theirs)
+        assert fast == decode_pod(value, ConstraintTracker(cluster["spec"]))
+        assert ours._affinity == theirs._affinity
+        assert len(fast.affinity_refs) == (1 if raw else 0)
+        assert decode_pod_fast(value) == decode_pod_obj(json.loads(value))
+
+
+def test_an_external_bind_of_a_pinned_pod_is_accounted_as_the_json_lane_does(
+    lanes, json_lane
+):
+    """POD_HAS_NODE with a selector, an affinity and an anti-affinity
+    term, both nodeName forms: accounted with the shape's terms, and the
+    pod that repels keeps its PodInfo."""
+    shaped, legacy = lanes(**dict(AFF_IPA, nodes=16))
+    ext = [
+        build_pod(0, **AFF_SHAPES["dedicated"]),
+        build_pod(1, app="one-a-node", node_affinity=_REQUIRED_ALONE),
+    ]
+    raw = _anti({"app": "one-a-node"})
+    ext[0].node_name = "kwok-node-9"
+    values = [
+        encode_pod(ext[0]),
+        coordinator_mod.splice_node_name(
+            encode_pod(ext[1], raw_affinity=raw), "kwok-node-3"),
+    ]
+    before = _counts()
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+        lane.store.put_batch([
+            (pod_key(p.namespace, p.name), v) for p, v in zip(ext, values)
+        ])
+        lane.coord.drain_watches()
+        assert not lane.coord.queue
+        lane.coord.step()
+    grown = _grown(before, _counts())
+    assert (grown["canonical"], grown["json"]) == (2, 2)
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    kept = shaped.coord._bound["default/bench-pod-1"][5]
+    assert kept == legacy.coord._bound["default/bench-pod-1"][5]
+    assert kept.affinity_refs and kept.required_terms
+    assert shaped.coord._bound["default/bench-pod-0"][5] is None
+
+
+def test_a_priority_keeps_a_pinned_pod_on_lane_json():
+    lane = _Lane(True, **dict(AFF, nodes=16))
+    try:
+        lane.bootstrap()
+        before = _counts()
+        pods = [build_pod(i, **AFF_SHAPES["group-3"]) for i in range(3)]
+        pods[1].priority = 7
+        lane.put(pods)
+        lane.coord.drain_watches()
+        assert _grown(before, _counts()) == \
+            {"canonical": 2, "json": 1, "interned": 1}
+        assert [(p.priority, p.pod is None) for p in lane.coord.queue] == \
+            [(0, True), (7, False), (0, True)]
+        assert lane.coord.run_until_idle() == 3
+    finally:
+        lane.close()

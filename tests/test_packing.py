@@ -116,6 +116,26 @@ def test_roundtrip_every_column_at_width_edges(rng):
     assert packed.label_val.shape == (256, 0)     # fused: no value plane
 
 
+@pytest.mark.parametrize("layout", ["plain", "packed"])
+def test_the_device_table_does_not_read_the_mirrors_request_columns(layout):
+    """On the CPU backend ``jnp.asarray`` takes a 64-byte aligned numpy
+    buffer zero-copy; a device table built from the mirror's own request
+    columns would then learn of binds through the retire's in-place
+    ``np.add.at`` and not through its own commit (seen as the
+    benchmark's fault ``state_unchanged`` passing for sound).  Several
+    mirrors, so that some are aligned whatever the allocator does."""
+    spec = TableSpec(max_nodes=1024)
+    for _ in range(8):
+        host = NodeTableHost(spec)
+        if layout == "packed":
+            table = pack_table_host(host, build_packing_spec(spec, host.vocab))
+        else:
+            table = host.to_device()
+        for name in ("cpu_req", "mem_req", "pods_req"):
+            np.add.at(getattr(host, name), [3, 3, 7], 5)
+            assert int(np.asarray(getattr(table, name)).sum()) == 0, name
+
+
 def test_roundtrip_split_words_layout(rng):
     """The fail-closed fallback layout (fusion off) is also exact."""
     spec = TableSpec(max_nodes=128)

@@ -584,8 +584,9 @@ def test_poll_pods_columnar_frame(store):
 
 
 def test_poll_pods_shape_table(store):
-    """Labels and tolerations come back as byte spans, each distinct
-    pair once a frame, and every event names its pair by index."""
+    """Labels and tolerations come back as byte spans (of the five a
+    shape has, in encode_pod's order), each distinct shape once a frame,
+    and every event names its shape by index."""
     from k8s1m_tpu.control.objects import encode_pod, pod_key
     from k8s1m_tpu.snapshot.pod_encoding import PodInfo, Toleration
     from k8s1m_tpu.store.native import POD_CANONICAL, POD_SCHED_MATCH
@@ -604,18 +605,19 @@ def test_poll_pods_shape_table(store):
     assert evb.flags.tolist() == [POD_CANONICAL | POD_SCHED_MATCH] * 7
     assert evb.shape.tolist() == [1, 0, 1, 2, 3, 1, 2]
     assert evb.shapes == (
-        (b'"app":"bench-pod"',
-         b'{"key":"kwok.x-k8s.io/node","operator":"Exists"}', b""),
-        (b'"a":"b","c":"d"', b"", b""),
-        (b"", b'{"key":"k","operator":"Exists"}', b""),
+        (b'"app":"bench-pod"', b"",
+         b'{"key":"kwok.x-k8s.io/node","operator":"Exists"}', b"", b""),
+        (b'"a":"b","c":"d"', b"", b"", b"", b""),
+        (b"", b"", b'{"key":"k","operator":"Exists"}', b"", b""),
     )
     assert evb.aoff.tolist() == [0] * 8
 
 
 def test_poll_pods_shape_table_holds_the_spread_span(store):
-    """A shape is the triple: two Deployments that differ in their
-    spread constraints alone are two shapes, a pod with constraints and
-    nothing else is one too, and equal triples share an entry."""
+    """The spread span is part of the shape: two Deployments that differ
+    in their spread constraints alone are two shapes, a pod with
+    constraints and nothing else is one too, and equal shapes share an
+    entry."""
     from k8s1m_tpu.control.objects import encode_pod, pod_key
     from k8s1m_tpu.snapshot.pod_encoding import PodInfo
     from k8s1m_tpu.tools.make_pods import build_pod
@@ -642,16 +644,18 @@ def test_poll_pods_shape_table_holds_the_spread_span(store):
             b'"whenUnsatisfiable":"DoNotSchedule",'
             b'"labelSelector":{"matchLabels":{"app":"%s"}}}')
     assert evb.shapes == (
-        (b'"app":"web"', tol, span % (1, b"web")),
-        (b'"app":"web"', tol, span % (2, b"web")),
-        (b'"app":"web"', tol, b""),
-        (b"", b"", span % (1, b"db")),
+        (b'"app":"web"', b"", tol, b"", span % (1, b"web")),
+        (b'"app":"web"', b"", tol, b"", span % (2, b"web")),
+        (b'"app":"web"', b"", tol, b"", b""),
+        (b"", b"", b"", b"", span % (1, b"db")),
     )
 
 
 def _pod_grammar_corpus():
     """(id, value, accepted) for the canonical pod grammar: what both
     parsers must take and the near-misses both must leave to JSON."""
+    import dataclasses
+
     from k8s1m_tpu.config import (
         EFFECT_NO_EXECUTE,
         EFFECT_NO_SCHEDULE,
@@ -664,6 +668,7 @@ def _pod_grammar_corpus():
     from k8s1m_tpu.snapshot.pod_encoding import (
         NodeSelectorTerm,
         PodInfo,
+        PreferredSchedulingTerm,
         SelectorRequirement,
         Toleration,
     )
@@ -742,8 +747,86 @@ def _pod_grammar_corpus():
         "spread-unsupported-key": encode_pod(PodInfo("w", topology_spread=[
             dict(zone({}), topologyKey="example.com/rack")])),
     }
+    # Selectors and affinity: benchmark/pods/affinity.json's four kinds
+    # (nodeSelector alone, required nodeAffinity, required + preferred,
+    # toleration + nodeSelector), each also with nodeName in both forms.
+    required = [NodeSelectorTerm([SelectorRequirement(
+        "topology.kubernetes.io/zone", SEL_OP_IN, ["zone-0", "zone-1"])])]
+    preferred = [PreferredSchedulingTerm(1, NodeSelectorTerm([
+        SelectorRequirement("kwok-group", SEL_OP_IN, ["0"])]))]
+    batch = T("dedicated", TOL_OP_EQUAL, "batch", EFFECT_NO_SCHEDULE)
+    kinds = {
+        "selector": dict(node_selector={"kwok-group": "3"}),
+        "required": dict(required_terms=required),
+        "required-preferred": dict(
+            required_terms=required, preferred_terms=preferred),
+        "dedicated": dict(
+            node_selector={"dedicated": "batch"}, tolerations=[kwok, batch]),
+    }
+    for kind, fields in kinds.items():
+        fields.setdefault("tolerations", [kwok])
+        pod = PodInfo("a", labels={"app": kind}, **fields)
+        accepted[f"kind-{kind}"] = encode_pod(pod)
+        accepted[f"kind-{kind}-node-appended"] = encode_pod(
+            dataclasses.replace(pod, node_name="n-1"))
+        accepted[f"kind-{kind}-node-spliced"] = splice_node_name(
+            encode_pod(pod), "n-2")
+    anti = {"podAntiAffinity": {
+        "requiredDuringSchedulingIgnoredDuringExecution": [{
+            "topologyKey": "kubernetes.io/hostname",
+            "labelSelector": {"matchLabels": {"a": "b"}}}]}}
+    ipa = {"podAffinity": {
+        "requiredDuringSchedulingIgnoredDuringExecution": [{
+            "topologyKey": "kubernetes.io/hostname",
+            "labelSelector": {"matchLabels": {"a": "b"}}}]}}
+    every = PodInfo(
+        "a", labels=three, node_selector={"k": "v", "k2": "v2"},
+        tolerations=[kwok, full], required_terms=required,
+        preferred_terms=preferred, topology_spread=two)
+    accepted.update({
+        "node-selector": encode_pod(PodInfo("a", node_selector={"k": "v"})),
+        "node-selector-tols": encode_pod(PodInfo(
+            "a", node_selector={"k": "v"}, tolerations=[kwok])),
+        "node-selector-brackets": encode_pod(PodInfo(
+            "a", node_selector={"a}": "]{,", "": ""})),
+        "affinity": encode_pod(PodInfo(
+            "a", required_terms=[NodeSelectorTerm([
+                SelectorRequirement("k", SEL_OP_IN, ["v"])])])),
+        "affinity-preferred-alone": encode_pod(PodInfo(
+            "a", preferred_terms=preferred)),
+        "affinity-anti": encode_pod(PodInfo("a"), raw_affinity=anti),
+        "affinity-anti-and-node": encode_pod(
+            PodInfo("a", labels={"a": "b"}, required_terms=required),
+            raw_affinity=anti),
+        # Braces, brackets and commas inside strings are not structure.
+        "affinity-braces-in-values": encode_pod(PodInfo(
+            "a", required_terms=[NodeSelectorTerm([SelectorRequirement(
+                "}{", SEL_OP_IN, ["}", "]}", "{[,"])])])),
+        "spread-node-selector": encode_pod(PodInfo(
+            "a", node_selector={"k": "v"}, topology_spread=two)),
+        "spread-affinity-before": encode_pod(
+            PodInfo("a", topology_spread=two), raw_affinity=ipa),
+        "every-member": encode_pod(every, raw_affinity={**ipa, **anti}),
+        "every-member-node-appended": encode_pod(
+            dataclasses.replace(every, node_name="n-1"), raw_affinity=anti),
+        "every-member-node-spliced": splice_node_name(
+            encode_pod(every, raw_affinity=anti), "n-2"),
+        "every-member-other-scheduler": encode_pod(
+            every, scheduler_name="default-scheduler"),
+    })
     mp = accepted["make-pods"]
     cell = accepted["spread-cell"]
+    whole = accepted["every-member"]
+    sel_key, aff_key = b',"nodeSelector":{', b',"affinity":{'
+    sel = sel_key + b'"k":"v","k2":"v2"}'
+    assert sel in whole and aff_key in whole
+    # Objects the encoder never writes and JSON allows: nothing selected.
+    accepted["node-selector-empty"] = mp.replace(
+        b'}}}],', b'}}}]' + sel_key + b"},")
+    accepted["affinity-empty"] = mp.replace(
+        b'},"status"', aff_key + b'}},"status"')
+    accepted["affinity-whitespace"] = whole.replace(
+        aff_key, aff_key + b" ").replace(b'"weight":1', b'"weight": 1')
     spread_key = b',"topologySpreadConstraints":['
     assert spread_key in cell
     # An array the encoder never writes and JSON allows: no constraints.
@@ -762,21 +845,8 @@ def _pod_grammar_corpus():
         "priority": encode_pod(PodInfo("a", priority=3)),
         "priority-shaped": encode_pod(PodInfo(
             "a", priority=3, labels={"x": "y"}, tolerations=[kwok])),
-        "node-selector": encode_pod(PodInfo("a", node_selector={"k": "v"})),
-        "node-selector-tols": encode_pod(PodInfo(
-            "a", node_selector={"k": "v"}, tolerations=[kwok])),
-        "affinity": encode_pod(PodInfo(
-            "a", required_terms=[NodeSelectorTerm([
-                SelectorRequirement("k", SEL_OP_IN, ["v"])])])),
         "spread-priority-after": encode_pod(PodInfo(
             "a", priority=3, labels={"x": "y"}, topology_spread=two)),
-        "spread-node-selector": encode_pod(PodInfo(
-            "a", node_selector={"k": "v"}, topology_spread=two)),
-        "spread-affinity-before": encode_pod(
-            PodInfo("a", topology_spread=two), raw_affinity={"podAffinity": {
-                "requiredDuringSchedulingIgnoredDuringExecution": [{
-                    "topologyKey": "kubernetes.io/hostname",
-                    "labelSelector": {"matchLabels": {"a": "b"}}}]}}),
         "spread-backslash": encode_pod(PodInfo("a", topology_spread=[
             zone({"a": 'q"r'})])),
         "spread-before-tolerations": cell.replace(b"," + tol, b"").replace(
@@ -830,6 +900,66 @@ def _pod_grammar_corpus():
         "trailing-byte": mp + b" ",
         "cpu-cores": mp.replace(b'"cpu":"100m"', b'"cpu":"1"'),
     }
+    aff_at = whole.index(aff_key)
+    aff_end = whole.index(b',"topologySpreadConstraints"')
+    aff = whole[aff_at:aff_end]
+    tols_at = whole.index(b',"tolerations":[')
+    rejected.update({
+        "priority-node-selector": encode_pod(PodInfo(
+            "a", priority=3, node_selector={"k": "v"})),
+        "priority-affinity": encode_pod(PodInfo(
+            "a", priority=3, required_terms=required)),
+        "priority-every-member": encode_pod(
+            dataclasses.replace(every, priority=1)),
+        "backslash-node-selector": encode_pod(PodInfo(
+            "a", node_selector={"k": 'q"r'})),
+        "backslash-affinity": encode_pod(PodInfo(
+            "a", required_terms=[NodeSelectorTerm([SelectorRequirement(
+                "k", SEL_OP_IN, ["a\\b"])])])),
+        # Members out of encode_pod's order.
+        "node-selector-after-tolerations": whole.replace(sel, b"").replace(
+            aff_key, sel + aff_key),
+        "node-selector-after-affinity": whole.replace(sel, b"").replace(
+            b',"topologySpreadConstraints"',
+            sel + b',"topologySpreadConstraints"'),
+        "node-selector-last": whole.replace(sel, b"").replace(
+            b'},"status"', sel + b'},"status"'),
+        "node-selector-before-appended-node": accepted[
+            "every-member-node-appended"].replace(
+            b',"nodeName":"n-1"', b"").replace(
+            sel, sel + b',"nodeName":"n-1"'),
+        "node-selector-before-containers": whole.replace(sel, b"").replace(
+            b'"containers":', sel[1:] + b',"containers":'),
+        "affinity-before-tolerations": (
+            whole[:tols_at] + aff + whole[tols_at:aff_at] + whole[aff_end:]),
+        "affinity-after-spread": whole.replace(aff, b"").replace(
+            b'},"status"', aff + b'},"status"'),
+        "node-selector-twice": whole.replace(sel, sel + sel_key + b"}"),
+        "affinity-twice": whole.replace(
+            b',"topologySpreadConstraints"',
+            aff_key + b'},"topologySpreadConstraints"'),
+        # A nodeSelector must be a flat map of strings.
+        "node-selector-number": whole.replace(b'"k":"v"', b'"k":1'),
+        "node-selector-null": whole.replace(b'"k":"v"', b'"k":null'),
+        "node-selector-nested": whole.replace(b'"k":"v"', b'"k":{"a":"b"}'),
+        "node-selector-array": whole.replace(sel, sel_key[:-1] + b'["k"]'),
+        "node-selector-trailing-comma": whole.replace(
+            b'"k2":"v2"}', b'"k2":"v2",}'),
+        "node-selector-unclosed": whole.replace(b'"k2":"v2"}', b'"k2":"v2"'),
+        # An affinity must be a balanced object.
+        "affinity-array": whole.replace(aff, b',"affinity":[]'),
+        "affinity-array-of-object": whole.replace(
+            aff, b',"affinity":[' + aff[len(aff_key) - 1:] + b"]"),
+        "affinity-string": whole.replace(aff, b',"affinity":"x"'),
+        "affinity-unclosed": whole.replace(aff, aff[:-1]),
+        "affinity-closed-twice": whole.replace(aff, aff + b"}"),
+        "affinity-closed-by-bracket": whole.replace(aff, aff[:-1] + b"]"),
+        "affinity-closed-early": whole.replace(aff_key, aff_key + b"}"),
+        "affinity-open-string": whole.replace(
+            b'"nodeAffinity"', b'"nodeAffinity', 1),
+        "affinity-trailing-key": whole.replace(
+            b'},"status"', b',"x":1},"status"'),
+    })
     cases = [(k, v, True) for k, v in accepted.items()]
     cases += [(k, v, False) for k, v in rejected.items()]
     # A value cut at (and just inside) every landmark of the grammar.
@@ -851,6 +981,15 @@ def _pod_grammar_corpus():
         lo = cell.index(m, at)
         for cut in (lo, lo + 1, lo + len(m)):
             cases.append((f"cut-spread-{m.decode()}-{cut - lo}", cell[:cut],
+                          False))
+    # ... and inside the selector and the affinity of the pod with every
+    # member.
+    for m in (sel_key, b'"k2"', b'"v2"}', aff_key, b'"nodeAffinity"',
+              b'"nodeSelectorTerms"', b'"zone-1"]}]}]}',
+              b'"podAntiAffinity"', b'{"a":"b"}}}]}'):
+        lo = whole.index(m)
+        for cut in (lo, lo + 1, lo + len(m)):
+            cases.append((f"cut-whole-{m.decode()}-{cut - lo}", whole[:cut],
                           False))
     return cases
 
@@ -905,10 +1044,16 @@ def test_poll_pods_parses_exactly_what_decode_pod_fast_does(
     )
     assert bool(flags & POD_HAS_NODE) == (ref.node_name is not None)
     assert aux.decode() == (ref.node_name or "")
-    if ref.labels or ref.tolerations or ref.topology_spread:
+    spec = json.loads(value)["spec"]
+    raw_affinity = spec.get("affinity", {})
+    if (ref.labels or ref.node_selector or ref.tolerations or raw_affinity
+            or ref.topology_spread):
         assert evb.shape.tolist() == [1] and len(evb.shapes) == 1
-        assert decode_pod_shape(*evb.shapes[0]) == (
-            ref.labels, ref.tolerations, ref.topology_spread
+        assert decode_pod_shape(*evb.shapes[0]) == dict(
+            labels=ref.labels, node_selector=ref.node_selector,
+            tolerations=ref.tolerations, required_terms=ref.required_terms,
+            preferred_terms=ref.preferred_terms, affinity=raw_affinity,
+            topology_spread=ref.topology_spread,
         )
     else:
         assert evb.shape.tolist() == [0] and evb.shapes == ()

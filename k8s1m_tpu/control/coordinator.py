@@ -450,9 +450,9 @@ _BIND_LATENCY = Histogram(
 
 
 def _constraintful(pod: "PodInfo | PodShape") -> bool:
-    """Whether a bound pod's record keeps its PodInfo: what a later
-    delete takes its increments back with, and what a required
-    anti-affinity term of its own is read from."""
+    """Whether a bound pod's record keeps what a later delete takes its
+    increments back with (slot 5 of ``_bound``: a fast-lane pod's bound
+    PodShape, any other pod's PodInfo)."""
     return bool(
         pod.spread_incs
         or pod.ipa_incs
@@ -1287,23 +1287,23 @@ class Coordinator:
         # thread-safe to mutate from the handler directly).
         self._external: list[dict] = []
         self._external_lock = threading.Lock()
-        # Bound-pod record per pod key: (node, cpu, mem, zone, region, pod?,
+        # Bound-pod record per pod key: (node, cpu, mem, zone, region, keep,
         # priority, bind seq, tenant, gang id) — ONE record and one dict
         # insert a bind (the retire enters a wave's with one update).
-        # The PodInfo is retained only for constraint-carrying pods — it is
-        # needed to decrement count tables on deletion; plain pods stay
-        # compact (the 1M-pod case must not hold 1M PodInfos).  The last
-        # four are the preemption metadata, kept here so victim selection
-        # never decodes stored objects: the tenant is captured at bind
-        # time (the label override would otherwise be lost for pods whose
-        # PodInfo is not retained), and a nonempty gang id marks the pod
+        # ``keep`` (_constraintful) is None but for a constraint-carrying
+        # pod: what decrements the count tables on deletion, its bound
+        # PodShape (shared by its template) or, off the fast lane, its
+        # PodInfo.  The last four are the preemption metadata, kept here
+        # so victim selection never decodes stored objects: the tenant is
+        # captured at bind time (the label override would otherwise be
+        # lost), and a nonempty gang id marks the pod
         # unpreemptable — evicting one member would strand the rest of
         # its gang bound, the exact state gangs exist to prevent.
         self._bound: dict[str, tuple] = {}
         # Constraint-count corrections awaiting a batched device scatter:
-        # (pod, node_name, zone, region, sign).  sign=+1 for externally
+        # (keep, node_name, zone, region, sign).  sign=+1 for externally
         # bound pods entering the snapshot, -1 for deletions.
-        self._pending_adjusts: list[tuple[PodInfo, str, int, int, int]] = []
+        self._pending_adjusts: list[tuple] = []
         # Bound pods whose node is not in the snapshot yet (bootstrap
         # list/watch interleaving); accounted when the node arrives.
         self._orphan_bound: dict[str, PodInfo] = {}
@@ -2736,7 +2736,7 @@ class Coordinator:
             group = [a for a in pending if a[4] == sign]
             for off in range(0, len(group), b):
                 chunk = group[off : off + b]
-                batch = self.encoder.encode_packed([g[0] for g in chunk])
+                batch = self.encoder.encode_packed(*_adjusted(chunk))
                 fields = commit_fields_np(batch.fields)
                 for arr in scr.values():
                     arr[:] = 0
@@ -4004,10 +4004,10 @@ class Coordinator:
         the store against the bytes it already holds (ms_bind_batch).
         The bookkeeping around it is done by the wave, in columns, for
         the pods that are plain — read from the wave itself: the store
-        has ``bind_batch``, the record has an observed revision, no
-        PodInfo and no shape with constraint increments (a fast-lane
-        record with nothing to keep: the tenant is its shape's or its
-        namespace's), and no fault plan is installed.
+        has ``bind_batch``, the record has an observed revision and no
+        PodInfo (a fast-lane record: the tenant is its shape's or its
+        namespace's, and what its bound record keeps is its shape), and
+        no fault plan is installed.
         Every other pod is an exception and runs the per-pod code, over
         the exception indices only: webhook intake (no revision) and a
         store without ``bind_batch`` bind one by one through _bind ahead
@@ -4088,18 +4088,14 @@ class Coordinator:
             else:
                 sent = np.zeros(n, bool)
             plain = np.zeros(n, bool) if inj_active else sent & _is_none(infos)
-            # A shape with constraint increments (there is none while the
-            # tracker holds no constraint): the bound record keeps a
-            # PodInfo, which the columns below do not make.
+            # Each shape with constraint increments (there is none while
+            # the tracker holds no constraint), mapped to itself: what the
+            # bound record of a fast-lane pod of it keeps (_constraintful).
             tr = self.tracker
             keeping = (
-                {sh for sh in set(shapes) if sh is not None and sh.keeps}
-                if tr._spread or tr._affinity else ()
+                {sh: sh for sh in set(shapes) if sh is not None and sh.keeps}
+                if tr._spread or tr._affinity else {}
             )
-            if keeping:
-                plain &= ~np.fromiter(
-                    map(keeping.__contains__, shapes), bool, n
-                )
             exceptions = np.nonzero(~plain)[0].tolist()
 
         def bind_singly(j: int, now: float) -> bool:
@@ -4199,12 +4195,13 @@ class Coordinator:
                     self._bind_seq + 1, self._bind_seq + columnar + 1
                 )
                 self._bind_seq += columnar
-                tenants = _wave_tenants(k, pick(shapes))
-                # Nothing a record retired here carries is constraintful
-                # (``keeping`` above).
+                sh = pick(shapes)
+                tenants = _wave_tenants(k, sh)
                 self._bound.update(zip(k, zip(
                     on, cpu, mem, pick(zones), pick(regions),
-                    itertools.repeat(None), prio, seqs, tenants, gang,
+                    map(keeping.get, sh) if keeping
+                    else itertools.repeat(None),
+                    prio, seqs, tenants, gang,
                 )))
                 if self._track_victims:
                     for note in zip(
@@ -4228,24 +4225,19 @@ class Coordinator:
                     continue
                 p = wave[j]
                 pod = p.pod
-                if pod is None and shapes[j] in keeping:
-                    # What a later delete hands _process_adjusts to take
-                    # the increments back.
-                    pod = p.ensure_pod()
-                keep = (
-                    pod if pod is not None and self._constraintful(pod)
-                    else None
-                )
                 self._bind_seq += 1
                 # bind_batch takes ANY pod with an observed revision,
                 # decoded or not: a decoded PodInfo supplies the
-                # label-aware tenant, a fast-lane pod's shape knows
-                # whether its labels name one; failing both the key's
+                # label-aware tenant and is what its record keeps, a
+                # fast-lane pod's shape knows whether its labels name
+                # one and is what it keeps; failing both the key's
                 # namespace IS the tenant.
                 if pod is not None:
+                    keep = pod if self._constraintful(pod) else None
                     tenant = tenant_of_pod(pod)
                 else:
                     sh = p.shape
+                    keep = keeping.get(sh)
                     tenant = (
                         sh.tenant if sh is not None else None
                     ) or tenant_of_key(p.key_str)
@@ -4692,3 +4684,20 @@ class Coordinator:
 # the row sharding pinned on top; a replay caller that keeps its input
 # table alive must jit its own non-donating wrapper.
 _scatter_rows_donated = jax.jit(scatter_rows, donate_argnums=(0,))
+
+
+def _adjusted(chunk) -> tuple[list, list]:
+    """``(pods, fps)`` of ``HotPodBatchHost.encode_packed`` for a chunk of
+    ``Coordinator._pending_adjusts``: each entry's slot 5 of a bound
+    record, a PodInfo as itself, a bound PodShape on the encoder's shape
+    path (an adjustment reads the constraint increments alone, which the
+    shape fixes)."""
+    pods, fps = [], []
+    for keep, *_ in chunk:
+        if isinstance(keep, PodShape):
+            pods.append(PendingPod(None, None, 0.0, key_str="/", shape=keep))
+            fps.append(keep.fp)
+        else:
+            pods.append(keep)
+            fps.append(None)
+    return pods, fps

@@ -27,6 +27,7 @@ from k8s1m_tpu.config import TOPO_ZONE, PodSpec, TableSpec
 from k8s1m_tpu.control import coordinator as coordinator_mod
 from k8s1m_tpu.control.coordinator import (
     Coordinator,
+    PodShape,
     _wave_tenants,
     tenant_of_key,
     tenant_of_pod,
@@ -126,15 +127,14 @@ def _reference_bind_wave(self, batch_pods, rows, bound_ok, failed) -> int:
                 ok_mem.append(p.mem_kib)
                 lats.append(now - p.enqueued_at)
                 pod = p.pod
-                if pod is None and p.shape is not None and p.shape.keeps:
+                if pod is not None:
+                    keep = pod if self._constraintful(pod) else None
+                elif p.shape is not None and p.shape.keeps:
                     # A fast-lane record whose shape carries constraint
-                    # increments: the bound record keeps its PodInfo.
-                    pod = p.ensure_pod()
-                keep = (
-                    pod
-                    if pod is not None and self._constraintful(pod)
-                    else None
-                )
+                    # increments: the bound record keeps the shape.
+                    keep = p.shape
+                else:
+                    keep = None
                 node_name = nv[ids_l[j]]
                 self._bind_seq += 1
                 if pod is not None:
@@ -251,6 +251,9 @@ class _Side:
         self.reached = 0            # pods the device gave a row
         self.columnar_due = 0       # of them plain, and bound
         self.fell_back = 0          # of those, bound by _bind after BIND_INVALID
+        # a wave's pods on lane per_pod and coord.bind.per_pod spans opened
+        self.per_pod_waves: list = []
+        self.per_pod_spans = 0
         inner = (
             types.MethodType(_reference_bind_wave, self.coord)
             if reference else self.coord._bind_wave
@@ -264,12 +267,13 @@ class _Side:
                 if getattr(c.store, "bind_batch", None) is not None
                 and batch_pods[i].mod_revision is not None
                 and batch_pods[i].pod is None
-                and not (batch_pods[i].shape is not None
-                         and batch_pods[i].shape.keeps)
                 and not faultline.active_injector().plan.faults
                 and c.host.valid[rows[i]]
             ]
+            spans, retired = self.per_pod_spans, _retired()["per_pod"]
             n = inner(batch_pods, rows, bound_ok, failed)
+            self.per_pod_waves.append((
+                _retired()["per_pod"] - retired, self.per_pod_spans - spans))
             self.reached += len(at)
             self.columnar_due += sum(bool(bound_ok[i]) for i in plain)
             self.masks.append((
@@ -280,6 +284,13 @@ class _Side:
             return n
 
         self.coord._bind_wave = bind_wave
+        stage = self.coord._stage
+
+        def spied(name, *rest, **kw):
+            self.per_pod_spans += name == "per_pod"
+            return stage(name, *rest, **kw)
+
+        self.coord._stage = spied
         if prepare is not None:
             prepare(self.coord)
         self.coord.bootstrap()
@@ -392,6 +403,48 @@ def _constraint_pods(side: _Side):
     return n
 
 
+def _keeping_shapes_beside_a_cas_loss_and_a_retry(side: _Side):
+    """Fast-lane pods whose shape keeps its spread increment, plain ones,
+    the first wave's CAS losses and, later, those pods again: retried,
+    so decoded, each with a PodInfo.  Returns what lost."""
+    lost: list = []
+
+    def fn(real, entries, rest, call):
+        if call == 1:
+            for key, _mod, _node in entries[2::5]:
+                lost.append(key.decode())
+                cur = side.store.get(key)
+                side.store.put(key, cur.value.replace(
+                    b'"labels":{', b'"labels":{"touched":"yes",'))
+        return real(entries, *rest)
+
+    side.plant(fn)
+    side.put(_pods(0, 3 * WAVE - 8) + [
+        PodInfo(f"other-{i}", namespace="bench", cpu_milli=10, mem_kib=1024,
+                labels={"app": "other"})
+        for i in range(8)
+    ])
+    return side.coord.run_until_idle(), lost
+
+
+def _keeping_shapes_under_a_fault_plan(side: _Side):
+    """Under a fault plan every pod takes the per-pod loop: a fast-lane
+    pod's record keeps its shape there too."""
+    side.put(_pods(0, 2 * WAVE))
+    install_plan(FaultPlan(
+        [FaultSpec("coordinator.bind", "cas", kind="stale_revision",
+                   probability=0.3)],
+        seed=31,
+    ))
+    try:
+        n = side.coord.run_until_idle()
+    finally:
+        install_plan(None)
+    assert sum(isinstance(r[5], PodShape)
+               for r in side.coord._bound.values()) > WAVE
+    return n
+
+
 def _gang_with_one_failing(side: _Side):
     def fn(real, entries, rest, call):
         revs = list(real(entries, *rest))
@@ -470,6 +523,12 @@ def _spread_tracked(coord) -> None:
     coord.tracker.spread_slot("bench", {"app": "bench-pod"}, TOPO_ZONE)
 
 
+def _constrained(_side: str) -> dict:
+    """A spread constraint that selects the ``bench-pod`` pods."""
+    return {"with_constraints": True, "profile": Profile(interpod_affinity=0),
+            "prepare": _spread_tracked}
+
+
 # name -> (drive, exceptions expected, keywords of _Side by side name)
 WAVES = {
     "all_plain": (_all_plain, False, lambda s: {}),
@@ -480,10 +539,11 @@ WAVES = {
     "webhook_pods_mixed_in": (_webhook_pods_mixed_in, True, lambda s: {}),
     "retried_pods_carry_a_podinfo": (
         _retried_pods_carry_a_podinfo, True, lambda s: {}),
-    "constraint_pods": (_constraint_pods, True, lambda s: {
-        "with_constraints": True, "profile": Profile(interpod_affinity=0),
-        "prepare": _spread_tracked,
-    }),
+    "constraint_pods": (_constraint_pods, False, _constrained),
+    "keeping_shapes_beside_a_cas_loss_and_a_retry": (
+        _keeping_shapes_beside_a_cas_loss_and_a_retry, True, _constrained),
+    "keeping_shapes_under_a_fault_plan": (
+        _keeping_shapes_under_a_fault_plan, True, _constrained),
     "gang_with_one_failing": (_gang_with_one_failing, True, lambda s: {
         "tenancy": _tenancy(f"retire-gang-{s}", preempt_enabled=False),
     }),
@@ -519,6 +579,7 @@ def _drive(name: str, reference: bool) -> dict:
             "retired": _delta(_retired(), ret0),
             "reached": side.reached,
             "columnar_due": side.columnar_due - side.fell_back,
+            "per_pod_waves": side.per_pod_waves,
             "store": {
                 kv.key: json.loads(kv.value)["spec"].get("nodeName")
                 for kv in side.store.range(
@@ -532,8 +593,20 @@ def _drive(name: str, reference: bool) -> dict:
 SEQ = 7     # _bind_seq's place in a _bound record
 
 
+def _fields(keep):
+    """Slot 5 field by field: a PodShape (the bound record of a fast-lane
+    pod) has no equality of its own, and each side binds its own."""
+    if isinstance(keep, PodShape):
+        return ("shape", *(getattr(keep, f) for f in PodShape.__slots__))
+    return keep
+
+
+def _comparable(bound: dict) -> dict:
+    return {k: r[:5] + (_fields(r[5]),) + r[6:] for k, r in bound.items()}
+
+
 def _without_seq(bound: dict) -> dict:
-    return {k: r[:SEQ] + r[SEQ + 1:] for k, r in bound.items()}
+    return {k: r[:SEQ] + r[SEQ + 1:] for k, r in _comparable(bound).items()}
 
 
 @pytest.fixture(scope="module")
@@ -567,7 +640,7 @@ def test_columnar_retire_equals_the_per_pod_loop(drives, name):
             assert min(s[0] for s in seqs) == min(s[1] for s in seqs)
             assert max(s[0] for s in seqs) == max(s[1] for s in seqs)
     if not WAVES[name][1]:
-        assert col["bound"] == ref["bound"]
+        assert _comparable(col["bound"]) == _comparable(ref["bound"])
     victims = lambda d: {
         node: {k: (v.key, v.node, v.row, v.cpu_milli, v.mem_kib, v.priority,
                    v.tenant) for k, v in vs.items()}
@@ -604,6 +677,32 @@ def test_the_retire_counter_holds_exactly_the_exceptions(drives, name):
         assert got["per_pod"] == 0
     elif name != "preemption":       # its exception never reaches a row
         assert got["per_pod"] > 0
+
+
+def test_keeping_shapes_retire_in_columns_beside_the_exceptions(drives):
+    """A fast-lane pod whose shape keeps its increment retires in columns,
+    and its record keeps that shape; the CAS losses and the retried pods
+    (a PodInfo each) are the per-pod loop's alone: the span
+    ``coord.bind.per_pod`` opens in a wave exactly where the wave retires
+    a pod on lane ``per_pod``."""
+    ref, col = drives("keeping_shapes_beside_a_cas_loss_and_a_retry")
+    n, lost = col["returned"]
+    lost = [key[len("/registry/pods/"):] for key in lost]
+    assert n == 3 * WAVE and lost
+    keeps = [r[5] for r in col["bound"].values()]
+    assert sorted(k for k, r in col["bound"].items()
+                  if isinstance(r[5], PodInfo)) == sorted(lost)
+    shapes = [k for k in keeps if isinstance(k, PodShape)]
+    assert len(shapes) == 3 * WAVE - 8 - len(lost)
+    assert len({id(k) for k in shapes}) == 1
+    assert col["retired"] == {
+        "columnar": 3 * WAVE - len(lost), "per_pod": 2 * len(lost)}
+    waves = col["per_pod_waves"]
+    assert any(pods == 0 for pods, _ in waves)
+    assert any(pods > 0 for pods, _ in waves)
+    for pods, spans in waves:
+        assert (spans > 0) == (pods > 0)
+    assert all(spans == 0 for _, spans in ref["per_pod_waves"])
 
 
 def test_a_make_pods_fill_is_all_columnar():

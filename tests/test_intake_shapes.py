@@ -29,7 +29,7 @@ from k8s1m_tpu.config import (
     TableSpec,
 )
 from k8s1m_tpu.control import coordinator as coordinator_mod
-from k8s1m_tpu.control.coordinator import Coordinator
+from k8s1m_tpu.control.coordinator import Coordinator, PodShape
 from k8s1m_tpu.control.objects import (
     decode_pod,
     decode_pod_fast,
@@ -39,6 +39,7 @@ from k8s1m_tpu.control.objects import (
     node_key,
     pod_key,
 )
+from k8s1m_tpu.engine.cycle import commit_fields_np
 from k8s1m_tpu.faultline.policy import RetryPolicy
 from k8s1m_tpu.obs.metrics import REGISTRY
 from k8s1m_tpu.oracle import oracle_feasible
@@ -206,14 +207,42 @@ def _assert_waves_equal(a: _Lane, b: _Lane, first: int | None = None) -> None:
         assert ba.tobytes() == bb.tobytes()
 
 
+# The commit fields a constraint adjustment reads (adjust_constraints_impl).
+KEPT_FIELDS = ("sinc_valid", "sinc_cid", "sinc_topo", "iinc_valid",
+               "iinc_tid", "iinc_topo", "ipa_own_valid", "ipa_tid",
+               "ipa_topo")
+
+
+def _kept(coord: Coordinator) -> dict:
+    """Each bound record's field 5 as the commit fields _process_adjusts
+    encodes from it (None where the record keeps nothing): what a delete
+    takes back, whether the record holds a PodShape or a PodInfo."""
+    bound = coord._bound
+    keys = [k for k, r in bound.items() if r[5] is not None]
+    out = dict.fromkeys(bound)
+    b = coord.pod_spec.batch
+    for off in range(0, len(keys), b):
+        chunk = keys[off : off + b]
+        fields = commit_fields_np(coord.encoder.encode_packed(
+            *coordinator_mod._adjusted([(bound[k][5],) for k in chunk])
+        ).fields)
+        for i, k in enumerate(chunk):
+            out[k] = tuple(np.asarray(getattr(fields, f))[i].tolist()
+                           for f in KEPT_FIELDS)
+    return out
+
+
 def _assert_accounting_equal(a: Coordinator, b: Coordinator) -> None:
     """``_bound`` equal but for ``_bind_seq`` (its field 7), which is held
     up to its order among the pods of one wave: a record that holds a
     PodInfo takes its number after the wave's fast-lane records
     (``Coordinator._complete``), and which records hold one is what
-    differs between the two lanes."""
-    strip = lambda bound: {k: r[:7] + r[8:] for k, r in bound.items()}
+    differs between the two lanes; and but for field 5, held to the
+    commit fields it encodes (a shaped record keeps its bound shape, a
+    JSON one its PodInfo)."""
+    strip = lambda bound: {k: r[:5] + r[6:7] + r[8:] for k, r in bound.items()}
     assert strip(a._bound) == strip(b._bound)
+    assert _kept(a) == _kept(b)
     assert sorted(r[7] for r in a._bound.values()) == \
         sorted(r[7] for r in b._bound.values())
     assert set(a.unschedulable) == set(b.unschedulable)
@@ -556,8 +585,10 @@ def test_a_constraint_registered_mid_frame_reaches_the_next_pod(lanes):
     _assert_waves_equal(shaped, legacy)
     _assert_accounting_equal(shaped.coord, legacy.coord)
     _assert_tables_equal(shaped.coord, legacy.coord)
-    # The plain pod the constraint matched keeps a PodInfo, the other not.
-    kept = {k for k, r in shaped.coord._bound.items() if r[5] is not None}
+    # The plain pod the constraint matched keeps its increment, the other
+    # not, in the same commit fields on both lanes.
+    kept = {k for k, v in _kept(shaped.coord).items() if v is not None}
+    assert _kept(shaped.coord) == _kept(legacy.coord)
     assert kept == {f"default/bench-pod-{i}" for i in (0, 2, 3, 5, 6, 7)} \
         | {"default/bare"}
 
@@ -973,14 +1004,14 @@ def test_a_selector_shape_is_what_the_json_lane_decodes(
         assert lane.coord.run_until_idle() == len(wave)
     _assert_waves_equal(shaped, legacy)
     _assert_accounting_equal(shaped.coord, legacy.coord)
-    # A pod that repels its own kind keeps a PodInfo once bound, on both
-    # lanes; every other record of the shaped lane retired in columns.
-    kept = {k for k, r in shaped.coord._bound.items() if r[5] is not None}
+    # A pod that repels its own kind keeps its term once bound, in the
+    # same commit fields on both lanes (the shaped lane's record its
+    # shape); every record of the shaped lane retired in columns.
+    kept = {k for k, v in _kept(shaped.coord).items() if v is not None}
     assert kept == (pinned_keys if raw else set())
+    assert _kept(shaped.coord) == _kept(legacy.coord)
     grown = _grown(retired, _retired())
-    assert grown == {
-        "columnar": len(wave) - len(kept), "per_pod": len(wave) + len(kept)
-    }
+    assert grown == {"columnar": len(wave), "per_pod": len(wave)}
     # ... and every bind honours the pod's selector, terms and taints.
     for lane in (shaped, legacy):
         for p in wave:
@@ -1208,7 +1239,8 @@ def test_an_external_bind_of_a_pinned_pod_is_accounted_as_the_json_lane_does(
     assert (grown["canonical"], grown["json"]) == (2, 2)
     _assert_accounting_equal(shaped.coord, legacy.coord)
     kept = shaped.coord._bound["default/bench-pod-1"][5]
-    assert kept == legacy.coord._bound["default/bench-pod-1"][5]
+    assert _kept(shaped.coord)["default/bench-pod-1"] == \
+        _kept(legacy.coord)["default/bench-pod-1"] is not None
     assert kept.affinity_refs and kept.required_terms
     assert shaped.coord._bound["default/bench-pod-0"][5] is None
 
@@ -1229,3 +1261,103 @@ def test_a_priority_keeps_a_pinned_pod_on_lane_json():
         assert lane.coord.run_until_idle() == 3
     finally:
         lane.close()
+
+
+# ---- what a bound record keeps, taken back -----------------------------
+
+# interpod-1m-pct5's widths on 1,000 nodes, with room for four terms.
+IPA = dict(
+    with_constraints=True, retry_policy=NO_DELAY,
+    spec=TableSpec(max_nodes=1024, max_zones=9, max_regions=5,
+                   spread_slots=1, affinity_slots=4),
+    pods=PodSpec(batch=WAVE, spread_refs=1, spread_incs=1, affinity_refs=2,
+                 ipa_incs=1),
+    profile=Profile(node_affinity=0, topology_spread=0),
+)
+TABLES = ("spread_node", "spread_zone", "spread_region", "tgt_node",
+          "tgt_zone", "tgt_region", "own_node", "own_zone", "own_region")
+
+
+def _soft(match: dict) -> dict:
+    return {"podAntiAffinity": {
+        "preferredDuringSchedulingIgnoredDuringExecution": [
+            {"weight": 1, "podAffinityTerm": _ipa_term(match)}]}}
+
+
+def _keeping(kind: str) -> tuple:
+    """(lanes' keywords, a frame of sixteen pods, spec.affinity by pod
+    name, pods to evict before the rest are deleted)."""
+    if kind == "spread":
+        return CONS, [_web(i, i % 2) for i in range(16)], None, 0
+    if kind == "cache":
+        pods = [build_pod(i, app="cache-0", cpu_milli=10, mem_kib=1024)
+                for i in range(16)]
+        return IPA, pods, {p.name: _anti({"app": "cache-0"}) for p in pods}, 6
+    # Four pods carry a preferred term that selects the twelve after them.
+    assert kind == "targeted"
+    pods = [build_pod(i, app="frontend" if i < 4 else "backend",
+                      cpu_milli=10, mem_kib=1024) for i in range(16)]
+    return IPA, pods, {p.name: _soft({"app": "backend"}) for p in pods[:4]}, 0
+
+
+def _all_tables_equal(a: Coordinator, b: Coordinator) -> dict:
+    ta, tb = ({t: np.asarray(getattr(c.constraints, t)) for t in TABLES}
+              for c in (a, b))
+    for t in TABLES:
+        np.testing.assert_array_equal(ta[t], tb[t], err_msg=t)
+    return ta
+
+
+def _same(x: dict, y: dict) -> bool:
+    return all(np.array_equal(x[t], y[t]) for t in TABLES)
+
+
+@pytest.mark.parametrize("kind", ["spread", "cache", "targeted"])
+def test_what_a_bound_record_keeps_takes_its_counts_back(lanes, json_lane, kind):
+    """Bound on the shaped lane (a record keeps its bound shape) and on the
+    JSON lane (its PodInfo); counted once more and taken back in the form
+    the re-add paths (a preemptor's bind, a fallback bind) and a delete
+    queue; then evicted and deleted: the device's count tables are equal
+    between the lanes at every step, and once every pod has gone they are
+    what they were before the bind."""
+    cluster, pods, raw, evict = _keeping(kind)
+    shaped, legacy = lanes(**dict(cluster, nodes=64))
+    for lane in (shaped, legacy):
+        lane.bootstrap()
+        lane.put(pods, raw)
+        lane.coord.drain_watches()
+    assert _queue_view(shaped.coord) == _queue_view(legacy.coord)
+    before = _all_tables_equal(shaped.coord, legacy.coord)
+    for lane in (shaped, legacy):
+        assert lane.coord.run_until_idle() == len(pods)
+    bound = _all_tables_equal(shaped.coord, legacy.coord)
+    assert not _same(bound, before)
+    _assert_accounting_equal(shaped.coord, legacy.coord)
+    kept = [k for k, r in shaped.coord._bound.items() if r[5] is not None]
+    assert len(kept) == (12 if kind == "targeted" else 16)
+    # (a pod the in-wave gate sent back was decoded on its retry)
+    shapes = [k for k in kept if isinstance(shaped.coord._bound[k][5], PodShape)]
+    assert len(shapes) >= 8
+    assert all(isinstance(shaped.coord._bound[k][5], PodInfo)
+               for k in set(kept) - set(shapes))
+    assert all(isinstance(legacy.coord._bound[k][5], PodInfo) for k in kept)
+    for sign in (1, -1):
+        for lane in (shaped, legacy):
+            for k in shapes[:3]:
+                rec = lane.coord._bound[k]
+                lane.coord._pending_adjusts.append(
+                    (rec[5], rec[0], rec[3], rec[4], sign))
+            lane.coord._process_adjusts()
+        again = _all_tables_equal(shaped.coord, legacy.coord)
+        assert _same(again, bound) == (sign == -1)
+    for lane in (shaped, legacy):
+        for k in shapes[:evict]:
+            assert lane.coord._evict_bound(k)[0]
+        lane.store.put_batch(
+            [(pod_key(p.namespace, p.name), None) for p in pods])
+        lane.coord.run_until_idle()
+        lane.coord.step()
+        assert not lane.coord._bound
+    left = _all_tables_equal(shaped.coord, legacy.coord)
+    for t in TABLES:
+        np.testing.assert_array_equal(left[t], before[t], err_msg=t)

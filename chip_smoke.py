@@ -28,6 +28,11 @@ prints set-up seconds and counts, never a rate.
   conflicts in parallel rounds) against the sequential scan it replaced,
   the four outputs bit for bit, over a wave of this cluster and over the
   last waves that fill a 10,000-node cluster to its brim.
+- **Phase F — every plug-in at once**: the benchmark's ``full-1m-pct5``
+  deployment as the benchmark sets it up (1M tainted nodes, its widths,
+  split label planes), a dozen waves of its own pods, then the combined
+  kernel ``fused_topk_affinity_constraints`` against the XLA scan on a
+  window of the next pods, bit for bit, on live and long-lived counts.
 - **Mesh phase** (>= 4 chips): phase A once more over an explicit 1x4
   mesh, and a one-chip / 1x4 pair at percentageOfNodesToScore 100 whose
   binds must be byte-identical (sampled windows rotate shard-locally by
@@ -57,6 +62,8 @@ WEBHOOK_PODS = 256
 ORACLE_SAMPLE = 256
 MESH_WAVES = 2
 FIT_NODES = 10_000      # fit-10k's cluster, filled to its brim in phase B
+FULL_CELL = "full-1m-pct5.fill"     # the benchmark cell phase F sets up
+FULL_WAVES = 12         # its waves before the window is compared
 SEED = 0
 
 
@@ -390,10 +397,12 @@ def _equal(name: str, a, b) -> None:
         )
 
 
-def _window_candidates(packed, profile, *, rows, chunk, k, offset, backend):
+def _window_candidates(packed, profile, *, rows, chunk, k, offset, backend,
+                       in_wave_skew=False):
     """Jitted ``(table, ints, bools, key, constraints) -> (idx, prio)``:
     the step's own candidates stage (engine/cycle.candidates) over one
-    scan window, on the backend asked for."""
+    scan window, on the backend asked for (``in_wave_skew``: the best row
+    of every zone, as a deployment that counts skew in the wave builds it)."""
     import jax
 
     from k8s1m_tpu.engine.cycle import candidates, has_selectors
@@ -408,6 +417,7 @@ def _window_candidates(packed, profile, *, rows, chunk, k, offset, backend):
         cand = candidates(
             table, batch, key, constraints, profile, chunk=chunk, k=k,
             backend=backend, with_affinity=aff, window=(offset, rows),
+            in_wave_skew=in_wave_skew,
         )
         return cand.idx, cand.prio
 
@@ -731,7 +741,6 @@ def phase_b(coord, timer, *, batch, chunk, score_pct,
     all refusals at once; returns {variant: error string} (empty = all
     compiled and matched)."""
     import jax
-    import jax.numpy as jnp
 
     from k8s1m_tpu.cluster.workload import (
         affinity_deployment,
@@ -837,25 +846,9 @@ def phase_b(coord, timer, *, batch, chunk, score_pct,
         # bits each comes back one too high, which puts the least-loaded
         # zone over maxSkew and turns every spread pod infeasible.  Only
         # a count dot that keeps full precision agrees with XLA here.
-        def long_lived(cons):
-            def counts(t):
-                return jnp.broadcast_to(
-                    4351 + 512 * jnp.arange(t.shape[1], dtype=t.dtype),
-                    t.shape,
-                )
-
-            return cons.replace(
-                spread_zone=counts(cons.spread_zone),
-                spread_region=counts(cons.spread_region),
-                tgt_zone=counts(cons.tgt_zone),
-                tgt_region=counts(cons.tgt_region),
-                own_zone=counts(cons.own_zone),
-                own_region=counts(cons.own_region),
-            )
-
         _variant(
             "constraint", table, packed, Profile(), timer,
-            constraints=empty_constraints(cspec), also=long_lived, **win
+            constraints=empty_constraints(cspec), also=_long_lived, **win
         )
 
     attempt(failures, "constraint", constraint)
@@ -898,6 +891,113 @@ def phase_b(coord, timer, *, batch, chunk, score_pct,
         table, base, base_profile, timer, fit_nodes=fit_nodes, **win
     ))
     return failures
+
+
+# ---- phase F: every plug-in at once, at full-1m-pct5's widths --------------
+
+
+def _long_lived(cons):
+    """Count tables a long-lived cluster holds: thousands a domain, uneven,
+    every count of the form m*512 + 255, which bf16 cannot carry."""
+    import jax.numpy as jnp
+
+    def counts(t):
+        return jnp.broadcast_to(
+            4351 + 512 * jnp.arange(t.shape[1], dtype=t.dtype), t.shape
+        )
+
+    return cons.replace(**{
+        name: counts(getattr(cons, name)) for name in (
+            "spread_zone", "spread_region", "tgt_zone", "tgt_region",
+            "own_zone", "own_region")
+    })
+
+
+def phase_full(timer, *, waves=FULL_WAVES, tiny=None) -> dict:
+    """The deployment of the benchmark cell ``full-1m-pct5.fill`` as the
+    benchmark sets it up (``benchmark.run.Cell``: its tainted nodes, its
+    widths, its coordinator), ``waves`` more waves of its own pods, then a
+    window of the next pods of its stream: the candidates stage on the
+    kernel its waves run (``fused_topk_affinity_constraints``, best row of
+    every zone) against the XLA scan, ``idx`` and ``prio`` bit for bit, on
+    the live count tables and on long-lived ones.  At 1M nodes the label
+    planes are split (the hostnames pass the fused word's value bits).
+    ``tiny`` is a function that cuts the cell's files to a test's size.
+    Returns what it compared."""
+    import jax
+
+    from benchmark import run as bench
+    from k8s1m_tpu.control.objects import decode_pod, encode_pod
+    from k8s1m_tpu.engine.cycle import (
+        candidates_kernel,
+        sample_offset_for,
+        sample_rows_for,
+    )
+    from k8s1m_tpu.snapshot.pod_encoding import PodBatchHost
+    from k8s1m_tpu.store.native import MemStore
+
+    files = bench.load_cell(bench.read_json("BENCHMARK.json"), FULL_CELL)
+    workload, config, pods = tiny(*files) if tiny else files
+    with MemStore() as store:
+        cell = bench.Cell(store, config, workload, pods, SEED)
+        try:
+            with timer.measure("F.setup"):
+                cell.setup()
+            with timer.measure("F.waves"):
+                for _ in range(waves):
+                    cell._offer(cell.wave)
+                    cell.coord.step()
+                    cell.drain_watch()
+                cell.idle()
+            coord = cell.coord
+            lo, pattern = cell.offered, cell.pods.pattern
+            wave = [
+                decode_pod(encode_pod(cell.pods.build(
+                    i, pattern[i % len(pattern)])), coord.tracker)
+                for i in range(lo, lo + cell.wave)
+            ]
+            packed = PodBatchHost(
+                coord.pod_spec, coord.table_spec, coord.host.vocab
+            ).encode_packed(wave)
+            kernel = candidates_kernel("pallas", packed.groups, True)
+            if kernel != "fused_topk_affinity_constraints":
+                raise RuntimeError(f"full: a wave of the mix builds {kernel}")
+            n = coord.table.num_rows
+            rows = sample_rows_for(
+                n, int(config["coordinator"]["score_pct"]), coord.chunk) or n
+            offset = sample_offset_for(waves, n, rows)
+            key = jax.random.key(SEED)
+            states = [coord.constraints, _long_lived(coord.constraints)]
+            out = {}
+            for backend in ("pallas", "xla"):
+                fn = _window_candidates(
+                    packed, coord.profile, rows=rows, chunk=coord.chunk,
+                    k=coord.k, offset=offset, backend=backend,
+                    in_wave_skew=True,
+                )
+                with timer.measure(f"F.{backend}_candidates"):
+                    out[backend] = [
+                        jax.device_get(fn(coord.table, packed.ints,
+                                          packed.bools, key, st))
+                        for st in states
+                    ]
+            for i, (got, want) in enumerate(zip(out["pallas"], out["xla"])):
+                _equal(f"full idx (state {i})", got[0], want[0])
+                _equal(f"full prio (state {i})", got[1], want[1])
+                if not (got[1] >= 0).any():
+                    raise RuntimeError(f"full: no feasible candidate (state {i})")
+            fused = coord.table.spec.fuse_labels
+            counted = int(sum(
+                int(getattr(coord.constraints, name).sum())
+                for name in ("spread_node", "tgt_node", "own_node")))
+        finally:
+            cell.close()
+    log(f"phase F: {kernel} == XLA scan (idx, prio) on {len(states)} states, "
+        f"{cell.offered} pods offered, {counted} pods in the node count "
+        f"planes, label words {'fused' if fused else 'split'}, window "
+        f"{rows} rows at {offset}")
+    return {"kernel": kernel, "fused_labels": fused, "offered": cell.offered,
+            "counted": counted, "candidates": int((out["pallas"][0][1] >= 0).sum())}
 
 
 # ---- mesh phase ------------------------------------------------------------
@@ -1014,6 +1114,7 @@ def main() -> int:
                 coord, timer, batch=BATCH, chunk=CHUNK, score_pct=SCORE_PCT
             ))
             del coord, done
+        attempt(failures, "F", lambda: phase_full(timer))
         if device["count"] >= 4:
             attempt(failures, "mesh", lambda: mesh_phase(
                 store, compiles, timer, waves=MESH_WAVES,

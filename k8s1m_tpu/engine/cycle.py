@@ -261,10 +261,10 @@ def _counted_in_wave(batch: PodBatch):
 
 
 def wave_skew(batch: PodBatch, constraints: ConstraintState, stats) -> WaveSkew:
-    """greedy_assign's ``skew`` for one wave: the wave-start zone and
-    region count tables side by side, the domains that are there, and
-    the pods' constraint references and increments."""
-    return WaveSkew(
+    """greedy_assign's ``skew`` for one wave: the wave-start count tables,
+    the domains there, the pods' constraint references and increments, as
+    arrays of the trace (a wave without spread unpacks them numpy)."""
+    return jax.tree.map(jnp.asarray, WaveSkew(
         counts=jnp.concatenate(
             [constraints.spread_zone, constraints.spread_region], axis=1
         ),
@@ -278,7 +278,7 @@ def wave_skew(batch: PodBatch, constraints: ConstraintState, stats) -> WaveSkew:
         hard=_counted_in_wave(batch),
         inc_valid=batch.sinc_valid, inc_cid=batch.sinc_cid,
         inc_topo=batch.sinc_topo,
-    )
+    ))
 
 
 def has_interpod_gate(groups, with_constraints: bool) -> bool:

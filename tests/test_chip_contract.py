@@ -161,6 +161,29 @@ def test_smoke_phases_a_and_b_tiny(smoke):
             "B.assign.fit_10k_brim"} <= set(timer.seconds)
 
 
+def test_smoke_phase_full_tiny(smoke):
+    """Phase F on the benchmark cell cut to 1,000 nodes: the combined
+    kernel (interpreted here) equals the XLA scan on live and long-lived
+    counts, on a wave of the cell's own pods."""
+    import copy
+
+    def tiny(workload, config, pods):
+        config = copy.deepcopy(config)
+        config["nodes"].update(count=1000, cordon_every=16)
+        config["table_spec"]["max_nodes"] = 1024
+        config["pod_spec"]["batch"] = 128
+        config["coordinator"].update(chunk=128, backend="xla")
+        return dict(workload, wave=128), config, pods
+
+    timer = smoke.Timer()
+    got = smoke.phase_full(timer, waves=3, tiny=tiny)
+    assert got["kernel"] == "fused_topk_affinity_constraints"
+    assert got["fused_labels"] is True       # 1,000 hostnames fit the word
+    assert got["counted"] > 0 and got["candidates"] > 0
+    assert {"F.setup", "F.pallas_candidates", "F.xla_candidates"} <= set(
+        timer.seconds)
+
+
 def test_smoke_check_catches_a_double_bind(smoke):
     """The pass/fail logic itself: a pod seen bound twice on the watch
     (or never) fails the drive check."""

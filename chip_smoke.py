@@ -27,7 +27,11 @@ prints set-up seconds and counts, never a rate.
   the window shape and over all 1M rows; and ``greedy_assign`` (a wave's
   conflicts in parallel rounds) against the sequential scan it replaced,
   the four outputs bit for bit, over a wave of this cluster and over the
-  last waves that fill a 10,000-node cluster to its brim.
+  last waves that fill a 10,000-node cluster to its brim; and a
+  ``WaveSkew`` wave settled in blocks against the sequential skew scan
+  (``sequential_skew_assign``), bit for bit, on a wave of
+  ``spread-1m-pct5``'s interleave and one of ``full-1m-pct5``'s mix under
+  the inter-pod gate, with the ``settled`` sums of each.
 - **Phase F — every plug-in at once**: the benchmark's ``full-1m-pct5``
   deployment as the benchmark sets it up (1M tainted nodes, its widths,
   split label planes), a dozen waves of its own pods, then the combined
@@ -503,6 +507,223 @@ def sequential_assign(cand_idx, cand_prio, cand_cpu, cand_mem, cand_pods,
     return lax.scan(step, init, None, length=b)[1]
 
 
+def sequential_skew_assign(cand_idx, cand_prio, cand_cpu, cand_mem, cand_pods,
+                           pod_cpu, pod_mem, pod_valid, skew, cand_zone,
+                           cand_region, interpod=None):
+    """The reference of a ``WaveSkew`` wave: one sequential scan, pod i
+    taking the first of its candidates that has room after pods j < i,
+    keeps every hard zone and region constraint within maxSkew on the
+    counts as pods j < i left them and, with ``interpod``, lies in no
+    domain an earlier pod of the wave closed to it — what ``greedy_assign``
+    ran as until its blocks, kept to hold them to it: here on the chip,
+    and in tests/test_assign_blocks.py on the CPU.  Returns (node_row,
+    bound, score, chosen_k, legal, blocked — None without ``interpod`` —,
+    the count table [C, D] after the wave)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from k8s1m_tpu.config import TOPO_HOSTNAME, TOPO_REGION, TOPO_ZONE
+    from k8s1m_tpu.engine.assign import interpod_relation
+    from k8s1m_tpu.ops.priority import unpack_score
+
+    big = 1 << 30                   # an absent domain: never the minimum
+    bits = ((TOPO_HOSTNAME, 1), (TOPO_ZONE, 2), (TOPO_REGION, 4))
+    b, k = cand_idx.shape
+    arange_b = jnp.arange(b)
+    feasible = (cand_prio >= 0) & (cand_idx >= 0)
+    z = skew.zones
+    is_zone = skew.topo == TOPO_ZONE                              # [B, S]
+    ref_dom = jnp.where(is_zone[:, :, None], cand_zone[:, None, :],
+                        z + cand_region[:, None, :])              # [B, S, K]
+    ref_dom_ok = jnp.where(is_zone[:, :, None], cand_zone[:, None, :] != 0,
+                           cand_region[:, None, :] != 0)
+    seg_zone = jnp.arange(skew.counts.shape[1]) < z
+    counts0 = jnp.where(skew.present[None, :], skew.counts, big)
+    cand_doms = (cand_idx, cand_zone, cand_region)
+    if interpod is not None:
+        rel, inc, boot = interpod_relation(interpod)
+        term_bits = jnp.zeros_like(interpod.ipa_topo, dtype=jnp.int8)
+        for topo, bit in bits:
+            term_bits = jnp.where(interpod.ipa_topo == topo, jnp.int8(bit),
+                                  term_bits)
+
+    def domains(node_of, zone_of, region_of):
+        dom = lambda d: jnp.where((node_of >= 0) & (d > 0), d, -1)
+        return node_of, dom(zone_of), dom(region_of)
+
+    def closed(i, prev, doms):
+        eq = jnp.zeros((k, b), jnp.int8)
+        for (_topo, bit), dom, cand in zip(bits, doms, cand_doms):
+            eq = eq | jnp.where(cand[i][:, None] == dom[None, :],
+                                jnp.int8(bit), jnp.int8(0))
+        eq = jnp.where(prev[None, :], eq, jnp.int8(0))            # [K, B]
+        shut = ((rel[i][None, :] & eq) != 0).any(axis=1)
+        sel = jnp.where(
+            boot[i][:, None] & jnp.take(inc.T, interpod.ipa_tid[i], axis=0),
+            term_bits[i][:, None], jnp.int8(0),
+        )[:, None, :]                                             # [AR, 1, B]
+        seen = ((sel != 0) & prev[None, None, :]).any(axis=2)
+        met = ((sel & eq[None, :, :]) != 0).any(axis=2)
+        return shut | (seen & ~met).any(axis=0)
+
+    def step(carry, _):
+        node_of, bound, i, counts, doms = carry
+        prev = (arange_b < i) & bound
+        taken = (cand_idx[i][:, None] == node_of[None, :]) & prev[None, :]
+        ok = (
+            feasible[i]
+            & (pod_cpu[i] <= cand_cpu[i] - (taken * pod_cpu[None, :]).sum(-1))
+            & (pod_mem[i] <= cand_mem[i] - (taken * pod_mem[None, :]).sum(-1))
+            & (cand_pods[i] - taken.sum(-1) >= 1)
+        )
+        rows = counts[skew.cid[i]]                                # [S, D]
+        seg = seg_zone[None, :] == is_zone[i][:, None]
+        least = jnp.where(seg, rows, big).min(axis=1)
+        cnt = jnp.take_along_axis(rows, ref_dom[i], axis=1)       # [S, K]
+        within = ref_dom_ok[i] & (
+            cnt + skew.self_inc[i][:, None] - least[:, None]
+            <= skew.max_skew[i][:, None])
+        lawful = (~skew.hard[i][:, None] | within).all(axis=0)
+        legal = (feasible[i] & lawful).any()
+        ok = ok & lawful
+        blocked = jnp.zeros((), bool)
+        if interpod is not None:
+            open_ = ~closed(i, prev, doms)
+            blocked = pod_valid[i] & ok.any() & ~(ok & open_).any()
+            ok = ok & open_
+        any_ok = ok.any() & pod_valid[i]
+        kstar = jnp.argmax(ok)
+        node = jnp.where(any_ok, cand_idx[i, kstar], -1)
+        score = jnp.where(any_ok, unpack_score(cand_prio[i, kstar]), -1)
+        it = skew.inc_topo[i]
+        dom = jnp.where(it == TOPO_ZONE, cand_zone[i, kstar],
+                        z + cand_region[i, kstar])
+        inc_ = any_ok & skew.inc_valid[i] & (it != TOPO_HOSTNAME)
+        counts = counts.at[skew.inc_cid[i], dom].add(inc_.astype(jnp.int32))
+        node_of = node_of.at[i].set(node)
+        _, zone_of, region_of = doms
+        doms = domains(node_of, zone_of.at[i].set(cand_zone[i, kstar]),
+                       region_of.at[i].set(cand_region[i, kstar]))
+        carry = (node_of, bound.at[i].set(any_ok), i + 1, counts, doms)
+        return carry, (node, any_ok, score, kstar.astype(jnp.int32), legal,
+                       blocked)
+
+    none = jnp.full((b,), -1, jnp.int32)
+    init = (none, jnp.zeros((b,), bool), jnp.int32(0), counts0,
+            (none, none, none))
+    carry, (node_row, bound, score, chosen_k, legal, blocked) = lax.scan(
+        step, init, None, length=b)
+    return (node_row, bound, score, chosen_k, legal,
+            None if interpod is None else blocked, carry[3])
+
+
+def skew_wave(kind: str, seed: int, *, batch: int = BATCH):
+    """A ``WaveSkew`` wave at a cell's widths, nine candidates a pod (the
+    best row of each of eight zones, the slot of zone id 0 empty, in
+    priority order), 110 slots a node.  ``spread``: sixteen Deployments,
+    pod i of ``pattern[i % 16]``, a hard zone and a soft hostname
+    constraint each, 131,072 rows a zone.  ``full``: full-1m-pct5's
+    64-unit mix — two web and one ha pod of each of four Deployments a
+    unit, one cache pod of each of four, the rest plain; ha and cache
+    replicas one a node (required hostname anti-affinity) and sixteen rows
+    a zone, so that replicas meet at the gate and rows fill.  Returns
+    (``greedy_assign``'s eleven positional arguments, ``interpod`` or
+    None)."""
+    import numpy as np
+
+    from k8s1m_tpu.config import TOPO_HOSTNAME, TOPO_ZONE
+    from k8s1m_tpu.engine.assign import WaveInterpod, WaveSkew
+
+    rng = np.random.default_rng(seed)
+    zones, k = 8, 9
+    rows = 1 << 17 if kind == "spread" else 16
+    zone = np.tile(np.arange(k, dtype=np.int32), (batch, 1))
+    idx = np.where(zone > 0, zone * rows + rng.integers(0, rows, (batch, k)), -1)
+    prio = np.where(zone > 0, rng.integers(0, 1 << 20, (batch, k)), -1)
+    order = np.argsort(-prio, axis=1, kind="stable")
+    idx, prio, zone = (np.take_along_axis(x, order, axis=1).astype(np.int32)
+                       for x in (idx, prio, zone))
+    region = np.where(zone > 0, (zone - 1) % 4 + 1, 0).astype(np.int32)
+    full = lambda v: np.full((batch, k), v, np.int32)
+    raw = (idx, prio, full(32_000), full(64 << 20),
+           np.where(idx >= 0, 110, 0).astype(np.int32),
+           np.full(batch, 100, np.int32), np.full(batch, 200 << 10, np.int32),
+           np.ones(batch, bool))
+    # a unit of the pattern: (its spread slot pair, its anti-affinity term),
+    # None where it has none
+    if kind == "spread":
+        units = [(2 * d, None) for d in range(16)]
+    else:
+        units = ([(2 * d, None) for d in range(4) for _ in range(2)]   # web-d
+                 + [(8 + 2 * d, d) for d in range(4)]                  # ha-d
+                 + [(None, 4 + d) for d in range(4)]                   # cache-d
+                 + [(None, None)] * 48)                                # plain
+    pattern = [units[j] for j in rng.permutation(len(units))]
+    slot_of, term_of = zip(*(pattern[i % len(pattern)] for i in range(batch)))
+    spread = np.array([s is not None for s in slot_of])
+    slot = np.array([s or 0 for s in slot_of])
+    pair = lambda a, c: np.stack([a, c], axis=1).astype(np.int32)
+    cid = pair(slot, slot + 1)
+    topo = pair(np.full(batch, TOPO_ZONE), np.full(batch, TOPO_HOSTNAME))
+    d_ = zones + 1 + 5                  # zone ids 0..8, region ids 0..4
+    present = np.arange(d_) % (zones + 1) != 0
+    skew = WaveSkew(
+        counts=rng.integers(0, 2, (2 * 16 if kind == "spread" else 16, d_))
+        .astype(np.int32),
+        present=present, zones=zones + 1, cid=cid, topo=topo,
+        max_skew=np.ones((batch, 2), np.int32), self_inc=pair(spread, spread),
+        hard=pair(spread, np.zeros(batch)).astype(bool),
+        inc_valid=pair(spread, spread).astype(bool), inc_cid=cid, inc_topo=topo,
+    )
+    args = (*raw, skew, zone, region)
+    if kind == "spread":
+        return args, None
+    term = np.array([t is not None for t in term_of])[:, None]
+    tid = np.array([t or 0 for t in term_of])
+    col = lambda a: np.asarray(a).reshape(batch, 1)
+    interpod = WaveInterpod(
+        slots=16, ipa_valid=term, ipa_tid=col(tid).astype(np.int32),
+        ipa_topo=np.full((batch, 1), TOPO_HOSTNAME, np.int32),
+        ipa_required=term, ipa_anti=term, ipa_self=term,
+        inc_valid=term, inc_tid=col(tid).astype(np.int32),
+        tgt_total=np.ones(16, np.int32),
+    )
+    return args, interpod
+
+
+def _skew_variant(timer, *, batch=BATCH) -> dict:
+    """``greedy_assign``'s blocks against ``sequential_skew_assign`` on the
+    chip, bit for bit: a wave of ``spread-1m-pct5``'s interleave and one of
+    ``full-1m-pct5``'s mix under the gate (``skew_wave``).  Returns the
+    ``settled`` sums of each (pods by rounds, by the step, evaluations)."""
+    import jax
+    import numpy as np
+
+    from k8s1m_tpu.engine.assign import greedy_assign
+
+    blocks = jax.jit(lambda args, ip: greedy_assign(*args, ip))
+    scan = jax.jit(lambda args, ip: sequential_skew_assign(*args, ip))
+    seen = {}
+    for kind in ("spread", "full"):
+        args, ip = skew_wave(kind, SEED, batch=batch)
+        with timer.measure(f"B.skew_blocks.{kind}"):
+            got = jax.device_get(blocks(args, ip))
+            want = jax.device_get(scan(args, ip))
+        valid = args[7]
+        pairs = [("node_row", got[0], want[0]), ("bound", got[1], want[1]),
+                 ("score", got[2], want[2]), ("legal", got[4], want[4]),
+                 ("chosen_k", got[3][valid], want[3][valid])]
+        if ip is not None:
+            pairs.append(("blocked", got[6], want[5]))
+        for name, a, b in pairs:
+            _equal(f"skew blocks {kind} {name}", a, b)
+        seen[kind] = np.asarray(got[5]).tolist()
+        log(f"phase B skew blocks: a {kind} wave == the sequential skew scan "
+            f"({int(got[1].sum())}/{batch} bound); settled (rounds, scan, "
+            f"evaluations) {seen[kind]}")
+    return seen
+
+
 def _assign_inputs(packed, profile, *, chunk, k, window):
     """Jitted ``(table, ints, bools, key) -> greedy_assign's eight
     arguments``: the wave's candidates from the fused kernel with what
@@ -890,6 +1111,9 @@ def phase_b(coord, timer, *, batch, chunk, score_pct,
     attempt(failures, "assign", lambda: _assign_variant(
         table, base, base_profile, timer, fit_nodes=fit_nodes, **win
     ))
+
+    # (vii) a WaveSkew wave's blocks against the sequential skew scan.
+    attempt(failures, "skew_blocks", lambda: _skew_variant(timer, batch=batch))
     return failures
 
 

@@ -24,9 +24,9 @@ without contention: once), and where rounds stop paying (``_go_on``) the
 scan's own step finishes the pods that have not settled, from m to the
 last pod that has a candidate.
 
-With ``skew`` the sequential scan runs as it always has, and it also keeps
-PodTopologySpread's hard zone and region constraints (whenUnsatisfiable:
-DoNotSchedule) exact *inside* the wave: it carries the matching-pod count of every (constraint slot, domain)
+With ``skew`` the same order also keeps PodTopologySpread's hard zone and
+region constraints (whenUnsatisfiable: DoNotSchedule) exact *inside* the
+wave: the matching-pod count of every (constraint slot, domain) is carried
 forward from the wave-start tables, pod by pod in wave order, and a
 candidate is legal for pod i only if binding it keeps ``count + self -
 min over the present domains`` within maxSkew on the counts as pods j < i
@@ -35,7 +35,19 @@ candidates' own zone filter is switched off for those constraints
 (engine/cycle.candidates) and they arrive one per zone, so whichever zone
 is legal at a pod's turn has a candidate.  Hostname-keyed constraints are
 not re-checked here: their domains are nodes, and the kernel's filter on
-the wave-start counts stands for them.
+the wave-start counts stands for them.  Such a wave is settled a block of
+W pods at a time (``_skew_blocks``): every pod of a Deployment reads the
+zone the one before it took, so rounds over the whole wave would settle
+about a pod of each Deployment a round, while a block holds a few of each.
+``F`` over a block reads the exact state at its start — what the pods
+before it took from the block's candidates and which domains they closed,
+once a block as ``[W*K, B]`` compares, and the count table — and, for the
+block's own pods, the capacity they took and the gate they close
+(``[W*K, W]``) and the counts they added (the table plus the prefix sum of
+their one-hot increments).  Rounds run from "nothing of the block bound"
+until nothing changes; where they stop paying (``_go_on`` over W) the
+scan's own step settles the block from m.  The block's final choices are
+folded into the state and the next block starts.
 
 With ``interpod`` (a wave whose pods carry a required inter-pod term)
 required inter-pod anti-affinity is exact inside the wave too, on both
@@ -64,6 +76,8 @@ same trip counts), so no cross-device coordination is needed at commit time.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from flax import struct
@@ -85,8 +99,9 @@ _BIG = 1 << 30
 UNBOUND_REASONS = ("capacity", "skew", "no_candidate", "interpod")
 
 # What settled a valid pod's choice (``greedy_assign``'s ``settled`` holds
-# the two sums, and behind them the evaluations of ``F``): the rounds, or
-# the sequential step (the tail the rounds left; a ``WaveSkew`` wave whole).
+# the two sums, and behind them the evaluations of ``F``): the rounds (a
+# ``WaveSkew`` wave's: of its blocks), or the sequential step (the tail the
+# rounds left: of the wave, or of a block).
 SETTLED_BY = ("rounds", "scan")
 
 # The scan's step is launch latency, ~11 us whatever the wave; a round is
@@ -97,6 +112,19 @@ _ROUND_STEPS = 16
 _ROUND_FIXED_STEPS = 2
 # Rounds that may pass before the settled prefix has to have paid for them.
 _GRACE_ROUNDS = 8
+# Pods a block of a ``WaveSkew`` wave holds at most: a power of two, so that
+# a block divides the wave.  Fewer blocks cost fewer set-ups and confirming
+# rounds, a larger block more rounds where chains are long; over a wave of
+# spread-1m-pct5's interleave and one of full-1m-pct5's mix at 4096 x 9 the
+# sum was least at 128 (TPU v5e: 11.1 + 15.0 ms, against 9.8 + 20.1 at 64
+# and 16.6 + 15.3 at 256; the sequential scan 69.4 + 105.1).
+_BLOCK_PODS = 128
+
+
+def _block_pods(b: int) -> int:
+    """W for a ``WaveSkew`` wave of ``b`` pods: the largest power of two up
+    to ``_BLOCK_PODS`` that divides it (a short wave is one block)."""
+    return math.gcd(b, _BLOCK_PODS)
 
 
 def _round_steps(b: int, k: int) -> int:
@@ -196,6 +224,162 @@ def _domains_of(node_of, zone_of, region_of):
     return node_of, dom(zone_of), dom(region_of)
 
 
+def _skew_blocks(step, per_pod, counts, zones: int, first_boot):
+    """A ``WaveSkew`` wave settled a block of W pods at a time.
+
+    ``per_pod`` holds the wave's per-pod arrays (``rel``, the gate's
+    relation, where the wave has one), ``counts`` the wave-start table
+    [C, D] (absent domains ``_BIG``), ``step`` the scan's own step over the
+    whole wave, which settles what the rounds leave of a block and every pod
+    from ``first_boot`` on.  Returns (node_row, bound, chosen_k, legal,
+    blocked — None without the gate —, the table after the wave, the valid
+    pods the step settled, the evaluations of ``F``)."""
+    b, k = per_pod["idx"].shape
+    w = _block_pods(b)
+    gate = "rel" in per_pod
+    d = counts.shape[1]
+    arange_w = jnp.arange(w)
+    earlier = arange_w[None, :] < arange_w[:, None]          # [W, W]: p' < p
+    take1 = lambda x, kstar: jnp.take_along_axis(x, kstar[:, None], axis=1)[:, 0]
+    pod_cpu, pod_mem = per_pod["pod_cpu"], per_pod["pod_mem"]
+    last = jnp.max(jnp.where(per_pod["feasible"].any(axis=1), jnp.arange(b) + 1, 0))
+
+    def domains(node, at, kstar):
+        return _domains_of(node, take1(at["zone"], kstar), take1(at["region"], kstar))
+
+    def increments(at, node, kstar):
+        """Each pod's constraint increments as the scan's step adds them:
+        (slot, domain column, 0 or 1), [W, SI] each."""
+        dom = jnp.where(
+            at["inc_topo"] == TOPO_ZONE, take1(at["zone"], kstar)[:, None],
+            zones + take1(at["region"], kstar)[:, None],
+        )
+        add = (node >= 0)[:, None] & at["inc_valid"] & (at["inc_topo"] != TOPO_HOSTNAME)
+        return at["inc_cid"], dom, add
+
+    def block(blk, state):
+        node_of, bound, chosen_k, legal, blocked, counts, by_scan, rounds = state
+        s = blk * w
+        at = {name: lax.dynamic_slice_in_dim(x, s, w) for name, x in per_pod.items()}
+        # Once a block: what the pods before it (every bound pod so far) took
+        # from its candidates, and which of them they closed.
+        before = (at["idx"][:, :, None] == node_of[None, None, :]) & bound  # [W, K, B]
+        took = lambda what: jnp.where(before, what, 0).sum(axis=2)
+        pre = (took(pod_cpu), took(pod_mem), before.sum(axis=2, dtype=jnp.int32))
+        cand_doms = (at["idx"], at["zone"], at["region"])
+        if gate:
+            doms = domains(node_of, per_pod, chosen_k)
+            hit = jnp.zeros(before.shape, jnp.int8)
+            for (_topo, bit), dom, cand in zip(_TOPO_BITS, doms, cand_doms):
+                hit = hit | jnp.where(cand[:, :, None] == dom, jnp.int8(bit), jnp.int8(0))
+            pre_closed = (((at["rel"][:, None, :] & hit) != 0) & bound).any(axis=2)
+            rel_w = lax.dynamic_slice_in_dim(at["rel"], s, w, axis=1)        # [W, W]
+        rows0 = counts[at["cid"]]                                           # [W, S, D]
+        seg = (jnp.arange(d) < zones)[None, None, :] == at["is_zone"][:, :, None]
+        target = at["cid"][:, :, None] * d + jnp.arange(d)                  # [W, S, D]
+
+        def evaluate(node, kstar):
+            """``F`` over the block: every pod's choice given the choices
+            ``node`` / ``kstar`` of the block's pods before it."""
+            prev = earlier & (node >= 0)[None, :]                           # [W, W]
+            taken = (at["idx"][:, :, None] == node) & prev[:, None, :]     # [W, K, W]
+            took = lambda what: jnp.where(taken, what, 0).sum(axis=2)
+            ok = (
+                at["feasible"]
+                & (at["pod_cpu"][:, None] <= at["cpu"] - pre[0] - took(at["pod_cpu"]))
+                & (at["pod_mem"][:, None] <= at["mem"] - pre[1] - took(at["pod_mem"]))
+                & (at["pods"] - pre[2] - taken.sum(axis=2, dtype=jnp.int32) >= 1)
+            )
+            cid, dom, add = increments(at, node, kstar)
+            src = jnp.where(add, cid * d + dom, -1)                         # [W, SI]
+            added = (
+                (target[:, :, :, None, None] == src[None, None, None, :, :])
+                & earlier[:, None, None, :, None]
+            ).sum(axis=(3, 4), dtype=jnp.int32)
+            rows = rows0 + added                                            # [W, S, D]
+            least = jnp.where(seg, rows, _BIG).min(axis=2)                  # [W, S]
+            cnt = jnp.take_along_axis(rows, at["ref_dom"], axis=2)          # [W, S, K]
+            within = at["ref_dom_ok"] & (
+                cnt + at["self_inc"][:, :, None] - least[:, :, None]
+                <= at["max_skew"][:, :, None]
+            )
+            lawful = (~at["hard"][:, :, None] | within).all(axis=1)         # [W, K]
+            legal = (at["feasible"] & lawful).any(axis=1)
+            ok = ok & lawful
+            blocked = None
+            if gate:
+                hit = jnp.zeros(taken.shape, jnp.int8)
+                for (_topo, bit), dom, cand in zip(
+                        _TOPO_BITS, domains(node, at, kstar), cand_doms):
+                    hit = hit | jnp.where(cand[:, :, None] == dom, jnp.int8(bit), jnp.int8(0))
+                closed = pre_closed | (
+                    ((rel_w[:, None, :] & hit) != 0) & prev[:, None, :]
+                ).any(axis=2)
+                blocked = at["valid"] & ok.any(axis=1) & ~(ok & ~closed).any(axis=1)
+                ok = ok & ~closed
+            any_ok = ok.any(axis=1) & at["valid"]
+            kstar = jnp.argmax(ok, axis=1).astype(jnp.int32)
+            return jnp.where(any_ok, take1(at["idx"], kstar), -1), kstar, legal, blocked
+
+        last_w = jnp.clip(last - s, 0, w)
+        boot_w = jnp.clip(first_boot - s, 0, w)
+
+        def another_round(st):
+            m, n = st[4], st[5]
+            return _go_on(m, n, last_w, w, k) & (m < boot_w)
+
+        def round_(st):
+            node, kstar, _legal, _blocked, _m, n = st
+            new, new_k, legal, blocked = evaluate(node, kstar)
+            # the first pod the round moved: below it both iterates are final
+            same = (new == node) & (new_k == kstar)
+            m = jnp.where(same.all(), w, jnp.argmin(same) + 1).astype(jnp.int32)
+            return new, new_k, legal, blocked, m, n + 1
+
+        none = jnp.full((w,), -1, jnp.int32)
+        node, kstar, legal_w, blocked_w, m, n = lax.while_loop(
+            another_round, round_,
+            (none, jnp.zeros((w,), jnp.int32), jnp.zeros((w,), bool),
+             jnp.zeros((w,), bool) if gate else None, jnp.int32(0), jnp.int32(0)),
+        )
+        m = jnp.minimum(m, boot_w)
+        # Below m the rounds' choices are the scan's: fold them in.
+        node = jnp.where(arange_w < m, node, -1)
+        cid, dom, add = increments(at, node, kstar)
+        counts = counts.at[cid, dom].add(add.astype(jnp.int32))
+        put = lambda whole, part: lax.dynamic_update_slice_in_dim(whole, part, s, 0)
+        node_of, bound = put(node_of, node), put(bound, node >= 0)
+        chosen_k, legal = put(chosen_k, kstar), put(legal, legal_w)
+        if gate:
+            blocked = put(blocked, blocked_w)
+
+        def settle(i, st):
+            node_of, bound, chosen_k, legal, blocked, counts, doms = st
+            (node_of, bound, _, counts, doms), (_, _, _, kstar, ok_, shut) = step(
+                (node_of, bound, i, counts, doms), None
+            )
+            if gate:
+                blocked = blocked.at[i].set(shut)
+            return (node_of, bound, chosen_k.at[i].set(kstar), legal.at[i].set(ok_),
+                    blocked, counts, doms)
+
+        doms = domains(node_of, per_pod, chosen_k) if gate else None
+        node_of, bound, chosen_k, legal, blocked, counts, _ = lax.fori_loop(
+            s + m, s + last_w, settle,
+            (node_of, bound, chosen_k, legal, blocked, counts, doms),
+        )
+        in_step = (arange_w >= m) & (arange_w < last_w) & at["valid"]
+        by_scan = by_scan + in_step.sum(dtype=jnp.int32)
+        return node_of, bound, chosen_k, legal, blocked, counts, by_scan, rounds + n
+
+    false = jnp.zeros((b,), bool)
+    return lax.fori_loop(
+        0, (last + w - 1) // w, block,
+        (jnp.full((b,), -1, jnp.int32), false, jnp.zeros((b,), jnp.int32),
+         false, false if gate else None, counts, jnp.int32(0), jnp.int32(0)),
+    )
+
+
 def greedy_assign(
     cand_idx,   # i32[B, K] global node rows, priority-descending (-1 = none)
     cand_prio,  # i32[B, K] packed priorities (-1 = infeasible)
@@ -220,8 +404,8 @@ def greedy_assign(
     them in a domain the gate closed.
 
     ``skew=None`` traces the rounds and the capacity step alone; with
-    ``skew`` the one sequential scan alone: nothing of the other path is
-    in either program.  ``interpod=None`` traces no gate."""
+    ``skew`` the blocks (``_skew_blocks``) alone: nothing of the other path
+    is in either program.  ``interpod=None`` traces no gate."""
     b, k = cand_idx.shape
     arange_b = jnp.arange(b)
     feasible = (cand_prio >= 0) & (cand_idx >= 0)             # [B, K]
@@ -336,21 +520,26 @@ def greedy_assign(
         return carry, (node, any_ok, score, kstar.astype(jnp.int32),
                        out_legal, out_blocked)
 
-    def no_doms():
-        none = jnp.full((b,), -1, jnp.int32)
-        return (none, none, none) if interpod is not None else None
-
     valid_pods = pod_valid.sum().astype(jnp.int32)
     if skew is not None:
-        # xs=None + carried index: see engine/cycle.py on lifted-constant scans.
-        init = (
-            jnp.full((b,), -1, jnp.int32), jnp.zeros((b,), bool), jnp.int32(0),
-            counts0, no_doms(),
+        per_pod = dict(
+            idx=cand_idx, feasible=feasible, cpu=cand_cpu, mem=cand_mem,
+            pods=cand_pods, pod_cpu=pod_cpu, pod_mem=pod_mem, valid=pod_valid,
+            zone=cand_zone, region=cand_region, cid=skew.cid, is_zone=is_zone,
+            max_skew=skew.max_skew, self_inc=skew.self_inc, hard=skew.hard,
+            inc_valid=skew.inc_valid, inc_cid=skew.inc_cid,
+            inc_topo=skew.inc_topo, ref_dom=ref_dom, ref_dom_ok=ref_dom_ok,
         )
-        _, (node_row, bound, score, chosen_k, legal, blocked) = lax.scan(
-            step, init, None, length=b
+        if interpod is not None:
+            per_pod["rel"] = rel
+        node_row, bound, chosen_k, legal, blocked, _counts, by_scan, rounds = (
+            _skew_blocks(
+                step, per_pod, counts0, z, b if interpod is None else first_boot,
+            )
         )
-        settled = jnp.stack([jnp.int32(0), valid_pods, jnp.int32(0)])
+        prio = jnp.take_along_axis(cand_prio, chosen_k[:, None], axis=1)[:, 0]
+        score = jnp.where(bound, unpack_score(prio), -1)
+        settled = jnp.stack([valid_pods - by_scan, by_scan, rounds])
         out = (node_row, bound, score, chosen_k, legal, settled)
         return out if interpod is None else (*out, blocked)
 

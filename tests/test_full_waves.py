@@ -8,8 +8,8 @@ anti-affinity and its symmetry, required affinity with its bootstrap.  The
 spread and inter-pod rules are the benchmark's own references
 (``benchmark/references/spread.py``, ``interpod.py``), loaded by path as the
 harness loads them, so each rule has one implementation.  The batched step
-(``schedule_batch(in_wave_skew=True)``: the skew scan under the inter-pod
-gate) has to agree with it bind for bind on both backends; the combined
+(``schedule_batch(in_wave_skew=True)``: the skew blocks under the
+inter-pod gate) has to agree with it bind for bind on both backends; the combined
 kernel has to be the plugins' filter and score, on fused and on split label
 words; and a Deployment larger than its candidates can hold comes back
 under ``skew`` or ``interpod``, one reason a pod.
@@ -228,7 +228,7 @@ def test_a_wave_of_the_mix_is_the_sequential_schedule_bind_for_bind(backend):
 def test_a_wave_without_a_spread_constraint_runs_under_the_skew_scan():
     """Pods the gate or the skew sent back come in a wave of their own,
     and such a wave may carry no spread constraint: its packed spread
-    fields are numpy zeros, which the scan indexes by its traced pod
+    fields are numpy zeros, which the skew path slices by a traced pod
     index.  The wave binds as the reference allows."""
     host = NodeTableHost(SPEC)
     host.bulk_upsert(NODE)

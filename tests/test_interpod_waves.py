@@ -152,7 +152,8 @@ def interpod_of(fields, slots):
 
 
 def no_skew(b, k):
-    """A ``WaveSkew`` that holds nothing back: the scan's path alone."""
+    """A ``WaveSkew`` that holds nothing back: the path of a wave that
+    counts skew alone (its blocks, the step that finishes them)."""
     z = jnp.zeros((b, 1), jnp.int32)
     return WaveSkew(
         counts=jnp.zeros((1, 10), jnp.int32), present=jnp.ones((10,), bool),

@@ -25,7 +25,7 @@ import pytest
 
 from k8s1m_tpu.config import PodSpec, TOPO_HOSTNAME, TOPO_ZONE, TableSpec
 from k8s1m_tpu.control.objects import decode_pod, encode_pod, pod_key
-from k8s1m_tpu.engine import schedule_batch
+from k8s1m_tpu.engine import assign, schedule_batch
 from k8s1m_tpu.engine.assign import (
     UNBOUND_REASONS, greedy_assign, unbound_by_reason,
 )
@@ -274,8 +274,10 @@ def loops_of(jaxpr) -> list[str]:
 def test_a_wave_without_constraints_is_the_parents_row_for_row(seed):
     """Without ``skew`` the rows are the parent's scan's (by rounds since
     PR 36: tests/test_assign_rounds.py); with count tables in hand but no
-    pod carrying a constraint, still its rows, and the program is the one
-    scan: nothing of the rounds is traced with ``skew``."""
+    pod carrying a constraint, still its rows, settled in blocks (since
+    PR 42: tests/test_assign_blocks.py) — a loop over the blocks, and in it
+    a block's rounds and the step that finishes it — and nothing of the
+    whole wave's rounds is traced with ``skew``."""
     raw = contended_candidates(seed)
     args = tuple(map(jnp.asarray, raw))
     want = parents_greedy_assign(*args)
@@ -300,11 +302,15 @@ def test_a_wave_without_constraints_is_the_parents_row_for_row(seed):
     left = unbound_by_reason(counted[1], counted[4], args[0], args[1], args[7])
     assert int(left[1]) == 0 and int(left.sum()) == int(
         (raw[7] & ~np.asarray(counted[1])).sum())
-    # every pod under ``scan``, and the one scan the only loop traced
-    assert np.asarray(counted[5]).tolist() == [0, int(raw[7].sum()), 0]
+    # the blocks' rounds settle most pods (48 pods: three blocks of 16),
+    # and the blocks' loops are the only ones traced
+    by_rounds, by_scan, evaluations = np.asarray(counted[5]).tolist()
+    assert by_rounds > 0 and by_rounds + by_scan == int(raw[7].sum())
+    assert evaluations >= 48 // assign._block_pods(48)
     loops = lambda *extra: loops_of(
         jax.make_jaxpr(lambda *a: greedy_assign(*a, *extra))(*args).jaxpr)
-    assert loops(skew, zone, zone) == ["scan"]
+    assert loops(skew, zone, zone) == ["while", "while", "while"]  # blocks:
+    # their rounds, their step
     assert loops() == ["while", "while"]             # the rounds, the tail
 
 

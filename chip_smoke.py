@@ -31,7 +31,11 @@ prints set-up seconds and counts, never a rate.
   ``WaveSkew`` wave settled in blocks against the sequential skew scan
   (``sequential_skew_assign``), bit for bit, on a wave of
   ``spread-1m-pct5``'s interleave and one of ``full-1m-pct5``'s mix under
-  the inter-pod gate, with the ``settled`` sums of each.
+  the inter-pod gate, with the ``settled`` sums of each; and the
+  prologue's presence flags (``prologue_stats`` on a packed table of the
+  cluster's rows) by the compare-and-any against the scatter-max it
+  replaced (``scatter_presence``), equal on every field and timed, at 9 x 5
+  and 512 x 64 domains.
 - **Phase F — every plug-in at once**: the benchmark's ``full-1m-pct5``
   deployment as the benchmark sets it up (1M tainted nodes, its widths,
   split label planes), a dozen waves of its own pods, then the combined
@@ -724,6 +728,94 @@ def _skew_variant(timer, *, batch=BATCH) -> dict:
     return seen
 
 
+def scatter_presence(table, size, ids, axis_name=None):
+    """The prologue's presence flags as the scatter-max of ``valid`` into
+    the domain ids that ``topology._domain_presence`` was until its
+    compare-and-any: the reference the prologue variant times and matches."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    present = jnp.zeros((size,), jnp.int32).at[ids].max(table.valid.astype(jnp.int32))
+    if axis_name is not None:
+        present = lax.pmax(present, axis_name)
+    return present.at[0].set(0)
+
+
+def prologue_inputs(nodes: int, zones: int, regions: int, seed: int = SEED):
+    """A packed table of ``nodes`` rows and count tables at full-1m-pct5's
+    slots (16 spread, 16 terms) for ``prologue_stats``: zone ids 1..zones-1
+    and region ids 1..regions-1 drawn per row, one row in 128 tombstoned,
+    uneven counts."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from k8s1m_tpu.config import TableSpec
+    from k8s1m_tpu.snapshot import NodeTableHost
+    from k8s1m_tpu.snapshot.constraints import ConstraintState
+    from k8s1m_tpu.snapshot.packing import build_packing_spec, pack_table_host
+
+    rng = np.random.default_rng(seed)
+    spec = TableSpec(max_nodes=nodes, max_zones=zones, max_regions=regions,
+                     spread_slots=16, affinity_slots=16)
+    host = NodeTableHost(spec)
+    host.valid[:] = rng.random(nodes) >= 1 / 128
+    host.zone[:] = rng.integers(1, zones, nodes)
+    host.region[:] = rng.integers(1, regions, nodes)
+    counts = lambda *shape: jnp.asarray(rng.integers(0, 1 << 12, shape), jnp.int32)
+    c, a = spec.spread_slots, spec.affinity_slots
+    cons = ConstraintState(
+        spread_node=counts(c, nodes), spread_zone=counts(c, zones),
+        spread_region=counts(c, regions), tgt_node=counts(a, nodes),
+        tgt_zone=counts(a, zones), tgt_region=counts(a, regions),
+        own_node=counts(a, nodes), own_zone=counts(a, zones),
+        own_region=counts(a, regions),
+    )
+    return pack_table_host(host, build_packing_spec(spec)), cons
+
+
+def _prologue_variant(timer, *, nodes=NODES, reps=15) -> dict:
+    """``prologue_stats`` on a packed table of ``nodes`` rows, its presence
+    flags by the compare-and-any it runs and by ``scatter_presence``, at
+    the cells' domain counts (9 zones, 5 regions) and the ``TableSpec``
+    defaults (512, 64): every ``TopoStats`` field equal, and each form's
+    median of ``reps`` timed calls (``block_until_ready``) in ms."""
+    from unittest import mock
+
+    import jax
+    import numpy as np
+
+    from k8s1m_tpu.engine.cycle import prologue_stats
+    from k8s1m_tpu.plugins import topology
+
+    ms = {}
+    for zones, regions in ((9, 5), (512, 64)):
+        table, cons = prologue_inputs(nodes, zones, regions)
+        # a function of its own for each form: jit reuses the trace of a
+        # function it has seen, and the patch is seen only while tracing
+        compiled = lambda: jax.jit(
+            lambda t, c: prologue_stats(t, c)).lower(table, cons).compile()
+        with timer.measure(f"B.prologue.{zones}x{regions}"):
+            forms = {"compare": compiled()}
+            with mock.patch.object(topology, "_domain_presence", scatter_presence):
+                forms["scatter"] = compiled()
+        outs = {name: jax.device_get(f(table, cons)) for name, f in forms.items()}
+        for field in ("zone_present", "region_present", "spread_min",
+                      "spread_max", "tgt_max", "tgt_total"):
+            _equal(f"prologue {zones}x{regions} {field}",
+                   getattr(outs["compare"], field), getattr(outs["scatter"], field))
+        for name, f in forms.items():
+            laps = []
+            for _ in range(reps):
+                t0 = time.perf_counter()
+                jax.block_until_ready(f(table, cons))
+                laps.append(time.perf_counter() - t0)
+            ms[f"{name}.{zones}x{regions}"] = round(1e3 * float(np.median(laps)), 4)
+        log(f"phase B prologue {zones}x{regions}: compare == scatter on every "
+            f"TopoStats field; median ms compare {ms[f'compare.{zones}x{regions}']}"
+            f", scatter {ms[f'scatter.{zones}x{regions}']}")
+    return ms
+
+
 def _assign_inputs(packed, profile, *, chunk, k, window):
     """Jitted ``(table, ints, bools, key) -> greedy_assign's eight
     arguments``: the wave's candidates from the fused kernel with what
@@ -1114,6 +1206,9 @@ def phase_b(coord, timer, *, batch, chunk, score_pct,
 
     # (vii) a WaveSkew wave's blocks against the sequential skew scan.
     attempt(failures, "skew_blocks", lambda: _skew_variant(timer, batch=batch))
+
+    # (viii) the prologue's presence flags, compare against scatter.
+    attempt(failures, "prologue", lambda: _prologue_variant(timer, nodes=n))
     return failures
 
 

@@ -66,7 +66,12 @@ class TopoStats:
 
 
 def _domain_presence(table: NodeTable, size: int, ids, axis_name=None):
-    present = jnp.zeros((size,), jnp.int32).at[ids].max(table.valid.astype(jnp.int32))
+    # A [size, N] compare reduced along N: one streaming pass over the
+    # columns, fused with a packed table's decode.  A scatter into so few
+    # bins serialises on its collisions (~10 ms a column at 1M rows on a
+    # TPU v5e, against ~1 ms for both columns compared).
+    hit = (ids[None, :] == jnp.arange(size, dtype=ids.dtype)[:, None]) & table.valid[None, :]
+    present = hit.any(axis=1).astype(jnp.int32)
     if axis_name is not None:
         present = lax.pmax(present, axis_name)
     return present.at[0].set(0)  # domain 0 = "label missing", never a domain

@@ -103,23 +103,6 @@ def main():
         "profile with live ConstraintState",
     )
     ap.add_argument(
-        "--deltacache", action="store_true",
-        help="ISSUE 12 deltasched lane: pre-fill the per-shape "
-        "feasibility/score planes (engine/deltacache.py) and run the "
-        "delta step — full kernel over --delta-dirty rows per step, "
-        "scatter-merge, hashed top-k over the merged planes.  The "
-        "steady-state low-churn regime; byte-identical binds to the "
-        "full pass.  Implies --score-pct 100 (planes cover the whole "
-        "table); incompatible with --constraints (constraint-coupled "
-        "pods are not cacheable).",
-    )
-    ap.add_argument(
-        "--delta-dirty", type=int, default=128,
-        help="journaled dirty rows recomputed per delta step (the "
-        "churn knob of the --deltacache lane; default 128 ~ the "
-        "<=100 dirty rows/s low-churn regime at wave rate)",
-    )
-    ap.add_argument(
         "--affinity", action="store_true",
         help="BASELINE config 2: pods carry NodeAffinity required terms "
         "(zone In + region NotIn) and preferred zone terms, scheduled "
@@ -130,12 +113,6 @@ def main():
     args = ap.parse_args()
     if args.constraints and args.affinity:
         ap.error("--constraints and --affinity are separate configs")
-    if args.deltacache:
-        if args.constraints:
-            ap.error("--deltacache: constraint-coupled pods are not "
-                     "cacheable (engine/deltacache.py)")
-        if args.score_pct is None:
-            args.score_pct = 100     # planes cover the whole table
     from k8s1m_tpu.snapshot.packing import resolve_packing
 
     args.packing = resolve_packing(args.packing)
@@ -175,10 +152,6 @@ def main():
         args.nodes // mesh.shape["sp"] if mesh is not None else args.nodes
     )
     sample_rows = sample_rows_for(window_nodes, args.score_pct, args.chunk)
-    if args.deltacache and sample_rows is not None:
-        ap.error("--deltacache needs the full scan (--score-pct 100): "
-                 "a sampled window computes different planes than the "
-                 "cache holds")
 
     # Constraint runs size the domain dims to the workload (64 zones /
     # 8 regions from populate_kwok_nodes): the fused constraint stage
@@ -298,87 +271,15 @@ def main():
     # from every return.
     donate = True
 
-    delta_detail = {}
-    if args.deltacache:
-        # The deltasched lane: pre-fill one plane slot per pod shape
-        # (engine/deltacache.py fill executable, in fill-batch groups),
-        # then run the delta step — the steady-state shape-hit wave.
-        # ``planes`` rides the loop like ``table``: both donate.
-        import dataclasses as _dc
-
-        from jax import numpy as jnp
-
-        from k8s1m_tpu.engine.cycle import (
-            fill_shape_planes,
-            schedule_batch_delta,
+    def step(table, constraints, i):
+        table, constraints, _asg, rows = schedule_batch_packed(
+            table, packed, keys[i], profile=profile,
+            constraints=constraints,
+            chunk=args.chunk, k=args.k, backend=args.backend,
+            sample_rows=sample_rows, sample_offset=window(i),
+            mesh=mesh, donate=donate,
         )
-        from k8s1m_tpu.snapshot.hotfeed import shape_key
-
-        pods_of = {}
-        for p in pods:
-            pods_of.setdefault(shape_key(p), []).append(p)
-        if None in pods_of:
-            raise SystemExit("--deltacache: workload has uncacheable pods")
-        shapes = list(pods_of)
-        slot_of = {s: i for i, s in enumerate(shapes)}
-        slot_ids = jnp.asarray(np.array(
-            [slot_of[shape_key(p)] for p in pods], np.int32
-        ))
-        nslots = len(shapes)
-        pmask = jnp.zeros((nslots, args.nodes), jnp.bool_)
-        pscore = jnp.zeros((nslots, args.nodes), jnp.int32)
-        if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            plane_sharding = NamedSharding(mesh, P(None, "sp"))
-            pmask = jax.device_put(pmask, plane_sharding)
-            pscore = jax.device_put(pscore, plane_sharding)
-        fb = 16
-        fill_enc = PodBatchHost(
-            _dc.replace(pod_spec, batch=fb), spec, host.vocab
-        )
-        planes = (pmask, pscore)
-        for off in range(0, nslots, fb):
-            reps = [pods_of[s][0] for s in shapes[off:off + fb]]
-            fs = np.full(fb, nslots, np.int32)
-            fs[: len(reps)] = range(off, off + len(reps))
-            planes = fill_shape_planes(
-                table, fill_enc.encode_packed(reps), jnp.asarray(fs),
-                planes, profile=profile, chunk=args.chunk, mesh=mesh,
-            )
-        rng = np.random.default_rng(0)
-        dirtys = [
-            jnp.asarray(np.sort(rng.choice(
-                args.nodes, args.delta_dirty, replace=False,
-            )).astype(np.int32))
-            for _ in range(args.warmup + args.steps)
-        ]
-        delta_detail = {"delta": {
-            "dirty_rows_per_step": args.delta_dirty,
-            "dirty_fraction": round(args.delta_dirty / args.nodes, 6),
-            "shapes": nslots,
-            "plane_mb": round(nslots * args.nodes * 5 / 2**20, 1),
-        }}
-
-        def step(table, planes, i):
-            table, _asg, rows, planes = schedule_batch_delta(
-                table, packed, keys[i], profile=profile,
-                slot_ids=slot_ids, planes=planes, dirty=dirtys[i],
-                chunk=args.chunk, k=args.k, mesh=mesh, donate=donate,
-            )
-            return table, planes, rows
-
-        constraints = planes     # rides the loop variable below
-    else:
-        def step(table, constraints, i):
-            table, constraints, _asg, rows = schedule_batch_packed(
-                table, packed, keys[i], profile=profile,
-                constraints=constraints,
-                chunk=args.chunk, k=args.k, backend=args.backend,
-                sample_rows=sample_rows, sample_offset=window(i),
-                mesh=mesh, donate=donate,
-            )
-            return table, constraints, rows
+        return table, constraints, rows
 
     from k8s1m_tpu.snapshot import packing
 
@@ -436,8 +337,6 @@ def main():
         else "_affinity" if args.affinity
         else ""
     )
-    if args.deltacache:
-        suffix += "_delta"
     if sample_rows is not None:
         # Only when a window is actually in effect: chunk rounding can
         # promote a small table's pct window to a full scan.
@@ -460,7 +359,6 @@ def main():
         # — the requested mode is never reported as evidence.
         **layout_report,
         "donation_inplace": donation_inplace,
-        **delta_detail,
     }
     print(json.dumps(report))
 

@@ -21,8 +21,7 @@ prints set-up seconds and counts, never a rate.
 - **Phase B — kernels that compile**: against the same resident table,
   one wave through the normal step functions for each kernel variant the
   production path can select (base, affinity, constraint — all over the
-  packed int16/int8 planes — and the pallas delta tail behind the
-  candidate index), each pallas ``(idx, prio)`` compared bit for bit
+  packed int16/int8 planes), each pallas ``(idx, prio)`` compared bit for bit
   with the XLA scan's on the same inputs, plus the XLA scan itself at
   the window shape and over all 1M rows; and ``greedy_assign`` (a wave's
   conflicts in parallel rounds) against the sequential scan it replaced,
@@ -940,114 +939,6 @@ def _window_node_names(host, offset: int, rows: int, *, want: int) -> list:
     raise RuntimeError(f"only {len(names)} of {want} nodes in the window")
 
 
-def _delta_variant(table, vocab, table_spec, timer, *, batch, chunk, k):
-    """A pct100 template wave through the delta step with the candidate
-    index on: the pallas plane tail (delta_plane_topk) compiles inside
-    the normal step function, and its (idx, prio) must equal the XLA
-    tail's over the same merged planes."""
-    import dataclasses as dc
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from k8s1m_tpu.cluster.workload import node_affinity_pods
-    from k8s1m_tpu.config import PodSpec
-    from k8s1m_tpu.engine.cycle import fill_shape_planes, schedule_batch_delta
-    from k8s1m_tpu.engine.deltacache import DeltaPlaneCache, plane_topk
-    from k8s1m_tpu.ops.pallas_topk import delta_plane_topk
-    from k8s1m_tpu.ops.priority import seed_of
-    from k8s1m_tpu.plugins.registry import Profile
-    from k8s1m_tpu.snapshot.hotfeed import shape_key
-    from k8s1m_tpu.snapshot.pod_encoding import PodBatchHost
-    from k8s1m_tpu.tools.megarow_drill import stratum_bits_for
-
-    n = table.num_rows
-    profile = Profile(topology_spread=0, interpod_affinity=0)
-    pod_spec = PodSpec(
-        batch=batch, aff_terms=1, aff_exprs=2, aff_values=2, pref_terms=1
-    )
-    # Deployment-template shapes (tools/sched_bench --shape-pool): a few
-    # structural shapes, every pod of the wave drawn from them.
-    templates = node_affinity_pods(8, zones=8, regions=4)
-    pods = []
-    for i in range(batch):
-        t = templates[i % len(templates)]
-        pods.append(dc.replace(t, name=f"tmpl-{i}", cpu_milli=10 + i % 8))
-    sbits = stratum_bits_for(n)
-    cache = DeltaPlaneCache(n, index_k=64, stratum_bits=sbits)
-    gen = vocab.generation()
-    cache.check_generation(gen)
-    keys = [shape_key(p) for p in pods]
-    cache.plan(keys, batch)             # first sighting: shapes noted
-    plan = cache.plan(keys, batch)      # second: promoted, fills planned
-    if plan.slot_ids is None or not plan.fill_idx:
-        raise RuntimeError("delta: template wave did not plan a delta step")
-    packed = PodBatchHost(pod_spec, table_spec, vocab).encode_packed(pods)
-    fill_enc = PodBatchHost(
-        dc.replace(pod_spec, batch=cache.fill_batch), table_spec, vocab
-    )
-    fs = np.full(cache.fill_batch, cache.slots, np.int32)
-    fs[: len(plan.fill_slots)] = plan.fill_slots
-    with timer.measure("B.delta.fill"):
-        planes = fill_shape_planes(
-            table, fill_enc.encode_packed([pods[i] for i in plan.fill_idx]),
-            jnp.asarray(fs), cache.planes(gen), profile=profile, chunk=chunk,
-        )
-        jax.block_until_ready(planes)
-    cache.commit(*planes)
-    cache.note_fill(plan)
-
-    key = jax.random.key(SEED)
-    flags = []
-    rows_by = {}
-    for backend in ("pallas", "xla"):
-        planes, index = cache.planes(gen), cache.index_state(gen)
-        with timer.measure(f"B.delta.{backend}_step"):
-            # Two waves of the one executable: the first finds the index
-            # unbuilt and takes the plane tail (rebuilding it), the
-            # second may take the index tail.
-            for _ in range(2):
-                _t, _asg, rows, planes, index, flag = schedule_batch_delta(
-                    table, packed, key, profile=profile,
-                    slot_ids=jnp.asarray(plan.slot_ids), planes=planes,
-                    dirty=jnp.asarray(plan.dirty), chunk=chunk, k=k,
-                    backend=backend, stratum_bits=sbits, index=index,
-                    rep_idx=jnp.asarray(plan.rep_idx),
-                    rebuild_slots=jnp.asarray(plan.rebuild_slots),
-                    index_dirty_cap=cache.index_dirty_cap,
-                )
-                flags.append(int(jax.device_get(flag)))
-            rows_by[backend] = jax.device_get(rows)
-    _equal("delta step rows", rows_by["pallas"], rows_by["xla"])
-    if flags[0] != 0:
-        raise RuntimeError("delta: unbuilt index did not take the plane tail")
-
-    pmask, pscore = planes
-    slot_ids = jnp.asarray(plan.slot_ids)
-    out = {}
-    for backend, fn in (("pallas", delta_plane_topk), ("xla", plane_topk)):
-        with timer.measure(f"B.delta.{backend}_tail"):
-            cand = jax.jit(
-                lambda m, s, sl, sd, fn=fn: fn(
-                    m, s, sl, sd, chunk=chunk, k=k, stratum_bits=sbits
-                )
-            )(pmask, pscore, slot_ids, seed_of(key))
-            out[backend] = jax.device_get((cand.idx, cand.prio))
-    _equal("delta idx", out["pallas"][0], out["xla"][0])
-    _equal("delta prio", out["pallas"][1], out["xla"][1])
-    # Not every pod need bind: a few hundred pods of one shape share
-    # one plane's top classes and can exhaust their four candidates'
-    # pod slots (they retry next wave) — identically on both backends.
-    bound = int((rows_by["pallas"] >= 0).sum())
-    if not bound:
-        raise RuntimeError("delta: no pod bound")
-    log(
-        f"phase B delta: delta_plane_topk compiled, {bound}/{batch} bound, "
-        f"(idx, prio) == XLA plane_topk, index path flags={flags}"
-    )
-
-
 def phase_b(coord, timer, *, batch, chunk, score_pct,
             fit_nodes=FIT_NODES) -> dict:
     """Runs every variant even after one fails, so a chip run reports
@@ -1166,12 +1057,7 @@ def phase_b(coord, timer, *, batch, chunk, score_pct,
 
     attempt(failures, "constraint", constraint)
 
-    # (iv) the pallas delta tail behind the candidate index.
-    attempt(failures, "delta", lambda: _delta_variant(
-        table, vocab, spec, timer, batch=batch, chunk=chunk, k=k
-    ))
-
-    # (v) the XLA scan through the normal step function: at the window
+    # (iv) the XLA scan through the normal step function: at the window
     # shape, then over every row of the table (the shape that did not
     # finish warm-up on the chip before chunk_topk took lax.top_k).
     def xla_scan():
@@ -1199,15 +1085,15 @@ def phase_b(coord, timer, *, batch, chunk, score_pct,
 
     attempt(failures, "xla_scan", xla_scan)
 
-    # (vi) the conflict rounds against the sequential scan.
+    # (v) the conflict rounds against the sequential scan.
     attempt(failures, "assign", lambda: _assign_variant(
         table, base, base_profile, timer, fit_nodes=fit_nodes, **win
     ))
 
-    # (vii) a WaveSkew wave's blocks against the sequential skew scan.
+    # (vi) a WaveSkew wave's blocks against the sequential skew scan.
     attempt(failures, "skew_blocks", lambda: _skew_variant(timer, batch=batch))
 
-    # (viii) the prologue's presence flags, compare against scatter.
+    # (vii) the prologue's presence flags, compare against scatter.
     attempt(failures, "prologue", lambda: _prologue_variant(timer, nodes=n))
     return failures
 

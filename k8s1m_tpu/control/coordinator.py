@@ -115,17 +115,9 @@ from k8s1m_tpu.engine.cycle import (
     adjust_constraints_impl,
     candidates_kernel,
     commit_fields_np,
-    fill_shape_planes,
     sample_offset_for,
     sample_rows_for,
-    schedule_batch_delta,
     schedule_batch_packed,
-)
-from k8s1m_tpu.engine.deltacache import (
-    DeltaPlaneCache,
-    note_index_oversized,
-    note_index_wave,
-    resolve_deltasched,
 )
 from k8s1m_tpu.loadshed import CircuitBreaker, HealthController, Signals
 from k8s1m_tpu.loadshed import CLOSED as BREAKER_CLOSED
@@ -138,7 +130,6 @@ from k8s1m_tpu.oracle import oracle_feasible, oracle_score
 from k8s1m_tpu.plugins.registry import Profile, degraded_profile
 from k8s1m_tpu.snapshot.constraints import ConstraintTracker, empty_constraints
 from k8s1m_tpu.snapshot.hotfeed import (
-    PLAIN,
     EncodeCache,
     HostFeed,
     HotPodBatchHost,
@@ -146,7 +137,6 @@ from k8s1m_tpu.snapshot.hotfeed import (
     cache_counts,
     encode_batch,
     fingerprint,
-    shape_key,
 )
 from k8s1m_tpu.snapshot.node_table import (
     ALL_COLUMNS,
@@ -473,7 +463,7 @@ class PodShape:
     __slots__ = ("labels", "node_selector", "tolerations", "required_terms",
                  "preferred_terms", "affinity", "topology_spread",
                  "scheduler_name", "spread_refs", "affinity_refs",
-                 "spread_incs", "ipa_incs", "fp", "coupled", "keeps",
+                 "spread_incs", "ipa_incs", "fp", "keeps",
                  "registers", "gang", "tenant")
 
     def __init__(self, labels: dict, tolerations: list, topology_spread: list,
@@ -501,10 +491,6 @@ class PodShape:
         # hotfeed.fingerprint of every pod that refers to this shape
         # (labels are not structural; PLAIN for a shape of labels alone).
         self.fp = fingerprint(self.pod("/", 0, 0))
-        # Its plane reads the live count tables (hotfeed.shape_key).
-        self.coupled = bool(
-            spread_refs or affinity_refs or spread_incs or ipa_incs
-        )
         self.keeps = _constraintful(self)
         # Binding it registers constraints of its own with the tracker.
         self.registers = bool(
@@ -848,7 +834,7 @@ class Coordinator:
         # head-samples 1-in-N pods (deterministic by pod-key hash) and
         # records their whole journey as a contiguous span chain —
         # admit, gang staging, queue wait, encode (cache attrs),
-        # dispatch wait, device (wave epoch / depth / delta-vs-full),
+        # dispatch wait, device (wave epoch / depth),
         # bind CAS incl. retries, preemption/eviction, failover
         # requeue.  None (the default) installs the null tracer: every
         # emit site is behind a single ``enabled`` read, so tracing off
@@ -905,29 +891,6 @@ class Coordinator:
         # (meshpack): the packed planes shard over sp like the plain
         # columns and decode inside the shard-local chunk slice.
         packing: str | None = None,
-        # Incremental scheduling (engine/deltacache.py): cache each pod
-        # shape's feasibility/score plane in HBM and run the full
-        # filter+score kernel only over dirty rows ∪ in-flight bind
-        # rows when every shape in a wave hits — byte-identical binds,
-        # O(batch × dirty) steady-state device work.  None is "off".
-        # Engages only for full-scan XLA waves (score_pct 100, no row
-        # mask, not degraded); everything else takes the ordinary full
-        # pass.
-        deltacache: str | bool | None = None,
-        delta_slots: int = 64,
-        # Score-stratified candidate index (engine/deltacache.py): keep
-        # a per-resident-slot top-K row index in HBM so an all-hit wave
-        # with a small dirty set derives candidates from index + dirty
-        # rows and skips the full-plane scan — O(dirty + K·batch)
-        # instead of O(batch × N).  0 (default) = planes only.  The
-        # index keys on class_key(score, column, stratum_bits): with
-        # stratum_bits=0 it fails closed whenever scores tie at the
-        # floor (homogeneous clusters), so saturated drills set
-        # stratum_bits to split score levels into hash strata whose
-        # order is wave-invariant.  Byte-identical either way.
-        delta_index_k: int = 0,
-        stratum_bits: int = 0,
-        delta_index_dirty_cap: int | None = None,
         # PodTopologySpread's hard zone and region constraints counted
         # inside the wave, pod by pod in wave order (engine/assign.py),
         # so that they hold at every bind and not only between waves.
@@ -962,14 +925,6 @@ class Coordinator:
         # before the retire pass, and cannot stamp those spans).
         self._trace_gaveup: set[str] = set()
         self.backend = backend
-        from k8s1m_tpu.ops.priority import JITTER_BITS
-
-        if not 0 <= stratum_bits <= JITTER_BITS:
-            raise ValueError(
-                f"stratum_bits must be in [0, {JITTER_BITS}], "
-                f"got {stratum_bits}"
-            )
-        self.stratum_bits = stratum_bits
         self.pipeline = pipeline
         if depth < 1:
             raise ValueError(f"depth must be >= 1, got {depth}")
@@ -1200,40 +1155,6 @@ class Coordinator:
                     donate_argnums=(0,),
                     out_shardings=cons_shardings,
                 )
-        # Delta-plane cache (deltasched): built after the mesh/sharding
-        # decisions so the plane buffers land row-sharded over sp like
-        # every other packed plane.  The fill encoder shares the one
-        # template cache — shape representatives were all seen at
-        # intake, so fills re-encode against warm templates.
-        self._delta: DeltaPlaneCache | None = None
-        self._delta_fill_enc: HotPodBatchHost | None = None
-        if resolve_deltasched(deltacache) == "on":
-            plane_sharding = None
-            if mesh is not None:
-                from jax.sharding import NamedSharding
-                from jax.sharding import PartitionSpec as P
-
-                plane_sharding = NamedSharding(mesh, P(None, "sp"))
-            self._delta = DeltaPlaneCache(
-                table_spec.max_nodes, slots=delta_slots,
-                sharding=plane_sharding,
-                index_k=delta_index_k, stratum_bits=stratum_bits,
-                index_dirty_cap=delta_index_dirty_cap,
-            )
-            self._delta_fill_enc = HotPodBatchHost(
-                dataclasses.replace(
-                    pod_spec, batch=self._delta.fill_batch
-                ),
-                table_spec, self.host.vocab, cache=self.encode_cache,
-            )
-        elif delta_index_k:
-            # Same fail-loud rationale as resolve_deltasched: an index
-            # with no delta cache would silently never engage while the
-            # run is labeled "index on".
-            raise ValueError(
-                "delta_index_k requires deltacache='on' (the candidate "
-                "index rides the delta-plane cache)"
-            )
         self.key = jax.random.key(seed)
 
         self.queue: collections.deque[PendingPod] = collections.deque()
@@ -2086,10 +2007,6 @@ class Coordinator:
         # and the relist may need rows.
         self.host.release_rows(None)
         self._midflight_rows.clear()
-        if self._delta is not None:
-            # The relist rebuilds the row->node mapping wholesale; no
-            # row set bounds what a cached plane may now mis-describe.
-            self._delta.drop_all("resync")
         with self._stage("resync"):
             self._nodes_watch.cancel()
             self._pods_watch.cancel()
@@ -2527,16 +2444,6 @@ class Coordinator:
         if not self._dirty_rows and not self._dirty_caps:
             return
         with self._stage("sync"):
-            if self._delta is not None:
-                # Journal the rows BEFORE the scatters dispatch: a delta
-                # wave enqueued after this point recomputes them from
-                # the post-scatter table (stream order), so version <=
-                # journal stamp <= device truth holds per row.  Both
-                # dirty classes ride one recompute — re-deriving a full
-                # row's plane columns is exact for a capacity-only
-                # change too, just conservative.
-                self._delta.note_rows(self._dirty_rows)
-                self._delta.note_rows(self._dirty_caps)
             if self._dirty_rows:
                 # A row needing the full upload supersedes its
                 # capacity-only entry (the full delta includes CAP cols).
@@ -2625,14 +2532,6 @@ class Coordinator:
         per-wave counter."""
         return self._donation_inplace
 
-    @property
-    def delta_enabled(self) -> bool:
-        """Whether the delta-plane cache (engine/deltacache.py) is
-        wired into this coordinator.  The public read for bench/report
-        surfaces — `deltasched_waves_total{path}` is the per-wave
-        counter."""
-        return self._delta is not None
-
     def _note_table_bytes(self, table) -> None:
         layout = "packed" if is_packed(table) else "unpacked"
         other = "unpacked" if layout == "packed" else "packed"
@@ -2700,12 +2599,6 @@ class Coordinator:
             jax.block_until_ready(jax.tree.leaves(self.table))
         self._dirty_rows.clear()
         self._dirty_caps.clear()
-        if self._delta is not None:
-            # The wholesale re-upload resets the device request columns
-            # to host truth — a state no journaled row set describes
-            # (deltasched invalidation contract: packing rebuilds drop
-            # the cache wholesale).
-            self._delta.drop_all("packing")
         self.table = self._table_to_device()
 
     # ---- the cycle -----------------------------------------------------
@@ -3385,139 +3278,6 @@ class Coordinator:
             return self._profile_degraded, self._sample_rows_degraded
         return self.profile, self._sample_rows
 
-    # ---- deltasched: plane-cached waves (engine/deltacache.py) ---------
-
-    @staticmethod
-    def _delta_key(p: PendingPod):
-        """The pod's plane-cache shape key (snapshot/hotfeed.shape_key),
-        or None for uncacheable shapes.  Native fast-lane pods
-        (pod=None) carry no nodeName by construction, and their interned
-        shape holds their fingerprint (PLAIN without one) and whether
-        constraints couple them to the live count tables — their key
-        needs no PodInfo materialization at all."""
-        if p.pod is None:
-            sh = p.shape
-            if sh is None:
-                return (PLAIN, p.cpu_milli, p.mem_kib)
-            return None if sh.coupled else (sh.fp, p.cpu_milli, p.mem_kib)
-        return shape_key(p.pod)
-
-    def _plan_delta(self, batch_pods, batch):
-        """Plan this wave's delta path: shape-key lookups, plane fills
-        for recurring cold shapes (dispatched here, BEFORE the wave, so
-        a filled wave can still go delta), and the journaled dirty
-        slice.  Returns the WavePlan when the wave may run the delta
-        step, None for the ordinary full pass."""
-        cache = self._delta
-        gen = self.host.vocab.generation()
-        cache.check_generation(gen)
-        plan = cache.plan(
-            [self._delta_key(p) for p in batch_pods], batch.batch
-        )
-        if plan.fill_idx:
-            try:
-                reps = [batch_pods[i].ensure_pod() for i in plan.fill_idx]
-                fill_pb = self._delta_fill_enc.encode_packed(reps)
-            except ValueError:
-                # Representative shapes overflowed a fill-batch bound
-                # (e.g. distinct selector keys past PodSpec.query_keys
-                # across shapes): un-allocate and take the full pass —
-                # never guess at a partial fill.
-                cache.abort_fills(plan)
-                return None
-            fs = np.full(cache.fill_batch, cache.slots, np.int32)
-            fs[: len(plan.fill_slots)] = plan.fill_slots
-            try:
-                planes = fill_shape_planes(
-                    self.table, fill_pb, jnp.asarray(fs),
-                    cache.planes(gen),
-                    profile=self.profile, chunk=self.chunk, mesh=self.mesh,
-                )
-            except Exception:
-                # The fill executable donates the plane buffers; after a
-                # failed dispatch they are in an unknown consumed state.
-                # Reset fail-closed and re-raise for the breaker.
-                cache.reset("fill-error")
-                raise
-            cache.commit(*planes)
-            cache.note_fill(plan)
-        return plan if plan.slot_ids is not None else None
-
-    def _launch_delta(self, batch, subkey, plan):
-        """Dispatch the delta-wave executable: full kernel over the
-        dirty slice ∪ in-flight bind rows (each unretired wave's
-        device-resident rows_dev — consumed on-stream, no host sync),
-        scatter-merged into the cached planes, hashed top-k over the
-        merged planes, shared greedy/commit epilogue.  Constraint state
-        is untouched: delta waves carry only constraint-termless pods,
-        whose commit increments are identically zero.
-
-        Returns (table, asg, rows_dev, index_flag_dev, attempted,
-        touched): the last three feed the wave's retire-time
-        ``deltasched_index_*`` metric stamping (flag is a device scalar
-        — fetched only at _complete, never here)."""
-        cache = self._delta
-        gen = self.host.vocab.generation()
-        planes = cache.planes(gen)
-        index = flag = None
-        attempted = False
-        touched = (0, 0)
-        if cache.index_k:
-            index = cache.index_state(gen)
-            # Whether the in-step index update will run is a trace-time
-            # SHAPE decision inside the executable (pow2-padded dirty
-            # width vs the cap); mirror it host-side for the metric —
-            # an oversized wave runs the plane tail + rebuild, never
-            # the index tail, so it is not an "attempt".
-            dirty_w = len(plan.dirty) + sum(
-                int(w.rows_dev.shape[0]) for w in self._inflights
-            )
-            attempted = dirty_w <= cache.index_dirty_cap
-            if not attempted:
-                note_index_oversized()
-            # Touched-rows accounting for the sublinear claim (sched_bench
-            # --delta-profile): index tail visits the dirty slice plus K
-            # index entries per pod; the plane tail scans all N rows plus
-            # the dirty slice.
-            touched = (
-                dirty_w + cache.index_k * batch.batch,
-                cache.num_rows + dirty_w,
-            )
-        try:
-            out = schedule_batch_delta(
-                self.table, batch, subkey,
-                profile=self.profile,
-                slot_ids=jnp.asarray(plan.slot_ids),
-                planes=planes,
-                dirty=jnp.asarray(plan.dirty),
-                inflight_rows=tuple(w.rows_dev for w in self._inflights),
-                chunk=self.chunk, k=self.k,
-                mesh=self.mesh, donate=self._donate,
-                backend=self.backend,
-                stratum_bits=self.stratum_bits,
-                index=index,
-                rep_idx=(
-                    jnp.asarray(plan.rep_idx) if index is not None else None
-                ),
-                rebuild_slots=(
-                    jnp.asarray(plan.rebuild_slots)
-                    if index is not None else None
-                ),
-                index_dirty_cap=cache.index_dirty_cap,
-            )
-        except Exception:
-            # Donated buffers are in an unknown state after a failed
-            # dispatch; reset fail-closed and re-raise for the breaker.
-            cache.reset("dispatch-error")
-            raise
-        if index is not None:
-            table, asg, rows_dev, planes, index, flag = out
-            cache.commit(planes[0], planes[1], plan, index=index)
-        else:
-            table, asg, rows_dev, planes = out
-            cache.commit(planes[0], planes[1], plan)
-        return table, asg, rows_dev, flag, attempted, touched
-
     def _launch(self, batch_pods, batch):
         """Enqueue the device step for an encoded batch (async — no
         device→host transfer is forced).  Faultline hook
@@ -3538,21 +3298,6 @@ class Coordinator:
                         raise faultline.InjectedFault(d)
             profile, sample_rows = self._active_knobs()
             self.key, subkey = jax.random.split(self.key)
-            delta_plan = None
-            if (
-                self._delta is not None
-                and sample_rows is None
-                and self._row_mask_dev is None
-                and profile is self.profile
-                and self.table is not None
-            ):
-                # Delta eligibility is wave-local and conservative: only
-                # the full-scan production shape reuses planes (sampled
-                # windows, degraded profiles and masked candidate views
-                # all compute DIFFERENT planes than the cache holds).
-                # Both backends qualify — the pallas delta tail
-                # (delta_plane_topk) landed with the candidate index.
-                delta_plan = self._plan_delta(batch_pods, batch)
             probe_ptr = None
             if self._donate and self._donation_inplace is None:
                 # One-time donation probe (first wave): did the runtime
@@ -3563,30 +3308,20 @@ class Coordinator:
                     probe_ptr = donation_probe(self.table)
                 except Exception:  # graftlint: disable=broad-except (probe is evidence-only; any exotic array type just reports inplace=no)
                     self._donation_inplace = False
-        idx_flag = None
-        idx_attempted = False
-        idx_touched = (0, 0)
         with self._stage("device"):
-            if delta_plan is not None:
-                (
-                    self.table, asg, rows_dev,
-                    idx_flag, idx_attempted, idx_touched,
-                ) = self._launch_delta(batch, subkey, delta_plan)
-            else:
-                self.table, self.constraints, asg, rows_dev = schedule_batch_packed(
-                    self.table, batch, subkey,
-                    profile=profile, constraints=self.constraints,
-                    chunk=self.chunk, k=self.k, backend=self.backend,
-                    sample_rows=sample_rows,
-                    sample_offset=(
-                        self._next_window(sample_rows) if sample_rows else 0
-                    ),
-                    row_mask=self._row_mask_dev,
-                    mesh=self.mesh,
-                    donate=self._donate,
-                    stratum_bits=self.stratum_bits,
-                    in_wave_skew=self.in_wave_skew,
-                )
+            self.table, self.constraints, asg, rows_dev = schedule_batch_packed(
+                self.table, batch, subkey,
+                profile=profile, constraints=self.constraints,
+                chunk=self.chunk, k=self.k, backend=self.backend,
+                sample_rows=sample_rows,
+                sample_offset=(
+                    self._next_window(sample_rows) if sample_rows else 0
+                ),
+                row_mask=self._row_mask_dev,
+                mesh=self.mesh,
+                donate=self._donate,
+                in_wave_skew=self.in_wave_skew,
+            )
         if probe_ptr is not None:
             try:
                 self._donation_inplace = donation_inplace(
@@ -3600,7 +3335,6 @@ class Coordinator:
             )
         _WAVES.inc(kernel=candidates_kernel(
             self.backend, batch.groups, self.constraints is not None,
-            "delta" if delta_plan is not None else "full",
         ))
         # Start the device->host copy of the bind decision now: by the
         # time _complete runs (a drain + encode later), the bytes are
@@ -3621,10 +3355,6 @@ class Coordinator:
             batch_pods, batch, asg, rows_dev, t_start,
             epoch=self.host.begin_wave(),
             depth=len(self._inflights) + 1,
-            path="delta" if delta_plan is not None else "full",
-            index_flag_dev=idx_flag,
-            index_attempted=idx_attempted,
-            index_touched=idx_touched,
         )
         tracer = self._tracer
         if tracer.enabled:
@@ -3905,14 +3635,6 @@ class Coordinator:
                     if n:
                         _WAVE_UNBOUND.inc(n, reason=reason)
         t_sync = time.perf_counter()
-        if inflight.index_flag_dev is not None:
-            # The which-tail-ran flag is fetched at retire (the wave's
-            # sync point) so the launch path never blocks on it.
-            note_index_wave(
-                int(jax.device_get(inflight.index_flag_dev)),
-                inflight.index_attempted,
-                *inflight.index_touched,
-            )
 
         failed = np.zeros(batch.batch, bool)
         # Per-pod settled outcome (True = the bind stuck), consumed by
@@ -4044,16 +3766,6 @@ class Coordinator:
             if not reached:
                 return 0, 0, 0
             brows = rows[bound_idx]
-            if self._delta is not None:
-                # This wave's device-side assumes are now host-visible:
-                # journal its bound rows so later delta waves recompute
-                # their plane columns.  While the wave was IN flight the
-                # same rows reached delta waves on-stream via rows_dev
-                # (engine/deltacache.combine_dirty) — this retire stamp
-                # closes the window for waves launched from here on.
-                # CAS conflicts and tombstoned rows additionally ride
-                # the ordinary dirty-row re-upload below.
-                self._delta.note_rows(brows)
             # Rows tombstoned while this wave was in flight: the node is
             # gone (quarantine guarantees no reuse before this retire, so
             # an invalid row can't alias a new node) — treat like a CAS
@@ -4254,8 +3966,8 @@ class Coordinator:
     def _trace_retire(self, inflight: Wave, rows, bound_ok, t_sync: float) -> None:
         """Wave-retire observability pass (runs only while tracing is
         enabled): close every sampled pod's span chain — the device
-        span stamped with the wave's epoch, pipeline depth and
-        delta-vs-full pass, the bind span with the settled outcome."""
+        span stamped with the wave's epoch and pipeline depth, the bind
+        span with the settled outcome."""
         tracer = self._tracer
         if not tracer.enabled:
             return
@@ -4265,7 +3977,6 @@ class Coordinator:
             tracer.emit(
                 p.key_str, "device", t=t_sync,
                 wave_epoch=inflight.epoch, depth=inflight.depth,
-                path=inflight.path,
             )
             if ok:
                 tracer.finish(p.key_str, "bind", t=now, outcome="bound")

@@ -103,21 +103,9 @@ class Wave:
     rows_dev: jax.Array  # i32[B] bound row per pod (-1 = unbound)
     t_start: float
     epoch: int
-    # Podtrace span attributes stamped at launch (obs/podtrace.py):
-    # in-flight depth including this wave, and which kernel pass ran
-    # ("full" vs the deltacache "delta" path).
+    # Podtrace span attribute stamped at launch (obs/podtrace.py):
+    # in-flight depth including this wave.
     depth: int = 1
-    path: str = "full"
-    # Candidate-index outcome (deltasched index waves only): the device
-    # i32 flag the delta step returns (1 = candidates derived from the
-    # index, 0 = the index failed closed to the plane tail), fetched at
-    # retire alongside rows_dev; ``index_attempted`` is the host-side
-    # trace decision (False = the dirty slice exceeded the in-step
-    # cap); ``index_touched`` is the (index-path, plane-path) touched-
-    # row pair for deltasched_index_touched_rows_total.
-    index_flag_dev: object | None = None
-    index_attempted: bool = False
-    index_touched: tuple = (0, 0)
 
 
 @struct.dataclass
@@ -430,7 +418,6 @@ def filter_score_topk(
     stats=None,
     row_offset=0,
     pod_offset=0,
-    stratum_bits: int = 0,
     per_zone: bool = False,
 ) -> Candidates:
     """Stream the node table in chunks, keeping each pod's top-k candidates
@@ -479,7 +466,7 @@ def filter_score_topk(
             lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
             + start + row_offset
         )
-        prio = pack_hashed(score, seed, mask, pod_rows, node_cols, stratum_bits)
+        prio = pack_hashed(score, seed, mask, pod_rows, node_cols)
         if per_zone:
             top_prio, idx = chunk_top_per_zone(prio, tchunk.zone, k)
         else:
@@ -644,7 +631,6 @@ def candidates(
     row_offset=0,
     pod_offset=0,
     axis_name: str | None = None,
-    stratum_bits: int = 0,
     stats=None,
     in_wave_skew: bool = False,
 ) -> Candidates:
@@ -721,15 +707,14 @@ def candidates(
                 view, batch, key, profile, chunk=chunk, k=k,
                 row_offset=row_offset, pod_offset=pod_offset,
                 with_affinity=with_affinity,
-                constraints=view_cons, stats=stats,
-                stratum_bits=stratum_bits, per_zone=in_wave_skew,
+                constraints=view_cons, stats=stats, per_zone=in_wave_skew,
             )
         else:
             cand = filter_score_topk(
                 view, batch, key, profile,
                 chunk=chunk, k=k, constraints=view_cons, stats=stats,
                 row_offset=row_offset, pod_offset=pod_offset,
-                stratum_bits=stratum_bits, per_zone=in_wave_skew,
+                per_zone=in_wave_skew,
             )
         if remap is not None:
             cand = cand.replace(
@@ -756,7 +741,6 @@ def _schedule_batch_impl(
     backend: str = "xla",
     with_affinity: bool = True,
     src: NodeTable | None = None,
-    stratum_bits: int = 0,
     window=None,
     in_wave_skew: bool = False,
     interpod: bool = False,
@@ -774,7 +758,7 @@ def _schedule_batch_impl(
     cand = candidates(
         table, batch, key, constraints, profile, chunk=chunk, k=k,
         backend=backend, with_affinity=with_affinity, src=src,
-        window=window, stratum_bits=stratum_bits, stats=stats,
+        window=window, stats=stats,
         in_wave_skew=in_wave_skew,
     )
     return finalize_batch(
@@ -787,8 +771,7 @@ def _schedule_batch_impl(
 def _jitted_schedule(
     profile: Profile, chunk: int, k: int, with_constraints: bool,
     backend: str = "xla", with_affinity: bool = True,
-    stratum_bits: int = 0, in_wave_skew: bool = False,
-    interpod: bool = False,
+    in_wave_skew: bool = False, interpod: bool = False,
 ):
     # One jax.jit function object per static configuration.  Routing every
     # configuration through a single jitted function trips a pjit fast-path
@@ -798,13 +781,13 @@ def _jitted_schedule(
     if with_constraints:
         fn = lambda table, batch, key, constraints: _schedule_batch_impl(
             table, batch, key, constraints, profile, chunk, k, backend,
-            with_affinity=with_affinity, stratum_bits=stratum_bits,
+            with_affinity=with_affinity,
             in_wave_skew=in_wave_skew, interpod=interpod,
         )
     else:
         fn = lambda table, batch, key: _schedule_batch_impl(
             table, batch, key, None, profile, chunk, k, backend,
-            with_affinity=with_affinity, stratum_bits=stratum_bits,
+            with_affinity=with_affinity,
         )
     # schedule_batch is the unpacked replay/test surface (differential
     # suites re-run one table); the production path is schedule_batch_
@@ -823,7 +806,6 @@ def schedule_batch(
     k: int = 4,
     backend: str = "xla",
     with_affinity: bool = True,
-    stratum_bits: int = 0,
     in_wave_skew: bool = False,
 ):
     """Schedule one pod batch end-to-end on a single device.
@@ -851,7 +833,7 @@ def schedule_batch(
     )
     step = _jitted_schedule(
         profile, chunk, k, constraints is not None, backend, with_affinity,
-        stratum_bits, in_wave_skew, interpod,
+        in_wave_skew, interpod,
     )
     if constraints is None:
         table, cons, asg = step(table, batch, key)
@@ -897,8 +879,7 @@ def _jitted_schedule_packed(
     profile: Profile, chunk: int, k: int, with_constraints: bool,
     backend: str, pod_spec, table_spec, groups: frozenset,
     sample_rows: int | None, with_mask: bool = False,
-    donate: bool = False, stratum_bits: int = 0,
-    in_wave_skew: bool = False,
+    donate: bool = False, in_wave_skew: bool = False,
 ):
     from k8s1m_tpu.snapshot.pod_encoding import unpack_pod_batch
 
@@ -914,7 +895,6 @@ def _jitted_schedule_packed(
             table, batch, key, constraints, profile, chunk, k, backend,
             with_affinity=aff,
             src=None if row_mask is None else mask_rows(table, row_mask),
-            stratum_bits=stratum_bits,
             window=None if sample_rows is None else (offset, sample_rows),
             in_wave_skew=in_wave_skew, interpod=interpod,
         )
@@ -969,7 +949,6 @@ def schedule_batch_packed(
     row_mask=None,
     mesh=None,
     donate: bool = False,
-    stratum_bits: int = 0,
     in_wave_skew: bool = False,
 ):
     """schedule_batch over a PackedPodBatch: the pod features cross the
@@ -1029,7 +1008,7 @@ def schedule_batch_packed(
             mesh, profile, chunk=chunk, k=k,
             pod_spec=packed.spec, table_spec=packed.table_spec,
             groups=packed.groups, sample_rows=sample_rows, backend=backend,
-            donate=donate, stratum_bits=stratum_bits,
+            donate=donate,
         )
         offset = np.int32(sample_offset)
         if constraints is not None:
@@ -1040,7 +1019,7 @@ def schedule_batch_packed(
     step = _jitted_schedule_packed(
         profile, chunk, k, constraints is not None, backend,
         packed.spec, packed.table_spec, packed.groups, sample_rows,
-        row_mask is not None, donate, stratum_bits, in_wave_skew,
+        row_mask is not None, donate, in_wave_skew,
     )
     offset = np.int32(sample_offset)
     args = (table, packed.ints, packed.bools, key, offset)
@@ -1049,281 +1028,6 @@ def schedule_batch_packed(
     if constraints is not None:
         args += (constraints,)
     return step(*args)
-
-
-# ---- deltasched: the plane-cached wave (engine/deltacache.py) -------------
-
-
-@functools.lru_cache(maxsize=256)
-def _jitted_schedule_delta(
-    profile: Profile, chunk: int, k: int,
-    pod_spec, table_spec, groups: frozenset, n_inflight: int,
-    donate: bool = False, backend: str = "xla", stratum_bits: int = 0,
-    index_k: int = 0, index_dirty_cap: int = 0,
-):
-    """The delta-wave executable: merge the dirty slice into the cached
-    planes, hashed top-k over the merged planes, payload gather, shared
-    greedy/commit epilogue.  Byte-identical to _jitted_schedule_packed
-    for the same wave whenever the planes equal a full recompute of the
-    un-dirty rows (the deltacache invalidation contract; gated by
-    tests/test_deltasched.py).  Constraint state is deliberately not
-    threaded: delta waves carry only constraint-termless pods, whose
-    commit increments are identically zero.
-
-    ``backend="pallas"`` runs the merged-plane top-k tail through the
-    fused pallas kernel (ops/pallas_topk.delta_plane_topk) — the dirty
-    gather/scatter-merge prolog is O(dirty) and stays XLA either way.
-
-    ``index_k > 0`` threads the score-stratified candidate index
-    through the step: the dirty slice updates the per-slot index
-    in-step, a device-side ``lax.cond`` on index_usable picks between
-    the O(K·batch) index tail and the O(N·batch) plane tail (which
-    rebuilds the used slots' indexes from the merged planes), and the
-    step reports which path ran as an extra i32 flag.  A dirty vector
-    wider than ``index_dirty_cap`` skips the in-step update entirely —
-    the cutoff is a trace-time SHAPE decision, so oversized waves
-    compile the plane-only variant with no dead index code."""
-    from k8s1m_tpu.engine.deltacache import (
-        attach_payload,
-        combine_dirty,
-        dedup_rows,
-        index_topk,
-        index_usable,
-        merge_dirty_planes,
-        plane_topk,
-        rebuild_index,
-        update_index,
-    )
-    from k8s1m_tpu.snapshot.pod_encoding import unpack_pod_batch
-
-    def impl(table, ints, bools, key, slot_ids, pmask, pscore, dirty,
-             *rest):
-        if index_k:
-            rep_idx, rebuild_slots, idx_row, idx_class, idx_floor = rest[:5]
-            inflight = rest[5:]
-        else:
-            inflight = rest
-        batch = unpack_pod_batch(ints, bools, pod_spec, table_spec, groups)
-        n = pmask.shape[1]
-        with jax.named_scope("candidates"):
-            rows = combine_dirty(dirty, inflight, n)
-            pmask, pscore, mask_d, score_d = merge_dirty_planes(
-                table, batch, profile, slot_ids, pmask, pscore, rows
-            )
-            seed = seed_of(key)
-
-            def plane_tail():
-                if backend == "pallas":
-                    from k8s1m_tpu.ops.pallas_topk import delta_plane_topk
-
-                    return delta_plane_topk(
-                        pmask, pscore, slot_ids, seed, chunk=chunk, k=k,
-                        stratum_bits=stratum_bits,
-                    )
-                return plane_topk(
-                    pmask, pscore, slot_ids, seed, chunk=chunk, k=k,
-                    stratum_bits=stratum_bits,
-                )
-
-            flag = jnp.int32(0)
-            if index_k and rows.shape[0] <= index_dirty_cap:
-                rows_dd = dedup_rows(rows, n)
-                idx_row, idx_class, idx_floor = update_index(
-                    idx_row, idx_class, idx_floor, rep_idx, rows_dd,
-                    mask_d, score_d, n, stratum_bits=stratum_bits,
-                )
-                usable = index_usable(idx_class, idx_floor, slot_ids, k)
-
-                def from_index(state):
-                    ir, ic, fl = state
-                    return (
-                        index_topk(
-                            ir, ic, slot_ids, seed, k=k,
-                            stratum_bits=stratum_bits,
-                        ),
-                        ir, ic, fl,
-                    )
-
-                def from_planes(state):
-                    ir, ic, fl = state
-                    ir, ic, fl = rebuild_index(
-                        pmask, pscore, rebuild_slots, rep_idx, ir, ic, fl,
-                        chunk=chunk, stratum_bits=stratum_bits,
-                        batch_b=slot_ids.shape[0],
-                    )
-                    return plane_tail(), ir, ic, fl
-
-                cand, idx_row, idx_class, idx_floor = lax.cond(
-                    usable, from_index, from_planes,
-                    (idx_row, idx_class, idx_floor),
-                )
-                flag = usable.astype(jnp.int32)
-            elif index_k:
-                # Oversized dirty slice: plane tail, and the used slots'
-                # indexes rebuild from the merged planes (or fail closed).
-                cand = plane_tail()
-                idx_row, idx_class, idx_floor = rebuild_index(
-                    pmask, pscore, rebuild_slots, rep_idx,
-                    idx_row, idx_class, idx_floor,
-                    chunk=chunk, stratum_bits=stratum_bits,
-                    batch_b=slot_ids.shape[0],
-                )
-            else:
-                cand = plane_tail()
-            cand = attach_payload(table, cand)
-        table, _cons, asg = finalize_batch(
-            table, None, cand, commit_fields_of(batch)
-        )
-        rows_out = jnp.where(asg.bound, asg.node_row, -1).astype(jnp.int32)
-        if index_k:
-            return (table, asg, rows_out, flag, pmask, pscore,
-                    idx_row, idx_class, idx_floor)
-        return table, asg, rows_out, pmask, pscore
-
-    if donate:
-        # Production form: the table, both plane buffers AND the index
-        # buffers donate — the scatter-merge and index update rewrite
-        # HBM in place, exactly like the wave's bind commit updates the
-        # table.
-        if index_k:
-            return jax.jit(impl, donate_argnums=(0, 5, 6, 10, 11, 12))
-        return jax.jit(impl, donate_argnums=(0, 5, 6))
-    return jax.jit(impl)  # graftlint: disable=undonated-device-update (replay/differential variant; production passes donate=True)
-
-
-def schedule_batch_delta(
-    table,
-    packed,
-    key: jax.Array,
-    *,
-    profile: Profile,
-    slot_ids,
-    planes,
-    dirty,
-    inflight_rows=(),
-    chunk: int = 16384,
-    k: int = 4,
-    mesh=None,
-    donate: bool = False,
-    backend: str = "xla",
-    stratum_bits: int = 0,
-    index=None,
-    rep_idx=None,
-    rebuild_slots=None,
-    index_dirty_cap: int = 0,
-):
-    """schedule_batch_packed's delta-wave twin (deltasched): every pod's
-    feasibility/score plane is already cached, so the device step runs
-    the full kernel only over ``dirty`` ∪ the in-flight waves' bind rows
-    and re-derives candidates from the merged planes.
-
-    ``planes`` is the (mask, score) pair from the epoch-checked
-    ``DeltaPlaneCache.planes`` accessor; ``slot_ids`` maps each batch
-    position to its shape's plane slot (sentinel = slot count for
-    padding); ``dirty`` is the sentinel-padded journaled dirty-row
-    vector and ``inflight_rows`` the unretired waves' device-resident
-    ``rows_dev`` arrays — consumed on-stream, never synced to host.
-
-    ``index`` is the (idx_row, idx_class, idx_floor) triple from the
-    epoch-checked ``DeltaPlaneCache.index_state`` accessor (with
-    ``rep_idx``/``rebuild_slots`` from the WavePlan); when passed, the
-    wave derives candidates from the candidate index whenever it is
-    usable and the return grows to (new_table, Assignment, rows,
-    new_planes, new_index, path_flag) — ``path_flag`` an i32 device
-    scalar, 1 = index tail ran.  Without ``index`` the return stays
-    (new_table, Assignment, rows, new_planes).
-
-    Under ``mesh`` the planes must be sharded ``P(None, "sp")`` —
-    row-sharded like every packed plane — the dirty gather stays
-    shard-local, and the candidate index is unsupported (plane tail
-    only).  ``backend="pallas"`` fuses the plane tail on either step.
-    """
-    pmask, pscore = planes
-    if mesh is not None:
-        if index is not None:
-            raise ValueError(
-                "the candidate index does not compose with mesh sharding"
-            )
-        from k8s1m_tpu.parallel.sharded_cycle import make_sharded_delta_step
-
-        step = make_sharded_delta_step(
-            mesh, profile, chunk=chunk, k=k,
-            pod_spec=packed.spec, table_spec=packed.table_spec,
-            groups=packed.groups, n_inflight=len(inflight_rows),
-            donate=donate, backend=backend, stratum_bits=stratum_bits,
-        )
-        table, asg, rows, pmask, pscore = step(
-            table, packed.ints, packed.bools, key, slot_ids, pmask,
-            pscore, dirty, *inflight_rows,
-        )
-        return table, asg, rows, (pmask, pscore)
-    index_k = 0 if index is None else index[0].shape[1]
-    step = _jitted_schedule_delta(
-        profile, chunk, k, packed.spec, packed.table_spec,
-        packed.groups, len(inflight_rows), donate, backend, stratum_bits,
-        index_k, index_dirty_cap,
-    )
-    if index is None:
-        table, asg, rows, pmask, pscore = step(
-            table, packed.ints, packed.bools, key, slot_ids, pmask,
-            pscore, dirty, *inflight_rows,
-        )
-        return table, asg, rows, (pmask, pscore)
-    table, asg, rows, flag, pmask, pscore, ir, ic, fl = step(
-        table, packed.ints, packed.bools, key, slot_ids, pmask, pscore,
-        dirty, rep_idx, rebuild_slots, *index, *inflight_rows,
-    )
-    return table, asg, rows, (pmask, pscore), (ir, ic, fl), flag
-
-
-@functools.lru_cache(maxsize=64)
-def _jitted_plane_fill(
-    profile: Profile, chunk: int, pod_spec, table_spec, groups: frozenset
-):
-    """Plane-fill executable: one full filter+score pass for a batch of
-    shape representatives, scattered into their plane slots.  The table
-    is read-only here (fills never commit); only the plane buffers
-    donate."""
-    from k8s1m_tpu.engine.deltacache import fill_planes_scan
-    from k8s1m_tpu.snapshot.pod_encoding import unpack_pod_batch
-
-    def impl(table, ints, bools, fill_slots, pmask, pscore):
-        batch = unpack_pod_batch(ints, bools, pod_spec, table_spec, groups)
-        return fill_planes_scan(
-            table, batch, profile, fill_slots, pmask, pscore, chunk=chunk
-        )
-
-    return jax.jit(impl, donate_argnums=(4, 5))
-
-
-def fill_shape_planes(
-    table,
-    packed,
-    fill_slots,
-    planes,
-    *,
-    profile: Profile,
-    chunk: int = 16384,
-    mesh=None,
-):
-    """Populate the plane slots in ``fill_slots`` from a full pass for
-    the representative pods in ``packed`` (deltasched cold-shape /
-    refresh path).  Returns the new (mask, score) planes; the table is
-    untouched and NOT donated."""
-    pmask, pscore = planes
-    if mesh is not None:
-        from k8s1m_tpu.parallel.sharded_cycle import make_sharded_plane_fill
-
-        fill = make_sharded_plane_fill(
-            mesh, profile, chunk=chunk,
-            pod_spec=packed.spec, table_spec=packed.table_spec,
-            groups=packed.groups,
-        )
-    else:
-        fill = _jitted_plane_fill(
-            profile, chunk, packed.spec, packed.table_spec, packed.groups
-        )
-    return fill(table, packed.ints, packed.bools, fill_slots, pmask, pscore)
 
 
 # ---- which candidates kernel a wave's step is built with ------------------
@@ -1336,17 +1040,12 @@ def has_selectors(groups) -> bool:
     return bool(groups & SELECTOR_GROUPS)
 
 
-def candidates_kernel(
-    backend: str, groups, with_constraints: bool, path: str = "full"
-) -> str:
+def candidates_kernel(backend: str, groups, with_constraints: bool) -> str:
     """The name of the candidates kernel the step of a wave with these
     packed field groups is built with (``coordinator_waves_total``'s
     label): on the pallas backend the string ``pallas_call(name=)`` gets,
     on the XLA backend the scan's name with the same suffixes (its filter
-    chain skips an absent group at trace time); a ``path="delta"`` wave's
-    is the plane tail's."""
-    if path == "delta":
-        return "delta_plane_topk" if backend == "pallas" else "plane_topk"
+    chain skips an absent group at trace time)."""
     aff = has_selectors(groups)
     if backend == "pallas":
         from k8s1m_tpu.ops.pallas_topk import kernel_name

@@ -50,10 +50,6 @@ from k8s1m_tpu.lint.lockgraph import (
 )
 from k8s1m_tpu.lint.rules_blocking import BlockingUnderLock
 from k8s1m_tpu.lint.rules_clock import NoWallClock
-from k8s1m_tpu.lint.rules_deltacache import (
-    DeltaCacheEpochKeyed,
-    DeltaCacheIndexKeyed,
-)
 from k8s1m_tpu.lint.rules_donate import UndonatedDeviceUpdate
 from k8s1m_tpu.lint.rules_except import BroadExcept
 from k8s1m_tpu.lint.rules_fallback import FallbackAccounting
@@ -82,8 +78,6 @@ ALL_RULES: tuple[type[Rule], ...] = (
     MeshPurity,
     FencedStoreWrite,
     UndonatedDeviceUpdate,
-    DeltaCacheEpochKeyed,
-    DeltaCacheIndexKeyed,
     TraceLazyEmit,
     BoundedWatchBuffer,
     NondetToPlacement,

@@ -1,12 +1,11 @@
 """nondet-to-placement: no nondeterministic value reaches a placement
 decision.
 
-The byte-identity contract (mesh == single-device, packed == unpacked,
-delta/index == full recompute) holds because every placement input is a
-deterministic function of (store state, wave seed).  mesh-purity
-enforces one corner of that — axis-derived values in shard_map code —
-but every regression so far entered through a DIFFERENT corner:
-fold_mesh_key (PR 6), the stratum-width collapse (PR 18), wall-stamp
+The byte-identity contract (mesh == single-device, packed == unpacked)
+holds because every placement input is a deterministic function of
+(store state, wave seed).  mesh-purity enforces one corner of that —
+axis-derived values in shard_map code — but every regression so far
+entered through a DIFFERENT corner: a mesh-folded key, wall-stamp
 tie-breaks.  This pass is the general statement, on the flow.py
 chassis: taint from any **nondeterminism source**
 

@@ -54,11 +54,6 @@ ROWS = [
     # The dp x sp sharded execution path (parallel/): mesh axis sizes,
     # sharded dirty-row scatters by column class, per-dp-shard feed depth.
     ("Mesh (dp x sp sharded cycle)", ("mesh_",)),
-    # Incremental scheduling (engine/deltacache.py): delta vs full wave
-    # split, per-pod shape hit/miss, plane fills and LRU evictions
-    # (HBM-budget pressure), journaled dirty rows (mean dirty fraction),
-    # and planes resident across live caches.
-    ("Incremental scheduling (deltasched)", ("deltasched_",)),
     # The 1,048,576-row operating shape (ISSUE 14 megarow): cold-build
     # wall seconds (bootstrap relist -> bulk ingest -> device table),
     # bulk-ingest row rate (snapshot/bulkload + bulk_upsert) with its

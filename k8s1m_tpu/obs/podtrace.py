@@ -69,7 +69,7 @@ STAGES = (
     "queue_wait",     # pending-queue (+ retry backoff) residence
     "encode",         # host encode (hotfeed claim or inline; cache attrs)
     "dispatch_wait",  # encode end -> device dispatch (pipeline slot wait)
-    "device",         # dispatch -> result sync (wave epoch/depth/path attrs)
+    "device",         # dispatch -> result sync (wave epoch/depth attrs)
     "bind",           # bind CAS + wave settlement (outcome attr)
     "requeue",        # terminal non-bind settlement (unschedulable, deleted)
 )

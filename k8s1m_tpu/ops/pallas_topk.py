@@ -155,7 +155,6 @@ def _kernel(
     with_aff: bool,
     with_cons: bool,
     pack: tuple | None = None,
-    stratum_bits: int = 0,
     per_zone: bool = False,
 ):
     """``per_zone`` (with_cons only): slot z of the K outputs is the best
@@ -601,7 +600,7 @@ def _kernel(
         lax.broadcasted_iota(jnp.int32, (1, c), 1)
         + c_i * chunk + seed_ref[0, 2]
     )
-    jitter = _hash_jitter(seed_ref[0, 0], rows_n, cols_n, stratum_bits)
+    jitter = _hash_jitter(seed_ref[0, 0], rows_n, cols_n)
     mask = fits & nn_ok & taint_ok & (p_valid[:] != 0)
     if pack is not None:
         # Packed layout carries row validity explicitly (meta bit 0) —
@@ -711,8 +710,7 @@ def _merge_running_topk(prio, cols, k, c_i, run_prio, run_idx,
     jax.jit,
     static_argnames=(
         "chunk", "k", "w_la", "w_ba", "w_tt", "w_na", "w_ts", "w_ipa",
-        "with_aff", "with_cons", "interpret", "pack", "stratum_bits",
-        "per_zone",
+        "with_aff", "with_cons", "interpret", "pack", "per_zone",
     ),
 )
 def _call(
@@ -735,7 +733,6 @@ def _call(
     with_cons: bool,
     interpret: bool,
     pack: tuple | None = None,
-    stratum_bits: int = 0,
     per_zone: bool = False,
 ):
     n = cpu_alloc.shape[0]
@@ -850,8 +847,7 @@ def _call(
     kernel = functools.partial(
         _kernel, chunk=chunk, k=k,
         w_la=w_la, w_ba=w_ba, w_tt=w_tt, w_na=w_na, w_ts=w_ts, w_ipa=w_ipa,
-        with_aff=with_aff, with_cons=with_cons, pack=pack,
-        stratum_bits=stratum_bits, per_zone=per_zone,
+        with_aff=with_aff, with_cons=with_cons, pack=pack, per_zone=per_zone,
     )
     idx, prio = pl.pallas_call(
         kernel,
@@ -892,7 +888,6 @@ def fused_topk(
     interpret: bool | None = None,
     row_base=0,
     col_base=0,
-    stratum_bits: int = 0,
     per_zone: bool = False,
 ):
     """(idx i32[B,K], prio i32[B,K]) — global-row candidates, -1 = none.
@@ -1057,7 +1052,6 @@ def fused_topk(
         with_cons=with_cons,
         interpret=interpret,
         pack=pack,
-        stratum_bits=stratum_bits,
         per_zone=per_zone,
     )
 
@@ -1081,7 +1075,6 @@ def pallas_candidates(
     constraints=None,
     stats=None,
     interpret: bool | None = None,
-    stratum_bits: int = 0,
     per_zone: bool = False,
 ):
     """Drop-in for engine.filter_score_topk.
@@ -1099,8 +1092,7 @@ def pallas_candidates(
         table, batch, seed_of(key), profile,
         chunk=chunk, k=k, with_affinity=with_affinity,
         constraints=constraints, stats=stats, interpret=interpret,
-        row_base=pod_offset, col_base=row_offset,
-        stratum_bits=stratum_bits, per_zone=per_zone,
+        row_base=pod_offset, col_base=row_offset, per_zone=per_zone,
     )
     safe = jnp.clip(idx, 0)
     free_cpu, free_mem, free_pods = table.free()
@@ -1128,183 +1120,9 @@ def kernel_name(with_affinity: bool, with_constraints: bool) -> str:
     )
 
 
-# ---- deltasched plane tail (engine/deltacache.py) -------------------------
-
-
-def _delta_kernel(
-    seed_ref, pmask_ref, pscore_ref, slot_ref,
-    out_idx, out_prio, run_prio, run_idx,
-    *, chunk: int, k: int, stratum_bits: int,
-):
-    """Fused delta-wave plane tail: per-pod slot gather over the merged
-    feasibility/score planes -> hashed priority pack -> running top-k,
-    one chunk of plane columns per grid step.
-
-    Refs:
-        seed_ref   i32[1, 3] SMEM — (seed, pod hash base, node hash base)
-        pmask_ref  i32[S, C]  merged feasibility plane chunk (0/1)
-        pscore_ref i32[S, C]  merged score plane chunk
-        slot_ref   i32[TB, 1] per-pod slot id (sentinel = S for padding)
-        out_idx, out_prio  i32[TB, K] accumulator outputs
-        run_prio, run_idx  i32[TB, 128] VMEM scratch
-
-    The slot gather is a one-hot [TB, S] x [S, 3C] dot on the MXU (the
-    taint/label trick): scores travel the f32 dot as two 16-bit halves
-    (exact under _EXACT, recombined in int32 — exact for negatives too since
-    x == (x >> 16) * 65536 + (x & 0xFFFF) under the arithmetic shift).
-    Slot ids clip to S-1 like jnp.take's clip mode, so padding pods read
-    the same garbage row plane_topk's take reads — bit-identical
-    priorities everywhere, including the padding the epilogue discards.
-    """
-    b_i = pl.program_id(0)
-    c_i = pl.program_id(1)
-
-    @pl.when(c_i == 0)
-    def _():
-        run_prio[:] = jnp.full(run_prio.shape, -1, jnp.int32)
-        run_idx[:] = jnp.full(run_idx.shape, -1, jnp.int32)
-
-    tb = slot_ref.shape[0]
-    s, c = pmask_ref.shape
-    sl = jnp.clip(slot_ref[:], 0, s - 1)                          # [TB, 1]
-    onehot = (
-        lax.broadcasted_iota(jnp.int32, (tb, s), 1) == sl
-    ).astype(jnp.float32)
-    sc = pscore_ref[:]
-    planes = jnp.concatenate(
-        [
-            pmask_ref[:].astype(jnp.float32),
-            (sc >> 16).astype(jnp.float32),
-            (sc & 0xFFFF).astype(jnp.float32),
-        ],
-        axis=1,
-    )                                                             # [S, 3C]
-    g = jnp.dot(
-        onehot, planes, precision=_EXACT, preferred_element_type=jnp.float32
-    )
-    mask = g[:, :c] > 0.5
-    score = (
-        g[:, c : 2 * c].astype(jnp.int32) * 65536
-        + g[:, 2 * c :].astype(jnp.int32)
-    )
-
-    cols = lax.broadcasted_iota(jnp.int32, (tb, c), 1) + c_i * chunk
-    rows_n = (
-        lax.broadcasted_iota(jnp.int32, (tb, 1), 0)
-        + b_i * tb + seed_ref[0, 1]
-    )
-    cols_n = (
-        lax.broadcasted_iota(jnp.int32, (1, c), 1)
-        + c_i * chunk + seed_ref[0, 2]
-    )
-    jitter = _hash_jitter(seed_ref[0, 0], rows_n, cols_n, stratum_bits)
-    prio = jnp.where(
-        mask,
-        (jnp.clip(score, 0, MAX_SCORE) << JITTER_BITS) | jitter,
-        -1,
-    )
-    _merge_running_topk(
-        prio, cols, k, c_i, run_prio, run_idx, out_prio, out_idx
-    )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("chunk", "k", "stratum_bits", "interpret")
-)
-def _delta_call(
-    seed, pmask_i, pscore, slot2d,
-    *, chunk: int, k: int, stratum_bits: int, interpret: bool,
-):
-    s, n = pmask_i.shape
-    b = slot2d.shape[0]
-    tb = b if (b <= 256 or b % 256) else 256
-    grid = (b // tb, n // chunk)
-    plane = pl.BlockSpec(
-        (s, chunk), lambda bi, ci: (0, ci), memory_space=pltpu.VMEM
-    )
-    pod = pl.BlockSpec(
-        (tb, 1), lambda bi, ci: (bi, 0), memory_space=pltpu.VMEM
-    )
-    out = pl.BlockSpec(
-        (tb, k), lambda bi, ci: (bi, 0), memory_space=pltpu.VMEM
-    )
-    kernel = functools.partial(
-        _delta_kernel, chunk=chunk, k=k, stratum_bits=stratum_bits
-    )
-    idx, prio = pl.pallas_call(
-        kernel,
-        name="delta_plane_topk",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, 3), lambda bi, ci: (0, 0), memory_space=pltpu.SMEM
-            ),
-            plane, plane, pod,
-        ],
-        out_specs=(out, out),
-        out_shape=(
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((tb, 128), jnp.int32),
-            pltpu.VMEM((tb, 128), jnp.int32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024,
-        ),
-        interpret=interpret,
-    )(seed.reshape(1, 3), pmask_i, pscore, slot2d)
-    return idx, prio
-
-
-def delta_plane_topk(
-    pmask, pscore, slot_ids, seed,
-    *, chunk: int, k: int, stratum_bits: int = 0,
-    row_offset=0, pod_offset=0, interpret: bool | None = None,
-):
-    """Drop-in for engine.deltacache.plane_topk on the pallas backend:
-    the fused merged-plane top-k tail of a delta wave.  Same contract —
-    per-pod hashed top-k over the cached planes at each pod's slot,
-    payload columns zeroed for ``attach_payload`` — and bit-identical
-    candidates (same pack_hashed jitter over global coordinates via the
-    SMEM (seed, pod_base, col_base) discipline, same earlier-row-wins
-    merge as fused_topk).  The O(dirty) gather/scatter-merge prolog
-    stays on XLA in the caller; this kernel is the O(batch x N) tail.
-    """
-    from k8s1m_tpu.engine.cycle import Candidates
-
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    n = pmask.shape[1]
-    if n % chunk:
-        raise ValueError(f"plane rows {n} not divisible by chunk {chunk}")
-    b = slot_ids.shape[0]
-    seedv = jnp.stack([
-        jnp.asarray(seed, jnp.int32),
-        jnp.asarray(pod_offset, jnp.int32),
-        jnp.asarray(row_offset, jnp.int32),
-    ])
-    idx, prio = _delta_call(
-        seedv,
-        pmask.astype(jnp.int32),
-        pscore,
-        slot_ids.reshape(b, 1).astype(jnp.int32),
-        chunk=chunk, k=k, stratum_bits=stratum_bits,
-        interpret=bool(interpret),
-    )
-    zeros = jnp.zeros((b, k), jnp.int32)
-    return Candidates(
-        idx=jnp.where(prio >= 0, idx + row_offset, -1),
-        prio=prio,
-        cpu=zeros, mem=zeros, pods=zeros, zone=zeros, region=zeros,
-    )
-
-
 def np_reference_topk(
     table, batch, seed: int, profile: Profile, k: int,
     with_affinity: bool = True,
-    stratum_bits: int = 0,
 ):
     """Pure-numpy oracle of the kernel (for differential tests): same
     filters, scores, hash jitter, and first-position tie rule."""
@@ -1432,15 +1250,6 @@ def np_reference_topk(
         s32 ^ (np.arange(n, dtype=np.uint32)[None, :] * np.uint32(0x85EBCA6B))
     )
     jitter = ((rh ^ ch) & np.uint32((1 << JITTER_BITS) - 1)).astype(np.int64)
-    if stratum_bits:
-        # ops/priority.stratum_hash: seed/pod-independent top bits.
-        sh = mix32(
-            np.arange(n, dtype=np.uint32) * np.uint32(0xC2B2AE35)
-        ) >> np.uint32(32 - stratum_bits)
-        low = JITTER_BITS - stratum_bits
-        jitter = (sh.astype(np.int64)[None, :] << low) | (
-            jitter & ((1 << low) - 1)
-        )
 
     mask = fits & nn_ok & (hard_cnt == 0) & pv
     if with_affinity:

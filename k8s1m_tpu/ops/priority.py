@@ -50,27 +50,7 @@ def mix32(h):
     return h
 
 
-def stratum_hash(col_ids, bits: int):
-    """The top ``bits`` tie-break bits as a pure function of the GLOBAL
-    node column — independent of both the wave seed and the pod row.
-
-    This is what makes a score-stratified candidate index possible at
-    all: a per-shape index must rank rows by a key that is stable
-    across waves, but the full jitter draw changes with (seed, pod), so
-    no strict (score, jitter) index survives one wave.  Carving the top
-    ``bits`` of the jitter field out of a fixed per-column hash splits
-    each integer score level into 2^bits strata whose ORDER is
-    wave-invariant, while the remaining low bits stay per-(seed, pod)
-    uniform — uniform tie-breaking within a stratum, deterministic
-    stratum order across waves.  A third mixing constant keeps the
-    stream independent of both hash_jitter axes."""
-    if not 0 < bits <= JITTER_BITS:
-        raise ValueError(f"stratum bits must be in [1, {JITTER_BITS}], got {bits}")
-    h = mix32(col_ids.astype(jnp.uint32) * jnp.uint32(0xC2B2AE35))
-    return (h >> jnp.uint32(32 - bits)).astype(jnp.int32)
-
-
-def hash_jitter(seed, row_ids, col_ids, stratum_bits: int = 0):
+def hash_jitter(seed, row_ids, col_ids):
     """Stateless uniform bits in [0, 2^JITTER_BITS) per (pod, node).
 
     Separable construction shared by BOTH backends (the fused pallas
@@ -79,13 +59,7 @@ def hash_jitter(seed, row_ids, col_ids, stratum_bits: int = 0):
     cols) and the full-width work is ONE xor + one mask.  Integer ops
     reproduce bit-for-bit everywhere, which is what the cross-backend
     tie-break parity rests on.  See ops/pallas_topk.py for the
-    correlated-tie trade-off note.
-
-    ``stratum_bits`` > 0 replaces the TOP bits of the draw with the
-    seed/pod-independent ``stratum_hash`` of the node column (the
-    candidate-index key contract, engine/deltacache.py); 0 — the
-    default everywhere outside an index-enabled coordinator — is
-    bit-identical to the historical draw."""
+    correlated-tie trade-off note."""
     rh = mix32(
         seed.astype(jnp.uint32)
         ^ (row_ids.astype(jnp.uint32) * jnp.uint32(0x9E3779B9))
@@ -94,11 +68,7 @@ def hash_jitter(seed, row_ids, col_ids, stratum_bits: int = 0):
         seed.astype(jnp.uint32)
         ^ (col_ids.astype(jnp.uint32) * jnp.uint32(0x85EBCA6B))
     )
-    j = ((rh ^ ch) & jnp.uint32((1 << JITTER_BITS) - 1)).astype(jnp.int32)
-    if stratum_bits == 0:
-        return j
-    low = JITTER_BITS - stratum_bits
-    return (stratum_hash(col_ids, stratum_bits) << low) | (j & ((1 << low) - 1))
+    return ((rh ^ ch) & jnp.uint32((1 << JITTER_BITS) - 1)).astype(jnp.int32)
 
 
 def seed_of(key: jax.Array) -> jax.Array:
@@ -110,36 +80,13 @@ def seed_of(key: jax.Array) -> jax.Array:
 def pack_hashed(
     score_int: jax.Array, seed: jax.Array, mask: jax.Array,
     row_ids: jax.Array, col_ids: jax.Array,
-    stratum_bits: int = 0,
 ) -> jax.Array:
     """``pack`` with the separable hash jitter: priorities are a pure
     function of (seed, pod row, node column), so the XLA scan path and
     the pallas kernel produce IDENTICAL tie-breaks for the same wave."""
     s = jnp.clip(score_int, 0, MAX_SCORE)
-    prio = (s << JITTER_BITS) | hash_jitter(seed, row_ids, col_ids, stratum_bits)
+    prio = (s << JITTER_BITS) | hash_jitter(seed, row_ids, col_ids)
     return jnp.where(mask, prio, INFEASIBLE)
-
-
-def class_key(score_int: jax.Array, col_ids: jax.Array, stratum_bits: int):
-    """The candidate-index stratum class of a (score, node column) pair:
-    the top ``11 + stratum_bits`` bits of the packed priority — exactly
-    the part of the priority that does NOT depend on (seed, pod row).
-
-    The algebra the index rests on: with ``low = JITTER_BITS −
-    stratum_bits`` every feasible priority decomposes as
-
-        prio == (class_key << low) | (per-pod jitter & (2^low − 1))
-
-    so ``class_key(a) > class_key(b)`` implies ``prio(a) > prio(b)``
-    for EVERY wave seed and EVERY pod row — a strictly-greater class
-    dominates regardless of the per-wave low bits.  That is the whole
-    fail-closed story of engine/deltacache.py's index: entries strictly
-    above the eviction floor beat every unindexed row, and nothing
-    about a wave can reorder them across the floor boundary."""
-    s = jnp.clip(score_int, 0, MAX_SCORE)
-    if stratum_bits == 0:
-        return s
-    return (s << stratum_bits) | stratum_hash(col_ids, stratum_bits)
 
 
 def unpack_score(prio: jax.Array) -> jax.Array:

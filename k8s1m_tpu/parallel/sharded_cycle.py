@@ -210,7 +210,6 @@ def make_sharded_packed_step(
     sample_rows: int | None = None,
     backend: str = "xla",
     donate: bool = False,
-    stratum_bits: int = 0,
 ):
     """The mesh analogue of engine.cycle._jitted_schedule_packed: the
     coordinator's production step — packed two-buffer pod upload,
@@ -301,7 +300,6 @@ def make_sharded_packed_step(
             backend=backend, with_affinity=aff,
             window=None if sample_rows is None else (offset, sample_rows),
             row_offset=row_offset, pod_offset=pod_offset, axis_name="sp",
-            stratum_bits=stratum_bits,
         )
 
         table, cons, asg = gather_and_finalize(
@@ -351,179 +349,3 @@ def make_sharded_packed_step(
         return step_plain(table, ints, bools, key, offset)
 
     return step
-
-
-# ---- deltasched: the sharded plane-cached wave (engine/deltacache.py) -----
-
-# The cached feasibility/score planes shard over ``sp`` on the row axis
-# — exactly like every packed table plane — and replicate over ``dp``
-# (each dp rank merges the dirty slice for the FULL batch, so the
-# replicated copies stay bit-identical by construction).
-PLANE_SPEC = P(None, "sp")
-
-
-@functools.lru_cache(maxsize=64)
-def make_sharded_delta_step(
-    mesh,
-    profile: Profile,
-    *,
-    chunk: int,
-    k: int,
-    pod_spec,
-    table_spec,
-    groups: frozenset,
-    n_inflight: int,
-    donate: bool = False,
-    backend: str = "xla",
-    stratum_bits: int = 0,
-):
-    """The mesh twin of engine.cycle._jitted_schedule_delta: per-shard
-    hashed top-k over the shard-local plane slices, shard-local dirty
-    gather and scatter-merge, then the ordinary sp/dp gather epilogue.
-
-    Byte-identity composes: the planes hold the same mask/score values
-    a full recompute would produce per (shape, global row), the top-k
-    jitter hashes over global coordinates (mesh_offsets), and
-    gather_and_finalize is the SAME epilogue the full sharded step runs
-    — so the mesh delta wave is bind-for-bind identical to the
-    single-device delta wave, which is identical to full recompute.
-
-    The dirty-slice recompute runs for the FULL batch on every dp rank
-    (the slice is tiny; dp-replicating it is what keeps the dp-
-    replicated plane copies bit-identical without a cross-dp merge).
-    Constraint state is not threaded — delta waves carry only
-    constraint-termless pods (engine/deltacache.py module doc).
-    """
-    from k8s1m_tpu.engine.deltacache import (
-        attach_payload,
-        combine_dirty,
-        merge_dirty_planes,
-        plane_topk,
-    )
-    from k8s1m_tpu.ops.priority import seed_of
-    from k8s1m_tpu.snapshot.pod_encoding import unpack_pod_batch
-
-    dp_size, sp_size = mesh.shape["dp"], mesh.shape["sp"]
-    b_full = pod_spec.batch
-    if b_full % dp_size:
-        raise ValueError(f"batch {b_full} not divisible by dp={dp_size}")
-    b_local = b_full // dp_size
-
-    def _local_step(table, ints, bools, key, slot_ids, pmask, pscore,
-                    dirty, *inflight):
-        pod_offset, row_offset = mesh_offsets(table, b_local)
-        dp = lax.axis_index("dp")
-
-        full = unpack_pod_batch(ints, bools, pod_spec, table_spec, groups)
-
-        def slice_dp(x):
-            if not (x.ndim >= 1 and x.shape[0] == b_full):
-                return x
-            if isinstance(x, np.ndarray) and not x.any():
-                # Same constant-preserving rule as the packed step: an
-                # absent group's zeros stay statically visible.
-                return np.zeros((b_local,) + x.shape[1:], x.dtype)
-            return lax.dynamic_slice_in_dim(x, dp * b_local, b_local, 0)
-
-        batch = jax.tree.map(slice_dp, full).replace(qkey=full.qkey)
-
-        n_local = pmask.shape[1]
-        n_global = n_local * sp_size
-        # Global dirty rows -> shard-local coordinates; rows outside
-        # this shard's range (and the sentinel padding / unbound -1
-        # markers) land on the local out-of-bounds sentinel and the
-        # scatter-merge drops them: the dirty gather stays shard-local.
-        rows = combine_dirty(dirty, inflight, n_global)
-        local = rows - row_offset
-        local = jnp.where((local >= 0) & (local < n_local), local, n_local)
-        pmask, pscore, _, _ = merge_dirty_planes(
-            table, full, profile, slot_ids, pmask, pscore, local
-        )
-
-        slot_local = lax.dynamic_slice_in_dim(
-            slot_ids, dp * b_local, b_local, 0
-        )
-        if backend == "pallas":
-            from k8s1m_tpu.ops.pallas_topk import delta_plane_topk
-
-            cand = delta_plane_topk(
-                pmask, pscore, slot_local, seed_of(key), chunk=chunk, k=k,
-                row_offset=row_offset, pod_offset=pod_offset,
-                stratum_bits=stratum_bits,
-            )
-        else:
-            cand = plane_topk(
-                pmask, pscore, slot_local, seed_of(key), chunk=chunk, k=k,
-                row_offset=row_offset, pod_offset=pod_offset,
-                stratum_bits=stratum_bits,
-            )
-        cand = attach_payload(table, cand, row_offset=row_offset)
-        table, _cons, asg = gather_and_finalize(
-            table, batch, cand, None, k=k
-        )
-        rows_out = jnp.where(asg.bound, asg.node_row, -1).astype(jnp.int32)
-        return table, asg, rows_out, pmask, pscore
-
-    def _step(table, ints, bools, key, slot_ids, pmask, pscore, dirty,
-              *inflight):
-        fn = jax.shard_map(
-            _local_step,
-            mesh=mesh,
-            in_specs=(
-                table_specs(table), P(), P(), P(), P(),
-                PLANE_SPEC, PLANE_SPEC, P(),
-            ) + (P(),) * n_inflight,
-            out_specs=(
-                table_specs(table), _ASG_SPECS, P(),
-                PLANE_SPEC, PLANE_SPEC,
-            ),
-            check_vma=False,
-        )
-        return fn(table, ints, bools, key, slot_ids, pmask, pscore,
-                  dirty, *inflight)
-
-    if donate:
-        # Production form: table and plane buffers donate; pinned
-        # out_specs + donation compose shard-by-shard like the packed
-        # step's.
-        return jax.jit(_step, donate_argnums=(0, 5, 6))
-    return jax.jit(_step)  # graftlint: disable=undonated-device-update (replay/differential variant; production passes donate=True)
-
-
-@functools.lru_cache(maxsize=64)
-def make_sharded_plane_fill(
-    mesh,
-    profile: Profile,
-    *,
-    chunk: int,
-    pod_spec,
-    table_spec,
-    groups: frozenset,
-):
-    """The mesh twin of engine.cycle._jitted_plane_fill: the shape
-    representatives replicate to every device and each sp shard fills
-    its local plane slice from its own table rows — no cross-shard
-    traffic at all (the fill is a pure per-row map).  The table is
-    read-only; only the plane buffers donate."""
-    from k8s1m_tpu.engine.deltacache import fill_planes_scan
-    from k8s1m_tpu.snapshot.pod_encoding import unpack_pod_batch
-
-    def _local_fill(table, ints, bools, fill_slots, pmask, pscore):
-        batch = unpack_pod_batch(ints, bools, pod_spec, table_spec, groups)
-        return fill_planes_scan(
-            table, batch, profile, fill_slots, pmask, pscore, chunk=chunk
-        )
-
-    def _fill(table, ints, bools, fill_slots, pmask, pscore):
-        fn = jax.shard_map(
-            _local_fill,
-            mesh=mesh,
-            in_specs=(
-                table_specs(table), P(), P(), P(), PLANE_SPEC, PLANE_SPEC
-            ),
-            out_specs=(PLANE_SPEC, PLANE_SPEC),
-            check_vma=False,
-        )
-        return fn(table, ints, bools, fill_slots, pmask, pscore)
-
-    return jax.jit(_fill, donate_argnums=(4, 5))

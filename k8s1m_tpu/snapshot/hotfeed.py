@@ -225,33 +225,6 @@ def fingerprint(pod: PodInfo):
     return tuple(parts)
 
 
-def shape_key(pod: PodInfo):
-    """Delta-plane cache key of a pod's filter+score plane, or None when
-    the pod's plane is not cacheable (engine/deltacache.py).
-
-    The plane a pod computes over the node table is a pure function of
-    its structural ``fingerprint`` *plus* its request scalars — Fit and
-    the allocation scores read cpu/mem — so the key extends the encode
-    cache's fingerprint with exactly those.  Not cacheable (None):
-
-    - constraint-coupled pods (spread/affinity refs or incs): their
-      mask/score reads the live count tables, which move with every
-      constraintful bind ANYWHERE in a domain — row-level dirty
-      tracking cannot bound that;
-    - ``spec.nodeName`` pods: the baked ``node_name_id`` lookup can
-      resolve differently after the name interns (queued pods never
-      carry one — the coordinator settles them as bound — so this is a
-      guard, not a hot case).
-    """
-    if (
-        pod.spread_refs or pod.affinity_refs
-        or pod.spread_incs or pod.ipa_incs
-        or pod.node_name is not None
-    ):
-        return None
-    return (fingerprint(pod), pod.cpu_milli, pod.mem_kib)
-
-
 @dataclasses.dataclass
 class _Template:
     """One shape's encoded rows.  ``direct`` rows broadcast verbatim;

@@ -673,76 +673,6 @@ class NodeTableHost:
         )
 
 
-class RowVersions:
-    """Monotone per-row mutation journal: the dirty bookkeeping that
-    feeds the delta-plane cache's invalidation (engine/deltacache.py).
-
-    Every batch of device-table row mutations — dirty-row scatters,
-    retired bind commits, eviction repairs — is noted here with one
-    version stamp; a consumer holding per-row derived state (a cached
-    feasibility/score plane) records the version it was computed at and
-    asks ``rows_since(v)`` for exactly the rows that moved afterwards.
-    The journal is bounded: when it outgrows ``cap`` the oldest entries
-    compact away and ``floor`` rises — a consumer whose recorded
-    version sits below ``floor`` can no longer enumerate its delta and
-    must treat its state as wholly stale (recompute, don't guess).
-    That is the fail-closed direction: compaction can only ever force
-    extra recompute, never hide a moved row.
-    """
-
-    def __init__(self, cap: int = 1 << 16) -> None:
-        self.cap = cap
-        self.ver = 0
-        # Versions below this are compacted out of the journal: a
-        # consumer stamped older than floor cannot enumerate its delta.
-        self.floor = 0
-        self._journal: collections.deque[tuple[int, int]] = (
-            collections.deque()
-        )
-
-    def note(self, rows) -> int:
-        """Stamp one mutation batch; returns the new version."""
-        self.ver += 1
-        v = self.ver
-        self._journal.extend((v, int(r)) for r in rows)
-        if len(self._journal) > self.cap:
-            self.compact(keep=self.cap // 2)
-        return v
-
-    def compact(self, keep: int) -> None:
-        """Drop the oldest entries down to ``keep``, raising ``floor``
-        to the newest dropped version (consumers below it go stale)."""
-        q = self._journal
-        while len(q) > keep:
-            v, _ = q.popleft()
-            self.floor = max(self.floor, v)
-
-    def release(self, before_ver: int) -> None:
-        """Drop entries at versions < ``before_ver`` WITHOUT staling
-        consumers at or past it (the caller proved every live consumer
-        is stamped >= before_ver)."""
-        q = self._journal
-        while q and q[0][0] < before_ver:
-            q.popleft()
-        self.floor = max(self.floor, before_ver - 1)
-
-    def rows_since(self, ver: int) -> set | None:
-        """Rows mutated at versions > ``ver``; None when ``ver`` is
-        below the compaction floor (the delta is unenumerable — treat
-        everything as dirty)."""
-        if ver < self.floor:
-            return None
-        out: set[int] = set()
-        for v, r in reversed(self._journal):
-            if v <= ver:
-                break
-            out.add(r)
-        return out
-
-    def __len__(self) -> int:
-        return len(self._journal)
-
-
 # ---- jit-side mutation ----------------------------------------------------
 
 

@@ -20,19 +20,12 @@ headline shape and lands the number:
    identical (gated); the speedup is reported, not gated (the cold
    build is timed on the chip's host, ``bootstrap_ingest_s``).  The
    bulk lane runs FIRST so process warm-up favors the baseline.
-4. **Composed byte-identity differential** — the deltacache+index
-   lane vs the full-recompute lane over identical stores and
-   submission sequences at ``--differential-nodes`` rows: every bind
-   must land byte-identically, and the index lane must actually have
-   taken index waves (gated).
-5. **Sustained window** — the composed steady-drill shape at full
+4. **Sustained window** — the composed steady-drill shape at full
    scale: tenant-aware weighted-fair submission, capacity-only node
    churn scattering mid-flight, a forced bind-CAS conflict cadence,
    an overload phase that must walk to SHEDDING and recover, depth-3
-   pipelining, deltacache + the score-stratified candidate index on
-   (full-scan waves, so all-hit waves ride the O(dirty + K*batch)
-   index path instead of the O(batch x N) plane scan), packed
-   layout.  Gates: zero admitted pods lost, zero structural/resync
+   pipelining, full-scan waves (``--score-pct`` samples a window
+   instead), packed layout.  Gates: zero admitted pods lost, zero structural/resync
    quiesces, SHEDDING seen + HEALTHY recovered, median in-flight
    depth at the configured depth, zero retry give-ups, zero packed
    fallbacks.
@@ -105,43 +98,10 @@ def parse_args(argv=None):
     ap.add_argument("--rss-budget-mib", type=int, default=0,
                     help="gate peak host RSS at this budget "
                     "(0 = report only; the tier-1 smoke sets it)")
-    ap.add_argument("--deltacache", choices=("off", "on"), default="on")
     ap.add_argument(
         "--score-pct", type=int, default=100,
-        help="scored-window fraction.  100 (the default since the "
-        "candidate index landed) keeps waves on the full-scan shape "
-        "the delta cache requires — sampled windows compute different "
-        "planes than the cache holds, so any score_pct < 100 disables "
-        "the delta/index path entirely (the pre-index drill ran 50)",
-    )
-    ap.add_argument(
-        "--delta-index-k", type=int, default=64,
-        help="per-resident-plane top-K candidate index: all-hit waves "
-        "derive candidates from the index + dirty set and skip the "
-        "O(N) plane scan (0 disables; requires --deltacache on).  64 "
-        "spans ~two default-width strata, so the eviction floor cuts "
-        "BELOW the whole top class instead of through it",
-    )
-    ap.add_argument(
-        "--stratum-bits", type=int, default=None,
-        help="high jitter bits drawn from a wave-invariant per-column "
-        "hash stratum: KWOK nodes are homogeneous, so ~every row ties "
-        "at one score and an unstratified index floor fails closed "
-        "every wave.  Default derives from the shape — "
-        "log2(nodes) - 5, i.e. ~32 tied rows per (score, stratum) "
-        "class (see stratum_bits_for).  Too coarse and the K-deep "
-        "floor cannot cut inside the top class (permanent underflow); "
-        "too FINE and the class order becomes a near-total "
-        "wave-invariant ranking shared by every pod — each wave then "
-        "converges on the same few rows, the per-row pod cap starves "
-        "it, and retried pods march to give-up (0 pins the historical "
-        "seeded jitter bit-for-bit)",
-    )
-    ap.add_argument(
-        "--differential-nodes", type=int, default=131072,
-        help="composed byte-identity differential shape: the "
-        "deltacache+index lane vs full recompute over identical "
-        "stores/submissions, every bind compared (0 skips the lane)",
+        help="scored-window fraction (percentageOfNodesToScore); 100 "
+        "scans every row a wave",
     )
     ap.add_argument("--packing", choices=("off", "packed"),
                     default="packed")
@@ -160,37 +120,11 @@ def parse_args(argv=None):
         args.churn_per_tick = 128
         args.bulk = 4096
         args.sat_ticks = 16
-        args.differential_nodes = min(args.differential_nodes, 32768)
         if args.rss_budget_mib == 0:
             args.rss_budget_mib = 4096
     if args.nodes % args.chunk:
         ap.error(f"--nodes {args.nodes} not divisible by --chunk {args.chunk}")
-    if args.differential_nodes % args.chunk:
-        ap.error(
-            f"--differential-nodes {args.differential_nodes} not "
-            f"divisible by --chunk {args.chunk}"
-        )
-    if args.delta_index_k and args.deltacache != "on":
-        ap.error("--delta-index-k requires --deltacache on")
-    args.stratum_auto = args.stratum_bits is None
-    if args.stratum_auto:
-        args.stratum_bits = stratum_bits_for(args.nodes)
     return args
-
-
-def stratum_bits_for(nodes: int) -> int:
-    """Stratum width targeting ~2^5 tied rows per (score, stratum)
-    class: log2(nodes) - 5, clamped to [1, 18].
-
-    The class width is the placement-diversity budget.  Per-pod jitter
-    only varies WITHIN a class (the stratum occupies the high tie-break
-    bits so the index floor argument holds), so a wave of B pods
-    spreads over roughly one class worth of rows; at ~32 rows x the
-    110-pod row cap that is ~3,500 pods of headroom per wave against a
-    512-pod batch and depth-3 pipelining.  Widths that leave <= a few
-    rows per class collapse every wave onto the same near-full rows —
-    the give-up march the zero-lost gate exists to catch."""
-    return max(1, min(18, max(nodes, 2).bit_length() - 1 - 5))
 
 
 def _node_bytes(i: int, gen: int) -> bytes:
@@ -309,127 +243,6 @@ def cold_build_compare(n: int, packing: str) -> dict:
     }
 
 
-def index_differential(n: int, args) -> dict | None:
-    """Phase 4: composed byte-identity differential — the
-    deltacache+index lane vs the full-recompute lane over identical
-    stores and submission sequences.  Both lanes run the SAME
-    stratum_bits (stratified jitter changes tie-breaks, so the
-    differential isolates the index, not the algebra); every bound
-    pod's stored bytes must match exactly, and the index lane must
-    have taken at least one index wave or the comparison is vacuous.
-
-    Both lanes run a ZERO-DELAY retry policy.  The default policy
-    parks a CAS-rolled-back pod behind ``perf_counter() + ~10-20ms``
-    of jittered backoff, so whether it rejoins the wave after next or
-    the one after depends on how the inter-step wall time raced the
-    delay — batch composition (and with it every later tie-break)
-    becomes a function of host speed.  Pinning the delay to zero makes
-    requeued pods eligible at the very next take, and with it this
-    lane has NO wall-clock input left to placement: pod/node intake is
-    poll-synchronous (MemStore watch queues drain at step start, no
-    pump thread), and no breaker, loadshed controller or adaptive
-    bucket is configured here — the only other paths that branch on
-    elapsed time.  Validated by running each lane twice at the full
-    131,072-row shape and comparing every stored pod byte-for-byte:
-    identical run to run, and identical across lanes.  A failure here
-    is therefore a REAL index bug, never timing — do not reach for a
-    backoff explanation before reproducing the divergence with this
-    function standalone."""
-    if not n:
-        return None
-    from k8s1m_tpu.config import PodSpec, TableSpec
-    from k8s1m_tpu.control.coordinator import Coordinator
-    from k8s1m_tpu.control.objects import encode_pod, node_key, pod_key
-    from k8s1m_tpu.faultline.policy import RetryPolicy
-    from k8s1m_tpu.obs.metrics import REGISTRY
-    from k8s1m_tpu.plugins.registry import Profile
-    from k8s1m_tpu.snapshot.pod_encoding import PodInfo
-    from k8s1m_tpu.store.native import MemStore
-    from k8s1m_tpu.tools.make_nodes import build_node
-
-    b = args.batch
-    waves = 6
-    # The differential runs at its own (smaller) shape: a stratum width
-    # tuned for the main lane's node count would leave <1 row per class
-    # here — re-derive unless the caller pinned --stratum-bits.
-    stratum = (
-        stratum_bits_for(n) if getattr(args, "stratum_auto", False)
-        else args.stratum_bits
-    )
-    no_backoff = RetryPolicy(
-        component="coordinator.bind", base_delay_s=0.0, max_delay_s=0.0,
-        jitter=0.0,
-    )
-
-    def drive(index_on: bool) -> dict[str, bytes | None]:
-        store = MemStore()
-        batch: list = []
-        for i in range(n):
-            batch.append((node_key(build_node(i).name), _node_bytes(i, -1)))
-            if len(batch) >= args.bulk:
-                store.put_batch(batch)
-                batch = []
-        if batch:
-            store.put_batch(batch)
-        coord = Coordinator(
-            store,
-            TableSpec(max_nodes=n, max_zones=16, max_regions=8),
-            PodSpec(batch=b),
-            Profile(topology_spread=0, interpod_affinity=0),
-            chunk=min(args.chunk, n), k=4, with_constraints=False,
-            seed=args.seed, score_pct=100, pipeline=True,
-            depth=args.depth, mesh="none", packing=args.packing,
-            deltacache="on" if index_on else "off",
-            delta_index_k=args.delta_index_k if index_on else 0,
-            stratum_bits=stratum,
-            retry_policy=no_backoff,
-        )
-        try:
-            coord.bootstrap()
-            seq = 0
-            churned = 0
-            for _ in range(waves):
-                for _ in range(b):
-                    seq += 1
-                    pod = PodInfo(f"d{seq:06d}", namespace="diff",
-                                  cpu_milli=10, mem_kib=1 << 10)
-                    store.put(pod_key("diff", pod.name), encode_pod(pod))
-                # Capacity-only churn, identical rows in both lanes.
-                for _ in range(64):
-                    i = churned % n
-                    store.put(
-                        node_key(build_node(i).name),
-                        _node_bytes(i, churned),
-                    )
-                    churned += 1
-                coord.step()
-            coord.run_until_idle()
-            binds: dict[str, bytes | None] = {}
-            for s in range(1, seq + 1):
-                kv = store.get(pod_key("diff", f"d{s:06d}"))
-                binds[f"d{s:06d}"] = kv.value if kv else None
-            return binds
-        finally:
-            coord.close()
-            store.close()
-
-    iw = REGISTRY.get("deltasched_index_waves_total")
-    iw0 = iw.value(path="index")
-    with_index = drive(True)
-    index_waves = int(iw.value(path="index") - iw0)
-    full = drive(False)
-    bound = sum(1 for v in full.values() if v and b'"nodeName"' in v)
-    return {
-        "nodes": n,
-        "waves": waves,
-        "pods": len(full),
-        "bound": bound,
-        "stratum_bits": stratum,
-        "index_waves": index_waves,
-        "byte_identical": bool(with_index == full),
-    }
-
-
 def run(args) -> dict:
     from k8s1m_tpu import faultline
     from k8s1m_tpu.cluster.workload import zipf_weights
@@ -491,20 +304,6 @@ def run(args) -> dict:
         cold_build_compare(args.compare_nodes, args.packing)
         if args.compare_nodes else None
     )
-    differential = (
-        index_differential(args.differential_nodes, args)
-        if args.delta_index_k else None
-    )
-
-    # Index baselines AFTER the differential lane (which takes its own
-    # index waves) so the window accounting below is the window's own.
-    idx_waves = REGISTRY.get("deltasched_index_waves_total")
-    idx_drops = REGISTRY.get("deltasched_index_drops_total")
-    iw0 = {p: idx_waves.value(path=p) for p in ("index", "plane")}
-    _DROP_REASONS = ("underflow", "oversized-dirty", "fill",
-                     "generation", "resync", "packing",
-                     "fill-error", "dispatch-error")
-    id0 = {r: idx_drops.value(reason=r) for r in _DROP_REASONS}
 
     store = MemStore()
     ingest = register_nodes(store, args.nodes, args.bulk)
@@ -516,8 +315,6 @@ def run(args) -> dict:
         chunk=args.chunk, k=4, with_constraints=False, seed=args.seed,
         score_pct=args.score_pct, pipeline=True, depth=args.depth,
         tenancy=tn, mesh="none", packing=args.packing,
-        deltacache=args.deltacache, delta_index_k=args.delta_index_k,
-        stratum_bits=args.stratum_bits,
     )
 
     seq = 0
@@ -637,7 +434,6 @@ def run(args) -> dict:
             if kv is None or b'"nodeName"' not in kv.value:
                 lost += 1
         host_mirror_bytes = int(coord.host.mirror_nbytes())
-        delta_on = coord.delta_enabled
     finally:
         install_plan(None)
         coord.close()
@@ -659,18 +455,7 @@ def run(args) -> dict:
         "nodes": args.nodes,
         "weights": weights,
         "packing": args.packing,
-        "deltacache": "on" if delta_on else "off",
         "score_pct": args.score_pct,
-        "delta_index_k": args.delta_index_k,
-        "stratum_bits": args.stratum_bits,
-        "index_waves": {
-            p: int(idx_waves.value(path=p) - iw0[p]) for p in iw0
-        },
-        "index_drops": {
-            r: int(idx_drops.value(reason=r) - id0[r])
-            for r in id0 if idx_drops.value(reason=r) - id0[r]
-        },
-        "index_differential": differential,
         "bulk_ingest": ingest,
         "cold_build_seconds": round(cold_build_s, 3),
         "cold_build_metric_seconds": round(cold_gauge.value(), 3),
@@ -706,14 +491,6 @@ def run(args) -> dict:
             and give_ups == 0
             and (args.packing != "packed" or packing_fallbacks == 0)
             and (compare is None or compare["byte_identical"])
-            and (
-                differential is None
-                or (
-                    differential["byte_identical"]
-                    and differential["index_waves"] > 0
-                    and differential["bound"] > 0
-                )
-            )
             and (not args.rss_budget_mib or rss <= args.rss_budget_mib)
         ),
     }
@@ -735,10 +512,7 @@ def main(argv=None) -> dict:
             "chunk": args.chunk, "depth": args.depth,
             "tenants": args.tenants, "factor": args.factor,
             "churn_per_tick": args.churn_per_tick,
-            "packing": args.packing, "deltacache": args.deltacache,
-            "score_pct": args.score_pct,
-            "delta_index_k": args.delta_index_k,
-            "stratum_bits": args.stratum_bits,
+            "packing": args.packing, "score_pct": args.score_pct,
             "smoke": bool(args.smoke),
         },
         "evidence": evidence,

@@ -95,42 +95,6 @@ def parse_args(argv=None):
         "counts (snapshot/hotfeed.py)",
     )
     ap.add_argument(
-        "--deltacache", choices=("off", "on"), default=None,
-        help="incremental scheduling (engine/deltacache.py): cache each "
-        "pod shape's feasibility/score plane in HBM and recompute only "
-        "dirty rows on a shape hit — byte-identical binds, O(batch x "
-        "dirty) steady-state device work.  Unset is 'off'",
-    )
-    ap.add_argument(
-        "--delta-profile", action="store_true",
-        help="add delta-plane-cache evidence to the report detail: "
-        "delta vs full wave split, shape hit rate, mean dirty "
-        "fraction, planes resident, fills and LRU evictions, and (with "
-        "--delta-index-k) the candidate-index wave/touched-rows/drop "
-        "accounting (engine/deltacache.py)",
-    )
-    ap.add_argument(
-        "--delta-index-k", type=int, default=0, metavar="K",
-        help="per-resident-plane top-K candidate index (requires "
-        "--deltacache on): all-hit waves derive candidates from the "
-        "index + dirty set and skip the O(N) plane scan entirely — "
-        "byte-identical binds, fail-closed on floor underflow.  0 "
-        "disables the index",
-    )
-    ap.add_argument(
-        "--stratum-bits", type=int, default=0, metavar="B",
-        help="high jitter bits drawn from a wave-invariant per-(node, "
-        "column) hash stratum instead of the seeded draw: splits tied "
-        "score levels so the candidate-index floor can cut inside them "
-        "(homogeneous clusters tie ~all rows at one score, which "
-        "otherwise fails the index closed every wave).  Scale it with "
-        "the cluster — per-pod spread only exists WITHIN a class, so "
-        "target ~32 tied rows per class (log2(nodes) - 5, the "
-        "megarow_drill.stratum_bits_for rule); 2^B >= nodes collapses "
-        "every wave onto the same few rows.  0 keeps the historical "
-        "jitter bit-for-bit",
-    )
-    ap.add_argument(
         "--shape-pool", type=int, default=0, metavar="N",
         help="pods draw structural shapes (nodeAffinity required + "
         "preferred terms, Deployment-template style) from a pool of N "
@@ -143,11 +107,6 @@ def parse_args(argv=None):
         help="with --shape-pool: fraction of pods drawing from the hot "
         "pool; the rest draw from a bounded 4N-spec tail (the 90%%-hot "
         "regime of artifacts/hostpath_bench.json)",
-    )
-    ap.add_argument(
-        "--shape-cold", action="store_true",
-        help="every pod is its OWN shape (unique request scalars): the "
-        "shape cache can never hit — the deltasched overhead lane",
     )
     ap.add_argument(
         "--depth", type=int, default=2,
@@ -326,89 +285,6 @@ def _encode_profile_detail(enabled: bool) -> dict:
             REGISTRY.get("hotfeed_staged_depth").value()
         ),
     }}
-
-
-def _delta_profile_detail(args, coord) -> dict:
-    """Delta-plane-cache evidence for the report (ISSUE 12 deltasched;
-    empty unless --delta-profile)."""
-    if not args.delta_profile:
-        return {}
-    from k8s1m_tpu.obs.metrics import REGISTRY
-
-    waves = REGISTRY.get("deltasched_waves_total")
-    delta_waves = waves.value(path="delta")
-    full_waves = waves.value(path="full")
-    hits = REGISTRY.get("deltasched_shape_hits_total").value()
-    misses = REGISTRY.get("deltasched_shape_misses_total").value()
-    dirty = REGISTRY.get("deltasched_dirty_rows_total").value()
-    rows = coord.table_spec.max_nodes
-    detail = {"delta_profile": {
-        "enabled": coord.delta_enabled,
-        "delta_waves": int(delta_waves),
-        "full_waves": int(full_waves),
-        "shape_hit_rate": (
-            round(hits / (hits + misses), 4) if hits + misses else None
-        ),
-        # Journaled dirty rows actually recomputed, as a fraction of the
-        # full-recompute work the delta waves displaced.
-        "mean_dirty_fraction": (
-            round(dirty / (delta_waves * rows), 6) if delta_waves else None
-        ),
-        "planes_resident": int(
-            REGISTRY.get("deltasched_planes_resident").value()
-        ),
-        "fills": int(REGISTRY.get("deltasched_fills_total").value()),
-        "evictions": int(
-            REGISTRY.get("deltasched_evictions_total").value()
-        ),
-    }}
-    cache = getattr(coord, "_delta", None)
-    index_k = getattr(cache, "index_k", 0) if cache is not None else 0
-    if index_k:
-        iw = REGISTRY.get("deltasched_index_waves_total")
-        touched = REGISTRY.get("deltasched_index_touched_rows_total")
-        drops = REGISTRY.get("deltasched_index_drops_total")
-        idx_waves = iw.value(path="index")
-        plane_waves = iw.value(path="plane")
-        t_idx = touched.value(path="index")
-        t_plane = touched.value(path="plane")
-        detail["delta_profile"]["index"] = {
-            "index_k": int(index_k),
-            "stratum_bits": int(cache.stratum_bits),
-            "index_waves": int(idx_waves),
-            "plane_waves": int(plane_waves),
-            # Mean rows visited per wave on each tail — the index path
-            # touches dirty + k*batch candidate rows; the plane path is
-            # the N-row chunk scan plus the dirty slice.  The fraction
-            # is the index tail's visit cost against the N rows each
-            # such wave would otherwise have scanned.
-            "mean_touched_rows": {
-                "index": (
-                    round(t_idx / idx_waves, 1) if idx_waves else None
-                ),
-                "plane": (
-                    round(t_plane / plane_waves, 1)
-                    if plane_waves else None
-                ),
-            },
-            "index_touched_fraction_of_n": (
-                round(t_idx / (idx_waves * rows), 6)
-                if idx_waves else None
-            ),
-            # Why index-eligible waves fell back to the plane scan —
-            # floor underflows vs oversized dirty sets vs wholesale
-            # invalidations (fill / generation / resync / packing).
-            "drops": {
-                r: int(drops.value(reason=r))
-                for r in (
-                    "underflow", "oversized-dirty", "fill",
-                    "generation", "resync", "packing",
-                    "fill-error", "dispatch-error",
-                )
-                if drops.value(reason=r)
-            },
-        }
-    return detail
 
 
 def _trace_detail(args, tracer) -> dict:
@@ -845,9 +721,9 @@ def main(argv=None):
         # The chunked scan runs per shard; clamp to the shard's rows.
         args.chunk = min(args.chunk, cap // mesh.shape["sp"])
     # Template-shaped pods (--shape-pool) do real per-(pod, node)
-    # selector work, so the affinity plugin is live for them — the
-    # regime the delta-plane cache collapses.  Plain pods keep the
-    # committed-baseline profile (affinity would contribute zeros).
+    # selector work, so the affinity plugin is live for them.  Plain
+    # pods keep the committed-baseline profile (affinity would
+    # contribute zeros).
     profile = (
         Profile(topology_spread=0, interpod_affinity=0)
         if args.shape_pool
@@ -865,9 +741,6 @@ def main(argv=None):
         score_pct=args.score_pct, adaptive_batch=bool(args.rate),
         mesh=mesh,
         packing=args.packing,
-        deltacache=args.deltacache,
-        delta_index_k=args.delta_index_k,
-        stratum_bits=args.stratum_bits,
         tracer=tracer,
     )
     t0 = time.perf_counter()
@@ -894,10 +767,10 @@ def main(argv=None):
         # work against the KWOK zone/region labels: a required In over
         # two zones + a region NotIn, plus a preferred zone — the
         # node_affinity_pods structure (sized to build_node's 8 zones /
-        # 4 regions), made key-distinct beyond the 8 structural combos
-        # by the request scalar the shape key also covers.  Pods draw
-        # from a HOT pool of N specs or, for the (1 - share) slice, a
-        # bounded 4N-spec tail — real pools' tails repeat too
+        # 4 regions), made distinct beyond the 8 structural combos by
+        # their request scalar.  Pods draw from a HOT pool of N specs
+        # or, for the (1 - share) slice, a bounded 4N-spec tail — real
+        # pools' tails repeat too
         # (hotfeed's hit rate is 1.0 at 90%-hot pools,
         # artifacts/hostpath_bench.json).
         from k8s1m_tpu.cluster.workload import node_affinity_pods
@@ -912,12 +785,6 @@ def main(argv=None):
             f"bench-{i}", namespace=namespaces[i],
             cpu_milli=10, mem_kib=1024,
         )
-        if args.shape_cold:
-            # Every pod its own shape (the key includes the request
-            # scalars): identical device work, zero possible cache hits
-            # — isolates the deltasched host overhead.
-            p.cpu_milli = 10 + i
-            return p
         if shape_templates:
             import random as _random
 
@@ -1096,7 +963,6 @@ def main(argv=None):
                 **_tenant_detail(args),
                 **_trace_detail(args, tracer),
                 **_encode_profile_detail(args.encode_profile),
-                **_delta_profile_detail(args, coord),
                 **_device_state_detail(coord),
                 **_kernel_profile_detail(args, coord),
                 **_resilience_detail(),
@@ -1195,7 +1061,6 @@ def main(argv=None):
             **_tenant_detail(args),
             **_trace_detail(args, tracer),
             **_encode_profile_detail(args.encode_profile),
-            **_delta_profile_detail(args, coord),
             **_device_state_detail(coord),
             **_kernel_profile_detail(args, coord),
             **_resilience_detail(),

@@ -96,17 +96,34 @@ def test_the_mix_puts_every_selector_group_in_a_wave_and_names_its_kernel():
     assert has_selectors(packed.groups)
     assert candidates_kernel("pallas", packed.groups, False) == "fused_topk_affinity"
     assert candidates_kernel("xla", packed.groups, False) == "filter_score_topk_affinity"
-    plain = frozenset({"tol"})
-    assert candidates_kernel("pallas", plain, False) == "fused_topk"
-    assert candidates_kernel("pallas", plain, True) == "fused_topk_constraints"
-    assert candidates_kernel("pallas", packed.groups, True) == \
-        "fused_topk_affinity_constraints"
-    assert candidates_kernel("pallas", plain, False, "delta") == "delta_plane_topk"
-    assert candidates_kernel("xla", plain, False, "delta") == "plane_topk"
     # at most three taints and six labels a node, three taint triples in all
     assert len(list(host.vocab.taints.items())) == 3
     assert max(len(node(i).taints) + node(i).unschedulable
                for i in range(640)) == SPEC.taint_slots == 3
+
+
+@pytest.mark.parametrize("backend", ["pallas", "xla"])
+@pytest.mark.parametrize("groups", [
+    frozenset({"tol"}), frozenset({"tol", "sel", "req", "pref", "qkey"}),
+], ids=["plain", "selectors"])
+@pytest.mark.parametrize("constraints", [False, True],
+                         ids=["unconstrained", "constrained"])
+def test_a_waves_kernel_is_named_by_its_backend_and_stages(
+    backend, groups, constraints,
+):
+    """``coordinator_waves_total{kernel}``: the pallas name is the one
+    ``pallas_call(name=)`` gets, the XLA scan's carries the same suffixes,
+    and a selector group or constraint state alone adds each."""
+    from k8s1m_tpu.ops.pallas_topk import kernel_name
+
+    aff = has_selectors(groups)
+    name = candidates_kernel(backend, groups, constraints)
+    suffix = "_affinity" * aff + "_constraints" * constraints
+    if backend == "pallas":
+        assert name == kernel_name(aff, constraints) == "fused_topk" + suffix
+    else:
+        assert name == "filter_score_topk" + suffix
+    assert aff == ("sel" in groups)
 
 
 # ---- candidates against the oracle ------------------------------------------

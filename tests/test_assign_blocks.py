@@ -292,7 +292,7 @@ def test_behind_its_last_pod_with_a_candidate_a_wave_has_no_block():
 
 
 def test_the_chip_smokes_skew_phase_rehearses_on_the_cpu():
-    """chip_smoke.py phase B (vii) at a sixteenth of its wave: both of its
+    """chip_smoke.py phase B (vi) at a sixteenth of its wave: both of its
     waves equal the scan, and each is settled by rounds."""
     from chip_smoke import Timer, _skew_variant
 

@@ -186,8 +186,7 @@ def scan_in_place_of_the_rounds(*args):
 
 
 def clear_step_caches():
-    for fn in (cycle._jitted_schedule, cycle._jitted_schedule_packed,
-               cycle._jitted_schedule_delta):
+    for fn in (cycle._jitted_schedule, cycle._jitted_schedule_packed):
         fn.cache_clear()
 
 

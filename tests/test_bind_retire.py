@@ -60,8 +60,6 @@ def _reference_bind_wave(self, batch_pods, rows, bound_ok, failed) -> int:
     host = self.host
     nbound = 0
     bound_idx = np.nonzero(rows >= 0)[0]
-    if self._delta is not None and bound_idx.size:
-        self._delta.note_rows(rows[bound_idx])
     brows = rows[bound_idx]
     if bound_idx.size:
         alive = host.valid[brows]

@@ -156,7 +156,7 @@ def test_smoke_phases_a_and_b_tiny(smoke):
         )
     assert failures == {}
     # Set-up seconds are labelled as such: one entry per executable.
-    assert {"A.bootstrap", "B.base.pallas_step", "B.delta.pallas_tail",
+    assert {"A.bootstrap", "B.base.pallas_step",
             "B.xla_scan.all_rows.xla_step", "B.assign.window_wave",
             "B.assign.fit_10k_brim", "B.prologue.9x5",
             "B.prologue.512x64"} <= set(timer.seconds)

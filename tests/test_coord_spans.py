@@ -352,7 +352,7 @@ def test_the_step_names_its_phases(sample_rows):
     )
     step = cycle._jitted_schedule_packed(
         PROFILE, 128, 4, False, "pallas", packed.spec, packed.table_spec,
-        packed.groups, sample_rows, False, False, 0,
+        packed.groups, sample_rows, False, False,
     )
     hlo = step.lower(
         host.to_device(), packed.ints, packed.bools, jax.random.key(0),

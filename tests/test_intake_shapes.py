@@ -45,7 +45,7 @@ from k8s1m_tpu.obs.metrics import REGISTRY
 from k8s1m_tpu.oracle import oracle_feasible
 from k8s1m_tpu.plugins.registry import Profile
 from k8s1m_tpu.snapshot.constraints import ConstraintTracker
-from k8s1m_tpu.snapshot.hotfeed import fingerprint
+from k8s1m_tpu.snapshot.hotfeed import PLAIN, fingerprint
 from k8s1m_tpu.snapshot.node_table import Taint
 from k8s1m_tpu.snapshot.pod_encoding import PodInfo, Toleration
 from k8s1m_tpu.store.native import MemStore
@@ -96,6 +96,16 @@ class _EventWatch:
         self._w.cancel()
 
 
+def _shape_of(p):
+    """What the encoder makes of a queued pod: its structural fingerprint
+    and its request scalars, read without materializing a fast-lane
+    pod's PodInfo."""
+    if p.pod is None:
+        fp = p.shape.fp if p.shape is not None else PLAIN
+        return fp, p.cpu_milli, p.mem_kib
+    return fingerprint(p.pod), p.pod.cpu_milli, p.pod.mem_kib
+
+
 class _Lane:
     """One store, one coordinator, and a record of what it launched."""
 
@@ -120,7 +130,7 @@ class _Lane:
         def spy(batch_pods, batch):
             self.waves.append((
                 [p.key_str for p in batch_pods],
-                [Coordinator._delta_key(p) for p in batch_pods],
+                [_shape_of(p) for p in batch_pods],
                 batch.ints.copy(), batch.bools.copy(), batch.groups,
             ))
             return launch(batch_pods, batch)
@@ -254,7 +264,7 @@ def _assert_accounting_equal(a: Coordinator, b: Coordinator) -> None:
 def test_shaped_lane_equals_json_lane(lanes):
     """Same seeded waves, both lanes: equal queue order, equal
     ``peek_pod`` of every record, byte-equal packed batches, equal
-    ``_delta_key``, equal binds, equal ``_bound``."""
+    fingerprints and request scalars, equal binds, equal ``_bound``."""
     shaped, legacy = lanes()
     for lane in (shaped, legacy):
         lane.bootstrap()
@@ -995,7 +1005,7 @@ def test_a_selector_shape_is_what_the_json_lane_decodes(
             assert getattr(made, f.name) == getattr(ref.pod, f.name), f.name
         fp = rec.shape.fp if rec.shape is not None else fingerprint(made)
         assert fp == fingerprint(ref.pod)
-        assert Coordinator._delta_key(rec) == Coordinator._delta_key(ref)
+        assert _shape_of(rec) == _shape_of(ref)
     pinned = [p for p in shaped.coord.queue if p.key_str in pinned_keys]
     assert len(pinned) == len(wave) // 2
     assert len({id(p.shape) for p in pinned}) == 1
@@ -1361,3 +1371,24 @@ def test_what_a_bound_record_keeps_takes_its_counts_back(lanes, json_lane, kind)
     left = _all_tables_equal(shaped.coord, legacy.coord)
     for t in TABLES:
         np.testing.assert_array_equal(left[t], before[t], err_msg=t)
+
+
+def test_shape_bindings_bounded():
+    store = MemStore()
+    store.put(node_key("n0"), encode_node(build_node(0)))
+    c = Coordinator(store, TableSpec(max_nodes=16, max_zones=4, max_regions=2),
+                    PodSpec(batch=8), PLAIN_PROFILE, chunk=16, k=2)
+    c.bootstrap()
+    try:
+        for i in range(1100):
+            c._bound_shape(None, f"ns-{i}")
+        # The cap clears the dict rather than let dead generations pile
+        # up across long soaks.
+        assert len(c._shape_bindings) <= 1024
+        # Still correct after the clear: nothing registered, so a
+        # label-less pod carries nothing but its scalars.
+        assert c._bound_shape(None, "ns-0") is None
+        assert c._bound_shape(None, "ns-0") is None
+    finally:
+        c.close()
+        store.close()

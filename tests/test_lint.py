@@ -46,8 +46,6 @@ EXPECTED = {
     "mesh-purity": "k8s1m_tpu/parallel/bad_mesh.py",
     "fenced-store-write": "k8s1m_tpu/control/bad_fenced_write.py",
     "undonated-device-update": "k8s1m_tpu/engine/bad_donate.py",
-    "deltacache-epoch-keyed": "k8s1m_tpu/engine/bad_deltacache.py",
-    "deltacache-index-keyed": "k8s1m_tpu/engine/bad_deltacache_index.py",
     "trace-lazy-emit": "k8s1m_tpu/control/bad_trace_emit.py",
     "bounded-watch-buffer": "k8s1m_tpu/store/bad_watchbuf.py",
     "nondet-to-placement": "k8s1m_tpu/engine/bad_nondet.py",

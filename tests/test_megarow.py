@@ -9,15 +9,12 @@ replaced, plus the 131k tier-1 smoke of the end-to-end drill.
    lane) == ``upsert(decode_node(v))`` over mixed canonical /
    non-canonical value streams, across chunk boundaries.
 3. ``list_prefix_values`` / ``list_prefix_sharded`` == ``list_prefix``.
-4. ``RowVersions`` journal boundary: exactly-full vs one-past-full
-   fail closed the same way before and after the scale-aware cap —
-   and the derived cap IS the old fixed cap at the old 131k size.
-5. The incremental preemption-victims index materializes to exactly
+4. The incremental preemption-victims index materializes to exactly
    the old full ``_bound.items()`` scan, through binds, deletes,
    evictions and a resync.
-6. Host-mirror narrow dtypes: spec-bounded columns shrink, the device
+5. Host-mirror narrow dtypes: spec-bounded columns shrink, the device
    table stays int32, out-of-range effects fail closed.
-7. ``megarow_drill --smoke``: 131,072 rows end to end in tier-1, with
+6. ``megarow_drill --smoke``: 131,072 rows end to end in tier-1, with
    the >= 3x cold-build proxy and the peak-RSS budget gated inside
    the drill (the full 1M run is ``-m slow``).
 """
@@ -41,13 +38,11 @@ from k8s1m_tpu.control.objects import (  # noqa: E402
     node_key,
     pod_key,
 )
-from k8s1m_tpu.engine.deltacache import DeltaPlaneCache  # noqa: E402
 from k8s1m_tpu.plugins.registry import Profile  # noqa: E402
 from k8s1m_tpu.snapshot.bulkload import BulkNodeLoader  # noqa: E402
 from k8s1m_tpu.snapshot.node_table import (  # noqa: E402
     NodeInfo,
     NodeTableHost,
-    RowVersions,
     Taint,
     mirror_dtype,
 )
@@ -285,64 +280,7 @@ def test_list_prefix_values_and_sharded_match():
         store.close()
 
 
-# ---- 4. RowVersions: boundary + the scale-aware cap ------------------
-
-
-def _drive(rv: RowVersions, batches):
-    stamps = []
-    for rows in batches:
-        stamps.append(rv.note(rows))
-    return stamps
-
-
-def test_rowversions_boundary_full_vs_one_past_full():
-    """Journal exactly full: every consumer delta stays enumerable.
-    One entry past full: compaction raises the floor and consumers
-    stamped below it fail CLOSED (None = recompute), never a partial
-    delta.  Identical behavior at the old fixed cap and at the
-    scale-aware cap evaluated at the old size."""
-    for rv in (RowVersions(cap=64),
-               DeltaPlaneCache(128, journal_cap=64).versions):
-        v0 = rv.ver
-        _drive(rv, ([i] for i in range(64)))      # exactly full
-        assert len(rv) == 64 and rv.floor == 0
-        assert rv.rows_since(v0) == set(range(64))
-        rv.note([64])                              # one past full
-        assert rv.floor > 0
-        assert len(rv) == 32                       # compacted to cap//2
-        assert rv.rows_since(v0) is None           # fail closed
-        assert rv.rows_since(rv.floor - 1) is None
-        live = rv.rows_since(rv.floor)
-        assert live is not None and 64 in live
-
-
-def test_scale_aware_journal_cap_derivation():
-    # old size -> exactly the old fixed cap (the differential anchor)
-    assert DeltaPlaneCache(131072).versions.cap == 1 << 16
-    # below: floored at the old cap; above: half the table
-    assert DeltaPlaneCache(2048).versions.cap == 1 << 16
-    assert DeltaPlaneCache(1 << 20).versions.cap == 1 << 19
-    # explicit override still wins
-    assert DeltaPlaneCache(1 << 20, journal_cap=123).versions.cap == 123
-
-
-def test_scale_aware_cap_trajectory_matches_fixed_cap_at_old_size():
-    """Same note/compact/release trajectory, entry for entry."""
-    a = RowVersions(cap=1 << 16)
-    b = DeltaPlaneCache(131072).versions
-    rng = np.random.default_rng(0)
-    for _ in range(40):
-        rows = rng.integers(0, 131072, size=int(rng.integers(1, 4096)))
-        a.note(rows)
-        b.note(rows)
-    assert (a.ver, a.floor, len(a)) == (b.ver, b.floor, len(b))
-    assert list(a._journal) == list(b._journal)
-    a.release(a.ver - 5)
-    b.release(b.ver - 5)
-    assert list(a._journal) == list(b._journal) and a.floor == b.floor
-
-
-# ---- 5. incremental victims index == full scan -----------------------
+# ---- 4. incremental victims index == full scan -----------------------
 
 
 def test_victims_index_incremental_matches_full_scan():
@@ -390,7 +328,7 @@ def test_victims_index_incremental_matches_full_scan():
         store.close()
 
 
-# ---- 6. host-mirror narrow dtypes ------------------------------------
+# ---- 5. host-mirror narrow dtypes ------------------------------------
 
 
 def test_mirror_dtypes_follow_table_spec_bounds():
@@ -414,7 +352,7 @@ def test_mirror_dtypes_follow_table_spec_bounds():
     assert host.mirror_nbytes() > 0
 
 
-# ---- 7. make_nodes --bulk over the wire ------------------------------
+# ---- 6. make_nodes --bulk over the wire ------------------------------
 
 
 def test_make_nodes_bulk_batched_puts():
@@ -447,7 +385,7 @@ def test_make_nodes_bulk_batched_puts():
         store.close()
 
 
-# ---- 8. the drill smoke (tier-1) and full shape (slow) ---------------
+# ---- 7. the drill smoke (tier-1) and full shape (slow) ---------------
 
 
 def _run_drill(extra, timeout):

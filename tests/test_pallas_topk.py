@@ -21,7 +21,6 @@ from k8s1m_tpu.config import (
 )
 from k8s1m_tpu.engine.cycle import filter_score_topk, schedule_batch
 from k8s1m_tpu.ops.pallas_topk import (
-    delta_plane_topk,
     fused_topk,
     np_reference_topk,
     pallas_candidates,
@@ -471,54 +470,6 @@ def test_constrained_schedule_batch_parity(rng):
     np.testing.assert_array_equal(
         np.asarray(asg_x.score), np.asarray(asg_p.score)
     )
-
-
-# ---- the fused delta tail (deltasched plane top-k) ------------------------
-
-
-def _delta_parity(rng, n, s, b, chunk, hb=0, seeds=(0, 4242)):
-    """delta_plane_topk (fused dirty-gather → merge → top-k) vs
-    plane_topk (the XLA delta tail) over the same cached planes: idx
-    AND prio bit-identical for real pods.  Padding pods (slot sentinel)
-    are don't-cares — plane_topk's jnp.take fills out-of-range slots
-    while the kernel clips, and finalize valid-masks padding out before
-    anything binds."""
-    from k8s1m_tpu.engine.deltacache import plane_topk
-
-    pmask = jnp.asarray(rng.random((s, n)) < 0.6)
-    pscore = jnp.asarray(rng.integers(0, 2048, (s, n)), jnp.int32)
-    slot_ids = jnp.asarray(
-        np.concatenate([rng.integers(0, s, b - 2), [s, s]]), jnp.int32
-    )
-    real = np.asarray(slot_ids) < s
-    for seed in seeds:
-        sd = jnp.int32(seed)
-        cand_p = delta_plane_topk(
-            pmask, pscore, slot_ids, sd, chunk=chunk, k=4, stratum_bits=hb
-        )
-        cand_x = plane_topk(
-            pmask, pscore, slot_ids, sd, chunk=chunk, k=4, stratum_bits=hb
-        )
-        np.testing.assert_array_equal(
-            np.asarray(cand_p.idx)[real], np.asarray(cand_x.idx)[real]
-        )
-        np.testing.assert_array_equal(
-            np.asarray(cand_p.prio)[real], np.asarray(cand_x.prio)[real]
-        )
-
-
-def test_delta_tail_matches_xla_plane_topk(rng):
-    """Chunk-carry and slot-gather parity at small scale, with and
-    without stratification."""
-    _delta_parity(rng, n=512, s=8, b=16, chunk=128)
-    _delta_parity(rng, n=512, s=8, b=16, chunk=128, hb=12)
-
-
-def test_delta_tail_bit_identical_at_131072_rows(rng):
-    """The ISSUE 18 acceptance gate: the pallas delta step's top-k tail
-    is bit-identical to the XLA delta step at 131,072 plane rows
-    (interpreter mode here; the identical kernel compiles on TPU)."""
-    _delta_parity(rng, n=131072, s=4, b=8, chunk=16384, hb=12, seeds=(7,))
 
 
 def test_scaled_oracle_chunk_and_tile_boundaries(rng):

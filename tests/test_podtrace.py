@@ -142,7 +142,7 @@ def test_export_validates_and_flows_resolve(tmp_path):
     # Device spans carry the wave attributes.
     dev = [e for e in evs if e["ph"] == "X" and e["name"] == "device"]
     assert dev and all(
-        "wave_epoch" in e["args"] and e["args"]["path"] in ("full", "delta")
+        "wave_epoch" in e["args"] and "path" not in e["args"]
         and e["args"]["depth"] >= 1
         for e in dev
     )

@@ -137,6 +137,7 @@ from k8s1m_tpu.snapshot.hotfeed import (
     cache_counts,
     encode_batch,
     fingerprint,
+    widen_packed,
 )
 from k8s1m_tpu.snapshot.node_table import (
     ALL_COLUMNS,
@@ -364,6 +365,16 @@ _MESH_SCATTER = Counter(
     "capacity/feature columns only) — each one lands mid-flight with no "
     "quiesce and no reshard (make_sharded_scatter pins the row sharding)",
     ("cols",),
+)
+# Once per wave dispatched to a mesh (_launch): how its step merged the
+# node shards' candidates (engine/cycle.merge_shards) -- zone = zone id by
+# zone id, the best of the shards' rows in each (in_wave_skew); topk = the
+# k best of all.
+_MESH_WAVES = Counter(
+    "mesh_waves_total",
+    "Waves dispatched to the device mesh, by how their step merged the "
+    "node shards' candidates",
+    ("merge",),
 )
 _MESH_FEED_DEPTH = Gauge(
     "mesh_feed_staged_depth",
@@ -897,8 +908,8 @@ class Coordinator:
         # A pod then brings one candidate a zone id, so ``k`` is
         # ``table_spec.max_zones`` whatever was passed — which is why
         # this is a choice and not the default (the default TableSpec
-        # has 512 zone ids).  One device only.  False = the wave-start
-        # counts alone decide.
+        # has 512 zone ids).  On a mesh the shards' candidates then merge
+        # zone by zone.  False = the wave-start counts alone decide.
         in_wave_skew: bool = False,
     ):
         self.store = store
@@ -953,10 +964,8 @@ class Coordinator:
                 max_nodes=table_spec.max_nodes, chunk=chunk,
             )
         self.mesh = mesh
-        if in_wave_skew and (mesh is not None or not with_constraints):
-            raise ValueError(
-                "in_wave_skew takes with_constraints=True and no mesh"
-            )
+        if in_wave_skew and not with_constraints:
+            raise ValueError("in_wave_skew takes with_constraints=True")
         self.in_wave_skew = in_wave_skew
         if mesh is not None:
             dp_size, sp_size = mesh.shape["dp"], mesh.shape["sp"]
@@ -1114,6 +1123,9 @@ class Coordinator:
             empty_constraints(table_spec) if with_constraints else None
         )
         self._table_sharding = None
+        # The field-group sets of the waves dispatched so far, by the
+        # (profile, sample_rows) of their step (_built_step_for).
+        self._built_groups: dict = {}
         # Dirty-row scatters donate on both paths (in-place updates);
         # the mesh override below additionally pins the row sharding.
         self._scatter = _scatter_rows_donated
@@ -2125,7 +2137,9 @@ class Coordinator:
                 break
         if not pods:
             return False
-        batch = self.encoder.encode_packed(pods)
+        batch = self._built_step_for(
+            self.encoder.encode_packed(pods), self.profile, self._sample_rows
+        )
         # The production executable donates its inputs: warm it against
         # throwaway COPIES so the live mirror table (and constraint
         # state) survive this discarded dispatch.
@@ -3278,6 +3292,27 @@ class Coordinator:
             return self._profile_degraded, self._sample_rows_degraded
         return self.profile, self._sample_rows
 
+    def _built_step_for(self, batch, profile, sample_rows):
+        """The packed wave to dispatch: ``batch`` itself, or, where no
+        wave with its field groups has run yet but one with a wider set
+        has, ``batch`` packed with that set (the smallest such), so that
+        it runs a step already built instead of compiling one of its own
+        (a few minutes, for the combined Mosaic kernel).  The groups it
+        lacks go in as the zeros an absent group unpacks to
+        (hotfeed.widen_packed), so the step sees the values its own would
+        have seen and binds what it would have bound: only which
+        executable runs changes.  The wave that needs this is the one of
+        a few pods the gate or the skew sent back, whose shapes are a
+        part of the mix."""
+        built = self._built_groups.setdefault((profile, sample_rows), set())
+        if batch.groups in built:
+            return batch
+        wider = [g for g in built if batch.groups < g]
+        if not wider:
+            built.add(batch.groups)
+            return batch
+        return widen_packed(batch, min(wider, key=lambda g: (len(g), sorted(g))))
+
     def _launch(self, batch_pods, batch):
         """Enqueue the device step for an encoded batch (async — no
         device→host transfer is forced).  Faultline hook
@@ -3297,6 +3332,7 @@ class Coordinator:
                     else:
                         raise faultline.InjectedFault(d)
             profile, sample_rows = self._active_knobs()
+            batch = self._built_step_for(batch, profile, sample_rows)
             self.key, subkey = jax.random.split(self.key)
             probe_ptr = None
             if self._donate and self._donation_inplace is None:
@@ -3336,6 +3372,8 @@ class Coordinator:
         _WAVES.inc(kernel=candidates_kernel(
             self.backend, batch.groups, self.constraints is not None,
         ))
+        if self.mesh is not None:
+            _MESH_WAVES.inc(merge="zone" if self.in_wave_skew else "topk")
         # Start the device->host copy of the bind decision now: by the
         # time _complete runs (a drain + encode later), the bytes are
         # already on the host and device_get returns without waiting

@@ -33,12 +33,14 @@ of the wave it conflicts with took, and holds a required affinity term
 that bootstraps (upstream's rule) to the domains of the pods it selects
 (engine/assign.py).  Hostname-keyed spread constraints and every score
 still read the wave-start tables; so does spread when ``in_wave_skew`` is
-off (the default, and the mesh step: its candidate gather is a top-k
-merge, and it builds no gate), and two same-batch pods can then exceed
-maxSkew by the batch size in the worst case.  The reference has exactly
-that window (256 shards bind optimistically and only capacity conflicts
-roll back, reference README.adoc:558-560);
-constraint counts are exact again at the next batch boundary.  The
+off (the default), and two same-batch pods can then exceed maxSkew by the
+batch size in the worst case.  The reference has exactly that window (256
+shards bind optimistically and only capacity conflicts roll back,
+reference README.adoc:558-560);
+constraint counts are exact again at the next batch boundary.  The mesh
+step does all of this as one device does: its shards' candidates merge
+zone by zone under ``in_wave_skew``, and the count and the gate run
+replicated (parallel/sharded_cycle.py).  The
 pipelined coordinator widens the wave-start window across waves:
 capacity-only node deltas (allocatable, labels, taints, zone — same row,
 same name) scatter into the live table while earlier waves are still in
@@ -397,6 +399,32 @@ def merge_per_zone(a: Candidates, b: Candidates) -> Candidates:
     return jax.tree.map(lambda xa, xb: jnp.where(keep, xa, xb), a, b)
 
 
+def merge_shards(cand: Candidates, axis_name: str, k: int,
+                 per_zone: bool) -> Candidates:
+    """Inside shard_map: the node shards' candidates merged over
+    ``axis_name``, so that every device holds the whole table's.  The
+    lists gather shard-major and a shard's rows follow the one before
+    it, so the lower shard holds the lower rows.  With ``per_zone`` the
+    slot of each zone id takes the best of the shards' rows there, the
+    lowest shard's where priorities tie (argmax keeps the first): what
+    ``merge_per_zone`` folds the chunks of one device to.  Otherwise the
+    ``k`` best of all (``lax.top_k`` keeps the earlier of equal
+    priorities: again the lower row)."""
+    gathered = jax.tree.map(lambda x: lax.all_gather(x, axis_name), cand)
+    if per_zone:
+        best = jnp.argmax(gathered.prio, axis=0)[None]          # [1, B, k]
+        return jax.tree.map(
+            lambda x: jnp.take_along_axis(x, best, axis=0)[0], gathered
+        )
+    flat = jax.tree.map(                                        # [B, SP*k]
+        lambda x: jnp.moveaxis(x, 0, 1).reshape(x.shape[1], -1), gathered
+    )
+    top_prio, sel = lax.top_k(flat.prio, k)
+    return jax.tree.map(
+        lambda x: jnp.take_along_axis(x, sel, axis=-1), flat
+    ).replace(prio=top_prio)
+
+
 def empty_candidates(b: int, k: int) -> Candidates:
     zeros = jnp.zeros((b, k), jnp.int32)
     return Candidates(
@@ -654,24 +682,26 @@ def candidates(
     ``stats`` is ``prologue_stats(table, constraints)`` where the caller
     has taken it already.
 
-    ``in_wave_skew`` (one device, ``k`` = ``TableSpec.max_zones``) hands
-    the hard zone and region constraints over to the in-wave count
-    (``wave_skew`` + engine/assign.py): here they lose their skew test
-    against the wave-start counts (a node still has to carry the label),
-    and a pod's candidates are the best row of every zone, sorted by
-    priority, so that the zone that is legal at its turn has one."""
+    Under shard_map the shards' candidates are merged over ``axis_name``
+    (``merge_shards``, scope ``sp_merge``): what this returns there is the
+    whole table's, on every device.
+
+    ``in_wave_skew`` (``k`` = ``TableSpec.max_zones``) hands the hard zone
+    and region constraints over to the in-wave count (``wave_skew`` +
+    engine/assign.py): here they lose their skew test against the
+    wave-start counts (a node still has to carry the label), and a pod's
+    candidates are the best row of every zone, sorted by priority, so
+    that the zone that is legal at its turn has one; a mesh merges them
+    zone by zone before the sort."""
     src = table if src is None else src
     if constraints is not None and stats is None:
         stats = prologue_stats(table, constraints, axis_name)
     with jax.named_scope("candidates"):
         if in_wave_skew:
-            if constraints is None or axis_name is not None or (
-                k != constraints.spread_zone.shape[1]
-            ):
+            if constraints is None or k != constraints.spread_zone.shape[1]:
                 raise ValueError(
-                    "in_wave_skew takes constraint state, one device and "
-                    "one candidate a zone id: k = TableSpec.max_zones "
-                    f"(k={k})"
+                    "in_wave_skew takes constraint state and one candidate "
+                    f"a zone id: k = TableSpec.max_zones (k={k})"
                 )
             batch = batch.replace(spread_max_skew=jnp.where(
                 _counted_in_wave(batch), _NO_SKEW_LIMIT, batch.spread_max_skew
@@ -720,6 +750,9 @@ def candidates(
             cand = cand.replace(
                 idx=jnp.where(cand.idx >= 0, cand.idx + remap, -1)
             )
+        if axis_name is not None:
+            with jax.named_scope("sp_merge"):
+                cand = merge_shards(cand, axis_name, k, in_wave_skew)
         if in_wave_skew:
             # zone order -> priority order (the lower zone id wins a tie):
             # greedy_assign takes the first candidate that is ok
@@ -993,22 +1026,22 @@ def schedule_batch_packed(
     both backends, and binds are byte-identical to the unpacked layout
     (tests/test_packing.py differential gate).
 
-    ``in_wave_skew``: as ``schedule_batch``'s; one device only.
+    ``in_wave_skew``: as ``schedule_batch``'s, on one device and on a
+    mesh alike.
 
     Returns (new_table, new_constraints, Assignment, rows).
     """
     if mesh is not None:
         if row_mask is not None:
             raise ValueError("mesh and row_mask are mutually exclusive")
-        if in_wave_skew:
-            raise ValueError("in_wave_skew does not compose with mesh sharding")
         from k8s1m_tpu.parallel.sharded_cycle import make_sharded_packed_step
 
         step = make_sharded_packed_step(
             mesh, profile, chunk=chunk, k=k,
             pod_spec=packed.spec, table_spec=packed.table_spec,
             groups=packed.groups, sample_rows=sample_rows, backend=backend,
-            donate=donate,
+            donate=donate, in_wave_skew=in_wave_skew,
+            interpod=has_interpod_gate(packed.groups, constraints is not None),
         )
         offset = np.int32(sample_offset)
         if constraints is not None:

@@ -4,16 +4,23 @@ Dataflow per cycle (replacing reference SURVEY.md §3.2's process hops):
 
 1. each (dp, sp) device runs the chunked filter+score+top-k over its
    [B/dp, N/sp] block — the hot loop, purely local;
-2. candidates all-gather over ``sp`` and re-top-k — the ICI replacement
-   for the CollectScore gRPC gather + ScoreEvaluator rendezvous
-   (reference pkg/scoreevaluator/scoreevaluator.go:45-126);
+2. candidates all-gather over ``sp`` and merge (engine/cycle.merge_shards,
+   scope ``sp_merge``): a re-top-k, or, where the wave counts skew
+   (``in_wave_skew``), zone by zone, the best of the shards' rows in each
+   zone id — the ICI replacement for the CollectScore gRPC gather +
+   ScoreEvaluator rendezvous (reference
+   pkg/scoreevaluator/scoreevaluator.go:45-126);
 3. candidates (and pod resources) all-gather over ``dp``, giving every
    device the full batch's candidate lists — a few KB;
 4. the greedy conflict-resolution scan runs *replicated* on every device
    (identical inputs -> identical result, no coordination), replacing the
    reference's optimistic bind-and-rollback — replicated by
    construction, not by inference, which is why every shard_map here
-   passes ``check_vma=False``;
+   passes ``check_vma=False``.  The in-wave skew count and the inter-pod
+   gate run inside it as on one device: their count tables are the
+   replicated zone / region tables, their domain statistics the global
+   prologue's (``prologue_stats(axis_name="sp")``), their pod fields the
+   whole wave's, which every device unpacks;
 5. each sp shard commits the binds that landed in its row range to its
    slice of the table and of the hostname-domain count tables; zone /
    region count tables are replicated and take the full (identical)
@@ -66,11 +73,13 @@ from jax.sharding import PartitionSpec as P
 import functools
 
 from k8s1m_tpu.engine.cycle import (
-    Assignment,
     candidates,
     commit_fields_of,
     finalize_batch,
     has_selectors,
+    prologue_stats,
+    wave_interpod,
+    wave_skew,
 )
 from k8s1m_tpu.parallel.mesh import batch_specs, constraint_specs, table_specs
 from k8s1m_tpu.plugins.registry import Profile
@@ -79,8 +88,8 @@ from k8s1m_tpu.snapshot.node_table import NodeTable, scatter_rows
 from k8s1m_tpu.snapshot.pod_encoding import PodBatch
 
 # greedy_assign runs replicated: every leaf of its Assignment is whole on
-# every device (no mesh step counts skew in the wave: ``unbound`` is None).
-_ASG_SPECS = Assignment(P(), P(), P(), P(), P(), settled=P())
+# every device, ``unbound`` too where the wave counts skew or runs the gate.
+_ASG_SPECS = P()
 
 
 def make_sharded_scatter(table_sharding):
@@ -122,34 +131,25 @@ def mesh_offsets(table, b_local: int):
     return lax.axis_index("dp") * b_local, lax.axis_index("sp") * table.num_rows
 
 
-def gather_and_finalize(table, batch, cand, constraints, *, k: int):
-    """The shared sharded epilogue (call inside shard_map over (dp, sp)):
+def gather_and_finalize(table, batch, cand, constraints, *, skew=None,
+                        interpod=None):
+    """The shared sharded epilogue (call inside shard_map over (dp, sp)),
+    on candidates ``candidates()`` has already merged over ``sp``:
 
-    1. gather candidates across node shards (``sp``), keep global top-k —
-       the ICI replacement for the CollectScore gRPC gather
-       (reference pkg/scoreevaluator/scoreevaluator.go:45-126);
-    2. gather candidates and commit fields across ``dp`` (pods stay in
+    1. gather candidates and commit fields across ``dp`` (pods stay in
        batch order: dp shards are contiguous blocks) — only CommitFields
        crosses this hop, the selector tensors never leave home;
-    3. replicated greedy conflict resolution (identical inputs ->
-       identical result on every device, no coordination), then commit
-       the binds landing in this shard's row range; zone/region count
-       tables are replicated and take the full identical update.
+    2. replicated greedy conflict resolution (identical inputs ->
+       identical result on every device, no coordination), with the
+       in-wave count (``skew``) and the inter-pod gate (``interpod``) over
+       the whole wave where given, then commit the binds landing in this
+       shard's row range; zone/region count tables are replicated and
+       take the full identical update.
 
     Returns (table, constraints|None, Assignment).
     """
     rows = table.num_rows
     row_offset = lax.axis_index("sp") * rows
-
-    def gather_sp(x):
-        g = lax.all_gather(x, "sp")                 # [SP, b, k]
-        return jnp.moveaxis(g, 0, 1).reshape(x.shape[0], -1)
-
-    cand = jax.tree.map(gather_sp, cand)
-    top_prio, sel = lax.top_k(cand.prio, k)
-    cand = jax.tree.map(
-        lambda x: jnp.take_along_axis(x, sel, axis=-1), cand
-    ).replace(prio=top_prio)
 
     def gather_dp(x):
         g = lax.all_gather(x, "dp")
@@ -159,7 +159,8 @@ def gather_and_finalize(table, batch, cand, constraints, *, k: int):
     fields = jax.tree.map(gather_dp, commit_fields_of(batch))
 
     return finalize_batch(
-        table, constraints, cand, fields, row_offset=row_offset, rows=rows
+        table, constraints, cand, fields, row_offset=row_offset, rows=rows,
+        skew=skew, interpod=interpod,
     )
 
 
@@ -179,7 +180,7 @@ def make_sharded_step(mesh, profile: Profile, *, chunk: int, k: int):
             table, batch, key, constraints, profile, chunk=chunk, k=k,
             row_offset=row_offset, pod_offset=pod_offset, axis_name="sp",
         )
-        return gather_and_finalize(table, batch, cand, constraints, k=k)
+        return gather_and_finalize(table, batch, cand, constraints)
 
     def step(table, batch, key, constraints=None):
         cons_specs = constraint_specs(constraints) if constraints is not None else None
@@ -210,6 +211,8 @@ def make_sharded_packed_step(
     sample_rows: int | None = None,
     backend: str = "xla",
     donate: bool = False,
+    in_wave_skew: bool = False,
+    interpod: bool = False,
 ):
     """The mesh analogue of engine.cycle._jitted_schedule_packed: the
     coordinator's production step — packed two-buffer pod upload,
@@ -248,6 +251,12 @@ def make_sharded_packed_step(
       rotating chunk-aligned window of its own rows (the reference's
       percentageOfNodesToScore works the same way per replica —
       dist-scheduler samples 5% of the nodes *it owns*).
+
+    ``in_wave_skew`` and ``interpod`` (engine.cycle.has_interpod_gate of
+    the wave's groups, decided by the caller) build the in-wave count and
+    the inter-pod gate into the replicated conflict scan, as the
+    single-device step builds them: an unsampled wave binds what one
+    device binds, and ``Assignment.unbound`` says why pods stayed unbound.
 
     Overload note: ``sample_rows`` and ``profile`` are cache keys, so a
     coordinator flipping to its degraded mode (k8s1m_tpu/loadshed:
@@ -292,6 +301,17 @@ def make_sharded_packed_step(
             qkey=full.qkey          # qkey is [Q]; stays whole on every rank
         )
 
+        stats = skew = gate = None
+        if constraints is not None and (in_wave_skew or interpod):
+            # the global prologue, taken once for the candidates, the
+            # in-wave count and the gate, as one device takes it; the
+            # count and the gate read the whole wave's pods
+            stats = prologue_stats(table, constraints, "sp")
+            if in_wave_skew:
+                skew = wave_skew(full, constraints, stats)
+            if interpod:
+                gate = wave_interpod(full, constraints, stats)
+
         # Same key on every device; the tie-break jitter globalizes via
         # the (pod_offset, row_offset) hash bases instead (mesh_offsets) —
         # an unsampled wave is byte-identical to the single-device wave.
@@ -300,10 +320,11 @@ def make_sharded_packed_step(
             backend=backend, with_affinity=aff,
             window=None if sample_rows is None else (offset, sample_rows),
             row_offset=row_offset, pod_offset=pod_offset, axis_name="sp",
+            stats=stats, in_wave_skew=in_wave_skew,
         )
 
         table, cons, asg = gather_and_finalize(
-            table, batch, cand, constraints, k=k
+            table, batch, cand, constraints, skew=skew, interpod=gate
         )
         rows_out = jnp.where(asg.bound, asg.node_row, -1).astype(jnp.int32)
         return table, cons, asg, rows_out

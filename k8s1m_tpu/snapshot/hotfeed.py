@@ -769,6 +769,27 @@ def merge_packed(parts: list[PackedPodBatch]) -> PackedPodBatch | None:
     )
 
 
+def widen_packed(batch: PackedPodBatch, groups: frozenset) -> PackedPodBatch:
+    """``batch`` packed with the field groups ``groups``, a superset of its
+    own: the fields of a group it lacks go in as the zeros
+    ``unpack_pod_batch`` gives an absent group, so a step built for
+    ``groups`` reads the values the step of the wave's own groups reads
+    (Coordinator._built_step_for)."""
+    specs = batch_field_specs(batch.spec, batch.table_spec)
+    fields = {
+        name: (
+            _merge_zero_view(name, is_bool, shape)
+            if _GROUP_OF.get(name) not in (None, *batch.groups)
+            else batch.fields[name]
+        )
+        for name, is_bool, shape in specs
+    }
+    ints, bools, fields = _pack_buffers(specs, groups, fields, _merge_zero_view)
+    return dataclasses.replace(
+        batch, ints=ints, bools=bools, fields=fields, groups=groups
+    )
+
+
 def encode_batch(enc: HotPodBatchHost, batch_pods, *, mutate: bool = True):
     """Encode popped/peeked PendingPods with ``enc`` — the ONE encode
     body both the inline path (Coordinator._take_batch) and the feed

@@ -22,7 +22,9 @@ prints set-up seconds and counts, never a rate.
   one wave through the normal step functions for each kernel variant the
   production path can select (base, affinity, constraint — all over the
   packed int16/int8 planes), each pallas ``(idx, prio)`` compared bit for bit
-  with the XLA scan's on the same inputs, plus the XLA scan itself at
+  with the XLA scan's on the same inputs (the affinity wave once more
+  with its label value ids moved past 2^24, the constraint wave once
+  more on count tables up to 2^24 - 1), plus the XLA scan itself at
   the window shape and over all 1M rows; and ``greedy_assign`` (a wave's
   conflicts in parallel rounds) against the sequential scan it replaced,
   the four outputs bit for bit, over a wave of this cluster and over the
@@ -39,7 +41,8 @@ prints set-up seconds and counts, never a rate.
   deployment as the benchmark sets it up (1M tainted nodes, its widths,
   split label planes), a dozen waves of its own pods, then the combined
   kernel ``fused_topk_affinity_constraints`` against the XLA scan on a
-  window of the next pods, bit for bit, on live and long-lived counts.
+  window of the next pods, bit for bit, on live counts and on counts up
+  to 2^24 - 1.
 - **Mesh phase** (>= 4 chips): phase A once more over an explicit 1x4
   mesh, and a one-chip / 1x4 pair at percentageOfNodesToScore 100 whose
   binds must be byte-identical (sampled windows rotate shard-locally by
@@ -405,11 +408,12 @@ def _equal(name: str, a, b) -> None:
 
 
 def _window_candidates(packed, profile, *, rows, chunk, k, offset, backend,
-                       in_wave_skew=False):
+                       in_wave_skew=False, move=None):
     """Jitted ``(table, ints, bools, key, constraints) -> (idx, prio)``:
     the step's own candidates stage (engine/cycle.candidates) over one
     scan window, on the backend asked for (``in_wave_skew``: the best row
-    of every zone, as a deployment that counts skew in the wave builds it)."""
+    of every zone, as a deployment that counts skew in the wave builds it;
+    ``move``: ``(table, batch) -> (table, batch)`` applied first)."""
     import jax
 
     from k8s1m_tpu.engine.cycle import candidates, has_selectors
@@ -421,6 +425,8 @@ def _window_candidates(packed, profile, *, rows, chunk, k, offset, backend,
         batch = unpack_pod_batch(
             ints, bools, packed.spec, packed.table_spec, packed.groups
         )
+        if move is not None:
+            table, batch = move(table, batch)
         cand = candidates(
             table, batch, key, constraints, profile, chunk=chunk, k=k,
             backend=backend, with_affinity=aff, window=(offset, rows),
@@ -472,6 +478,71 @@ def _variant(name, table, packed, profile, timer, *, chunk, k, sample_rows,
             raise RuntimeError(f"{name}: no feasible candidate (state {i})")
     log(f"phase B {name}: compiled, {bound} bound, (idx, prio) == XLA")
     return rows
+
+
+# Label value ids of the wide-id wave: past 2^24 (f32's integer range),
+# with every byte of the id set.
+WIDE_ID_SHIFT = (1 << 30) + (1 << 24) + 1
+
+
+def _wide_label_ids(table, batch, by=WIDE_ID_SHIFT):
+    """The same wave with every label value id, on the nodes and in the
+    pods' selectors, moved up by ``by`` (``NONE_ID`` stays): every
+    equality the wave tests holds as before, so its candidates are the
+    same, but the ids pass 2^24.  A table of fused label words is split
+    first, since a fused word holds no such id."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from k8s1m_tpu.config import NONE_ID
+
+    if table.spec.fuse_labels:
+        kb = table.spec.key_bits
+        table = table.replace(
+            spec=dataclasses.replace(table.spec, fuse_labels=False),
+            label_key=table.label_key & ((1 << kb) - 1),
+            label_val=table.label_key >> kb,
+        )
+
+    def move(x):
+        return jnp.where(x != NONE_ID, x + by, x)
+
+    return (
+        table.replace(label_val=move(table.label_val)),
+        batch.replace(sel_val=move(batch.sel_val), req_vals=move(batch.req_vals),
+                      pref_vals=move(batch.pref_vals)),
+    )
+
+
+def _wide_id_variant(table, packed, profile, timer, *, chunk, k, sample_rows,
+                     offset) -> None:
+    """The affinity wave once more, its label value ids moved past 2^24
+    (``_wide_label_ids``): the compiled ``fused_topk_affinity`` against
+    the XLA scan on the moved ids, and against its own candidates on the
+    ids as they were, bit for bit."""
+    import jax
+
+    rows = table.num_rows if sample_rows is None else sample_rows
+    key = jax.random.key(SEED)
+    out = {}
+    for label, backend, move in (("pallas", "pallas", _wide_label_ids),
+                                 ("xla", "xla", _wide_label_ids),
+                                 ("pallas_narrow", "pallas", None)):
+        fn = _window_candidates(
+            packed, profile, rows=rows, chunk=chunk, k=k, offset=offset,
+            backend=backend, move=move,
+        )
+        with timer.measure(f"B.affinity_wide_ids.{label}_candidates"):
+            out[label] = jax.device_get(
+                fn(table, packed.ints, packed.bools, key, None))
+    for other in ("xla", "pallas_narrow"):
+        _equal(f"affinity_wide_ids idx vs {other}", out["pallas"][0], out[other][0])
+        _equal(f"affinity_wide_ids prio vs {other}", out["pallas"][1], out[other][1])
+    if not (out["pallas"][1] >= 0).any():
+        raise RuntimeError("affinity_wide_ids: no feasible candidate")
+    log("phase B affinity_wide_ids: label ids moved past 2^24 (by "
+        f"{WIDE_ID_SHIFT}); (idx, prio) == XLA and == the unmoved ids")
 
 
 def sequential_assign(cand_idx, cand_prio, cand_cpu, cand_mem, cand_pods,
@@ -1001,8 +1072,9 @@ def phase_b(coord, timer, *, batch, chunk, score_pct,
     # (ii) the affinity stage, PodSpec slots sized to the workload.
     # Zone and region ids are small; every fourth pod instead requires
     # one of two hostnames inside the window, whose value ids run to the
-    # node count — the kernel carries ids through its dots as 16-bit
-    # halves, and only wide ids show whether the dot kept them exact.
+    # node count — the kernel carries ids through its dots as bytes, and
+    # only wide ids show whether the selection kept them exact; the same
+    # wave then runs once more with every id moved past 2^24.
     def affinity():
         pods = node_affinity_pods(batch, zones=8, regions=4)
         names = _window_node_names(
@@ -1018,10 +1090,9 @@ def phase_b(coord, timer, *, batch, chunk, score_pct,
                     pref_terms=1),
             spec, vocab,
         ).encode_packed(pods)
-        _variant(
-            "affinity", table, packed,
-            Profile(topology_spread=0, interpod_affinity=0), timer, **win
-        )
+        profile = Profile(topology_spread=0, interpod_affinity=0)
+        _variant("affinity", table, packed, profile, timer, **win)
+        _wide_id_variant(table, packed, profile, timer, **win)
 
     attempt(failures, "affinity", affinity)
 
@@ -1044,12 +1115,12 @@ def phase_b(coord, timer, *, batch, chunk, score_pct,
             cspec, vocab,
         ).encode_packed(pods)
         # One wave leaves per-domain counts that are small and even, and
-        # bf16 carries those exactly.  The second state holds what a
-        # long-lived cluster does — thousands per domain, uneven — with
-        # every count of the form m*512 + 255: rounded to 8 significant
-        # bits each comes back one too high, which puts the least-loaded
+        # bf16 carries those exactly.  The second state holds counts at
+        # the edge of the kernel's exact range, up to 2^24 - 1, every
+        # byte of them set (_long_lived): rounded to 8 significant bits
+        # each comes back off by thousands, which puts the least-loaded
         # zone over maxSkew and turns every spread pod infeasible.  Only
-        # a count dot that keeps full precision agrees with XLA here.
+        # a count selection that is exact agrees with XLA here.
         _variant(
             "constraint", table, packed, Profile(), timer,
             constraints=empty_constraints(cspec), also=_long_lived, **win
@@ -1102,20 +1173,31 @@ def phase_b(coord, timer, *, batch, chunk, score_pct,
 
 
 def _long_lived(cons):
-    """Count tables a long-lived cluster holds: thousands a domain, uneven,
-    every count of the form m*512 + 255, which bf16 cannot carry."""
+    """Count tables at the edge of the kernel's exact range (2^24 - 1),
+    uneven and with every byte set, which bf16 cannot carry: the count of
+    zone or region id m is ``0xFFFFFF - 0x010101 * (m % 128)`` (bytes
+    255 - m), and on every odd row the node count is ``0xFFFFFF`` less
+    the row's low 16 bits; the even rows keep their live counts, so a pod
+    with hostname anti-affinity still finds a node."""
     import jax.numpy as jnp
 
-    def counts(t):
-        return jnp.broadcast_to(
-            4351 + 512 * jnp.arange(t.shape[1], dtype=t.dtype), t.shape
-        )
+    top = (1 << 24) - 1
 
-    return cons.replace(**{
-        name: counts(getattr(cons, name)) for name in (
+    def domains(t):
+        m = jnp.arange(t.shape[1], dtype=t.dtype) % 128
+        return jnp.broadcast_to(top - 0x010101 * m, t.shape)
+
+    def nodes(t):
+        row = jnp.arange(t.shape[1], dtype=t.dtype)
+        return jnp.where(row % 2 == 1, top - (row & 0xFFFF), t)
+
+    return cons.replace(
+        **{name: domains(getattr(cons, name)) for name in (
             "spread_zone", "spread_region", "tgt_zone", "tgt_region",
-            "own_zone", "own_region")
-    })
+            "own_zone", "own_region")},
+        **{name: nodes(getattr(cons, name)) for name in (
+            "spread_node", "tgt_node", "own_node")},
+    )
 
 
 def phase_full(timer, *, waves=FULL_WAVES, tiny=None) -> dict:

@@ -21,18 +21,30 @@ Plugin coverage: NodeResourcesFit + NodeName + TaintToleration
 terms, preferred-term scoring — all six selector ops).  The NodeAffinity
 gathers (per-expression lookups into the per-chunk label resolution)
 become one-hot matmuls on the MXU, like the taint trick: the [Q, C]
-query-key resolution is packed as a [Q, 5C] plane (found, value-id hi/lo,
-numeric hi/lo) and each expression slot selects its row with a
-[TB, Q] x [Q, 5C] dot.  Every id travels the f32 dot as two 16-bit
-halves and is recombined in int32, so In/NotIn equality and Gt/Lt
-compares are bit-exact even for ids beyond f32's 2^24 integer range
-(one-hot rows make the dot a pure selection — no summation error).  That
-only holds if the dot itself keeps f32 precision: Mosaic's default
-contraction rounds f32 operands to bf16 (8 significant bits — measured
-on v5e: every score above 255 came back off by a few units), so every
-dot that carries a wide value asks for ``_EXACT`` (fp32 contraction);
-the taint dots carry only 0/1 and counts <= taint_slots and stay on the
-fast default.
+query-key resolution is packed as a selection plane with the columns
+found, value-id hi/lo half, numeric hi/lo half, and each expression slot
+selects its rows with one dot.  The 16-bit halves are recombined in
+int32, so In/NotIn equality and Gt/Lt compares are bit-exact for every
+int32 id and numeric, beyond f32's 2^24 integer range too.
+
+**Every selection dot is one exact bf16 pass.**  Mosaic contracts in
+bf16 (8 significant bits: measured on v5e, every score above 255 sent
+through an f32 dot at the default precision came back off by a few
+units), and an fp32 contraction costs six bf16 passes.  Neither is
+needed, because the operands are made exact in bf16: the one-hot side
+holds 0 and powers of two (1, 256, 65,536), and a wide value travels
+as its bytes — each below 256, the top one signed.  A byte times a
+power of two is exact in the MXU's f32 accumulator, and so is the sum
+of a value's two or three such terms, every partial sum being an
+integer below 2^24.  So a label half is two byte rows of a [2Q, 5C]
+plane, selected by a [TB, 2Q] row weighted 256 / 1; a domain count is
+three byte rows of a [9*SLOTS, C] plane (below), selected by a
+[TB, 9*SLOTS] row weighted 1 / 256 / 65,536 — the exact count for any
+count of magnitude below 2^24, f32's own integer range, in the
+topology the ref names, in ceil(9*SLOTS / 128) passes of the MXU's
+128-deep contraction.  No dot of the kernel asks for more than the
+default precision.  The taint dots carry only 0/1 and
+counts <= taint_slots, and their f32 operands stay as they were.
 Constraint plugins (PodTopologySpread, InterPodAffinity) run fused too
 when the caller passes the count tables and their prologue (the
 ``with_cons`` stage below, compiled as ``fused_topk_constraints``); with
@@ -93,10 +105,19 @@ from k8s1m_tpu.plugins.registry import Profile
 from k8s1m_tpu.snapshot.node_table import NodeTable
 from k8s1m_tpu.snapshot.pod_encoding import PodBatch
 
-# Contraction precision for the one-hot selection dots whose operands
-# carry integers wider than 8 bits (16-bit id/score halves, domain
-# counts) — see the module doc.
-_EXACT = lax.Precision.HIGHEST
+
+def _bf16(x):
+    """A selection dot's operand in bf16.  Every value cast here is a
+    0/1, a power of two up to 2^16 or a byte, which bf16 holds exactly
+    (see the module doc)."""
+    return x.astype(jnp.float32).astype(jnp.bfloat16)
+
+
+def _count_bytes(x):
+    """i32[S, W] counts -> i32[3S, W]: bytes 0 and 1 unsigned, then the
+    signed rest ``x >> 16``, each exact in bf16 for |x| < 2^24 — the
+    rows byte 0, byte 1, byte 2 of every slot."""
+    return jnp.concatenate([x & 255, (x >> 8) & 255, x >> 16], axis=0)
 
 
 def supports(profile: Profile) -> bool:
@@ -298,15 +319,9 @@ def _kernel(
         # form vectorizes the same.
         q = qkey.shape[0]
         kq = qkey[:]                                  # [Q, 1]
-        found = jnp.zeros((q, c), jnp.float32)
-        # Every id travels the f32 dot as two 16-bit halves (exact under
-        # the _EXACT contraction) and is recombined in int32 — value ids
-        # as well as numerics, so vocab ids beyond f32's 2^24 integer
-        # range can never alias.
-        vhi = jnp.zeros((q, c), jnp.float32)
-        vlo = jnp.zeros((q, c), jnp.float32)
-        nhi = jnp.zeros((q, c), jnp.float32)
-        nlo = jnp.zeros((q, c), jnp.float32)
+        found = jnp.zeros((q, c), jnp.int32)
+        val = jnp.zeros((q, c), jnp.int32)
+        num = jnp.zeros((q, c), jnp.int32)
         for l in range(lkey.shape[0]):
             if fused_labels:
                 # Fused word: val << key_bits | key (snapshot/packing.py).
@@ -319,25 +334,34 @@ def _kernel(
                 lk = lkey[l : l + 1, :]               # [1, C]
                 lv = lval[l : l + 1, :]
             eq = (kq == lk) & (lk != NONE_ID)         # [Q, C]
-            found = jnp.where(eq, 1.0, found)
-            vhi = jnp.where(eq, (lv >> 16).astype(jnp.float32), vhi)
-            vlo = jnp.where(eq, (lv & 0xFFFF).astype(jnp.float32), vlo)
-            ln = lnum[l : l + 1, :]
-            nhi = jnp.where(eq, (ln >> 16).astype(jnp.float32), nhi)
-            nlo = jnp.where(eq, (ln & 0xFFFF).astype(jnp.float32), nlo)
-        planes = jnp.concatenate([found, vhi, vlo, nhi, nlo], axis=1)  # [Q, 5C]
-        iota_q = lax.broadcasted_iota(jnp.int32, (tb, q), 1)
+            found = jnp.where(eq, 1, found)
+            val = jnp.where(eq, lv, val)
+            num = jnp.where(eq, lnum[l : l + 1, :], num)
+        # The selection's right-hand side (module doc): columns found,
+        # value hi/lo half, numeric hi/lo half; rows the high byte of
+        # each half (the value's top byte signed), then the low byte.
+        zero = jnp.zeros((q, c), jnp.int32)
+        planes = _bf16(jnp.concatenate([
+            jnp.concatenate(
+                [zero, val >> 24, (val >> 8) & 255,
+                 num >> 24, (num >> 8) & 255], axis=1),
+            jnp.concatenate(
+                [found, (val >> 16) & 255, val & 255,
+                 (num >> 16) & 255, num & 255], axis=1),
+        ], axis=0))                                   # [2Q, 5C]
+        # Lane j of the selection row stands for query index j mod Q,
+        # weighted 256 on the high-byte rows and 1 on the low-byte rows.
+        lane_q = lax.broadcasted_iota(jnp.int32, (1, 2 * q), 1)
+        q_of = jnp.where(lane_q < q, lane_q, lane_q - q)
+        w_of = jnp.where(lane_q < q, 256.0, 1.0)
         one_i = jnp.int32(1)
 
         def gather_slot(qidx_c):
             """One expression slot's per-node view: (found 0/1, value id
-            i32, numeric i32 — both recombined exactly from 16-bit
-            halves)."""
-            onehot = (qidx_c == iota_q).astype(jnp.float32)       # [TB, Q]
-            g = jnp.dot(
-                onehot, planes, precision=_EXACT,
-                preferred_element_type=jnp.float32,
-            )
+            i32, numeric i32 — both recombined from their 16-bit halves,
+            each an exact one-pass selection of two bytes)."""
+            sel = _bf16(jnp.where(q_of == qidx_c, w_of, 0.0))  # [TB, 2Q]
+            g = jnp.dot(sel, planes, preferred_element_type=jnp.float32)
             fi = (g[:, :c] > 0.5).astype(jnp.int32)
             v = (
                 g[:, c : 2 * c].astype(jnp.int32) * 65536
@@ -450,11 +474,15 @@ def _kernel(
     # ---- constraint plugins (with_cons): PodTopologySpread +
     # InterPodAffinity count-table lookups as one-hot matmuls.  The
     # domain-count gathers of the XLA path (plugins/topology.py
-    # _counts_for) become: per chunk, project the [SLOTS, Z] zone/region
-    # tables onto the chunk's nodes with a domain one-hot ([SLOTS, Z] x
-    # [Z, C] on the MXU), then select each pod ref's slot with a one-hot
-    # [TB, SLOTS] dot.  Counts are integers < 2^24, exact through the
-    # dots under the _EXACT contraction.  Batch-global statistics
+    # _counts_for) become: per chunk and family, one [9*SLOTS, C] bf16
+    # plane of count bytes — the node columns split on the VPU, the
+    # byte-split [SLOTS, Z] zone and [SLOTS, R] region tables projected
+    # onto the chunk's nodes with a domain one-hot ([3*SLOTS, Z] x
+    # [Z, C], one nonzero term a column, so each result is a byte
+    # again) — and per pod ref one [TB, 9*SLOTS] selection whose
+    # weights sit at the byte rows of the ref's own topology and slot:
+    # one default-precision dot returns the count in that topology,
+    # exact for |count| < 2^24 (module doc).  Batch-global statistics
     # (min/max per domain, target totals, preferred-score bounds) are
     # [TB, *] inputs precomputed by the caller from topology.prologue —
     # global reductions don't belong in a chunk-local kernel.
@@ -463,58 +491,65 @@ def _kernel(
         rdim = sr.shape[1]
         zc_ids = zone_c[:]                                    # [1, C]
         rc_ids = region_c[:]
-        onehot_z = (
+        onehot_z = _bf16(
             lax.broadcasted_iota(jnp.int32, (zdim, c), 0) == zc_ids
-        ).astype(jnp.float32)                                 # [Z, C]
-        onehot_r = (
+        )                                                     # [Z, C]
+        onehot_r = _bf16(
             lax.broadcasted_iota(jnp.int32, (rdim, c), 0) == rc_ids
-        ).astype(jnp.float32)
+        )
         dom_z = (zc_ids != 0).astype(jnp.int32)               # [1, C]
         dom_r = (rc_ids != 0).astype(jnp.int32)
 
-        def chunk_tables(node_cols, ztab, rtab):
-            return (
-                node_cols[:].astype(jnp.float32),
-                jnp.dot(ztab[:].astype(jnp.float32), onehot_z,
-                        precision=_EXACT,
-                        preferred_element_type=jnp.float32),
-                jnp.dot(rtab[:].astype(jnp.float32), onehot_r,
-                        precision=_EXACT,
-                        preferred_element_type=jnp.float32),
-            )
+        def count_planes(node_cols, ztab, rtab):
+            """The family's counts as one [9*SLOTS, C] selection
+            right-hand side (module doc): rows (hostname, zone, region)
+            x (byte 0, 1, 2) x slot.  The zone and region bytes are the
+            byte-split tables projected onto the chunk's nodes by their
+            domain one-hot, one nonzero term a column."""
+            def project(tab, onehot):
+                return jnp.dot(_bf16(_count_bytes(tab[:])), onehot,
+                               preferred_element_type=jnp.float32)
+            return _bf16(jnp.concatenate([
+                _count_bytes(node_cols[:]).astype(jnp.float32),
+                project(ztab, onehot_z),
+                project(rtab, onehot_r),
+            ], axis=0))
 
-        def ref_counts(tables, slot_col, topo_col):
+        def ref_counts(planes, slot_col, topo_col):
             """One [TB, 1] (slot, topo) ref -> (cnt i32[TB, C],
-            domain_ok i32[TB, C])."""
-            nf, zf, rf = tables
-            slots = nf.shape[0]
-            sel = (
-                lax.broadcasted_iota(jnp.int32, (tb, slots), 1) == slot_col
-            ).astype(jnp.float32)                             # [TB, SLOTS]
-            cn = jnp.dot(sel, nf, precision=_EXACT,
-                         preferred_element_type=jnp.float32)
-            cz = jnp.dot(sel, zf, precision=_EXACT,
-                         preferred_element_type=jnp.float32)
-            cr = jnp.dot(sel, rf, precision=_EXACT,
-                         preferred_element_type=jnp.float32)
+            domain_ok i32[TB, C]): one selection row a pod, weighted
+            1 / 256 / 65,536 at the three byte rows of its topology
+            and slot, so the dot returns the count itself."""
+            slots = planes.shape[0] // 9
+            lane = lax.broadcasted_iota(jnp.int32, (1, 9 * slots), 1)
+            slot_of, topo_of, w_of = lane, jnp.zeros_like(lane), 1.0
+            for blk in range(1, 9):
+                past = lane >= blk * slots
+                slot_of = jnp.where(past, lane - blk * slots, slot_of)
+                topo_of = jnp.where(past, blk // 3, topo_of)
+                w_of = jnp.where(past, float(256 ** (blk % 3)), w_of)
             is_h = topo_col == TOPO_HOSTNAME
             is_z = topo_col == TOPO_ZONE
-            cnt = jnp.where(is_h, cn, jnp.where(is_z, cz, cr))
+            tix = jnp.where(is_h, 0, jnp.where(is_z, 1, 2))   # [TB, 1]
+            sel = _bf16(jnp.where(
+                (slot_of == slot_col) & (topo_of == tix), w_of, 0.0
+            ))                                                # [TB, 9*SLOTS]
+            cnt = jnp.dot(sel, planes, preferred_element_type=jnp.float32)
             dok = jnp.where(
                 is_h, jnp.ones((tb, c), jnp.int32),
                 jnp.where(is_z, dom_z, dom_r),
             )
             return cnt.astype(jnp.int32), dok
 
-        s_tabs = chunk_tables(sn, sz, sr)
-        t_tabs = chunk_tables(tn, tz, tr)
-        o_tabs = chunk_tables(on_, oz, orr)
+        s_planes = count_planes(sn, sz, sr)
+        t_planes = count_planes(tn, tz, tr)
+        o_planes = count_planes(on_, oz, orr)
 
         cons_ok = jnp.ones((tb, c), jnp.int32)
         spread_acc = jnp.zeros((tb, c), jnp.float32)
         for j in range(sp_cid.shape[1]):
             cnt, dok = ref_counts(
-                s_tabs, sp_cid[:, j : j + 1], sp_topo[:, j : j + 1]
+                s_planes, sp_cid[:, j : j + 1], sp_topo[:, j : j + 1]
             )
             minc = sp_min[:, j : j + 1]
             maxc = sp_max[:, j : j + 1]
@@ -535,7 +570,7 @@ def _kernel(
         raw_pref = jnp.zeros((tb, c), jnp.float32)
         for j in range(ia_tid.shape[1]):
             tcnt, tdok = ref_counts(
-                t_tabs, ia_tid[:, j : j + 1], ia_topo[:, j : j + 1]
+                t_planes, ia_tid[:, j : j + 1], ia_topo[:, j : j + 1]
             )
             aff_ok = jnp.maximum(
                 tdok
@@ -555,7 +590,7 @@ def _kernel(
             )
         for j in range(ii_tid.shape[1]):
             ocnt, odok = ref_counts(
-                o_tabs, ii_tid[:, j : j + 1], ii_topo[:, j : j + 1]
+                o_planes, ii_tid[:, j : j + 1], ii_topo[:, j : j + 1]
             )
             sym_ok = jnp.maximum(
                 jnp.maximum(1 - odok, (ocnt == 0).astype(jnp.int32)),

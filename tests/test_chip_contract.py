@@ -102,7 +102,8 @@ def test_cleaned_cpu_env_leaves_pythonpath_alone():
 # ---- no CPU fallback -------------------------------------------------------
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+@pytest.mark.parametrize(
+    "script", ["chip_smoke.py", "bench.py", "tools/kernel_time.py"])
 def test_entry_points_refuse_a_cpu_and_print_no_metric(script):
     proc = _run(os.path.join(REPO, script), script=True)
     assert proc.returncode not in (0, None)
@@ -159,7 +160,8 @@ def test_smoke_phases_a_and_b_tiny(smoke):
     assert {"A.bootstrap", "B.base.pallas_step",
             "B.xla_scan.all_rows.xla_step", "B.assign.window_wave",
             "B.assign.fit_10k_brim", "B.prologue.9x5",
-            "B.prologue.512x64"} <= set(timer.seconds)
+            "B.prologue.512x64",
+            "B.affinity_wide_ids.pallas_candidates"} <= set(timer.seconds)
 
 
 def test_smoke_phase_full_tiny(smoke):

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from k8s1m_tpu.config import (
     EFFECT_NO_SCHEDULE,
@@ -538,3 +539,175 @@ def test_scaled_affinity_oracle_boundaries(rng):
     ref_i, ref_p = np_reference_topk(table, batch, 4242, prof, k=4)
     np.testing.assert_array_equal(np.asarray(prio), ref_p)
     np.testing.assert_array_equal(np.asarray(idx), ref_i)
+
+
+# ---- exactness of the one-pass bf16 selection dots at wide values -------
+#
+# The kernel's selection dots carry wide values as bf16 bytes (module doc
+# of ops/pallas_topk.py).  Each case below puts one regime of wide values
+# through them: constraint counts at and across the byte boundaries, up to
+# 2^24 - 1, in the hostname, zone and region topologies of spread, target
+# and own refs; label value ids past 2^24 and near 2^31; numerics past
+# 2^24, negative, and just above the NO_NUMERIC sentinel.  The candidates
+# must equal the XLA scan's, and, where the numpy oracle covers the
+# plugins, the oracle's, bit for bit.
+
+WIDE_COUNTS = (255, 256, 65_535, 65_536, (1 << 24) - 1)
+NARROW = PodSpec(
+    batch=64, query_keys=4, aff_terms=1, aff_exprs=1, aff_values=1,
+    pref_terms=1, spread_refs=2, affinity_refs=2, spread_incs=2, ipa_incs=2,
+)
+
+
+def _wide_count_inputs(rng, level):
+    """build_cons' cluster after a first wave, with every count table —
+    node, zone and region planes of the spread, target and own families —
+    redrawn from {0, level - 2, ..., level + 1} (capped at 2^24 - 1), and
+    a batch whose refs name all three topologies in all three families."""
+    from k8s1m_tpu.cluster.workload import (
+        affinity_deployment,
+        spread_deployment,
+    )
+    from k8s1m_tpu.config import TOPO_HOSTNAME, TOPO_REGION, TOPO_ZONE
+
+    from k8s1m_tpu.snapshot.constraints import ConstraintTracker
+
+    spec, host, enc, pods, cons = build_cons(rng)
+    table, cons = _populate_counts(host, enc, pods, cons)
+    tracker = ConstraintTracker(spec)
+    wave = []
+    for t in (TOPO_HOSTNAME, TOPO_ZONE, TOPO_REGION):
+        target = {"app": f"w-sp{t}"}
+        wave += spread_deployment(tracker, f"w-sp{t}", 4, topo=t)
+        wave += affinity_deployment(
+            tracker, f"w-aff{t}", 3, target=target, topo=t)
+        # Self-selecting required anti-affinity: the own (symmetry) refs.
+        wave += affinity_deployment(tracker, f"w-anti{t}", 3, anti=True, topo=t)
+        wave += affinity_deployment(
+            tracker, f"w-pref{t}", 3, required=False, anti=bool(t % 2),
+            weight=7 + t, target=target, topo=t,
+        )
+    rng.shuffle(wave)
+    batch = PodBatchHost(NARROW, spec, host.vocab).encode(wave)
+    vals = np.minimum(
+        np.array([0, level - 2, level - 1, level, level + 1]), (1 << 24) - 1
+    )
+
+    def redraw(t):
+        return jnp.asarray(vals[rng.integers(0, len(vals), t.shape)], jnp.int32)
+
+    cons = cons.replace(**{
+        name: redraw(getattr(cons, name)) for name in (
+            "spread_node", "spread_zone", "spread_region", "tgt_node",
+            "tgt_zone", "tgt_region", "own_node", "own_zone", "own_region")
+    })
+    return table, batch, cons, Profile()
+
+
+def _wide_label_inputs(rng, regime):
+    """affinity_pods on build_labeled's nodes (nodeSelector, required and
+    preferred slots, all six ops, a never-interned value as NONE_ID, the
+    tier labels' NO_NUMERIC) with the value ids or the numerics moved into
+    one wide regime by an order-keeping map that leaves both sentinels."""
+    from k8s1m_tpu.config import NO_NUMERIC, NONE_ID
+
+    spec, host = build_labeled(rng)
+    batch = affinity_pods(host, spec)
+    table = host.to_device()
+    top = int(np.asarray(table.label_val).max())
+    shift = {
+        "ids-past-2^24": ("ids", (1 << 24) + 1),
+        "ids-near-2^31": ("ids", (1 << 31) - 1 - top),
+        # gen runs from 1e8 (past 2^24): mirrored below zero, then moved
+        # to just above the sentinel.
+        "numerics-negative": ("num", None),
+        "numerics-above-NO_NUMERIC": ("num", NO_NUMERIC + 1),
+    }
+    kind, by = shift[regime]
+    if kind == "ids":
+        move = lambda x: jnp.where(x != NONE_ID, x + by, x)
+        return (
+            table.replace(label_val=move(table.label_val)),
+            batch.replace(
+                sel_val=move(batch.sel_val), req_vals=move(batch.req_vals),
+                pref_vals=move(batch.pref_vals),
+            ),
+        )
+    if by is None:
+        move = lambda x: jnp.where(x != NO_NUMERIC, -x, x)
+    else:
+        move = lambda x: jnp.where(x != NO_NUMERIC, x - 100_000_000 + by, x)
+    return (
+        table.replace(label_num=move(table.label_num)),
+        batch.replace(req_num=move(batch.req_num), pref_num=move(batch.pref_num)),
+    )
+
+
+@pytest.mark.parametrize(
+    "regime",
+    [f"counts-{c}" for c in WIDE_COUNTS]
+    + ["ids-past-2^24", "ids-near-2^31", "numerics-negative",
+       "numerics-above-NO_NUMERIC"],
+)
+def test_selection_dots_exact_at_wide_values(rng, regime):
+    from k8s1m_tpu.engine.cycle import candidates
+
+    if regime.startswith("counts-"):
+        table, batch, cons, prof = _wide_count_inputs(
+            rng, int(regime.split("-")[1]))
+    else:
+        (table, batch), cons, prof = _wide_label_inputs(rng, regime), None, AFF
+        idx, prio = fused_topk(table, batch, jnp.int32(31), prof, chunk=CHUNK, k=4)
+        ref_i, ref_p = np_reference_topk(table, batch, 31, prof, k=4)
+        np.testing.assert_array_equal(np.asarray(prio), ref_p)
+        np.testing.assert_array_equal(np.asarray(idx), ref_i)
+    key = jax.random.key(17)
+    got = {
+        backend: candidates(
+            table, batch, key, cons, prof, chunk=CHUNK, k=4, backend=backend,
+        )
+        for backend in ("pallas", "xla")
+    }
+    np.testing.assert_array_equal(
+        np.asarray(got["pallas"].prio), np.asarray(got["xla"].prio))
+    np.testing.assert_array_equal(
+        np.asarray(got["pallas"].idx), np.asarray(got["xla"].idx))
+    prio = np.asarray(got["pallas"].prio)
+    # The regime decides something: some pods keep candidates, and some
+    # pods or slots lose them.
+    assert (prio >= 0).any() and (prio < 0).any()
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters."""
+    for e in jaxpr.eqns:
+        yield e
+        for p in e.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_kernel_dots_are_one_default_pass(rng):
+    """No dot of the kernel with both stages asks for more than the
+    default precision, and every one-hot selection runs on bf16 operands;
+    only the two taint dots (0/1 and small counts) keep f32 ones."""
+    from k8s1m_tpu.plugins import topology
+
+    spec, host, enc, pods, cons = build_cons(rng)
+    table = host.to_device()
+    batch = PodBatchHost(NARROW, spec, host.vocab).encode(pods[:12])
+    stats = topology.prologue(table, cons)
+    closed = jax.make_jaxpr(lambda: fused_topk(
+        table, batch, jnp.int32(0), Profile(), chunk=CHUNK, k=4,
+        constraints=cons, stats=stats,
+    ))()
+    (call,) = [e for e in _eqns(closed.jaxpr) if e.primitive.name == "pallas_call"]
+    dots = [e for e in _eqns(call.params["jaxpr"])
+            if e.primitive.name == "dot_general"]
+    assert all(d.params["precision"] in (None, (lax.Precision.DEFAULT,) * 2)
+               for d in dots)
+    wide = [d for d in dots
+            if any(v.aval.dtype != jnp.bfloat16 for v in d.invars)]
+    assert len(wide) == 2 and len(dots) > 2
